@@ -1,8 +1,8 @@
 """acclint CLI: ``python -m accl_tpu.analysis``.
 
 Exit status: 0 when no unsuppressed findings, 1 otherwise, 2 on usage
-errors — so it slots straight into shell gates (chip_session.sh leg 0,
-bench.py's LKG stash gate, CI).
+errors — so it slots straight into shell gates (CI; bench.py runs the
+same checks in-process as a capture gate).
 """
 
 from __future__ import annotations

@@ -97,10 +97,11 @@ class CallOptions:
 class InteractionCounter:
     """Counts *device interactions*: program dispatches and host<->device
     transfers an engine issues on the data path.  The reference's hostctrl
-    discipline is ONE command per collective (hostctrl.cpp:22-63); on a
-    tunneled host every extra interaction bills a full RTT, so the engines
-    keep an honest running count — exposed via
-    ``ACCL.capabilities()["device_interactions"]`` and asserted by
+    discipline is ONE command per collective (hostctrl.cpp:22-63); every
+    extra interaction costs the host a dispatch and leaves the device
+    idle until it lands, so the engines keep an honest running count —
+    exposed via ``ACCL.capabilities()["device_interactions"]`` and
+    asserted by
     tests/test_dispatch_overhead.py (one collective == one bump on the
     gang fast path).
 

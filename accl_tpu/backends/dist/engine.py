@@ -171,10 +171,6 @@ class DistEngine(StreamPortMixin, BaseEngine):
         self._nf_sig: Optional[tuple] = None
         self._nf_probed = False
         self._nf_probe_tries = 0
-        # compat KV adapter cache (legacy jaxlib clients lack the
-        # try-get/increment surface; see compat.kv_client)
-        self._kv_raw = None
-        self._kv_wrapped = None
         # contract plane: per-comm KV digest-piggyback cursors +
         # lifetime counters (see _kv_contract_exchange)
         self._vfy_kv_state: Dict[int, dict] = {}
@@ -800,14 +796,7 @@ class DistEngine(StreamPortMixin, BaseEngine):
         client = distributed.global_state.client
         if client is None:  # pragma: no cover - initialize() guarantees it
             raise RuntimeError("distributed KV service unavailable")
-        # modern KV surface over whatever jaxlib provides: legacy clients
-        # (no try-get/increment) are wrapped once by the compat adapter
-        if self._kv_raw is not client:
-            from ...compat import kv_client
-
-            self._kv_raw = client
-            self._kv_wrapped = kv_client(client)
-        return self._kv_wrapped
+        return client
 
     def arbiter_kv(self):
         """The KV plane handed to the QoS arbiter's cross-process tenant
@@ -1091,18 +1080,11 @@ def dist_group_member(
     """
     import os
 
-    # honor an explicit platform request via config as well as env: some
-    # site PJRT hooks only respect the config path, and probing the
-    # backend here would initialize it before jax.distributed
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        jax.config.update("jax_platforms", platforms)
+    # (no backend probe here: it would initialize jax before
+    # jax.distributed does)
     if "tpu" not in os.environ.get("JAX_PLATFORMS", "").lower():
-        try:
-            # CPU backend needs an explicit cross-process collectives impl
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # pragma: no cover - older jax without the option
-            pass
+        # CPU backend needs an explicit cross-process collectives impl
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator, num_processes=world, process_id=rank
     )

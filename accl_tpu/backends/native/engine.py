@@ -100,16 +100,10 @@ def _load():
     if _LOAD_ATTEMPTED:
         return _LIB
     _LOAD_ATTEMPTED = True
-    so = _native_dataplane._NATIVE_DIR / "build" / "libaccl_engine.so"
-    if not so.exists():
-        _native_dataplane._try_build()
-    if not so.exists():
+    if not _native_dataplane.build():
         return None
-    try:
-        lib = ctypes.CDLL(str(so))
-        _bind(lib)
-    except (OSError, AttributeError):
-        return None
+    lib = ctypes.CDLL(str(_native_dataplane._ENGINE_SO_PATH))
+    _bind(lib)
     _LIB = lib
     return _LIB
 

@@ -141,15 +141,49 @@ class _RingRefillMsg:
 
 
 def default_lowering() -> str:
-    """Sequencer lowering: the Pallas remote-DMA mega-window kernel on a
-    real TPU, the persistent XLA session everywhere else (the
-    emulator/CI tier).  Override with ``ACCL_CMDRING_LOWERING``."""
+    """Sequencer lowering: ``"xla"`` on every backend, unless
+    ``ACCL_CMDRING_LOWERING`` names one.
+
+    The Pallas mega-window kernel was the TPU default until it first met
+    a TPU with more than one chip (chip run, PR 21, four v5e, jax 0.9.0):
+    its slot epilogue slices VALUES dynamically inside the kernel and
+    Mosaic has no lowering for that —
+
+        NotImplementedError: Unimplemented primitive in Pallas TPU
+        lowering for KernelType.TC: dynamic_slice
+        (ops/pallas/cmdring.py slot_epilogue, lax.dynamic_slice_in_dim)
+
+    — at every payload tried (64 KiB to 4 MiB a slot, 3 and 8 slots); on
+    ONE chip it compiles only because a world of one returns before the
+    epilogue.  The xla lowering's one-shot inline form compiled and ran
+    the same windows on one chip and on four, so it is what a TPU gets;
+    the Pallas kernel stays reachable by name (the CPU mesh runs it
+    interpreted) and fails loudly on a multi-chip TPU.  Observed, not
+    tuned: D4 decides which lowering stays."""
     explicit = os.environ.get("ACCL_CMDRING_LOWERING")
     if explicit in ("xla", "pallas"):
         return explicit
+    return "xla"
+
+
+def persistent_runs_lower() -> bool:
+    """Whether the xla lowering's PERSISTENT form — the resident
+    ``while_loop`` of ordered ``io_callback`` mailbox pulls — can be
+    used on this backend.  On a TPU it cannot: jax 0.9.0 / libtpu
+    0.0.34 refuse to lower it (chip run, PR 21, one v5e, 64 KiB x 3
+    slots x 4 windows posted ahead)::
+
+        ValueError: Cannot lower jaxpr with verifier errors:
+          'stablehlo.recv' op result 0 - sharding doesn't match tensor
+          rank: 0 != 2   at loc("jit(body)/io_callback" ... run_session)
+
+    so there every xla-lowered window takes the one-shot INLINE form
+    (one program a window, no mailbox), which compiles and runs.  The
+    Pallas lowering never had a persistent form.  Observed, not
+    configured: D4 decides what stays."""
     import jax
 
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return jax.default_backend() != "tpu"
 
 
 class _RowAdopter:
@@ -365,6 +399,7 @@ class GangCommandRing:
         except ValueError:
             self.max_bytes = CMDRING_MAX_PAYLOAD_BYTES
         self.lowering = default_lowering()
+        self.persistent = persistent_runs_lower()
         self.run_windows = default_run_windows()
         self.linger_s = default_linger_s()
         self._lock = threading.Lock()
@@ -501,6 +536,7 @@ class GangCommandRing:
                 "mode": "eager" if self.eager else
                         ("batch" if self.enabled else "off"),
                 "lowering": self.lowering,
+                "persistent": self.persistent,
                 "depth": self.depth,
                 "run_windows": self.run_windows,
                 "linger_ms": round(self.linger_s * 1e3, 3),
@@ -766,7 +802,7 @@ class GangCommandRing:
             return False
         gang = self.gang
         mesh = gang.submesh(comm)
-        if mesh is None or npos == 0:
+        if npos == 0:
             return False
         # ring circuit breaker (membership plane): an OPEN comm rides
         # host dispatch until the cool-down; HALF_OPEN probes with the
@@ -1196,7 +1232,7 @@ class GangCommandRing:
                     # (zero-copy operands, async dispatch, no mailbox
                     # round trip on its latency path).
                     streaming = len(session.parks) > 1
-                if (live or streaming) and not probe:
+                if self.persistent and (live or streaming) and not probe:
                     # (a half-open probe window stays INLINE — the
                     # ring -> inline degradation step: one-shot
                     # program, no persistent run to wedge)

@@ -34,10 +34,6 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 import jax
-
-from ...compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 
 from ...communicator import Communicator
@@ -120,7 +116,11 @@ def _prep_program(width: int, wire_name: Optional[str], device,
     def f(a):
         a = a[:width]
         if wire_name is not None:
-            a = a.astype(jnp.dtype(wire_name)).astype(a.dtype)
+            # the shared lane helper (as opdriver._with_prep): a bare
+            # astype pair is one XLA on a TPU sees through and removes
+            from ...ops import wire as devwire
+
+            a = devwire.wire_lane_roundtrip(a, jnp.dtype(wire_name))
         return a if flat else a.reshape(1, width)
 
     return jax.jit(f, out_shardings=SingleDeviceSharding(device))
@@ -156,10 +156,7 @@ def _p2p_hop_program(src_dev, dst_dev):
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map  # type: ignore
+    from jax import shard_map
 
     mesh = Mesh([src_dev, dst_dev], ("p2p",))
     spec = PartitionSpec("p2p")
@@ -558,19 +555,21 @@ class XLAGangContext:
         """Sub-mesh over the communicator's member devices — rank i of the
         communicator executes on the device of its *global* rank identity
         (``Rank.session``), so a subcommunicator of ranks {4..7} runs on
-        devices 4-7, not 0-3.  None when the host has fewer devices than the
-        membership needs — execution falls back to host numpy, the
-        single-controller analog of the reference's emulator tier."""
+        devices 4-7, not 0-3.  A membership the host has too few devices
+        for raises: a gang collective never runs anywhere but on its
+        members' devices (``xla_group`` refuses such a group up front)."""
         sessions = tuple(r.session for r in comm.ranks)
         if sessions in self._submeshes:
             return self._submeshes[sessions]
         devs = jax.devices()
-        if max(sessions) < len(devs):
-            from jax.sharding import Mesh
+        if max(sessions) >= len(devs):
+            raise ValueError(
+                f"communicator member {max(sessions)} has no device: jax "
+                f"found {len(devs)} on {jax.default_backend()!r}"
+            )
+        from jax.sharding import Mesh
 
-            mesh = Mesh([devs[s] for s in sessions], (opdriver.AXIS,))
-        else:
-            mesh = None
+        mesh = Mesh([devs[s] for s in sessions], (opdriver.AXIS,))
         self._submeshes[sessions] = mesh
         return mesh
 
@@ -1077,7 +1076,7 @@ class XLAGangContext:
         mismatch at any position (that position must surface its error
         through the sequential path)."""
         mesh = self.submesh(comm)
-        if mesh is None or npos == 0:
+        if npos == 0:
             return False
         if any(
             self.tuning.get(k, "xla") != "xla" for k in self._BATCH_TUNING_KEYS
@@ -1225,10 +1224,9 @@ class XLAGangContext:
                 return ErrorCode.RECEIVE_TIMEOUT
             return ErrorCode.OK
         mesh = self.submesh(comm)
-        if mesh is not None:
-            code = self._run_op_device(comm, calls, lead, mesh, reqs, t0)
-            if code is not None:
-                return code
+        code = self._run_op_device(comm, calls, lead, mesh, reqs, t0)
+        if code is not None:
+            return code
         return self._run_op_host(comm, calls, lead, mesh)
 
     # -- overlap plane --------------------------------------------------------
@@ -1788,11 +1786,7 @@ class XLAGangContext:
 
         if op == Operation.REDUCE:
             stacked = wire_cast(_np_stack_op0(calls, [n] * size, ic))
-            out = np.asarray(
-                self._run_rooted(op, stacked, mesh, lead)
-                if mesh is not None
-                else self._host_reduce(stacked, fn)[None].repeat(size, 0)
-            )
+            out = np.asarray(self._run_rooted(op, stacked, mesh, lead))
             root = lead.root_dst
             res = calls[root].res
             if res is not None and not res.is_dummy:
@@ -1801,33 +1795,21 @@ class XLAGangContext:
 
         if op == Operation.BCAST:
             stacked = wire_cast(_np_stack_op0(calls, [n] * size, ic))
-            out = np.asarray(
-                self._run_rooted(op, stacked, mesh, lead)
-                if mesh is not None
-                else stacked[lead.root_src][None].repeat(size, 0)
-            )
+            out = np.asarray(self._run_rooted(op, stacked, mesh, lead))
             for r, call in enumerate(calls):
                 _write_host_result(call.res, out[r], n, ic)
             return ErrorCode.OK
 
         if op == Operation.ALLGATHER:
             stacked = wire_cast(_np_stack_op0(calls, [n] * size, ic))
-            out = np.asarray(
-                opdriver.run_allgather(stacked, mesh)
-                if mesh is not None
-                else stacked.reshape(-1)[None].repeat(size, 0)
-            )
+            out = np.asarray(opdriver.run_allgather(stacked, mesh))
             for r, call in enumerate(calls):
                 _write_host_result(call.res, out[r], size * n, ic)
             return ErrorCode.OK
 
         if op == Operation.REDUCE_SCATTER:
             stacked = wire_cast(_np_stack_op0(calls, [size * n] * size, ic))
-            out = np.asarray(
-                opdriver.run_reduce_scatter(stacked, mesh, fn)
-                if mesh is not None
-                else self._host_reduce(stacked, fn).reshape(size, n)
-            )
+            out = np.asarray(opdriver.run_reduce_scatter(stacked, mesh, fn))
             for r, call in enumerate(calls):
                 _write_host_result(call.res, out[r][:n], n, ic)
             return ErrorCode.OK
@@ -1835,11 +1817,7 @@ class XLAGangContext:
         if op == Operation.SCATTER:
             root = lead.root_src
             stacked = wire_cast(_np_stack_op0(calls, [size * n] * size, ic))
-            out = np.asarray(
-                self._run_rooted(op, stacked, mesh, lead)
-                if mesh is not None
-                else stacked[root].reshape(size, n)
-            )
+            out = np.asarray(self._run_rooted(op, stacked, mesh, lead))
             for r, call in enumerate(calls):
                 _write_host_result(call.res, out[r], n, ic)
             return ErrorCode.OK
@@ -1847,11 +1825,7 @@ class XLAGangContext:
         if op == Operation.GATHER:
             root = lead.root_src
             stacked = wire_cast(_np_stack_op0(calls, [n] * size, ic))
-            out = np.asarray(
-                self._run_rooted(op, stacked, mesh, lead)
-                if mesh is not None
-                else stacked.reshape(-1)[None].repeat(size, 0)
-            )
+            out = np.asarray(self._run_rooted(op, stacked, mesh, lead))
             res = calls[root].res
             if res is not None and not res.is_dummy:
                 _write_host_result(res, out[root], size * n, ic)
@@ -1859,13 +1833,7 @@ class XLAGangContext:
 
         if op == Operation.ALLTOALL:
             stacked = wire_cast(_np_stack_op0(calls, [size * n] * size, ic))
-            out = np.asarray(
-                opdriver.run_alltoall(stacked, mesh)
-                if mesh is not None
-                else stacked.reshape(size, size, n).transpose(1, 0, 2).reshape(
-                    size, size * n
-                )
-            )
+            out = np.asarray(opdriver.run_alltoall(stacked, mesh))
             for r, call in enumerate(calls):
                 _write_host_result(call.res, out[r], size * n, ic)
             return ErrorCode.OK
@@ -1874,22 +1842,9 @@ class XLAGangContext:
 
     def _allreduce(self, stacked, mesh, fn, wire_dtype, prep=None,
                    tuning=None):
-        if mesh is None:
-            if wire_dtype is not None:
-                npdt = dtype_to_numpy(wire_dtype)
-                stacked = stacked.astype(npdt).astype(stacked.dtype)
-            return self._host_reduce(stacked, fn)[None].repeat(stacked.shape[0], 0)
         return run_allreduce_with_tuning(
             stacked, mesh, fn, wire_dtype,
             self.tuning if tuning is None else tuning, prep=prep,
-        )
-
-    @staticmethod
-    def _host_reduce(stacked: np.ndarray, fn: ReduceFunction) -> np.ndarray:
-        return (
-            stacked.sum(axis=0, dtype=stacked.dtype)
-            if fn == ReduceFunction.SUM
-            else stacked.max(axis=0)
         )
 
 

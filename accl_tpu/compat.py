@@ -1,65 +1,34 @@
-"""Cross-version jax compatibility shims.
+"""Probes of what the installed jax and platform actually do.
 
-The codebase targets current jax — ``jax.shard_map`` with the
-``check_vma`` switch, ``jax.lax.axis_size`` — but must keep running on
-older installations (0.4.x) where those names either do not exist or
-spell differently.  :func:`install` adds the missing public names once,
-adapting drifted keyword arguments.
+The repository targets one installation — jax/jaxlib 0.9.0 — and holds
+no branch for another.  What stays here are three facts that differ by
+PLATFORM, not by version, and that test suites skip on loudly (a reason
+string, never a silent numeric fudge):
 
-It is deliberately NOT invoked from ``accl_tpu/__init__``: importing the
-package must stay jax-free (the emulator/native tiers run in processes
-that never load jax — see ``ACCL.capabilities``'s platform note).
-Instead, every module that binds the shimmed symbols calls ``install()``
-right after its own ``import jax`` (and tests/conftest does the same
-before test modules import), so each jax-binding call site resolves to
-one consistent surface without the package import paying for it.
+* :func:`has_faithful_fp8_cast` — XLA's f32 -> fp8 cast rounds as
+  ``ml_dtypes`` does on this backend;
+* :func:`has_interpret_params` — the Pallas TPU interpreter runs here
+  (the CPU tier's way of executing the Mosaic kernels);
+* :func:`has_bitexact_replay` — a donated train-step output and a
+  ``device_put`` clone replay to identical bits.
 
-Shims are additive only: on a jax that already provides a name, install()
-leaves it untouched.
+Importing this module does not import jax: the emulator/native tiers run
+in processes that never load it.
 """
 
 from __future__ import annotations
-
-import inspect
-
-_installed = False
-
-
-def has_modern_vma() -> bool:
-    """True when this jax provides the varying-manual-axes machinery
-    (``lax.pvary``/``lax.pcast`` and the checked shard_map that places
-    gradient psums from vma tracking).  Features whose CORRECTNESS
-    depends on it — ZeRO's mixed replicated/sharded gradient placement,
-    the composed pipeline's transpose bookkeeping — cannot be shimmed:
-    on legacy jax the adapter runs shard_map unchecked, which silently
-    misplaces those transposes.  Their test modules skip on this flag
-    (a loud environment skip instead of minutes of wrong numerics)."""
-    import jax
-
-    return hasattr(jax.lax, "pvary") or hasattr(jax.lax, "pcast")
-
-
-def has_profiler_options() -> bool:
-    """True when this jax ships ``jax.profiler.ProfileOptions`` (the
-    knob object ``utils.profiling.trace`` feeds ``start_trace``).
-    Legacy jax (0.4.x) predates it — callers degrade to an optionless
-    trace capture instead of raising AttributeError."""
-    import jax
-
-    return hasattr(jax.profiler, "ProfileOptions")
-
 
 _fp8_cast_faithful: bool = None
 
 
 def has_faithful_fp8_cast() -> bool:
     """True when XLA's f32 -> float8_e4m3fn cast rounds identically to
-    ml_dtypes' numpy cast on this host.  Some jax/XLA versions round a
-    small fraction of values to the other neighboring representable
-    (observed: 1/512 on jaxlib 0.4.36 CPU), so a device-tier compressed
-    transfer cannot be checked bit-exactly against the ml_dtypes
-    reference — scenario suites gate their fp8 wire cases on this probe
-    (a loud skip with a reason string, never a silent numeric fudge)."""
+    ml_dtypes' numpy cast on this backend.  A backend may round a small
+    fraction of values to the other neighboring representable, and then
+    a device-tier compressed transfer cannot be checked bit-exactly
+    against the ml_dtypes reference — scenario suites gate their fp8
+    wire cases on this probe (a loud skip with a reason string, never a
+    silent numeric fudge)."""
     global _fp8_cast_faithful
     if _fp8_cast_faithful is not None:
         return _fp8_cast_faithful
@@ -78,172 +47,19 @@ def has_faithful_fp8_cast() -> bool:
     return _fp8_cast_faithful
 
 
-class KVNotFoundError(KeyError):
-    """The legacy KV adapter's key-absent signal: renders with the same
-    'no such key' vocabulary the dist engine's learned not-found
-    signature expects, so polling loops treat it as 'nothing posted
-    yet' and real transport failures keep raising loudly."""
-
-    def __init__(self, key: str):
-        super().__init__(f"NOT_FOUND: no such key: {key}")
-
-
-class _LegacyKVAdapter:
-    """jaxlib < 0.5 ``DistributedRuntimeClient`` adapter: provides the
-    modern KV surface (``key_value_try_get_bytes`` /
-    ``key_value_increment``) on top of the legacy one.
-
-    * try-get rides ``key_value_dir_get_bytes`` over the key's directory
-      (non-blocking, non-destructive) and raises :class:`KVNotFoundError`
-      when absent — the modern method's contract.
-    * increment is emulated with first-write-wins claim keys: the legacy
-      ``key_value_set`` refuses to overwrite an existing key, so
-      claiming ``<key>/<n>`` is atomic.  Within one process a local hint
-      keeps the scan O(1); a cold start resumes past surviving claims
-      (one directory list).  Claims older than a retained window are
-      deleted so a long stream cannot grow the service unboundedly.
-      Cross-process single-writer streams (the stream-port protocol's
-      shape) stay correct, concurrent writers serialize on the claim.
-    """
-
-    def __init__(self, client):
-        self._client = client
-        self._hints = {}
-
-    # passthroughs the dist engine also uses
-    def key_value_set_bytes(self, key: str, value: bytes) -> None:
-        self._client.key_value_set_bytes(key, value)
-
-    def key_value_delete(self, key: str) -> None:
-        self._client.key_value_delete(key)
-
-    def key_value_try_get_bytes(self, key: str) -> bytes:
-        # fast path: a ~zero-timeout blocking get probes ONE key per
-        # poll (O(1)); the directory scan below transfers every pending
-        # value per probe — quadratic traffic while a stream consumer
-        # is behind — so it is only the fallback for clients without
-        # the bytes getter
-        getter = getattr(
-            self._client, "blocking_key_value_get_bytes", None
-        )
-        if getter is not None:
-            try:
-                return getter(key, 1)  # timeout_in_ms
-            except Exception as e:
-                text = str(e).lower()
-                if any(
-                    sig in text
-                    for sig in ("not found", "no such",
-                                "does not exist", "not_found")
-                ):
-                    raise KVNotFoundError(key) from None
-                if not any(
-                    sig in text
-                    for sig in ("deadline", "timeout", "timed out")
-                ):
-                    raise
-                # a deadline on the ~zero-timeout probe is ambiguous: the
-                # key may EXIST on a slow coordinator — fall through to
-                # the directory scan, which distinguishes present from
-                # absent (and keeps real transport failures loud)
-        prefix = key.rsplit("/", 1)[0]
-        try:
-            entries = self._client.key_value_dir_get_bytes(prefix)
-        except Exception as e:
-            # directory absent renders as an error on some versions —
-            # that (and only that) is 'nothing posted yet' for a poller;
-            # transport/RPC failures must keep raising loudly
-            text = str(e).lower()
-            if any(
-                sig in text
-                for sig in ("not found", "no such", "does not exist",
-                            "not_found")
-            ):
-                raise KVNotFoundError(key) from None
-            raise
-        for k, v in entries or ():
-            # dir-get may return keys relative to the directory or fully
-            # qualified, depending on the jaxlib vintage
-            if k == key or key.endswith("/" + k) or k.endswith(key):
-                return v
-        raise KVNotFoundError(key)
-
-    #: retained claim-key window: old claims beyond this are deleted so
-    #: a long stream cannot grow the coordination service unboundedly
-    _CLAIM_WINDOW = 64
-
-    def key_value_increment(self, key: str, n: int = 1) -> int:
-        if n != 1:  # the stream protocol only ever takes the next slot
-            raise ValueError("legacy KV increment supports n=1 only")
-        seq = self._hints.get(key, 0)
-        if seq == 0:
-            # cold start (fresh process): resume past any surviving
-            # claims instead of linearly colliding up from 1 — also what
-            # keeps the claim-window cleanup below restart-safe
-            try:
-                entries = self._client.key_value_dir_get_bytes(
-                    f"{key}/claim"
-                )
-            except Exception:
-                entries = ()
-            for k, _ in entries or ():
-                try:
-                    seq = max(seq, int(str(k).rsplit("/", 1)[-1]))
-                except ValueError:
-                    pass
-        while True:
-            seq += 1
-            try:
-                self._client.key_value_set(f"{key}/claim/{seq}", "1")
-            except Exception as e:
-                if "exist" in str(e).lower():
-                    continue  # another writer claimed it: take the next
-                raise
-            self._hints[key] = seq
-            if seq > self._CLAIM_WINDOW:
-                # bound the claim trail: drop the claim that just left
-                # the retained window (best-effort; a failed delete only
-                # leaves one extra key)
-                try:
-                    self._client.key_value_delete(
-                        f"{key}/claim/{seq - self._CLAIM_WINDOW}"
-                    )
-                except Exception:
-                    pass
-            return seq
-
-
-def kv_client(client):
-    """The modern KV surface over whatever ``DistributedRuntimeClient``
-    this jaxlib provides: the client itself when it already has try-get
-    + increment, else a :class:`_LegacyKVAdapter` around it."""
-    if hasattr(client, "key_value_try_get_bytes") and hasattr(
-        client, "key_value_increment"
-    ):
-        return client
-    return _LegacyKVAdapter(client)
-
-
 _interpret_probe = None
 
 
 def _probe_interpret_params():
-    """(ok, reason) for the Pallas TPU interpreter on this host: the
-    attribute must exist AND a trivial kernel must actually execute
-    under it — some environments ship the name but fail at run time, so
-    presence alone is not evidence."""
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-    except Exception as e:  # pragma: no cover - pallas absent entirely
-        return False, f"pallas unavailable: {type(e).__name__}: {e}"
-    if not hasattr(pltpu, "InterpretParams"):
-        return False, (
-            f"jax {jax.__version__} has no pltpu.InterpretParams "
-            "(TPU interpreter): Pallas kernels only run on real TPU here"
-        )
+    """(ok, reason) for the Pallas TPU interpreter on this host: a
+    trivial kernel must actually execute under it — some environments
+    ship the name but fail at run time, so presence alone is not
+    evidence."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
     try:
         def k(x_ref, o_ref):
             o_ref[:] = x_ref[:]
@@ -370,58 +186,3 @@ def bitexact_replay_reason() -> str:
     if _replay_probe is None:
         _replay_probe = _probe_bitexact_replay()
     return _replay_probe[1]
-
-
-def has_pallas_interpret() -> bool:
-    """True when jax ships the Pallas TPU interpreter
-    (``pltpu.InterpretParams``) that lets the Mosaic kernels run
-    off-chip.  Without it (legacy jax), the Pallas kernel suites and the
-    ``pallas_ring`` tuning lowerings can only run on a real TPU — their
-    tests skip on this flag off-chip instead of failing on the missing
-    attribute."""
-    try:
-        import jax.experimental.pallas.tpu as pltpu
-    except Exception:  # pragma: no cover - pallas absent entirely
-        return False
-    return hasattr(pltpu, "InterpretParams")
-
-
-def install() -> None:
-    global _installed
-    if _installed:
-        return
-    _installed = True
-    import jax
-    from jax import lax
-
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _legacy
-
-        params = set(inspect.signature(_legacy).parameters)
-
-        def shard_map(f=None, **kwargs):
-            # Adapt modern kwargs onto the legacy signature.  check_vma
-            # nominally maps onto the old replication checker's switch,
-            # but that checker predates these programs and rejects valid
-            # out_specs ("requires replication which can't be statically
-            # inferred"), so on legacy jax it is disabled outright; the
-            # modern varying-manual-axes checker runs wherever the real
-            # jax.shard_map exists.
-            kwargs.pop("check_vma", None)
-            if "check_rep" in params:
-                kwargs.setdefault("check_rep", False)
-            if f is None:  # partial-application (decorator) form
-                return lambda fn: _legacy(fn, **kwargs)
-            return _legacy(f, **kwargs)
-
-        jax.shard_map = shard_map
-
-    if not hasattr(lax, "axis_size"):
-
-        def axis_size(axis_name):
-            # psum of the unit constant folds to the STATIC mapped-axis
-            # size (a Python int at trace time) on every jax that lacks
-            # lax.axis_size — callers can keep using it in shape math
-            return lax.psum(1, axis_name)
-
-        lax.axis_size = axis_size

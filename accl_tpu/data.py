@@ -57,11 +57,9 @@ def write_token_file(path: str, tokens: np.ndarray) -> None:
 
 
 def _load_lib():
-    from .native import _DATALOADER_SO_PATH, _try_build
+    from .native import _DATALOADER_SO_PATH, build
 
-    if not _DATALOADER_SO_PATH.exists():
-        _try_build()
-    if not _DATALOADER_SO_PATH.exists():
+    if not build():
         raise RuntimeError(
             "libaccl_dataloader.so unavailable (no C++ toolchain?); "
             "run `make -C native`"

@@ -410,6 +410,9 @@ def main(argv=None) -> int:
         help="parameter/activation dtype",
     )
     args = ap.parse_args(argv)
+    from ..utils import use_compile_cache
+
+    use_compile_cache()
     train(
         steps=args.steps, ckpt_dir=args.ckpt_dir,
         save_every=args.save_every, tp=args.tp, seed=args.seed,

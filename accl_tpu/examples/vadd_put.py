@@ -16,10 +16,6 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 
 from ..backends.base import CallOptions
@@ -85,10 +81,7 @@ def vadd_put_pallas(stacked, mesh, increment: float = 1.0, distance: int = 1):
     neighbor ``distance`` away, host and XLA collective scheduler both out
     of the data path.  ``stacked[r]`` is rank r's operand; returns stacked
     results (row r = what rank r received)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..ops.driver import AXIS

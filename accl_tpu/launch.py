@@ -18,6 +18,7 @@ from synthetic local addresses (ref generate_ranks' synthetic subnets).
 
 from __future__ import annotations
 
+import glob
 import multiprocessing as mp
 import os
 import pickle
@@ -29,30 +30,16 @@ from typing import Callable, List, Optional
 def _worker(fn_spec, rank, world, base_port, design_name, conn):
     try:
         # persistent XLA compilation cache, shared across rank processes
-        # and across runs (same knob bench.py uses): the jax-backed dist
-        # tier compiles one program per (op, wire-bucket, comm) and a
-        # cold cache pays that once per PROCESS per RUN otherwise.  Only
-        # for jax-backed designs — the emulator/socket/native tiers are
-        # numpy/C++ and keep their jax import lazy (an unconditional
-        # import would tax every spawned rank ~1 s for nothing).  Opt
-        # out with ACCL_COMPILE_CACHE="".
-        cache_dir = os.environ.get(
-            "ACCL_COMPILE_CACHE",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                ".jax_cache",
-            ),
-        )
-        if cache_dir and design_name.startswith("xla"):
-            try:
-                import jax
+        # and across runs: the jax-backed dist tier compiles one program
+        # per (op, wire-bucket, comm) and a cold cache pays that once per
+        # PROCESS per RUN otherwise.  Only for jax-backed designs — the
+        # emulator/socket/native tiers are numpy/C++ and keep their jax
+        # import lazy (an unconditional import would tax every spawned
+        # rank ~1 s for nothing).
+        if design_name.startswith("xla"):
+            from .utils.platform import use_compile_cache
 
-                jax.config.update("jax_compilation_cache_dir", cache_dir)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.5
-                )
-            except Exception:
-                pass  # older jax without the knobs
+            use_compile_cache()
         if isinstance(fn_spec, tuple):  # (script_path, fn_name) from the CLI
             import importlib.util
 
@@ -79,6 +66,17 @@ def _worker(fn_spec, rank, world, base_port, design_name, conn):
         conn.send(("error", traceback.format_exc()))
 
 
+def _ranks_would_open_tpu() -> bool:
+    """Whether a spawned jax rank process would initialize the TPU
+    backend: ``JAX_PLATFORMS`` names it, or names nothing on a host
+    that has the chips' device nodes.  Decided without importing jax —
+    the launcher parent must never hold a chip itself."""
+    platforms = os.environ.get("JAX_PLATFORMS", "").lower()
+    if platforms:
+        return "tpu" in platforms
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 def launch_processes(
     fn: Callable,
     world: int,
@@ -89,10 +87,26 @@ def launch_processes(
     """Run ``fn(accl, rank, world)`` in ``world`` separate OS processes over
     a per-rank TCP fabric; returns per-rank results, raises on any failure.
 
-    ``design`` selects the engine tier: "socket" (Python emulator) or
-    "native_socket" (C++ engine).  ``fn`` is either a picklable module-level
-    function or a ``(script_path, fn_name)`` tuple loaded fresh in each
-    worker."""
+    ``design`` selects the engine tier: "socket" (Python emulator),
+    "native_socket" (C++ engine) or "xla_dist" (one jax process a rank
+    over ``jax.distributed`` — the CPU/gloo tier; refused on a TPU host,
+    where each rank would open every chip).  ``fn`` is either a
+    picklable module-level function or a ``(script_path, fn_name)`` tuple
+    loaded fresh in each worker."""
+    if design == "xla_dist" and _ranks_would_open_tpu():
+        # every rank calls jax.distributed.initialize with no device
+        # selection and opens EVERY local chip, and a chip belongs to one
+        # process (chip run, PR 21: rank 1 dies at start-up with
+        # "ABORTED: Internal error when accessing libtpu multi-process
+        # lockfile", rank 0 then waits out the launcher's timeout)
+        raise RuntimeError(
+            "design='xla_dist' cannot run on a TPU host: each rank "
+            "process would open every local chip, and a chip belongs to "
+            "one process.  Drive all chips from ONE process "
+            "(accl_tpu.core.xla_group), or hold the ranks to the CPU/gloo "
+            "tier with JAX_PLATFORMS=cpu — the dist tier is exercised "
+            "there only."
+        )
     ctx = mp.get_context("spawn")
     payload = fn if isinstance(fn, tuple) else pickle.dumps(fn)
     procs = []

@@ -35,19 +35,12 @@ from functools import partial
 from typing import Dict
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax import shard_map
 
 from .pipeline import pipeline_apply, pipeline_apply_interleaved
 from .transformer import (

@@ -18,10 +18,6 @@ Switch-Transformer formulation.
 from __future__ import annotations
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax import lax
 

@@ -19,10 +19,6 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax import lax
 
@@ -31,10 +27,8 @@ def _pvary(x, axis_name):
     """Mark ``x`` varying over ``axis_name`` for shard_map's replication
     checker (loop carries initialized from constants are invariant, but
     the loop body makes them varying — the types must match up front).
-    No-op data-wise; compat across jax pvary/pcast spellings."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    return lax.pvary(x, (axis_name,))  # pragma: no cover - older jax
+    No-op data-wise."""
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def pipeline_apply(

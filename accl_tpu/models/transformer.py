@@ -25,17 +25,10 @@ from functools import partial
 from typing import Dict, Optional
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax import shard_map
 
 from ..constants import ReduceFunction
 from ..ops import collectives
@@ -506,32 +499,40 @@ def _auto_flash_fits(q) -> bool:
 
     if q.dtype == jnp.float16:
         # Mosaic's TPU lowering rejects f16 matmul operands (ValueError
-        # at compile, observed as a session abort on the chip tier), so
-        # auto must never route f16 into the flash kernel — it falls
-        # through to the XLA blockwise fold instead.  Explicit
+        # at compile), so auto must never route f16 into the flash
+        # kernel — it falls through to the XLA blockwise fold instead.
+        # Explicit
         # attention="flash" still surfaces the kernel's own f16 error.
         return False
     Dp = -(-q.shape[-1] // 128) * 128  # lane-padded head dim
     return 2 * q.shape[2] * Dp * q.dtype.itemsize <= _AUTO_FLASH_KV_BYTES
 
 
+def resolve_attention(impl: str, q) -> str:
+    """The lowering ``impl`` names for a per-device ``(B, H, T, hd)``
+    query (anything with ``shape`` and ``dtype``).  ``"auto"`` resolves
+    by sequence length and backend: naive under ``_AUTO_FUSED_MIN_T``;
+    at/above it the Pallas flash kernel on TPU while its K/V tiles fit
+    VMEM (:func:`_auto_flash_fits`), and the XLA blockwise fold off TPU
+    or past that gate.  Callers that must know which one ran (the chip
+    smoke) ask here instead of guessing."""
+    if impl != "auto":
+        return impl
+    if q.shape[2] < _AUTO_FUSED_MIN_T:
+        return "naive"
+    if jax.default_backend() == "tpu" and _auto_flash_fits(q):
+        return "flash"  # Mosaic-compiled; trainable via custom_vjp
+    return "blockwise"
+
+
 def _attention(q, k, v, impl: str = "naive", causal: bool = True):
     """Attention; q,k,v: (B, H, T, hd); ``causal=False`` is the
     bidirectional (encoder) form.
 
-    ``impl="auto"`` resolves by sequence length and backend (naive under
-    ``_AUTO_FUSED_MIN_T``; at/above it the Pallas flash kernel on TPU
-    while its K/V tiles fit VMEM — :func:`_auto_flash_fits` — and the
-    XLA blockwise fold elsewhere); ``"blockwise"`` runs the fused
-    online-softmax fold (no (T, T) score matrix in HBM); ``"naive"`` is
-    the materialized-scores baseline."""
-    if impl == "auto":
-        if q.shape[2] < _AUTO_FUSED_MIN_T:
-            impl = "naive"
-        elif jax.default_backend() == "tpu" and _auto_flash_fits(q):
-            impl = "flash"  # Mosaic-compiled; trainable via custom_vjp
-        else:
-            impl = "blockwise"
+    ``impl="auto"`` resolves through :func:`resolve_attention`;
+    ``"blockwise"`` runs the fused online-softmax fold (no (T, T) score
+    matrix in HBM); ``"naive"`` is the materialized-scores baseline."""
+    impl = resolve_attention(impl, q)
     if impl == "blockwise":
         from ..ops.attention import blockwise_attention
 
@@ -1269,13 +1270,8 @@ def _reshard(x, mesh, spec):
     axis modes: explicit axes take :func:`jax.sharding.reshard`,
     auto axes take ``with_sharding_constraint``."""
     s = NamedSharding(mesh, spec)
-    try:
-        from jax.sharding import AxisType
-
-        if AxisType.Explicit in mesh.axis_types:
-            return jax.sharding.reshard(x, s)
-    except ImportError:  # pragma: no cover - older jax: auto-only meshes
-        pass
+    if jax.sharding.AxisType.Explicit in mesh.axis_types:
+        return jax.sharding.reshard(x, s)
     return jax.lax.with_sharding_constraint(x, s)
 
 

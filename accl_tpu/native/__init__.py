@@ -4,16 +4,21 @@ The reference implements its dataplane in native code (HLS C++ reduce_ops /
 hp_compression kernels, C firmware); our equivalent hot paths live in
 ``native/src/dataplane.cpp`` (built into ``libaccl_dataplane.so`` by
 ``native/Makefile``) and are loaded here via ctypes, with numpy fallbacks in
-``backends/emulator/dataplane.py`` when the library is unavailable.  If the
-shared library is missing but a C++ toolchain exists, it is built on first
-import (best-effort, silent fallback).
+``backends/emulator/dataplane.py`` when the library is unavailable.  Every
+process that wants a library first runs ``make -C native`` (:func:`build`)
+and lets make's timestamps decide: ``native/build/`` is not under git, so a
+library found there may be older than the sources beside it, or built on
+another machine.  No toolchain means the numpy path; a build that FAILS says
+so in a warning and also means the numpy path, never a stale library.
 """
 
 from __future__ import annotations
 
 import ctypes
 import pathlib
+import shutil
 import subprocess
+import warnings
 
 import numpy as np
 
@@ -28,28 +33,41 @@ _ENGINE_SO_PATH = _NATIVE_DIR / "build" / "libaccl_engine.so"
 _DATALOADER_SO_PATH = _NATIVE_DIR / "build" / "libaccl_dataloader.so"
 
 
-def _try_build() -> None:
-    """Best-effort make, serialized across processes with a file lock so N
-    spawn-launched ranks don't race on the same output file."""
-    try:
-        import fcntl
+_BUILD_OK = None  # None until build() ran in this process
 
-        _NATIVE_DIR.mkdir(exist_ok=True)
-        with open(_NATIVE_DIR / ".build.lock", "w") as lock:
-            fcntl.flock(lock, fcntl.LOCK_EX)
-            if (
-                not _SO_PATH.exists()
-                or not _ENGINE_SO_PATH.exists()
-                or not _DATALOADER_SO_PATH.exists()
-            ):
-                subprocess.run(
-                    ["make", "-C", str(_NATIVE_DIR)],
-                    capture_output=True,
-                    timeout=120,
-                    check=True,
-                )
-    except Exception:
-        pass
+
+def build() -> bool:
+    """``make -C native`` once a process, serialized across processes with
+    a file lock so N spawn-launched ranks don't race on the same output
+    file.  True when make left the libraries up to date."""
+    global _BUILD_OK
+    if _BUILD_OK is not None:
+        return _BUILD_OK
+    _BUILD_OK = False
+    if shutil.which("make") is None or not _NATIVE_DIR.is_dir():
+        return False  # no toolchain: the documented numpy path
+    import fcntl
+
+    with open(_NATIVE_DIR / ".build.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        try:
+            proc = subprocess.run(
+                ["make", "-C", str(_NATIVE_DIR)],
+                capture_output=True, text=True, timeout=300,
+            )
+        except subprocess.TimeoutExpired:
+            warnings.warn("native build timed out: `make -C native`",
+                          RuntimeWarning)
+            return False
+    if proc.returncode != 0:
+        warnings.warn(
+            "native build failed (`make -C native`), using the numpy "
+            "path:\n" + proc.stderr[-2000:],
+            RuntimeWarning,
+        )
+        return False
+    _BUILD_OK = True
+    return True
 
 
 def _bind(lib):
@@ -87,28 +105,12 @@ def _load():
     if _LOAD_ATTEMPTED:
         return _LIB
     _LOAD_ATTEMPTED = True
-    if not _SO_PATH.exists():
-        _try_build()
-    rebuilt = False
-    while True:
-        if not _SO_PATH.exists():
-            return None
-        try:
-            lib = ctypes.CDLL(str(_SO_PATH))
-            _bind(lib)
-            _LIB = lib
-            return _LIB
-        except (OSError, AttributeError):
-            # stale library from older sources: rebuild once, then give up
-            # to the numpy fallback
-            if rebuilt:
-                return None
-            rebuilt = True
-            try:
-                _SO_PATH.unlink()
-            except OSError:
-                return None
-            _try_build()
+    if not build():
+        return None
+    lib = ctypes.CDLL(str(_SO_PATH))
+    _bind(lib)
+    _LIB = lib
+    return _LIB
 
 
 # dtype codes shared with native/src/dataplane.cpp

@@ -16,10 +16,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax import lax
 
@@ -124,9 +120,11 @@ def allgather(
     return lax.all_gather(x, axis_name, tiled=tiled, axis=axis)
 
 
-try:  # Varying -> Invariant allgather (not yet re-exported publicly)
+try:  # Varying -> Invariant allgather: a PRIVATE symbol (jax 0.9.0 does
+    # not re-export it), so a later jax may move it — the tested fallback
+    # below keeps the semantics if it does
     from jax._src.lax.parallel import all_gather_invariant as _ag_invariant
-except ImportError:  # pragma: no cover - older jax
+except ImportError:  # pragma: no cover - private symbol moved
     _ag_invariant = None
 
 
@@ -136,8 +134,8 @@ def allgather_invariant(
     """Allgather whose output shard_map's replication checker accepts as
     axis-invariant — required whenever the gathered value flows to a
     replicated (``P(None)``) output.  Falls back to a psum of scattered
-    slices (provably invariant, 2x the wire bytes) on jax versions
-    without ``all_gather_invariant``."""
+    slices (provably invariant, 2x the wire bytes) should jax's private
+    ``all_gather_invariant`` move."""
     if _ag_invariant is not None:
         return _ag_invariant(x, axis_name, axis=axis, tiled=tiled)
     return _allgather_invariant_fallback(x, axis_name, axis=axis, tiled=tiled)
@@ -146,18 +144,10 @@ def allgather_invariant(
 def _allgather_invariant_fallback(
     x: jax.Array, axis_name: str, axis: int = 0, tiled: bool = True
 ) -> jax.Array:
-    """Psum-of-scattered-slices allgather: provably axis-invariant on any
-    jax, at 2x the wire bytes.  Kept directly testable (tests force
+    """Psum-of-scattered-slices allgather: provably axis-invariant, at
+    2x the wire bytes.  Kept directly testable (tests force
     ``_ag_invariant=None``) so a jax upgrade that drops the private op
     cannot silently change semantics."""
-    # The assembly needs the STATIC axis size for its shapes; a jax old
-    # enough to lack both the private op and lax.axis_size gets a clear
-    # error instead of a trace-time mystery.
-    if not hasattr(lax, "axis_size"):
-        raise RuntimeError(
-            "allgather_invariant needs jax with lax.axis_size or "
-            "all_gather_invariant"
-        )
     size = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     block = x.shape[axis]

@@ -13,17 +13,10 @@ from functools import lru_cache
 from typing import Optional
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax import shard_map
 
 from ..constants import ReduceFunction
 from . import collectives, pallas, ring

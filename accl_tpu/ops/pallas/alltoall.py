@@ -16,10 +16,6 @@ attention, ``models.ulysses_attention``).
 from __future__ import annotations
 
 import jax
-
-from ...compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import numpy as np
 import jax.numpy as jnp
 from jax import lax

@@ -60,10 +60,6 @@ from typing import Optional, Sequence
 import numpy as np
 
 import jax
-
-from ...compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import io_callback
@@ -782,14 +778,9 @@ def _pallas_windows(slots, xs, axis_name, size, nwin, depth,
 
 
 def _compiler_params():
-    """CompilerParams across jax vintages: modern ``CompilerParams``
-    (has_side_effects) when present, else the legacy
-    ``TPUCompilerParams`` surface (collective id 5 — the module
-    namespace holds 0=ring, 1=put, 2=attention, 3=alltoall, 4=int8
-    scale leg, 5=this sequencer)."""
-    if hasattr(pltpu, "CompilerParams"):
-        return pltpu.CompilerParams(has_side_effects=True, collective_id=5)
-    return pltpu.TPUCompilerParams(collective_id=5)  # pragma: no cover
+    """Collective id 5: the module namespace holds 0=ring, 1=put,
+    2=attention, 3=alltoall, 4=int8 scale leg, 5=this sequencer."""
+    return pltpu.CompilerParams(has_side_effects=True, collective_id=5)
 
 
 @lru_cache(maxsize=128)

@@ -22,10 +22,6 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-
-from ...compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl

@@ -25,10 +25,6 @@ detector enabled.
 from __future__ import annotations
 
 import jax
-
-from ...compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
@@ -310,8 +306,8 @@ def _allgather_kernel(axis_name, size, num_segments):
 
 def _call(kernel, x, out_rows, scratch, collective_id, interpret):
     interp = default_interpret(interpret)
-    # no XLA reroute here: these are remote-DMA kernels, not math — an
-    # abort-the-session compile failure becomes a usable error
+    # no XLA reroute here: these are remote-DMA kernels, not math — the
+    # compiler's f16 rejection becomes a usable error up front
     require_mosaic_dtypes(interp, "ring collective", x.dtype)
     return pl.pallas_call(
         kernel,

@@ -24,10 +24,6 @@ interpreter like the rest of the kernel tier.
 from __future__ import annotations
 
 import jax
-
-from ...compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl

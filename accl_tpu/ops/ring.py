@@ -19,10 +19,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 from jax import lax
 

@@ -154,9 +154,17 @@ def wire_lane_roundtrip(x, wire_dtype, seed=0):
         return dequantize_int8(
             q, scales, int(x.size), out_dtype=orig
         ).reshape(shape)
+    # The barrier keeps the narrow value a value: XLA on a TPU removes a
+    # narrow -> wide convert pair it can see through (its "excess
+    # precision" licence), and then the lane rounds nothing — measured on
+    # the v5e (chip run, PR 21): max |roundtrip(x) - x| was 0.0 for the
+    # fp8, bf16 and f16 lanes inside one program, 0.249 / 0.0153 / 0.0019
+    # with encode and decode in programs apart (and on the CPU).
     if dropped_mantissa_bits(dt) is not None:
-        return _cast_lane(x, wire_np, seed).astype(orig)
-    return x.astype(wire_np).astype(orig)
+        narrow = _cast_lane(x, wire_np, seed)
+    else:
+        narrow = x.astype(wire_np)
+    return lax.optimization_barrier(narrow).astype(orig)
 
 
 #: lane-kind table for the registered wire dtypes (numpy-name keyed):

@@ -27,19 +27,12 @@ from functools import partial
 from typing import Dict, NamedTuple, Optional
 
 import jax
-
-from ..compat import install as _compat_install
-
-_compat_install()  # legacy-jax shims (shard_map kwargs, lax.axis_size)
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax import shard_map
 
 from ..ops.collectives import allgather_invariant
 
@@ -495,10 +488,7 @@ def make_zero_train_step(
             # pvary'd primals keep each microbatch's gradient dp-LOCAL,
             # so the whole step pays ONE gradient psum after the scan
             # (accum_steps x less cross-dp wire, identical math)
-            try:
-                _pvary = partial(lax.pcast, to="varying")
-            except AttributeError:  # pragma: no cover - older jax
-                _pvary = lax.pvary
+            _pvary = partial(lax.pcast, to="varying")
             is_p_ = lambda x: isinstance(x, P)
             pl_, pd_ = jax.tree.flatten(params)
             sl_ = jax.tree.leaves(specs, is_leaf=is_p_)

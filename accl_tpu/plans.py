@@ -5,9 +5,9 @@ registers (``ccl_offload_control.h:86-90``, written by
 ``driver/xrt/src/accl.cpp:1198-1208``) and re-reads them per call inside
 the firmware main loop.  Our facade used to re-derive the full call plan
 in Python on every collective — arithmetic-config resolution, wire dtype,
-eager-vs-rendezvous verdict, algorithm selection, host flags — ~271 us of
-pure control plane per call (BENCH_NOTES "Single-interaction dispatch"
-table).  A :class:`CollectivePlan` snapshots all of it once per
+eager-vs-rendezvous verdict, algorithm selection, host flags — pure
+control plane on every call.  A :class:`CollectivePlan` snapshots all of
+it once per
 ``(op, communicator id+epoch, dtype, size bucket, options fingerprint)``
 so a warm collective goes pool-lookup -> dispatch.
 

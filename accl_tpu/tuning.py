@@ -795,9 +795,13 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.backend == "xla":
-        from .utils import mirror_platform_env
+        import jax
 
-        mirror_platform_env(args.platform)
+        from .utils import use_compile_cache
+
+        if args.platform:
+            jax.config.update("jax_platforms", args.platform)
+        use_compile_cache()
 
     from . import core
 
@@ -881,7 +885,7 @@ def main(argv=None) -> int:
             w.writeheader()
             # same writer-side refusal as benchmarks/sweep.py write_row:
             # a sentinel/garbage duration must be an ERROR here, not a
-            # committed chip artifact (chip_session.sh autotune leg)
+            # committed artifact
             ceiling = float(
                 os.environ.get("ACCL_SWEEP_GBPS_CEILING", "10000")
             )

@@ -1,6 +1,10 @@
 from .timing import Timer  # noqa: F401
 from .logging import Log, LogLevel  # noqa: F401
-from .platform import mirror_platform_env  # noqa: F401
+from .platform import (  # noqa: F401
+    DEVICE_PEAKS,
+    device_peaks,
+    use_compile_cache,
+)
 from .profiling import (  # noqa: F401
     annotate,
     device_memory_profile,

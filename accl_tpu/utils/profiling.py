@@ -70,20 +70,11 @@ def device_scope(name: str):
 @contextlib.contextmanager
 def trace(logdir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
     """Capture a profiler trace of everything inside the block into
-    ``logdir`` (xprof format; load with tensorboard or xprof).
-
-    ``ProfileOptions`` is a recent jax addition — legacy installs
-    degrade to an optionless capture (default host tracer level) instead
-    of raising, gated by ``compat.has_profiler_options``."""
-    from ..compat import has_profiler_options
-
+    ``logdir`` (xprof format; load with tensorboard or xprof)."""
     jax = _jax()
-    if has_profiler_options():
-        options = jax.profiler.ProfileOptions()
-        options.host_tracer_level = host_tracer_level
-        jax.profiler.start_trace(logdir, profiler_options=options)
-    else:
-        jax.profiler.start_trace(logdir)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         yield
     finally:

@@ -44,26 +44,21 @@ SANE_GBPS_CEILING = float(os.environ.get("ACCL_SWEEP_GBPS_CEILING", "10000"))
 class ImpossibleRateError(RuntimeError):
     """A computed rate exceeded the sanity ceiling: the duration under it
     is garbage (sentinel, clock bug), and writing it would poison the
-    committed artifact chain (CSV -> parse_results -> BENCH_NOTES)."""
+    committed artifact chain (CSV -> parse_results -> summary tables)."""
 
 
-# The second writer-side gate: facade_arch_overhead_us regressions.
-# Defined next to the parser (stdlib-only, no jax) and re-exported here
-# so both artifact writers carry the same refusal surface; bench.py
-# invokes it on every fresh capture before the LKG stash.  The tuned
+# The capture gates are defined next to the parser (stdlib-only, no jax)
+# and re-exported here so both artifact writers carry the same refusal
+# surface; bench.py invokes them on every capture.  The tuned
 # not-slower gate rides along for the --tuning-plan sweeps.
 try:
     from parse_results import (  # running as a script: sibling import
-        ARCH_REGRESSION_TOLERANCE,
-        ArchOverheadRegressionError,
         CmdringGateError,
         CompressionGateError,
-        OVERLAP_REGRESSION_TOLERANCE,
         OverlapGateError,
         TelemetryGateError,
         TunedPlanRegressionError,
         VerifyGateError,
-        check_arch_overhead,
         check_cmdring,
         check_compression,
         check_overlap,
@@ -73,16 +68,12 @@ try:
     )
 except ImportError:  # pragma: no cover - running as a package module
     from benchmarks.parse_results import (  # noqa: F401
-        ARCH_REGRESSION_TOLERANCE,
-        ArchOverheadRegressionError,
         CmdringGateError,
         CompressionGateError,
-        OVERLAP_REGRESSION_TOLERANCE,
         OverlapGateError,
         TelemetryGateError,
         TunedPlanRegressionError,
         VerifyGateError,
-        check_arch_overhead,
         check_cmdring,
         check_compression,
         check_overlap,
@@ -321,8 +312,7 @@ def sweep_dist(world: int, sizes: List[int], collectives: List[str],
 
 def sweep_ops(world: int, sizes: List[int], writer, extra_algos=()) -> None:
     """Sweep the pure shard_map ops layer over the device mesh (wall-clock
-    around the jitted program; slope-corrected like bench.py would need on
-    tunneled backends is overkill here — this path is for CPU/TPU local)."""
+    around the jitted program, not slope-corrected like bench.py)."""
     import jax.numpy as jnp
 
     from accl_tpu.ops import driver as opdriver
@@ -407,8 +397,7 @@ def main(argv=None) -> int:
     ap.add_argument("--collectives", nargs="*", default=COLLECTIVES)
     ap.add_argument(
         "--platform", default=None,
-        help="force a jax platform (e.g. 'cpu'); needed where a site PJRT "
-             "plugin overrides the JAX_PLATFORMS env var",
+        help="force a jax platform (e.g. 'cpu') before device discovery",
     )
     ap.add_argument(
         "--extra-algos", nargs="*", default=[],
@@ -446,11 +435,14 @@ def main(argv=None) -> int:
     )
     args = ap.parse_args(argv)
 
-    from accl_tpu.utils import mirror_platform_env
+    if args.backend in ("ops", "xla"):  # the in-process jax tiers
+        import jax
 
-    # the CONFIG path, before any jax.devices(): env alone doesn't stop
-    # site PJRT hooks from initializing their own platform
-    mirror_platform_env(args.platform)
+        from accl_tpu.utils import use_compile_cache
+
+        if args.platform:
+            jax.config.update("jax_platforms", args.platform)
+        use_compile_cache()
 
     sizes = [2**e for e in range(args.min_exp, args.max_exp + 1)]
     out = sys.stdout if args.csv == "-" else open(args.csv, "w", newline="")

@@ -192,10 +192,7 @@ def test_hybrid_mesh_runs_two_level_collective():
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from accl_tpu.parallel import hybrid_mesh
 
@@ -273,7 +270,7 @@ def test_capabilities_report(group2):
 
 def test_parse_results_regenerates_sweep_tables(capsys):
     """benchmarks/parse_results.py (the parse_bench_results.py analog)
-    folds the committed sweep CSVs into the BENCH_NOTES tables — the
+    folds the committed sweep CSVs into summary tables — the
     quoted 8-rank allreduce numbers must come back out of the CSVs."""
     mod = _load_bench_module("parse_results")
     doc = mod.main([])
@@ -365,7 +362,7 @@ def test_sweep_writer_refuses_impossible_rate():
 
 def test_parse_results_refuses_poisoned_csv(tmp_path):
     """The parser is the second gate: a poisoned committed CSV errors
-    out instead of summarizing/plotting 16.7 Pb/s into BENCH_NOTES."""
+    out instead of summarizing/plotting 16.7 Pb/s into a table."""
     mod = _load_bench_module("parse_results")
     bad = tmp_path / "sweep_bad.csv"
     bad.write_text(

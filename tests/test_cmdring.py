@@ -456,48 +456,38 @@ def _evidence(**over):
 
 
 def test_check_cmdring_passes_good_capture():
-    _gate().check_cmdring(_evidence(), {})
+    _gate().check_cmdring(_evidence())
 
 
 def test_check_cmdring_noop_when_bench_never_ran():
-    _gate().check_cmdring({}, {})
+    _gate().check_cmdring({})
 
 
 def test_check_cmdring_refuses_floor_without_evidence():
     mod = _gate()
     with pytest.raises(mod.CmdringGateError):
         mod.check_cmdring(
-            {"gang_cmdring_dispatch_floor_us": 40.0}, {}
-        )
+            {"gang_cmdring_dispatch_floor_us": 40.0})
 
 
 def test_check_cmdring_refuses_unamortized_refills():
     mod = _gate()
     with pytest.raises(mod.CmdringGateError):
         mod.check_cmdring(
-            _evidence(gang_cmdring_refills_per_call=1.0), {}
-        )
+            _evidence(gang_cmdring_refills_per_call=1.0))
 
 
 def test_check_cmdring_refuses_ring_not_engaging():
     mod = _gate()
     with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(_evidence(gang_cmdring_ring_slots=0), {})
+        mod.check_cmdring(_evidence(gang_cmdring_ring_slots=0))
 
 
 def test_check_cmdring_requires_ring_below_host_floor():
     mod = _gate()
     with pytest.raises(mod.CmdringGateError):
         mod.check_cmdring(
-            _evidence(gang_cmdring_dispatch_floor_us=250.0), {}
-        )
-
-
-def test_check_cmdring_refuses_lkg_regression():
-    mod = _gate()
-    lkg = {"extras": _evidence(gang_cmdring_dispatch_floor_us=10.0)}
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(_evidence(), {"extras": lkg["extras"]})
+            _evidence(gang_cmdring_dispatch_floor_us=250.0))
 
 
 def test_committed_cpu_capture_passes_gate():
@@ -511,7 +501,7 @@ def test_committed_cpu_capture_passes_gate():
     )
     with open(path) as f:
         doc = json.load(f)
-    mod.check_cmdring(doc["cmdring"], {})
+    mod.check_cmdring(doc["cmdring"])
     assert doc["cmdring"]["gang_cmdring_refills_per_call"] < 1.0
     # the committed capture carries the persistence evidence: the
     # sustained stream's redispatch amortization and the per-opcode
@@ -584,15 +574,14 @@ def test_check_cmdring_refuses_partial_evidence_any_side():
     ):
         partial = {k: v for k, v in ev.items() if k != missing}
         with pytest.raises(mod.CmdringGateError):
-            mod.check_cmdring(partial, {})
+            mod.check_cmdring(partial)
 
 
 def test_check_cmdring_refuses_unamortized_redispatch():
     mod = _gate()
     with pytest.raises(mod.CmdringGateError):
         mod.check_cmdring(
-            _evidence(gang_cmdring_redispatches_per_window=1.0), {}
-        )
+            _evidence(gang_cmdring_redispatches_per_window=1.0))
 
 
 def test_check_cmdring_requires_per_opcode_residency():
@@ -602,7 +591,7 @@ def test_check_cmdring_requires_per_opcode_residency():
         ev["gang_cmdring_op_slots"], ALLTOALL=0
     )
     with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(ev, {})
+        mod.check_cmdring(ev)
 
 
 def test_check_cmdring_fallback_zero_gate():
@@ -612,7 +601,7 @@ def test_check_cmdring_fallback_zero_gate():
         "unsupported_op": 0, "compressed": 2,
     }
     with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(ev, {})
+        mod.check_cmdring(ev)
 
 
 def test_check_cmdring_refuses_partial_persistence_evidence():
@@ -620,14 +609,7 @@ def test_check_cmdring_refuses_partial_persistence_evidence():
     ev = _evidence()
     del ev["gang_cmdring_sustained_floor_us"]
     with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(ev, {})
-
-
-def test_check_cmdring_refuses_sustained_lkg_regression():
-    mod = _gate()
-    lkg = {"extras": _evidence(gang_cmdring_sustained_floor_us=5.0)}
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(_evidence(), lkg)
+        mod.check_cmdring(ev)
 
 
 def test_check_cmdring_accepts_pre_persistence_capture():
@@ -641,7 +623,7 @@ def test_check_cmdring_accepts_pre_persistence_capture():
         "gang_cmdring_refills_per_call": 0.125,
         "gang_cmdring_ring_slots": 96,
     }
-    mod.check_cmdring(ev, {})
+    mod.check_cmdring(ev)
 
 
 # ---------------------------------------------------------------------------
@@ -1660,7 +1642,7 @@ def _fused_evidence(**over):
 
 
 def test_check_cmdring_passes_fused_capture():
-    _gate().check_cmdring(_fused_evidence(), {})
+    _gate().check_cmdring(_fused_evidence())
 
 
 def test_check_cmdring_refuses_partial_fused_evidence():
@@ -1674,7 +1656,7 @@ def test_check_cmdring_refuses_partial_fused_evidence():
         ev = _fused_evidence()
         del ev[missing]
         with pytest.raises(mod.CmdringGateError, match="partial fused"):
-            mod.check_cmdring(ev, {})
+            mod.check_cmdring(ev)
 
 
 def test_check_cmdring_refuses_fused_host_reentry():
@@ -1686,12 +1668,12 @@ def test_check_cmdring_refuses_fused_host_reentry():
         mod.check_cmdring(_fused_evidence(
             gang_cmdring_fused_interactions_per_step=2.0,
             gang_cmdring_fused_refills_per_step=2.0,
-        ), {})
+        ))
     with pytest.raises(mod.CmdringGateError, match="re-entering"):
         mod.check_cmdring(_fused_evidence(
             gang_cmdring_fused_interactions_per_step=1.0,
             gang_cmdring_fused_refills_per_step=0.5,
-        ), {})
+        ))
 
 
 def test_check_cmdring_requires_fused_opcode_residency():
@@ -1701,7 +1683,7 @@ def test_check_cmdring_requires_fused_opcode_residency():
         ev["gang_cmdring_fused_op_slots"], FUSED_ATTN_HOP=0
     )
     with pytest.raises(mod.CmdringGateError, match="FUSED_ATTN_HOP"):
-        mod.check_cmdring(ev, {})
+        mod.check_cmdring(ev)
 
 
 def test_check_cmdring_fused_fallback_zero_gate():
@@ -1717,7 +1699,7 @@ def test_check_cmdring_fused_fallback_zero_gate():
         else:
             ev["gang_cmdring_fused_fallbacks"] = bad
         with pytest.raises(mod.CmdringGateError, match="fallback"):
-            mod.check_cmdring(ev, {})
+            mod.check_cmdring(ev)
 
 
 def test_check_cmdring_refuses_fused_slower_than_unfused():
@@ -1726,7 +1708,7 @@ def test_check_cmdring_refuses_fused_slower_than_unfused():
         mod.check_cmdring(_fused_evidence(
             gang_cmdring_fused_step_us=20000.0,
             gang_cmdring_unfused_step_us=18000.0,
-        ), {})
+        ))
 
 
 def test_check_cmdring_refuses_unanchored_fused_evidence():
@@ -1737,14 +1719,7 @@ def test_check_cmdring_refuses_unanchored_fused_evidence():
         mod.check_cmdring({
             "gang_cmdring_fused_step_us": 9000.0,
             "gang_cmdring_fused_interactions_per_step": 1.0,
-        }, {})
-
-
-def test_check_cmdring_refuses_fused_lkg_regression():
-    mod = _gate()
-    lkg = {"extras": _fused_evidence(gang_cmdring_fused_step_us=1000.0)}
-    with pytest.raises(mod.CmdringGateError, match="fused_step_us"):
-        mod.check_cmdring(_fused_evidence(), lkg)
+        })
 
 
 # ---------------------------------------------------------------------------

@@ -10,12 +10,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
-from accl_tpu.compat import has_modern_vma
+from accl_tpu.compat import has_interpret_params
 from accl_tpu.models import (
     TransformerConfig,
     forward,
@@ -27,30 +24,11 @@ from accl_tpu.models import (
 )
 
 
-# Legacy-jax feature boundary (same rationale as test_zero /
-# test_moe_pipeline): these tests differentiate through shard_map
-# programs whose gradient psum placement comes from checked
-# varying-manual-axes semantics — the compat shim can only run them
-# UNCHECKED on legacy jax, which misplaces those transposes, so they
-# would burn minutes failing on numerics (or AttributeError on
-# lax.pvary).  Skip loudly with the environment reason instead.
-requires_modern_jax = pytest.mark.skipif(
-    not has_modern_vma(),
-    reason="differentiates through shard_map; legacy-jax shim runs "
-           "unchecked (wrong gradient placement / missing lax.pvary)",
-)
-
-
 def _skip_unless_flash_runnable():
-    """The Pallas flash kernel needs Mosaic (real TPU) or the pallas TPU
-    interpret mode (pltpu.InterpretParams, absent on legacy jax)."""
-    import jax.experimental.pallas.tpu as pltpu
-
-    if jax.default_backend() != "tpu" and not hasattr(
-        pltpu, "InterpretParams"
-    ):
+    """The Pallas flash kernel needs Mosaic (real TPU) or a working
+    Pallas TPU interpreter."""
+    if jax.default_backend() != "tpu" and not has_interpret_params():
         pytest.skip("flash kernel needs Mosaic or pallas TPU interpret mode")
-
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +73,6 @@ def test_sharded_train_step_decreases_loss(cfg, mesh22):
     assert losses[-1] < losses[0], losses
 
 
-@requires_modern_jax
 def test_sharded_train_step_matches_single_device(cfg, mesh22):
     """One step on the mesh == one step single-device (same grads)."""
     from accl_tpu.models.transformer import loss_fn
@@ -298,7 +275,6 @@ def test_seq_parallel_forward_matches(cfg, mesh22):
     )
 
 
-@requires_modern_jax
 def test_seq_parallel_train_step_matches(cfg, mesh22):
     """SP changes the activation layout, not the math: same loss and same
     updated params as the plain sharded step."""
@@ -443,7 +419,6 @@ def test_attention_impls_match_naive(cfg, impl):
     )
 
 
-@requires_modern_jax
 def test_blockwise_train_step_matches_naive(cfg, mesh22):
     """Same loss and same updated params whichever attention lowering the
     sharded train step compiles."""
@@ -536,7 +511,6 @@ def test_encoder_attention_impls_match(cfg, impl):
     )
 
 
-@requires_modern_jax
 def test_sharded_encoder_step_matches_single_device(cfg, mesh22):
     """The dp x tp MLM step equals the unsharded step: same loss, same
     updated params."""
@@ -575,7 +549,6 @@ def test_encode_pools(cfg):
     assert emb.shape == (3, cfg.d_model) and np.isfinite(emb).all()
 
 
-@requires_modern_jax
 def test_encoder_seq_parallel_matches(cfg, mesh22):
     """The encoder honors Megatron-SP: sequence-sharded activations
     between bidirectional blocks produce the same hidden states."""
@@ -653,7 +626,6 @@ def test_stripe_roundtrip():
         stripe_sequence(x, 5)
 
 
-@requires_modern_jax
 def test_trainer_pipeline_parallelism(tmp_path):
     """The trainer example over the composed pp x dp x tp mesh: trains,
     checkpoints stacked params, resumes, and rejects the unsupported
@@ -681,7 +653,6 @@ def test_trainer_pipeline_parallelism(tmp_path):
         )
 
 
-@requires_modern_jax
 def test_trainer_parallelism_mismatch_diagnosable(tmp_path):
     from accl_tpu.examples.train import train
 
@@ -963,7 +934,6 @@ def test_vocab_parallel_shards_embedding(vp_cfg, mesh22):
 
 
 @pytest.mark.parametrize("sp", [False, True])
-@requires_modern_jax
 def test_vocab_parallel_train_matches_replicated(vp_cfg, cfg, mesh22, sp):
     """The fused vocab-parallel cross-entropy (sharded logits never
     materialized) must produce the identical loss AND updated params as
@@ -1053,7 +1023,6 @@ def mesh24():
 @pytest.mark.parametrize(
     "pos,remat", [("learned", False), ("rope", False), ("rope", True)]
 )
-@requires_modern_jax
 def test_context_parallel_train_matches_dense(mesh24, pos, remat):
     """A cp=4 train step (weights replicated over the ring, activations
     sequence-sharded end-to-end, striped ring attention, local loss +
@@ -1092,13 +1061,8 @@ def test_context_parallel_forward_matches_dense(mesh24, mesh_kind):
     import dataclasses
 
     if mesh_kind == "explicit":
-        pytest.importorskip("jax.sharding", reason="needs AxisType")
-        try:
-            from jax.sharding import AxisType
-        except ImportError:
-            pytest.skip("jax without explicit sharding axes")
         mesh = jax.make_mesh((2, 4), ("dp", "tp"))
-        if AxisType.Explicit not in mesh.axis_types:
+        if jax.sharding.AxisType.Explicit not in mesh.axis_types:
             pytest.skip("make_mesh is not explicit-axes on this jax")
     else:
         mesh = mesh24
@@ -1268,7 +1232,6 @@ def test_moe_flagship_forward_matches_single_device(moe_cfg, mesh42m):
     )
 
 
-@requires_modern_jax
 def test_moe_flagship_train_matches_single_device(moe_cfg, mesh42m):
     """One sharded MoE train step == the single-device step — loss AND
     params, expert grads riding the backward all-to-all.  Router aux
@@ -1356,7 +1319,6 @@ def test_moe_rejections(moe_cfg, mesh42m):
         )
 
 
-@requires_modern_jax
 def test_moe_composes_with_vocab_parallel(moe_cfg, mesh42m):
     """MoE (experts on dp) + vocab parallelism (embedding/loss on tp)
     use different axes and compose: identical loss and params to the
@@ -1380,7 +1342,6 @@ def test_moe_composes_with_vocab_parallel(moe_cfg, mesh42m):
         )
 
 
-@requires_modern_jax
 def test_moe_composes_with_context_parallelism(moe_cfg, mesh24_moecp):
     """Long-context MoE: experts dispatch over the dp all-to-all while
     the K/V ring turns over tp — one train step equals the single-device
@@ -1443,7 +1404,6 @@ def test_moe_cp_aux_terms_flow(moe_cfg, mesh24_moecp):
     assert np.isfinite(float(l1)) and float(l1) > float(l0)
 
 
-@requires_modern_jax
 def test_moe_expert_axis_unwelded_from_dp(moe_cfg):
     """Experts on a DEDICATED ep mesh axis (dp x ep x tp): the batch
     shards over dp x ep, dense grads psum over both, the expert bank
@@ -1478,7 +1438,6 @@ def test_moe_expert_axis_unwelded_from_dp(moe_cfg):
         )
 
 
-@requires_modern_jax
 def test_moe_ep_axis_zero_step_matches_welded(moe_cfg):
     """The ZeRO-Adam step on a (dp, ep, tp) mesh with experts on ep
     computes the same update as the welded experts-on-dp layout on a
@@ -1614,7 +1573,6 @@ def test_dense_config_ignores_ep_axis_unless_opted_in():
     np.testing.assert_allclose(float(loss), float(loss0), rtol=1e-5)
 
 
-@requires_modern_jax
 def test_trainer_interleaved_pipeline(tmp_path):
     """--v-stages 2 trains the composed pipeline with interleaved
     virtual stages and resumes from the permuted-stack checkpoint."""
@@ -1635,7 +1593,6 @@ def test_trainer_interleaved_pipeline(tmp_path):
         train(steps=1, log_every=0, v_stages=2)
 
 
-@requires_modern_jax
 def test_trainer_pipeline_1f1b(tmp_path):
     """--pp-schedule 1f1b trains the composed pipeline with the
     hand-scheduled backward and resumes."""
@@ -1667,7 +1624,6 @@ def test_trainer_moe_with_context_parallelism(tmp_path):
     assert done == 3 and np.isfinite(loss)
 
 
-@requires_modern_jax
 def test_trainer_pipeline_zero_adam(tmp_path):
     """optimizer='zero_adam' now composes with parallelism='pipeline':
     the ZeRO state (moments sharded inside the stage layout) checkpoints
@@ -1691,9 +1647,8 @@ def test_trainer_pipeline_zero_adam(tmp_path):
 
 def test_auto_attention_f16_never_selects_flash(monkeypatch):
     """Regression (ADVICE r5 medium): Mosaic rejects f16 matmul operands
-    (a ValueError at kernel compile, observed as a session abort on the
-    chip tier), so the ``attention='auto'`` resolver must gate the flash
-    branch on dtype — an f16 activation at flash-eligible T
+    (a ValueError at kernel compile), so the ``attention='auto'``
+    resolver must gate the flash branch on dtype — an f16 activation at flash-eligible T
     (1024 <= T < 4096) falls through to the XLA blockwise fold instead.
     bf16 keeps selecting the kernel (the VMEM gate alone decides)."""
     from accl_tpu.models.transformer import (

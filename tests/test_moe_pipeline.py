@@ -9,25 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from accl_tpu.compat import has_modern_vma
-
-# The pipeline/composed layers' transpose bookkeeping comes out of
-# shard_map's varying-axis tracking (composed.py design notes); on a
-# legacy jax the compat shim runs these programs unchecked, which
-# silently misplaces gradient psums — skip the feature's suite loudly
-# instead of spending minutes failing on numerics.
-pytestmark = pytest.mark.skipif(
-    not has_modern_vma(),
-    reason="pipeline/composed correctness requires modern shard_map "
-           "varying-manual-axes semantics (jax.lax.pvary); legacy-jax "
-           "shim runs unchecked",
-)
 
 from accl_tpu.models import (
     init_moe_params,

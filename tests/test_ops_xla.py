@@ -176,10 +176,7 @@ def test_sendrecv_shift(mesh, stacked):
 def _smap_overlap(fn, mesh):
     from jax.sharding import PartitionSpec as PS
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     return jax.jit(
         shard_map(
@@ -251,10 +248,7 @@ def test_matmul_allreduce_replicated_outspec(mesh):
     provable, the exact scenario row-parallel layers need."""
     from jax.sharding import PartitionSpec as PS
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from accl_tpu.ops import overlap
 
@@ -283,10 +277,7 @@ def test_allgather_invariant_fallback(mesh, monkeypatch):
     zero.py / seq-parallel exits through this path (ADVICE r2)."""
     from jax.sharding import PartitionSpec as PS
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from accl_tpu.ops import collectives
 
@@ -341,10 +332,7 @@ def test_reduce_scatter_non_divisible_non_sum_raises(mesh):
     silently truncate (ADVICE r2)."""
     from jax.sharding import PartitionSpec as PS
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from accl_tpu.ops import collectives
 
@@ -366,10 +354,7 @@ def test_reduce_scatter_non_sum_untiled_matches_sum(mesh):
     SUM (psum_scatter) and composed non-SUM paths."""
     from jax.sharding import PartitionSpec as PS
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from accl_tpu.ops import collectives
 

@@ -10,24 +10,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from accl_tpu.compat import has_pallas_interpret
+from accl_tpu.compat import has_interpret_params, interpret_params_reason
 from accl_tpu.constants import ReduceFunction
 from accl_tpu.ops import pallas as pk
 
 pytestmark = [
     pytest.mark.pallas,
-    # off-chip these kernels run under the Pallas TPU interpreter,
-    # which legacy jax does not ship — skip loudly with the environment
-    # reason instead of failing on the missing attribute
+    # off-chip these kernels run under the Pallas TPU interpreter: where
+    # its probe fails the suite skips loudly with the probe's reason
     pytest.mark.skipif(
-        jax.default_backend() != "tpu" and not has_pallas_interpret(),
-        reason="Pallas kernels need Mosaic (TPU) or pltpu.InterpretParams",
+        jax.default_backend() != "tpu" and not has_interpret_params(),
+        reason=f"Pallas interpret tier unavailable: "
+               f"{interpret_params_reason()}",
     ),
 ]
 

@@ -406,9 +406,8 @@ def test_autotune_emits_valid_plan_and_restores_registers(pair):
 
 
 def test_committed_cpu_mesh_plan_fixture_loads():
-    """The checked-in CPU-mesh artifact (scripts/chip_session.sh writes
-    the chip-tier sibling) must stay loadable and well-formed, and its
-    same-session tuned-vs-default CSV pair must satisfy the not-slower
+    """The checked-in CPU-mesh artifact must stay loadable and
+    well-formed, and its same-session tuned-vs-default CSV pair must satisfy the not-slower
     gate: a winner that was NOT >=margin faster than the defaults in
     its own race session means the selection hysteresis regressed."""
     results = os.path.join(

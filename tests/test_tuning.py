@@ -12,7 +12,7 @@ gang's algorithm-selection registers.
 import numpy as np
 import pytest
 
-from accl_tpu.compat import has_pallas_interpret
+from accl_tpu.compat import has_interpret_params
 
 from helpers import run_parallel
 
@@ -160,8 +160,8 @@ def test_tuning_invalid_inputs(group2):
     "algo", ["ring", "pallas_ring", "pallas_ring_bidir", "xla"]
 )
 def test_xla_allreduce_algorithm_via_facade(algo, rng):
-    if algo.startswith("pallas") and not has_pallas_interpret():
-        pytest.skip("pallas lowering off-chip needs pltpu.InterpretParams")
+    if algo.startswith("pallas") and not has_interpret_params():
+        pytest.skip("pallas lowering off-chip needs the Pallas interpreter")
     from accl_tpu.core import xla_group
 
     g = xla_group(4)
@@ -190,8 +190,8 @@ def test_xla_allreduce_algorithm_via_facade(algo, rng):
 def test_xla_rooted_algorithms_via_facade(algo, rng):
     """bcast/reduce/scatter/gather flip between the XLA lowering and the
     rooted Pallas ring-relay kernels through the tuning registers."""
-    if algo.startswith("pallas") and not has_pallas_interpret():
-        pytest.skip("pallas lowering off-chip needs pltpu.InterpretParams")
+    if algo.startswith("pallas") and not has_interpret_params():
+        pytest.skip("pallas lowering off-chip needs the Pallas interpreter")
     from accl_tpu.core import xla_group
 
     g = xla_group(4)

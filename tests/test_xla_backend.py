@@ -6,7 +6,7 @@ mesh — the tier-equivalence contract of SURVEY.md §4.
 import numpy as np
 import pytest
 
-from accl_tpu.compat import has_pallas_interpret
+from accl_tpu.compat import has_interpret_params
 
 from helpers import run_parallel
 
@@ -371,8 +371,8 @@ def test_xla_allreduce_algorithm_tuning(algo, rng):
     runtime flat-vs-tree threshold surface, accl.cpp:1198-1208) switches
     the allreduce lowering: explicit ppermute ring or the Pallas
     remote-DMA ring kernel — same MPI-facade semantics either way."""
-    if algo.startswith("pallas") and not has_pallas_interpret():
-        pytest.skip("pallas lowering off-chip needs pltpu.InterpretParams")
+    if algo.startswith("pallas") and not has_interpret_params():
+        pytest.skip("pallas lowering off-chip needs the Pallas interpreter")
     g = xla_group(4)
     try:
         g[0].engine.gang.tuning.update(
@@ -399,8 +399,8 @@ def test_xla_allreduce_algorithm_tuning(algo, rng):
 def test_xla_allreduce_compressed_pallas_ring(rng):
     """ETH_COMPRESSED + pallas_ring tuning: the compression lanes execute
     inside the kernel (wire narrowed to bf16, f32 accumulation)."""
-    if not has_pallas_interpret():
-        pytest.skip("pallas lowering off-chip needs pltpu.InterpretParams")
+    if not has_interpret_params():
+        pytest.skip("pallas lowering off-chip needs the Pallas interpreter")
     g = xla_group(4)
     try:
         g[0].engine.gang.tuning.update({"allreduce_algorithm": "pallas_ring"})

@@ -12,23 +12,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from accl_tpu.compat import has_modern_vma
 from accl_tpu.models import TransformerConfig, init_params
 from accl_tpu.models.transformer import loss_fn
 from accl_tpu.parallel import AdamConfig, make_zero_train_step
-
-# zero.py's gradient placement comes out of shard_map's varying-axis
-# tracking ("manual placement under check_vma=False gets mixed
-# replicated/sharded params wrong", zero.py) — on a legacy jax the
-# compat shim can only run these programs UNCHECKED, which is
-# numerically wrong by the module's own design notes.  Skip loudly
-# rather than spend minutes producing wrong numerics.
-pytestmark = pytest.mark.skipif(
-    not has_modern_vma(),
-    reason="ZeRO correctness requires modern shard_map varying-manual-"
-           "axes semantics (jax.lax.pvary); legacy-jax shim runs "
-           "unchecked",
-)
 
 
 @pytest.fixture(scope="module")
