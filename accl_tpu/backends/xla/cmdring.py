@@ -81,6 +81,7 @@ from ...constants import (
 )
 from ...membership import CircuitBreaker
 from ...overlap import drain_deadline_s
+from ...utils.profiling import annotate
 
 _F = CMDRING_FIELDS
 
@@ -1540,11 +1541,7 @@ class GangCommandRing:
             self._assemble_ring_global(calls, plan, mesh)
             for calls, lead, plan in window
         ]
-        import jax
-
-        with jax.profiler.TraceAnnotation(
-            f"accl::cmdring[{len(window)}]"
-        ):
+        with annotate(f"accl::cmdring[{len(window)}]"):
             st, results = devring.run_windows(
                 [(slots_np, globals_)], mesh, shape, lowering=lowering,
             )

@@ -57,6 +57,7 @@ from ...buffer import (
 )
 from ...overlap import InflightWindow, drain_deadline_s
 from ...request import Request
+from ...utils.profiling import annotate
 from ..base import BaseEngine, CallOptions, InteractionCounter, StreamPortMixin
 from ...ops import driver as opdriver
 from .cmdring import GangCommandRing
@@ -66,6 +67,10 @@ from .cmdring import GangCommandRing
 #: must NOT complete the requests — the window's drainer will, from the
 #: device done-probe, in launch order.
 IN_FLIGHT = object()
+
+
+#: the gang call's own host span, by op: constant names on the hot path
+_OP_SPAN = {op: f"accl::{op.name.lower()}" for op in Operation}
 
 
 def _np_stack_op0(
@@ -823,16 +828,12 @@ class XLAGangContext:
                 # the plain base op would compute the wrong thing —
                 # decompose with the host reference semantics instead
                 # (counted on the ring's fallback table)
-                with jax.profiler.TraceAnnotation(
-                    f"accl::fused{int(lead.fuse)}_decomposed"
-                ):
+                with annotate(f"accl::fused{int(lead.fuse)}_decomposed"):
                     code = self._execute_fused_decomposed(comm, calls)
             else:
                 # named range in the xprof timeline (the per-call span the
                 # reference's perf counter provides, SURVEY §5 tracing)
-                with jax.profiler.TraceAnnotation(
-                    f"accl::{lead.op.name.lower()}"
-                ):
+                with annotate(_OP_SPAN[lead.op]):
                     code = self._run_op(comm, calls, lead, reqs, t0)
         except Exception:
             import traceback
@@ -1186,7 +1187,7 @@ class XLAGangContext:
                 return False
 
         self.interactions.bump()  # ONE dispatch for the whole batch
-        with jax.profiler.TraceAnnotation(f"accl::batch[{len(plans)}]"):
+        with annotate(f"accl::batch[{len(plans)}]"):
             outs = opdriver.run_batch(globals_, mesh, specs)
         all_reqs: List[Request] = []
         for i, (calls, lead, plan) in enumerate(plans):
@@ -1487,6 +1488,27 @@ class XLAGangContext:
         buffers are validated, the global assembled, and the ONE program
         dispatched.  Returns None to fall back to the full path (operand
         shape drift, dummy/view operands, host buffers)."""
+        comm_id = lead.comm.id
+        with annotate("accl.gang::assemble", comm=comm_id):
+            prepared = self._assemble_prepared(calls, lead, state)
+        if prepared is None:
+            return None
+        prog, global_arr = prepared
+        self.interactions.bump()  # THE dispatch: one prepared program
+        with annotate("accl.gang::dispatch", comm=comm_id):
+            out = prog(global_arr)
+        with annotate("accl.gang::adopt", comm=comm_id):
+            self._adopt_out_shards(
+                out, calls, state["tmpl"], reqs, state["dev_to_rank"]
+            )
+        with annotate("accl.gang::park", comm=comm_id):
+            return self._park_inflight(lead.comm, out, reqs, t0)
+
+    def _assemble_prepared(self, calls: List[CallOptions],
+                           lead: CallOptions, state: dict):
+        """Validate the operands against the prepared template, assemble
+        the global and find the program: ``(prog, global_arr)``, or None
+        where the call must take the full path."""
         tmpl = state["tmpl"]
         devs, npdt, in_w = tmpl["devs"], tmpl["npdt"], tmpl["in_w"]
         shards = []
@@ -1557,12 +1579,7 @@ class XLAGangContext:
             )
             state["programs"][w] = prog
 
-        self.interactions.bump()  # THE dispatch: one prepared program
-        out = prog(global_arr)
-        self._adopt_out_shards(
-            out, calls, tmpl, reqs, state["dev_to_rank"]
-        )
-        return self._park_inflight(lead.comm, out, reqs, t0)
+        return prog, global_arr
 
     def _adopt_out_shards(self, out, calls, plan, reqs,
                           dev_to_rank=None) -> None:
@@ -1652,79 +1669,83 @@ class XLAGangContext:
                 )
                 if code is not None:
                     return code
-        plan = self._plan_device_call(comm, calls, lead, mesh)
-        if plan is None:
-            return None
-        if fast_eligible:
-            # park the prepared state on the facade's CollectivePlan: the
-            # next warm call on this plan skips re-validation, sharding
-            # construction and program-cache hashing entirely
-            from jax.sharding import NamedSharding, PartitionSpec
+        with annotate("accl.gang::assemble", comm=comm.id):
+            plan = self._plan_device_call(comm, calls, lead, mesh)
+            if plan is None:
+                return None
+            if fast_eligible:
+                # park the prepared state on the facade's CollectivePlan: the
+                # next warm call on this plan skips re-validation, sharding
+                # construction and program-cache hashing entirely
+                from jax.sharding import NamedSharding, PartitionSpec
 
-            states = fp.engine.setdefault("gang", {})
-            if len(states) > 8 and lead.count not in states:
-                states.clear()  # pathological count churn within a bucket
-            states[lead.count] = {
-                "tmpl": plan,
-                "mesh": mesh,
-                "tuning_epoch": self.tuning_epoch,
-                "sharding": NamedSharding(
-                    mesh, PartitionSpec(opdriver.AXIS)
-                ),
-                "dev_to_rank": {
-                    d: r for r, d in enumerate(plan["devs"])
-                },
-                "programs": {},
-            }
-        op = plan["op"]
-        global_arr, prep, raw_bufs = self._assemble_flat(calls, plan, mesh)
-
+                states = fp.engine.setdefault("gang", {})
+                if len(states) > 8 and lead.count not in states:
+                    states.clear()  # pathological count churn within a bucket
+                states[lead.count] = {
+                    "tmpl": plan,
+                    "mesh": mesh,
+                    "tuning_epoch": self.tuning_epoch,
+                    "sharding": NamedSharding(
+                        mesh, PartitionSpec(opdriver.AXIS)
+                    ),
+                    "dev_to_rank": {
+                        d: r for r, d in enumerate(plan["devs"])
+                    },
+                    "programs": {},
+                }
+            op = plan["op"]
+            global_arr, prep, raw_bufs = self._assemble_flat(calls, plan, mesh)
         fn = lead.reduce_function
         self.interactions.bump()  # THE dispatch: one fused program
-        if op == Operation.ALLREDUCE:
-            wire = lead.arithcfg.compressed if plan["compressed"] else None
-            # allreduce keeps its wire lane inside its own program (a
-            # single rounding); prep carries only the width slice here
-            # (_assemble_flat never sets a prep wire for allreduce)
-            out = self._allreduce(
-                global_arr, mesh, fn, wire, prep=prep,
-                tuning=effective_tuning(self.tuning, lead),
-            )
-        elif op in (
-            Operation.REDUCE, Operation.BCAST, Operation.SCATTER,
-            Operation.GATHER,
-        ):
-            donate = op == Operation.BCAST and prep is None
-            if donate:
-                # The donating bcast consumes shard arrays that may also
-                # back cached assembled globals from earlier ops on the
-                # same buffers.  JAX copy-on-donate keeps those entries
-                # readable, but evict them anyway so no cache hit can ever
-                # observe a donated (possibly aliased) array.
-                donors = {
-                    id(c.op0) for c in calls
-                    if c.op0 is not None and not c.op0.is_dummy
-                }
-                stale = [
-                    k for k, v in self._asm_cache.items()
-                    if any(id(ref()) in donors for ref in v[2])
-                ]
-                for k in stale:
-                    self._asm_cache.pop(k, None)
-            out = self._run_rooted(
-                op, global_arr, mesh, lead, donate=donate, prep=prep
-            )
-        elif op == Operation.ALLGATHER:
-            out = opdriver.run_allgather(global_arr, mesh, prep=prep)
-        elif op == Operation.REDUCE_SCATTER:
-            out = opdriver.run_reduce_scatter(global_arr, mesh, fn, prep=prep)
-        elif op == Operation.ALLTOALL:
-            out = opdriver.run_alltoall(global_arr, mesh, prep=prep)
-        else:  # pragma: no cover - guarded by IN_W
-            return None
-
-        self._adopt_out_shards(out, calls, plan, reqs)
-        return self._park_inflight(comm, out, reqs, t0)
+        with annotate("accl.gang::dispatch", comm=comm.id):
+            if op == Operation.ALLREDUCE:
+                wire = lead.arithcfg.compressed if plan["compressed"] else None
+                # allreduce keeps its wire lane inside its own program (a
+                # single rounding); prep carries only the width slice here
+                # (_assemble_flat never sets a prep wire for allreduce)
+                out = self._allreduce(
+                    global_arr, mesh, fn, wire, prep=prep,
+                    tuning=effective_tuning(self.tuning, lead),
+                )
+            elif op in (
+                Operation.REDUCE, Operation.BCAST, Operation.SCATTER,
+                Operation.GATHER,
+            ):
+                donate = op == Operation.BCAST and prep is None
+                if donate:
+                    # The donating bcast consumes shard arrays that may also
+                    # back cached assembled globals from earlier ops on the
+                    # same buffers.  JAX copy-on-donate keeps those entries
+                    # readable, but evict them anyway so no cache hit can ever
+                    # observe a donated (possibly aliased) array.
+                    donors = {
+                        id(c.op0) for c in calls
+                        if c.op0 is not None and not c.op0.is_dummy
+                    }
+                    stale = [
+                        k for k, v in self._asm_cache.items()
+                        if any(id(ref()) in donors for ref in v[2])
+                    ]
+                    for k in stale:
+                        self._asm_cache.pop(k, None)
+                out = self._run_rooted(
+                    op, global_arr, mesh, lead, donate=donate, prep=prep
+                )
+            elif op == Operation.ALLGATHER:
+                out = opdriver.run_allgather(global_arr, mesh, prep=prep)
+            elif op == Operation.REDUCE_SCATTER:
+                out = opdriver.run_reduce_scatter(
+                    global_arr, mesh, fn, prep=prep
+                )
+            elif op == Operation.ALLTOALL:
+                out = opdriver.run_alltoall(global_arr, mesh, prep=prep)
+            else:  # pragma: no cover - guarded by IN_W
+                return None
+        with annotate("accl.gang::adopt", comm=comm.id):
+            self._adopt_out_shards(out, calls, plan, reqs)
+        with annotate("accl.gang::park", comm=comm.id):
+            return self._park_inflight(comm, out, reqs, t0)
 
     def _run_rooted(self, op, global_arr, mesh, lead, donate=False,
                     prep=None):

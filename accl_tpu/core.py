@@ -51,6 +51,7 @@ from . import membership as _mbr
 from .overlap import drain_deadline_s
 from .plans import CollectivePlan, PlanCache, size_bucket
 from .request import Request
+from .utils.profiling import annotate, annotated
 from .telemetry import (
     Telemetry,
     chrome_trace,
@@ -1599,6 +1600,7 @@ class ACCL:
     #: ``compress_dtype=`` keeps working on every op that accepts it.
     _WIRE_VERDICT_OPS = frozenset((Operation.ALLREDUCE,))
 
+    @annotated("accl.facade::plan")
     def _plan_for(
         self,
         op: Operation,
@@ -2923,11 +2925,13 @@ class ACCL:
         self, options: CallOptions, run_async: bool, context: str
     ) -> Optional[Request]:
         tel = self._telemetry
-        self._membership_intake(options, context)
+        with annotate("accl.facade::membership"):
+            self._membership_intake(options, context)
         # QoS admission BEFORE the contract fingerprint: the arbiter
         # can only delay a whole call (bounded), never reorder within a
         # comm, so the digest stream the verifier checks is untouched
-        self._arbiter_gate(options)
+        with annotate("accl.facade::arbiter"):
+            self._arbiter_gate(options)
         qos = getattr(self._call_tls, "qos", None)
         if qos is not None:
             self._call_tls.qos = None
@@ -2938,36 +2942,40 @@ class ACCL:
         # the async callback / the sync finally owns the release
         tracked = False
         try:
-            self._contract_gate(options, context)
-            # quantized wire plane: per-wire-dtype accounting at intake
-            # (casts + bytes the narrow lane keeps off the wire for
-            # this rank's contribution — the effective-bandwidth
-            # evidence's live counterpart)
-            if (
-                tel is not None
-                and options.arithcfg is not None
-                and options.compression & CompressionFlags.ETH_COMPRESSED
-            ):
-                wname = options.arithcfg.compressed.name
-                payload_b = options.count * dtype_size(
-                    options.arithcfg.uncompressed
+            with annotate("accl.facade::contract"):
+                self._contract_gate(options, context)
+            with annotate("accl.facade::meta"):
+                # quantized wire plane: per-wire-dtype accounting at
+                # intake (casts + bytes the narrow lane keeps off the
+                # wire for this rank's contribution — the
+                # effective-bandwidth evidence's live counterpart)
+                if (
+                    tel is not None
+                    and options.arithcfg is not None
+                    and options.compression
+                    & CompressionFlags.ETH_COMPRESSED
+                ):
+                    wname = options.arithcfg.compressed.name
+                    payload_b = options.count * dtype_size(
+                        options.arithcfg.uncompressed
+                    )
+                    tel.metrics.inc(
+                        "accl_compression_casts_total", (wname,)
+                    )
+                    tel.metrics.inc(
+                        "accl_compression_wire_bytes_saved_total",
+                        (wname,),
+                        max(0, payload_b - _wire.wire_nbytes(
+                            options.count, options.arithcfg.compressed
+                        )),
+                    )
+                # trace/span id assigned at INTAKE — before dispatch —
+                # so the fabric's outbound trace stamp covers this
+                # call's own wire traffic, not just its successors'
+                meta = (
+                    self._call_meta(options, qos) if tel is not None
+                    else None
                 )
-                tel.metrics.inc(
-                    "accl_compression_casts_total", (wname,)
-                )
-                tel.metrics.inc(
-                    "accl_compression_wire_bytes_saved_total", (wname,),
-                    max(0, payload_b - _wire.wire_nbytes(
-                        options.count, options.arithcfg.compressed
-                    )),
-                )
-            # trace/span id assigned at INTAKE — before dispatch — so
-            # the fabric's outbound trace stamp covers this call's own
-            # wire traffic, not just its successors'
-            meta = (
-                self._call_meta(options, qos) if tel is not None
-                else None
-            )
             if self._pending is not None:
                 req = Request(op_name=options.op.name)
                 req._pre_wait = self._dispatch_pending  # dispatch on wait
@@ -2986,35 +2994,36 @@ class ACCL:
                 # UNRELATED wedged call
                 self._dispatch_pending()
                 tracked = True
-                try:
-                    if not req.wait(
-                        timeout=drain_deadline_s(self._timeout_s)
-                    ):
-                        raise self._deadlock_error(context)
-                    self._membership_after_failure(
-                        options, req, context
-                    )
-                    self._check_failed(req, context)
-                finally:
-                    if qos is not None:  # freed however the call ends
-                        self._arbiter_done(options, req, qos)
-                return req
-            req = self.engine.start(options)
-            if qos is not None and run_async:
-                self._arbiter_async(options, req, qos)
-                tracked = True
-            if tel is not None:
-                # attach AFTER start: engines that complete
-                # synchronously inside start() are recorded
-                # immediately by attach()
-                tel.attach(req, meta)
+                return self._wait_sync(options, req, qos, context)
+            with annotate("accl.facade::submit"):
+                req = self.engine.start(options)
+                if qos is not None and run_async:
+                    self._arbiter_async(options, req, qos)
+                    tracked = True
+                if tel is not None:
+                    # attach AFTER start: engines that complete
+                    # synchronously inside start() are recorded
+                    # immediately by attach()
+                    tel.attach(req, meta)
             if run_async:
                 return req
-            # facade-level deadline follows the shared drain policy so
-            # the engine's own RECEIVE_TIMEOUT fires first for assembly
-            # stalls — and a first-call XLA compile of a large program
-            # doesn't spuriously trip the deadlock detector
             tracked = True
+            return self._wait_sync(options, req, qos, context)
+        except BaseException:
+            if qos is not None and not tracked and qos.get("paced"):
+                self._arbiter.release(
+                    options.comm.id, owner=self._arbiter_owner
+                )
+            raise
+
+    def _wait_sync(self, options: CallOptions, req: Request, qos,
+                   context: str) -> Request:
+        """The blocking tail of a sync call.  The facade-level deadline
+        follows the shared drain policy so the engine's own
+        RECEIVE_TIMEOUT fires first for assembly stalls — and a
+        first-call XLA compile of a large program doesn't spuriously
+        trip the deadlock detector."""
+        with annotate("accl.facade::wait"):
             try:
                 if not req.wait(
                     timeout=drain_deadline_s(self._timeout_s)
@@ -3025,13 +3034,7 @@ class ACCL:
             finally:
                 if qos is not None:  # slot freed however the call ends
                     self._arbiter_done(options, req, qos)
-            return req
-        except BaseException:
-            if qos is not None and not tracked and qos.get("paced"):
-                self._arbiter.release(
-                    options.comm.id, owner=self._arbiter_owner
-                )
-            raise
+        return req
 
     def _check_failed(self, req: Request, context: str) -> None:
         """``Request.check`` with the postmortem hook: a structured
@@ -3315,6 +3318,7 @@ class ACCL:
         return self._launch(opts, run_async, "stream_put")
 
     # -- collectives ---------------------------------------------------------
+    @annotated("accl.facade::call")
     def bcast(
         self,
         buf: BaseBuffer,
@@ -3362,6 +3366,7 @@ class ACCL:
         )
         return self._launch(opts, run_async, "bcast")
 
+    @annotated("accl.facade::call")
     def scatter(
         self,
         sendbuf: Optional[BaseBuffer],
@@ -3395,6 +3400,7 @@ class ACCL:
         )
         return self._launch(opts, run_async, "scatter")
 
+    @annotated("accl.facade::call")
     def gather(
         self,
         sendbuf: BaseBuffer,
@@ -3428,6 +3434,7 @@ class ACCL:
         )
         return self._launch(opts, run_async, "gather")
 
+    @annotated("accl.facade::call")
     def allgather(
         self,
         sendbuf: BaseBuffer,
@@ -3437,32 +3444,36 @@ class ACCL:
         compress_dtype: Optional[DTypeLike] = None,
         run_async: bool = False,
     ):
-        comm = comm or self._world
-        n = self._count_of(sendbuf, count)
-        host = self._host_flags(sendbuf, None, recvbuf)
-        plan = self._plan_for(
-            Operation.ALLGATHER, comm, sendbuf.dtype, n, compress_dtype, host,
-        )
-        if self._hier_eligible_call(
-            plan, comm, compress_dtype, "allgather", n
-        ):
+        with annotate("accl.facade::prepare"):
+            comm = comm or self._world
+            n = self._count_of(sendbuf, count)
+            host = self._host_flags(sendbuf, None, recvbuf)
+            plan = self._plan_for(
+                Operation.ALLGATHER, comm, sendbuf.dtype, n,
+                compress_dtype, host,
+            )
+            hier = self._hier_eligible_call(
+                plan, comm, compress_dtype, "allgather", n
+            )
+            opts = None if hier else CallOptions(
+                op=Operation.ALLGATHER,
+                comm=comm,
+                count=n,
+                arithcfg=plan.arithcfg,
+                compression=plan.compression,
+                host=host,
+                op0=sendbuf,
+                res=recvbuf,
+                plan=plan,
+                tuning=plan.tuning,
+            )
+        if hier:
             return self._hier_allgather(
                 plan, comm, sendbuf, recvbuf, n, run_async
             )
-        opts = CallOptions(
-            op=Operation.ALLGATHER,
-            comm=comm,
-            count=n,
-            arithcfg=plan.arithcfg,
-            compression=plan.compression,
-            host=host,
-            op0=sendbuf,
-            res=recvbuf,
-            plan=plan,
-            tuning=plan.tuning,
-        )
         return self._launch(opts, run_async, "allgather")
 
+    @annotated("accl.facade::call")
     def reduce(
         self,
         sendbuf: Optional[BaseBuffer],
@@ -3539,6 +3550,7 @@ class ACCL:
         )
         return self._launch(opts, run_async, "reduce")
 
+    @annotated("accl.facade::call")
     def allreduce(
         self,
         sendbuf: BaseBuffer,
@@ -3549,23 +3561,33 @@ class ACCL:
         compress_dtype: Optional[DTypeLike] = None,
         run_async: bool = False,
     ):
-        comm = comm or self._world
-        n = self._count_of(sendbuf, count)
-        host = self._host_flags(sendbuf, None, recvbuf)
-        plan = self._plan_for(
-            Operation.ALLREDUCE, comm, sendbuf.dtype, n, compress_dtype,
-            host, (int(function),),
-        )
-        # topology plane: hierarchical decomposition BEFORE the
-        # pipelining split — the stages are ordinary facade calls on
-        # the derived subcomms and may pipeline there
-        if self._hier_eligible_call(
-            plan, comm, compress_dtype, "allreduce", n
-        ):
+        with annotate("accl.facade::prepare"):
+            comm = comm or self._world
+            n = self._count_of(sendbuf, count)
+            host = self._host_flags(sendbuf, None, recvbuf)
+            plan = self._plan_for(
+                Operation.ALLREDUCE, comm, sendbuf.dtype, n, compress_dtype,
+                host, (int(function),),
+            )
+            # topology plane: hierarchical decomposition BEFORE the
+            # pipelining split — the stages are ordinary facade calls on
+            # the derived subcomms and may pipeline there
+            hier = self._hier_eligible_call(
+                plan, comm, compress_dtype, "allreduce", n
+            )
+            nseg = 1 if hier else self._pipeline_segments_for(
+                plan, n, sendbuf.dtype
+            )
+            opts = (
+                None if hier or nseg > 1
+                else self._allreduce_options(
+                    plan, comm, sendbuf, recvbuf, n, function, host
+                )
+            )
+        if hier:
             return self._hier_allreduce(
                 plan, comm, sendbuf, recvbuf, n, function, run_async
             )
-        nseg = self._pipeline_segments_for(plan, n, sendbuf.dtype)
         if nseg > 1:
             return self._launch_pipelined(
                 "allreduce", plan, comm, n, nseg, run_async,
@@ -3576,11 +3598,15 @@ class ACCL:
                 ),
                 "allreduce",
             )
+        return self._launch(opts, run_async, "allreduce")
+
+    def _allreduce_options(self, plan, comm, sendbuf, recvbuf, n, function,
+                           host) -> CallOptions:
         seed = self._derive_wire_seed(plan, comm, Operation.ALLREDUCE)
         staged = self._error_feedback_operand(
             plan, comm, sendbuf, n, function, seed
         )
-        opts = CallOptions(
+        return CallOptions(
             op=Operation.ALLREDUCE,
             comm=comm,
             count=n,
@@ -3595,8 +3621,8 @@ class ACCL:
             tuning=plan.tuning,
             wire_seed=seed,
         )
-        return self._launch(opts, run_async, "allreduce")
 
+    @annotated("accl.facade::call")
     def reduce_scatter(
         self,
         sendbuf: BaseBuffer,
@@ -3607,35 +3633,37 @@ class ACCL:
         compress_dtype: Optional[DTypeLike] = None,
         run_async: bool = False,
     ):
-        comm = comm or self._world
-        n = self._count_of(recvbuf, count)
-        host = self._host_flags(sendbuf, None, recvbuf)
-        plan = self._plan_for(
-            Operation.REDUCE_SCATTER, comm, recvbuf.dtype, n, compress_dtype,
-            host, (int(function),),
-        )
-        if self._hier_eligible_call(
-            plan, comm, compress_dtype, "reduce_scatter", n
-        ):
+        with annotate("accl.facade::prepare"):
+            comm = comm or self._world
+            n = self._count_of(recvbuf, count)
+            host = self._host_flags(sendbuf, None, recvbuf)
+            plan = self._plan_for(
+                Operation.REDUCE_SCATTER, comm, recvbuf.dtype, n,
+                compress_dtype, host, (int(function),),
+            )
+            hier = self._hier_eligible_call(
+                plan, comm, compress_dtype, "reduce_scatter", n
+            )
+            opts = None if hier else CallOptions(
+                op=Operation.REDUCE_SCATTER,
+                comm=comm,
+                count=n,
+                reduce_function=function,
+                arithcfg=plan.arithcfg,
+                compression=plan.compression,
+                host=host,
+                op0=sendbuf,
+                res=recvbuf,
+                plan=plan,
+                tuning=plan.tuning,
+                wire_seed=self._derive_wire_seed(
+                    plan, comm, Operation.REDUCE_SCATTER
+                ),
+            )
+        if hier:
             return self._hier_reduce_scatter(
                 plan, comm, sendbuf, recvbuf, n, function, run_async
             )
-        opts = CallOptions(
-            op=Operation.REDUCE_SCATTER,
-            comm=comm,
-            count=n,
-            reduce_function=function,
-            arithcfg=plan.arithcfg,
-            compression=plan.compression,
-            host=host,
-            op0=sendbuf,
-            res=recvbuf,
-            plan=plan,
-            tuning=plan.tuning,
-            wire_seed=self._derive_wire_seed(
-                plan, comm, Operation.REDUCE_SCATTER
-            ),
-        )
         return self._launch(opts, run_async, "reduce_scatter")
 
     # -- fused compute slots (ref accl_hls kernel-initiated calls) -----------
@@ -3758,6 +3786,7 @@ class ACCL:
             run_async, "fused_attn_hop",
         )
 
+    @annotated("accl.facade::call")
     def alltoall(
         self,
         sendbuf: BaseBuffer,
@@ -3767,28 +3796,30 @@ class ACCL:
         compress_dtype: Optional[DTypeLike] = None,
         run_async: bool = False,
     ):
-        comm = comm or self._world
-        if count is None:
-            count = sendbuf.count // comm.size
-        host = self._host_flags(sendbuf, None, recvbuf)
-        plan = self._plan_for(
-            Operation.ALLTOALL, comm, sendbuf.dtype, int(count),
-            compress_dtype, host,
-        )
-        opts = CallOptions(
-            op=Operation.ALLTOALL,
-            comm=comm,
-            count=int(count),
-            arithcfg=plan.arithcfg,
-            compression=plan.compression,
-            host=host,
-            op0=sendbuf,
-            res=recvbuf,
-            plan=plan,
-            tuning=plan.tuning,
-        )
+        with annotate("accl.facade::prepare"):
+            comm = comm or self._world
+            if count is None:
+                count = sendbuf.count // comm.size
+            host = self._host_flags(sendbuf, None, recvbuf)
+            plan = self._plan_for(
+                Operation.ALLTOALL, comm, sendbuf.dtype, int(count),
+                compress_dtype, host,
+            )
+            opts = CallOptions(
+                op=Operation.ALLTOALL,
+                comm=comm,
+                count=int(count),
+                arithcfg=plan.arithcfg,
+                compression=plan.compression,
+                host=host,
+                op0=sendbuf,
+                res=recvbuf,
+                plan=plan,
+                tuning=plan.tuning,
+            )
         return self._launch(opts, run_async, "alltoall")
 
+    @annotated("accl.facade::call")
     def barrier(
         self, comm: Optional[Communicator] = None, run_async: bool = False
     ):

@@ -39,7 +39,9 @@ call that merely rode the window reports 0: nothing overlapped it.
 
 Zero jax imports: waiters are opaque thunks (typically
 ``lambda: jax.block_until_ready(out)``), so the module is unit-testable
-with plain threading primitives and importable from jax-free processes.
+with plain threading primitives and importable from jax-free processes
+(the drainer's two ``utils.profiling.annotate`` spans are the shared
+no-op there).
 """
 
 from __future__ import annotations
@@ -50,6 +52,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from .constants import DEFAULT_INFLIGHT_WINDOW, MAX_INFLIGHT_WINDOW
+from .utils.profiling import annotate
 
 __all__ = ["InflightWindow", "default_window_depth", "drain_deadline_s"]
 
@@ -353,7 +356,8 @@ class InflightWindow:
 
     def _complete(self, entry: _Entry) -> None:
         try:
-            entry.waiter()
+            with annotate("accl.window::ready"):
+                entry.waiter()
         except BaseException as e:  # device-side failure
             with self._lock:
                 self.failed += 1
@@ -377,7 +381,8 @@ class InflightWindow:
                 self.ring_completed += 1
             self.overlap_ns_total += overlap_ns
         try:
-            entry.on_ready(overlap_ns, entry.depth, ready_ns)
+            with annotate("accl.window::complete"):
+                entry.on_ready(overlap_ns, entry.depth, ready_ns)
         except Exception:  # pragma: no cover - defensive
             import traceback
 

@@ -25,8 +25,9 @@ Three pillars:
 * **Trace export** — each rank's records render as Chrome/Perfetto
   trace events (``pid`` = rank, ``tid`` 0 = the engine tier, ``tid`` 1 =
   buffered wire events), named ``accl::<op>`` so they line up with the
-  host ranges ``utils.profiling.annotate`` already puts in xprof
-  timelines.  ``python -m accl_tpu.telemetry merge`` folds per-rank
+  gang engine's host span of the same name in xprof timelines (one of
+  the spans ``utils.profiling`` lists; the stage spans inside it,
+  ``accl.<layer>::<stage>``, exist in the profiler's trace only).  ``python -m accl_tpu.telemetry merge`` folds per-rank
   files into one Perfetto-loadable timeline.
 
 Always-on cheap: recording is append-to-preallocated-ring plus a couple
@@ -650,9 +651,10 @@ class Telemetry:
         """This rank's records as Chrome/Perfetto complete events.
 
         ``pid`` = rank, ``tid`` 0 = the engine tier's call stream, ``tid``
-        1 = buffered wire events (instants).  Names use the same
-        ``accl::<op>`` convention the gang's ``profiling.annotate``
-        ranges carry in xprof, so host spans and exported spans line up.
+        1 = buffered wire events (instants).  Names are ``accl::<op>``,
+        the name of the gang engine's own span of a call in xprof
+        (``utils.profiling`` lists the spans), so host spans and
+        exported spans line up.
         """
         events: List[dict] = [
             {

@@ -7,6 +7,7 @@ from .platform import (  # noqa: F401
 )
 from .profiling import (  # noqa: F401
     annotate,
+    annotated,
     device_memory_profile,
     device_scope,
     start_server,

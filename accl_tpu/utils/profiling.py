@@ -7,27 +7,79 @@ host timers; the TPU-native equivalents layer up:
 * per-call ns: ``Request.get_duration_ns`` (already on every tier);
 * per-call records: the telemetry plane (``accl_tpu.telemetry``) rings
   every completion into the flight recorder and exports Chrome/Perfetto
-  spans named ``accl::<op>`` — the SAME naming :func:`annotate` puts in
-  the xprof timeline, so host ranges and exported spans line up;
-* host spans: :func:`annotate` marks facade calls so they appear as
-  named ranges in the xprof timeline;
+  spans named ``accl::<op>`` — the name the gang engine's own
+  :func:`annotate` span of a call carries in the xprof timeline, so
+  host ranges and exported spans line up;
+* host spans: :func:`annotate` is the ONE place the package makes a
+  ``jax.profiler.TraceAnnotation``; the table below lists every span;
 * device spans: :func:`device_scope` names a region *inside* a jitted
   program (XLA op metadata), so kernels show up attributed in the trace
   viewer;
 * whole-program capture: :func:`trace` / :func:`start_server` drive
   ``jax.profiler`` — open the result in xprof/tensorboard or perfetto.
 
+Host spans, on the profiler's clock (the device trace's).  Names are
+constants; what a reader needs beyond a duration rides as keyword stats
+(``comm``).  There is no switch: a span costs a fraction of a
+microsecond while no trace is being taken.
+
+======================== ==================================================
+``accl.facade::call``    ``core.py``, ``@annotated`` on a public collective:
+                         entry to return, on the rank's calling thread
+``accl.facade::prepare`` ``core.py``, the four swept collectives: the
+                         public method up to ``_launch`` (counts, plan
+                         lookup, hier/pipeline decisions, ``CallOptions``)
+``accl.facade::plan``    ``core.py``, ``@annotated`` on ``_plan_for``
+``accl.facade::membership`` ``core.py`` ``_launch``: ``_membership_intake``
+``accl.facade::arbiter`` ``core.py`` ``_launch``: ``_arbiter_gate``
+``accl.facade::contract`` ``core.py`` ``_launch``: ``_contract_gate``
+``accl.facade::meta``    ``core.py`` ``_launch``: compression counters +
+                         ``_call_meta``
+``accl.facade::submit``  ``core.py`` ``_launch``: ``engine.start`` +
+                         ``tel.attach`` (holds ``accl::<op>`` on the rank
+                         that completes the gang slot)
+``accl.facade::wait``    ``core.py`` ``_launch``: ``req.wait`` through
+                         ``_check_failed`` / ``_arbiter_done`` (sync calls)
+``accl::<op>``           ``backends/xla/engine.py`` ``_execute_calls``: one
+                         gang call on the thread that assembled its slot
+``accl::fused<k>_decomposed`` the same, a fused call that missed the ring
+``accl.gang::assemble``  ``engine.py`` ``_run_op_device[_prepared]``:
+                         operand checks, assembled global, program lookup
+``accl.gang::dispatch``  ``engine.py``: the one program call
+``accl.gang::adopt``     ``engine.py``: ``_adopt_out_shards``
+``accl.gang::park``      ``engine.py``: ``_park_inflight``
+``accl::batch[n]``       ``engine.py`` ``_dispatch_batch_fused``: a fused
+                         batch of n collectives
+``accl::cmdring[n]``     ``backends/xla/cmdring.py``: a ring refill window
+``accl.window::ready``   ``overlap.py`` ``InflightWindow._complete``: the
+                         drainer's ``block_until_ready``
+``accl.window::complete`` ``overlap.py``: requests completed, telemetry
+                         record, done callbacks
+======================== ==================================================
+
+Only the ``accl::`` names are read by the benchmark's ``engine_span_us``,
+``facade_self_us`` and ``breakdown``; the stage spans are ``accl.<layer>::``
+so that those keep reading what they read (``perfbench/stage_spans.py``
+reads the stages).
+
 jax is imported LAZILY: the emulator/native tiers (and the telemetry
 plane's exporters) run in jax-free processes, and pulling a device
 runtime into them just to name a span would be a side effect a tracing
-utility must not have.  Off-jax, :func:`annotate` / :func:`device_scope`
-degrade to no-op context managers.
+utility must not have.  :func:`annotate` never imports jax: where the
+process has not imported it, it returns one shared no-op context.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import sys
 from typing import Iterator, Optional
+
+_NO_SPAN = contextlib.nullcontext()
+#: ``jax.profiler.TraceAnnotation`` once some module of the process has
+#: imported jax (a cache of a lookup, never a switch)
+_trace_annotation = None
 
 
 def _jax():
@@ -36,26 +88,34 @@ def _jax():
     return jax
 
 
-class annotate:
-    """Host-side named range (xprof Python/host rows); a no-op context
-    manager when jax is unavailable (jax-free emulator processes)."""
+def annotate(name: str, **stats):
+    """Host-side named range on the profiler's clock: the
+    ``jax.profiler.TraceAnnotation`` itself (``stats`` become the
+    event's stats), or the shared no-op context in a process that has
+    not imported jax."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            return _NO_SPAN
+        _trace_annotation = profiler.TraceAnnotation
+    return _trace_annotation(name, **stats)
 
-    def __init__(self, name: str):
-        self._name = name
-        try:
-            self._inner = _jax().profiler.TraceAnnotation(name)
-        except Exception:
-            self._inner = None
 
-    def __enter__(self):
-        if self._inner is not None:
-            self._inner.__enter__()
-        return self
+def annotated(name: str):
+    """Decorator form of :func:`annotate`: the whole call of the
+    decorated function is one span named ``name``."""
 
-    def __exit__(self, *exc):
-        if self._inner is not None:
-            return self._inner.__exit__(*exc)
-        return False
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with annotate(name):
+                return fn(*args, **kwargs)
+
+        return spanned
+
+    return wrap
 
 
 def device_scope(name: str):

@@ -480,11 +480,15 @@ def test_check_tuned_not_slower(tmp_path):
     _write_csv(tuned, [("allreduce", 16, 1200)])  # 1.2x: refused
     with pytest.raises(TunedPlanRegressionError, match="allreduce count=16"):
         check_tuned_not_slower(default, tuned)
-    # sweep.py re-exports the same surface (the tuned-artifact writer)
-    from benchmarks.sweep import check_tuned_not_slower as via_sweep
+    # sweep.py re-exports the same surface (the tuned-artifact writer).
+    # Its own re-exported error class: sweep imports the parser as a
+    # sibling (`parse_results`) where an earlier test of this worker left
+    # that name in sys.modules, and as `benchmarks.parse_results`
+    # otherwise — two module objects, two classes
+    from benchmarks import sweep
 
-    with pytest.raises(TunedPlanRegressionError):
-        via_sweep(default, tuned)
+    with pytest.raises(sweep.TunedPlanRegressionError):
+        sweep.check_tuned_not_slower(default, tuned)
 
 
 def test_plan_pipeline_verdict():
