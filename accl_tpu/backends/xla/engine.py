@@ -26,6 +26,7 @@ Mapping notes (ref -> here):
 from __future__ import annotations
 
 import functools
+import heapq
 import threading
 import time
 import weakref
@@ -440,13 +441,142 @@ _FAST_OPS = frozenset((
 ))
 
 
+class _DeadlineKeeper:
+    """The gang tier's ONE deadline thread: a parked call (a gang slot
+    short of members, an unmatched p2p post) arms its deadline here, so
+    no thread is started for a call.
+
+    An entry is ``(deadline, serial, weakref(parked))`` and nothing
+    else: no request, no buffer.  Nothing is ever cancelled.  A parked
+    object that assembled or matched leaves its table and dies, so its
+    entry is dropped as stale — swept by a later ``arm`` once the heap
+    has doubled, or popped when it reaches the head; one that outlives
+    its deadline has ``parked.expire()`` run, which decides liveness
+    under the owner's own lock (True: it completed the parked requests).
+
+    The thread starts lazily on the first arm and lasts until the
+    engine's ``stop()``; an arm wakes it only when the new deadline is
+    earlier than the one it sleeps toward (a shorter SET_TIMEOUT)."""
+
+    _SWEEP_MIN = 64  # stale entries tolerated before an arm sweeps
+
+    def __init__(self):
+        self._cv = threading.Condition(threading.Lock())
+        self._heap: List[tuple] = []
+        self._serial = 0  # heap tie-break: refs do not order
+        self._sweep_at = self._SWEEP_MIN
+        self._wake_at = float("inf")  # the deadline the thread sleeps toward
+        self._thread: Optional[threading.Thread] = None
+        self._stopped = False
+        self.armed = self.fired = self.dropped_stale = 0
+        self.threads_started = 0
+
+    def arm(self, deadline: float, parked) -> None:
+        """Run ``parked.expire()`` at ``deadline`` (``time.monotonic``)
+        if ``parked`` is still alive then.  Only a weak reference is
+        kept."""
+        with self._cv:
+            heap = self._heap
+            if len(heap) >= self._sweep_at:
+                live = [e for e in heap if e[2]() is not None]
+                self.dropped_stale += len(heap) - len(live)
+                heapq.heapify(live)
+                heap[:] = live
+                self._sweep_at = max(self._SWEEP_MIN, 2 * len(heap))
+            self._serial += 1
+            heapq.heappush(heap, (deadline, self._serial, weakref.ref(parked)))
+            self.armed += 1
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="accl-xla-deadlines", daemon=True
+                )
+                self.threads_started += 1
+                self._thread.start()
+            elif deadline < self._wake_at:
+                self._cv.notify()
+
+    def stop(self) -> None:
+        """Engine shutdown: the thread ends once nothing live is left
+        (a call still parked keeps its deadline; a later arm starts the
+        thread anew)."""
+        with self._cv:
+            self._stopped = True
+            self._cv.notify()
+
+    def stats(self) -> dict:
+        with self._cv:
+            return {
+                "armed": self.armed,
+                "fired": self.fired,
+                "dropped_stale": self.dropped_stale,
+                "threads_started": self.threads_started,
+                "queued": len(self._heap),
+            }
+
+    def _run(self) -> None:
+        while True:
+            parked = self._next_due()
+            if parked is None:
+                return
+            # expiry runs outside the keeper's lock: it takes the
+            # owner's lock and completes requests (user callbacks)
+            try:
+                fired = parked.expire()
+            except Exception:  # a callback must never end the keeper
+                import traceback
+
+                traceback.print_exc()
+                fired = True
+            del parked
+            with self._cv:
+                if fired:
+                    self.fired += 1
+                else:
+                    self.dropped_stale += 1
+
+    def _next_due(self):
+        """Sleep until the earliest live entry is due and pop it; None
+        once stopped with nothing left."""
+        heap = self._heap
+        with self._cv:
+            while True:
+                while heap and heap[0][2]() is None:
+                    heapq.heappop(heap)
+                    self.dropped_stale += 1
+                if not heap:
+                    if self._stopped:
+                        self._thread = None
+                        return None
+                    self._wake_at = float("inf")
+                    # acclint: allow[unbounded-wait] an idle keeper has
+                    # no deadline to keep: the next arm() or stop()
+                    # notifies it
+                    self._cv.wait()
+                    continue
+                deadline = heap[0][0]
+                wait_s = deadline - time.monotonic()
+                if wait_s > 0:
+                    self._wake_at = deadline
+                    self._cv.wait(wait_s)
+                    continue
+                parked = heapq.heappop(heap)[2]()
+                if parked is not None:
+                    return parked
+                self.dropped_stale += 1
+
+
 class _GangSlot:
-    def __init__(self, world: int, timeout_s: float, comm=None):
+    def __init__(self, world: int, timeout_s: float, comm=None,
+                 gang=None, key=None):
         self.calls: Dict[int, Tuple[CallOptions, Request]] = {}
         self.world = world
         self.deadline = time.monotonic() + timeout_s
-        self.watchdog: Optional[threading.Timer] = None
         self.comm = comm  # for absent-rank health attribution on timeout
+        self.gang = gang  # the deadline keeper's way back: expire()
+        self.key = key
+
+    def expire(self) -> bool:
+        return self.gang._expire_slot(self)
 
 
 class XLAGangContext:
@@ -502,6 +632,10 @@ class XLAGangContext:
         # handle): the fallback route for batched SEND/RECV positions
         # that did not pair into a ring slot
         self.p2p = None
+        # every parked call's deadline (gang slots here, the p2p
+        # channel's posts): one thread for the engine's life
+        self.deadlines = _DeadlineKeeper()
+        weakref.finalize(self, self.deadlines.stop)
 
     _DEAD_AFTER_TIMEOUTS = 2
 
@@ -636,19 +770,19 @@ class XLAGangContext:
             slot = self._slots.get(slot_key)
             arm = False
             if slot is None:
-                slot = _GangSlot(comm.size, self.timeout_s, comm=comm)
+                slot = _GangSlot(comm.size, self.timeout_s, comm=comm,
+                                 gang=self, key=slot_key)
                 self._slots[slot_key] = slot
-                arm = True  # exactly one watchdog per slot
+                arm = True  # exactly one deadline per slot
             slot.calls[comm.local_rank] = entry
             ready = len(slot.calls) == slot.world
             if ready:
+                # nothing to cancel: the keeper finds the slot dead
                 del self._slots[slot_key]
-                if slot.watchdog is not None:
-                    slot.watchdog.cancel()
         if ready:
             self._execute(comm, slot)
         elif arm:
-            self._arm_watchdog(slot_key, slot)
+            self.deadlines.arm(slot.deadline, slot)
 
     @staticmethod
     def _slot_requests(slot: "_GangSlot"):
@@ -690,8 +824,6 @@ class XLAGangContext:
         # can still be in flight when the ring state is abandoned)
         self.cmdring.reset()
         for slot in slots:
-            if slot.watchdog is not None:
-                slot.watchdog.cancel()
             for req in self._slot_requests(slot):
                 if not req.done():
                     req.complete(ErrorCode.RECEIVE_TIMEOUT)
@@ -711,8 +843,6 @@ class XLAGangContext:
             keys = [k for k in self._slots if k[0] == comm_id]
             slots = [self._slots.pop(k) for k in keys]
         for slot in slots:
-            if slot.watchdog is not None:
-                slot.watchdog.cancel()
             for req in self._slot_requests(slot):
                 if not req.done():
                     req.complete(
@@ -734,40 +864,39 @@ class XLAGangContext:
                 )
         return lines
 
-    def _arm_watchdog(self, slot_key, slot: _GangSlot) -> None:
-        def fire():
-            with self._lock:
-                live = self._slots.get(slot_key) is slot
-                if live:
-                    del self._slots[slot_key]
-                    # health accounting: every member that never posted to
-                    # this starved slot takes a strike (graceful
-                    # degradation — two strikes mark it dead and later
-                    # collectives fail fast)
-                    absent = []
-                    if slot.comm is not None:
-                        for r in range(slot.world):
-                            if r not in slot.calls:
-                                absent.append(r)
-                                self._health_note_absent(
-                                    slot.comm.ranks[r].session
-                                )
+    def _expire_slot(self, slot: _GangSlot) -> bool:
+        """The deadline keeper's call when ``slot.deadline`` has passed:
+        a slot still parked starves no longer.  False: it assembled (or
+        a reset / contract verdict completed it) in the meantime."""
+        slot_key = slot.key
+        with self._lock:
+            live = self._slots.get(slot_key) is slot
             if live:
-                ctx = {
-                    "comm": slot_key[0],
-                    "peer": absent if len(absent) != 1 else absent[0],
-                    "elapsed_s": round(self.timeout_s, 3),
-                }
-                for req in self._slot_requests(slot):
-                    req.complete(
-                        ErrorCode.RECEIVE_TIMEOUT,
-                        context=dict(ctx, op=req.op_name),
-                    )
-
-        t = threading.Timer(max(0.01, slot.deadline - time.monotonic()), fire)
-        t.daemon = True
-        slot.watchdog = t
-        t.start()
+                del self._slots[slot_key]
+                # health accounting: every member that never posted to
+                # this starved slot takes a strike (graceful
+                # degradation — two strikes mark it dead and later
+                # collectives fail fast)
+                absent = []
+                if slot.comm is not None:
+                    for r in range(slot.world):
+                        if r not in slot.calls:
+                            absent.append(r)
+                            self._health_note_absent(
+                                slot.comm.ranks[r].session
+                            )
+        if live:
+            ctx = {
+                "comm": slot_key[0],
+                "peer": absent if len(absent) != 1 else absent[0],
+                "elapsed_s": round(self.timeout_s, 3),
+            }
+            for req in self._slot_requests(slot):
+                req.complete(
+                    ErrorCode.RECEIVE_TIMEOUT,
+                    context=dict(ctx, op=req.op_name),
+                )
+        return live
 
     # -- execution -----------------------------------------------------------
     @staticmethod
@@ -1872,9 +2001,28 @@ class XLAGangContext:
 # p2p pairing: send/recv matched by (comm, tag, src, dst) independent of the
 # collective gang sequence.  Receivers register a *sink* callable so the same
 # channel serves buffer receives and recv-to-stream.  Unmatched posts carry a
-# watchdog honoring the engine timeout (the firmware's per-call deadline);
-# delivery — which may jit the fabric-hop program — runs OUTSIDE the channel
-# lock so unrelated pairs never serialize behind a compile.
+# deadline honoring the engine timeout (the firmware's per-call deadline),
+# armed at the gang's one deadline keeper; delivery — which may jit the
+# fabric-hop program — runs OUTSIDE the channel lock so unrelated pairs
+# never serialize behind a compile.
+class _ParkedPost:
+    """One unmatched send (``item`` is its payload) or recv (its sink)."""
+
+    __slots__ = ("channel", "table", "key", "item", "request", "t0",
+                 "__weakref__")
+
+    def __init__(self, channel, table, key, item, request, t0):
+        self.channel = channel
+        self.table = table
+        self.key = key
+        self.item = item
+        self.request = request
+        self.t0 = t0
+
+    def expire(self) -> bool:
+        return self.channel._expire(self)
+
+
 class _P2PChannel:
     """Tag-matched send/recv rendezvous between rank engines.
 
@@ -1886,10 +2034,11 @@ class _P2PChannel:
     (including the partner's late arrival); the late-arriving side
     reports roughly the delivery/copy cost alone."""
 
-    def __init__(self):
+    def __init__(self, deadlines: _DeadlineKeeper):
         self._lock = threading.Lock()
         self._sends: Dict[tuple, list] = {}
         self._recvs: Dict[tuple, list] = {}
+        self._deadlines = deadlines  # the gang's keeper
 
     def dump_parked(self) -> list:
         """Unmatched-post lines for the debug dump (a parked send holds
@@ -1909,70 +2058,66 @@ class _P2PChannel:
 
     def post_send(self, key, payload, request, timeout_s=None):
         t0 = time.perf_counter_ns()
-        match = None
         with self._lock:
-            if self._recvs.get(key):
-                sink, rreq, rtimer, rt0 = self._recvs[key].pop(0)
-                if rtimer is not None:
-                    rtimer.cancel()
-                match = (sink, rreq, rt0)
-            else:
-                self._park(self._sends, key, [payload, request], timeout_s, t0)
+            match = self._match(self._recvs, key)
+            if match is None:
+                parked = self._park(self._sends, key, payload, request, t0)
         if match is not None:
-            self._deliver(match[0], match[1], payload, request, match[2], t0)
+            self._deliver(match.item, match.request, payload, request,
+                          match.t0, t0)
+        elif timeout_s:
+            self._deadlines.arm(time.monotonic() + timeout_s, parked)
 
     def post_recv(self, key, sink, request, timeout_s=None):
         t0 = time.perf_counter_ns()
-        match = None
         with self._lock:
-            if self._sends.get(key):
-                payload, sreq, stimer, st0 = self._sends[key].pop(0)
-                if stimer is not None:
-                    stimer.cancel()
-                match = (payload, sreq, st0)
-            else:
-                self._park(self._recvs, key, [sink, request], timeout_s, t0)
+            match = self._match(self._sends, key)
+            if match is None:
+                parked = self._park(self._recvs, key, sink, request, t0)
         if match is not None:
-            self._deliver(sink, request, match[0], match[1], t0, match[2])
+            self._deliver(sink, request, match.item, match.request,
+                          t0, match.t0)
+        elif timeout_s:
+            self._deadlines.arm(time.monotonic() + timeout_s, parked)
 
-    def _park(self, table, key, entry, timeout_s, t0) -> None:
-        """Append an unmatched post (caller holds the lock), arming a
-        timeout watchdog when requested."""
-        entry.append(None)
-        entry.append(t0)
-        if timeout_s:
-            code = (
-                ErrorCode.SEND_TIMEOUT
-                if table is self._sends
-                else ErrorCode.RECEIVE_TIMEOUT
-            )
-            t = threading.Timer(
-                timeout_s, self._expire, (table, key, entry, code)
-            )
-            t.daemon = True
-            entry[2] = t
-            t.start()
-        table.setdefault(key, []).append(entry)
+    @staticmethod
+    def _match(table, key) -> Optional[_ParkedPost]:
+        """Pop the oldest parked partner (caller holds the lock).  It is
+        not cancelled at the keeper: it leaves the table, and its
+        deadline finds it gone."""
+        posts = table.get(key)
+        return posts.pop(0) if posts else None
 
-    def _expire(self, table, key, entry, code) -> None:
+    def _park(self, table, key, item, request, t0) -> _ParkedPost:
+        """Append an unmatched post (caller holds the lock, and arms its
+        deadline after releasing it: the keeper's lock nests in none)."""
+        post = _ParkedPost(self, table, key, item, request, t0)
+        table.setdefault(key, []).append(post)
+        return post
+
+    def _expire(self, post: _ParkedPost) -> bool:
+        key = post.key
         with self._lock:
-            # identity-based scan: payloads are arrays, so `in`/`remove`
-            # would trip elementwise ==
-            lst = table.get(key, [])
-            idx = next((i for i, e in enumerate(lst) if e is entry), None)
-            if idx is None:
-                return  # matched in the meantime: nothing to do
-            del lst[idx]
-        dt = time.perf_counter_ns() - entry[3]
+            posts = post.table.get(key, ())
+            if post not in posts:  # by identity: a post defines no ==
+                return False  # matched in the meantime: nothing to do
+            posts.remove(post)
+        code = (
+            ErrorCode.SEND_TIMEOUT
+            if post.table is self._sends
+            else ErrorCode.RECEIVE_TIMEOUT
+        )
+        dt = time.perf_counter_ns() - post.t0
         comm_id, _tag, src, dst = key
-        entry[1].complete(code, dt, context={
-            "op": entry[1].op_name,
+        post.request.complete(code, dt, context={
+            "op": post.request.op_name,
             "comm": comm_id,
             # the absent partner: the sender for a starved recv, the
             # receiver for a starved send (global rank identities)
             "peer": src if code == ErrorCode.RECEIVE_TIMEOUT else dst,
             "elapsed_s": round(dt / 1e9, 3),
         })
+        return True
 
     @staticmethod
     def _deliver(sink, rreq: Request, payload: np.ndarray, sreq,
@@ -2005,7 +2150,7 @@ class XLAEngine(StreamPortMixin, BaseEngine):
         device=None,
     ):
         self.gang = gang
-        self.p2p = p2p or _P2PChannel()
+        self.p2p = p2p or _P2PChannel(gang.deadlines)
         if gang.p2p is None:
             gang.p2p = self.p2p
         self.peers = peers if peers is not None else {}
@@ -2143,7 +2288,7 @@ class XLAEngine(StreamPortMixin, BaseEngine):
             plan.get("admit", ())
         ):
             self.gang.health.pop(s, None)
-        # snapshot before iterating: the watchdog timer thread inserts
+        # snapshot before iterating: the deadline keeper's thread inserts
         # concurrently, and a bare .values() walk can raise mid-cutover
         for h in list(self.gang.health.values()):
             if h["state"] == "suspect":
@@ -2166,6 +2311,9 @@ class XLAEngine(StreamPortMixin, BaseEngine):
         return {
             "device_interactions": self.gang.interactions.read(),
             "gang_pending_slots": pending_slots,
+            # the deadline keeper: armed == multi-rank slots + parked
+            # p2p posts; a healthy run has fired 0, threads_started 1
+            "gang_deadlines": self.gang.deadlines.stats(),
             "gang_tuning_epoch": self.gang.tuning_epoch,
             "p2p_parked": len(self.p2p.dump_parked()),
             "stream_depths": stream_depths,
@@ -2620,6 +2768,7 @@ class XLAEngine(StreamPortMixin, BaseEngine):
         # first rank handle's deinit does the work; later ones find it
         # already stopped — parks then degrade to inline completion)
         self.gang.window.stop()
+        self.gang.deadlines.stop()
         # command ring: halt every resident sequencer run so the
         # long-running programs return promptly instead of riding out
         # their linger with the process tearing down around them

@@ -4415,7 +4415,7 @@ def xla_group(n: int, **accl_kwargs) -> List[ACCL]:
             f"{jax.default_backend()!r} (one rank owns one device)"
         )
     gang = XLAGangContext()
-    p2p = _P2PChannel()
+    p2p = _P2PChannel(gang.deadlines)
     peers: dict = {}
     ranks = [Rank(address=f"xla:{i}", session=i) for i in range(n)]
     group = []
