@@ -18,6 +18,7 @@ from .transformer import (  # noqa: F401
     make_sharded_generate,
     make_sharded_train_step,
     make_sharded_forward,
+    make_sharded_router_probe,
     prefill,
 )
 from .ring_attention import (  # noqa: F401

@@ -272,6 +272,12 @@ def make_pp_train_step(
             "n_experts (MoE) is supported on the decoder flagship only "
             "(forward/loss_fn/generate), not the composed pipeline"
         )
+    if not cfg.default_block():
+        raise ValueError(
+            "norm/ffn/qk_norm/tie_head other than the default block are "
+            "supported on the decoder flagship only "
+            "(forward/loss_fn/generate), not the composed pipeline"
+        )
     M = num_microbatches
     heads_local = cfg.n_heads // tp
     specs = stacked_param_specs(cfg)

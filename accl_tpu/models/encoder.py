@@ -66,6 +66,12 @@ def encoder_forward(
             "n_experts (MoE) is supported on the decoder flagship only "
             "(forward/loss_fn/generate), not the encoder family"
         )
+    if not cfg.default_block():
+        raise ValueError(
+            "norm/ffn/qk_norm/tie_head other than the default block are "
+            "supported on the decoder flagship only "
+            "(forward/loss_fn/generate), not the encoder family"
+        )
     B, T = tokens.shape
     x = _embed_tokens(params, tokens, cfg)
     x, block, sp = _enter_block_layout(

@@ -9,39 +9,127 @@ substrate is exactly the reference's fused ``all_to_all``
 ``accl_tpu.ops.collectives.alltoall``'s lowering (or the Pallas
 one-sided-write kernel when composed manually).
 
-The routing is top-1 switch gating with a fixed per-expert capacity so the
-whole layer is static-shaped and jit/XLA friendly (no data-dependent
-shapes): over-capacity tokens fall through the residual path, the standard
-Switch-Transformer formulation.
+:func:`moe_ffn` routes every token to its top-k experts (any k) and has
+two dispatches:
+
+* **fixed capacity** (``capacity_factor`` a number): each expert takes at
+  most ``capacity_factor * N * k / E`` routing entries, so the whole layer
+  is static-shaped; entries past capacity fall through the residual path
+  (the Switch-Transformer formulation).  Every multi-chip path uses it:
+  the ``(E, cap, D)`` buffer is what the fixed-count all-to-all carries.
+* **dropless** (``capacity_factor=None``): the N*k routing entries are
+  sorted by expert and the experts run as one grouped (ragged) matmul
+  over the sorted rows with per-expert group sizes
+  (``jax.lax.ragged_dot``; on a TPU the compiler lowers it to its own
+  grouped-matmul Mosaic kernels — ``ragged-dot-*`` in the device trace —
+  in all three forms autodiff needs), then unsorted and combined.  No
+  entry is ever dropped and no buffer scales with E.  One chip's experts
+  only: across an expert axis the exchange needs a different count a
+  peer (ROADMAP R2(b)).
+
+Experts are two-matrix GELU FFNs (``w1``, ``w2``) or, with a ``w3`` in
+the parameters, gated-SiLU FFNs ``(silu(x w1) * (x w3)) w2``.
 """
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils.profiling import device_scope
 
-def init_moe_params(key, d_model: int, d_ff: int, n_experts: int, dtype=jnp.float32):
-    """Gate + per-expert FFN weights (unsharded; shard E over 'ep')."""
+
+def init_moe_params(key, d_model: int, d_ff: int, n_experts: int,
+                    dtype=jnp.float32, gated: bool = False):
+    """Gate + per-expert FFN weights (unsharded; shard E over 'ep').
+    ``gated`` adds ``w3``, the second up-projection of a gated-SiLU
+    expert."""
     k1, k2, k3 = jax.random.split(key, 3)
     scale = d_model ** -0.5
-    return {
+    params = {
         "gate": jax.random.normal(k1, (d_model, n_experts), dtype) * scale,
         "w1": jax.random.normal(k2, (n_experts, d_model, d_ff), dtype) * scale,
         "w2": jax.random.normal(k3, (n_experts, d_ff, d_model), dtype)
         * (d_ff ** -0.5),
     }
+    if gated:
+        params["w3"] = jax.random.normal(
+            jax.random.fold_in(k2, 1), (n_experts, d_model, d_ff), dtype
+        ) * scale
+    return params
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, idx, back, k: int):
+    """``x[idx // k]``: each row of ``x`` appears ``k`` times in the
+    result (a permutation at k=1).  ``back`` is the inverse of the
+    permutation ``idx``, so the cotangent is a gather and a sum of k
+    rows too — never a scatter-add over duplicate rows."""
+    return x[idx // k]
+
+
+def _take_rows_fwd(x, idx, back, k):
+    return x[idx // k], (back, x.shape[0])
+
+
+def _take_rows_bwd(k, res, g):
+    back, rows = res
+    g = g[back].reshape(rows, k, g.shape[-1])
+    return g.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _expert_act(up, params, matmul):
+    """The experts' hidden activation from the first up-projection:
+    gated SiLU where the bank has a ``w3``, GELU otherwise."""
+    if "w3" in params:
+        return jax.nn.silu(up) * matmul(params["w3"])
+    return jax.nn.gelu(up)
+
+
+def _dropless_experts(flat, params, topk_e, topk_p, tp_axis):
+    """Every routing entry through its expert, none dropped: sort the
+    N*k entries by expert, grouped matmuls over the sorted rows, unsort,
+    weight and sum a token's k results.  Returns ``(y, tokens an
+    expert)``."""
+    N, D = flat.shape
+    k = topk_e.shape[-1]
+    E = params["w1"].shape[0]
+    with device_scope("accl.moe::dispatch"):
+        expert = topk_e.reshape(-1)                       # (N*k,) entries
+        order = jnp.argsort(expert, stable=True)          # sorted -> entry
+        back = jnp.argsort(order)                         # entry -> sorted
+        sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
+        rows = _take_rows(flat, order, back, k)           # (N*k, D) by expert
+    with device_scope("accl.moe::experts"):
+        grouped = lambda a, w: lax.ragged_dot(a, w, sizes)
+        h = _expert_act(
+            grouped(rows, params["w1"]), params, partial(grouped, rows)
+        )
+        out = grouped(h, params["w2"])                    # (N*k, D)
+    with device_scope("accl.moe::combine"):
+        got = _take_rows(out, back, order, 1).reshape(N, k, D)
+        y = (got.astype(jnp.float32) * topk_p[..., None]).sum(axis=1)
+        y = y.astype(flat.dtype)
+        if tp_axis is not None:
+            y = lax.psum(y, tp_axis)
+    return y, sizes
 
 
 def moe_ffn(
     x: jax.Array,
     params: dict,
     ep_axis: str | None = None,
-    capacity_factor: float = 1.5,
+    capacity_factor: float | None = 1.5,
     k: int = 1,
     return_aux: bool = False,
     tp_axis: str | None = None,
+    renormalize: bool = True,
 ):
     """Top-k gated MoE FFN (k=1 is Switch routing, k=2 the classic MoE).
 
@@ -52,9 +140,15 @@ def moe_ffn(
     dispatch and combine are all-to-alls over the axis.
 
     Each token routes to its top-k experts with the gate probabilities
-    renormalized over the chosen k; every (token, choice) pair is an
-    independent routing entry through the same fixed-capacity dispatch,
-    so the layer stays static-shaped for any k.
+    renormalized over the chosen k (``renormalize=False`` keeps the raw
+    softmax probabilities, a published ``norm_topk_prob: false``); every
+    (token, choice) pair is an independent routing entry through the same
+    dispatch, so the layer stays static-shaped for any k.
+
+    ``capacity_factor=None`` is the DROPLESS dispatch (module docstring):
+    the router's matmul accumulates and its softmax runs in float32, the
+    entries are sorted by expert and run through grouped matmuls, and no
+    entry is dropped whatever the routing.  It has no ``ep_axis`` form.
 
     Returns (B, T, D): expert outputs weighted by the gate probability;
     over-capacity entries contribute zero (callers add the residual).
@@ -76,6 +170,10 @@ def moe_ffn(
       loss to keep experts utilized.
     * ``router_z`` — the ST-MoE z-loss, ``mean(logsumexp(logits)^2)``,
       which keeps router logits small/stable in bf16.
+
+    and two counters: ``expert_tokens`` (E,), the routing entries sent to
+    each expert, and ``dropped``, the entries past capacity (0 when
+    dropless).
     """
     B, T, D = x.shape
     N = B * T
@@ -85,11 +183,40 @@ def moe_ffn(
     e_local = params["w1"].shape[0]
     E = e_local * ep  # global expert count
 
+    if capacity_factor is None:
+        if ep > 1:
+            raise NotImplementedError(
+                "dropless routing (capacity_factor=None) over an expert "
+                f"axis of {ep}: the exchange needs a different count a "
+                "peer, which the fixed-count all-to-all does not carry "
+                "(ROADMAP R2(b)); give a capacity_factor or keep every "
+                "expert on the chip"
+            )
+        with device_scope("accl.moe::route"):
+            logits = jnp.dot(
+                flat, params["gate"], preferred_element_type=jnp.float32
+            )
+            probs = jax.nn.softmax(logits, axis=-1)
+            topk_p, topk_e = lax.top_k(probs, k)
+            if k > 1 and renormalize:  # as below: k=1 keeps the raw prob
+                topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
+        y, sizes = _dropless_experts(flat, params, topk_e, topk_p, tp_axis)
+        y = y.reshape(B, T, D)
+        if not return_aux:
+            return y
+        f = sizes.astype(jnp.float32) / (N * k)
+        return y, {
+            "load_balance": E * jnp.sum(f * probs.mean(axis=0)),
+            "router_z": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
+            "expert_tokens": sizes,
+            "dropped": jnp.zeros((), jnp.int32),
+        }
+
     # --- routing (replicated math: identical on every member rank) -------
     logits = flat @ params["gate"]  # (N, E)
     probs = jax.nn.softmax(logits, axis=-1)
     topk_p, topk_e = lax.top_k(probs, k)  # (N, k)
-    if k > 1:
+    if k > 1 and renormalize:
         # classic top-k MoE renormalizes over the chosen experts; k=1
         # keeps the RAW softmax prob — Switch routing scales by it so the
         # router keeps a gradient (p/p == 1 would zero d/d(gate))
@@ -128,7 +255,8 @@ def moe_ffn(
         work = disp  # (E, cap, D)
 
     # --- expert FFN on the local experts (batched einsum -> MXU) ---------
-    h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", work, params["w1"]))
+    up = lambda w: jnp.einsum("ecd,edf->ecf", work, w)
+    h = _expert_act(up(params["w1"]), params, up)
     out = jnp.einsum("ecf,efd->ecd", h, params["w2"])
 
     if ep_axis is not None:
@@ -163,4 +291,9 @@ def moe_ffn(
     router_z = jnp.mean(
         jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1) ** 2
     )
-    return y, {"load_balance": load_balance, "router_z": router_z}
+    return y, {
+        "load_balance": load_balance,
+        "router_z": router_z,
+        "expert_tokens": onehot.sum(axis=0),
+        "dropped": jnp.sum(~keep).astype(jnp.int32),
+    }

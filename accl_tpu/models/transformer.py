@@ -32,6 +32,7 @@ from jax import shard_map
 
 from ..constants import ReduceFunction
 from ..ops import collectives
+from ..utils.profiling import device_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,19 +90,40 @@ class TransformerConfig:
     # (AR = RS + AG), but layernorm/residual compute and inter-block
     # activation memory drop by the tp factor
     seq_parallel: bool = False
+    # the block's kinds, every layer alike (no layer pattern yet, D8):
+    # ``norm`` is "layernorm" (mean-centred) or "rmsnorm", both a scale
+    # and no bias at eps 1e-5; ``ffn`` is "gelu" (two matrices) or
+    # "swiglu" (gated SiLU, three matrices: ``(silu(x w1) * (x w3)) w2``),
+    # the dense FFN and each expert alike; ``qk_norm`` puts an RMSNorm
+    # on the whole projected q and k before the split into heads;
+    # ``tie_head=False`` gives the LM head its own (d_model, vocab)
+    # matrix instead of the embedding's transpose.  The decoder paths
+    # (train/forward/prefill/generate) honour all four; the encoder and
+    # the composed pipeline take the default block only.
+    norm: str = "layernorm"
+    ffn: str = "gelu"
+    qk_norm: bool = False
+    tie_head: bool = True
     # Mixture-of-Experts: n_experts > 0 replaces every block's dense FFN
-    # with a top-k routed expert FFN (models/moe.py — Switch routing at
-    # k=1, fixed capacity, static shapes).  Expert parallelism rides the
-    # DP mesh axis: each dp rank owns n_experts/dp experts and tokens
+    # with a top-k routed expert FFN (models/moe.py, any k; k=1 is Switch
+    # routing).  ``moe_capacity_factor`` a number: fixed capacity, static
+    # shapes, entries past capacity dropped; expert parallelism rides the
+    # DP mesh axis, each dp rank owns n_experts/dp experts and tokens
     # travel to their expert's chip through the all-to-all (dispatch +
     # return), the fourth parallelism axis composed into the flagship.
-    # loss_fn adds the router health terms (Switch load-balance aux +
-    # ST-MoE z-loss) averaged over layers.  Requires n_experts divisible
-    # by dp; decoder train/forward/decode paths (not encoder/pipeline,
-    # and not combined with seq_parallel/context_parallel yet).
+    # ``moe_capacity_factor=None`` MEANS dropless: entries sorted by
+    # expert through a grouped matmul, float32 router softmax, nothing
+    # dropped; every expert on the chip (an expert axis larger than one
+    # raises: ROADMAP R2(b)).  ``moe_norm_topk_prob`` is the published
+    # key: renormalise the k chosen probabilities (k > 1) or keep them
+    # raw.  loss_fn adds the router health terms (Switch load-balance
+    # aux + ST-MoE z-loss) averaged over layers.  Requires n_experts
+    # divisible by dp; decoder train/forward/decode paths (not
+    # encoder/pipeline, and not combined with seq_parallel yet).
     n_experts: int = 0
     moe_top_k: int = 1
-    moe_capacity_factor: float = 1.5
+    moe_capacity_factor: Optional[float] = 1.5
+    moe_norm_topk_prob: bool = True
     moe_aux_weight: float = 0.01
     moe_router_z_weight: float = 1e-3
     # which mesh axis the expert bank shards over.  "dp" (default) is the
@@ -154,6 +176,19 @@ class TransformerConfig:
             raise ValueError("rope needs an even head dim")
         return self.pos_embedding == "rope"
 
+    def __post_init__(self):
+        if self.norm not in _NORMS:
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.ffn not in ("gelu", "swiglu"):
+            raise ValueError(f"unknown ffn {self.ffn!r}")
+
+    def default_block(self) -> bool:
+        """The block the encoder and the composed pipeline compute."""
+        return (
+            self.norm == "layernorm" and self.ffn == "gelu"
+            and not self.qk_norm and self.tie_head
+        )
+
 
 def _check_axis_compat(cfg) -> None:
     """context_parallel turns the tp axis into the sequence ring —
@@ -194,6 +229,13 @@ def _check_moe_mesh(cfg, mesh) -> None:
         )
     ep = mesh.shape[ep_ax]
     tp = mesh.shape["tp"]
+    if cfg.moe_capacity_factor is None and ep > 1:
+        raise NotImplementedError(
+            f"dropless MoE (moe_capacity_factor=None) with {ep_ax} = {ep}:"
+            " the expert exchange would need a different count a peer, "
+            "which the fixed-count all-to-all does not carry (ROADMAP "
+            "R2(b)); keep every expert on the chip or give a capacity"
+        )
     if cfg.n_experts % ep:
         raise ValueError(
             f"n_experts ({cfg.n_experts}) must divide by {ep_ax} ({ep}) "
@@ -246,6 +288,7 @@ def _mean_over_axes(local, axes: tuple, denom: int):
 # their output dim on tp, row-parallel weights their input dim.
 def param_specs(cfg: TransformerConfig) -> Dict:
     _check_axis_compat(cfg)
+    gated = cfg.ffn == "swiglu"
     if cfg.context_parallel:
         # context parallelism: the tp axis carries the SEQUENCE ring, so
         # every weight is replicated over it (dp still shards the batch)
@@ -253,6 +296,8 @@ def param_specs(cfg: TransformerConfig) -> Dict:
             k: P(None, None) if k[0] == "w" else P(None)
             for k in ("wq", "wk", "wv", "wo", "w1", "w2", "ln1", "ln2")
         }
+        if gated:
+            layer["w3"] = P(None, None)
     else:
         layer = {
             "wq": P(None, "tp"),  # (d_model, d_model/tp): heads sharded
@@ -264,12 +309,20 @@ def param_specs(cfg: TransformerConfig) -> Dict:
             "ln1": P(None),
             "ln2": P(None),
         }
+        if gated:
+            layer["w3"] = P(None, "tp")  # the gate's twin of w1
+    if cfg.qk_norm:
+        # scales of the whole projected q and k: sharded like the
+        # projections' output columns
+        heads = None if cfg.context_parallel else "tp"
+        layer["q_norm"] = P(heads)
+        layer["k_norm"] = P(heads)
     if cfg.n_experts:
         # MoE: the dense FFN pair is replaced by the expert bank — the
         # EXPERT dim shards over the expert axis (cfg.moe_mesh_axis:
         # "dp" welded, or a dedicated "ep"); the router gate is
         # replicated
-        for k_ in ("w1", "w2"):
+        for k_ in ("w1", "w2", "w3"):
             layer.pop(k_, None)
         ep_ax = cfg.moe_mesh_axis
         if cfg.context_parallel:
@@ -281,6 +334,8 @@ def param_specs(cfg: TransformerConfig) -> Dict:
                 "w1": P(ep_ax, None, None),
                 "w2": P(ep_ax, None, None),
             }
+            if gated:
+                layer["moe"]["w3"] = P(ep_ax, None, None)
         else:
             # experts shard over the expert axis AND each expert's d_ff
             # over tp (Megatron column/row split within the expert), so
@@ -291,6 +346,8 @@ def param_specs(cfg: TransformerConfig) -> Dict:
                 "w1": P(ep_ax, None, "tp"),
                 "w2": P(ep_ax, "tp", None),
             }
+            if gated:
+                layer["moe"]["w3"] = P(ep_ax, None, "tp")
     out = {
         # vocab parallelism shards the table's VOCAB rows over tp (the
         # pos table and everything fed by the tp-allreduced lookup stay
@@ -301,6 +358,9 @@ def param_specs(cfg: TransformerConfig) -> Dict:
     }
     if not cfg.uses_rope():
         out["pos"] = P(None, None)
+    if not cfg.tie_head:
+        # the head's VOCAB columns shard over tp as the table's rows do
+        out["head"] = P(None, "tp") if cfg.vocab_parallel else P(None, None)
     return out
 
 
@@ -317,6 +377,14 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
             jax.random.normal(k[1], (cfg.max_seq, cfg.d_model), cfg.dtype)
             * scale
         )
+    if not cfg.tie_head:
+        params["head"] = (
+            jax.random.normal(
+                jax.random.fold_in(k[0], 1), (cfg.d_model, cfg.vocab),
+                cfg.dtype,
+            ) * scale
+        )
+    gated = cfg.ffn == "swiglu"
     d_kv = cfg.kv_heads() * (cfg.d_model // cfg.n_heads)
     for i in range(cfg.n_layers):
         kk = k[2 + 4 * i : 6 + 4 * i]
@@ -336,11 +404,15 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
             "ln1": jnp.ones((cfg.d_model,), cfg.dtype),
             "ln2": jnp.ones((cfg.d_model,), cfg.dtype),
         }
+        if cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((cfg.d_model,), cfg.dtype)
+            layer["k_norm"] = jnp.ones((d_kv,), cfg.dtype)
         if cfg.n_experts:
             from .moe import init_moe_params
 
             layer["moe"] = init_moe_params(
-                kk[2], cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.dtype
+                kk[2], cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.dtype,
+                gated=gated,
             )
         else:
             layer["w1"] = (
@@ -351,6 +423,13 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
                 jax.random.normal(kk[3], (cfg.d_ff, cfg.d_model), cfg.dtype)
                 * scale
             )
+            if gated:
+                layer["w3"] = (
+                    jax.random.normal(
+                        jax.random.fold_in(kk[2], 1),
+                        (cfg.d_model, cfg.d_ff), cfg.dtype,
+                    ) * scale
+                )
         params["layers"].append(layer)
     return params
 
@@ -359,6 +438,34 @@ def _layernorm(x, scale):
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + 1e-5) * scale
+
+
+def _rmsnorm(x, scale, tp_axis=None):
+    """RMSNorm, statistics in f32 and the scale applied in the input's
+    type.  ``tp_axis``: the last dim is a tp shard of the normed width
+    (QK-norm over head-sharded projections), so the mean square is taken
+    over the whole width with one allreduce of a scalar a row."""
+    x32 = x.astype(jnp.float32)
+    ss = jnp.sum(x32 * x32, axis=-1, keepdims=True)
+    width = x.shape[-1]
+    if tp_axis is not None:
+        ss = collectives.allreduce(ss, tp_axis, ReduceFunction.SUM)
+        width = width * jax.lax.axis_size(tp_axis)
+    return (x32 * jax.lax.rsqrt(ss / width + 1e-5)).astype(x.dtype) * scale
+
+
+_NORMS = {"layernorm": _layernorm, "rmsnorm": _rmsnorm}
+
+
+def _qk_norm(q, k, lp, tp_axis):
+    """A layer with ``q_norm``/``k_norm`` scales RMS-normalises the whole
+    projected q and k (every head at once, over ``tp_axis`` where the
+    heads are sharded) before the split into heads."""
+    if "q_norm" not in lp:
+        return q, k
+    return (
+        _rmsnorm(q, lp["q_norm"], tp_axis), _rmsnorm(k, lp["k_norm"], tp_axis)
+    )
 
 
 def _vp_active(cfg, tp_axis) -> bool:
@@ -439,12 +546,16 @@ def _token_nll(logits, targets) -> jax.Array:
     ).squeeze(-1)
 
 
-def _lm_logits(x, embed, cfg, tp_axis, gather: bool = True) -> jax.Array:
-    """Tied LM head ``x @ embed.T``.  Under vocab parallelism the product
+def _lm_logits(x, params, cfg, tp_axis, gather: bool = True) -> jax.Array:
+    """LM head: tied ``x @ embed.T``, or ``x @ head`` where the tree has
+    a head of its own.  Under vocab parallelism the product
     is VOCAB-SHARDED ``(..., vocab/tp)``; ``gather=True`` (the forward()
     API contract) reassembles the full vocab axis, ``gather=False``
     leaves the shards for the fused loss."""
-    z = x @ embed.T
+    if "head" in params:
+        z = x @ params["head"]
+    else:
+        z = x @ params["embed"].T
     if _vp_active(cfg, tp_axis) and gather:
         z = collectives.allgather_invariant(z, tp_axis, axis=z.ndim - 1)
     return z
@@ -567,8 +678,17 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True):
     return out.reshape(B, H, T, hd)
 
 
+def _ffn_hidden(h, lp):
+    """The dense FFN's hidden activation: gated SiLU where the layer has
+    a ``w3``, GELU otherwise."""
+    if "w3" in lp:
+        return jax.nn.silu(h @ lp["w1"]) * (h @ lp["w3"])
+    return jax.nn.gelu(h @ lp["w1"])
+
+
 def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
-         moe_no_drop=False, reduce_fn=None, fanout_fn=None):
+         moe_no_drop=False, reduce_fn=None, fanout_fn=None,
+         norm=_layernorm):
     """The block's MLP half (shared by train and decode paths): ln2 ->
     column-parallel up, row-parallel down -> tp-allreduce, residual.
 
@@ -578,7 +698,7 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
     outputs return the same way (models/moe.py).  ``with_aux=True``
     (training) additionally returns the router health terms; serving
     paths leave it off."""
-    h = _layernorm(x, lp["ln2"])
+    h = norm(x, lp["ln2"])
     if "moe" in lp:
         from .moe import moe_ffn
 
@@ -586,18 +706,18 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
         # capacity_factor there could drop a token the full forward
         # would have kept (decode-vs-forward divergence), so serving
         # uses the no-drop capacity (cf = E covers even an all-tokens-
-        # to-one-expert step at trivial memory)
-        cf = (
-            float(moe_cfg.n_experts)
-            if moe_no_drop
-            else moe_cfg.moe_capacity_factor
-        )
+        # to-one-expert step at trivial memory); a dropless config
+        # (None) drops nothing anywhere
+        cf = moe_cfg.moe_capacity_factor
+        if moe_no_drop and cf is not None:
+            cf = float(moe_cfg.n_experts)
         out = moe_ffn(
             h, lp["moe"], ep_axis=ep_axis,
             capacity_factor=cf,
             k=moe_cfg.moe_top_k,
             return_aux=with_aux,
             tp_axis=tp_axis,
+            renormalize=moe_cfg.moe_norm_topk_prob,
         )
         if with_aux:
             y, aux = out
@@ -605,7 +725,7 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
         return x + out
     if fanout_fn is not None and tp_axis is not None:
         h = fanout_fn(h, tp_axis)  # see _block: the w1 fan-out point
-    partial_f = jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
+    partial_f = _ffn_hidden(h, lp) @ lp["w2"]
     if tp_axis is not None:
         if reduce_fn is None:
             partial_f = collectives.allreduce(
@@ -617,7 +737,8 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
 
 
 def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
-                  rope_base=None, positions=None, attention_fn=None):
+                  rope_base=None, positions=None, attention_fn=None,
+                  tp_axis=None):
     """Column-parallel attention on a full-sequence activation: returns
     the row-parallel PARTIAL output (pre-reduction) and the (k, v) head
     tensors (B, Hkv_local, T, hd) for KV-cache prefill.  The kv head
@@ -630,9 +751,10 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     ``positions`` overrides the rope positions (context parallelism
     passes its shard's global token positions); ``attention_fn``
     replaces the dense :func:`_attention` lowering (context parallelism
-    passes the striped ring)."""
+    passes the striped ring).  ``tp_axis`` is for :func:`_qk_norm`."""
     B, T, _ = h.shape
     q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]  # column-parallel
+    q, k = _qk_norm(q, k, lp, tp_axis)
     hd = q.shape[-1] // n_heads_local
     n_kv_local = k.shape[-1] // hd
     heads = lambda t, n: t.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
@@ -644,10 +766,11 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
         tables = _rope_tables(pos, hd // 2, rope_base)
         q = _rope_rotate(q, tables)
         k = _rope_rotate(k, tables)
-    if attention_fn is not None:
-        attn = attention_fn(q, k, v)
-    else:
-        attn = _attention(q, k, v, impl=attn_impl, causal=causal)
+    with device_scope("accl.attn::core"):
+        if attention_fn is not None:
+            attn = attention_fn(q, k, v)
+        else:
+            attn = _attention(q, k, v, impl=attn_impl, causal=causal)
     attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
     return attn @ lp["wo"], (k, v)
 
@@ -655,7 +778,7 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
 def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
            attn_impl="naive", causal=True, rope_base=None,
            ep_axis=None, moe_cfg=None, with_aux=False,
-           reduce_fn=None, fanout_fn=None):
+           reduce_fn=None, fanout_fn=None, norm=_layernorm):
     """One transformer block on tp-sharded weights.  ``lp['wqkv']`` etc. are
     the *local shards*; the tp-allreduce after each row-parallel matmul is
     the reference's fused-allreduce hot path in model form.
@@ -672,20 +795,20 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
         reduce_fn = lambda v, ax: collectives.allreduce(
             v, ax, ReduceFunction.SUM
         )
-    h = _layernorm(x, lp["ln1"])
+    h = norm(x, lp["ln1"])
     if fanout_fn is not None and tp_axis is not None:
         # replicated h fans out into the tp-sharded q/k/v matmuls: the
         # manual-backward mode marks the fan-out so its transpose (a tp
         # psum of the branch cotangents) lands here and nowhere else
         h = fanout_fn(h, tp_axis)
     partial_o, kv = _attn_partial(
-        h, lp, n_heads_local, attn_impl, causal, rope_base
+        h, lp, n_heads_local, attn_impl, causal, rope_base, tp_axis=tp_axis
     )
     if tp_axis is not None:
         partial_o = reduce_fn(partial_o, tp_axis)
     x = x + partial_o
     out = _mlp(x, lp, tp_axis, ep_axis, moe_cfg, with_aux,
-               reduce_fn=reduce_fn, fanout_fn=fanout_fn)
+               reduce_fn=reduce_fn, fanout_fn=fanout_fn, norm=norm)
     return (out, kv) if return_kv else out
 
 
@@ -707,7 +830,7 @@ def _cp_block_k(t_local: int, attn_impl: str):
 
 
 def _block_cp(x, lp, n_heads, cp_axis, rope_base=None, attn_impl="auto",
-              ep_axis=None, moe_cfg=None, with_aux=False):
+              ep_axis=None, moe_cfg=None, with_aux=False, norm=_layernorm):
     """Context-parallel block: ``x`` is (B, T/cp, D), this rank's STRIPED
     sequence shard over ``cp_axis``; weights are full (replicated over
     the axis).  QKV/MLP matmuls are purely local; attention is striped
@@ -730,17 +853,18 @@ def _block_cp(x, lp, n_heads, cp_axis, rope_base=None, attn_impl="auto",
     ring = lambda q, k, v: striped_attention(
         q, k, v, cp_axis, causal=True, block_k=block_k
     )
-    h = _layernorm(x, lp["ln1"])
+    h = norm(x, lp["ln1"])
     o, _ = _attn_partial(
         h, lp, n_heads, rope_base=rope_base,
         positions=positions, attention_fn=ring,
     )
     x = x + o
-    return _mlp(x, lp, None, ep_axis, moe_cfg, with_aux)
+    return _mlp(x, lp, None, ep_axis, moe_cfg, with_aux, norm=norm)
 
 
 def _block_sp(x_sp, lp, n_heads_local, tp_axis, return_kv=False,
-              attn_impl="naive", causal=True, rope_base=None):
+              attn_impl="naive", causal=True, rope_base=None,
+              norm=_layernorm):
     """Sequence-parallel block (Megatron-SP): ``x_sp`` is (B, T/tp, D),
     sequence-sharded over ``tp``.  All-gather restores the full sequence
     in front of each column-parallel matmul; the row-parallel reduction
@@ -752,18 +876,19 @@ def _block_sp(x_sp, lp, n_heads_local, tp_axis, return_kv=False,
     FULL-sequence per local head (B, H_local, T, hd), because attention
     inside the block already runs on the gathered sequence; this is the
     sequence-parallel prefill path of the KV-cache decode."""
-    h = _layernorm(x_sp, lp["ln1"])
+    h = norm(x_sp, lp["ln1"])
     h_full = collectives.allgather(h, tp_axis, axis=1)
     partial_o, kv = _attn_partial(
-        h_full, lp, n_heads_local, attn_impl, causal, rope_base
+        h_full, lp, n_heads_local, attn_impl, causal, rope_base,
+        tp_axis=tp_axis,
     )
     o_sp = collectives.reduce_scatter(
         partial_o, tp_axis, tiled=True, axis=1
     )
     x_sp = x_sp + o_sp
-    h = _layernorm(x_sp, lp["ln2"])
+    h = norm(x_sp, lp["ln2"])
     h_full = collectives.allgather(h, tp_axis, axis=1)
-    partial_f = jax.nn.gelu(h_full @ lp["w1"]) @ lp["w2"]
+    partial_f = _ffn_hidden(h_full, lp) @ lp["w2"]
     f_sp = collectives.reduce_scatter(
         partial_f, tp_axis, tiled=True, axis=1
     )
@@ -803,7 +928,7 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
         cp_kw = dict(
             n_heads=cfg.n_heads, cp_axis=tp_axis,
             rope_base=cfg.rope_base if cfg.uses_rope() else None,
-            attn_impl=cfg.attention,
+            attn_impl=cfg.attention, norm=_NORMS[cfg.norm],
         )
         if cfg.n_experts:
             cp_kw["ep_axis"] = cfg.moe_mesh_axis
@@ -827,6 +952,7 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
         n_heads_local=heads_local, tp_axis=tp_axis,
         attn_impl=cfg.attention, causal=causal,
         rope_base=cfg.rope_base if cfg.uses_rope() else None,
+        norm=_NORMS[cfg.norm],
     )
     if return_kv:
         kw["return_kv"] = True
@@ -856,25 +982,34 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
 def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):
     """Embed -> blocks -> final layernorm.  Returns ``(x, layout, aux)``:
     ``layout`` flags how ``x`` is sequence-sharded ("" / "sp" / "cp");
-    ``aux`` is None for dense FFNs or the layer-summed MoE router health
-    terms ({"load_balance", "router_z"}) — shared by forward() and the
-    fused loss."""
+    ``aux`` is None for dense FFNs or the MoE router's terms: the health
+    terms summed over layers ({"load_balance", "router_z"}, shared by
+    forward() and the fused loss) and its counters a layer
+    ("expert_tokens" (L, E), "dropped" (L,); only the router probe reads
+    them, elsewhere they are dead code)."""
     x = _embed_tokens(params, tokens, cfg, tp_axis)
     x, block, sp = _enter_block_layout(x, cfg, tp_axis, tp_size)
     if cfg.remat:
         block = jax.checkpoint(block)
+    norm = _NORMS[cfg.norm]
     if not cfg.n_experts:
         for lp in params["layers"]:
             x = block(x, lp)
-        return _layernorm(x, params["ln_f"]), sp, None
+        return norm(x, params["ln_f"]), sp, None
     lb = jnp.zeros((), jnp.float32)
     rz = jnp.zeros((), jnp.float32)
+    counts, dropped = [], []
     for lp in params["layers"]:
         x, aux = block(x, lp)
         lb = lb + aux["load_balance"]
         rz = rz + aux["router_z"]
-    aux = {"load_balance": lb, "router_z": rz}
-    return _layernorm(x, params["ln_f"]), sp, aux
+        counts.append(aux["expert_tokens"])
+        dropped.append(aux["dropped"])
+    aux = {
+        "load_balance": lb, "router_z": rz,
+        "expert_tokens": jnp.stack(counts), "dropped": jnp.stack(dropped),
+    }
+    return norm(x, params["ln_f"]), sp, aux
 
 
 def forward(params, tokens, cfg: TransformerConfig, tp_axis=None, tp_size=1):
@@ -890,14 +1025,14 @@ def forward(params, tokens, cfg: TransformerConfig, tp_axis=None, tp_size=1):
     of replicating full-sequence logits on every ring rank."""
     x, sp, _ = _final_hidden(params, tokens, cfg, tp_axis, tp_size)
     if sp == "cp":
-        return _lm_logits(x, params["embed"], cfg, tp_axis)
+        return _lm_logits(x, params, cfg, tp_axis)
     if sp and _vp_active(cfg, tp_axis):
         # vocab-parallel head under SP: gather the sequence FIRST (every
         # rank needs every row to score its vocab shard — the Megatron
         # layout; gathering hidden is vocab/d_model cheaper than logits)
         x = collectives.allgather_invariant(x, tp_axis, axis=1)
         sp = False
-    logits = _lm_logits(x, params["embed"], cfg, tp_axis)
+    logits = _lm_logits(x, params, cfg, tp_axis)
     if sp:
         # leave the sharded regime: gather the sequence back (invariant
         # form — the caller may claim tp-replicated outputs)
@@ -924,7 +1059,7 @@ def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1):
     _check_axis_compat(cfg)
     if _cp_active(cfg, tp_axis):
         x, _, aux = _final_hidden(params, tokens, cfg, tp_axis, tp_size)
-        z = _lm_logits(x, params["embed"], cfg, tp_axis, gather=False)
+        z = _lm_logits(x, params, cfg, tp_axis, gather=False)
         nll = _token_nll(z, targets)
         local = nll.mean()
         if aux is not None:
@@ -942,7 +1077,7 @@ def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1):
             # one shared trunk pass: hidden AND the router aux terms
             # (moe rejects sp/cp above, so x is the full sequence)
             x, _, aux = _final_hidden(params, tokens, cfg, tp_axis, tp_size)
-            logits = _lm_logits(x, params["embed"], cfg, tp_axis)
+            logits = _lm_logits(x, params, cfg, tp_axis)
             nll = _token_nll(logits, targets).mean()
             return nll + _moe_penalty(cfg, aux)
         logits = forward(params, tokens, cfg, tp_axis, tp_size)
@@ -958,7 +1093,7 @@ def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1):
         # costs vocab/d_model LESS wire+memory than gathering logits —
         # the saving the fused loss exists for.
         x = collectives.allgather_invariant(x, tp_axis, axis=1)
-    z = _lm_logits(x, params["embed"], cfg, tp_axis, gather=False)
+    z = _lm_logits(x, params, cfg, tp_axis, gather=False)
     # f32 softmax statistics (bf16 logits overflow exp quickly)
     z = z.astype(jnp.float32)
     tgt = targets
@@ -999,7 +1134,8 @@ def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1):
 
 
 def _block_decode(x_t, lp, cache_k, cache_v, pos, n_heads_local, tp_axis,
-                  rope_tables=None, ep_axis=None, moe_cfg=None):
+                  rope_tables=None, ep_axis=None, moe_cfg=None,
+                  norm=_layernorm):
     """One block for a single decode position: write this step's k/v into
     the cache at ``pos`` (dynamic_update_slice keeps shapes static under
     jit/scan), attend over positions <= pos, same tp collectives as the
@@ -1009,8 +1145,9 @@ def _block_decode(x_t, lp, cache_k, cache_v, pos, n_heads_local, tp_axis,
     heads, the factor-G serving-memory saving that motivates GQA; query
     heads group onto kv head h // G in the einsum."""
     B, _, D = x_t.shape
-    h = _layernorm(x_t, lp["ln1"])
+    h = norm(x_t, lp["ln1"])
     q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+    q, k = _qk_norm(q, k, lp, tp_axis)
     hd = q.shape[-1] // n_heads_local
     n_kv_local = k.shape[-1] // hd
     rs = lambda t, n: t.reshape(B, 1, n, hd).transpose(0, 2, 1, 3)
@@ -1048,7 +1185,7 @@ def _block_decode(x_t, lp, cache_k, cache_v, pos, n_heads_local, tp_axis,
         partial_o = collectives.allreduce(partial_o, tp_axis, ReduceFunction.SUM)
     x = x_t + partial_o
     return (
-        _mlp(x, lp, tp_axis, ep_axis, moe_cfg, moe_no_drop=True),
+        _mlp(x, lp, tp_axis, ep_axis, moe_cfg, moe_no_drop=True, norm=norm),
         cache_k,
         cache_v,
     )
@@ -1091,13 +1228,13 @@ def prefill(
         ck = jnp.zeros(shape, x.dtype).at[:, :, :T].set(k)
         cv = jnp.zeros(shape, x.dtype).at[:, :, :T].set(v)
         caches.append((ck, cv))
-    x = _layernorm(x, params["ln_f"])
+    x = _NORMS[cfg.norm](x, params["ln_f"])
     last = x[:, -1]
     if sp:
         # the prompt's final position lives on the LAST sequence shard;
         # broadcast its activation to the gang for the shared logits
         last = collectives.bcast(last, tp_axis, root=tp_size - 1)
-    return _lm_logits(last, params["embed"], cfg, tp_axis), caches
+    return _lm_logits(last, params, cfg, tp_axis), caches
 
 
 def _select_token(logits, key, temperature: float, top_k: Optional[int]):
@@ -1186,10 +1323,11 @@ def generate(
                     if (tp_axis and cfg.n_experts) else None
                 ),
                 moe_cfg=cfg if cfg.n_experts else None,
+                norm=_NORMS[cfg.norm],
             )
             new_caches.append((ck, cv))
-        x = _layernorm(x, params["ln_f"])
-        logits = _lm_logits(x[:, 0], params["embed"], cfg, tp_axis)
+        x = _NORMS[cfg.norm](x, params["ln_f"])
+        logits = _lm_logits(x[:, 0], params, cfg, tp_axis)
         key, sub = jax.random.split(key)
         nxt = _select_token(logits, sub, temperature, top_k).astype(tok.dtype)
         return (new_caches, nxt, pos + 1, key), tok
@@ -1354,6 +1492,37 @@ def make_sharded_forward(cfg: TransformerConfig, mesh: Mesh):
             )
         )
     return fn, partial(_shard_params, specs=specs, mesh=mesh)
+
+
+def make_sharded_router_probe(cfg: TransformerConfig, mesh: Mesh):
+    """The MoE router's counters from the program's own forward path
+    (same blocks, same dispatch as :func:`make_sharded_forward`): a
+    jitted ``fn(params, tokens) -> {"expert_tokens": (L, E) routing
+    entries sent to each expert of each layer, "dropped": (L,) entries
+    past capacity}``, summed over the data axes.  A probe beside the
+    step, so that the train step keeps its ``(params, loss)``."""
+    if not cfg.n_experts or cfg.context_parallel:
+        raise ValueError("the router probe needs an MoE config without cp")
+    _check_moe_mesh(cfg, mesh)
+    tp = mesh.shape["tp"]
+    axes = _data_axes(cfg, mesh)
+
+    def probe(params, tokens):
+        aux = _final_hidden(params, tokens, cfg, "tp", tp)[2]
+        out = {k: aux[k] for k in ("expert_tokens", "dropped")}
+        for a in axes:
+            out = collectives.allreduce(out, a, ReduceFunction.SUM)
+        return out
+
+    return jax.jit(
+        shard_map(
+            probe,
+            mesh=mesh,
+            in_specs=(param_specs(cfg), P(_batch_entry(axes), None)),
+            out_specs=P(),
+            check_vma=False,
+        )
+    )
 
 
 def _reject_untrainable_attention(cfg) -> None:

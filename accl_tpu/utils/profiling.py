@@ -62,6 +62,27 @@ Only the ``accl::`` names are read by the benchmark's ``engine_span_us``,
 so that those keep reading what they read (``perfbench/stage_spans.py``
 reads the stages).
 
+Device scopes (:func:`device_scope`), inside the jitted train step and
+forward.  The name lands in every covered instruction's ``op_name``
+(backward ops as ``transpose(jvp(<scope>))``), which the COMPILED
+program's text keeps; a v5e trace names its events by instruction and
+drops ``op_name``, so a reader joins the two by instruction name
+(``perfbench/scope_ops.py``).
+
+======================== ==================================================
+``accl.attn::core``      ``models/transformer.py`` ``_attn_partial``: the
+                         attention call (flash kernels, or the XLA forms)
+``accl.moe::route``      ``models/moe.py``, dropless path: router matmul,
+                         float32 softmax, top-k
+``accl.moe::dispatch``   the same: sort of the routing entries by expert,
+                         group sizes, gather of the rows
+``accl.moe::experts``    the same: the three grouped matmuls and the gate
+                         product (the compiler's ``ragged-dot-*`` kernels
+                         carry only that name and are counted here)
+``accl.moe::combine``    the same: unsort, weight by the router
+                         probability, sum a token's k results
+======================== ==================================================
+
 jax is imported LAZILY: the emulator/native tiers (and the telemetry
 plane's exporters) run in jax-free processes, and pulling a device
 runtime into them just to name a span would be a side effect a tracing
