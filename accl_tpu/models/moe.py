@@ -18,14 +18,15 @@ two dispatches:
   (the Switch-Transformer formulation).  Every multi-chip path uses it:
   the ``(E, cap, D)`` buffer is what the fixed-count all-to-all carries.
 * **dropless** (``capacity_factor=None``): the N*k routing entries are
-  sorted by expert and the experts run as one grouped (ragged) matmul
-  over the sorted rows with per-expert group sizes
-  (``jax.lax.ragged_dot``; on a TPU the compiler lowers it to its own
-  grouped-matmul Mosaic kernels — ``ragged-dot-*`` in the device trace —
-  in all three forms autodiff needs), then unsorted and combined.  No
-  entry is ever dropped and no buffer scales with E.  One chip's experts
-  only: across an expert axis the exchange needs a different count a
-  peer (ROADMAP R2(b)).
+  sorted by expert and the experts run as one grouped matmul over the
+  sorted rows with per-expert group sizes
+  (``ops.pallas.grouped_matmul``: tiled Pallas kernels after jax's
+  megablox, one each for the forward, the input's and the weights'
+  gradient, tiles from the shapes; interpreted off the TPU like every
+  kernel of that tier), then unsorted and combined.  No entry is ever
+  dropped and no buffer scales with E.  One chip's experts only: across
+  an expert axis the exchange needs a different count a peer (ROADMAP
+  R2(b)).
 
 Experts are two-matrix GELU FFNs (``w1``, ``w2``) or, with a ``w3`` in
 the parameters, gated-SiLU FFNs ``(silu(x w1) * (x w3)) w2``.
@@ -39,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.pallas.grouped_matmul import grouped_matmul
 from ..utils.profiling import device_scope
 
 
@@ -107,7 +109,7 @@ def _dropless_experts(flat, params, topk_e, topk_p, tp_axis):
         sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
         rows = _take_rows(flat, order, back, k)           # (N*k, D) by expert
     with device_scope("accl.moe::experts"):
-        grouped = lambda a, w: lax.ragged_dot(a, w, sizes)
+        grouped = lambda a, w: grouped_matmul(a, w, sizes)
         h = _expert_act(
             grouped(rows, params["w1"]), params, partial(grouped, rows)
         )

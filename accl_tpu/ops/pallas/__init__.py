@@ -8,6 +8,12 @@ TPU kernels.
 * ``ring`` — the firmware's segmented ring collectives as single Pallas
   kernels whose hops are Mosaic remote DMAs over ICI, with slot-ack flow
   control (the RX-buffer release protocol).
+* ``attention`` — flash attention (forward and backward) and ring
+  attention whose K/V blocks rotate over ICI inside the kernel.
+* ``grouped_matmul`` — rows grouped by expert against one matrix a group
+  (the dropless MoE experts): three tiled kernels after jax's megablox
+  (forward, the input's and the weights' gradient) behind one
+  ``custom_vjp``, tiles chosen from the shapes for each form.
 * ``cmdring`` — the device-resident command ring (the CCLO run-loop
   analog): host-side slot encoder + the sequencer program that decodes
   slots on device and executes a whole refill window under one
@@ -32,6 +38,7 @@ from .attention import flash_attention  # noqa: F401
 from .alltoall import alltoall as alltoall_kernel  # noqa: F401
 from .combine import combine  # noqa: F401
 from .compression import cast, dequantize_int8, quantize_int8  # noqa: F401
+from .grouped_matmul import grouped_matmul  # noqa: F401
 from .put import fused_shift  # noqa: F401
 from .ring import (  # noqa: F401
     int8_allreduce,

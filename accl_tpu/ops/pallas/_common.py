@@ -50,6 +50,16 @@ def default_interpret(interpret: InterpretArg = None):
     return pltpu.InterpretParams()
 
 
+def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A ``pallas_call`` ``out_shape`` that varies over the union of the
+    operands' varying mesh axes: inside a ``check_vma`` shard_map (the
+    sharded train steps) ``pallas_call`` refuses an output without."""
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    if vma:
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
 def mosaic_rejects(interpret_resolved, *dtypes) -> bool:
     """True when ``interpret_resolved`` (the output of
     :func:`default_interpret`) selects compiled Mosaic and any of

@@ -33,6 +33,7 @@ from ._common import (
     ack_gate,
     ack_release,
     default_interpret,
+    out_struct,
     require_mosaic_dtypes,
     neighbor_barrier,
 )
@@ -368,16 +369,6 @@ def _flash_block(T: int, dtype, block: int) -> int:
     return min(max(block // sub * sub, sub), (T + sub - 1) // sub * sub)
 
 
-def _flash_struct(shape, dtype, *ops):
-    """ShapeDtypeStruct inheriting the union of the operands' varying
-    mesh axes — required for pallas_call outputs inside a
-    ``check_vma=True`` shard_map (the sharded train steps)."""
-    vma = frozenset().union(*(jax.typeof(o).vma for o in ops))
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
 def _flash_kv_map(H: int, Hkv: int, blocked: bool = False):
     """Grid index -> flattened K/V head.  For grouped-query attention
     (Hkv < H) q head ``h`` reads kv head ``h // G`` — sharing happens in
@@ -413,7 +404,7 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse):
     vf = v.reshape(B * Hkv, Tp, Dp)
     kv_map = _flash_kv_map(H, Hkv)
 
-    out_shape = [_flash_struct((B * H, Tp, Dp), q.dtype, q, k, v)]
+    out_shape = [out_struct((B * H, Tp, Dp), q.dtype, q, k, v)]
     out_specs = [
         pl.BlockSpec((1, b, Dp), lambda bh, iq: (bh, iq, 0),
                      memory_space=pltpu.VMEM),
@@ -423,7 +414,7 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse):
         # its last two dims EQUAL to the array's — the only tile shape
         # Mosaic accepts for a lane vector shorter than 128
         out_shape.append(
-            _flash_struct((B * H, nq, 1, b), jnp.float32, q, k, v)
+            out_struct((B * H, nq, 1, b), jnp.float32, q, k, v)
         )
         out_specs.append(
             pl.BlockSpec((1, 1, 1, b), lambda bh, iq: (bh, iq, 0, 0),
@@ -603,7 +594,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret):
     rows_whole = pl.BlockSpec((1, nq, 1, b), lambda bh, i: (bh, 0, 0, 0),
                               memory_space=pltpu.VMEM)
 
-    grad_struct = _flash_struct((B * H, Tp, Dp), q.dtype, q, k, v, g)
+    grad_struct = out_struct((B * H, Tp, Dp), q.dtype, q, k, v, g)
     dq = pl.pallas_call(
         _flash_bwd_dq_kernel(causal, scale, b, b, nkb, T),
         grid=(B * H, nq),

@@ -77,8 +77,11 @@ drops ``op_name``, so a reader joins the two by instruction name
 ``accl.moe::dispatch``   the same: sort of the routing entries by expert,
                          group sizes, gather of the rows
 ``accl.moe::experts``    the same: the three grouped matmuls and the gate
-                         product (the compiler's ``ragged-dot-*`` kernels
-                         carry only that name and are counted here)
+                         product.  The grouped matmuls are the Pallas
+                         kernels of ``ops/pallas/grouped_matmul.py``, each
+                         form under a plain nested scope of its own:
+                         ``gmm_fwd``, ``gmm_dlhs`` (the input's gradient),
+                         ``gmm_drhs`` (the weights')
 ``accl.moe::combine``    the same: unsort, weight by the router
                          probability, sum a token's k results
 ======================== ==================================================
