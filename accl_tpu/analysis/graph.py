@@ -352,23 +352,23 @@ _CMDRING_CANONICAL_NAMES = frozenset((
 
 #: modules that encode/decode slots (relative to the accl_tpu root)
 _CMDRING_MODULES = (
-    "cmdring.py",            # host half: slot codec + mailbox protocol
-    "ops/pallas/cmdring.py",  # device half: both sequencer lowerings
+    "cmdring.py",            # host half: slot codec + window shape
+    "ops/cmdring.py",        # device half: decode loop + window program
     "backends/xla/cmdring.py",  # engine half: sessions + refills
 )
 
-#: the module holding the decode loop both lowerings share — it must
-#: reference every executable opcode (the cross-file presence check)
-_CMDRING_DECODE_MODULE = "ops/pallas/cmdring.py"
+#: the module holding the decode loop — it must reference every
+#: executable opcode (the cross-file presence check)
+_CMDRING_DECODE_MODULE = "ops/cmdring.py"
 
 #: the shared device-side wire-lane module: its literal ``WIRE_LANES``
 #: table must cover every dtype constants.WIRE_LANE_DTYPES registers
 _WIRE_LANE_MODULE = "ops/wire.py"
 
-#: the decode module's two sequencer lowerings: EACH must route its
-#: wire cast through the shared lane machinery (a wire value only one
-#: lowering decodes is a finding — the quantized-wire cross-check)
-_CMDRING_LOWERING_FUNCS = ("_decode_slot_xla", "_pallas_windows")
+#: the decode loop's per-slot function: it must route its wire cast
+#: through the shared lane machinery (a wire value decoded privately
+#: is a finding — the quantized-wire cross-check)
+_CMDRING_DECODE_FUNC = "_decode_slot_xla"
 
 #: names that constitute "routing through the shared lane machinery":
 #: the roundtrip helper, or the cast+scaled lane pair it is built from
@@ -482,8 +482,7 @@ def _wire_lanes_table(src: SourceFile):
 
 
 def _func_wire_refs(src: SourceFile, fn_name: str):
-    """(found_fn, helper names referenced) for one lowering function:
-    every ``X.helper`` / bare ``helper`` reference inside its body."""
+    """(found_fn, helper names referenced) for one function: every ``X.helper`` / bare ``helper`` reference inside its body."""
     for node in src.tree.body:
         if isinstance(node, ast.FunctionDef) and node.name == fn_name:
             refs = set()
@@ -535,10 +534,9 @@ def check_cmdring_slot_layout(sources: List[SourceFile]) -> List[Finding]:
     * every executable opcode (non-NOP/HALT) must appear as a value of
       the ``CMDRING_OPCODES`` Operation map (the engine's eligibility
       table covers the space) AND be referenced by the decode module's
-      shared epilogue (``ops/pallas/cmdring.py`` — both lowerings run
-      that one decode loop, so presence there is presence in both):
-      the cross-file guarantee that growing the enum without wiring a
-      lowering fails the tree, not a workload."""
+      epilogue (``ops/cmdring.py``): the cross-file guarantee that
+      growing the enum without wiring the decode loop fails the tree,
+      not a workload."""
     root = package_root()
     findings: List[Finding] = []
     consts = None
@@ -562,34 +560,34 @@ def check_cmdring_slot_layout(sources: List[SourceFile]) -> List[Finding]:
     fields, slot_words = _cmdring_table(consts)
     opcodes, mapped, map_line = _cmdring_opcodes(consts)
     # quantized-wire cross-check: every REGISTERED wire dtype must be
-    # handled by BOTH decode-loop lowerings.  Handling is proven
-    # structurally: (a) each lowering function routes its wire cast
-    # through the shared lane machinery (ops/wire helpers), so one lane
-    # table serves both; (b) that table covers every registered lane.
-    # A lane only one lowering decodes — or a registered dtype the
-    # shared table misses — fails the tree before it can surface as a
-    # silent workload fallback.
+    # handled by the decode loop.  Handling is proven structurally:
+    # (a) the decode function routes its wire cast through the shared
+    # lane machinery (ops/wire helpers), the one lane table every
+    # compressed program reads; (b) that table covers every registered
+    # lane.  A lane the decode loop handles privately — or a registered
+    # dtype the shared table misses — fails the tree before it can
+    # surface as a silent workload fallback.
     lanes, lanes_line = _wire_lane_dtypes(consts)
     if lanes and decode_mod is not None:
-        for fn_name in _CMDRING_LOWERING_FUNCS:
-            fn_node, refs = _func_wire_refs(decode_mod, fn_name)
-            if fn_node is None:
-                findings.append(Finding(
-                    check="cmdring-slot-layout", path=decode_mod.path,
-                    line=1,
-                    message=f"decode module lost lowering function "
-                            f"{fn_name!r}: the wire-lane cross-check "
-                            "anchors on both lowerings by name",
-                ))
-            elif not refs:
-                findings.append(decode_mod.finding(
-                    "cmdring-slot-layout", fn_node,
-                    f"lowering {fn_name!r} never routes through the "
-                    f"shared wire-lane helpers "
-                    f"({sorted(_WIRE_LANE_HELPERS)}): a wire dtype "
-                    "this lowering decodes privately can diverge from "
-                    "the other lowering's lane",
-                ))
+        fn_name = _CMDRING_DECODE_FUNC
+        fn_node, refs = _func_wire_refs(decode_mod, fn_name)
+        if fn_node is None:
+            findings.append(Finding(
+                check="cmdring-slot-layout", path=decode_mod.path,
+                line=1,
+                message=f"decode module lost its decode function "
+                        f"{fn_name!r}: the wire-lane cross-check "
+                        "anchors on it by name",
+            ))
+        elif not refs:
+            findings.append(decode_mod.finding(
+                "cmdring-slot-layout", fn_node,
+                f"decode function {fn_name!r} never routes through "
+                f"the shared wire-lane helpers "
+                f"({sorted(_WIRE_LANE_HELPERS)}): a wire dtype the "
+                "ring decodes privately can diverge from every other "
+                "compressed path's lane",
+            ))
         if lane_mod is not None:
             table, table_line = _wire_lanes_table(lane_mod)
             if table is None:
@@ -609,7 +607,7 @@ def check_cmdring_slot_layout(sources: List[SourceFile]) -> List[Finding]:
                         message=f"registered wire dtypes {missing} "
                                 "(constants.WIRE_LANE_DTYPES) missing "
                                 "from the shared WIRE_LANES table: "
-                                "both lowerings would fall back on "
+                                "the decode loop would fall back on "
                                 "them",
                     ))
     if opcodes is not None and ringmods:
@@ -644,7 +642,7 @@ def check_cmdring_slot_layout(sources: List[SourceFile]) -> List[Finding]:
                     check="cmdring-slot-layout", path=decode_mod.path,
                     line=1,
                     message=f"decode module never references CmdOpcode "
-                            f"{missing_dec}: both lowerings run this "
+                            f"{missing_dec}: every window runs this "
                             "module's decode loop, so an unreferenced "
                             "opcode is an unimplemented one",
                 ))

@@ -205,7 +205,7 @@ class BaseEngine:
     def on_membership_cutover(self, plan: dict, addresses: tuple = (),
                               comm_ids: tuple = ()) -> None:
         """Engine-side shrink hook: tear down / re-arm per-comm session
-        state over the survivors (ring sessions + mailboxes on the XLA
+        state over the survivors (ring sessions on the XLA
         tier; rx/ledger/retransmit purge + health-strike hygiene on the
         emulator).  ``addresses`` are the evicted peers' transport
         addresses; ``comm_ids`` the communicators that shrank.

@@ -1086,13 +1086,6 @@ class EmuEngine(BaseEngine):
                     return ErrorCode.CONFIG_ERROR
             if key == TuningKey.HIERARCHICAL and int(val) > 1:
                 return ErrorCode.CONFIG_ERROR
-            if key == TuningKey.CMDRING_RUN_WINDOWS:
-                from ...constants import CMDRING_MAX_RUN_WINDOWS
-
-                if int(val) > CMDRING_MAX_RUN_WINDOWS:
-                    return ErrorCode.CONFIG_ERROR
-            if key == TuningKey.CMDRING_LINGER_US and int(val) > 1_000_000:
-                return ErrorCode.CONFIG_ERROR
             if key in ALGORITHM_TUNING_KEYS:
                 try:
                     algo = AllreduceAlgorithm(int(val))

@@ -338,35 +338,6 @@ class NativeEngine(BaseEngine):
             else:
                 req.complete(ErrorCode.CONFIG_ERROR)
             return req
-        if (
-            options.op == Operation.CONFIG
-            and int(options.cfg_function) == int(ConfigFunction.SET_TUNING)
-            and int(options.cfg_key) in (
-                int(TuningKey.CMDRING_RUN_WINDOWS),
-                int(TuningKey.CMDRING_LINGER_US),
-            )
-        ):
-            # persistent-sequencer posture registers, handled host-side
-            # like pipeline_threshold: the C ABI's register table
-            # predates them, and the ring posture overlay reads the
-            # host mirror anyway — same clamps as every other tier
-            from ...constants import CMDRING_MAX_RUN_WINDOWS
-
-            req = Request(op_name=options.op.name)
-            req.mark_executing()
-            val = int(options.cfg_value)
-            if int(options.cfg_key) == int(TuningKey.CMDRING_RUN_WINDOWS):
-                ok = 0 <= val <= CMDRING_MAX_RUN_WINDOWS
-                name = "cmdring_run_windows"
-            else:
-                ok = 0 <= val <= 1_000_000  # >1s would pin the stream
-                name = "cmdring_linger_us"
-            if ok:
-                self.tuning[name] = val
-                req.complete(ErrorCode.OK)
-            else:
-                req.complete(ErrorCode.CONFIG_ERROR)
-            return req
         mv = self.membership
         if (
             mv is not None and mv.self_evicted

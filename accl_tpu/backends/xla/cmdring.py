@@ -1,44 +1,33 @@
 """The gang engine's command-ring sessions: arm / refill / teardown.
 
-This is the host half of the TPU CCLO analog (the device half is
-``ops/pallas/cmdring.py``, the mailbox protocol ``accl_tpu/cmdring.py``):
-host code that used to *issue* collectives becomes code that *refills a
-queue*.  A warm batched window of N eligible collectives is encoded
-into N slots of the per-communicator ring and handed to the
-**persistent sequencer**:
-
-* first window of a burst: ONE program dispatch arms a sequencer *run*
-  (``dispatches`` counter) and the window rides it;
-* every further window while the run is live: a **mailbox post** — the
-  doorbell is a host memory write, zero program launches
-  (``mailbox_posts`` counter).  A warm sustained stream of K windows
-  therefore executes with 0 re-dispatches after the first
-  (counter-asserted by tests/test_cmdring.py), which is the reference
-  firmware's actual execution model: the run loop lives on the device
-  and the host only writes commands into the FIFO.
+This is the engine half of the TPU CCLO analog (the device half is
+``ops/cmdring.py``, the slot codec ``accl_tpu/cmdring.py``): host code
+that used to *issue* collectives becomes code that *refills a queue*.
+A warm batched window of N eligible collectives is encoded into N slots
+of the per-communicator ring and dispatched as ONE program — the window
+program decodes the slot words on the device, executes every slot and
+returns the per-slot status words.  A refill is a doorbell is a program
+launch: ``refills == doorbells == dispatches``, one host interaction a
+window, on every platform.
 
 The opcode space is the FULL warm set (``constants.CMDRING_OPCODES``):
 allreduce, bcast, reduce-scatter, allgather, alltoall, barrier, and
 matched send/recv pairs; compressed (wire-cast) windows ride the ring
-with the cast lowered into the decode loop, and f16 windows ride the
-f32 compute view.  Everything else — cold calls, oversized payloads,
-host operands, mixed dtypes, unpaired p2p — falls back to the ordinary
-host-dispatch paths with the reason counted in
-:meth:`GangCommandRing.stats`.
+with the cast lowered into the decode loop.  Everything else — cold
+calls, oversized payloads, host operands, mixed dtypes, unpaired p2p —
+falls back to the ordinary host-dispatch paths with the reason counted
+in :meth:`GangCommandRing.stats`.
 
-Lifecycle (the ``run loop`` states of the reference firmware):
+Lifecycle:
 
-* **parked** — no run accepting, no window in flight: the sequencer
-  program has returned and the device stream is free (no spin, no
-  occupancy).  The next refill re-arms with one dispatch.
-* **resident** — a run is live and lingering on the mailbox; a refill
-  is a doorbell write.
+* **parked** — no window in flight: nothing of the ring is on the
+  device (no spin, no occupancy).  The next refill arms with one
+  dispatch.
 * **armed** — windows in flight; the in-flight window
   (``overlap.InflightWindow``) is the refill window: its drain points
-  block on the device status words the sequencer pushed.
-* **teardown/reset** — ``soft_reset`` halts every run's mailbox (the
-  ``HALT`` opcode marks this transition in the slot schema), clears
-  every session and realigns seqn/head at 0.
+  block on the device status words the window program returned.
+* **teardown/reset** — ``soft_reset`` clears every session and realigns
+  seqn/head at 0 (the gang has already drained the in-flight window).
 """
 
 from __future__ import annotations
@@ -51,17 +40,12 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ...cmdring import (
-    SequencerMailbox,
     WindowShape,
     complementary_pair,
-    default_linger_s,
-    default_run_windows,
     encode_fparam,
     encode_slot,
     fused_slot_eligible,
-    register_mailbox,
     ring_widths,
-    unregister_mailbox,
 )
 from ...constants import (
     CMDRING_DEPTH_DEFAULT,
@@ -88,8 +72,8 @@ _F = CMDRING_FIELDS
 #: ring-session circuit breaker (membership plane): window failures
 #: against a dying peer strike the per-comm breaker; OPEN degrades the
 #: comm's dispatch ring -> host (counted ``circuit_open``), HALF_OPEN
-#: re-probes with an INLINE window (one-shot program, no persistent
-#: run to wedge) after the cool-down, success restores the ring.
+#: lets one window through after the cool-down, success restores the
+#: ring.
 CMDRING_BREAKER_COOLDOWN_ENV = "ACCL_CMDRING_COOLDOWN_S"
 CMDRING_BREAKER_COOLDOWN_S = 2.0
 CMDRING_BREAKER_THRESHOLD = 2
@@ -103,7 +87,7 @@ def _env_mode() -> str:
 
 
 #: opcode word chaos poisoning writes into a refill's first slot —
-#: out of every lowering's opcode range, so the sequencer reports
+#: out of the opcode range, so the window program reports
 #: BAD_OP and the slot fails fast with INVALID_OPERATION
 _CHAOS_BAD_OPCODE = 0x7F
 
@@ -141,155 +125,19 @@ class _RingRefillMsg:
         self.seqn = seqn
 
 
-def default_lowering() -> str:
-    """Sequencer lowering: ``"xla"`` on every backend, unless
-    ``ACCL_CMDRING_LOWERING`` names one.
-
-    The Pallas mega-window kernel was the TPU default until it first met
-    a TPU with more than one chip (chip run, PR 21, four v5e, jax 0.9.0):
-    its slot epilogue slices VALUES dynamically inside the kernel and
-    Mosaic has no lowering for that —
-
-        NotImplementedError: Unimplemented primitive in Pallas TPU
-        lowering for KernelType.TC: dynamic_slice
-        (ops/pallas/cmdring.py slot_epilogue, lax.dynamic_slice_in_dim)
-
-    — at every payload tried (64 KiB to 4 MiB a slot, 3 and 8 slots); on
-    ONE chip it compiles only because a world of one returns before the
-    epilogue.  The xla lowering's one-shot inline form compiled and ran
-    the same windows on one chip and on four, so it is what a TPU gets;
-    the Pallas kernel stays reachable by name (the CPU mesh runs it
-    interpreted) and fails loudly on a multi-chip TPU.  Observed, not
-    tuned: D4 decides which lowering stays."""
-    explicit = os.environ.get("ACCL_CMDRING_LOWERING")
-    if explicit in ("xla", "pallas"):
-        return explicit
-    return "xla"
-
-
-def persistent_runs_lower() -> bool:
-    """Whether the xla lowering's PERSISTENT form — the resident
-    ``while_loop`` of ordered ``io_callback`` mailbox pulls — can be
-    used on this backend.  On a TPU it cannot: jax 0.9.0 / libtpu
-    0.0.34 refuse to lower it (chip run, PR 21, one v5e, 64 KiB x 3
-    slots x 4 windows posted ahead)::
-
-        ValueError: Cannot lower jaxpr with verifier errors:
-          'stablehlo.recv' op result 0 - sharding doesn't match tensor
-          rank: 0 != 2   at loc("jit(body)/io_callback" ... run_session)
-
-    so there every xla-lowered window takes the one-shot INLINE form
-    (one program a window, no mailbox), which compiles and runs.  The
-    Pallas lowering never had a persistent form.  Observed, not
-    configured: D4 decides what stays."""
-    import jax
-
-    return jax.default_backend() != "tpu"
-
-
-class _RowAdopter:
-    """Deferred host-row adoption with COLLAPSING: park the result
-    placement on the buffer (the PR 1 lazy-adoption discipline) so a
-    fire-and-forget window never pays the writeback at completion
-    time — and when a later ring window writes the SAME buffer before
-    anyone read it, update the parked row in place instead of chaining
-    another thunk.  A warm stream writing one result buffer K times
-    otherwise replays K chained stores (K device interactions) at
-    first read.  Collapsing is allowed ONLY when no other deferred
-    write slipped in between (the buffer's ``_defer_seq`` proves it) —
-    partial/foreign writes must keep layering in issue order."""
-
-    def __init__(self, gang):
-        self._gang = gang
-        self._lock = threading.Lock()
-        self._gen = 0
-        # (root id, arm generation) -> (buf, row, n): every armed thunk
-        # owns its own generation slot, so an interleaved foreign defer
-        # can never make an EARLIER thunk drain a LATER generation's row
-        self._rows: Dict[tuple, tuple] = {}
-        self._armed: Dict[int, tuple] = {}  # root id -> (defer_seq, gen)
-        # one weakref per tracked root, with an eviction callback: a
-        # buffer dropped with its deferred store unresolved must not
-        # strand its parked row (unbounded growth over a fire-and-
-        # forget loop), and a recycled id(root) must never match a dead
-        # buffer's stale entries (the callback runs before the id can
-        # be reused)
-        self._reaper: Dict[int, object] = {}
-
-    def _track(self, root, key: int) -> None:
-        """Caller holds self._lock."""
-        if key in self._reaper:
-            return
-        import weakref
-
-        def evict(_ref, self=self, key=key):
-            with self._lock:
-                self._reaper.pop(key, None)
-                self._armed.pop(key, None)
-                for k in [k for k in self._rows if k[0] == key]:
-                    self._rows.pop(k, None)
-
-        self._reaper[key] = weakref.ref(root, evict)
-
-    def adopt(self, buf, row: np.ndarray, n: int) -> None:
-        root = buf._root()
-        key = id(root)
-        with root._plock:
-            with self._lock:
-                self._track(root, key)
-                armed = self._armed.get(key)
-                if armed is not None and armed[0] == root._defer_seq:
-                    parked = self._rows.get((key, armed[1]))
-                    # collapse ONLY a rewrite of the SAME destination
-                    # region (same buffer object, same width): two ring
-                    # writes to different slices of one root must
-                    # layer, not replace each other
-                    if (
-                        parked is not None
-                        and parked[0] is buf
-                        and parked[2] == n
-                    ):
-                        self._rows[(key, armed[1])] = (buf, row, n)
-                        return
-                self._gen += 1
-                gen = self._gen
-                self._rows[(key, gen)] = (buf, row, n)
-
-            def place(self=self, key=key, gen=gen):
-                with self._lock:
-                    parked = self._rows.pop((key, gen), None)
-                    if (
-                        self._armed.get(key) is not None
-                        and self._armed[key][1] == gen
-                    ):
-                        self._armed.pop(key, None)
-                if parked is not None:
-                    from .engine import _write_host_result
-
-                    _write_host_result(
-                        parked[0], parked[1], parked[2],
-                        self._gang.interactions,
-                    )
-
-            buf.defer_store(place)
-            with self._lock:
-                self._armed[key] = (root._defer_seq, gen)
-
-
 class _WindowPark:
     """One in-flight refill window's completion record (the status-FIFO
-    side of the mailbox protocol)."""
+    side of the ring)."""
 
-    __slots__ = ("window_id", "event", "status", "results", "plans",
+    __slots__ = ("window_id", "event", "status", "plans",
                  "reqs_per_slot", "calls_per_slot", "t0", "settled",
-                 "slots_info", "form", "logged")
+                 "slots_info", "logged")
 
     def __init__(self, window_id: int, plans, reqs_per_slot,
                  calls_per_slot, t0):
         self.window_id = window_id
         self.event = threading.Event()
         self.status: Optional[np.ndarray] = None
-        self.results: Optional[dict] = None
         self.plans = plans
         self.reqs_per_slot = reqs_per_slot
         self.calls_per_slot = calls_per_slot
@@ -298,69 +146,18 @@ class _WindowPark:
         # done exactly once, by whichever completion path ran
         self.settled = False
         # introspection: per-slot facts for the window log (seqn,
-        # opcode, the issuing call's trace id), the dispatch form
-        # (inline / mailbox), and the logged-once latch
+        # opcode, the issuing call's trace id) and the logged-once latch
         self.slots_info: list = []
-        self.form = "inline"
         self.logged = False
-
-
-class _ResidentRun:
-    """One live sequencer run: its mailbox, the dispatch thread that
-    owns the long-running program, and the failure latch.
-
-    The program is dispatched from a dedicated ``accl-cmdring-run``
-    thread: XLA executes callback-bearing programs synchronously on the
-    dispatching thread (single-device CPU meshes always; others per
-    runtime), and the refill path must never become the run loop — the
-    host's doorbell returns immediately whatever the runtime does.  The
-    thread exists per RUN, not per window: a warm sustained stream of K
-    windows costs one thread spawn, the same amortization as the one
-    dispatch."""
-
-    __slots__ = ("mbox", "mbox_id", "shape", "thread", "failed", "exc")
-
-    def __init__(self, mbox, mbox_id, shape):
-        self.mbox = mbox
-        self.mbox_id = mbox_id
-        self.shape = shape
-        self.thread: Optional[threading.Thread] = None
-        self.failed = threading.Event()
-        self.exc: Optional[BaseException] = None
-
-    def launch(self, mesh, run_windows: int) -> None:
-        from ...ops.pallas import cmdring as devring
-
-        def drive(self=self, mesh=mesh, run_windows=run_windows):
-            try:
-                handle = devring.run_session(
-                    mesh, self.shape, self.mbox_id, run_windows
-                )
-                import jax
-
-                jax.block_until_ready(handle)
-            except BaseException as e:  # surface to every parked window
-                self.exc = e
-                self.failed.set()
-                self.mbox.halt()
-                import traceback
-
-                traceback.print_exc()
-
-        t = threading.Thread(
-            target=drive, name="accl-cmdring-run", daemon=True
-        )
-        self.thread = t
-        t.start()
 
 
 class _RingSession:
     """Per-communicator ring state: the persistent host mirror of the
     device ring (wrap-around is real — slot i of refill k+1 reuses the
-    words of slot i of refill k-depth), the monotone seqn, the live
-    resident run, and the cross-window write-dependency ledger."""
+    words of slot i of refill k-depth), the monotone seqn, and the
+    cross-window write-dependency ledger."""
 
-    __slots__ = ("ring", "head", "seqn", "run", "parks", "written",
+    __slots__ = ("ring", "head", "seqn", "parks", "written",
                  "next_window", "last_status")
 
     def __init__(self, depth: int):
@@ -369,7 +166,6 @@ class _RingSession:
         self.ring = np.zeros((depth, CMDRING_SLOT_WORDS), np.int32)
         self.head = 0
         self.seqn = 0
-        self.run: Optional[_ResidentRun] = None
         self.parks: List[_WindowPark] = []   # outstanding, refill order
         self.written: Dict[int, int] = {}    # result-root id -> pending
         self.next_window = 0
@@ -399,10 +195,6 @@ class GangCommandRing:
             )
         except ValueError:
             self.max_bytes = CMDRING_MAX_PAYLOAD_BYTES
-        self.lowering = default_lowering()
-        self.persistent = persistent_runs_lower()
-        self.run_windows = default_run_windows()
-        self.linger_s = default_linger_s()
         self._lock = threading.Lock()
         self._sessions: Dict[int, _RingSession] = {}
         self._inflight_windows = 0
@@ -410,30 +202,25 @@ class GangCommandRing:
         # the p2p pair's non-source ranks): first use dispatches the
         # zeros program (counted), warm windows reuse with no dispatch
         self._zeros: Dict[tuple, object] = {}
-        # collapsing deferred adoption for mailbox-window results
-        self._adopter = _RowAdopter(gang)
-        self._drained_runs: List[_ResidentRun] = []  # awaiting unregister
         # lifetime counters (telemetry_report()["cmdring"]).  One
         # counter backs both the refill and doorbell stats keys: every
-        # refill rings the doorbell exactly once (as a program dispatch
-        # arming a run, or as a mailbox post into a live one).
+        # refill rings the doorbell exactly once, as a program dispatch.
         self.refills = 0          # refill windows (= doorbells)
-        self.dispatches = 0       # sequencer program launches (runs)
-        self.mailbox_posts = 0    # refills that rode a live run
+        self.dispatches = 0       # window program launches
         self.slots_enqueued = 0   # collectives executed ring-resident
         self.wraps = 0            # head wrapped past the ring depth
-        self.resets = 0           # soft_reset teardowns (runs halted)
+        self.resets = 0           # soft_reset teardowns
         self.max_window = 0
         self.last_window = 0
         self.op_slots: Dict[str, int] = {}  # per-opcode residency
         self.fallbacks: Dict[str, int] = {}
         # introspection plane: a bounded log of completed windows
         # (per-slot seqn/opcode/retcode/trace-id next to the host-side
-        # timing — basis "host": neither lowering can write a device
-        # clock next to the status word on this mesh, and the snapshot
-        # says so instead of faking device time), a window-latency
-        # log2-us histogram, and the facade's failure hook (postmortem
-        # plane: run latch / drain deadline / dispatch error)
+        # timing — basis "host": the window program writes no device
+        # clock next to the status word, and the snapshot says so
+        # instead of faking device time), a window-latency log2-us
+        # histogram, and the facade's failure hook (postmortem plane:
+        # drain deadline / dispatch error)
         from collections import deque as _deque
 
         try:
@@ -446,7 +233,7 @@ class GangCommandRing:
         self.window_latency_sum_us = 0.0
         self.on_failure = None
         # per-comm ring circuit breakers (membership plane): window
-        # failures degrade that comm's dispatch ring -> inline -> host,
+        # failures degrade that comm's dispatch ring -> host,
         # re-probing after a cool-down — a dying peer no longer needs a
         # full soft_reset to get the ring back
         try:
@@ -492,16 +279,10 @@ class GangCommandRing:
 
     @property
     def parked(self) -> bool:
-        """True when no refill window is in flight AND no run still
-        accepts posts — the sequencer program has returned the device
-        stream (no device work, no spin, no occupancy)."""
+        """True when no refill window is in flight — nothing of the
+        ring is on the device (no device work, no spin, no occupancy)."""
         with self._lock:
-            if self._inflight_windows:
-                return False
-            return not any(
-                s.run is not None and s.run.mbox.accepting
-                for s in self._sessions.values()
-            )
+            return not self._inflight_windows
 
     def last_status(self, comm_id: int) -> Optional[np.ndarray]:
         """The most recent window's device status words for a session
@@ -515,37 +296,18 @@ class GangCommandRing:
     def stats(self) -> dict:
         breakers = self._breaker_snapshots()
         with self._lock:
-            live_mboxes = [
-                s.run.mbox for s in self._sessions.values()
-                if s.run is not None
-            ]
-        # mailbox locks taken OUTSIDE the ring lock (leaf discipline,
-        # like the breaker snapshots): queued-but-unpulled refill
-        # windows across every live run — how far the host runs ahead
-        mailbox_depth = sum(m.depth() for m in live_mboxes)
-        with self._lock:
-            resident = any(
-                s.run is not None and s.run.mbox.accepting
-                for s in self._sessions.values()
-            )
-            state = (
-                "armed" if self._inflight_windows
-                else ("resident" if resident else "parked")
-            )
             return {
                 "enabled": self.enabled,
                 "mode": "eager" if self.eager else
                         ("batch" if self.enabled else "off"),
-                "lowering": self.lowering,
-                "persistent": self.persistent,
+                # a constant: perfbench's sweep driver records it as a
+                # fact of every run and is not this module's to edit
+                "lowering": "xla",
                 "depth": self.depth,
-                "run_windows": self.run_windows,
-                "linger_ms": round(self.linger_s * 1e3, 3),
-                "state": state,
+                "state": "armed" if self._inflight_windows else "parked",
                 "refills": self.refills,
                 "doorbells": self.refills,  # every refill rings once
                 "dispatches": self.dispatches,
-                "mailbox_posts": self.mailbox_posts,
                 "slots": self.slots_enqueued,
                 "wraps": self.wraps,
                 "resets": self.resets,
@@ -554,13 +316,6 @@ class GangCommandRing:
                 # filled the ring (1.0 = a full ring per refill)
                 "occupancy": round(self.last_window / self.depth, 3)
                 if self.last_window else 0.0,
-                # sustained occupancy: refill windows served per program
-                # dispatch — the persistence gauge (>1 means the
-                # sequencer survived across refills; the warm target is
-                # the full run budget)
-                "sustained_occupancy": round(
-                    self.refills / self.dispatches, 3
-                ) if self.dispatches else 0.0,
                 "ops": dict(self.op_slots),
                 "fallbacks": dict(self.fallbacks),
                 "chaos_faults": dict(self.chaos_faults),
@@ -576,9 +331,8 @@ class GangCommandRing:
                 },
                 "budgeted_windows": self.budgeted_windows,
                 # introspection plane: the refill-window timeline (per-
-                # slot seqn/opcode/retcode/trace-id, host-basis timing),
-                # the window-latency histogram, and the mailbox depth
-                "mailbox_depth": mailbox_depth,
+                # slot seqn/opcode/retcode/trace-id, host-basis timing)
+                # and the window-latency histogram
                 "windows_logged": self.windows_logged,
                 "window_latency_sum_us": round(
                     self.window_latency_sum_us, 3
@@ -626,8 +380,8 @@ class GangCommandRing:
 
     def breaker_for(self, comm_id: int) -> CircuitBreaker:
         """The comm's ring circuit breaker (membership plane): strikes
-        on window failures, degrades ring -> inline -> host, re-probes
-        after the cool-down."""
+        on window failures, degrades ring -> host, re-probes after the
+        cool-down."""
         with self._lock:
             brk = self._breakers.get(comm_id)
             if brk is None:
@@ -639,54 +393,14 @@ class GangCommandRing:
 
     # -- teardown ------------------------------------------------------------
     def reset(self) -> None:
-        """soft_reset: halt every run's mailbox (the sequencer programs
-        drain their backlog and return — the HALT transition) and
-        realign every session's seqn/head at 0 (the gang has already
-        drained the in-flight window — the full-flush contract)."""
+        """soft_reset: realign every session's seqn/head at 0 (the gang
+        has already drained the in-flight window — the full-flush
+        contract)."""
         with self._lock:
-            runs = [
-                s.run for s in self._sessions.values() if s.run is not None
-            ]
             self._sessions.clear()
             self._inflight_windows = 0
             self.resets += 1
             self._breakers.clear()  # full recovery re-closes the ring
-            self._drained_runs.extend(runs)
-        for run in runs:
-            run.mbox.halt()
-        self._prune_retired_runs()
-
-    def _prune_retired_runs(self) -> None:
-        """Unregister the mailboxes of retired runs whose programs have
-        actually RETURNED (every rank pulled the HALT) — a halted run
-        still draining its queued windows must keep its registry entry,
-        or its pulls degrade to HALT payloads and the queued windows'
-        requests strand (halt() promises queued windows execute)."""
-        with self._lock:
-            keep, drop = [], []
-            for run in self._drained_runs:
-                (drop if run.mbox.drained.is_set() else keep).append(run)
-            self._drained_runs = keep
-        for run in drop:
-            unregister_mailbox(run.mbox_id)
-
-    def halt_sessions(self) -> None:
-        """Engine shutdown: same run teardown as reset, without touching
-        the counters or session mirrors — and the run threads are
-        JOINED (bounded): a sequencer program still draining while the
-        interpreter tears the XLA runtime down aborts the process."""
-        with self._lock:
-            runs = [
-                s.run for s in self._sessions.values() if s.run is not None
-            ]
-            runs += self._drained_runs
-            self._drained_runs = []
-        for run in runs:
-            run.mbox.halt()
-        for run in runs:
-            if run.thread is not None:
-                run.thread.join(timeout=10.0)
-            unregister_mailbox(run.mbox_id)
 
     # -- position planning ---------------------------------------------------
     def _plan_collective(self, comm, calls, lead, mesh):
@@ -806,14 +520,11 @@ class GangCommandRing:
         if npos == 0:
             return False
         # ring circuit breaker (membership plane): an OPEN comm rides
-        # host dispatch until the cool-down; HALF_OPEN probes with the
-        # inline window form (no persistent run to wedge on a dying
-        # peer); a probe success restores the ring
+        # host dispatch until the cool-down; HALF_OPEN lets one window
+        # through, and its success restores the ring
         brk = self.breaker_for(comm.id)
-        verdict = brk.allow()
-        if verdict == CircuitBreaker.OPEN:
+        if brk.allow() == CircuitBreaker.OPEN:
             return self._fallback("circuit_open")
-        probe = verdict == "probe"
         # explicit algorithm registers (global or per-call TuningPlan
         # overlay) selecting a non-XLA lowering keep their meaning: the
         # ring is its own lowering and must not shadow a requested one
@@ -874,14 +585,13 @@ class GangCommandRing:
                     plan = self._plan_fused(comm, calls, lead, plan, fuse)
                     if isinstance(plan, str):
                         return self._fallback(plan)
-            # one payload dtype per window: the pallas lowering packs
-            # every slot into ONE concatenated buffer, where a mixed
-            # window would silently promote
+            # one payload dtype per window (WindowShape.npdt keys the
+            # window program)
             if window_npdt is None:
                 window_npdt = plan["npdt"]
             elif plan["npdt"] != window_npdt:
                 return self._fallback("mixed_dtype")
-            # all operands assemble BEFORE dispatch/post: a position
+            # all operands assemble BEFORE dispatch: a position
             # reading an earlier position's result would see pre-window
             # bytes — only the sequential path orders such chains
             for call in calls:
@@ -907,8 +617,7 @@ class GangCommandRing:
         # windows of at most `depth` slots — clamped to the comm's QoS
         # slot budget when one is configured (the flooder pays extra
         # doorbells; unbudgeted tenants keep full windows): each window
-        # is one refill (doorbell) — a program dispatch only when no
-        # run is live
+        # is one refill (doorbell), one program dispatch
         with self._lock:
             budget = self._slot_budgets.get(comm.id)
         eff_depth = min(self.depth, budget) if budget else self.depth
@@ -923,7 +632,7 @@ class GangCommandRing:
             ]
             try:
                 self._dispatch_window(
-                    comm, mesh, window, reqs_per_slot, t0, probe=probe
+                    comm, mesh, window, reqs_per_slot, t0
                 )
             except Exception:
                 # this window's dispatch failed: fail ITS slots and the
@@ -935,7 +644,7 @@ class GangCommandRing:
                 traceback.print_exc()
                 brk.record_failure("dispatch_error")
                 # postmortem plane: a failed window DISPATCH is a ring
-                # failure too (the latch path covers in-flight wedges)
+                # failure too (on_error covers in-flight failures)
                 if self.on_failure is not None:
                     try:
                         self.on_failure(comm.id, "dispatch_error")
@@ -985,7 +694,7 @@ class GangCommandRing:
         words[_F["wire"]] = wire
         # quantized wire plane: the call's SR seed rides the flags word
         # as slot DATA (rank-mixed inside the decode loop) — seed churn
-        # on a warm compressed stream never recompiles the sequencer
+        # on a warm compressed stream never recompiles the window
         words[_F["flags"]] = int(getattr(lead, "wire_seed", 0)) & 0x7FFFFFFF
         # fused compute slots: the epilogue scalar rides the fparam
         # word Q16.16; an attn-hop slot's hop OFFSET rides the peer
@@ -1030,47 +739,13 @@ class GangCommandRing:
             npdt = plan["npdt"]
         return WindowShape(len(window), in_ws, out_ws, wires, npdt)
 
-    def _payload_rows(self, comm, window, shape: WindowShape):
-        """Per-slot per-rank operand rows — the refill's command
-        payload, as VIEWS of the committed device arrays (zero-copy
-        snapshots: jax arrays are immutable and later stores swap
-        pointers, so what the mailbox holds can never mutate; the only
-        copy on the wire is the pull's host→device move).  ``None``
-        rows (dummy operands, barrier tokens, the p2p pair's non-source
-        ranks) pull as zeros."""
-        payload = []
-        for k, (calls, lead, plan) in enumerate(window):
-            w = shape.in_ws[k]
-            if plan["op"] == Operation.BARRIER:
-                payload.append(None)
-                continue
-            src_only = plan.get("p2p")
-            rows = []
-            for r, call in enumerate(calls):
-                buf = call.op0
-                if (
-                    (src_only is not None and r != src_only[0])
-                    or buf is None
-                    or buf.is_dummy
-                ):
-                    rows.append(None)
-                    continue
-                view = np.asarray(buf.device_view()[:w])
-                if view.shape[0] < w:
-                    padded = np.zeros((w,), shape.npdt)
-                    padded[: view.shape[0]] = view
-                    view = padded
-                rows.append(view)
-            payload.append(rows)
-        return payload
-
     def _wait_written_dependencies(self, session: _RingSession,
                                    window) -> None:
         """Cross-window ordering: a refill whose OPERAND was written by
         a still-in-flight earlier window must wait for that window's
-        completion before snapshotting payload bytes (within one batch
+        completion before assembling its operands (within one batch
         the data_dependency fallback already rejects such chains; this
-        covers chains across batches riding one live run)."""
+        covers chains across batches)."""
         roots = set()
         for calls, _, plan in window:
             for call in calls:
@@ -1087,36 +762,21 @@ class GangCommandRing:
             if not park.event.wait(
                 max(0.01, deadline - time.monotonic())
             ):
-                # NEVER snapshot stale operand bytes: surfacing beats
+                # NEVER assemble stale operand bytes: surfacing beats
                 # silently computing on pre-write data (the caller
-                # fails this window's requests, same as the waiter's
-                # wedged-run path)
+                # fails this window's requests)
                 raise TimeoutError(
                     "command-ring refill blocked on an in-flight "
                     "window writing its operand past the drain "
                     "deadline"
                 )
 
-    def _window_posture(self, window):
-        """Per-window sequencer posture: the lead call's tuning-register
-        overlay (``CMDRING_RUN_WINDOWS`` / ``CMDRING_LINGER_US``, raced
-        as autotuner axes and dispatched per plan key) over the gang's
-        env-default registers.  0 = default — the env knobs keep
-        steering any call without an overlay."""
-        lead = window[0][1]
-        t = lead.effective_tuning(getattr(self.gang, "tuning", None) or {})
-        rw = int(t.get("cmdring_run_windows", 0) or 0)
-        lus = int(t.get("cmdring_linger_us", 0) or 0)
-        run_windows = rw if rw > 0 else self.run_windows
-        linger_s = (lus / 1e6) if lus > 0 else self.linger_s
-        return run_windows, linger_s
-
     def _chaos_hook(self, comm, window, slots_np):
         """The chaos plane's reach into the ring path.  Refills never
         cross the emulated fabric, so the installed fault injector sees
         each window as ONE pseudo-message of type ``"RING"``:
         ``corrupt``/``drop`` poison the first slot's opcode word to an
-        out-of-range value — the sequencer reports BAD_OP and that
+        out-of-range value — the window program reports BAD_OP and that
         slot's requests complete INVALID_OPERATION fast, never a hang
         (a silently vanished refill would strand its waiters);
         ``delay`` sleeps a bounded interval before the doorbell.
@@ -1148,11 +808,10 @@ class GangCommandRing:
 
     # -- dispatch ------------------------------------------------------------
     def _dispatch_window(self, comm, mesh, window, reqs_per_slot,
-                         t0, probe: bool = False) -> None:
+                         t0) -> None:
         gang = self.gang
         n = len(window)
         shape = self._window_shape(comm, window)
-        lowering = self._effective_lowering(shape, window)
         with self._lock:
             session = self._sessions.get(comm.id)
             if session is None:
@@ -1215,43 +874,11 @@ class GangCommandRing:
 
         try:
             gang.interactions.bump()  # THE refill: one host interaction
-            # for the whole window (an inline dispatch, a dispatch
-            # arming a resident run, or a mailbox write into one)
-            run = None
-            waiter_st = None
-            if lowering == "xla":
-                with self._lock:
-                    live = (
-                        session.run is not None
-                        and session.run.shape == shape
-                        and session.run.mbox.accepting
-                    )
-                    # the stream detector: an earlier window of this
-                    # session is still in flight — the host is running
-                    # ahead of the device, the regime the resident run
-                    # exists for.  A lone window takes the inline form
-                    # (zero-copy operands, async dispatch, no mailbox
-                    # round trip on its latency path).
-                    streaming = len(session.parks) > 1
-                if self.persistent and (live or streaming) and not probe:
-                    # (a half-open probe window stays INLINE — the
-                    # ring -> inline degradation step: one-shot
-                    # program, no persistent run to wedge)
-                    payload = self._payload_rows(comm, window, shape)
-                    park.form = "mailbox"
-                    run = self._post_or_dispatch(
-                        comm, mesh, session, shape, window_id, slots_np,
-                        payload, self._window_posture(window),
-                    )
-                else:
-                    waiter_st = self._dispatch_inline(
-                        comm, mesh, shape, park, slots_np, window, "xla"
-                    )
-            else:
-                waiter_st = self._dispatch_inline(
-                    comm, mesh, shape, park, slots_np, window, lowering
-                )
-            self._park_window(comm, session, park, run, waiter_st, t0)
+            # for the whole window
+            st = self._launch_window(
+                comm, mesh, shape, park, slots_np, window
+            )
+            self._park_window(comm, session, park, st, t0)
         except BaseException:
             # the window never parked: the armed count must not leak
             # (the parked/no-spin posture is part of the contract)
@@ -1261,65 +888,11 @@ class GangCommandRing:
                     session.parks.remove(park)
             raise
 
-    def _effective_lowering(self, shape: WindowShape, window) -> str:
-        """Per-window lowering.  The Pallas mega-window kernel cannot
-        take f16 wire casts (no Mosaic f16 — the f32 compute view
-        cannot express the f16 rounding lane on the VPU), and BARRIER
-        tokens / SEND-RECV pair slots assemble their payload through
-        the mailbox rather than the zero-copy flat globals; such
-        windows ride the XLA session INSTEAD of falling back to host
-        dispatch — still ring-resident, fallback counters untouched."""
-        if self.lowering != "pallas":
-            return self.lowering
-        f16 = np.dtype(np.float16)
-        if np.dtype(shape.npdt) == f16:
-            return "xla"
-        if any(w is not None and np.dtype(w) == f16 for w in shape.wires):
-            return "xla"
-        return "pallas"
-
-    def _post_or_dispatch(self, comm, mesh, session, shape, window_id,
-                          slots_np, payload, posture) -> "_ResidentRun":
-        """The persistent doorbell: post into the live run when one
-        accepts this shape, else arm a fresh run (ONE dispatch) and
-        post the window as its first pull.  Returns the run the window
-        rode (its failure latch feeds the window's waiter).  ``posture``
-        is the arming window's (run_windows, linger_s) from its tuning
-        overlay — a live run keeps the posture it launched with."""
-        run_windows, linger_s = posture
-        with self._lock:
-            run = session.run
-        if run is not None and run.shape == shape:
-            if run.mbox.post(window_id, slots_np, payload):
-                with self._lock:
-                    self.mailbox_posts += 1
-                return run
-        if run is not None:
-            run.mbox.halt()  # stale shape / spent budget: let it drain
-            with self._lock:
-                self._drained_runs.append(run)
-            self._prune_retired_runs()
-        mbox = SequencerMailbox(
-            comm.size, shape,
-            run_windows=run_windows,
-            linger_s=linger_s,
-            on_window_done=self._make_window_done(comm.id),
-        )
-        mid = register_mailbox(mbox)
-        ok = mbox.post(window_id, slots_np, payload)
-        assert ok  # fresh mailbox always accepts its first window
-        new_run = _ResidentRun(mbox, mid, shape)
-        new_run.launch(mesh, run_windows)
-        with self._lock:
-            session.run = new_run
-            self.dispatches += 1
-        return new_run
-
     def _settle_window(self, session, park) -> None:
         """Session bookkeeping at window completion, exactly once per
-        window whichever completion path ran: decrement the
-        written-root ledger (cross-window dependency releases) and
-        stash the status words for introspection."""
+        window: decrement the written-root ledger (cross-window
+        dependency releases) and stash the status words for
+        introspection."""
         with self._lock:
             if park.settled:
                 return
@@ -1338,14 +911,13 @@ class GangCommandRing:
                             session.written[rid] = left
 
     def _log_window(self, comm_id: int, park: _WindowPark, status,
-                    end_ns: int, run=None, error=None) -> None:
+                    end_ns: int, error=None) -> None:
         """One completed (or failed) window into the bounded window
         log: per-slot (seqn, opcode, retcode, trace id) next to the
-        host-side timing — basis ``"host"`` labeled honestly (neither
-        lowering can write a device clock next to its status words on
-        this mesh; the mailbox's posted/pulled/pushed stamps are the
-        closest observable refill timeline).  Logged exactly once per
-        window whichever completion path ran."""
+        host-side timing — basis ``"host"`` labeled honestly (the
+        window program writes no device clock next to its status
+        words).  Logged exactly once per window whichever completion
+        path ran."""
         from ...telemetry import _perf_to_epoch_us
 
         with self._lock:
@@ -1363,7 +935,6 @@ class GangCommandRing:
         entry = {
             "window_id": park.window_id,
             "comm": comm_id,
-            "form": park.form,
             "ts_us": round(t0_us, 3),
             "dur_us": round(max(end_us - t0_us, 0.001), 3),
             "slots": slots,
@@ -1371,14 +942,6 @@ class GangCommandRing:
         }
         if error is not None:
             entry["error"] = str(error)[:200]
-        if run is not None:
-            timing = run.mbox.take_timing(park.window_id)
-            if timing is not None:
-                entry["mailbox_us"] = {
-                    k2.replace("_ns", "_us"):
-                        round(_perf_to_epoch_us(v), 3)
-                    for k2, v in timing.items()
-                }
         with self._lock:
             self._window_log.append(entry)
             self.windows_logged += 1
@@ -1461,80 +1024,15 @@ class GangCommandRing:
                     })
         return events
 
-    def _make_window_done(self, comm_id: int):
-        """Completion hook one mailbox carries: adopt results (deferred
-        stores), stash status, complete the slots' requests, release
-        the park's event.  Runs on the run thread (the push callback's
-        context), outside every mailbox lock.  Completing HERE — not in
-        the drainer's on_ready — saves two thread handoffs per window
-        on the latency path; ordering holds because one run pushes its
-        windows strictly in order on one thread, and the park entry
-        still rides the in-flight window so every drain point sees
-        it."""
-
-        def on_done(window_id, status, results, comm_id=comm_id):
-            with self._lock:
-                session = self._sessions.get(comm_id)
-                park = None
-                if session is not None:
-                    for p in session.parks:
-                        if p.window_id == window_id:
-                            park = p
-                            break
-            if park is None:
-                return  # torn down (soft_reset) while in flight
-            for k, plan in enumerate(park.plans):
-                out_w = plan["out_w"] if "p2p" not in plan else plan["n"]
-                for r in sorted(plan["writers"]):
-                    res = park.calls_per_slot[k][r].res
-                    if res is None or res.is_dummy:
-                        continue
-                    row = results.get(r)
-                    if row is None:
-                        continue
-                    self._adopter.adopt(res, row[k][:out_w], out_w)
-            park.status = np.asarray(status, np.int32)
-            if session is not None:
-                self._settle_window(session, park)
-            # Complete the slots' requests NOW (the latency path): the
-            # drainer's on_ready then finds them done and only settles
-            # the window-plane accounting.  Guarded: a LATE push racing
-            # the waiter's drain-deadline failure must not flip
-            # already-failed requests back to OK.  Cross-window WRITE
-            # ordering needs no extra fence here: XLA serializes
-            # program execution per device, so every rank's run-R2
-            # pushes strictly follow its run-R1 pushes — window
-            # completions (all-ranks fan-in) therefore fire in
-            # execution order, and successive adoptions of one buffer
-            # land newest-last.
-            sv = park.status
-            dt = max(time.perf_counter_ns() - park.t0, 1)
-            for i, slot_reqs in enumerate(park.reqs_per_slot):
-                code = (
-                    ErrorCode.OK
-                    if i < len(sv) and int(sv[i, 1]) == CMDRING_ST_OK
-                    else ErrorCode.INVALID_OPERATION
-                )
-                for req in slot_reqs:
-                    if req.done():  # side-effect-free engine probe
-                        continue
-                    req.ring_resident = True
-                    req.complete(code, dt)
-            park.event.set()
-
-        return on_done
-
-    def _dispatch_inline(self, comm, mesh, shape, park, slots_np,
-                         window, lowering):
-        """The one-shot window form: ONE async program executes the
-        window on zero-copy assembled operand globals (no mailbox on
-        the latency path — a lone drained window costs exactly what the
-        pre-persistent ring charged).  On the pallas lowering this is
-        the mega-window Mosaic kernel with a backlog of one; a flushed
-        batch larger than the ring depth dispatches once per depth
-        window, in order.  Returns the status global the park's waiter
-        blocks on."""
-        from ...ops.pallas import cmdring as devring
+    def _launch_window(self, comm, mesh, shape, park, slots_np, window):
+        """The window's one form: ONE async program executes the window
+        on zero-copy assembled operand globals; a flushed batch larger
+        than the ring depth dispatches once per depth window, in order.
+        Results are adopted at launch, in issue order (a pointer swap,
+        or a deferred store layered on the buffer), so successive
+        windows writing one buffer land newest-last.
+        Returns the status global the park's waiter blocks on."""
+        from ...ops import cmdring as devring
 
         gang = self.gang
         globals_ = [
@@ -1543,7 +1041,7 @@ class GangCommandRing:
         ]
         with annotate(f"accl::cmdring[{len(window)}]"):
             st, results = devring.run_windows(
-                [(slots_np, globals_)], mesh, shape, lowering=lowering,
+                [(slots_np, globals_)], mesh, shape
             )
         with self._lock:
             self.dispatches += 1
@@ -1600,13 +1098,11 @@ class GangCommandRing:
         )
 
     # -- completion ----------------------------------------------------------
-    def _park_window(self, comm, session, park, run, waiter_st,
-                     t0) -> None:
+    def _park_window(self, comm, session, park, st, t0) -> None:
         """Hand the window's completion to the in-flight window (the
-        refill window): the drainer blocks on the device status words
-        — the mailbox park event on the resident path, the status
-        global on the inline path — then completes every slot's
-        requests with its per-slot retcode."""
+        refill window): the drainer blocks on the status global — THE
+        device status words — then completes every slot's requests
+        with its per-slot retcode."""
         gang = self.gang
 
         def window_done():
@@ -1615,46 +1111,20 @@ class GangCommandRing:
                 if park in session.parks:
                     session.parks.remove(park)
 
-        if waiter_st is not None:
-            # inline form: the status global IS the completion word
-            def waiter(park=park, st=waiter_st):
-                import jax
+        def waiter(park=park, st=st):
+            import jax
 
-                from ...ops.pallas.cmdring import status_view
+            from ...ops.cmdring import status_view
 
-                jax.block_until_ready(st)
-                park.status = status_view(st)[: len(park.plans)]
-                self._settle_window(session, park)
-                park.event.set()
-        else:
-            def waiter(park=park, run=run):
-                deadline = time.monotonic() + drain_deadline_s(
-                    gang.timeout_s
-                )
-                while True:
-                    if park.event.wait(0.2):
-                        return
-                    if run is not None and run.failed.is_set():
-                        raise RuntimeError(
-                            "sequencer run failed: "
-                            f"{type(run.exc).__name__}: {run.exc}"
-                        )
-                    if time.monotonic() > deadline:
-                        raise TimeoutError(
-                            "command-ring window never completed "
-                            "(sequencer run wedged past the drain "
-                            "deadline)"
-                        )
+            jax.block_until_ready(st)
+            park.status = status_view(st)[: len(park.plans)]
+            self._settle_window(session, park)
+            park.event.set()
 
-        def on_ready(overlap_ns, depth, ready_ns, park=park, t0=t0,
-                     run=run):
-            # the xla mailbox path completed the requests on the run
-            # thread already (on_window_done, the latency path); this
-            # settles anything still pending (the pallas backlog path,
-            # torn-down sessions) and the window-plane accounting
+        def on_ready(overlap_ns, depth, ready_ns, park=park, t0=t0):
             sv = park.status
             dt = max(ready_ns - t0, 1)
-            self._log_window(comm.id, park, sv, ready_ns, run=run)
+            self._log_window(comm.id, park, sv, ready_ns)
             window_done()
             # a completed window closes (or restores) the comm's ring
             # circuit breaker — per-slot BAD_OP retcodes are opcode
@@ -1675,12 +1145,12 @@ class GangCommandRing:
                     req.ring_resident = True
                     req.complete(code, dt)
 
-        def on_error(exc, park=park, run=run, t0=t0, comm_id=comm.id):
+        def on_error(exc, park=park, t0=t0, comm_id=comm.id):
             dt = max(time.perf_counter_ns() - t0, 1)
             err = f"{type(exc).__name__}: {exc}"
             self._log_window(
                 comm_id, park, park.status, time.perf_counter_ns(),
-                run=run, error=err,
+                error=err,
             )
             # postmortem plane: the ring failure latch — the facade's
             # BlackBox captures the window log + flight evidence
@@ -1690,25 +1160,12 @@ class GangCommandRing:
                 except Exception:  # must never mask the failure path
                     pass
             window_done()
-            # window failure (run latch, drain deadline, dispatch
-            # error): strike the comm's ring breaker — repeated strikes
-            # open it and the comm degrades to host dispatch until the
-            # cool-down probe
+            # window failure (drain deadline, device error): strike the
+            # comm's ring breaker — repeated strikes open it and the
+            # comm degrades to host dispatch until the cool-down probe
             self.breaker_for(comm_id).record_failure(
                 type(exc).__name__
             )
-            # tear down the run THIS window rode (an inline window rode
-            # none) — never whatever run the session points at now,
-            # which may be a healthy successor serving later windows.
-            # The mailbox stays registered until the program actually
-            # returns (queued windows still drain), then prunes.
-            if run is not None:
-                with self._lock:
-                    if session.run is run:
-                        session.run = None
-                    self._drained_runs.append(run)
-                run.mbox.halt()
-                self._prune_retired_runs()
             ctx = {
                 "comm": comm_id,
                 "error": f"{type(exc).__name__}: {exc}"[:300],

@@ -337,19 +337,6 @@ def apply_tuning(tuning: dict, options) -> ErrorCode:
         if int(val) > 1:
             return ErrorCode.CONFIG_ERROR
         tuning["hierarchical"] = int(val)
-    elif key == TuningKey.CMDRING_RUN_WINDOWS:
-        # persistent-sequencer posture registers: 0 = env default;
-        # the run-windows budget is clamped exactly like the env knob
-        # (an unbounded run would pin the device stream indefinitely)
-        from ...constants import CMDRING_MAX_RUN_WINDOWS
-
-        if int(val) > CMDRING_MAX_RUN_WINDOWS:
-            return ErrorCode.CONFIG_ERROR
-        tuning["cmdring_run_windows"] = int(val)
-    elif key == TuningKey.CMDRING_LINGER_US:
-        if int(val) > 1_000_000:  # >1s would pin the device stream
-            return ErrorCode.CONFIG_ERROR
-        tuning["cmdring_linger_us"] = int(val)
     else:
         if key == TuningKey.GATHER_FLAT_TREE_MAX_FANIN and val < 1:
             return ErrorCode.CONFIG_ERROR
@@ -2769,7 +2756,3 @@ class XLAEngine(StreamPortMixin, BaseEngine):
         # already stopped — parks then degrade to inline completion)
         self.gang.window.stop()
         self.gang.deadlines.stop()
-        # command ring: halt every resident sequencer run so the
-        # long-running programs return promptly instead of riding out
-        # their linger with the process tearing down around them
-        self.gang.cmdring.halt_sessions()

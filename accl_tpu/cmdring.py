@@ -1,8 +1,7 @@
-"""Command-ring host half: slot codec + the persistent-sequencer mailbox.
+"""Command-ring host half: the slot codec and the window's shape.
 
 Role model: the reference's hostctrl path — the host writes fixed-width
 commands into a hardware FIFO and reads completions from a status FIFO
-while the CCLO firmware's ``run()`` loop *lives on the device*
 (``ccl_offload_control.c``).  This module is everything the host owns
 of that protocol, importable without jax (numpy only — the CI ring
 smoke exercises it standalone):
@@ -11,42 +10,26 @@ smoke exercises it standalone):
   pack a collective into ``CMDRING_SLOT_WORDS`` int32 words through the
   ONE layout table (:data:`accl_tpu.constants.CMDRING_FIELDS` — the
   acclint ``cmdring-slot-layout`` check keeps every reader honest);
-* the **mailbox**: :class:`SequencerMailbox` is the host-visible region
-  one persistent sequencer *run* drains.  A run is ONE long-running
-  device program that pulls up to ``run_windows`` refill windows before
-  returning; while it is live, a refill is a mailbox ``post`` (the
-  doorbell becomes a memory write), NOT a program launch.  The pull
-  side blocks the sequencer for at most ``linger_s`` on an empty
-  mailbox, then HALTs the run so the device stream is never pinned by
-  an idle sequencer (the parked posture stays no-spin *and* no-occupy).
+* the **window shape**: :class:`WindowShape` is the static signature of
+  a refill window, the only thing that keys the window program's
+  compile cache;
+* the pair and fused-slot **eligibility** predicates the ring planner
+  and the engine's fallbacks share.
 
-The mailbox's decision protocol is SPMD-safe by construction: the first
-rank to pull step ``s`` decides (window w / HALT) once, every other
-rank's step-``s`` pull returns the identical decision — a rank can
-never gather against peers that saw a different schedule.
-
-The device half — the two sequencer lowerings that decode these slots —
-lives in ``ops/pallas/cmdring.py``; the gang engine's session/refill
-management in ``backends/xla/cmdring.py``.
+The device half — the window program that decodes these slots — lives
+in ``ops/cmdring.py``; the gang engine's session/refill management in
+``backends/xla/cmdring.py``.
 """
 
 from __future__ import annotations
 
-import os
-import threading
-import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
 from .constants import (
     CMDRING_FIELDS,
     CMDRING_FPARAM_ONE,
-    CMDRING_LINGER_ENV,
-    CMDRING_LINGER_MS_DEFAULT,
-    CMDRING_MAX_RUN_WINDOWS,
-    CMDRING_RUN_WINDOWS_DEFAULT,
-    CMDRING_RUN_WINDOWS_ENV,
     CMDRING_SLOT_WORDS,
     CmdOpcode,
     FusedCompute,
@@ -55,21 +38,15 @@ from .constants import (
 )
 
 __all__ = [
-    "SequencerMailbox",
     "WindowShape",
     "complementary_pair",
     "decode_fparam",
     "decode_slot",
-    "default_linger_s",
-    "default_run_windows",
     "encode_fparam",
     "encode_slot",
     "encode_window",
     "fused_slot_eligible",
-    "mailbox_for",
-    "register_mailbox",
     "ring_widths",
-    "unregister_mailbox",
 ]
 
 _F = CMDRING_FIELDS  # the one layout table (constants.py)
@@ -116,8 +93,8 @@ def encode_slot(
 def encode_fparam(x: float) -> int:
     """A fused epilogue's scalar as the Q16.16 fparam word: exact for
     the power-of-two alphas/lrs/scales that dominate training, and
-    decoded identically by both lowerings (int-to-float divide — no
-    float bit-pattern punning through the int32 slot plane)."""
+    decoded on the device by an int-to-float divide (no float
+    bit-pattern punning through the int32 slot plane)."""
     q = int(round(float(x) * CMDRING_FPARAM_ONE))
     return max(-(2 ** 31), min(2 ** 31 - 1, q))
 
@@ -200,7 +177,7 @@ def ring_widths(
 
     The width RELATIONS fully determine the fused geometry: operand
     width ``out*(size+1)`` only arises for APPLY, ``2*out`` (size>2)
-    only for ATTN_HOP — the sequencer lowerings classify slots by these
+    only for ATTN_HOP — the window program classifies slots by these
     relations with the opcode word selecting within a class."""
     n = int(count)
     fuse = FusedCompute(int(fuse))
@@ -271,43 +248,6 @@ def fused_slot_eligible(
     return None
 
 
-# ---------------------------------------------------------------------------
-# persistent-sequencer knobs
-# ---------------------------------------------------------------------------
-
-
-def default_run_windows() -> int:
-    """Refill windows one sequencer run drains before returning (the
-    ``fori``/scan bound of the mega-window program)."""
-    try:
-        n = int(
-            os.environ.get(
-                CMDRING_RUN_WINDOWS_ENV, CMDRING_RUN_WINDOWS_DEFAULT
-            )
-        )
-    except ValueError:
-        n = CMDRING_RUN_WINDOWS_DEFAULT
-    return max(1, min(n, CMDRING_MAX_RUN_WINDOWS))
-
-
-def default_linger_s() -> float:
-    """How long a live run waits on an empty mailbox before halting.
-    Small on purpose: a lingering sequencer occupies the device stream,
-    so anything else dispatched to the mesh pays at most this bound."""
-    try:
-        ms = float(
-            os.environ.get(CMDRING_LINGER_ENV, CMDRING_LINGER_MS_DEFAULT)
-        )
-    except ValueError:
-        ms = CMDRING_LINGER_MS_DEFAULT
-    return max(0.0, ms) / 1e3
-
-
-# ---------------------------------------------------------------------------
-# the mailbox
-# ---------------------------------------------------------------------------
-
-
 class WindowShape:
     """Static shape signature of a refill window — everything that keys
     the sequencer program's compile cache.  Slot CONTENT (opcode, reduce
@@ -332,234 +272,3 @@ class WindowShape:
 
     def __hash__(self) -> int:
         return hash(self.key())
-
-
-class _PostedWindow:
-    __slots__ = ("window_id", "slots", "payload", "status", "results",
-                 "pushed")
-
-    def __init__(self, window_id: int, slots: np.ndarray, payload):
-        self.window_id = window_id
-        self.slots = np.asarray(slots, np.int32)
-        # payload[i][r]: rank r's operand row for slot i (a VIEW of the
-        # committed immutable device array — snapshot semantics with no
-        # copy; None rows pull as zeros), or payload[i] a (size, w)
-        # array (the smoke/test convenience form)
-        self.payload = payload
-        self.status: Optional[np.ndarray] = None
-        self.results: Dict[int, List[np.ndarray]] = {}  # rank -> per slot
-        self.pushed = 0
-
-
-class SequencerMailbox:
-    """One sequencer run's host-visible mailbox (command FIFO in, status
-    FIFO out).  ``pull(rank)`` is the device program's per-step window
-    fetch; ``post`` the host's refill; ``push(rank, ...)`` the device's
-    per-step status/result writeback.  ``on_window_done(window_id,
-    status, results)`` fires — outside every mailbox lock — when all
-    ranks pushed a window's step."""
-
-    def __init__(self, size: int, shape: WindowShape,
-                 run_windows: Optional[int] = None,
-                 linger_s: Optional[float] = None,
-                 on_window_done: Optional[Callable] = None):
-        self.size = int(size)
-        self.shape = shape
-        self.run_windows = (
-            run_windows if run_windows is not None else default_run_windows()
-        )
-        self.linger_s = (
-            linger_s if linger_s is not None else default_linger_s()
-        )
-        self.on_window_done = on_window_done
-        self._lock = threading.Lock()
-        self._cv = threading.Condition(self._lock)
-        self._queue: List[_PostedWindow] = []
-        self._decisions: List[Optional[_PostedWindow]] = []  # None = HALT
-        self._pull_cursor = [0] * self.size
-        self._push_cursor = [0] * self.size
-        self._halt_seen = [False] * self.size
-        self._accepted = 0
-        self._halted = False
-        self.drained = threading.Event()  # every rank pulled a HALT
-        # per-window host-side timing (the introspection basis where
-        # the lowering can't write device timestamps next to the
-        # status words — labeled "host" honestly in every surface):
-        # posted_ns (refill doorbell), pulled_ns (first rank's fetch —
-        # the device-side dequeue point), pushed_ns (last rank's
-        # status writeback).  Bounded: entries are pruned once read by
-        # the session's window log.
-        self._timings: Dict[int, Dict[str, int]] = {}
-
-    # -- introspection -------------------------------------------------------
-    def depth(self) -> int:
-        """Queued refill windows not yet pulled (the mailbox-depth
-        gauge: how far the host runs ahead of the sequencer)."""
-        with self._lock:
-            return len(self._queue)
-
-    def take_timing(self, window_id: int) -> Optional[Dict[str, int]]:
-        """The window's host-side timing record, removed (the window
-        log consumes it exactly once)."""
-        with self._lock:
-            return self._timings.pop(int(window_id), None)
-
-    # -- host side -----------------------------------------------------------
-    def post(self, window_id: int, slots: np.ndarray, payload) -> bool:
-        """Queue one refill window.  False when this run can no longer
-        take it (halted, or its window budget is spent) — the caller
-        must dispatch a fresh run instead."""
-        with self._cv:
-            if self._halted or self._accepted >= self.run_windows:
-                return False
-            self._accepted += 1
-            self._queue.append(_PostedWindow(window_id, slots, payload))
-            self._timings[int(window_id)] = {
-                "posted_ns": time.perf_counter_ns()
-            }
-            if len(self._timings) > 4 * self.run_windows:
-                for k in sorted(self._timings)[: -2 * self.run_windows]:
-                    del self._timings[k]
-            self._cv.notify_all()
-            return True
-
-    def halt(self) -> None:
-        """Teardown doorbell (soft_reset / engine shutdown / shape
-        change): stop accepting posts and let the run drain its backlog,
-        then return.  Queued windows still execute — their requests are
-        already parked."""
-        with self._cv:
-            self._halted = True
-            self._cv.notify_all()
-
-    @property
-    def accepting(self) -> bool:
-        with self._lock:
-            return not self._halted and self._accepted < self.run_windows
-
-    # -- device side (io_callback targets; XLA runtime threads) --------------
-    def pull(self, rank: int):
-        """Step decision + window fetch for one rank.  Returns
-        ``(live, slots, payload_rows)`` with ``live=0`` zeros on a HALT
-        step.  The first rank to reach a step decides it (bounded by
-        ``linger_s`` on an empty queue); everyone else reads the same
-        decision."""
-        r = int(rank)
-        with self._cv:
-            step = self._pull_cursor[r]
-            self._pull_cursor[r] += 1
-            while len(self._decisions) <= step:
-                if self._queue:
-                    nxt = self._queue.pop(0)
-                    t = self._timings.get(nxt.window_id)
-                    if t is not None and "pulled_ns" not in t:
-                        # the device-side dequeue point (host clock —
-                        # the pull trampoline runs on the host)
-                        t["pulled_ns"] = time.perf_counter_ns()
-                    self._decisions.append(nxt)
-                    break
-                if self._halted:
-                    self._decisions.append(None)
-                    break
-                # bounded linger, measured fresh per step: an idle
-                # sequencer must hand the device stream back promptly
-                deadline = time.monotonic() + self.linger_s
-                decided = len(self._decisions)
-                while (
-                    not self._queue
-                    and not self._halted
-                    and len(self._decisions) == decided
-                ):
-                    rem = deadline - time.monotonic()
-                    if rem <= 0:
-                        break
-                    self._cv.wait(min(rem, 0.05))
-                if (
-                    not self._queue
-                    and not self._halted
-                    and len(self._decisions) == decided
-                ):
-                    self._halted = True  # linger expired: park the run
-                self._cv.notify_all()
-            win = self._decisions[step]
-            if win is None:
-                # the run-loop exit: this rank saw the HALT; once every
-                # rank has, the program has returned the device stream
-                self._halt_seen[r] = True
-                if all(self._halt_seen):
-                    self.drained.set()
-                self._cv.notify_all()
-        if win is None:
-            return self._halt_payload(r)
-        sh = self.shape
-        rows = []
-        for i, p in enumerate(win.payload):
-            row = p[r] if p is not None else None
-            if row is None:
-                row = np.zeros((sh.in_ws[i],), sh.npdt)
-            rows.append(row)
-        return (np.int32(1), win.slots, rows)
-
-    def _halt_payload(self, rank: int):
-        sh = self.shape
-        return (
-            np.int32(0),
-            np.zeros((sh.depth, CMDRING_SLOT_WORDS), np.int32),
-            [np.zeros((w,), sh.npdt) for w in sh.in_ws],
-        )
-
-    def push(self, rank: int, live: int, status: np.ndarray,
-             outs: List[np.ndarray]) -> None:
-        """Per-step status/result writeback from one rank.  Completion
-        callbacks fire outside the lock once every rank pushed."""
-        r = int(rank)
-        done = None
-        with self._cv:
-            step = self._push_cursor[r]
-            self._push_cursor[r] += 1
-            win = (
-                self._decisions[step]
-                if step < len(self._decisions) else None
-            )
-            if win is not None and int(live):
-                win.results[r] = [np.asarray(o) for o in outs]
-                if win.status is None:
-                    win.status = np.asarray(status, np.int32).copy()
-                win.pushed += 1
-                if win.pushed == self.size:
-                    done = win
-                    t = self._timings.get(win.window_id)
-                    if t is not None:
-                        t["pushed_ns"] = time.perf_counter_ns()
-            self._cv.notify_all()
-        if done is not None and self.on_window_done is not None:
-            self.on_window_done(done.window_id, done.status, done.results)
-
-
-# ---------------------------------------------------------------------------
-# mailbox registry (the device program addresses its mailbox by id, so
-# one compiled program serves every run of its shape — the callback
-# trampolines in ops/pallas/cmdring.py dispatch through here)
-# ---------------------------------------------------------------------------
-
-_REGISTRY: Dict[int, SequencerMailbox] = {}
-_REGISTRY_LOCK = threading.Lock()
-_NEXT_ID = [1]
-
-
-def register_mailbox(mbox: SequencerMailbox) -> int:
-    with _REGISTRY_LOCK:
-        mid = _NEXT_ID[0]
-        _NEXT_ID[0] += 1
-        _REGISTRY[mid] = mbox
-        return mid
-
-
-def mailbox_for(mid: int) -> Optional[SequencerMailbox]:
-    with _REGISTRY_LOCK:
-        return _REGISTRY.get(int(mid))
-
-
-def unregister_mailbox(mid: int) -> None:
-    with _REGISTRY_LOCK:
-        _REGISTRY.pop(int(mid), None)

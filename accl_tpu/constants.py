@@ -129,15 +129,9 @@ class TuningKey(enum.IntEnum):
     # hard-wires its hp_compression lane per ArithConfig — this makes
     # the lane a measured, per-bucket register like any algorithm)
     WIRE_DTYPE = 12
-    # streaming posture of the persistent sequencer, promoted from the
-    # ACCL_CMDRING_RUN_WINDOWS / ACCL_CMDRING_LINGER_MS env knobs to
-    # raceable per-plan registers: how many refill windows one run
-    # drains before re-dispatching (0 = env default), and how long an
-    # idle run lingers before parking, in MICROSECONDS (0 = env
-    # default; an int register, so the ms-granular env knob races at
-    # sub-ms resolution)
-    CMDRING_RUN_WINDOWS = 13
-    CMDRING_LINGER_US = 14
+    # 13 and 14 are unassigned (they steered a command-ring form that is
+    # gone): a SET_TUNING of either is refused like any unknown key, and
+    # the registers after them keep their numbers
     # topology plane: 1 = decompose eligible collectives hierarchically
     # (intra-slice / cross-slice stages over derived subcomms) when the
     # communicator carries a multi-slice Topology; 0 = flat (the
@@ -177,8 +171,6 @@ TUNING_KEY_NAMES = {
     TuningKey.GATHER_ALGORITHM: "gather_algorithm",
     TuningKey.PIPELINE_THRESHOLD: "pipeline_threshold",
     TuningKey.WIRE_DTYPE: "wire_dtype",
-    TuningKey.CMDRING_RUN_WINDOWS: "cmdring_run_windows",
-    TuningKey.CMDRING_LINGER_US: "cmdring_linger_us",
     TuningKey.HIERARCHICAL: "hierarchical",
     TuningKey.WIRE_DTYPE_ICI: "wire_dtype_ici",
     TuningKey.WIRE_DTYPE_DCN: "wire_dtype_dcn",
@@ -230,12 +222,12 @@ class DataType(enum.IntEnum):
 #: Registered WIRE LANES: DataType member name -> numpy dtype name, the
 #: ONE vocabulary of reduced-precision wire formats the whole stack
 #: speaks (facade verdicts, the shared host codec in accl_tpu.wire, the
-#: slot ``wire`` field of the command ring, and BOTH sequencer decode
-#: lowerings).  A LITERAL dict on purpose: the acclint
+#: slot ``wire`` field of the command ring, and the ring's decode
+#: loop).  A LITERAL dict on purpose: the acclint
 #: ``cmdring-slot-layout`` cross-check parses it from the AST and fails
-#: the tree when a registered lane is not handled by both decode-loop
-#: lowerings — growing this table without wiring a lane is a finding,
-#: not a workload fallback.
+#: the tree when a registered lane is not handled by the decode loop —
+#: growing this table without wiring a lane is a finding, not a
+#: workload fallback.
 WIRE_LANE_DTYPES = {
     "FLOAT16": "float16",
     "BFLOAT16": "bfloat16",
@@ -504,12 +496,6 @@ TUNING_DEFAULTS = {
     # WIRE_LANE_DTYPES makes eligible calls ride that lane — typically
     # set per size bucket by an autotuned TuningPlan overlay
     "wire_dtype": 0,
-    # persistent-sequencer streaming posture: 0 = ride the
-    # ACCL_CMDRING_RUN_WINDOWS / ACCL_CMDRING_LINGER_MS env defaults;
-    # nonzero values (windows per run / idle linger in microseconds)
-    # override per plan key, typically from an autotuned overlay
-    "cmdring_run_windows": 0,
-    "cmdring_linger_us": 0,
     # topology plane: 0 = flat dispatch (hierarchical decomposition off
     # until a TuningPlan or explicit set_tuning arms it on a comm that
     # actually carries a multi-slice Topology)
@@ -534,8 +520,9 @@ MAX_INFLIGHT_WINDOW = 64
 # ONE sequencer program per refill decodes the slots ON DEVICE and
 # executes the whole window, writing a (seqn, retcode) status word per
 # slot that the drainer polls.  This table is the single source of truth
-# for the slot layout: the host-side encoder (ops/pallas/cmdring.py) and
-# the device-side sequencer decode THE SAME indices from it, and the
+# for the slot layout: the host-side encoder (accl_tpu/cmdring.py) and
+# the device-side decode loop (ops/cmdring.py) read THE SAME indices
+# from it, and the
 # acclint ``cmdring-slot-layout`` check fails any module that re-derives
 # them locally.  Everything here is plain ints — the jax-free closure.
 # ---------------------------------------------------------------------------
@@ -544,9 +531,9 @@ MAX_INFLIGHT_WINDOW = 64
 class CmdOpcode(enum.IntEnum):
     """Opcode space of a command-ring slot — the sequencer's full
     dispatch vocabulary (the reference CCLO's run-loop opcode set).
-    Every non-NOP opcode is implemented by BOTH sequencer lowerings
-    (enforced by the acclint ``cmdring-slot-layout`` cross-file
-    presence check); anything outside this enum falls back to host
+    Every non-NOP opcode is implemented by the decode loop (enforced
+    by the acclint ``cmdring-slot-layout`` cross-file presence check);
+    anything outside this enum falls back to host
     dispatch with a counted reason."""
 
     NOP = 0        # padding slot: decoded, skipped, status OK
@@ -625,7 +612,7 @@ CMDRING_FUSED_OPCODES = {
 #: Q16.16 fixed-point unit of the fparam slot word: fused epilogues
 #: carry their scalar (alpha / lr / scale) as round(x * FPARAM_ONE)
 #: in an int32 word — exact for the power-of-two scales that dominate
-#: training, and identical across both lowerings.
+#: training.
 CMDRING_FPARAM_ONE = 65536
 
 #: int32 words per slot (fields below + reserved headroom)
@@ -652,7 +639,7 @@ CMDRING_FIELDS = {
                     # (alpha / lr / scale; 0 for plain slots)
 }
 
-#: per-slot status-word retcodes the sequencer writes back
+#: per-slot status-word retcodes the window program writes back
 CMDRING_ST_OK = 1
 CMDRING_ST_BAD_OP = 2
 
@@ -666,20 +653,6 @@ CMDRING_MAX_BYTES_ENV = "ACCL_CMDRING_MAX_BYTES"
 CMDRING_DEPTH_DEFAULT = 8
 CMDRING_MAX_DEPTH = 64
 CMDRING_MAX_PAYLOAD_BYTES = 4 * 1024 * 1024
-
-# Persistent-sequencer mailbox knobs.  One sequencer *run* is one
-# long-running device program that drains up to ACCL_CMDRING_RUN_WINDOWS
-# refill windows from the host-visible mailbox before returning; while
-# a run is live, a refill is a mailbox write (doorbell), NOT a program
-# launch.  When the mailbox stays empty for ACCL_CMDRING_LINGER_MS the
-# run halts and the sequencer parks (returns the device) — the bounded
-# linger keeps a parked sequencer from pinning the device stream under
-# host-dispatch traffic.
-CMDRING_RUN_WINDOWS_ENV = "ACCL_CMDRING_RUN_WINDOWS"
-CMDRING_LINGER_ENV = "ACCL_CMDRING_LINGER_MS"
-CMDRING_RUN_WINDOWS_DEFAULT = 16
-CMDRING_MAX_RUN_WINDOWS = 128
-CMDRING_LINGER_MS_DEFAULT = 2.0
 
 # Segmented-pipelining wire tags (overlap plane): concurrent segment
 # sub-collectives of ONE pipelined call execute as concurrent engine

@@ -781,7 +781,7 @@ class ACCL:
     def _postmortem_evidence(self) -> dict:
         """This rank's evidence for a bundle: the flight-recorder tail
         plus the full merged telemetry snapshot (which carries the
-        ring/mailbox state, the membership event ring, skew baselines
+        command ring's state, the membership event ring, skew baselines
         and contract window digests).  Called from the failing thread
         locally and from peers' capture paths (board registry / wire
         request) — must stay bounded and side-effect-free."""
@@ -4114,7 +4114,6 @@ class ACCL:
                 f"cmdring: state={ring.get('state', '?')} "
                 f"refills={ring.get('refills', 0)} "
                 f"dispatches={ring.get('dispatches', 0)} "
-                f"mailbox_depth={ring.get('mailbox_depth', 0)} "
                 f"fallbacks={sum((ring.get('fallbacks') or {}).values())}"
             )
         else:

@@ -65,8 +65,7 @@ Three coupled pieces:
   verdict (the shared judge on board-anchored tiers), never from local
   observation, and every per-call decision is latched per (comm, call
   index) on the shared ledger — the first rank to a call index decides,
-  every other rank reads the same decision (the sequencer-mailbox
-  discipline).  On wire tiers, whose straggler verdicts are pairwise
+  every other rank reads the same decision.  On wire tiers, whose straggler verdicts are pairwise
   (correct only on the conforming side), demotion never alters routing
   — verdicts stay operator signals there.
 

@@ -218,7 +218,7 @@ class BlackBox:
     CONTRACT_VIOLATION / RANK_EVICTED / DEADLOCK_SUSPECTED, the
     command-ring failure latch, a membership cutover) the facade calls
     :meth:`capture`: the local evidence (flight-recorder tail +
-    telemetry snapshot — which carries ring/mailbox state, the
+    telemetry snapshot — which carries the command ring's state, the
     membership event ring, skew baselines and contract window digests)
     is snapshotted, reachable peers are solicited for THEIR evidence —
     in process over the anchored registry (the contract-board

@@ -18,6 +18,9 @@ Pure-functional JAX collectives in two flavors:
   release protocol).  Off-TPU they execute under the Pallas TPU
   interpreter, optionally with its vector-clock race detector.
 
+* ``cmdring`` — the command ring's device half: a batched window of
+  collectives decoded from slot words and executed as ONE program.
+
 The ``driver`` module wraps both in host-level helpers that take global
 arrays and a Mesh and run the jitted SPMD program.
 """

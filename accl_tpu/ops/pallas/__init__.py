@@ -14,10 +14,6 @@ TPU kernels.
   (the dropless MoE experts): three tiled kernels after jax's megablox
   (forward, the input's and the weights' gradient) behind one
   ``custom_vjp``, tiles chosen from the shapes for each form.
-* ``cmdring`` — the device-resident command ring (the CCLO run-loop
-  analog): host-side slot encoder + the sequencer program that decodes
-  slots on device and executes a whole refill window under one
-  dispatch.
 
 On non-TPU backends every kernel runs under the Pallas TPU interpreter so
 the CI tier exercises the identical kernel code (see
@@ -27,7 +23,6 @@ the CI tier exercises the identical kernel code (see
 from . import (  # noqa: F401
     alltoall,
     attention,
-    cmdring,
     compression,
     put,
     ring,

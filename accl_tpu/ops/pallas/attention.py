@@ -64,8 +64,8 @@ def attn_hop_partial(q, kv, scale):
     resident q block against the kv block that just arrived on the relay
     (the sequencer's flat-row form of a hop's score contribution — the
     blocked kernel above folds full (T, D) tiles; a fused slot streams
-    the same hop product per lane row).  Shared by both sequencer
-    lowerings and the engine's host-decomposition reference so the slot
+    the same hop product per lane row).  Shared by the command ring's
+    decode loop and the engine's host-decomposition reference so the slot
     semantics have exactly one definition.  Works on jnp and numpy
     operands alike."""
     return (q * kv) * scale

@@ -40,9 +40,7 @@ from ._common import (
 def remote_block_put(src_ref, dst_ref, send_sem, recv_sem, dst_dev):
     """One device-initiated block put: remote-DMA ``src_ref`` into
     ``dst_ref`` on ``dst_dev`` and block until both sides drained — the
-    ``stream_put`` primitive factored out of :func:`fused_shift` so
-    other kernels (the command-ring sequencer's two-rank exchange) can
-    compose it.  The caller owns the pre-put barrier (the remote ref
+    ``stream_put`` primitive of :func:`fused_shift`.  The caller owns the pre-put barrier (the remote ref
     must exist before data lands in it)."""
     rdma = pltpu.make_async_remote_copy(
         src_ref=src_ref,

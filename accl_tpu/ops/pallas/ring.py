@@ -255,9 +255,8 @@ def _reduce_scatter_kernel(axis_name, size, num_segments, op):
 def relay_allgather_hops(dst_write, carry, comm, send_sem, recv_sem,
                          ack_sem, me, nxt, prv, size):
     """The store-and-relay ring allgather hop loop (ref
-    ccl_offload_control.c:1402-1500), factored out so the allgather
-    kernel AND the command-ring sequencer (``cmdring``) drive the same
-    machine: ``carry[j]`` must be pre-seeded with this rank's own block
+    ccl_offload_control.c:1402-1500) of the allgather kernel:
+    ``carry[j]`` must be pre-seeded with this rank's own block
     segments; ``dst_write(origin, j, data)`` places each arriving
     block's segment ``j`` (``origin`` = the block's home rank, traced).
     Segment count derives from ``carry``'s leading dim; semaphores drain

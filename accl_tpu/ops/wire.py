@@ -1,7 +1,7 @@
 """Device-side twin of the host wire codec (:mod:`accl_tpu.wire`).
 
-Bit-identical jnp forms of the quantized wire lanes — the sequencer
-decode loops (both lowerings), the compressed-allreduce program and the
+Bit-identical jnp forms of the quantized wire lanes — the command
+ring's decode loop, the compressed-allreduce program and the
 dist tier's in-program wire rounding all call THESE, and
 tests/test_wire.py holds them to byte equality against the numpy codec
 (same input, same seed -> same wire bytes).  Bit identity is why every
@@ -140,9 +140,10 @@ def wire_lane_roundtrip(x, wire_dtype, seed=0):
     dtype — the single-rounding semantic the decode loops and the
     compressed-allreduce program run per contribution, covering EVERY
     registered lane (cast lanes by dtype, the scaled int8 lane by
-    blockwise quantization).  THE shared lane helper: both sequencer
-    lowerings must route their wire casts through here (the acclint
-    ``cmdring-slot-layout`` wire cross-check enforces it)."""
+    blockwise quantization).  THE shared lane helper: the command
+    ring's decode loop must route its wire casts through this module
+    (the acclint ``cmdring-slot-layout`` wire cross-check enforces
+    it)."""
     wire_np = jnp.dtype(wire_dtype)
     orig = x.dtype
     from ..constants import numpy_to_dtype

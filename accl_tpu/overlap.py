@@ -99,11 +99,9 @@ class _Entry:
         # set when a LATER launch of this key parks while this entry is
         # still in flight — the witness that its device time was hidden
         self.overlapped = False
-        # command-ring refill window: its waiter blocks on the mailbox
-        # status words, not a program future, and completion may arrive
-        # while the sequencer run is STILL resident serving later
-        # windows (the multi-window drain contract: drain points never
-        # require the run to return, only its windows to push)
+        # command-ring refill window: ONE entry covers a whole window
+        # of collectives; its waiter blocks on the window program's
+        # status words
         self.ring = ring
 
 
@@ -139,10 +137,8 @@ class InflightWindow:
         self.max_depth_seen = 0
         self.overlap_ns_total = 0
         # command-ring plane: refill windows parked with ring=True (each
-        # is ONE entry covering a whole window of collectives).  With
-        # the persistent sequencer a run serves MANY windows: parks and
-        # completions count WINDOWS, never runs — draining the window
-        # plane is independent of the sequencer program returning.
+        # is ONE entry covering a whole window of collectives): parks
+        # and completions count WINDOWS.
         self.ring_launched = 0
         self.ring_completed = 0
 

@@ -72,7 +72,7 @@ class Request:
         self.inflight_depth: Optional[int] = None
         # command-ring plane (the TPU CCLO analog): True when this call
         # executed ring-resident — decoded and sequenced on device by
-        # the persistent sequencer, the host only refilling the ring
+        # a window program, the host only refilling the ring
         self.ring_resident: Optional[bool] = None
 
     # -- engine side --------------------------------------------------------

@@ -965,33 +965,25 @@ def to_prometheus(snapshot: dict) -> str:
                 if isinstance(vv, (int, float)) and not isinstance(vv, bool):
                     gauge(f"accl_engine_{k}_{kk}", vv)
 
-    # command-ring plane (the persistent sequencer): the sustained-
-    # occupancy gauge (refill windows served per program dispatch — the
-    # persistence evidence, >1 means the run survived across refills),
-    # per-opcode ring-residency counters and per-reason fallbacks.  The
-    # scalar ring counters (refills/dispatches/mailbox_posts/...) ride
-    # the generic accl_engine_cmdring_* folding above; these are the
-    # labeled third-level dicts that folding cannot reach.
+    # command-ring plane: per-opcode ring-residency counters and
+    # per-reason fallbacks.  The scalar ring counters
+    # (refills/dispatches/slots/...) ride the generic
+    # accl_engine_cmdring_* folding above; these are the labeled
+    # third-level dicts that folding cannot reach.
     ring = engine.get("cmdring") or {}
-    gauge(
-        "accl_cmdring_sustained_occupancy",
-        ring.get("sustained_occupancy"),
-    )
     for opname, cnt in sorted((ring.get("ops") or {}).items()):
         gauge("accl_cmdring_op_slots_total", cnt, op=opname)
     for reason, cnt in sorted((ring.get("fallbacks") or {}).items()):
         gauge("accl_cmdring_fallbacks_total", cnt, reason=reason)
-    # ring introspection (the causal trace plane): mailbox depth (how
-    # far the host runs ahead of the sequencer), the run-thread state
-    # as a numeric gauge (0 parked / 1 resident / 2 armed), and the
-    # refill-window latency histogram (log2-us buckets, host basis)
-    gauge("accl_cmdring_mailbox_depth", ring.get("mailbox_depth"))
+    # ring introspection (the causal trace plane): the ring state as a
+    # numeric gauge (0 parked / 2 armed) and the refill-window latency
+    # histogram (log2-us buckets, host basis)
     gauge("accl_cmdring_windows_total", ring.get("windows_logged"))
     state = ring.get("state")
     if state is not None:
         gauge(
             "accl_cmdring_run_state",
-            {"parked": 0, "resident": 1, "armed": 2}.get(state, -1),
+            {"parked": 0, "armed": 2}.get(state, -1),
         )
     wl = ring.get("window_latency_log2_us") or {}
     if wl:

@@ -39,7 +39,6 @@ import numpy as np
 
 from .constants import (
     AllreduceAlgorithm,
-    CMDRING_MAX_RUN_WINDOWS,
     DataType,
     EAGER_THRESHOLD_DEFAULT,
     MAX_EAGER_SIZE_LIMIT,
@@ -178,17 +177,6 @@ def validate_registers(regs: Dict[str, object]) -> Dict[str, object]:
             if name in ("ring_segments", "gather_flat_tree_max_fanin") \
                     and val < 1:
                 raise ValueError(f"register {name}: {val} < 1")
-            # persistent-sequencer posture registers: the same clamps
-            # the engines enforce at SET_TUNING (an unbounded run /
-            # >1s linger would pin the device stream)
-            if name == "cmdring_run_windows" and val > CMDRING_MAX_RUN_WINDOWS:
-                raise ValueError(
-                    f"register {name}: {val} > {CMDRING_MAX_RUN_WINDOWS}"
-                )
-            if name == "cmdring_linger_us" and val > 1_000_000:
-                raise ValueError(
-                    f"register {name}: {val} > 1000000 (1s)"
-                )
         out[name] = val
     return out
 
@@ -399,8 +387,6 @@ def _candidates(
     segments: Sequence[int],
     pipeline_thresholds: Sequence[int] = (),
     wire_dtypes: Sequence = (),
-    cmdring_run_windows: Sequence[int] = (),
-    cmdring_linger_us: Sequence[int] = (),
     race_hierarchical: bool = False,
     wire_dtypes_ici: Sequence = (),
     wire_dtypes_dcn: Sequence = (),
@@ -455,23 +441,6 @@ def _candidates(
         elif op == "gather":
             fanins = sorted({1, 2, max(1, world - 1)})
             cands += [{"gather_flat_tree_max_fanin": f} for f in fanins]
-    if tier in ("xla", "dist") and op == "allreduce":
-        # persistent-sequencer posture axes (command ring): the
-        # run-window budget and mailbox linger raced per size bucket —
-        # winners dispatch per plan key through the per-bucket overlay
-        # (CallOptions.effective_tuning -> the gang ring's
-        # _window_posture), so a hot training bucket can hold a long
-        # resident run while cold buckets keep the env defaults
-        cands += [
-            {"cmdring_run_windows": int(rw)}
-            for rw in cmdring_run_windows
-            if 0 < int(rw) <= CMDRING_MAX_RUN_WINDOWS
-        ]
-        cands += [
-            {"cmdring_linger_us": int(lu)}
-            for lu in cmdring_linger_us
-            if 0 < int(lu) <= 1_000_000
-        ]
     if op == "allreduce":
         # quantized wire plane: per-bucket compression verdicts raced
         # like any register — off is always candidate 0 (the defaults),
@@ -545,8 +514,6 @@ def autotune(
     segments: Sequence[int] = (1, 2, 4),
     pipeline_thresholds: Sequence[int] = (),
     wire_dtypes: Sequence = (),
-    cmdring_run_windows: Sequence[int] = (),
-    cmdring_linger_us: Sequence[int] = (),
     wire_dtypes_ici: Sequence = (),
     wire_dtypes_dcn: Sequence = (),
     topology=None,
@@ -590,7 +557,6 @@ def autotune(
                 for regs in _candidates(
                     tier, op, world, include_pallas, eager_candidates,
                     segments, pipeline_thresholds, wire_dtypes,
-                    cmdring_run_windows, cmdring_linger_us,
                     race_hierarchical=race_hier,
                     wire_dtypes_ici=wire_dtypes_ici,
                     wire_dtypes_dcn=wire_dtypes_dcn,
@@ -648,8 +614,6 @@ def autotune(
         "segments": [int(s) for s in segments],
         "pipeline_thresholds": [int(t) for t in pipeline_thresholds],
         "wire_dtypes": [wire_dtype_value(w) for w in wire_dtypes],
-        "cmdring_run_windows": [int(r) for r in cmdring_run_windows],
-        "cmdring_linger_us": [int(u) for u in cmdring_linger_us],
         "wire_dtypes_ici": [wire_dtype_value(w) for w in wire_dtypes_ici],
         "wire_dtypes_dcn": [wire_dtype_value(w) for w in wire_dtypes_dcn],
         "topology": None if topology is None else topology.signature(),
@@ -719,18 +683,6 @@ def main(argv=None) -> int:
              "bucket WIRE_DTYPE register): names from the registered "
              "lanes, e.g. float16 bfloat16 float8_e4m3 int8 — 'off' "
              "(the defaults) is always candidate 0",
-    )
-    ap.add_argument(
-        "--cmdring-run-windows", nargs="*", type=int, default=[],
-        help="command-ring run-window budgets to race for allreduce "
-             "(per-bucket CMDRING_RUN_WINDOWS register, XLA gang tier; "
-             "e.g. 32 128) — 0/default is always candidate 0",
-    )
-    ap.add_argument(
-        "--cmdring-linger-us", nargs="*", type=int, default=[],
-        help="command-ring mailbox linger candidates in microseconds "
-             "(per-bucket CMDRING_LINGER_US register, XLA gang tier; "
-             "e.g. 500 5000)",
     )
     ap.add_argument(
         "--wire-dtypes-ici", nargs="*", default=[],
@@ -843,8 +795,6 @@ def main(argv=None) -> int:
             segments=args.segments,
             pipeline_thresholds=args.pipeline_thresholds,
             wire_dtypes=args.wire_dtypes,
-            cmdring_run_windows=args.cmdring_run_windows,
-            cmdring_linger_us=args.cmdring_linger_us,
             wire_dtypes_ici=args.wire_dtypes_ici,
             wire_dtypes_dcn=args.wire_dtypes_dcn,
             topology=topology,

@@ -18,7 +18,7 @@ Every consumer reads THIS codec — the emulator's eager chunk lanes, the
 dist tier's staging path, the native engine's host-side mirror, the
 facade's error-feedback residual accounting — and the device-side twin
 (:mod:`accl_tpu.ops.wire`) implements bit-identical jnp forms for the
-sequencer decode loops, so "same seed -> same wire bytes" holds across
+command ring's decode loop, so "same seed -> same wire bytes" holds across
 tiers (tested bit-level by tests/test_wire.py).
 
 Stochastic rounding is **counter-based and seedable**: random bits are

@@ -1631,69 +1631,59 @@ def _bench_cmdring() -> dict:
             return t.elapsed_ns() / iters / 1e3
 
         def timed_ring(count):
-            """The persistent-sequencer stream: K refill windows posted
-            PIPELINED (``_dispatch_pending`` posts each window without
-            draining — the host keeps refilling while the sequencer
-            run drains the mailbox, the firmware regime) with one
-            drain at the end; a linger pinned above the posting
-            cadence so the measurement reads the sequencer's
-            persistence, not the box's thread scheduling.  Also
-            returns the per-window-DRAINED latency leg (a lone window
-            pays the mailbox round trip — reported, not gated) and the
-            redispatch amortization."""
-            ring = a.engine.gang.cmdring
+            """The pipelined stream: K refill windows dispatched
+            PIPELINED (``_dispatch_pending`` launches each window
+            without draining — the host keeps refilling while the
+            device executes) with one drain at the end.  Also returns
+            the per-window-DRAINED latency leg (reported, not gated)
+            and the dispatches a window cost."""
             sends = fresh_sends(count, wdepth)
             d = a.create_buffer(count, np.float32)
-            saved = ring.linger_s
-            ring.linger_s = 0.5
-            try:
-                # warm window: compiles the sequencer program
-                with a.batch():
-                    reqs = [
-                        a.allreduce(sb, d, count, run_async=True)
-                        for sb in sends
-                    ]
-                for r in reqs:
-                    r.wait(120)
-                    r.check()
-                drain(d)
-                # latency leg: each window drained before the next
-                with Timer() as tl:
-                    for _ in range(2):
-                        with a.batch():
-                            reqs = [
-                                a.allreduce(sb, d, count, run_async=True)
-                                for sb in sends
-                            ]
-                        for r in reqs:
-                            r.wait(120)
-                            r.check()
-                latency = tl.elapsed_ns() / (2 * wdepth) / 1e3
-
-                def burst():
-                    reqs = []
-                    a.begin_batch()
-                    try:
-                        for _ in range(windows):
-                            reqs.extend(
-                                a.allreduce(sb, d, count, run_async=True)
-                                for sb in sends
-                            )
-                            a._dispatch_pending()  # post, do NOT drain
-                    finally:
-                        a.end_batch()  # ONE drain for the whole stream
+            # warm window: compiles the sequencer program
+            with a.batch():
+                reqs = [
+                    a.allreduce(sb, d, count, run_async=True)
+                    for sb in sends
+                ]
+            for r in reqs:
+                r.wait(120)
+                r.check()
+            drain(d)
+            # latency leg: each window drained before the next
+            with Timer() as tl:
+                for _ in range(2):
+                    with a.batch():
+                        reqs = [
+                            a.allreduce(sb, d, count, run_async=True)
+                            for sb in sends
+                        ]
                     for r in reqs:
                         r.wait(120)
                         r.check()
+            latency = tl.elapsed_ns() / (2 * wdepth) / 1e3
 
-                burst()  # arms the resident run (stays live: linger)
-                ring0 = a.engine.telemetry_report().get("cmdring") or {}
-                with Timer() as t:
-                    burst()
-                    drain(d)
-                ring1 = a.engine.telemetry_report().get("cmdring") or {}
-            finally:
-                ring.linger_s = saved
+            def burst():
+                reqs = []
+                a.begin_batch()
+                try:
+                    for _ in range(windows):
+                        reqs.extend(
+                            a.allreduce(sb, d, count, run_async=True)
+                            for sb in sends
+                        )
+                        a._dispatch_pending()  # post, do NOT drain
+                finally:
+                    a.end_batch()  # ONE drain for the whole stream
+                for r in reqs:
+                    r.wait(120)
+                    r.check()
+
+            burst()  # warm
+            ring0 = a.engine.telemetry_report().get("cmdring") or {}
+            with Timer() as t:
+                burst()
+                drain(d)
+            ring1 = a.engine.telemetry_report().get("cmdring") or {}
             calls = windows * wdepth
             refills = ring1.get("refills", 0) - ring0.get("refills", 0)
             slots = ring1.get("slots", 0) - ring0.get("slots", 0)
@@ -1818,7 +1808,6 @@ def _bench_cmdring() -> dict:
             "gang_cmdring_op_slots": op_slots,
             "gang_cmdring_mixed_fallbacks": mixed_fallbacks,
             "gang_cmdring_mode": ring_stats.get("mode"),
-            "gang_cmdring_lowering": ring_stats.get("lowering"),
             "gang_cmdring_fallbacks": ring_stats.get("fallbacks"),
         }
     finally:

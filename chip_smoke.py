@@ -231,7 +231,7 @@ def _facade_size(g, devs, nbytes: int, warm_iters: int, clock: _Clock) -> dict:
 def _facade_window(g, window_bytes: int, clock: _Clock) -> dict:
     """A ``with a.batch():`` window of three collectives, cold then warm,
     which must ride the command ring: slots enqueued, no fallback, no
-    breaker strike, and the lowering named."""
+    breaker strike."""
     from accl_tpu.constants import ReduceFunction
 
     P = len(g)
@@ -284,7 +284,6 @@ def _facade_window(g, window_bytes: int, clock: _Clock) -> dict:
         c: b["reasons"] for c, b in ring["breakers"].items() if b["reasons"]
     }
     report = {
-        "lowering": ring["lowering"],
         "slots": ring["slots"],
         "dispatches": ring["dispatches"],
         "fallbacks": ring["fallbacks"],

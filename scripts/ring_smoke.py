@@ -1,11 +1,10 @@
 """CI command-ring smoke: exercise the ring's HOST half — the slot
-codec over the full opcode space and the persistent-sequencer mailbox
-protocol — plus the capture gate's units, with numpy only (no jax, the
-same footprint as the acclint gate job it runs next to,
-.github/workflows/analysis.yml).  The device lowerings are covered by
-the jax test tier (tests/test_cmdring.py); this job proves the
-protocol the firmware-side contract rides stays importable and correct
-standalone.
+codec over the full opcode space and the fused-slot units — plus the
+capture gate's units, with numpy only (no jax, the same footprint as
+the acclint gate job it runs next to, .github/workflows/analysis.yml).
+The window program is covered by the jax test tier
+(tests/test_cmdring.py); this job proves the codec the device-side
+decode rides stays importable and correct standalone.
 
 Usage::
 
@@ -14,7 +13,6 @@ Usage::
 
 import os
 import sys
-import threading
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -22,18 +20,13 @@ import numpy as np
 
 from accl_tpu.cmdring import (
     FUSED_BASE_OPS,
-    SequencerMailbox,
-    WindowShape,
     decode_fparam,
     decode_slot,
     encode_fparam,
     encode_slot,
     encode_window,
     fused_slot_eligible,
-    mailbox_for,
-    register_mailbox,
     ring_widths,
-    unregister_mailbox,
 )
 from accl_tpu.constants import (
     CMDRING_FUSED_OPCODES,
@@ -73,8 +66,8 @@ def codec_smoke() -> None:
 def fused_smoke() -> None:
     """Fused compute slots, host half: codec round-trip with the
     Q16.16 fparam word, the fused width relations, and the planner's
-    eligibility predicate — the same units the engine planner and both
-    lowerings read, importable without jax."""
+    eligibility predicate — the same units the engine planner and the
+    window program read, importable without jax."""
     # every fused hint maps to a slot opcode and round-trips the codec
     # with its epilogue scalar
     for fuse, opcode in CMDRING_FUSED_OPCODES.items():
@@ -131,72 +124,6 @@ def fused_smoke() -> None:
     print("fused: ok")
 
 
-def mailbox_smoke() -> None:
-    """The persistent run's mailbox protocol, driven like the device
-    program would: N rank pullers, SPMD-identical step decisions, one
-    completion per window once every rank pushed, bounded-linger HALT
-    park."""
-    size = 2
-    shape = WindowShape(1, [4], [4], [None], np.float32)
-    done = []
-    mbox = SequencerMailbox(
-        size, shape, run_windows=4, linger_s=0.2,
-        on_window_done=lambda wid, st, res: done.append((wid, st, res)),
-    )
-    mid = register_mailbox(mbox)
-    assert mailbox_for(mid) is mbox
-    slots = encode_window([encode_slot(0, CmdOpcode.ALLREDUCE, 4)], 1)
-    payload = [np.arange(size * 4, dtype=np.float32).reshape(size, 4)]
-    assert mbox.post(1, slots, payload)
-    assert mbox.post(2, slots, payload)
-    # introspection plane: queued-but-unpulled depth (the
-    # accl_cmdring_mailbox_depth gauge's source)
-    assert mbox.depth() == 2
-
-    schedules = {r: [] for r in range(size)}
-
-    def rank_loop(r):
-        for _step in range(4):
-            live, got_slots, rows = mbox.pull(r)
-            schedules[r].append(int(live))
-            status = np.stack(
-                [got_slots[:, 0], np.ones(1, np.int32)], axis=1
-            )
-            mbox.push(r, int(live), status, [rows[0] * 2])
-
-    threads = [
-        threading.Thread(target=rank_loop, args=(r,), daemon=True,
-                         name=f"accl-ring-smoke-{r}")
-        for r in range(size)
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(30)
-        assert not t.is_alive(), "mailbox protocol wedged"
-    # both ranks saw the identical schedule: 2 live windows, then the
-    # linger expired and every later step HALTed
-    assert schedules[0] == schedules[1] == [1, 1, 0, 0], schedules
-    assert [wid for wid, _, _ in done] == [1, 2]
-    for _wid, _st, res in done:
-        assert set(res) == {0, 1}
-        np.testing.assert_array_equal(res[0][0], payload[0][0] * 2)
-    assert not mbox.accepting  # halted: the next refill re-dispatches
-    assert not mbox.post(3, slots, payload)
-    assert mbox.drained.is_set()
-    assert mbox.depth() == 0
-    # host-side window timing (basis "host", labeled honestly in the
-    # window log): posted -> pulled -> pushed, consumed exactly once
-    for wid in (1, 2):
-        t = mbox.take_timing(wid)
-        assert t is not None, f"window {wid} timing missing"
-        assert t["posted_ns"] <= t["pulled_ns"] <= t["pushed_ns"]
-        assert mbox.take_timing(wid) is None
-    unregister_mailbox(mid)
-    assert mailbox_for(mid) is None
-    print("mailbox: ok")
-
-
 def gate_smoke() -> None:
     """check_cmdring's persistence requirements hold stand-alone (the
     same units tests/test_cmdring.py pins, importable without jax)."""
@@ -223,7 +150,7 @@ def gate_smoke() -> None:
             "unsupported_op": 0, "compressed": 0,
         },
     }
-    pr.check_cmdring(dict(good), {})
+    pr.check_cmdring(dict(good))
     fused_good = dict(
         good,
         gang_cmdring_fused_step_us=9000.0,
@@ -237,7 +164,7 @@ def gate_smoke() -> None:
             "unsupported_op": 0, "compressed": 0, "fused_decomposed": 0,
         },
     )
-    pr.check_cmdring(dict(fused_good), {})
+    pr.check_cmdring(dict(fused_good))
     for mutate, expect in (
         ({"gang_cmdring_redispatches_per_window": 1.0}, "re-dispatched"),
         (
@@ -246,7 +173,7 @@ def gate_smoke() -> None:
         ),
     ):
         try:
-            pr.check_cmdring(dict(good, **mutate), {})
+            pr.check_cmdring(dict(good, **mutate))
         except pr.CmdringGateError as e:
             assert expect in str(e), e
         else:
@@ -262,7 +189,7 @@ def gate_smoke() -> None:
         ({"gang_cmdring_fused_step_us": 20000.0}, "buy nothing"),
     ):
         try:
-            pr.check_cmdring(dict(fused_good, **mutate), {})
+            pr.check_cmdring(dict(fused_good, **mutate))
         except pr.CmdringGateError as e:
             assert expect in str(e), e
         else:
@@ -273,7 +200,6 @@ def gate_smoke() -> None:
 def main() -> int:
     codec_smoke()
     fused_smoke()
-    mailbox_smoke()
     gate_smoke()
     print("ring smoke: all ok")
     return 0

@@ -1119,10 +1119,10 @@ CMDRING_FIELDS = {"seqn": 0, "opcode": 1, "count": 2, "root": 3}
 
 def _ring_pkg(tmp_path, monkeypatch, consts, encoder):
     pkg = tmp_path / "accl_tpu"
-    (pkg / "ops" / "pallas").mkdir(parents=True)
+    (pkg / "ops").mkdir(parents=True)
     (pkg / "backends" / "xla").mkdir(parents=True)
     (pkg / "constants.py").write_text(consts)
-    (pkg / "ops" / "pallas" / "cmdring.py").write_text(encoder)
+    (pkg / "ops" / "cmdring.py").write_text(encoder)
     import accl_tpu.analysis.base as base_mod
     import accl_tpu.analysis.graph as graph_mod
 
@@ -1257,8 +1257,8 @@ def test_cmdring_flags_unimplemented_opcode_in_decoder(
 
 
 # the fused-opcode contract (kernel-initiated collectives): growing the
-# enum with FUSED_* compute slots without wiring the Operation map or a
-# lowering fails the tree — each wiring obligation has a known-bad
+# enum with FUSED_* compute slots without wiring the Operation map or
+# the decode loop fails the tree — each wiring obligation has a known-bad
 # fixture
 
 _RING_CONSTS_FUSED = _RING_CONSTS + """
@@ -1325,12 +1325,12 @@ def test_cmdring_flags_unmapped_fused_opcode(tmp_path, monkeypatch):
     assert "CMDRING_OPCODES" in findings[0].message
 
 
-def test_cmdring_flags_fused_opcode_missing_from_lowerings(
+def test_cmdring_flags_fused_opcode_missing_from_the_decode_loop(
     tmp_path, monkeypatch
 ):
-    """The both-lowerings presence check: a fused opcode the decode
-    module (the shared decode loop BOTH lowerings run) never references
-    is an unimplemented epilogue, caught by the tree not a workload."""
+    """The presence check: a fused opcode the decode module (the decode
+    loop every window runs) never references is an unimplemented
+    epilogue, caught by the tree not a workload."""
     decoder = _RING_DECODER_FUSED.replace(
         "    if op == CmdOpcode.FUSED_APPLY:\n"
         "        return own - fp * sum(blocks)\n", ""

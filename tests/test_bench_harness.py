@@ -9,7 +9,7 @@ number may hide the device.
 * the compile-cache helper, the peak table, and the refusals that took
   the place of quiet fallbacks (``xla_group`` past the device count,
   ``dryrun_multichip`` short of devices, the dist launcher on a TPU
-  host, a failed native build, the persistent ring form on a TPU).
+  host, a failed native build).
 
 Everything here is stubbed or tiny: no chip, no child process except
 ``chip_soak``'s own refusal.
@@ -86,7 +86,6 @@ def test_smoke_facade_leg_tiny():
     w = out["window"]
     assert w["slots"] > 0 and w["fallbacks"] == {}
     assert w["breaker_strikes"] == {} and w["warm_interactions"] == 1
-    assert w["lowering"] == "xla"  # the CPU mesh's default, named
 
 
 def test_smoke_kernels_leg_tiny():
@@ -635,30 +634,14 @@ def test_wire_cast_lane_cannot_be_compiled_away(lane):
     assert float(jnp.max(jnp.abs(fn(x) - x))) > 0.0
 
 
-def test_ring_on_a_tpu_takes_what_compiled_there(monkeypatch):
-    """Chip run, PR 21: the Pallas sequencer does not lower on a
-    multi-chip TPU and the xla lowering's persistent form does not lower
-    on any, so a TPU gets the xla lowering, inline."""
-    from accl_tpu.backends.xla import cmdring
-
-    monkeypatch.delenv("ACCL_CMDRING_LOWERING", raising=False)
-    assert cmdring.persistent_runs_lower() is True  # the CPU mesh
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert cmdring.persistent_runs_lower() is False
-    assert cmdring.default_lowering() == "xla"
-    monkeypatch.setenv("ACCL_CMDRING_LOWERING", "pallas")  # by name only
-    assert cmdring.default_lowering() == "pallas"
-
-
-def test_ring_stream_stays_inline_without_the_persistent_form():
-    """What a TPU backend runs: windows posted ahead of the device ride
-    one inline program each — no mailbox run, no fallback, same bytes."""
+def test_ring_stream_is_one_program_a_window():
+    """What every backend runs: windows dispatched ahead of the device
+    ride one program each — no fallback, same bytes."""
     from accl_tpu.core import xla_group
 
     g = xla_group(2)
     try:
         ring = g[0].engine.gang.cmdring
-        ring.persistent = False
         n, windows = 32, 4
         send = [a.create_buffer_from(np.full(n, r + 1.0, np.float32))
                 for r, a in enumerate(g)]
@@ -680,8 +663,7 @@ def test_ring_stream_stays_inline_without_the_persistent_form():
 
         chip_smoke._run_ranks(g, stream)
         st = ring.stats()
-        assert st["persistent"] is False
-        assert st["mailbox_posts"] == 0 and st["dispatches"] == windows
+        assert st["refills"] == st["dispatches"] == windows
         assert st["slots"] == 2 * windows and st["fallbacks"] == {}
         out[0].sync_from_device()
         np.testing.assert_array_equal(out[0].data, 3.0)

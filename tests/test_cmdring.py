@@ -1,15 +1,15 @@
-"""Command-ring mechanics: the device-resident sequencer contract.
+"""Command-ring mechanics: a batch becomes one program.
 
 The ring's counter-asserted claim (ISSUE 10 / ROADMAP item 1): a warm
 batched window of N eligible collectives costs exactly ONE host refill
 interaction — the host encodes slots and rings the doorbell, the
-sequencer program decodes and executes the window on device, and the
+window program decodes and executes the window on device, and the
 drainer polls the status word.  These tests pin the mechanics around
 that claim: slot encode/decode from the one layout table, wrap-around,
 refill underrun (sequencer parks — no spin), oversized/unsupported
 fallback to host dispatch, soft_reset teardown realigning seqn, and the
 ``ring_resident`` telemetry trail.  Runs on the 8-device virtual CPU
-mesh (xla sequencer lowering — the Pallas lowering is the chip tier).
+mesh; the window has one form, the one the chip runs.
 """
 
 import numpy as np
@@ -32,7 +32,7 @@ from accl_tpu.cmdring import (
     ring_widths,
 )
 from accl_tpu.core import xla_group
-from accl_tpu.ops.pallas.cmdring import (
+from accl_tpu.ops.cmdring import (
     decode_slot,
     encode_slot,
     encode_window,
@@ -627,7 +627,7 @@ def test_check_cmdring_accepts_pre_persistence_capture():
 
 
 # ---------------------------------------------------------------------------
-# the persistent sequencer: full opcode space, mixed windows
+# full opcode space, mixed windows, pipelined streams
 # ---------------------------------------------------------------------------
 
 
@@ -674,7 +674,7 @@ def test_mixed_opcode_window_rides_ring(g4):
             req.check()
         return reqs
 
-    run_parallel(g4, work)  # cold: arms the run, compiles the program
+    run_parallel(g4, work)  # cold: compiles the program
     st0 = ring.stats()
     ic0 = _interactions(g4[0])
     reqs = run_parallel(g4, work)
@@ -722,72 +722,142 @@ def test_mixed_opcode_window_rides_ring(g4):
         np.testing.assert_allclose(car[r].data, car_ref)
 
 
-def test_sustained_stream_zero_redispatch(g4):
-    """THE persistence counter-assert: a warm sustained stream of K
-    refill windows posted back-to-back executes with 0 program
-    re-dispatches after the first — the sequencer run survives across
-    refills and every doorbell after the first is a mailbox write."""
+def test_pipelined_stream_of_mixed_windows_one_interaction_each(g4):
+    """A warm stream of K windows dispatched back-to-back without a
+    drain (``_dispatch_pending``), each mixing allreduce, reduce-scatter,
+    allgather and alltoall: every window is one refill, one program and
+    one host interaction — K in all — and every rank's requests complete
+    in issue order."""
     ring = _ring(g4[0])
-    n = 32
-    K = 6
-    send = [
-        a.create_buffer_from(np.full(n, float(r + 1), np.float32))
-        for r, a in enumerate(g4)
+    n, world, K = 16, 4, 5
+    base = [
+        np.arange(n, dtype=np.float32) + 8.0 * (r + 1)
+        for r in range(world)
     ]
-    out = [a.create_buffer(n, np.float32) for a in g4]
+    wide = [
+        np.arange(world * n, dtype=np.float32) * 0.5 + 100.0 * (r + 1)
+        for r in range(world)
+    ]
+    send = [a.create_buffer_from(base[r]) for r, a in enumerate(g4)]
+    send_w = [a.create_buffer_from(wide[r]) for r, a in enumerate(g4)]
+    ar = [a.create_buffer(n, np.float32) for a in g4]
+    rs = [a.create_buffer(n, np.float32) for a in g4]
+    ag = [a.create_buffer(world * n, np.float32) for a in g4]
+    a2a = [a.create_buffer(world * n, np.float32) for a in g4]
+    order = {r: [] for r in range(world)}
 
     def stream(a, r):
-        """K windows posted PIPELINED: _dispatch_pending posts each
-        window without draining (batch exit would drain the in-flight
-        window and serialize the stream), so the host genuinely runs
-        ahead of the sequencer — the regime the resident run serves."""
-        all_reqs = []
+        """_dispatch_pending launches each window without draining
+        (batch exit would drain the in-flight window and serialize the
+        stream), so the host genuinely runs ahead of the device."""
+        reqs = []
         a.begin_batch()
         try:
-            for _ in range(K):
-                all_reqs.extend(
-                    a.allreduce(send[r], out[r], n, run_async=True)
-                    for _ in range(3)
-                )
-                a._dispatch_pending()  # post, do NOT drain
+            for k in range(K):
+                window = [
+                    a.allreduce(send[r], ar[r], n, run_async=True),
+                    a.reduce_scatter(send_w[r], rs[r], n, run_async=True),
+                    a.allgather(send[r], ag[r], n, run_async=True),
+                    a.alltoall(send_w[r], a2a[r], n, run_async=True),
+                ]
+                for i, req in enumerate(window):
+                    req.add_done_callback(
+                        lambda k=k, i=i: order[r].append((k, i))
+                    )
+                reqs.extend(window)
+                a._dispatch_pending()  # launch, do NOT drain
         finally:
             a.end_batch()  # the one drain for the whole stream
-        for req in all_reqs:
+        for req in reqs:
             assert req.wait(60)
             req.check()
-        return all_reqs
+        return reqs
 
-    # the contract under test: posts arriving WITHIN the linger ride
-    # the live run.  The default linger is sized for device-stream
-    # politeness (ms); a CI box's thread scheduling between gang
-    # assemblies can exceed it, so pin a test linger that the posting
-    # cadence is guaranteed to beat — the knob the env exposes.
-    saved = ring.linger_s
-    ring.linger_s = 0.5
-    try:
-        run_parallel(g4, stream)  # cold: compile + arm the resident run
-        st0 = ring.stats()
-        reqs = run_parallel(g4, stream)
-        st1 = ring.stats()
-    finally:
-        ring.linger_s = saved
-    assert st1["refills"] - st0["refills"] == K
-    # 0 re-dispatches after the first: at most ONE dispatch serves the
-    # whole warm stream (0 when the cold pass's resident run is still
-    # live), every other doorbell is a mailbox write
-    dispatches = st1["dispatches"] - st0["dispatches"]
-    assert dispatches <= 1, (
-        f"sequencer re-dispatched {dispatches - 1} times across {K} "
-        "warm windows — the run did not survive across refills"
+    run_parallel(g4, stream)  # cold: compiles the window program
+    for r in range(world):
+        order[r].clear()
+    st0 = ring.stats()
+    ic0 = _interactions(g4[0])
+    reqs = run_parallel(g4, stream)
+    st1 = ring.stats()
+    assert _interactions(g4[0]) - ic0 == K, (
+        "a warm pipelined stream of K windows must cost K host "
+        "interactions: one program launch a window, nothing else"
     )
-    assert st1["mailbox_posts"] - st0["mailbox_posts"] >= K - 1
-    assert st1["sustained_occupancy"] > 1.0
-    for req in reqs:
-        for r in req:
-            assert r.ring_resident is True
-    for r in range(4):
-        out[r].sync_from_device()
-        np.testing.assert_allclose(out[r].data, 10.0)
+    assert st1["refills"] - st0["refills"] == K
+    assert st1["doorbells"] - st0["doorbells"] == K
+    assert st1["dispatches"] - st0["dispatches"] == K
+    assert st1["slots"] - st0["slots"] == 4 * K
+    assert st1["fallbacks"] == st0["fallbacks"]
+    issue_order = [(k, i) for k in range(K) for i in range(4)]
+    for r in range(world):
+        assert order[r] == issue_order, f"rank {r} completed out of order"
+    for rank_reqs in reqs:
+        for req in rank_reqs:
+            assert req.ring_resident is True
+    stack = np.stack(wide)
+    rs_ref = stack.sum(axis=0).reshape(world, n)
+    a2a_ref = stack.reshape(world, world, n).transpose(1, 0, 2).reshape(
+        world, world * n
+    )
+    for r in range(world):
+        ar[r].sync_from_device()
+        np.testing.assert_allclose(ar[r].data, np.sum(base, axis=0))
+        rs[r].sync_from_device()
+        np.testing.assert_allclose(rs[r].data, rs_ref[r])
+        ag[r].sync_from_device()
+        np.testing.assert_allclose(ag[r].data, np.concatenate(base))
+        a2a[r].sync_from_device()
+        np.testing.assert_allclose(a2a[r].data, a2a_ref[r])
+
+
+@pytest.mark.parametrize("result_width", ["exact", "wider"])
+def test_successive_windows_into_one_buffer_land_newest_last(
+    g4, result_width
+):
+    """K pipelined windows all write the SAME result buffer with
+    different values: what a read finds afterwards is the last window's
+    — whether the buffer adopts by pointer swap (exact width) or by a
+    deferred store into a wider root (which must layer in issue
+    order)."""
+    n, world, K = 32, 4, 4
+    sends = [
+        [
+            a.create_buffer_from(
+                np.full(n, float((k + 1) * (r + 1)), np.float32)
+            )
+            for r, a in enumerate(g4)
+        ]
+        for k in range(K)
+    ]
+    width = n if result_width == "exact" else 2 * n
+    out = [
+        a.create_buffer_from(np.full(width, -1.0, np.float32))
+        for a in g4
+    ]
+
+    def stream(a, r):
+        reqs = []
+        a.begin_batch()
+        try:
+            for k in range(K):
+                reqs.append(
+                    a.allreduce(sends[k][r], out[r], n, run_async=True)
+                )
+                a._dispatch_pending()
+        finally:
+            a.end_batch()
+        for req in reqs:
+            assert req.wait(60)
+            req.check()
+
+    for _ in range(2):  # cold, then warm
+        run_parallel(g4, stream)
+        for r in range(world):
+            out[r].sync_from_device()
+            np.testing.assert_array_equal(out[r].data[:n], 10.0 * K)
+            # the tail of a wider buffer is not the windows' to touch
+            np.testing.assert_array_equal(out[r].data[n:], -1.0)
 
 
 def test_sendrecv_pair_rides_ring_slots():
@@ -856,27 +926,6 @@ def test_sendrecv_pair_rides_ring_slots():
     finally:
         for a in g:
             a.deinit()
-
-
-def test_pallas_pack_unpack_round_trip():
-    """The mega-window packer and unpacker must agree on the per-slot
-    chunking or padding reads back as payload (the review-found
-    corruption: a 1-wide op whose count divides the world size packed
-    chunked but unpacked flat — tail elements came back zero)."""
-    import jax.numpy as jnp
-
-    from accl_tpu.ops.pallas.cmdring import _pack_rows, _unpack_rows
-
-    x = jnp.arange(256, dtype=jnp.float32)
-    for chunks in (1, 2, 4):
-        rows = 16 if chunks == 1 else 8 * chunks
-        packed = _pack_rows(x, rows, chunks, jnp.float32)
-        got = _unpack_rows(packed, 256, chunks)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
-    # the failure mode the fix pins: chunk-packed, flat-unpacked
-    packed = _pack_rows(x, 16, 2, jnp.float32)
-    wrong = _unpack_rows(packed, 256, 1)
-    assert not np.array_equal(np.asarray(wrong), np.asarray(x))
 
 
 def test_torn_p2p_collective_position_fails_fast():
@@ -1119,7 +1168,11 @@ def test_wraparound_and_soft_reset_under_mixed_windows(g4):
             req.check()
 
     wraps0 = ring.stats()["wraps"]
-    rounds = depth // 3 + 2  # head must cross the ring boundary
+    # 3-slot windows from wherever earlier tests left the head: 3 and
+    # the depth share no factor, so `depth` rounds start once at every
+    # residue and one of them must straddle the ring boundary
+    assert depth % 3
+    rounds = depth
     for _ in range(rounds):
         run_parallel(g4, window)
     st = ring.stats()
@@ -1404,92 +1457,77 @@ def test_fused_ineligible_decomposes_counted(g4):
 
 
 # ---------------------------------------------------------------------------
-# streaming-posture registers: autotuner axes dispatched per plan key
+# one form: nothing selects, so nothing that used to select is read
 # ---------------------------------------------------------------------------
 
 
-def test_window_posture_reads_tuning_overlay(g4):
-    """_window_posture: the lead call's per-bucket register overlay
-    steers the arming window's (run_windows, linger_s); calls without
-    an overlay keep the gang's env-default posture (0 = default)."""
-    from accl_tpu.backends.base import CallOptions
+@pytest.mark.parametrize("name,value", [
+    ("ACCL_CMDRING_LOWERING", "pallas"),
+    ("ACCL_CMDRING_RUN_WINDOWS", "3"),
+    ("ACCL_CMDRING_LINGER_MS", "900"),
+])
+def test_retired_environment_names_change_nothing(monkeypatch, name, value):
+    """The three names that chose between window forms are read by
+    nothing: with one set, a fresh gang reports the same ring and lowers
+    the same window program, and a window still rides it."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
 
-    ring = _ring(g4[0])
-    lead = CallOptions(
-        op=Operation.ALLREDUCE,
-        tuning={"cmdring_run_windows": 5, "cmdring_linger_us": 200000},
-    )
-    rw, ls = ring._window_posture([([], lead, {})])
-    assert rw == 5 and abs(ls - 0.2) < 1e-12
-    plain = CallOptions(op=Operation.ALLREDUCE)
-    assert ring._window_posture([([], plain, {})]) == (
-        ring.run_windows, ring.linger_s,
-    )
-    # a zero register means "env default", not "zero windows"
-    zero = CallOptions(
-        op=Operation.ALLREDUCE,
-        tuning={"cmdring_run_windows": 0, "cmdring_linger_us": 0},
-    )
-    assert ring._window_posture([([], zero, {})]) == (
-        ring.run_windows, ring.linger_s,
-    )
+    from accl_tpu.cmdring import WindowShape
+    from accl_tpu.ops import cmdring as devring
+    from accl_tpu.ops.driver import AXIS, _mesh_key
 
-
-def test_posture_plan_overlay_arms_resident_run(g4):
-    """E2E per-plan-key dispatch: a loaded TuningPlan's posture
-    registers ride CallOptions.tuning into _window_posture, so the
-    resident run armed by that bucket's stream carries the plan's
-    run-window budget and linger — not the env defaults."""
-    from accl_tpu.plans import size_bucket
-    from accl_tpu.tuning import TuningPlan
-
-    ring = _ring(g4[0])
-    n = 32
-    plan = TuningPlan(
-        world=4, tier="xla",
-        entries={"allreduce": {size_bucket(n): {"registers": {
-            "cmdring_run_windows": 3, "cmdring_linger_us": 900000,
-        }}}},
-    )
-    send = [
-        a.create_buffer_from(np.full(n, float(r + 1), np.float32))
-        for r, a in enumerate(g4)
-    ]
-    out = [a.create_buffer(n, np.float32) for a in g4]
-
-    def stream(a, r):
-        all_reqs = []
-        a.begin_batch()
+    def observe():
+        devring._windows_program.cache_clear()
+        g = xla_group(2)
         try:
-            for _ in range(3):
-                all_reqs.extend(
-                    a.allreduce(send[r], out[r], n, run_async=True)
-                    for _ in range(2)
-                )
-                a._dispatch_pending()  # post pipelined, do NOT drain
-        finally:
-            a.end_batch()
-        for req in all_reqs:
-            assert req.wait(60)
-            req.check()
+            ring = _ring(g[0])
+            n = 16
+            send = [
+                a.create_buffer_from(np.full(n, r + 1.0, np.float32))
+                for r, a in enumerate(g)
+            ]
+            out = [a.create_buffer(n, np.float32) for a in g]
 
-    for a in g4:
-        a.load_tuning_plan(plan)
-    try:
-        run_parallel(g4, stream)  # arms the run under the overlay
-        comm_id = g4[0]._world.id
-        run = ring._sessions[comm_id].run
-        assert run is not None, "stream never armed a resident run"
-        assert run.mbox.run_windows == 3
-        assert abs(run.mbox.linger_s - 0.9) < 1e-12
-    finally:
-        for a in g4:
-            a.unload_tuning_plan()
-        run_parallel(g4, lambda a, r: a.soft_reset())  # kill the 0.9 s
-        # linger before the next test's counters read the ring
-    for r in range(4):
-        out[r].sync_from_device()
-        np.testing.assert_allclose(out[r].data, 10.0)
+            def window(a, r):
+                with a.batch():
+                    reqs = [
+                        a.allreduce(send[r], out[r], n, run_async=True),
+                        a.bcast(send[r], n, root=1, run_async=True),
+                    ]
+                for q in reqs:
+                    assert q.wait(60)
+                    q.check()
+
+            run_parallel(g, window)
+            st = ring.stats()
+            out[0].sync_from_device()
+            np.testing.assert_array_equal(out[0].data, 3.0)
+            mesh = g[0].engine.gang.submesh(g[0].comm)
+            shape = WindowShape(2, [n, n], [n, n], [None, None], np.float32)
+            sh = NamedSharding(mesh, PartitionSpec(AXIS))
+            args = [jax.ShapeDtypeStruct(
+                (2 * 2, CMDRING_SLOT_WORDS), np.int32, sharding=sh
+            )] + [
+                jax.ShapeDtypeStruct((2 * n,), np.float32, sharding=sh)
+            ] * 2
+            text = devring._windows_program(
+                _mesh_key(mesh), shape.key(), 1
+            ).lower(*args).as_text()
+        finally:
+            for a in g:
+                a.deinit()
+        for volatile in ("windows", "window_latency_sum_us",
+                         "window_latency_log2_us"):
+            st.pop(volatile)
+        return st, text
+
+    monkeypatch.delenv(name, raising=False)
+    plain = observe()
+    monkeypatch.setenv(name, value)
+    assert observe() == plain
+    assert plain[0]["dispatches"] == plain[0]["refills"] == 1
+    assert "all_gather" in plain[1]
 
 
 # ---------------------------------------------------------------------------

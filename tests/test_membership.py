@@ -591,8 +591,8 @@ def test_demotion_decision_latched_per_seq():
 
 def test_ring_breaker_degrades_and_reprobes(monkeypatch):
     """A comm whose ring windows fail degrades ring -> host (counted
-    circuit_open), re-probes INLINE after the cool-down, and a probe
-    success restores ring dispatch with fallback counters quiet."""
+    circuit_open), re-probes after the cool-down, and a probe success
+    restores ring dispatch with fallback counters quiet."""
     monkeypatch.setenv("ACCL_CMDRING_COOLDOWN_S", "0.2")
     from accl_tpu.core import xla_group
 
@@ -634,7 +634,7 @@ def test_ring_breaker_degrades_and_reprobes(monkeypatch):
         time.sleep(0.25)  # cool-down -> half-open
         run_parallel(g, batch_round, timeout=120.0)  # the probe window
         st = ring.stats()
-        assert st["slots"] > base_slots  # probe rode the ring (inline)
+        assert st["slots"] > base_slots  # the probe rode the ring
         assert st["breakers"][str(g[0].comm.id)]["state"] == "closed"
         fallbacks_after_restore = st["fallbacks"].get("circuit_open", 0)
         run_parallel(g, batch_round, timeout=120.0)
@@ -643,6 +643,64 @@ def test_ring_breaker_degrades_and_reprobes(monkeypatch):
         assert st["fallbacks"].get("circuit_open", 0) == (
             fallbacks_after_restore
         )
+    finally:
+        _deinit(g)
+
+
+def test_ring_breaker_probe_is_an_ordinary_window(monkeypatch):
+    """Half-open after the cool-down, the next warm window IS the probe
+    and nothing sets it apart: one refill, one program, one host
+    interaction, logged with OK retcodes — and its completion closes
+    the breaker."""
+    monkeypatch.setenv("ACCL_CMDRING_COOLDOWN_S", "0.2")
+    from accl_tpu.core import xla_group
+
+    g = xla_group(4)
+    try:
+        ring = g[0].engine.gang.cmdring
+        n = 16
+        send = [
+            a.create_buffer_from(np.full(n, float(r + 1), np.float32))
+            for r, a in enumerate(g)
+        ]
+        ar = [a.create_buffer(n, np.float32) for a in g]
+        ag = [a.create_buffer(4 * n, np.float32) for a in g]
+
+        def window(a, r):
+            with a.batch():
+                reqs = [
+                    a.allreduce(send[r], ar[r], n, run_async=True),
+                    a.allgather(send[r], ag[r], n, run_async=True),
+                ]
+            for q in reqs:
+                assert q.wait(60)
+                q.check()
+
+        run_parallel(g, window, timeout=120.0)  # cold: compiles
+        brk = ring.breaker_for(g[0].comm.id)
+        brk.record_failure("TimeoutError")
+        brk.record_failure("TimeoutError")
+        assert brk.allow() == "open"
+        time.sleep(0.25)
+        assert brk.allow() == "probe"  # half-open
+        st0 = ring.stats()
+        ic0 = g[0].capabilities()["device_interactions"]
+        run_parallel(g, window, timeout=120.0)  # the probe
+        st1 = ring.stats()
+        assert g[0].capabilities()["device_interactions"] - ic0 == 1
+        assert st1["refills"] - st0["refills"] == 1
+        assert st1["dispatches"] - st0["dispatches"] == 1
+        assert st1["slots"] - st0["slots"] == 2
+        assert st1["fallbacks"] == st0["fallbacks"]
+        assert [s["retcode"] for s in st1["windows"][-1]["slots"]] == [1, 1]
+        assert st1["breakers"][str(g[0].comm.id)]["state"] == "closed"
+        for r in range(4):
+            ar[r].sync_from_device()
+            np.testing.assert_allclose(ar[r].data, 10.0)
+            ag[r].sync_from_device()
+            np.testing.assert_allclose(
+                ag[r].data, np.repeat([1.0, 2.0, 3.0, 4.0], n)
+            )
     finally:
         _deinit(g)
 

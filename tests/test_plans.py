@@ -140,90 +140,86 @@ def test_validate_registers_rejects_garbage():
     assert out == {"allreduce_algorithm": "ring", "ring_segments": 2}
 
 
-def test_validate_registers_posture_clamps():
-    """The persistent-sequencer posture registers validate with the
-    engines' own SET_TUNING bounds: an unbounded run budget or >1s
-    linger would pin the device stream, so a plan carrying one fails at
-    load — not as CONFIG_ERROR mid-collective."""
-    from accl_tpu.constants import CMDRING_MAX_RUN_WINDOWS
+@pytest.mark.parametrize(
+    "name", ["cmdring_run_windows", "cmdring_linger_us"]
+)
+def test_retired_posture_registers_are_refused_by_name(name, tmp_path):
+    """The two registers that steered the command ring's resident run
+    went with it: a plan that still carries one fails at load, by name,
+    like any unknown register — not as CONFIG_ERROR mid-collective."""
+    from accl_tpu.constants import TUNING_DEFAULTS, TUNING_KEY_NAMES
 
-    out = validate_registers({
-        "cmdring_run_windows": CMDRING_MAX_RUN_WINDOWS,
-        "cmdring_linger_us": 1_000_000,
-    })
-    assert out == {
-        "cmdring_run_windows": CMDRING_MAX_RUN_WINDOWS,
-        "cmdring_linger_us": 1_000_000,
-    }
-    assert validate_registers({"cmdring_run_windows": 0}) == {
-        "cmdring_run_windows": 0  # 0 = env default, always valid
-    }
-    with pytest.raises(ValueError, match="cmdring_run_windows"):
-        validate_registers(
-            {"cmdring_run_windows": CMDRING_MAX_RUN_WINDOWS + 1}
-        )
-    with pytest.raises(ValueError, match="cmdring_linger_us"):
-        validate_registers({"cmdring_linger_us": 1_000_001})
-    with pytest.raises(ValueError, match="negative"):
-        validate_registers({"cmdring_run_windows": -1})
+    assert name not in REGISTER_DEFAULTS and name not in TUNING_DEFAULTS
+    assert name not in TUNING_KEY_NAMES.values()
+    with pytest.raises(ValueError, match=f"unknown tuning register '{name}'"):
+        validate_registers({name: 1})
+    stale = tmp_path / "stale_posture.json"
+    stale.write_text(json.dumps(
+        {"world": 2, "tier": "xla", "defaults": {name: 1}, "entries": {}}
+    ))
+    with pytest.raises(ValueError, match=name):
+        TuningPlan.load(str(stale))
 
 
-def test_candidates_race_posture_axes():
-    """ACCL_CMDRING_RUN_WINDOWS / ACCL_CMDRING_LINGER_MS as autotuner
-    axes: raced for the XLA gang tier's allreduce only (the ring lives
-    there), out-of-bounds candidates filtered, defaults candidate 0."""
-    from accl_tpu.constants import CMDRING_MAX_RUN_WINDOWS
-    from accl_tpu.tuning import _candidates
+@pytest.mark.parametrize("tier", ["emulator", "xla"])
+@pytest.mark.parametrize("key", [13, 14])
+def test_set_tuning_of_a_retired_register_is_config_error(tier, key):
+    """Registers 13 and 14 stay unassigned (the others keep their
+    numbers): the engine answers a SET_TUNING of either with
+    CONFIG_ERROR, as for any unknown key, and writes nothing."""
+    from accl_tpu import ACCLError
+    from accl_tpu.constants import ConfigFunction, ErrorCode, TuningKey
 
-    cands = _candidates(
-        "xla", "allreduce", 4, False, (), (),
-        cmdring_run_windows=(32, 128, CMDRING_MAX_RUN_WINDOWS + 1, 0),
-        cmdring_linger_us=(500, 5000, 2_000_000),
-    )
-    assert cands[0] == {}  # the defaults always race
-    assert {"cmdring_run_windows": 32} in cands
-    assert {"cmdring_run_windows": 128} in cands
-    assert {"cmdring_linger_us": 500} in cands
-    assert {"cmdring_linger_us": 5000} in cands
-    # out-of-bounds / zero candidates are filtered, not clamped
-    for c in cands:
-        assert c.get("cmdring_run_windows", 1) > 0
-        assert c.get("cmdring_run_windows", 0) <= CMDRING_MAX_RUN_WINDOWS
-        assert c.get("cmdring_linger_us", 0) <= 1_000_000
-    # the axes are gang-ring scoped: no posture candidates for the
-    # emulator tier or for non-allreduce collectives
-    for tier, op in (("emulator", "allreduce"), ("xla", "bcast")):
-        others = _candidates(
-            tier, op, 4, False, (), (),
-            cmdring_run_windows=(32,), cmdring_linger_us=(500,),
-        )
-        assert not any(
-            "cmdring_run_windows" in c or "cmdring_linger_us" in c
-            for c in others
-        ), (tier, op)
+    assert key not in {int(k) for k in TuningKey}
+    assert int(TuningKey.WIRE_DTYPE) == 12
+    assert int(TuningKey.HIERARCHICAL) == 15
+    if tier == "xla":
+        from accl_tpu.core import xla_group
+
+        g = xla_group(2)
+        table = g[0].engine.gang.tuning
+    else:
+        g = emulated_group(2)
+        table = g[0].engine.tuning
+    try:
+        before = dict(table)
+        with pytest.raises(ValueError):
+            g[0].set_tuning(key, 1)  # the facade knows no such key
+        with pytest.raises(ACCLError) as ei:
+            g[0]._config(ConfigFunction.SET_TUNING, 1.0, key=key)
+        assert ei.value.code == ErrorCode.CONFIG_ERROR
+        assert dict(table) == before
+    finally:
+        for a in g:
+            a.deinit()
 
 
-def test_tuning_cli_exposes_posture_axes(tmp_path, capsys):
-    """The sweep CLI races the posture registers end to end: the
-    ``--cmdring-run-windows`` / ``--cmdring-linger-us`` flags parse,
-    flow into autotune, and the emitted plan stays loadable (on the
-    emulator tier the axes are a no-op by design — gang-ring scoped —
-    so the race just keeps the defaults)."""
+def test_tuning_cli_runs_and_refuses_the_retired_posture_flags(
+    tmp_path, capsys
+):
+    """The sweep CLI end to end: a race on the emulator tier emits a
+    loadable plan, and the two flags that raced the resident run's
+    posture are no longer options."""
     from accl_tpu.tuning import main as tuning_main
 
     out = tmp_path / "plan.json"
-    rc = tuning_main([
+    base = [
         "--backend", "emulator", "--world", "2",
         "--min-exp", "4", "--max-exp", "4", "--runs", "1",
         "--collectives", "allreduce", "--segments", "1",
-        "--cmdring-run-windows", "32",
-        "--cmdring-linger-us", "500",
         "--out", str(out),
-    ])
-    assert rc == 0
+    ]
+    for flag in ("--cmdring-run-windows", "--cmdring-linger-us"):
+        with pytest.raises(SystemExit) as ei:
+            tuning_main(base + [flag, "32"])
+        assert ei.value.code == 2
+        assert "unrecognized arguments" in capsys.readouterr().err
+    assert not out.exists()
+    assert tuning_main(base) == 0
     plan = TuningPlan.load(str(out))
     assert plan.world == 2 and plan.tier == "emulator"
     assert "allreduce" in plan.entries
+    assert not any("cmdring" in k for k in plan.provenance)
 
 
 def test_stale_plan_file_fails_loudly(tmp_path):

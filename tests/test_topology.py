@@ -647,7 +647,7 @@ def test_autotune_candidate_axes_include_topology_lanes():
     cands = _candidates(
         "emulator", "allreduce", 4, include_pallas=False,
         eager_candidates=(), segments=(1,), pipeline_thresholds=(),
-        wire_dtypes=(), cmdring_run_windows=(), cmdring_linger_us=(),
+        wire_dtypes=(),
         race_hierarchical=True, wire_dtypes_ici=(),
         wire_dtypes_dcn=("int8",),
     )
@@ -661,7 +661,7 @@ def test_autotune_candidate_axes_include_topology_lanes():
     flat_ops = _candidates(
         "emulator", "sendrecv", 4, include_pallas=False,
         eager_candidates=(), segments=(1,), pipeline_thresholds=(),
-        wire_dtypes=(), cmdring_run_windows=(), cmdring_linger_us=(),
+        wire_dtypes=(),
         race_hierarchical=True,
     )
     assert all("hierarchical" not in c for c in flat_ops)

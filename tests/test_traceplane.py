@@ -390,7 +390,6 @@ def test_ring_window_log_and_spans(ring4):
     prom = g[0].telemetry_prometheus()
     assert "accl_cmdring_run_state" in prom
     assert "accl_cmdring_window_latency_us" in prom
-    assert "accl_cmdring_mailbox_depth" in prom
 
 
 def test_cmdring_route_and_index_page(ring4):
@@ -404,7 +403,7 @@ def test_cmdring_route_and_index_page(ring4):
         ) as r:
             ring = json.loads(r.read().decode())
         assert ring.get("enabled") is True
-        assert "windows" in ring and "mailbox_depth" in ring
+        assert "windows" in ring and "window_latency_log2_us" in ring
         with urllib.request.urlopen(
             f"http://127.0.0.1:{port}/", timeout=10
         ) as r:
@@ -417,30 +416,46 @@ def test_cmdring_route_and_index_page(ring4):
         g[0].stop_monitor()
 
 
-def test_mailbox_depth_and_timing_units():
-    """Host-half introspection (jax-free): the mailbox reports queued
-    depth and per-window posted/pulled/pushed host timestamps."""
-    from accl_tpu.cmdring import (
-        SequencerMailbox, WindowShape, encode_slot, encode_window,
-    )
-    from accl_tpu.constants import CmdOpcode
-
-    shape = WindowShape(1, [4], [4], [None], np.float32)
-    mbox = SequencerMailbox(1, shape, run_windows=4, linger_s=0.1)
-    slots = encode_window([encode_slot(0, CmdOpcode.ALLREDUCE, 4)], 1)
-    payload = [np.ones((1, 4), np.float32)]
-    assert mbox.post(1, slots, payload)
-    assert mbox.post(2, slots, payload)
-    assert mbox.depth() == 2
-    live, got, rows = mbox.pull(0)
-    assert int(live) == 1
-    assert mbox.depth() == 1
-    status = np.stack([got[:, 0], np.ones(1, np.int32)], axis=1)
-    mbox.push(0, 1, status, [rows[0]])
-    t = mbox.take_timing(1)
-    assert t is not None
-    assert t["posted_ns"] <= t["pulled_ns"] <= t["pushed_ns"]
-    assert mbox.take_timing(1) is None  # consumed exactly once
+def test_ring_stats_schema(ring4):
+    """The introspection block after a window: every key a reader has
+    (perfbench reads ``fallbacks`` and ``lowering``, the monitor and the
+    gauges the rest) is there, a refill is a doorbell is a dispatch, and
+    the keys that described the resident run are gone, with their
+    gauges."""
+    g = ring4
+    n = 64
+    send = [
+        a.create_buffer_from(np.full(n, r + 1.0, np.float32))
+        for r, a in enumerate(g)
+    ]
+    out1 = [a.create_buffer(n, np.float32) for a in g]
+    out2 = [a.create_buffer(n, np.float32) for a in g]
+    _ring_window(g, send, out1, out2, n)
+    for a in g:
+        a.flush()
+    ring = g[0].engine.telemetry_report()["cmdring"]
+    kept = {
+        "enabled", "mode", "lowering", "depth", "state", "refills",
+        "doorbells", "dispatches", "slots", "wraps", "resets",
+        "max_window", "occupancy", "ops", "fallbacks", "chaos_faults",
+        "breakers", "slot_budgets", "comm_slots", "budgeted_windows",
+        "windows_logged", "window_latency_sum_us",
+        "window_latency_log2_us", "windows",
+    }
+    assert set(ring) == kept
+    assert ring["state"] in ("parked", "armed")
+    assert ring["refills"] == ring["doorbells"] == ring["dispatches"] >= 1
+    assert ring["lowering"] == "xla"
+    win = ring["windows"][-1]
+    assert set(win) == {
+        "window_id", "comm", "ts_us", "dur_us", "slots", "basis",
+    }
+    prom = g[0].telemetry_prometheus()
+    for gone in ("mailbox", "sustained_occupancy", "persistent",
+                 "run_windows", "linger"):
+        assert gone not in prom
+    assert "accl_engine_cmdring_dispatches" in prom
+    assert "accl_cmdring_op_slots_total" in prom
 
 
 # ---------------------------------------------------------------------------

@@ -1054,7 +1054,7 @@ def _wire_lint(tmp_path, decode_src: str, lane_src: str):
     from accl_tpu.analysis import run_checks
 
     pkg = tmp_path / "accl_tpu"
-    (pkg / "ops" / "pallas").mkdir(parents=True)
+    (pkg / "ops").mkdir(parents=True)
     (pkg / "backends" / "xla").mkdir(parents=True)
     (pkg / "constants.py").write_text(
         "CMDRING_FIELDS = {'seqn': 0, 'opcode': 1}\n"
@@ -1063,7 +1063,7 @@ def _wire_lint(tmp_path, decode_src: str, lane_src: str):
     )
     (pkg / "cmdring.py").write_text("")
     (pkg / "ops" / "wire.py").write_text(lane_src)
-    (pkg / "ops" / "pallas" / "cmdring.py").write_text(decode_src)
+    (pkg / "ops" / "cmdring.py").write_text(decode_src)
     (pkg / "backends" / "xla" / "cmdring.py").write_text("")
     orig_base = base_mod.package_root
     orig_graph = graph_mod.package_root
@@ -1084,10 +1084,6 @@ def _wire_lint(tmp_path, decode_src: str, lane_src: str):
 _GOOD_DECODE = """
 def _decode_slot_xla(slots, i, own):
     return devwire.wire_lane_roundtrip(own, None, 0)
-
-
-def _pallas_windows(slots, xs):
-    return devwire.wire_lane_roundtrip(xs, None, 0)
 """
 
 _GOOD_LANES = "WIRE_LANES = {'float16': 'cast', 'int8': 'scaled'}\n"
@@ -1097,17 +1093,16 @@ def test_acclint_wire_crosscheck_clean_fixture(tmp_path):
     assert not _wire_lint(tmp_path, _GOOD_DECODE, _GOOD_LANES)
 
 
-def test_acclint_wire_crosscheck_private_lowering_flagged(tmp_path):
-    # one lowering casting privately (no shared helper) is a finding
+def test_acclint_wire_crosscheck_private_cast_flagged(tmp_path):
+    # the decode loop casting privately (no shared helper) is a finding
     bad = _GOOD_DECODE.replace(
-        "def _pallas_windows(slots, xs):\n"
-        "    return devwire.wire_lane_roundtrip(xs, None, 0)",
-        "def _pallas_windows(slots, xs):\n"
-        "    return xs.astype('float16')",
+        "    return devwire.wire_lane_roundtrip(own, None, 0)",
+        "    return own.astype('float16')",
     )
     findings = _wire_lint(tmp_path, bad, _GOOD_LANES)
     assert len(findings) == 1
-    assert "_pallas_windows" in findings[0].message
+    assert "_decode_slot_xla" in findings[0].message
+    assert "privately" in findings[0].message
 
 
 def test_acclint_wire_crosscheck_missing_lane_flagged(tmp_path):
@@ -1118,10 +1113,12 @@ def test_acclint_wire_crosscheck_missing_lane_flagged(tmp_path):
     assert "int8" in findings[0].message
 
 
-def test_acclint_wire_crosscheck_lost_lowering_flagged(tmp_path):
-    bad = _GOOD_DECODE.replace("def _pallas_windows", "def _renamed")
+def test_acclint_wire_crosscheck_lost_decode_function_flagged(tmp_path):
+    bad = _GOOD_DECODE.replace("def _decode_slot_xla", "def _renamed")
     findings = _wire_lint(tmp_path, bad, _GOOD_LANES)
-    assert any("_pallas_windows" in f.message for f in findings)
+    assert len(findings) == 1
+    assert "_decode_slot_xla" in findings[0].message
+    assert "lost" in findings[0].message
 
 
 def test_acclint_whole_tree_clean_at_head():
