@@ -154,7 +154,7 @@ class TransformerConfig:
     # (tiny-T regimes where kernel padding overhead dominates).
     # "blockwise" forces the XLA online-softmax tile fold (no (T, T)
     # matrix in HBM, ops/attention.py); "flash" forces the Pallas
-    # kernel — trainable via its custom_vjp backward kernels
+    # kernel — trainable via its custom_vjp backward kernel
     # (ops/pallas/attention.py); "naive" forces materialized scores
     # through jax.nn.softmax.
     attention: str = "auto"
@@ -597,11 +597,14 @@ def _rope_rotate(x, tables):
 # form that fits HBM), so auto resolves to a fused form at/above this
 # and to naive only below it (tiny-T padding-overhead regime)
 _AUTO_FUSED_MIN_T = 1024
-# flash holds whole K/V (and whole Q/dO in its backward kernels) in VMEM
-# per batch-head: auto uses it only while K+V fit this budget (4 MiB =
-# T 8192 at hd<=128 bf16; the gate scales with the PADDED head dim and
-# dtype width, so wide-head or f32 configs fall back to the streaming
-# XLA fold instead of failing Mosaic's VMEM allocation)
+# flash holds whole K/V in VMEM per batch-head (and its one backward
+# kernel whole Q, dO, the dq block and an f32 dq accumulator: six times
+# K's bytes plus T x hd x 4, which that call computes from the shapes
+# and passes as its own VMEM limit — 24 MiB at this gate's edge): auto
+# uses it only while K+V fit this budget (4 MiB = T 8192 at hd<=128
+# bf16; the gate scales with the PADDED head dim and dtype width, so
+# wide-head or f32 configs fall back to the streaming XLA fold instead
+# of failing Mosaic's VMEM allocation)
 _AUTO_FLASH_KV_BYTES = 4 * 2**20
 
 
@@ -650,7 +653,7 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True):
         return blockwise_attention(q, k, v, causal=causal)
     if impl == "flash":
         # the Pallas kernel owns the fold schedule; its custom_vjp
-        # backward kernels make it trainable (rebuild probability tiles
+        # backward kernel makes it trainable (rebuilds probability tiles
         # from the saved logsumexp — no (T, T) residual)
         from ..ops.pallas.attention import flash_attention
 

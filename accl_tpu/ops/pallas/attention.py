@@ -294,13 +294,12 @@ def ring_attention(
 def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False):
     """One grid step computes one (bq, D) output block: fold the visiting
     k/v blocks with online softmax.  Outputs are written exactly once per
-    grid step (blocked o spec): every grid axis is independent, so none
-    needs an "arbitrary" ordering.  (A design choice, not a platform
-    limit: grid-revisited accumulator outputs compile and run on the
-    attached v5e — chip run, PR 21.)
+    grid step (blocked o spec): every grid axis of the FORWARD is
+    independent, so none needs an "arbitrary" ordering.  (The backward
+    kernel revisits its dq block across k tiles and orders that axis.)
 
     ``with_lse`` adds a per-row logsumexp output (the softmax normalizer,
-    ``m + log l``) — the residual the backward kernels need to rebuild
+    ``m + log l``) — the residual the backward kernel needs to rebuild
     the probabilities tile by tile without ever storing them."""
 
     def kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse):
@@ -350,7 +349,7 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False):
         if with_lse:
             # (bq, 1) sublane vector -> (bq,) lane vector: an explicit
             # relayout Mosaic supports; rows beyond t_real carry ~-1e30
-            # and are masked out by the backward kernels
+            # and are masked out by the backward kernel
             maybe_lse[0][0, 0, 0] = (
                 m + jnp.log(jnp.maximum(l, 1e-30))
             ).reshape(bq)
@@ -433,6 +432,7 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse):
         ],
         out_specs=out_specs,
         interpret=default_interpret(interpret),
+        name="flash_fwd",
     )(qf, kf, vf)
     out = res[0].reshape(B, H, Tp, Dp)[:, :, :T, :D]
     if not with_lse:
@@ -441,66 +441,27 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse):
     return out, lse
 
 
-def _flash_bwd_dq_kernel(causal, scale, bq, bk, nkb, t_real):
-    """dQ: grid step (bh, iq) owns one (bq, D) dq block, folding the k/v
-    blocks it attended to.  Probabilities are rebuilt from the saved
-    logsumexp (p = exp(s - lse)), never stored — the same FLOPs-for-HBM
-    trade the forward makes [FlashAttention-2 backward split: the dq pass
-    grids over q blocks so every output is written exactly once]."""
-
-    def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref):
-        iq = pl.program_id(1)
-        q = q_ref[0]
-        do = do_ref[0]
-        # (bq,) lane vectors -> (bq, 1) sublane vectors for row broadcast
-        lse = lse_ref[0, 0, 0].reshape(bq, 1)
-        delta = dl_ref[0, 0, 0].reshape(bq, 1)
-        q_pos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-
-        def fold(j, acc):
-            kb = k_ref[0, pl.ds(j * bk, bk), :]
-            vb = v_ref[0, pl.ds(j * bk, bk), :]
-            s = lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=_mxu_precision(q.dtype),
-            ) * scale
-            k_pos = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = (k_pos < t_real) & (q_pos < t_real)
-            if causal:
-                mask &= q_pos >= k_pos
-            # explicit where: padded q rows have lse ~ -1e30, where a bare
-            # exp(s - lse) would resurrect them as p = 1
-            p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-            dp = lax.dot_general(
-                do, vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=_mxu_precision(do.dtype),
-            )
-            ds = p * (dp - delta) * scale
-            return acc + lax.dot_general(
-                ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=_mxu_precision(kb.dtype),
-            )
-
-        hi = jnp.minimum(iq + 1, nkb) if causal else nkb
-        acc = lax.fori_loop(
-            0, hi, fold, jnp.zeros((bq, q.shape[-1]), jnp.float32)
-        )
-        dq_ref[0] = acc.astype(dq_ref.dtype)
-
-    return kernel
-
-
-def _flash_bwd_dkv_kernel(causal, scale, bq, bk, nq, t_real):
-    """dK/dV: grid step (bh, jk) owns one (bk, D) dk + dv block pair,
-    folding the q blocks that attended to it (causal: q blocks jk..nq-1
-    — a dynamic lower bound, the mirror of the forward's early exit)."""
+def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real):
+    """The whole backward of one (k tile, q tile) pair, once: grid step
+    (bh, jk) owns one (bk, D) dk + dv block pair and folds the q blocks
+    that attended to it (causal: q blocks jk..nq-1, a dynamic lower
+    bound, the mirror of the forward's early exit).  The scores, the
+    probabilities (rebuilt from the saved logsumexp, p = exp(s - lse),
+    never stored), dp and ds of a pair feed dv, dk AND that q block's dq
+    rows: five products a pair.  dq is an f32 accumulator of the whole
+    (T, D) that stays in VMEM across the ``jk`` axis of one ``bh`` (so
+    that axis is "arbitrary": zeroed at jk == 0, each q block's rows
+    summed over k tiles in ascending order, cast into the revisited dq
+    output block at the last jk)."""
 
     def kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref,
-               dk_ref, dv_ref):
+               dq_ref, dk_ref, dv_ref, dq_acc):
         jk = pl.program_id(1)
+
+        @pl.when(jk == 0)
+        def _():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
         kb = k_ref[0]
         vb = v_ref[0]
         D = kb.shape[-1]
@@ -508,8 +469,10 @@ def _flash_bwd_dkv_kernel(causal, scale, bq, bk, nq, t_real):
 
         def fold(i, carry):
             dk, dv = carry
-            qb = q_ref[0, pl.ds(i * bq, bq), :]
-            dob = do_ref[0, pl.ds(i * bq, bq), :]
+            rows = pl.ds(i * bq, bq)
+            qb = q_ref[0, rows, :]
+            dob = do_ref[0, rows, :]
+            # (bq,) lane vectors -> (bq, 1) sublane vectors for row broadcast
             lse = lse_ref[0, i, 0].reshape(bq, 1)
             delta = dl_ref[0, i, 0].reshape(bq, 1)
             s = lax.dot_general(
@@ -521,6 +484,8 @@ def _flash_bwd_dkv_kernel(causal, scale, bq, bk, nq, t_real):
             mask = (k_pos < t_real) & (q_pos < t_real)
             if causal:
                 mask &= q_pos >= k_pos
+            # explicit where: padded q rows have lse ~ -1e30, where a bare
+            # exp(s - lse) would resurrect them as p = 1
             p = jnp.where(mask, jnp.exp(s - lse), 0.0)
             dv = dv + lax.dot_general(
                 p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
@@ -532,11 +497,16 @@ def _flash_bwd_dkv_kernel(causal, scale, bq, bk, nq, t_real):
                 preferred_element_type=jnp.float32,
                 precision=_mxu_precision(dob.dtype),
             )
-            ds = p * (dp - delta) * scale
+            ds = (p * (dp - delta) * scale).astype(qb.dtype)
             dk = dk + lax.dot_general(
-                ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
+                ds, qb, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=_mxu_precision(qb.dtype),
+            )
+            dq_acc[rows, :] += lax.dot_general(
+                ds, kb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=_mxu_precision(kb.dtype),
             )
             return dk, dv
 
@@ -549,7 +519,27 @@ def _flash_bwd_dkv_kernel(causal, scale, bq, bk, nq, t_real):
         dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
 
+        @pl.when(jk == pl.num_programs(1) - 1)
+        def _():
+            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
     return kernel
+
+
+def _flash_bwd_vmem_bytes(Tp: int, Dp: int, b: int, itemsize: int) -> int:
+    """What one grid step of :func:`_flash_bwd_kernel` keeps in VMEM, from
+    the shapes: q, dO and the dq output block whole (each double-buffered
+    by the pipeline), the f32 dq accumulator, the k/v/dk/dv tiles, the two
+    row statistics (a (1, b) f32 row fills an (8, b) tile) and the (b, b)
+    f32 temporaries of a pair (s, p, dp, ds and the two casts, counted as
+    six).  T=8192, D=128, bf16, b=512: 4+4+4 MiB, 4 MiB, 1 MiB, 1 MiB,
+    6 MiB = 24 MiB, past the compiler's 16 MiB scoped default — so the
+    call passes this sum (and a quarter of it as room for what Mosaic
+    spills) as its ``vmem_limit_bytes``."""
+    whole = Tp * Dp * itemsize
+    tiles = 4 * b * Dp * itemsize
+    stats = 2 * (Tp // b) * 8 * b * 4
+    return 2 * (3 * whole + tiles + stats) + Tp * Dp * 4 + 6 * b * b * 4
 
 
 def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret):
@@ -580,40 +570,33 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret):
     # row-stat layout (see _flash_fwd_impl): block last-two dims == array
     lsef = lse.reshape(B * H, nq, 1, b)
     dlf = delta.reshape(B * H, nq, 1, b)
-    kv_whole = pl.BlockSpec((1, Tp, Dp), _flash_kv_map(H, Hkv),
-                            memory_space=pltpu.VMEM)
     kv_blk = pl.BlockSpec((1, b, Dp), _flash_kv_map(H, Hkv, blocked=True),
                           memory_space=pltpu.VMEM)
-
-    blk = pl.BlockSpec((1, b, Dp), lambda bh, i: (bh, i, 0),
+    blk = pl.BlockSpec((1, b, Dp), lambda bh, j: (bh, j, 0),
                        memory_space=pltpu.VMEM)
-    whole = pl.BlockSpec((1, Tp, Dp), lambda bh, i: (bh, 0, 0),
+    whole = pl.BlockSpec((1, Tp, Dp), lambda bh, j: (bh, 0, 0),
                          memory_space=pltpu.VMEM)
-    rows_blk = pl.BlockSpec((1, 1, 1, b), lambda bh, i: (bh, i, 0, 0),
-                            memory_space=pltpu.VMEM)
-    rows_whole = pl.BlockSpec((1, nq, 1, b), lambda bh, i: (bh, 0, 0, 0),
+    rows_whole = pl.BlockSpec((1, nq, 1, b), lambda bh, j: (bh, 0, 0, 0),
                               memory_space=pltpu.VMEM)
 
+    # dk/dv come out PER Q-HEAD (a kv head's blocks are written once by
+    # each q head of its group); the group sum is one cheap XLA reduction
+    # after the kernel
     grad_struct = out_struct((B * H, Tp, Dp), q.dtype, q, k, v, g)
-    dq = pl.pallas_call(
-        _flash_bwd_dq_kernel(causal, scale, b, b, nkb, T),
-        grid=(B * H, nq),
-        out_shape=grad_struct,
-        in_specs=[blk, kv_whole, kv_whole, blk, rows_blk, rows_blk],
-        out_specs=blk,
-        interpret=default_interpret(interpret),
-    )(qf, kf, vf, dof, lsef, dlf)
-
-    # dk/dv come out PER Q-HEAD (every output block still written exactly
-    # once — adding a group grid dim would revisit them); the group sum
-    # is one cheap XLA reduction after the kernel
-    dk, dv = pl.pallas_call(
-        _flash_bwd_dkv_kernel(causal, scale, b, b, nq, T),
+    resident = _flash_bwd_vmem_bytes(Tp, Dp, b, q.dtype.itemsize)
+    dq, dk, dv = pl.pallas_call(
+        _flash_bwd_kernel(causal, scale, b, b, nq, T),
         grid=(B * H, nkb),
-        out_shape=[grad_struct] * 2,
+        out_shape=[grad_struct] * 3,
         in_specs=[kv_blk, kv_blk, whole, whole, rows_whole, rows_whole],
-        out_specs=[blk, blk],
+        out_specs=[whole, blk, blk],
+        scratch_shapes=[pltpu.VMEM((Tp, Dp), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=resident + resident // 4,
+        ),
         interpret=default_interpret(interpret),
+        name="flash_bwd",
     )(kf, vf, qf, dof, lsef, dlf)
 
     dq = dq.reshape(B, H, Tp, Dp)[:, :, :T, :D]
@@ -658,20 +641,23 @@ def flash_attention(
     """Local (single-chip) fused attention: ``(B, H, T, D) -> same`` with
     the (T, T) score matrix never leaving VMEM — the kernel-owned form of
     ``ops.attention.blockwise_attention``, and like it fully trainable:
-    a ``custom_vjp`` pairs the forward (which saves only o + per-row
-    logsumexp) with two backward Pallas kernels (dq; dk+dv) that rebuild
-    the probability tiles on the fly.  Every output block is written
-    exactly once per grid step across all three kernels (no
-    grid-revisited outputs: every grid axis stays independent).
+    a ``custom_vjp`` pairs the forward kernel ``flash_fwd`` (which saves
+    only o + per-row logsumexp) with ONE backward kernel ``flash_bwd``
+    that rebuilds each visited tile pair's probabilities once and feeds
+    dq, dk and dv from them (five matrix products a pair).  dk/dv blocks
+    are written once a grid step; the dq block of a batch-head is
+    revisited across the k-tile axis, summed in f32 in VMEM.
 
     Grouped-query attention comes free: pass k/v with FEWER heads
     (``(B, Hkv, T, D)``, ``H % Hkv == 0``) and q head ``h`` reads kv head
     ``h // (H // Hkv)`` through the BlockSpec index map — the smaller K/V
     are never expanded to H heads anywhere (fwd or bwd).
 
-    K/V live whole in VMEM per (batch*head) grid step — sized for
-    serving/training sequence lengths (T <= ~8K at 128 lanes); the ring
-    kernel covers longer sequences across chips.
+    K/V (forward) and q/dO/dq (backward) live whole in VMEM per
+    (batch*head) grid step — sized for serving/training sequence lengths
+    (T <= ~8K at 128 lanes; the backward passes the sum of its residents
+    as its VMEM limit, :func:`_flash_bwd_vmem_bytes`); the ring kernel
+    covers longer sequences across chips.
 
     ``block=512`` is the measured optimum on v5e at T=4096: vs 256 the
     forward runs 2.1x faster (40.7 vs 19.6 TFLOPs) and the full T=4096

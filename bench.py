@@ -236,7 +236,7 @@ def _bench_attention() -> dict:
         out[f"attn_{impl}_us"] = round(dt * 1e6, 1)
         out[f"attn_{impl}_tflops"] = round(flops / 2 / dt / 1e12, 2)
         # fwd+bwd (the training cost): flash exercises its custom_vjp
-        # backward kernels, blockwise its rematerialized scan transpose
+        # backward kernel, blockwise its rematerialized scan transpose
         gfn = jax.jit(jax.grad(
             lambda a, b, c, i=impl: _attention(a, b, c, impl=i)
             .astype(jnp.float32).sum(),

@@ -10,6 +10,9 @@ optionally under the interpreter's vector-clock race detector (an aux
 capability the reference lacks entirely, SURVEY.md §5).
 """
 
+import collections
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -737,39 +740,111 @@ def test_flash_attention_validates():
         )
 
 
+#: name -> (B, H, Hkv, T, D, dtype, block): every case has three or more
+#: tiles a sequence, so under the causal skip a q tile's dq rows are summed
+#: over up to that many k steps of the ONE backward kernel's grid
+_FLASH_GRAD_CASES = {
+    "mha_t96": (2, 2, 2, 96, 32, jnp.float32, 32),
+    "mqa_g4_t128": (1, 4, 1, 128, 32, jnp.float32, 32),
+    "gqa_g2_t96": (2, 4, 2, 96, 32, jnp.float32, 32),
+    # Tp = 128: the fourth tile is 4 real rows and 28 of padding
+    "ragged_t100_d24": (1, 2, 2, 100, 24, jnp.float32, 32),
+    "bf16_mqa_t96": (1, 4, 1, 96, 32, jnp.bfloat16, 32),
+    "bf16_ragged_t100": (1, 2, 2, 100, 24, jnp.bfloat16, 32),
+}
+
+
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_grads_match_naive(causal):
-    """The custom_vjp backward kernels (dq; dk+dv rebuilt from the saved
-    logsumexp) == autodiff through the materialized-softmax form."""
+@pytest.mark.parametrize("case", list(_FLASH_GRAD_CASES))
+def test_flash_attention_grads_match_naive(case, causal):
+    """The custom_vjp backward kernel (dq, dk and dv from ONE rebuild of a
+    tile pair's probabilities out of the saved logsumexp) == autodiff
+    through the materialized-softmax form."""
+    B, H, Hkv, T, D, dtype, block = _FLASH_GRAD_CASES[case]
     rng = np.random.default_rng(24)
-    B, H, T, D = 2, 2, 96, 32
-    q, k, v = (
-        jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
-        for _ in range(3)
+    q = jnp.asarray(rng.standard_normal((B, H, T, D)), dtype)
+    k, v = (
+        jnp.asarray(rng.standard_normal((B, Hkv, T, D)), dtype)
+        for _ in range(2)
     )
     w = jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
 
     def naive(q, k, v):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        k, v = (jnp.repeat(a, H // Hkv, axis=1) for a in (k, v))
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
         if causal:
             s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
         return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
 
     def loss(fn):
-        return lambda q, k, v: (fn(q, k, v) * w).sum()
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum()
 
-    got = jax.grad(
+    # jitted: one program a side, its results waited for below
+    got = jax.jit(jax.grad(
         loss(lambda q, k, v: pk.flash_attention(
-            q, k, v, causal=causal, block=32)),
+            q, k, v, causal=causal, block=block)),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    ))(q, k, v)
     with jax.default_matmul_precision("highest"):
-        expect = jax.grad(loss(naive), argnums=(0, 1, 2))(q, k, v)
+        expect = jax.jit(jax.grad(loss(naive), argnums=(0, 1, 2)))(q, k, v)
+    assert got[1].shape == (B, Hkv, T, D)  # kv grads at kv-head count
     for a, b, name in zip(got, expect, "qkv"):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=2e-4, atol=_GRAD_ATOL,
-            err_msg=f"d{name}",
+        assert a.dtype == dtype, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(
+                a, b, rtol=2e-4, atol=_GRAD_ATOL, err_msg=f"d{name}")
+        else:
+            # bfloat16: p and ds rounded once before their products, each
+            # result rounded once from its float32 sum (the bound
+            # tests/test_grouped_matmul.py uses for the same reason)
+            assert np.abs(a - b).max() <= 2 ** -7 * np.abs(b).max(), name
+
+
+def test_train_step_text_has_two_flash_kernels_an_attention():
+    """A train step calls two Pallas kernels a layer, ``flash_fwd`` and
+    ``flash_bwd`` (no separate dq kernel), and the benchmark's reader
+    (``perfbench/scope_ops.py``: innermost ``accl.<x>::<y>`` of an ENTRY
+    instruction of the COMPILED text) finds everything under either name
+    in ``accl.attn::core``.  On the chip the compiled text holds one
+    ``tpu_custom_call`` a call there (CHANGES.md, PR 30); here the
+    interpreter's expansion of each."""
+    from accl_tpu.models import (
+        TransformerConfig,
+        init_params,
+        make_sharded_train_step,
+    )
+    from perfbench import scope_ops
+
+    cfg = TransformerConfig(
+        vocab=64, d_model=64, n_heads=4, n_kv_heads=1, n_layers=2, d_ff=64,
+        max_seq=64, attention="flash",
+    )
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    step, shard = make_sharded_train_step(cfg, mesh, lr=0.05)
+    params = shard(init_params(jax.random.PRNGKey(0), cfg))
+    tok = jnp.zeros((1, 48), jnp.int32)
+    traced = step.trace(params, tok, tok)
+    jaxpr = str(traced.jaxpr)
+    assert len(re.findall(r"pallas_call\[", jaxpr)) == 2 * cfg.n_layers
+    assert collections.Counter(re.findall(r"name=(flash\w*)", jaxpr)) == {
+        "flash_fwd": cfg.n_layers, "flash_bwd": cfg.n_layers,
+    }
+
+    compiled = traced.lower().compile().as_text()
+    core = set(scope_ops.scopes_of(compiled)["accl.attn::core"])
+    start = compiled.find("\nENTRY ")
+    by_name = collections.Counter()
+    for line in compiled[start:compiled.find("\n}", start)].splitlines():
+        m = re.match(
+            r'\s*(?:ROOT )?%(\S+) = .*op_name="[^"]*'
+            r'accl\.attn::core\)*/(\w+)/(?:pallas_call|io_callback)', line
         )
+        if m:
+            assert m[1] in core, line[:200]
+            by_name[m[2]] += 1
+    assert set(by_name) == {"flash_fwd", "flash_bwd"}
 
 
 def test_flash_attention_grads_ragged_and_padded():
