@@ -10,7 +10,8 @@ the long-context layer SURVEY.md §5 notes the reference's segmented-ring
 machinery is the substrate for.
 """
 
-from .transformer import (  # noqa: F401
+from .transformer import (
+    LayerKind,  # noqa: F401
     TransformerConfig,
     generate,
     init_params,

@@ -28,6 +28,30 @@ two dispatches:
   an expert axis the exchange needs a different count a peer (ROADMAP
   R2(b)).
 
+The dropless dispatch also computes what lies beyond softmax top-k, each
+picked by what the layer's parameters hold or by the caller's word:
+
+* ``router="sigmoid"``: float32 sigmoid scores; selection on ``score +
+  bias`` where the bank has a ``bias`` (state: read through
+  ``stop_gradient``, moved by the train step's rule and by no gradient),
+  weights from the UNBIASED scores of the chosen, renormalised over them
+  (``/ (sum + 1e-20)``) and times ``route_scale``.  No auxiliary term.
+* a ``shared`` expert in the parameters: one dense gated-SiLU FFN every
+  token passes through, added to the routed result (scope
+  ``accl.moe::shared``).
+* HELD EXPERTS: a ``gate`` wider than the bank.  The router keeps all its
+  outputs and its top-k; the bank's ``E`` matrices are experts
+  ``first_expert .. first_expert + E`` of them (one chip's share of an
+  expert-parallel group).  Entries whose expert is not held are left out
+  of the sort's front, the gathers and the grouped matmuls (group sizes
+  over the held experts only), and what they would have added is left
+  out of the result; no code stands in for the absent chips or their
+  exchange.  The held entries' rows have a static buffer
+  (``held_row_factor`` times the balanced share, rounded up to the row
+  tile); an entry past it is dropped and counted, as a receive buffer of
+  the exchange would.  The layer counts tokens an expert over ALL the
+  router's experts, the entries held here, and those dropped.
+
 Experts are two-matrix GELU FFNs (``w1``, ``w2``) or, with a ``w3`` in
 the parameters, gated-SiLU FFNs ``(silu(x w1) * (x w3)) w2``.
 """
@@ -40,19 +64,24 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.pallas.grouped_matmul import grouped_matmul
+from ..ops.pallas.grouped_matmul import ROWS, grouped_matmul
 from ..utils.profiling import device_scope
 
 
 def init_moe_params(key, d_model: int, d_ff: int, n_experts: int,
-                    dtype=jnp.float32, gated: bool = False):
+                    dtype=jnp.float32, gated: bool = False,
+                    router_experts: int | None = None,
+                    shared_d_ff: int = 0, bias: bool = False):
     """Gate + per-expert FFN weights (unsharded; shard E over 'ep').
     ``gated`` adds ``w3``, the second up-projection of a gated-SiLU
-    expert."""
+    expert.  ``router_experts``: the gate's width where the bank holds
+    only ``n_experts`` of them; ``shared_d_ff``: a ``shared`` gated-SiLU
+    expert of that width; ``bias``: a float32 selection bias, zero."""
     k1, k2, k3 = jax.random.split(key, 3)
     scale = d_model ** -0.5
+    n_router = n_experts if router_experts is None else router_experts
     params = {
-        "gate": jax.random.normal(k1, (d_model, n_experts), dtype) * scale,
+        "gate": jax.random.normal(k1, (d_model, n_router), dtype) * scale,
         "w1": jax.random.normal(k2, (n_experts, d_model, d_ff), dtype) * scale,
         "w2": jax.random.normal(k3, (n_experts, d_ff, d_model), dtype)
         * (d_ff ** -0.5),
@@ -61,6 +90,16 @@ def init_moe_params(key, d_model: int, d_ff: int, n_experts: int,
         params["w3"] = jax.random.normal(
             jax.random.fold_in(k2, 1), (n_experts, d_model, d_ff), dtype
         ) * scale
+    if bias:
+        params["bias"] = jnp.zeros((n_router,), jnp.float32)
+    if shared_d_ff:
+        ks = jax.random.split(jax.random.fold_in(key, 7), 3)
+        params["shared"] = {
+            "w1": jax.random.normal(ks[0], (d_model, shared_d_ff), dtype) * scale,
+            "w3": jax.random.normal(ks[1], (d_model, shared_d_ff), dtype) * scale,
+            "w2": jax.random.normal(ks[2], (shared_d_ff, d_model), dtype)
+            * (shared_d_ff ** -0.5),
+        }
     return params
 
 
@@ -94,6 +133,121 @@ def _expert_act(up, params, matmul):
     return jax.nn.gelu(up)
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _gather_rows(x, idx, n: int):
+    """``x[idx]`` of an ``(n, D)`` ``x``, rows repeated or left out as
+    ``idx`` says; the cotangent is summed into its ``n`` rows in
+    float32."""
+    return x[idx]
+
+
+def _gather_rows_fwd(x, idx, n):
+    return x[idx], idx
+
+
+def _gather_rows_bwd(n, idx, g):
+    acc = jnp.zeros((n, g.shape[-1]), jnp.float32).at[idx].add(
+        g.astype(jnp.float32)
+    )
+    return acc.astype(g.dtype), None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_rows(out, w, idx, n: int):
+    """``y[idx[r]] += w[r] * out[r]`` into ``n`` rows: products and sums
+    in float32, the result in ``out``'s type.  Its cotangents are a
+    gather of the result's, in that type."""
+    acc = jnp.zeros((n, out.shape[-1]), jnp.float32).at[idx].add(
+        out.astype(jnp.float32) * w[:, None]
+    )
+    return acc.astype(out.dtype)
+
+
+def _combine_rows_fwd(out, w, idx, n):
+    return _combine_rows(out, w, idx, n), (out, w, idx)
+
+
+def _combine_rows_bwd(n, res, g):
+    out, w, idx = res
+    g = g[idx].astype(jnp.float32)
+    return (
+        (g * w[:, None]).astype(out.dtype),
+        jnp.sum(g * out.astype(jnp.float32), axis=-1), None,
+    )
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def _expert_bank(rows, params, sizes):
+    """``rows``, sorted by expert in groups of ``sizes``, through the
+    bank's grouped matmuls."""
+    with device_scope("accl.moe::experts"):
+        grouped = lambda a, w: grouped_matmul(a, w, sizes)
+        h = _expert_act(
+            grouped(rows, params["w1"]), params, partial(grouped, rows)
+        )
+        return grouped(h, params["w2"])
+
+
+def held_rows(entries: int, held: int, of: int, factor: float) -> int:
+    """Rows of the held entries' buffer: ``factor`` times the balanced
+    share ``entries * held / of``, rounded up to the grouped matmul's
+    row tile (to 16 rows, a bf16 tile's sublanes, where it is less than
+    one), and never more than every entry."""
+    want = -(-int(factor * entries * held) // of)
+    tile = ROWS if want >= ROWS else 16
+    return min(-(-want // tile) * tile, entries)
+
+
+def _held_experts(flat, params, topk_e, topk_p, tp_axis, first: int,
+                  rows: int):
+    """The held experts' part of the result (module docstring): the
+    bank is experts ``first .. first + E`` of the router's.  Entries are
+    sorted with the held ones in front, by expert; the first ``rows`` of
+    the order are gathered and run through the grouped matmuls with the
+    held experts' group sizes (clipped to the buffer), and scattered
+    back weighted.  The kernels visit only the tiles the groups reach,
+    so what lies past them in the buffer is never written: it is masked
+    on the way in (for the cotangent) and on the way out.  Returns ``(y,
+    counters)``: ``expert_tokens`` over the router's experts,
+    ``held_entries`` and ``dropped``."""
+    N, D = flat.shape
+    k = topk_e.shape[-1]
+    E = params["w1"].shape[0]
+    n_router = params["gate"].shape[1]
+    with device_scope("accl.moe::dispatch"):
+        expert = topk_e.reshape(-1)                       # (N*k,) entries
+        counts = jnp.zeros((n_router,), jnp.int32).at[expert].add(1)
+        local = expert - first
+        key = jnp.where((local >= 0) & (local < E), local, E)
+        order = jnp.argsort(key, stable=True)[:rows]      # held first
+        ends = jnp.minimum(jnp.cumsum(counts[first:first + E]), rows)
+        sizes = jnp.diff(ends, prepend=0)
+        held, kept = jnp.sum(counts[first:first + E]), ends[-1]
+        valid = jnp.arange(rows) < kept
+        token = order // k
+        x = jnp.where(valid[:, None], _gather_rows(flat, token, N), 0)
+    out = _expert_bank(x, params, sizes)                  # (rows, D)
+    with device_scope("accl.moe::combine"):
+        w = jnp.where(valid, topk_p.reshape(-1)[order], 0.0)
+        out = jnp.where(valid[:, None], out, 0)
+        # inside a shard_map: the weights varying over the axes the rows
+        # vary over (tp-sharded experts), so that their cotangent is
+        # summed over those by the cast's transpose
+        if missing := tuple(jax.typeof(out).vma - jax.typeof(w).vma):
+            w = lax.pcast(w, missing, to="varying")
+        y = _combine_rows(out, w, token, N)
+        if tp_axis is not None:
+            y = lax.psum(y, tp_axis)
+    return y, {
+        "expert_tokens": counts, "held_entries": held, "dropped": held - kept,
+    }
+
+
 def _dropless_experts(flat, params, topk_e, topk_p, tp_axis):
     """Every routing entry through its expert, none dropped: sort the
     N*k entries by expert, grouped matmuls over the sorted rows, unsort,
@@ -108,12 +262,7 @@ def _dropless_experts(flat, params, topk_e, topk_p, tp_axis):
         back = jnp.argsort(order)                         # entry -> sorted
         sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
         rows = _take_rows(flat, order, back, k)           # (N*k, D) by expert
-    with device_scope("accl.moe::experts"):
-        grouped = lambda a, w: grouped_matmul(a, w, sizes)
-        h = _expert_act(
-            grouped(rows, params["w1"]), params, partial(grouped, rows)
-        )
-        out = grouped(h, params["w2"])                    # (N*k, D)
+    out = _expert_bank(rows, params, sizes)               # (N*k, D)
     with device_scope("accl.moe::combine"):
         got = _take_rows(out, back, order, 1).reshape(N, k, D)
         y = (got.astype(jnp.float32) * topk_p[..., None]).sum(axis=1)
@@ -132,6 +281,10 @@ def moe_ffn(
     return_aux: bool = False,
     tp_axis: str | None = None,
     renormalize: bool = True,
+    router: str = "softmax",
+    route_scale: float = 1.0,
+    first_expert: int = 0,
+    held_row_factor: float = 2.0,
 ):
     """Top-k gated MoE FFN (k=1 is Switch routing, k=2 the classic MoE).
 
@@ -151,6 +304,10 @@ def moe_ffn(
     the router's matmul accumulates and its softmax runs in float32, the
     entries are sorted by expert and run through grouped matmuls, and no
     entry is dropped whatever the routing.  It has no ``ep_axis`` form.
+    ``router``, ``route_scale``, ``first_expert`` and ``held_row_factor``
+    are this dispatch's (module docstring: the sigmoid router, the shared
+    expert, held experts); with them ``return_aux`` adds ``held_entries``
+    and its ``load_balance`` and ``router_z`` are zero.
 
     Returns (B, T, D): expert outputs weighted by the gate probability;
     over-capacity entries contribute zero (callers add the residual).
@@ -184,7 +341,14 @@ def moe_ffn(
     ep = 1 if ep_axis is None else lax.axis_size(ep_axis)
     e_local = params["w1"].shape[0]
     E = e_local * ep  # global expert count
+    n_router = params["gate"].shape[1]
 
+    beyond = router != "softmax" or "shared" in params or n_router != E
+    if beyond and capacity_factor is not None:
+        raise ValueError(
+            "the sigmoid router, a shared expert and held experts are the "
+            "dropless dispatch's (capacity_factor=None)"
+        )
     if capacity_factor is None:
         if ep > 1:
             raise NotImplementedError(
@@ -198,14 +362,53 @@ def moe_ffn(
             logits = jnp.dot(
                 flat, params["gate"], preferred_element_type=jnp.float32
             )
-            probs = jax.nn.softmax(logits, axis=-1)
-            topk_p, topk_e = lax.top_k(probs, k)
-            if k > 1 and renormalize:  # as below: k=1 keeps the raw prob
-                topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
-        y, sizes = _dropless_experts(flat, params, topk_e, topk_p, tp_axis)
+            if router == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                pick = scores
+                if "bias" in params:
+                    pick = scores + lax.stop_gradient(params["bias"])
+                _, topk_e = lax.top_k(pick, k)
+                topk_p = jnp.take_along_axis(scores, topk_e, axis=-1)
+                if renormalize:
+                    topk_p = topk_p / (
+                        jnp.sum(topk_p, axis=-1, keepdims=True) + 1e-20
+                    )
+                if route_scale != 1.0:
+                    topk_p = topk_p * route_scale
+            elif router != "softmax":
+                raise ValueError(f"unknown router {router!r}")
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)
+                topk_p, topk_e = lax.top_k(probs, k)
+                if k > 1 and renormalize:  # as below: k=1 keeps the raw prob
+                    topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
+        if n_router != E:
+            rows = held_rows(N * k, E, n_router, held_row_factor)
+            y, counters = _held_experts(
+                flat, params, topk_e, topk_p, tp_axis, first_expert, rows
+            )
+        else:
+            y, sizes = _dropless_experts(flat, params, topk_e, topk_p, tp_axis)
+            counters = {
+                "expert_tokens": sizes, "dropped": jnp.zeros((), jnp.int32),
+            }
+        if "shared" in params:
+            with device_scope("accl.moe::shared"):
+                sp = params["shared"]
+                every = (
+                    jax.nn.silu(flat @ sp["w1"]) * (flat @ sp["w3"])
+                ) @ sp["w2"]
+                if tp_axis is not None:
+                    every = lax.psum(every, tp_axis)
+            y = y + every
         y = y.reshape(B, T, D)
         if not return_aux:
             return y
+        if beyond:
+            return y, {
+                "load_balance": jnp.zeros((), jnp.float32),
+                "router_z": jnp.zeros((), jnp.float32), **counters,
+            }
         f = sizes.astype(jnp.float32) / (N * k)
         return y, {
             "load_balance": E * jnp.sum(f * probs.mean(axis=0)),

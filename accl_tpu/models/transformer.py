@@ -22,7 +22,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,22 @@ from jax import shard_map
 from ..constants import ReduceFunction
 from ..ops import collectives
 from ..utils.profiling import device_scope
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """One layer of a pattern (``TransformerConfig.layers``): its mixer
+    and its FFN.  ``window``: how many keys a query sees, its own among
+    them (``None`` = every earlier key).  ``rope``: whether this layer's
+    q and k rotate (a ``pos_embedding="rope"`` model may leave some
+    layers without position, NoPE).  ``ffn``: ``"dense"`` or ``"moe"``
+    (the expert bank of the config's ``n_experts``); ``d_ff``: the dense
+    FFN's width, or one expert's."""
+
+    window: Optional[int] = None
+    rope: bool = True
+    ffn: str = "dense"
+    d_ff: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,20 +106,40 @@ class TransformerConfig:
     # (AR = RS + AG), but layernorm/residual compute and inter-block
     # activation memory drop by the tp factor
     seq_parallel: bool = False
-    # the block's kinds, every layer alike (no layer pattern yet, D8):
-    # ``norm`` is "layernorm" (mean-centred) or "rmsnorm", both a scale
-    # and no bias at eps 1e-5; ``ffn`` is "gelu" (two matrices) or
-    # "swiglu" (gated SiLU, three matrices: ``(silu(x w1) * (x w3)) w2``),
-    # the dense FFN and each expert alike; ``qk_norm`` puts an RMSNorm
-    # on the whole projected q and k before the split into heads;
-    # ``tie_head=False`` gives the LM head its own (d_model, vocab)
-    # matrix instead of the embedding's transpose.  The decoder paths
-    # (train/forward/prefill/generate) honour all four; the encoder and
-    # the composed pipeline take the default block only.
+    # the block's kinds, alike in every layer: ``norm`` is "layernorm"
+    # (mean-centred) or "rmsnorm", both a scale and no bias at eps 1e-5;
+    # ``ffn`` is "gelu" (two matrices) or "swiglu" (gated SiLU, three
+    # matrices: ``(silu(x w1) * (x w3)) w2``), the dense FFN and each
+    # expert alike; ``qk_norm`` puts an RMSNorm on the projected q and k:
+    # ``True`` over the WHOLE projection before the split into heads,
+    # ``"head"`` over each head's ``head_dim`` after it (one learned
+    # scale of ``head_dim`` for q, one for k); ``tie_head=False`` gives
+    # the LM head its own (d_model, vocab) matrix instead of the
+    # embedding's transpose; ``attn_gate`` multiplies the attention
+    # output by ``sigmoid(h wg)`` before ``wo``; ``post_norm`` norms each
+    # half's output once more before the residual add (``ln1_post``,
+    # ``ln2_post``: four norms a layer); ``embed_scale`` multiplies the
+    # embedding rows (a muP model's ``sqrt(d_model)``); ``head_dim`` is
+    # the heads' width where it is not ``d_model // n_heads`` (q and o
+    # are then ``n_heads * head_dim`` wide, :meth:`head_size`).  The
+    # train and forward paths honour all of them; prefill/generate, the
+    # context- and sequence-parallel blocks, the encoder and the
+    # composed pipeline take what :meth:`plain` allows.
     norm: str = "layernorm"
     ffn: str = "gelu"
-    qk_norm: bool = False
+    qk_norm: Union[bool, str] = False
     tie_head: bool = True
+    attn_gate: bool = False
+    post_norm: bool = False
+    embed_scale: float = 1.0
+    head_dim: Optional[int] = None
+    # the layer pattern (ROADMAP M1): one :class:`LayerKind` a layer —
+    # the mixer's window and whether it rotates, the FFN's kind and
+    # width.  ``None`` is the pattern the other fields describe, every
+    # layer alike: no window, rope as ``pos_embedding`` says, the expert
+    # bank where ``n_experts`` and the dense FFN elsewhere, ``d_ff``
+    # wide (:meth:`pattern`).
+    layers: Optional[Tuple[LayerKind, ...]] = None
     # Mixture-of-Experts: n_experts > 0 replaces every block's dense FFN
     # with a top-k routed expert FFN (models/moe.py, any k; k=1 is Switch
     # routing).  ``moe_capacity_factor`` a number: fixed capacity, static
@@ -126,6 +162,29 @@ class TransformerConfig:
     moe_norm_topk_prob: bool = True
     moe_aux_weight: float = 0.01
     moe_router_z_weight: float = 1e-3
+    # Beyond softmax top-k (dropless only; models/moe.py says how each is
+    # computed).  ``moe_router="sigmoid"``: float32 sigmoid scores,
+    # selection on ``score + bias``, weights from the unbiased scores,
+    # renormalised (``moe_norm_topk_prob``) and times ``moe_route_scale``;
+    # the bias is state no gradient touches, moved after each train step
+    # by ``moe_bias_rate * sign(mean load - load)`` (0 = no bias).
+    # ``moe_shared_d_ff``: one gated-SiLU expert of that width beside the
+    # routed ones, every token through it (0 = none).
+    # ``moe_router_experts``: the router's width where this chip HOLDS
+    # only ``n_experts`` of them, ``moe_first_expert`` on (one chip's
+    # share of an expert-parallel group, without its exchange): routing
+    # is over all of them, the held experts' part of the result is
+    # computed, the rest is left out.  The held entries' rows have a
+    # static buffer, ``moe_held_row_factor`` times the balanced share
+    # ``tokens * k * n_experts / moe_router_experts``; an entry past it is
+    # dropped and counted.
+    moe_router: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_bias_rate: float = 0.0
+    moe_shared_d_ff: int = 0
+    moe_router_experts: Optional[int] = None
+    moe_first_expert: int = 0
+    moe_held_row_factor: float = 2.0
     # which mesh axis the expert bank shards over.  "dp" (default) is the
     # DeepSpeed-MoE welded layout: expert parallelism rides the data
     # axis.  Naming a DEDICATED axis (conventionally "ep", on a
@@ -172,21 +231,99 @@ class TransformerConfig:
             raise ValueError(
                 f"unknown pos_embedding {self.pos_embedding!r}"
             )
-        if self.pos_embedding == "rope" and (self.d_model // self.n_heads) % 2:
+        if self.pos_embedding == "rope" and self.head_size() % 2:
             raise ValueError("rope needs an even head dim")
         return self.pos_embedding == "rope"
+
+    def head_size(self) -> int:
+        """One head's width: ``head_dim``, or ``d_model // n_heads``."""
+        if self.head_dim is not None:
+            return self.head_dim
+        return self.d_model // self.n_heads
+
+    def router_experts(self) -> int:
+        """The router's width: all of a layer's experts, held here or not."""
+        if self.moe_router_experts is None:
+            return self.n_experts
+        return self.moe_router_experts
+
+    def pattern(self) -> Tuple[LayerKind, ...]:
+        """One :class:`LayerKind` a layer: ``layers``, or the pattern the
+        other fields describe."""
+        if self.layers is not None:
+            return self.layers
+        kind = LayerKind(
+            window=None, rope=self.pos_embedding == "rope",
+            ffn="moe" if self.n_experts else "dense", d_ff=self.d_ff,
+        )
+        return (kind,) * self.n_layers
+
+    def plain(self) -> bool:
+        """Every layer alike and nothing of the later kinds (a pattern, a
+        head width of its own, the gate, per-head QK-norm, post-norms, a
+        scaled embedding, the sigmoid router, a shared expert, a held
+        share): what prefill/generate and the context- and
+        sequence-parallel blocks compute."""
+        return (
+            self.layers is None and self.head_dim is None
+            and not self.attn_gate and not self.post_norm
+            and self.qk_norm in (False, True) and self.embed_scale == 1.0
+            and self.moe_router == "softmax" and not self.moe_shared_d_ff
+            and self.moe_router_experts is None
+        )
 
     def __post_init__(self):
         if self.norm not in _NORMS:
             raise ValueError(f"unknown norm {self.norm!r}")
         if self.ffn not in ("gelu", "swiglu"):
             raise ValueError(f"unknown ffn {self.ffn!r}")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"unknown qk_norm {self.qk_norm!r}")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if self.layers is not None:
+            if len(self.layers) != self.n_layers:
+                raise ValueError(
+                    f"layers has {len(self.layers)} kinds for n_layers "
+                    f"{self.n_layers}"
+                )
+            for i, kind in enumerate(self.layers):
+                if kind.ffn not in ("dense", "moe"):
+                    raise ValueError(f"layer {i}: unknown ffn {kind.ffn!r}")
+                if kind.ffn == "moe" and not self.n_experts:
+                    raise ValueError(f"layer {i}: an moe layer needs n_experts")
+                if kind.window is not None and kind.window < 1:
+                    raise ValueError(f"layer {i}: window {kind.window}")
+                if kind.rope and self.pos_embedding != "rope":
+                    raise ValueError(
+                        f"layer {i} rotates but pos_embedding is "
+                        f"{self.pos_embedding!r}"
+                    )
+        beyond = (
+            self.moe_router != "softmax" or self.moe_shared_d_ff
+            or self.moe_router_experts is not None
+        )
+        if beyond and (not self.n_experts or self.moe_capacity_factor is not None):
+            raise ValueError(
+                "the sigmoid router, a shared expert and a held share are "
+                "the dropless path's (n_experts > 0, "
+                "moe_capacity_factor=None)"
+            )
+        if self.moe_router_experts is not None and not (
+            0 <= self.moe_first_expert
+            <= self.moe_router_experts - self.n_experts
+        ):
+            raise ValueError(
+                f"experts {self.moe_first_expert}.."
+                f"{self.moe_first_expert + self.n_experts} are not among "
+                f"the router's {self.moe_router_experts}"
+            )
 
     def default_block(self) -> bool:
         """The block the encoder and the composed pipeline compute."""
         return (
             self.norm == "layernorm" and self.ffn == "gelu"
-            and not self.qk_norm and self.tie_head
+            and not self.qk_norm and self.tie_head and self.plain()
         )
 
 
@@ -194,6 +331,13 @@ def _check_axis_compat(cfg) -> None:
     """context_parallel turns the tp axis into the sequence ring —
     it cannot share that axis with the strategies that give tp other
     jobs (head-sharded weights + sequence/vocab sharding)."""
+    if (cfg.context_parallel or cfg.seq_parallel) and not cfg.plain():
+        raise ValueError(
+            "context_parallel and seq_parallel take the plain block only "
+            "(TransformerConfig.plain): no layer pattern, window, head_dim, "
+            "gate, per-head QK-norm, post-norm, scaled embedding, sigmoid "
+            "router, shared expert or held share"
+        )
     if cfg.context_parallel and (cfg.seq_parallel or cfg.vocab_parallel):
         raise ValueError(
             "context_parallel is incompatible with seq_parallel and "
@@ -286,75 +430,78 @@ def _mean_over_axes(local, axes: tuple, denom: int):
 
 # parameter partition specs over ('dp', 'tp'): column-parallel weights shard
 # their output dim on tp, row-parallel weights their input dim.
-def param_specs(cfg: TransformerConfig) -> Dict:
-    _check_axis_compat(cfg)
+def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
+    """The specs of one layer of ``kind``."""
     gated = cfg.ffn == "swiglu"
-    if cfg.context_parallel:
-        # context parallelism: the tp axis carries the SEQUENCE ring, so
-        # every weight is replicated over it (dp still shards the batch)
-        layer = {
-            k: P(None, None) if k[0] == "w" else P(None)
-            for k in ("wq", "wk", "wv", "wo", "w1", "w2", "ln1", "ln2")
-        }
-        if gated:
-            layer["w3"] = P(None, None)
-    else:
-        layer = {
-            "wq": P(None, "tp"),  # (d_model, d_model/tp): heads sharded
-            "wk": P(None, "tp"),
-            "wv": P(None, "tp"),
-            "wo": P("tp", None),  # (d_model/tp, d_model)
-            "w1": P(None, "tp"),  # (d_model, d_ff/tp)
-            "w2": P("tp", None),  # (d_ff/tp, d_model)
-            "ln1": P(None),
-            "ln2": P(None),
-        }
-        if gated:
-            layer["w3"] = P(None, "tp")  # the gate's twin of w1
-    if cfg.qk_norm:
+    cp = cfg.context_parallel
+    # context parallelism: the tp axis carries the SEQUENCE ring, so
+    # every weight is replicated over it (dp still shards the batch)
+    col = P(None, None) if cp else P(None, "tp")   # output dim on tp
+    row = P(None, None) if cp else P("tp", None)   # input dim on tp
+    layer = {
+        "wq": col,  # (d_model, heads * head_size / tp): heads sharded
+        "wk": col,
+        "wv": col,
+        "wo": row,  # (heads * head_size / tp, d_model)
+        "ln1": P(None),
+        "ln2": P(None),
+    }
+    if cfg.attn_gate:
+        layer["wg"] = col  # the gate's columns follow q's heads
+    if cfg.post_norm:
+        layer["ln1_post"] = P(None)
+        layer["ln2_post"] = P(None)
+    if cfg.qk_norm == "head":
+        # one scale of head_size for every head: replicated
+        layer["q_norm"] = P(None)
+        layer["k_norm"] = P(None)
+    elif cfg.qk_norm:
         # scales of the whole projected q and k: sharded like the
         # projections' output columns
-        heads = None if cfg.context_parallel else "tp"
+        heads = None if cp else "tp"
         layer["q_norm"] = P(heads)
         layer["k_norm"] = P(heads)
-    if cfg.n_experts:
-        # MoE: the dense FFN pair is replaced by the expert bank — the
-        # EXPERT dim shards over the expert axis (cfg.moe_mesh_axis:
-        # "dp" welded, or a dedicated "ep"); the router gate is
-        # replicated
-        for k_ in ("w1", "w2", "w3"):
-            layer.pop(k_, None)
-        ep_ax = cfg.moe_mesh_axis
-        if cfg.context_parallel:
-            # under cp the tp axis is the sequence ring: experts (like
-            # every other weight) replicate over it — only the expert
-            # dim shards
-            layer["moe"] = {
-                "gate": P(None, None),
-                "w1": P(ep_ax, None, None),
-                "w2": P(ep_ax, None, None),
-            }
-            if gated:
-                layer["moe"]["w3"] = P(ep_ax, None, None)
-        else:
-            # experts shard over the expert axis AND each expert's d_ff
-            # over tp (Megatron column/row split within the expert), so
-            # MoE keeps the dense layout's tp FLOP/memory sharding
-            # instead of replicating expert compute across tp
-            layer["moe"] = {
-                "gate": P(None, None),
-                "w1": P(ep_ax, None, "tp"),
-                "w2": P(ep_ax, "tp", None),
-            }
-            if gated:
-                layer["moe"]["w3"] = P(ep_ax, None, "tp")
+    if kind.ffn == "dense":
+        layer["w1"] = col  # (d_model, d_ff/tp)
+        layer["w2"] = row  # (d_ff/tp, d_model)
+        if gated:
+            layer["w3"] = col  # the gate's twin of w1
+        return layer
+    # MoE: the dense FFN pair is replaced by the expert bank — the
+    # EXPERT dim shards over the expert axis (cfg.moe_mesh_axis: "dp"
+    # welded, or a dedicated "ep"); the router gate is replicated.
+    # Under cp the tp axis is the sequence ring: experts (like every
+    # other weight) replicate over it — only the expert dim shards.
+    # Otherwise experts shard over the expert axis AND each expert's
+    # d_ff over tp (Megatron column/row split within the expert), so
+    # MoE keeps the dense layout's tp FLOP/memory sharding instead of
+    # replicating expert compute across tp
+    ep_ax = cfg.moe_mesh_axis
+    tp = None if cp else "tp"
+    moe = {
+        "gate": P(None, None),
+        "w1": P(ep_ax, None, tp),
+        "w2": P(ep_ax, tp, None),
+    }
+    if gated:
+        moe["w3"] = P(ep_ax, None, tp)
+    if cfg.moe_bias_rate:
+        moe["bias"] = P(None)
+    if cfg.moe_shared_d_ff:
+        moe["shared"] = {"w1": col, "w3": col, "w2": row}
+    layer["moe"] = moe
+    return layer
+
+
+def param_specs(cfg: TransformerConfig) -> Dict:
+    _check_axis_compat(cfg)
     out = {
         # vocab parallelism shards the table's VOCAB rows over tp (the
         # pos table and everything fed by the tp-allreduced lookup stay
         # replicated)
         "embed": P("tp", None) if cfg.vocab_parallel else P(None, None),
         "ln_f": P(None),
-        "layers": [dict(layer) for _ in range(cfg.n_layers)],
+        "layers": [_layer_specs(cfg, kind) for kind in cfg.pattern()],
     }
     if not cfg.uses_rope():
         out["pos"] = P(None, None)
@@ -385,50 +532,47 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
             ) * scale
         )
     gated = cfg.ffn == "swiglu"
-    d_kv = cfg.kv_heads() * (cfg.d_model // cfg.n_heads)
-    for i in range(cfg.n_layers):
+    hd = cfg.head_size()
+    d_q, d_kv = cfg.n_heads * hd, cfg.kv_heads() * hd
+
+    def normal(key, shape):
+        return jax.random.normal(key, shape, cfg.dtype) * scale
+
+    for i, kind in enumerate(cfg.pattern()):
         kk = k[2 + 4 * i : 6 + 4 * i]
         layer = {
-            "wq": jax.random.normal(kk[0], (cfg.d_model, cfg.d_model), cfg.dtype)
-            * scale,
-            "wk": jax.random.normal(
-                jax.random.fold_in(kk[0], 1), (cfg.d_model, d_kv), cfg.dtype
-            )
-            * scale,
-            "wv": jax.random.normal(
-                jax.random.fold_in(kk[0], 2), (cfg.d_model, d_kv), cfg.dtype
-            )
-            * scale,
-            "wo": jax.random.normal(kk[1], (cfg.d_model, cfg.d_model), cfg.dtype)
-            * scale,
+            "wq": normal(kk[0], (cfg.d_model, d_q)),
+            "wk": normal(jax.random.fold_in(kk[0], 1), (cfg.d_model, d_kv)),
+            "wv": normal(jax.random.fold_in(kk[0], 2), (cfg.d_model, d_kv)),
+            "wo": normal(kk[1], (d_q, cfg.d_model)),
             "ln1": jnp.ones((cfg.d_model,), cfg.dtype),
             "ln2": jnp.ones((cfg.d_model,), cfg.dtype),
         }
-        if cfg.qk_norm:
-            layer["q_norm"] = jnp.ones((cfg.d_model,), cfg.dtype)
+        if cfg.attn_gate:
+            layer["wg"] = normal(jax.random.fold_in(kk[1], 1), (cfg.d_model, d_q))
+        if cfg.post_norm:
+            layer["ln1_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
+            layer["ln2_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
+        if cfg.qk_norm == "head":
+            layer["q_norm"] = jnp.ones((hd,), cfg.dtype)
+            layer["k_norm"] = jnp.ones((hd,), cfg.dtype)
+        elif cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((d_q,), cfg.dtype)
             layer["k_norm"] = jnp.ones((d_kv,), cfg.dtype)
-        if cfg.n_experts:
+        if kind.ffn == "moe":
             from .moe import init_moe_params
 
             layer["moe"] = init_moe_params(
-                kk[2], cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.dtype,
-                gated=gated,
+                kk[2], cfg.d_model, kind.d_ff, cfg.n_experts, cfg.dtype,
+                gated=gated, router_experts=cfg.router_experts(),
+                shared_d_ff=cfg.moe_shared_d_ff, bias=bool(cfg.moe_bias_rate),
             )
         else:
-            layer["w1"] = (
-                jax.random.normal(kk[2], (cfg.d_model, cfg.d_ff), cfg.dtype)
-                * scale
-            )
-            layer["w2"] = (
-                jax.random.normal(kk[3], (cfg.d_ff, cfg.d_model), cfg.dtype)
-                * scale
-            )
+            layer["w1"] = normal(kk[2], (cfg.d_model, kind.d_ff))
+            layer["w2"] = normal(kk[3], (kind.d_ff, cfg.d_model))
             if gated:
-                layer["w3"] = (
-                    jax.random.normal(
-                        jax.random.fold_in(kk[2], 1),
-                        (cfg.d_model, cfg.d_ff), cfg.dtype,
-                    ) * scale
+                layer["w3"] = normal(
+                    jax.random.fold_in(kk[2], 1), (cfg.d_model, kind.d_ff)
                 )
         params["layers"].append(layer)
     return params
@@ -517,6 +661,8 @@ def _embed_tokens(params, tokens, cfg, tp_axis=None) -> jax.Array:
     ``tokens`` is this rank's STRIPED shard, so the pos rows are
     gathered at the shard's global positions."""
     x = _embed_rows(params["embed"], tokens, cfg, tp_axis)
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale  # a Python float: x keeps its dtype
     if not cfg.uses_rope():
         if _cp_active(cfg, tp_axis):
             x = x + params["pos"][_cp_positions(tokens.shape[1], tp_axis)]
@@ -639,25 +785,30 @@ def resolve_attention(impl: str, q) -> str:
     return "blockwise"
 
 
-def _attention(q, k, v, impl: str = "naive", causal: bool = True):
+def _attention(q, k, v, impl: str = "naive", causal: bool = True,
+               window: Optional[int] = None):
     """Attention; q,k,v: (B, H, T, hd); ``causal=False`` is the
-    bidirectional (encoder) form.
+    bidirectional (encoder) form; ``window`` (causal only) keeps a
+    query's last ``window`` keys, its own among them, in every lowering.
 
     ``impl="auto"`` resolves through :func:`resolve_attention`;
     ``"blockwise"`` runs the fused online-softmax fold (no (T, T) score
     matrix in HBM); ``"naive"`` is the materialized-scores baseline."""
     impl = resolve_attention(impl, q)
+    # no window: each lowering is called as it was before there was one
+    # (tests put a spy of the old signature in a lowering's place)
+    windowed = {} if window is None else {"window": window}
     if impl == "blockwise":
         from ..ops.attention import blockwise_attention
 
-        return blockwise_attention(q, k, v, causal=causal)
+        return blockwise_attention(q, k, v, causal=causal, **windowed)
     if impl == "flash":
         # the Pallas kernel owns the fold schedule; its custom_vjp
         # backward kernel makes it trainable (rebuilds probability tiles
         # from the saved logsumexp — no (T, T) residual)
         from ..ops.pallas.attention import flash_attention
 
-        return flash_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, **windowed)
     if impl != "naive":
         raise ValueError(f"unknown attention impl {impl!r}")
     B, H, T, hd = q.shape
@@ -673,8 +824,12 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True):
     scores = jnp.einsum(
         "bhgqd,bhkd->bhgqk", qg, k, preferred_element_type=jnp.float32
     ) * (1.0 / math.sqrt(hd))
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((T, T), bool), -window)
         scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v)
@@ -714,6 +869,13 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
         cf = moe_cfg.moe_capacity_factor
         if moe_no_drop and cf is not None:
             cf = float(moe_cfg.n_experts)
+        beyond = {}
+        if moe_cfg.moe_router != "softmax":
+            beyond.update(router=moe_cfg.moe_router,
+                          route_scale=moe_cfg.moe_route_scale)
+        if moe_cfg.moe_router_experts is not None:
+            beyond.update(first_expert=moe_cfg.moe_first_expert,
+                          held_row_factor=moe_cfg.moe_held_row_factor)
         out = moe_ffn(
             h, lp["moe"], ep_axis=ep_axis,
             capacity_factor=cf,
@@ -721,11 +883,12 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
             return_aux=with_aux,
             tp_axis=tp_axis,
             renormalize=moe_cfg.moe_norm_topk_prob,
+            **beyond,
         )
-        if with_aux:
-            y, aux = out
-            return x + y, aux
-        return x + out
+        y, aux = out if with_aux else (out, None)
+        if "ln2_post" in lp:
+            y = norm(y, lp["ln2_post"])
+        return (x + y, aux) if with_aux else x + y
     if fanout_fn is not None and tp_axis is not None:
         h = fanout_fn(h, tp_axis)  # see _block: the w1 fan-out point
     partial_f = _ffn_hidden(h, lp) @ lp["w2"]
@@ -736,12 +899,14 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
             )
         else:
             partial_f = reduce_fn(partial_f, tp_axis)
+    if "ln2_post" in lp:
+        partial_f = norm(partial_f, lp["ln2_post"])
     return (x + partial_f, None) if with_aux else x + partial_f
 
 
 def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
                   rope_base=None, positions=None, attention_fn=None,
-                  tp_axis=None):
+                  tp_axis=None, window=None, head_norm=False):
     """Column-parallel attention on a full-sequence activation: returns
     the row-parallel PARTIAL output (pre-reduction) and the (k, v) head
     tensors (B, Hkv_local, T, hd) for KV-cache prefill.  The kv head
@@ -754,34 +919,54 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     ``positions`` overrides the rope positions (context parallelism
     passes its shard's global token positions); ``attention_fn``
     replaces the dense :func:`_attention` lowering (context parallelism
-    passes the striped ring).  ``tp_axis`` is for :func:`_qk_norm`."""
+    passes the striped ring).  ``tp_axis`` is for :func:`_qk_norm`.
+
+    What the layer's tree holds picks the rest: under ``head_norm`` the
+    ``q_norm`` / ``k_norm`` scales are one head wide and norm each head
+    AFTER the split (:func:`_qk_norm` is the whole projection's);
+    a ``wg`` gates the attention output, ``attn * sigmoid(h wg)``, before
+    ``wo``.  ``window`` is the sliding window, run under the device scope
+    ``accl.attn::window`` (full attention stays ``accl.attn::core``)."""
     B, T, _ = h.shape
     q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]  # column-parallel
-    q, k = _qk_norm(q, k, lp, tp_axis)
+    if not head_norm:
+        q, k = _qk_norm(q, k, lp, tp_axis)
     hd = q.shape[-1] // n_heads_local
     n_kv_local = k.shape[-1] // hd
     heads = lambda t, n: t.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
     q, k, v = (
         heads(q, n_heads_local), heads(k, n_kv_local), heads(v, n_kv_local)
     )
+    if head_norm and "q_norm" in lp:
+        q, k = _rmsnorm(q, lp["q_norm"]), _rmsnorm(k, lp["k_norm"])
     if rope_base is not None:
         pos = jnp.arange(T) if positions is None else positions
         tables = _rope_tables(pos, hd // 2, rope_base)
         q = _rope_rotate(q, tables)
         k = _rope_rotate(k, tables)
-    with device_scope("accl.attn::core"):
-        if attention_fn is not None:
-            attn = attention_fn(q, k, v)
-        else:
-            attn = _attention(q, k, v, impl=attn_impl, causal=causal)
+    if window is None:
+        with device_scope("accl.attn::core"):
+            if attention_fn is not None:
+                attn = attention_fn(q, k, v)
+            else:
+                attn = _attention(q, k, v, impl=attn_impl, causal=causal)
+    else:
+        with device_scope("accl.attn::window"):
+            attn = _attention(
+                q, k, v, impl=attn_impl, causal=causal, window=window
+            )
     attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    if "wg" in lp:
+        gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
+        attn = attn * gate.astype(attn.dtype)
     return attn @ lp["wo"], (k, v)
 
 
 def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
            attn_impl="naive", causal=True, rope_base=None,
            ep_axis=None, moe_cfg=None, with_aux=False,
-           reduce_fn=None, fanout_fn=None, norm=_layernorm):
+           reduce_fn=None, fanout_fn=None, norm=_layernorm,
+           window=None, head_norm=False):
     """One transformer block on tp-sharded weights.  ``lp['wqkv']`` etc. are
     the *local shards*; the tp-allreduce after each row-parallel matmul is
     the reference's fused-allreduce hot path in model form.
@@ -805,10 +990,14 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
         # psum of the branch cotangents) lands here and nowhere else
         h = fanout_fn(h, tp_axis)
     partial_o, kv = _attn_partial(
-        h, lp, n_heads_local, attn_impl, causal, rope_base, tp_axis=tp_axis
+        h, lp, n_heads_local, attn_impl, causal, rope_base, tp_axis=tp_axis,
+        window=window, head_norm=head_norm,
     )
     if tp_axis is not None:
         partial_o = reduce_fn(partial_o, tp_axis)
+    if "ln1_post" in lp:
+        # the tree's post-norm: the half's output normed once more
+        partial_o = norm(partial_o, lp["ln1_post"])
     x = x + partial_o
     out = _mlp(x, lp, tp_axis, ep_axis, moe_cfg, with_aux,
                reduce_fn=reduce_fn, fanout_fn=fanout_fn, norm=norm)
@@ -959,6 +1148,8 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
     )
     if return_kv:
         kw["return_kv"] = True
+    if cfg.qk_norm == "head":
+        kw["head_norm"] = True
     if cfg.n_experts:
         # expert parallelism rides cfg.moe_mesh_axis ("dp" welded, or a
         # dedicated "ep"): the sharded makers always run over a mesh
@@ -982,36 +1173,61 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
     return x, partial(_block_sp, **kw), "sp"
 
 
+def _layer_blocks(block, cfg):
+    """``block`` for each layer of the pattern: the layout's block as it
+    is where every layer is alike, and with the layer's own window and
+    rotation where ``cfg.layers`` gives them."""
+    if cfg.layers is None:
+        if cfg.remat:
+            block = jax.checkpoint(block)
+        return [block] * cfg.n_layers
+    blocks = [
+        partial(
+            block, window=kind.window,
+            rope_base=cfg.rope_base if kind.rope else None,
+        )
+        for kind in cfg.layers
+    ]
+    return [jax.checkpoint(b) for b in blocks] if cfg.remat else blocks
+
+
 def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):
     """Embed -> blocks -> final layernorm.  Returns ``(x, layout, aux)``:
     ``layout`` flags how ``x`` is sequence-sharded ("" / "sp" / "cp");
     ``aux`` is None for dense FFNs or the MoE router's terms: the health
     terms summed over layers ({"load_balance", "router_z"}, shared by
-    forward() and the fused loss) and its counters a layer
-    ("expert_tokens" (L, E), "dropped" (L,); only the router probe reads
-    them, elsewhere they are dead code)."""
+    forward() and the fused loss) and its counters an MoE layer
+    ("expert_tokens" (L, E) over all the router's experts, "dropped"
+    (L,), and where the chip holds a share of them "held_entries" (L,);
+    the router probe and the expert bias's rule read them, elsewhere
+    they are dead code)."""
     x = _embed_tokens(params, tokens, cfg, tp_axis)
     x, block, sp = _enter_block_layout(x, cfg, tp_axis, tp_size)
-    if cfg.remat:
-        block = jax.checkpoint(block)
+    blocks = _layer_blocks(block, cfg)
     norm = _NORMS[cfg.norm]
     if not cfg.n_experts:
-        for lp in params["layers"]:
-            x = block(x, lp)
+        for blk, lp in zip(blocks, params["layers"]):
+            x = blk(x, lp)
         return norm(x, params["ln_f"]), sp, None
     lb = jnp.zeros((), jnp.float32)
     rz = jnp.zeros((), jnp.float32)
-    counts, dropped = [], []
-    for lp in params["layers"]:
-        x, aux = block(x, lp)
+    counts, dropped, held = [], [], []
+    for blk, lp in zip(blocks, params["layers"]):
+        x, aux = blk(x, lp)
+        if aux is None:
+            continue  # a dense layer of the pattern
         lb = lb + aux["load_balance"]
         rz = rz + aux["router_z"]
         counts.append(aux["expert_tokens"])
         dropped.append(aux["dropped"])
+        if "held_entries" in aux:
+            held.append(aux["held_entries"])
     aux = {
         "load_balance": lb, "router_z": rz,
         "expert_tokens": jnp.stack(counts), "dropped": jnp.stack(dropped),
     }
+    if held:
+        aux["held_entries"] = jnp.stack(held)
     return norm(x, params["ln_f"]), sp, aux
 
 
@@ -1045,8 +1261,10 @@ def forward(params, tokens, cfg: TransformerConfig, tp_axis=None, tp_size=1):
     return logits
 
 
-def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1):
-    """Mean next-token NLL.  Under ``cfg.vocab_parallel`` (with a tp
+def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1,
+            with_aux=False):
+    """Mean next-token NLL (``with_aux``: and the MoE router's terms and
+    counters of :func:`_final_hidden`, on the plain path).  Under ``cfg.vocab_parallel`` (with a tp
     axis) the cross-entropy is computed FUSED on the vocab-sharded
     logits — per-rank max/sum-exp/target-logit combined with tp
     collectives (the Megatron vocab-parallel loss) — so the full
@@ -1081,8 +1299,10 @@ def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1):
             # (moe rejects sp/cp above, so x is the full sequence)
             x, _, aux = _final_hidden(params, tokens, cfg, tp_axis, tp_size)
             logits = _lm_logits(x, params, cfg, tp_axis)
-            nll = _token_nll(logits, targets).mean()
-            return nll + _moe_penalty(cfg, aux)
+            loss = _token_nll(logits, targets).mean()
+            if cfg.moe_aux_weight or cfg.moe_router_z_weight:
+                loss = loss + _moe_penalty(cfg, aux)
+            return (loss, aux) if with_aux else loss
         logits = forward(params, tokens, cfg, tp_axis, tp_size)
         return _token_nll(logits, targets).mean()
 
@@ -1134,6 +1354,21 @@ def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1):
 # ---------------------------------------------------------------------------
 # KV-cache decode (autoregressive generation)
 # ---------------------------------------------------------------------------
+
+
+def _reject_unservable(cfg) -> None:
+    """prefill/generate compute the plain block (one kind of layer, a
+    cache of every earlier key): a configuration beyond it is refused
+    here, by name, and not served wrongly."""
+    if not cfg.plain():
+        raise ValueError(
+            "prefill/generate serve TransformerConfig.plain() only: this "
+            "configuration has a layer pattern, a window, a head_dim of "
+            "its own, an attention gate, per-head QK-norm, post-norms, a "
+            "scaled embedding, a sigmoid router, a shared expert or a "
+            "held share of the experts, and the decode path has no cache "
+            "layout or block for those yet (train and forward do)"
+        )
 
 
 def _block_decode(x_t, lp, cache_k, cache_v, pos, n_heads_local, tp_axis,
@@ -1216,11 +1451,12 @@ def prefill(
     silently reverting to replicated activations.  The cache it builds is
     identical (head-sharded, full sequence): attention inside the SP
     block already runs on the gathered sequence."""
+    _reject_unservable(cfg)
     B, T = tokens.shape
     S = cfg.max_seq if cache_len is None else int(cache_len)
     x = _embed_tokens(params, tokens, cfg, tp_axis)
     kv_local = cfg.kv_heads() // tp_size  # GQA: cache holds kv heads only
-    hd = cfg.d_model // cfg.n_heads
+    hd = cfg.head_size()
     x, block_kv, sp = _enter_block_layout(
         x, cfg, tp_axis, tp_size, return_kv=True
     )
@@ -1281,6 +1517,7 @@ def generate(
     run the head-parallel math on that cache — the cache layout (and
     therefore the serving plan) is identical to what the SP training
     layout implies, not a silent strategy switch."""
+    _reject_unservable(cfg)
     B, T = prompt.shape
     if T + steps > cfg.max_seq and not cfg.uses_rope():
         # rope has no position table, so max_seq is not a serving cliff:
@@ -1304,7 +1541,7 @@ def generate(
     first = _select_token(logits, sub, temperature, top_k).astype(prompt.dtype)
 
     rope = cfg.rope_base if cfg.uses_rope() else None
-    hd = cfg.d_model // cfg.n_heads
+    hd = cfg.head_size()
 
     def step(carry, _):
         caches, tok, pos, key = carry
@@ -1362,6 +1599,7 @@ def make_sharded_generate(
             "dataclasses.replace(cfg, context_parallel=False) — cp "
             "params are replicated over tp and re-shard directly"
         )
+    _reject_unservable(cfg)
     _check_moe_mesh(cfg, mesh)
     specs = param_specs(cfg)
     tp = mesh.shape["tp"]
@@ -1501,9 +1739,12 @@ def make_sharded_router_probe(cfg: TransformerConfig, mesh: Mesh):
     """The MoE router's counters from the program's own forward path
     (same blocks, same dispatch as :func:`make_sharded_forward`): a
     jitted ``fn(params, tokens) -> {"expert_tokens": (L, E) routing
-    entries sent to each expert of each layer, "dropped": (L,) entries
-    past capacity}``, summed over the data axes.  A probe beside the
-    step, so that the train step keeps its ``(params, loss)``."""
+    entries sent to each of the router's experts in each MoE layer,
+    "dropped": (L,) entries past capacity (or past a held share's row
+    buffer), and where the chip holds a share of the experts
+    "held_entries": (L,) entries whose expert is held here}``, summed
+    over the data axes.  A probe beside the step, so that the train step
+    keeps its ``(params, loss)``."""
     if not cfg.n_experts or cfg.context_parallel:
         raise ValueError("the router probe needs an MoE config without cp")
     _check_moe_mesh(cfg, mesh)
@@ -1512,7 +1753,10 @@ def make_sharded_router_probe(cfg: TransformerConfig, mesh: Mesh):
 
     def probe(params, tokens):
         aux = _final_hidden(params, tokens, cfg, "tp", tp)[2]
-        out = {k: aux[k] for k in ("expert_tokens", "dropped")}
+        out = {
+            k: aux[k] for k in ("expert_tokens", "dropped", "held_entries")
+            if k in aux
+        }
         for a in axes:
             out = collectives.allreduce(out, a, ReduceFunction.SUM)
         return out
@@ -1529,18 +1773,42 @@ def make_sharded_router_probe(cfg: TransformerConfig, mesh: Mesh):
 
 
 def _reject_untrainable_attention(cfg) -> None:
-    """Historical guard shared by the train-step builders: the Pallas
-    flash kernel used to be forward-only.  Its custom_vjp backward
-    kernels (ops/pallas/attention.py) made every lowering trainable, so
-    this now only rejects unknown names up front (instead of deep inside
-    a traced forward)."""
+    """The train-step builders' check of ``cfg.attention``: every
+    lowering it can name is trainable (the flash kernels by their
+    custom_vjp backward), so this refuses an unknown NAME, here and not
+    deep inside a traced forward."""
     impl = getattr(cfg, "attention", None)
     if impl not in (None, "auto", "naive", "blockwise", "flash"):
-        raise ValueError(f"unknown attention impl {impl!r}")
+        raise ValueError(
+            f"unknown attention impl {impl!r}: one of 'auto', 'naive', "
+            "'blockwise', 'flash'"
+        )
+
+
+def _move_expert_bias(params, load, rate: float):
+    """The expert bias's rule, outside the gradient: after a step each
+    MoE layer's ``bias`` moves by ``rate * sign(mean load - load)``,
+    towards the experts that got fewer than the mean of the step's
+    routing entries.  ``load`` is (MoE layers, router's experts), the
+    step's ``expert_tokens``; the bias's own gradient is zero (selection
+    reads it through ``stop_gradient``), so SGD left it where it was."""
+    layers, i = [], 0
+    for lp in params["layers"]:
+        if "bias" in lp.get("moe", {}):
+            c = load[i].astype(jnp.float32)
+            bias = lp["moe"]["bias"] + rate * jnp.sign(c.mean() - c)
+            lp = {**lp, "moe": {**lp["moe"], "bias": bias}}
+        i += "moe" in lp
+        layers.append(lp)
+    return {**params, "layers": layers}
 
 
 def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh, lr: float = 1e-2):
     """One SGD train step as a single shard_map program over ('dp','tp').
+
+    Where the config has an expert bias (``moe_bias_rate``), the step
+    also applies :func:`_move_expert_bias` to it from the step's own
+    routing counts, summed over the data axes.
 
     The differentiated quantity is the *global* mean loss (dp-allreduce of
     the local means), so shard_map's varying-axis tracking transposes the
@@ -1558,14 +1826,29 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh, lr: float = 1e-2
     denom = 1
     for a in axes:
         denom *= mesh.shape[a]
+    bias_rate = cfg.moe_bias_rate if cfg.n_experts else 0.0
 
     def step(params, tokens, targets):
         def global_loss(p):
-            local = loss_fn(p, tokens, targets, cfg, "tp", tp)
-            return _mean_over_axes(local, axes, denom)
+            if not bias_rate:
+                local = loss_fn(p, tokens, targets, cfg, "tp", tp)
+                return _mean_over_axes(local, axes, denom)
+            # and the step's routing counts, for the bias's rule
+            local, aux = loss_fn(
+                p, tokens, targets, cfg, "tp", tp, with_aux=True
+            )
+            load = aux["expert_tokens"]
+            for a in axes:
+                load = collectives.allreduce(load, a, ReduceFunction.SUM)
+            return _mean_over_axes(local, axes, denom), load
 
-        loss, grads = jax.value_and_grad(global_loss)(params)
+        loss, grads = jax.value_and_grad(
+            global_loss, has_aux=bool(bias_rate)
+        )(params)
         params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
+        if bias_rate:
+            loss, load = loss
+            params = _move_expert_bias(params, load, bias_rate)
         return params, loss
 
     # context parallelism: tokens/targets are striped (outside shard_map

@@ -34,11 +34,14 @@ from .pallas.attention import _mxu_precision
 _NEG = -1e30
 
 
-def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int):
+def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
+                   window=None):
     """One (T, D) head: scan q blocks; fold k blocks with online softmax.
 
     ``t_real`` masks padded key positions (T may be padded to block
-    multiples by the wrapper)."""
+    multiples by the wrapper); ``window`` keeps a query's last ``window``
+    keys, its own among them (every tile is still folded: this is the
+    CPU tier and the fallback past the flash kernels' VMEM gate)."""
     T, D = q.shape
     nq, nk = T // bq, T // bk
     scale = 1.0 / math.sqrt(D)
@@ -65,6 +68,8 @@ def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int):
             mask = k_pos[None, :] < t_real
             if causal:
                 mask &= q_pos[:, None] >= k_pos[None, :]
+            if window is not None:
+                mask &= q_pos[:, None] - k_pos[None, :] < window
             s = jnp.where(mask, s, _NEG)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -106,10 +111,14 @@ def blockwise_attention(
     causal: bool = True,
     block_q: int = 256,
     block_k: int = 256,
+    window: int | None = None,
 ) -> jax.Array:
     """Causal (or full) attention over ``(B, H, T, Dh)`` operands without
     materializing the (T, T) score matrix.  Exact (not approximate):
     matches the naive softmax form to float tolerance.
+
+    ``window=W`` (causal only): query ``i`` sees keys ``0 <= i - j < W``,
+    as ``ops.pallas.flash_attention`` has it.
 
     Block sizes clamp to the (padded) sequence length; T is padded to a
     block multiple internally and the pad keys are masked out.
@@ -120,6 +129,10 @@ def blockwise_attention(
     kernel's index-map sharing avoids it."""
     B, H, T, Dh = q.shape
     Hkv = k.shape[1]
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"a window ({window}) is causal and at least 1 key wide"
+        )
     if Hkv != H:
         if Hkv <= 0 or H % Hkv:
             raise ValueError(
@@ -149,7 +162,7 @@ def blockwise_attention(
         k = jnp.pad(k, padding)
         v = jnp.pad(v, padding)
     single = functools.partial(
-        _attend_single, causal=causal, bq=bq, bk=bk, t_real=T
+        _attend_single, causal=causal, bq=bq, bk=bk, t_real=T, window=window
     )
     out = jax.vmap(jax.vmap(single))(q, k, v)
     return out[:, :, :T]

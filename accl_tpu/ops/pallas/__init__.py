@@ -29,7 +29,7 @@ from . import (  # noqa: F401
     rooted,
 )
 from ._common import default_interpret, pack_lanes, unpack_lanes  # noqa: F401
-from .attention import flash_attention  # noqa: F401
+from .attention import flash_attention, flash_tile_pairs  # noqa: F401
 from .alltoall import alltoall as alltoall_kernel  # noqa: F401
 from .combine import combine  # noqa: F401
 from .compression import cast, dequantize_int8, quantize_int8  # noqa: F401

@@ -1,0 +1,101 @@
+"""Guard for the code the benchmark's train cells share
+(``make_sharded_train_step``, ``loss_fn``, ``_block``, ``_attn_partial``,
+``_mlp``, ``_dropless_experts``, the attention lowerings): the StarCoder
+and the OLMoE train step at their rehearsal sizes are the programs they
+were before PR 31's layer pattern, held experts and window went in.
+
+Two texts a configuration, each by its sha256 at the parent commit
+(04da78e): the LOWERED module (StableHLO, what the program hands the
+compiler: the same in every process) and the COMPILED module without what
+is the machine's or the file's and not the program's (the CPU's thread
+partitions, stack frame ids and the table of file names and lines).  A
+later PR that means to change these steps records its own digests here
+and says so in CHANGES.md; one that does not mean to has found what it
+broke.
+"""
+
+import dataclasses
+import hashlib
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from accl_tpu.models import init_params, make_sharded_train_step
+from perfbench import manifest
+
+#: (cell, attention) -> (sha256 of the lowered text, of the normalised
+#: compiled text).  ``None`` is the configuration's own ``auto``, which at
+#: a rehearsal's length is the naive form; ``"flash"`` puts the two Pallas
+#: kernels in the step (interpreted here: their traced bodies are in the
+#: text), as the chip has them at the timed sizes; there only the lowered
+#: text is held (the compiled text numbers the interpreter's host
+#: callbacks by what the process compiled before).
+PARENT = {
+    ("train_t8192_b1", None): (
+        "4a3bfd2ae38f5de2aaadbca04d00a094943aeb1d7e2bd1a68ea34553a2bd33cc",
+        "1f781a8eefe8b7bf764fec8d8baacc0da2fb9ff192e2ac2901386406151f08f1",
+    ),
+    ("train_olmoe_t4096_b2", None): (
+        "5df31246299c5b34126f52f122132a1193099bba9663f409d25a1503efaebed5",
+        "4feeeab0878f609d05df71b6e3c30f1ab47b62b4114df84df3022839412600c1",
+    ),
+    ("train_t8192_b1", "flash"): (
+        "df5805e5d44c3b95f93112f29ab789d4b36cb1e00b82a2b812b795a4dc989511",
+        None,
+    ),
+    ("train_olmoe_t4096_b2", "flash"): (
+        "b55e3f402ec490b21d8a66a31bb4dd14466229164cd74e9c7b664ac3da07cdbc",
+        None,
+    ),
+}
+
+
+def normalised(compiled: str) -> str:
+    text = re.sub(
+        r'backend_config=\{"outer_dimension_partitions":\[[^\]]*\]\}', "",
+        compiled,
+    )
+    text = re.sub(r" stack_frame_id=\d+", "", text)
+    start = text.find("\nFileNames")
+    if start >= 0:
+        text = text[:start] + text[text.find("\n\n", text.find("\nStackFrames")):]
+    return text
+
+
+def step_texts(cell_name: str, attention=None):
+    cell = manifest.cell(manifest.load(), cell_name, rehearse=True)
+    driver = importlib.import_module(
+        "perfbench.drivers." + cell["traffic"]["driver"]
+    )
+    cfg = driver.program_config(cell["config"])
+    if attention is not None:
+        cfg = dataclasses.replace(cfg, attention=attention)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    step, _ = make_sharded_train_step(
+        cfg, mesh, lr=float(cell["traffic"]["lr"])
+    )
+    params = jax.eval_shape(
+        lambda k: init_params(k, cfg), jax.random.PRNGKey(0)
+    )
+    tok = jax.ShapeDtypeStruct(
+        (int(cell["traffic"]["batch"]), int(cell["traffic"]["seq"])), jnp.int32
+    )
+    lowered = step.lower(params, tok, tok)
+    return lowered.as_text(), normalised(lowered.compile().as_text())
+
+
+def _sha(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("cell,attention", list(PARENT))
+def test_rehearsal_size_step_is_the_program_it_was_at_the_parent(cell, attention):
+    lowered, compiled = step_texts(cell, attention)
+    want_lowered, want_compiled = PARENT[cell, attention]
+    assert _sha(lowered) == want_lowered
+    assert want_compiled in (None, _sha(compiled))

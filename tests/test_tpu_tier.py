@@ -265,3 +265,73 @@ def test_dumps(accl):
     dump = accl.dump_rx_buffers()
     assert "XLA gang rx state" in dump
     assert "rxbuf" not in dump
+
+
+# ---------------------------------------------------------------------------
+# the windowed flash kernels at their own tile size (Mosaic-compiled here)
+# ---------------------------------------------------------------------------
+
+#: the window against the kernels' 512-row tile at T=1500 (three tiles,
+#: the last one ragged): inside a tile, a whole number of tiles, not a
+#: whole number, and wider than the sequence
+_WINDOWS = {"under_a_block": 200, "two_blocks": 1024, "ragged": 700,
+            "past_t": 4096}
+
+
+@pytest.mark.parametrize("window", list(_WINDOWS))
+def test_flash_attention_window_at_the_kernels_tile_size(window):
+    """``flash_attention(window=)`` with the tile the train cells run (512),
+    heads of 128, GQA and a ragged T, forward and gradients against the
+    masked naive form; a window the sequence does not reach equals plain
+    causal attention bit for bit.  tests/test_pallas.py has the same cases
+    at small tiles; this tier compiles them."""
+    import jax.numpy as jnp
+
+    from accl_tpu.compat import has_interpret_params
+    from accl_tpu.ops import pallas as pk
+
+    if jax.default_backend() != "tpu" and not has_interpret_params():
+        pytest.skip("no Pallas TPU interpreter here")
+    W = _WINDOWS[window]
+    B, H, Hkv, T, D = 1, 4, 2, 1500, 128
+    r = np.random.default_rng(41)
+    q = jnp.asarray(r.standard_normal((B, H, T, D)), jnp.float32)
+    k, v = (
+        jnp.asarray(r.standard_normal((B, Hkv, T, D)), jnp.float32)
+        for _ in range(2)
+    )
+    w = jnp.asarray(r.standard_normal((B, H, T, D)), jnp.float32)
+
+    def naive(q, k, v):
+        k2, v2 = (jnp.repeat(a, H // Hkv, axis=1) for a in (k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k2) / np.sqrt(D)
+        dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+        s = jnp.where((dist >= 0) & (dist < W), s, -1e30)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v2)
+
+    def run(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return (out * w).sum(), out
+        return jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+        )(q, k, v)
+
+    (_, got), grads = run(lambda q, k, v: pk.flash_attention(q, k, v, window=W))
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = run(naive)
+    atol = 5e-4 if jax.default_backend() == "tpu" else 2e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=atol)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=20 * atol)
+    if W >= T:
+        (_, causal), causal_grads = run(
+            lambda q, k, v: pk.flash_attention(q, k, v)
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(causal))
+        for a, b in zip(grads, causal_grads):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert pk.flash_tile_pairs(T, 512, W) == {200: 5, 1024: 6, 700: 6,
+                                               4096: 6}[W]
