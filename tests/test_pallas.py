@@ -751,6 +751,14 @@ _FLASH_GRAD_CASES = {
     "ragged_t100_d24": (1, 2, 2, 100, 24, jnp.float32, 32),
     "bf16_mqa_t96": (1, 4, 1, 96, 32, jnp.bfloat16, 32),
     "bf16_ragged_t100": (1, 2, 2, 100, 24, jnp.bfloat16, 32),
+    # five tiles: the last two rows fold three and four INTERIOR tiles (no
+    # mask at all) before their diagonal one
+    "mha_t160_interior": (1, 2, 2, 160, 32, jnp.float32, 32),
+    # Tp = 160: three interior tiles in row 3, then the padded last row and
+    # (backward) the padded last q tile of every k tile; not causal, every
+    # tile but those is interior
+    "gqa_g2_ragged_t150": (1, 4, 2, 150, 24, jnp.float32, 32),
+    "bf16_gqa_ragged_t150": (1, 4, 2, 150, 24, jnp.bfloat16, 32),
 }
 
 
@@ -847,10 +855,19 @@ def test_train_step_text_has_two_flash_kernels_an_attention():
     assert set(by_name) == {"flash_fwd", "flash_bwd"}
 
 
-#: the window against the tile (block 32): inside one tile, a whole
-#: number of tiles, not a whole number, and wider than the sequence
-_FLASH_WINDOWS = {"under_a_block": 8, "two_blocks": 64, "ragged": 40,
-                  "past_t": 200}
+#: name -> (window, T).  The window against the tile (block 32): inside
+#: one tile, a whole number of tiles, not a whole number, and wider than
+#: the sequence, on T = 100 (three tiles and 4 rows: the last row of tiles
+#: is the padded class).  Then the bodies by tile class: eight whole tiles
+#: under a window of 4 tiles and 22 keys (three interior tiles a row
+#: between two window-edge tiles and the diagonal), the same edge on a
+#: padded T, and a window narrower than the tile on whole tiles (the
+#: diagonal tile compares the window too).
+_FLASH_WINDOWS = {
+    "under_a_block": (8, 100), "two_blocks": (64, 100), "ragged": (40, 100),
+    "past_t": (200, 100), "interior_between_edges": (150, 256),
+    "interior_and_padded": (100, 250), "under_a_block_whole_tiles": (8, 160),
+}
 
 
 def _windowed_naive(q, k, v, window):
@@ -866,13 +883,14 @@ def _windowed_naive(q, k, v, window):
 
 @pytest.mark.parametrize("window", list(_FLASH_WINDOWS))
 def test_flash_attention_window_fwd_and_grads_match_masked_naive(window):
-    """``flash_attention(window=)`` with GQA (G=2) and a ragged T (100 =
-    three tiles of 32 and 4 rows, D=24 padded to the lanes): forward, dq,
-    dk and dv against the masked naive form; a window the sequence does
-    not reach is plain causal attention BIT FOR BIT."""
-    W = _FLASH_WINDOWS[window]
+    """``flash_attention(window=)`` with GQA (G=2), D=24 padded to the
+    lanes and T ragged (100 = three tiles of 32 and 4 rows) or long enough
+    for interior tiles under the window: forward, dq, dk and dv against
+    the masked naive form; a window the sequence does not reach is plain
+    causal attention BIT FOR BIT."""
+    W, T = _FLASH_WINDOWS[window]
     rng = np.random.default_rng(31)
-    B, H, Hkv, T, D = 1, 4, 2, 100, 24
+    B, H, Hkv, D = 1, 4, 2, 24
     q = jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
     k, v = (
         jnp.asarray(rng.standard_normal((B, Hkv, T, D)), jnp.float32)
@@ -963,6 +981,267 @@ def test_flash_tile_pairs_counted_from_the_shapes(T, block, window, pairs):
         lo, hi = _window_q_tiles(jk, b, n, w)
         backward += int(hi) - int(lo)
     assert backward == pairs
+
+
+def _every_tile_mask(iq, jk, b, T, causal, window, with_q=False):
+    """The mask the kernels built on EVERY visited tile pair before there
+    were tile classes: positions from an iota, compared with ``T``, with
+    each other and with the window, ANDed."""
+    q_pos = iq * b + jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    k_pos = jk * b + jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    mask = k_pos < T
+    if with_q:
+        mask &= q_pos < T
+    if causal:
+        mask &= q_pos >= k_pos
+    if window is not None:
+        mask &= q_pos - k_pos < window
+    return mask
+
+
+def _visited(fixed, n, b, causal, window, padded, forward):
+    """``(x, class)`` of every tile a grid step ``fixed`` folds, by the
+    kernels' own bounds and ranges, in the order it folds them."""
+    from accl_tpu.ops.pallas import attention as fa
+
+    for start, stop, cls in fa._tile_ranges(
+        fixed, n, b, causal, window, padded, forward=forward
+    ):
+        for x in range(int(start), int(stop)):
+            yield x, cls
+
+
+def _tile_classes_by_hand(T, b, window):
+    """The class of every tile pair the mask touches, from the closest and
+    the farthest (query, key) of the two tiles."""
+    n = -(-T // b)
+    counts = collections.Counter()
+    for iq in range(n):
+        for jk in range(n):
+            nearest = max(iq * b - (jk * b + b - 1), 0)
+            farthest = iq * b + b - 1 - jk * b
+            if farthest < 0 or (window is not None and nearest >= window):
+                continue
+            if T % b and n - 1 in (iq, jk):
+                counts["padded"] += 1
+            elif iq == jk:
+                counts["diagonal"] += 1
+            elif window is not None and farthest >= window:
+                counts["edge"] += 1
+            else:
+                counts["interior"] += 1
+    return counts
+
+
+@pytest.mark.parametrize("T,block,window,classes", [
+    # (interior, diagonal, window-edge, padded); the four train cells'
+    # layers first: StarCoder and Trinity's full layer at 8192, Trinity's
+    # sliding layers, OLMoE at 4096, StarCoder at 1024
+    (8192, 512, None, (120, 16, 0, 0)),
+    (8192, 512, 2048, (42, 16, 12, 0)),
+    (4096, 512, None, (28, 8, 0, 0)),
+    (1024, 512, None, (1, 2, 0, 0)),
+    (8192, 512, 2561, (54, 16, 11, 0)),   # one key past 5 tiles: one edge tile a row
+    (8192, 512, 2600, (54, 16, 21, 0)),   # no multiple: two edge tiles a row
+    (8192, 512, 700, (0, 16, 29, 0)),     # W < 2 tiles: no interior tile
+    (8192, 512, 200, (0, 16, 15, 0)),     # W < 1 tile: diagonal and edge at once
+    (8000, 512, None, (105, 15, 0, 16)),  # a padded T: the last row of tiles
+    (3000, 512, 700, (0, 5, 7, 3)),
+    (100, 32, 40, (0, 3, 3, 3)),
+])
+def test_flash_tile_classes_counted_from_the_shapes(T, block, window, classes):
+    """The visited tile pairs by class, forward and backward by the same
+    ranges, and their sum against ``flash_tile_pairs``."""
+    from accl_tpu.ops.pallas import attention as fa
+
+    want = dict(zip(fa.TILE_CLASSES, classes))
+    assert fa.flash_tile_classes(T, block, window) == want
+    assert pk.flash_tile_pairs(T, block, window) == sum(classes)
+    b = fa._flash_block(T, jnp.bfloat16, block)
+    n = -(-T // b)
+    assert _tile_classes_by_hand(T, b, window) == collections.Counter(
+        {c: k for c, k in want.items() if k})
+    for forward in (True, False):
+        got = collections.Counter(
+            cls for y in range(n)
+            for _, cls in _visited(y, n, b, True, window, T % b != 0, forward)
+        )
+        assert {c: got[c] for c in fa.TILE_CLASSES} == want, forward
+
+
+@pytest.mark.parametrize("causal,T,window", [
+    (True, 160, None), (True, 256, 150), (True, 256, 64), (True, 256, 65),
+    (True, 160, 8), (True, 160, 1), (True, 150, None), (True, 250, 100),
+    (True, 250, 40), (True, 20, None), (False, 160, None), (False, 150, None),
+])
+def test_flash_tile_class_masks_have_the_every_tile_masks_truth(causal, T, window):
+    """Tiles of 32: on every pair either kernel visits, the mask of the
+    pair's class (one compare of the hoisted ``row - col`` against a scalar
+    from the tiles' distance; none on an interior tile) has the truth
+    values of the mask built from the positions, and the pairs come in
+    the order they came in (k tiles ascending in the forward, q tiles
+    ascending in the backward)."""
+    from accl_tpu.ops.pallas import attention as fa
+
+    b = 32
+    n = -(-T // b)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    for forward in (True, False):
+        for y in range(n):
+            xs = []
+            for x, cls in _visited(y, n, b, causal, window, T % b != 0, forward):
+                xs.append(x)
+                iq, jk = (y, x) if forward else (x, y)
+                pad = [cols < T - jk * b] + ([] if forward else [rows < T - iq * b])
+                got = fa._tile_mask(
+                    cls, rows - cols, iq - jk, b, causal, window,
+                    pad if cls == "padded" else [],
+                )
+                want = _every_tile_mask(iq, jk, b, T, causal, window,
+                                        with_q=not forward)
+                assert (cls == "interior") == (got is None), (y, x, cls)
+                if got is None:
+                    got = jnp.ones((b, b), bool)
+                np.testing.assert_array_equal(
+                    np.asarray(got), np.asarray(want), err_msg=f"{y} {x} {cls}")
+            bounds = fa._window_k_tiles if forward else fa._window_q_tiles
+            lo, hi = bounds(y, b, n, window) if causal else (0, n)
+            assert xs == list(range(int(lo), int(hi))), (forward, y)
+
+
+def _every_tile_masked_fold(q, k, v, g, causal, block, window):
+    """``o``, ``dq``, ``dk``, ``dv`` of ``flash_attention`` by the
+    arithmetic the kernels had before the tile classes, written in
+    ``jax.numpy``: the same tiles in the same order, the same products on
+    the same padded tiles, and on EVERY visited pair the mask from the
+    positions and the select.  One jitted function a tile pair and pass."""
+    from accl_tpu.ops.pallas import attention as fa
+
+    B, H, T, D = q.shape
+    Hkv = k.shape[1]
+    G = H // Hkv
+    scale = 1.0 / D ** 0.5
+    b = fa._flash_block(T, q.dtype, block)
+    padT, padD = (-T) % b, (-D) % fa.LANES
+    n, Dp = (T + padT) // b, D + padD
+    if window is not None and window >= T:
+        window = None
+    pad = lambda a: jnp.pad(a, [(0, 0), (0, 0), (0, padT), (0, padD)])
+    qp, kp, vp, gp = pad(q), pad(k), pad(v), pad(g)
+
+    def tile(a, bh, i):
+        h = bh % H
+        return a[bh // H, h if a.shape[1] == H else h // G, i * b:(i + 1) * b]
+
+    def dot(a, c, dims):
+        return jax.lax.dot_general(
+            a, c, (dims, ((), ())), preferred_element_type=jnp.float32,
+            precision=fa._mxu_precision(a.dtype))
+
+    @jax.jit
+    def fwd_pair(m, l, acc, qb, kb, vb, i, j):
+        s = dot(qb, kb, ((1,), (1,))) * scale
+        s = jnp.where(_every_tile_mask(i, j, b, T, causal, window), s, -1e30)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
+                acc * alpha + dot(p.astype(vb.dtype), vb, ((1,), (0,))))
+
+    @jax.jit
+    def bwd_pair(dk, dv, dq, qb, kb, vb, dob, lse, delta, i, j):
+        s = dot(qb, kb, ((1,), (1,))) * scale
+        mask = _every_tile_mask(i, j, b, T, causal, window, with_q=True)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        dv = dv + dot(p.astype(dob.dtype), dob, ((0,), (0,)))
+        ds = (p * (dot(dob, vb, ((1,), (1,))) - delta) * scale).astype(qb.dtype)
+        return (dk + dot(ds, qb, ((0,), (0,))), dv,
+                dq + dot(ds, kb, ((1,), (0,))))
+
+    o, lse = [], []
+    for bh in range(B * H):
+        for i in range(n):
+            m = jnp.full((b, 1), -1e30, jnp.float32)
+            l = jnp.zeros((b, 1), jnp.float32)
+            acc = jnp.zeros((b, Dp), jnp.float32)
+            for j, _ in _visited(i, n, b, causal, window, False, True):
+                m, l, acc = fwd_pair(
+                    m, l, acc, tile(qp, bh, i), tile(kp, bh, j),
+                    tile(vp, bh, j), i, j)
+            o.append((acc / jnp.maximum(l, 1e-30)).astype(q.dtype))
+            lse.append(m + jnp.log(jnp.maximum(l, 1e-30)))
+    out = jnp.concatenate(o).reshape(B, H, n * b, Dp)[:, :, :T, :D]
+    lse = jnp.concatenate(lse).reshape(B, H, n * b)[:, :, :T]
+    # what _flash_bwd_impl hands its kernel
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    rows = [(0, 0), (0, 0), (0, padT)]
+    lse = jnp.pad(lse, rows, constant_values=-1e30).reshape(B * H, n, b, 1)
+    delta = jnp.pad(delta, rows).reshape(B * H, n, b, 1)
+    dq, dk, dv = [], [], []
+    for bh in range(B * H):
+        dq_acc = [jnp.zeros((b, Dp), jnp.float32) for _ in range(n)]
+        for j in range(n):
+            dkj = dvj = jnp.zeros((b, Dp), jnp.float32)
+            for i, _ in _visited(j, n, b, causal, window, False, False):
+                dkj, dvj, dq_acc[i] = bwd_pair(
+                    dkj, dvj, dq_acc[i], tile(qp, bh, i), tile(kp, bh, j),
+                    tile(vp, bh, j), tile(gp, bh, i), lse[bh, i],
+                    delta[bh, i], i, j)
+            dk.append(dkj.astype(q.dtype))
+            dv.append(dvj.astype(q.dtype))
+        dq += [a.astype(q.dtype) for a in dq_acc]
+    dq = jnp.concatenate(dq).reshape(B, H, n * b, Dp)[:, :, :T, :D]
+
+    def group_sum(a):
+        a = jnp.concatenate(a).reshape(B, Hkv, G, n * b, Dp)[:, :, :, :T, :D]
+        if G == 1:
+            return a[:, :, 0]
+        return a.astype(jnp.float32).sum(2).astype(k.dtype)
+
+    return out, dq, group_sum(dk), group_sum(dv)
+
+
+#: name -> (H, Hkv, T, dtype, causal, window), tiles of 32, D = 64
+_FLASH_BIT_CASES = {
+    "interior_t160": (2, 2, 160, jnp.float32, True, None),
+    "window_two_edges_t256": (2, 1, 256, jnp.float32, True, 150),
+    "window_under_a_block_t160": (2, 2, 160, jnp.float32, True, 8),
+    "ragged_t150_gqa": (4, 2, 150, jnp.float32, True, None),
+    "ragged_t250_window": (2, 1, 250, jnp.float32, True, 100),
+    "not_causal_ragged_t150": (2, 2, 150, jnp.float32, False, None),
+    "bf16_window_t256": (2, 1, 256, jnp.bfloat16, True, 150),
+    "bf16_ragged_t150": (2, 2, 150, jnp.bfloat16, True, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_FLASH_BIT_CASES))
+def test_flash_attention_bit_equal_to_the_every_tile_masked_fold(case):
+    """A select whose mask is all true is the identity and the class
+    masks have the old masks' truth values, so ``o``, ``dq``, ``dk`` and
+    ``dv`` are the numbers they were when every visited tile was masked:
+    equal BIT FOR BIT to that fold written out in ``jax.numpy``.  D = 64,
+    so ``scale`` is a power of two: the CPU's compiler contracts ``dot *
+    scale - m`` of an interior tile into one fused multiply-add, which
+    rounds once where the select in between kept two roundings, and with
+    an exact ``scale`` both round alike (on the chip, which has no such
+    contraction, the kernels equal the parent's at D = 128: CHANGES.md,
+    PR 32)."""
+    _interpreter_only()
+    H, Hkv, T, dtype, causal, window = _FLASH_BIT_CASES[case]
+    rng = np.random.default_rng(33)
+    q, g = (jnp.asarray(rng.standard_normal((1, H, T, 64)), dtype)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((1, Hkv, T, 64)), dtype)
+            for _ in range(2))
+    out, vjp = jax.vjp(
+        lambda q, k, v: pk.flash_attention(
+            q, k, v, causal=causal, block=32, window=window), q, k, v)
+    want = _every_tile_masked_fold(q, k, v, g, causal, 32, window)
+    for a, b, name in zip((out, *vjp(g)), want, ("o", "dq", "dk", "dv")):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name)
 
 
 def _pallas_calls(jaxpr, stack=""):
