@@ -11,8 +11,10 @@ machinery is the substrate for.
 """
 
 from .transformer import (
+    LatentAttention,  # noqa: F401
     LayerKind,  # noqa: F401
     TransformerConfig,
+    YarnScaling,  # noqa: F401
     generate,
     init_params,
     forward,

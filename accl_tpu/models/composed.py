@@ -49,6 +49,7 @@ from .transformer import (
     _embed_tokens,
     _layernorm,
     _reject_untrainable_attention,
+    reject_latent,
     init_params,
 )
 
@@ -272,6 +273,7 @@ def make_pp_train_step(
             "n_experts (MoE) is supported on the decoder flagship only "
             "(forward/loss_fn/generate), not the composed pipeline"
         )
+    reject_latent(cfg, "the composed pipeline")
     if not cfg.default_block():
         raise ValueError(
             "norm/ffn/qk_norm/tie_head other than the default block are "

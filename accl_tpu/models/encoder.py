@@ -33,6 +33,7 @@ from .transformer import (
     _enter_block_layout,
     _layernorm,
     _reject_untrainable_attention,
+    reject_latent,
     _shard_params,
     param_specs,
 )
@@ -66,6 +67,7 @@ def encoder_forward(
             "n_experts (MoE) is supported on the decoder flagship only "
             "(forward/loss_fn/generate), not the encoder family"
         )
+    reject_latent(cfg, "the encoder family")
     if not cfg.default_block():
         raise ValueError(
             "norm/ffn/qk_norm/tie_head other than the default block are "

@@ -52,6 +52,19 @@ picked by what the layer's parameters hold or by the caller's word:
   the exchange would.  The layer counts tokens an expert over ALL the
   router's experts, the entries held here, and those dropped.
 
+* GROUP-LIMITED top-k (``n_group`` > 1, the softmax router; DeepSeek-V2's
+  ``group_limited_greedy``): the router's experts are ``n_group`` groups
+  of consecutive experts (the devices of its expert-parallel group), a
+  group's score is the LARGEST of its experts' probabilities, the
+  ``topk_group`` best groups stay and the top-k is over what stays; the
+  weights are the chosen probabilities, times ``route_scale``.  With a
+  held share of whole groups an entry can be held only where its group
+  was kept.
+* the BALANCE LOSSES (``balance_groups`` > 0; :func:`balance_losses`):
+  DeepSeek-V2's expert-, device- and communication-level terms, each a
+  sequence's, averaged over the batch; whole on every chip, since the
+  router scores all of its experts everywhere.
+
 Experts are two-matrix GELU FFNs (``w1``, ``w2``) or, with a ``w3`` in
 the parameters, gated-SiLU FFNs ``(silu(x w1) * (x w3)) w2``.
 """
@@ -123,6 +136,40 @@ def _take_rows_bwd(k, res, g):
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def balance_losses(probs, topk_e, seqs: int, n_group: int, topk_group: int):
+    """DeepSeek-V2's three balance losses of one layer, unweighted:
+    ``probs`` (N, E) the router's float32 probabilities, ``topk_e`` (N, k)
+    the chosen experts, ``N = seqs * T`` tokens in ``seqs`` sequences,
+    ``n_group`` groups ``E_d`` of consecutive experts, of which a token
+    may reach ``topk_group`` (= ``n_group`` where routing has no limit).
+    For each sequence, with ``c_e`` its entries sent to expert ``e``,
+    ``f_e = E / (k T) c_e``, ``P_e`` its tokens' mean ``probs[:, e]`` and
+    ``n_d`` its tokens with at least one entry in group ``d``:
+
+    * ``expert``: ``sum_e f_e P_e``;
+    * ``device``: ``sum_d (mean_{e in E_d} f_e) (sum_{e in E_d} P_e)``;
+    * ``comm``:   ``sum_d n_group / (topk_group T) n_d (sum_{e in E_d} P_e)``;
+
+    then the mean over the sequences.  The counts carry no gradient; the
+    gradient is ``P``'s."""
+    N, E = probs.shape
+    k = topk_e.shape[-1]
+    T = N // seqs
+    hit = jax.nn.one_hot(topk_e, E, dtype=jnp.float32).sum(axis=1)  # (N, E)
+    hit = lax.stop_gradient(hit).reshape(seqs, T, n_group, E // n_group)
+    f = hit.sum(axis=1) * (E / (k * T))                     # (S, G, E/G)
+    P = probs.reshape(seqs, T, n_group, E // n_group).mean(axis=1)
+    n = (hit.sum(axis=-1) > 0).astype(jnp.float32).sum(axis=1)   # (S, G)
+    group_p = P.sum(axis=-1)                                # (S, G)
+    return {
+        "balance_expert": jnp.mean(jnp.sum(f * P, axis=(1, 2))),
+        "balance_device": jnp.mean(jnp.sum(f.mean(axis=-1) * group_p, axis=1)),
+        "balance_comm": jnp.mean(jnp.sum(
+            n * (n_group / (topk_group * T)) * group_p, axis=1
+        )),
+    }
 
 
 def _expert_act(up, params, matmul):
@@ -285,6 +332,9 @@ def moe_ffn(
     route_scale: float = 1.0,
     first_expert: int = 0,
     held_row_factor: float = 2.0,
+    n_group: int = 1,
+    topk_group: int = 1,
+    balance_groups: int = 0,
 ):
     """Top-k gated MoE FFN (k=1 is Switch routing, k=2 the classic MoE).
 
@@ -307,7 +357,11 @@ def moe_ffn(
     ``router``, ``route_scale``, ``first_expert`` and ``held_row_factor``
     are this dispatch's (module docstring: the sigmoid router, the shared
     expert, held experts); with them ``return_aux`` adds ``held_entries``
-    and its ``load_balance`` and ``router_z`` are zero.
+    and its ``load_balance`` and ``router_z`` are zero.  So are
+    ``n_group`` / ``topk_group`` (group-limited top-k on the softmax
+    router; 1, 1 is plain top-k) and ``balance_groups``: where it is not
+    0, ``return_aux`` adds :func:`balance_losses` over that many groups,
+    a sequence a row of ``x``, held share or not.
 
     Returns (B, T, D): expert outputs weighted by the gate probability;
     over-capacity entries contribute zero (callers add the residual).
@@ -332,7 +386,8 @@ def moe_ffn(
 
     and two counters: ``expert_tokens`` (E,), the routing entries sent to
     each expert, and ``dropped``, the entries past capacity (0 when
-    dropless).
+    dropless); under group-limited routing also ``group_tokens``
+    (n_group,), the tokens whose kept groups include each group.
     """
     B, T, D = x.shape
     N = B * T
@@ -343,10 +398,14 @@ def moe_ffn(
     E = e_local * ep  # global expert count
     n_router = params["gate"].shape[1]
 
-    beyond = router != "softmax" or "shared" in params or n_router != E
+    beyond = (
+        router != "softmax" or "shared" in params or n_router != E
+        or n_group > 1 or route_scale != 1.0 or bool(balance_groups)
+    )
     if beyond and capacity_factor is not None:
         raise ValueError(
-            "the sigmoid router, a shared expert and held experts are the "
+            "the sigmoid router, a shared expert, held experts, grouped "
+            "top-k, a route scale and the balance losses are the "
             "dropless dispatch's (capacity_factor=None)"
         )
     if capacity_factor is None:
@@ -379,9 +438,23 @@ def moe_ffn(
                 raise ValueError(f"unknown router {router!r}")
             else:
                 probs = jax.nn.softmax(logits, axis=-1)
-                topk_p, topk_e = lax.top_k(probs, k)
+                pick = probs
+                if n_group > 1:
+                    # a group's score is its best expert's; what is not in
+                    # one of the topk_group best groups scores 0
+                    best = probs.reshape(N, n_group, -1).max(axis=-1)
+                    _, keep = lax.top_k(best, topk_group)
+                    kept = jax.nn.one_hot(
+                        keep, n_group, dtype=probs.dtype
+                    ).sum(axis=1)                         # (N, n_group) 1/0
+                    pick = probs * jnp.repeat(
+                        kept, n_router // n_group, axis=-1
+                    )
+                topk_p, topk_e = lax.top_k(pick, k)
                 if k > 1 and renormalize:  # as below: k=1 keeps the raw prob
                     topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
+                if route_scale != 1.0:
+                    topk_p = topk_p * route_scale
         if n_router != E:
             rows = held_rows(N * k, E, n_router, held_row_factor)
             y, counters = _held_experts(
@@ -404,6 +477,17 @@ def moe_ffn(
         y = y.reshape(B, T, D)
         if not return_aux:
             return y
+        if n_group > 1:
+            # tokens whose kept groups include each group
+            counters["group_tokens"] = kept.sum(axis=0).astype(jnp.int32)
+        if balance_groups:
+            if router != "softmax":
+                raise ValueError("the balance losses are the softmax router's")
+            with device_scope("accl.moe::route"):
+                counters.update(balance_losses(
+                    probs, topk_e, B, balance_groups,
+                    topk_group if n_group > 1 else balance_groups,
+                ))
         if beyond:
             return y, {
                 "load_balance": jnp.zeros((), jnp.float32),

@@ -52,6 +52,70 @@ class LayerKind:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """The sizes of a latent mixer (multi-head latent attention, MLA;
+    ``TransformerConfig.latent``): q comes up from a normed latent of
+    ``q_rank``, k's content part and v from a normed latent of
+    ``kv_rank``; a head's q and k are ``nope_dim`` columns without
+    position beside ``rope_dim`` that rotate (k's rotating part is ONE
+    head, projected straight from the input and shared by every query
+    head); v and the output are ``v_dim`` a head."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN's rescaling of the rotary frequencies (the published
+    ``rope_scaling`` of type ``yarn``; ``TransformerConfig.rope_yarn``):
+    :func:`yarn_inv_freq` has the formula."""
+
+    factor: float
+    original_max_seq: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
+    if factor <= 1.0 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, yarn: YarnScaling):
+    """The ``dim // 2`` inverse frequencies of a rotary embedding over
+    ``dim`` columns under YaRN, float32 (numpy): pair ``i`` keeps
+    ``base ** (-2 i / dim)`` where it turns more than ``beta_fast`` times
+    in the original context, is divided by ``factor`` where fewer than
+    ``beta_slow``, and is blended linearly between the two corrections'
+    pair indices ``low`` and ``high``."""
+    import numpy as np
+
+    half = dim // 2
+    extra = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / yarn.factor
+
+    def corr(turns):
+        return (
+            dim * math.log(yarn.original_max_seq / (turns * 2 * math.pi))
+            / (2 * math.log(base))
+        )
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    span = (high - low) or 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / span, 0, 1)
+    return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 256
     d_model: int = 128
@@ -73,6 +137,12 @@ class TransformerConfig:
     # into the params; requires an even head dim)
     pos_embedding: str = "learned"
     rope_base: float = 10000.0
+    # YaRN (:class:`YarnScaling`): the rotary tables take its inverse
+    # frequencies (:func:`yarn_inv_freq`) in place of ``rope_base``'s own,
+    # times ``m(mscale) / m(mscale_all_dim)``; a latent mixer's softmax
+    # scale is times ``m(mscale_all_dim) ** 2``.  ``None``: the plain
+    # frequencies, computed in the program as they always were.
+    rope_yarn: Optional[YarnScaling] = None
     # Megatron vocab parallelism: the embedding table shards its VOCAB
     # rows over tp.  Lookup becomes mask + tp-allreduce; the LM loss
     # computes a fused vocab-parallel cross-entropy on the SHARDED
@@ -124,8 +194,12 @@ class TransformerConfig:
     # are then ``n_heads * head_dim`` wide, :meth:`head_size`).  The
     # train and forward paths honour all of them; prefill/generate, the
     # context- and sequence-parallel blocks, the encoder and the
-    # composed pipeline take what :meth:`plain` allows.
+    # composed pipeline take what :meth:`plain` allows.  ``norm_eps`` is
+    # the epsilon of the block's norms, the final norm and a latent
+    # mixer's (the published ``rms_norm_eps`` / ``layer_norm_epsilon``;
+    # QK-norm keeps 1e-5).
     norm: str = "layernorm"
+    norm_eps: float = 1e-5
     ffn: str = "gelu"
     qk_norm: Union[bool, str] = False
     tie_head: bool = True
@@ -133,6 +207,23 @@ class TransformerConfig:
     post_norm: bool = False
     embed_scale: float = 1.0
     head_dim: Optional[int] = None
+    # the latent mixer (:class:`LatentAttention`, DeepSeek's MLA) in place
+    # of wq/wk/wv: ``q = RMSNorm(h wq_a) wq_b`` a head ``[nope | rope]``,
+    # ``[latent | k_rope] = h wkv_a`` with ONE rope key head, ``[k_nope |
+    # v] = RMSNorm(latent) wkv_b`` a head; rope (``rope_yarn``'s
+    # frequencies) on the rope columns only; scores over ``nope + rope``
+    # columns at ``(nope + rope) ** -0.5 * m(mscale_all_dim) ** 2``, v and
+    # the output ``v_dim`` wide, ``wo`` from ``n_heads * v_dim``.  The
+    # flash kernels take the two widths and the shared rope key as they
+    # are.  Honoured by the train and forward paths (``make_sharded_train_
+    # step``, ``make_sharded_forward``, the router probe; tp splits the
+    # heads: ``wq_b``, ``wkv_b`` column-parallel, ``wo`` row-parallel, the
+    # ``_a`` matrices and their norms replicated).  REFUSED by name by
+    # prefill/generate (no latent cache yet), the context- and
+    # sequence-parallel blocks, the encoder and the pipelines.  Needs
+    # ``pos_embedding="rope"``; ``n_kv_heads``, ``head_dim``, ``qk_norm``
+    # and ``attn_gate`` do not apply to it.
+    latent: Optional[LatentAttention] = None
     # the layer pattern (ROADMAP M1): one :class:`LayerKind` a layer —
     # the mixer's window and whether it rotates, the FFN's kind and
     # width.  ``None`` is the pattern the other fields describe, every
@@ -178,8 +269,20 @@ class TransformerConfig:
     # static buffer, ``moe_held_row_factor`` times the balanced share
     # ``tokens * k * n_experts / moe_router_experts``; an entry past it is
     # dropped and counted.
+    # Group-limited routing (dropless, the softmax router): the router's
+    # experts in ``moe_n_group`` groups of consecutive experts, a group's
+    # score the largest of its experts', the ``moe_topk_group`` best
+    # groups kept and the top-k taken over what stays (1, 1 = no limit).
+    # ``moe_route_scale`` multiplies the chosen weights on either router.
+    # ``moe_balance_weights``: DeepSeek-V2's expert-, device- and
+    # communication-level balance losses (models/moe.py), each a
+    # sequence's, averaged over the batch, summed over the expert layers
+    # and added to the loss with these weights (all zero = not computed).
     moe_router: str = "softmax"
     moe_route_scale: float = 1.0
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_balance_weights: Tuple[float, float, float] = (0.0, 0.0, 0.0)
     moe_bias_rate: float = 0.0
     moe_shared_d_ff: int = 0
     moe_router_experts: Optional[int] = None
@@ -231,12 +334,45 @@ class TransformerConfig:
             raise ValueError(
                 f"unknown pos_embedding {self.pos_embedding!r}"
             )
-        if self.pos_embedding == "rope" and self.head_size() % 2:
+        if self.pos_embedding == "rope" and self.rope_width() % 2:
             raise ValueError("rope needs an even head dim")
         return self.pos_embedding == "rope"
 
+    def rope_width(self) -> int:
+        """The columns of a head that rotate."""
+        return self.latent.rope_dim if self.latent else self.head_size()
+
+    def rope_inv_freq(self):
+        """The rotary tables' inverse frequencies where the config gives
+        them (YaRN), else ``None``: ``rope_base``'s own, computed in the
+        program."""
+        if self.rope_yarn is None:
+            return None
+        return yarn_inv_freq(self.rope_width(), self.rope_base, self.rope_yarn)
+
+    def rope_table_scale(self) -> float:
+        """What YaRN multiplies cos and sin by."""
+        y = self.rope_yarn
+        if y is None:
+            return 1.0
+        return yarn_mscale(y.factor, y.mscale) / yarn_mscale(
+            y.factor, y.mscale_all_dim
+        )
+
+    def attn_scale(self) -> Optional[float]:
+        """The softmax scale where it is not ``head_size() ** -0.5``."""
+        if self.latent is None:
+            return None
+        y = self.rope_yarn
+        m = 1.0 if y is None else yarn_mscale(y.factor, y.mscale_all_dim)
+        return self.head_size() ** -0.5 * m * m
+
     def head_size(self) -> int:
-        """One head's width: ``head_dim``, or ``d_model // n_heads``."""
+        """One head's width, the one its scores are taken over:
+        ``head_dim``, or ``d_model // n_heads``; ``nope_dim + rope_dim``
+        of a latent mixer."""
+        if self.latent is not None:
+            return self.latent.nope_dim + self.latent.rope_dim
         if self.head_dim is not None:
             return self.head_dim
         return self.d_model // self.n_heads
@@ -262,10 +398,13 @@ class TransformerConfig:
         """Every layer alike and nothing of the later kinds (a pattern, a
         head width of its own, the gate, per-head QK-norm, post-norms, a
         scaled embedding, the sigmoid router, a shared expert, a held
-        share): what prefill/generate and the context- and
-        sequence-parallel blocks compute."""
+        share, a latent mixer, grouped top-k, the balance losses): what
+        prefill/generate and the context- and sequence-parallel blocks
+        compute."""
         return (
             self.layers is None and self.head_dim is None
+            and self.latent is None and self.moe_n_group == 1
+            and not any(self.moe_balance_weights)
             and not self.attn_gate and not self.post_norm
             and self.qk_norm in (False, True) and self.embed_scale == 1.0
             and self.moe_router == "softmax" and not self.moe_shared_d_ff
@@ -281,6 +420,30 @@ class TransformerConfig:
             raise ValueError(f"unknown qk_norm {self.qk_norm!r}")
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if self.latent is not None and (
+            self.pos_embedding != "rope" or self.n_kv_heads is not None
+            or self.head_dim is not None or self.qk_norm or self.attn_gate
+        ):
+            raise ValueError(
+                "a latent mixer rotates (pos_embedding='rope') and has no "
+                "n_kv_heads, head_dim, qk_norm or attn_gate of its own"
+            )
+        if self.rope_yarn is not None and self.pos_embedding != "rope":
+            raise ValueError("rope_yarn rescales a rotary embedding")
+        if self.moe_n_group != 1 or self.moe_topk_group != 1:
+            of = self.router_experts()
+            if (
+                self.moe_router != "softmax" or self.moe_n_group < 1
+                or of % self.moe_n_group
+                or not 1 <= self.moe_topk_group <= self.moe_n_group
+                or self.moe_topk_group * (of // self.moe_n_group)
+                < self.moe_top_k
+            ):
+                raise ValueError(
+                    f"grouped top-k: {self.moe_n_group} groups must divide "
+                    f"the softmax router's {of} experts and the "
+                    f"{self.moe_topk_group} kept must hold {self.moe_top_k}"
+                )
         if self.layers is not None:
             if len(self.layers) != self.n_layers:
                 raise ValueError(
@@ -301,11 +464,13 @@ class TransformerConfig:
                     )
         beyond = (
             self.moe_router != "softmax" or self.moe_shared_d_ff
-            or self.moe_router_experts is not None
+            or self.moe_router_experts is not None or self.moe_n_group != 1
+            or any(self.moe_balance_weights)
         )
         if beyond and (not self.n_experts or self.moe_capacity_factor is not None):
             raise ValueError(
-                "the sigmoid router, a shared expert and a held share are "
+                "the sigmoid router, a shared expert, a held share, grouped "
+                "top-k and the balance losses are "
                 "the dropless path's (n_experts > 0, "
                 "moe_capacity_factor=None)"
             )
@@ -336,7 +501,8 @@ def _check_axis_compat(cfg) -> None:
             "context_parallel and seq_parallel take the plain block only "
             "(TransformerConfig.plain): no layer pattern, window, head_dim, "
             "gate, per-head QK-norm, post-norm, scaled embedding, sigmoid "
-            "router, shared expert or held share"
+            "router, shared expert, held share, latent mixer (MLA), grouped "
+            "top-k or balance losses"
         )
     if cfg.context_parallel and (cfg.seq_parallel or cfg.vocab_parallel):
         raise ValueError(
@@ -438,14 +604,23 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
     # every weight is replicated over it (dp still shards the batch)
     col = P(None, None) if cp else P(None, "tp")   # output dim on tp
     row = P(None, None) if cp else P("tp", None)   # input dim on tp
-    layer = {
-        "wq": col,  # (d_model, heads * head_size / tp): heads sharded
-        "wk": col,
-        "wv": col,
-        "wo": row,  # (heads * head_size / tp, d_model)
-        "ln1": P(None),
-        "ln2": P(None),
-    }
+    if cfg.latent is not None:
+        layer = {
+            # the down-projections and their norms are every chip's; the
+            # up-projections' columns are heads, sharded as wq's are
+            "wq_a": P(None, None), "q_a_norm": P(None), "wq_b": col,
+            "wkv_a": P(None, None), "kv_a_norm": P(None), "wkv_b": col,
+            "wo": row,  # (heads * v_dim / tp, d_model)
+        }
+    else:
+        layer = {
+            "wq": col,  # (d_model, heads * head_size / tp): heads sharded
+            "wk": col,
+            "wv": col,
+            "wo": row,  # (heads * head_size / tp, d_model)
+        }
+    layer["ln1"] = P(None)
+    layer["ln2"] = P(None)
     if cfg.attn_gate:
         layer["wg"] = col  # the gate's columns follow q's heads
     if cfg.post_norm:
@@ -538,16 +713,32 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
     def normal(key, shape):
         return jax.random.normal(key, shape, cfg.dtype) * scale
 
+    def latent_mixer(key, la):
+        ks = jax.random.split(key, 5)
+        H = cfg.n_heads
+        return {
+            "wq_a": normal(ks[0], (cfg.d_model, la.q_rank)),
+            "q_a_norm": jnp.ones((la.q_rank,), cfg.dtype),
+            "wq_b": normal(ks[1], (la.q_rank, H * (la.nope_dim + la.rope_dim))),
+            "wkv_a": normal(ks[2], (cfg.d_model, la.kv_rank + la.rope_dim)),
+            "kv_a_norm": jnp.ones((la.kv_rank,), cfg.dtype),
+            "wkv_b": normal(ks[3], (la.kv_rank, H * (la.nope_dim + la.v_dim))),
+            "wo": normal(ks[4], (H * la.v_dim, cfg.d_model)),
+        }
+
     for i, kind in enumerate(cfg.pattern()):
         kk = k[2 + 4 * i : 6 + 4 * i]
-        layer = {
-            "wq": normal(kk[0], (cfg.d_model, d_q)),
-            "wk": normal(jax.random.fold_in(kk[0], 1), (cfg.d_model, d_kv)),
-            "wv": normal(jax.random.fold_in(kk[0], 2), (cfg.d_model, d_kv)),
-            "wo": normal(kk[1], (d_q, cfg.d_model)),
-            "ln1": jnp.ones((cfg.d_model,), cfg.dtype),
-            "ln2": jnp.ones((cfg.d_model,), cfg.dtype),
-        }
+        if cfg.latent is not None:
+            layer = latent_mixer(kk[0], cfg.latent)
+        else:
+            layer = {
+                "wq": normal(kk[0], (cfg.d_model, d_q)),
+                "wk": normal(jax.random.fold_in(kk[0], 1), (cfg.d_model, d_kv)),
+                "wv": normal(jax.random.fold_in(kk[0], 2), (cfg.d_model, d_kv)),
+                "wo": normal(kk[1], (d_q, cfg.d_model)),
+            }
+        layer["ln1"] = jnp.ones((cfg.d_model,), cfg.dtype)
+        layer["ln2"] = jnp.ones((cfg.d_model,), cfg.dtype)
         if cfg.attn_gate:
             layer["wg"] = normal(jax.random.fold_in(kk[1], 1), (cfg.d_model, d_q))
         if cfg.post_norm:
@@ -578,13 +769,13 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
     return params
 
 
-def _layernorm(x, scale):
+def _layernorm(x, scale, eps: float = 1e-5):
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + 1e-5) * scale
+    return (x - mu) * jax.lax.rsqrt(var + eps) * scale
 
 
-def _rmsnorm(x, scale, tp_axis=None):
+def _rmsnorm(x, scale, tp_axis=None, eps: float = 1e-5):
     """RMSNorm, statistics in f32 and the scale applied in the input's
     type.  ``tp_axis``: the last dim is a tp shard of the normed width
     (QK-norm over head-sharded projections), so the mean square is taken
@@ -595,10 +786,16 @@ def _rmsnorm(x, scale, tp_axis=None):
     if tp_axis is not None:
         ss = collectives.allreduce(ss, tp_axis, ReduceFunction.SUM)
         width = width * jax.lax.axis_size(tp_axis)
-    return (x32 * jax.lax.rsqrt(ss / width + 1e-5)).astype(x.dtype) * scale
+    return (x32 * jax.lax.rsqrt(ss / width + eps)).astype(x.dtype) * scale
 
 
 _NORMS = {"layernorm": _layernorm, "rmsnorm": _rmsnorm}
+
+
+def _norm_fn(cfg):
+    """The config's norm at its ``norm_eps``."""
+    fn = _NORMS[cfg.norm]
+    return fn if cfg.norm_eps == 1e-5 else partial(fn, eps=cfg.norm_eps)
 
 
 def _qk_norm(q, k, lp, tp_axis):
@@ -671,15 +868,24 @@ def _embed_tokens(params, tokens, cfg, tp_axis=None) -> jax.Array:
     return x
 
 
+_BALANCE = ("balance_expert", "balance_device", "balance_comm")
+
+
 def _moe_penalty(cfg, aux) -> jax.Array:
     """The router health penalty loss_fn adds for MoE configs: Switch
     load-balance aux + ST-MoE z-loss, averaged over layers (``aux``
-    carries the layer SUMS from :func:`_final_hidden`)."""
+    carries the layer SUMS from :func:`_final_hidden`); and where the
+    config weighs them (``moe_balance_weights``) DeepSeek-V2's three
+    balance losses, summed over the expert layers."""
     n = float(cfg.n_layers)
-    return (
+    penalty = (
         cfg.moe_aux_weight * aux["load_balance"] / n
         + cfg.moe_router_z_weight * aux["router_z"] / n
     )
+    if any(cfg.moe_balance_weights):
+        for weight, name in zip(cfg.moe_balance_weights, _BALANCE):
+            penalty = penalty + weight * aux[name]
+    return penalty
 
 
 def _token_nll(logits, targets) -> jax.Array:
@@ -707,17 +913,25 @@ def _lm_logits(x, params, cfg, tp_axis, gather: bool = True) -> jax.Array:
     return z
 
 
-def _rope_tables(positions, half: int, base: float):
+def _rope_tables(positions, half: int, base: float, inv_freq=None,
+                 table_scale: float = 1.0):
     """cos/sin tables for rotary embedding at the given absolute
     ``positions`` (shape (T,); traced values fine — decode passes its
     dynamic cursor).  Computed once per attention site and shared by
     the q and k rotations (and across layers on the decode path), so
     scanned/rematerialized blocks don't rebuild the pow/cos/sin chain
-    per layer."""
-    freqs = jnp.asarray(base, jnp.float32) ** (
-        -jnp.arange(0, half, dtype=jnp.float32) / half
-    )
+    per layer.  ``inv_freq`` (``half`` of them) are the configuration's
+    own inverse frequencies (YaRN, ``TransformerConfig.rope_inv_freq``)
+    in place of ``base``'s; ``table_scale`` multiplies both tables."""
+    if inv_freq is None:
+        freqs = jnp.asarray(base, jnp.float32) ** (
+            -jnp.arange(0, half, dtype=jnp.float32) / half
+        )
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # (T, half)
+    if table_scale != 1.0:
+        return jnp.cos(ang) * table_scale, jnp.sin(ang) * table_scale
     return jnp.cos(ang), jnp.sin(ang)
 
 
@@ -786,18 +1000,43 @@ def resolve_attention(impl: str, q) -> str:
 
 
 def _attention(q, k, v, impl: str = "naive", causal: bool = True,
-               window: Optional[int] = None):
+               window: Optional[int] = None, scale: Optional[float] = None,
+               q_rope=None, k_rope=None):
     """Attention; q,k,v: (B, H, T, hd); ``causal=False`` is the
     bidirectional (encoder) form; ``window`` (causal only) keeps a
     query's last ``window`` keys, its own among them, in every lowering.
+    v (and the result) may be another width than q and k; ``scale``
+    multiplies the scores (``hd ** -0.5`` where not given); ``q_rope``
+    (B, H, T, dr) and ``k_rope`` (B, fewer heads, T, dr) are a second
+    part of q and k whose product is added to the scores: the flash
+    kernels take them as they are (the few key heads shared through the
+    index map), the XLA forms get them joined onto q and k.
 
     ``impl="auto"`` resolves through :func:`resolve_attention`;
     ``"blockwise"`` runs the fused online-softmax fold (no (T, T) score
     matrix in HBM); ``"naive"`` is the materialized-scores baseline."""
-    impl = resolve_attention(impl, q)
-    # no window: each lowering is called as it was before there was one
-    # (tests put a spy of the old signature in a lowering's place)
+    if q_rope is None:
+        impl = resolve_attention(impl, q)
+    else:
+        wide = jax.ShapeDtypeStruct(
+            q.shape[:-1] + (q.shape[-1] + q_rope.shape[-1],), q.dtype
+        )
+        impl = resolve_attention(impl, wide)
+        if scale is None:
+            scale = wide.shape[-1] ** -0.5
+        if impl != "flash":
+            B, H, T, _ = q.shape
+            expand = lambda t: jnp.broadcast_to(
+                t[:, :, None], (B, t.shape[1], H // t.shape[1], T, t.shape[-1])
+            ).reshape(B, H, T, t.shape[-1])
+            q = jnp.concatenate([q, q_rope], axis=-1)
+            k = jnp.concatenate([expand(k), expand(k_rope)], axis=-1)
+            q_rope = k_rope = None
+    # no window, no scale: each lowering is called as it was before there
+    # was one (tests put a spy of the old signature in a lowering's place)
     windowed = {} if window is None else {"window": window}
+    if scale is not None:
+        windowed["scale"] = scale
     if impl == "blockwise":
         from ..ops.attention import blockwise_attention
 
@@ -808,6 +1047,8 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
         # from the saved logsumexp — no (T, T) residual)
         from ..ops.pallas.attention import flash_attention
 
+        if q_rope is not None:
+            windowed.update(q_rope=q_rope, k_rope=k_rope)
         return flash_attention(q, k, v, causal=causal, **windowed)
     if impl != "naive":
         raise ValueError(f"unknown attention impl {impl!r}")
@@ -823,7 +1064,7 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
     # bf16 activations to f32 through the rest of the block.
     scores = jnp.einsum(
         "bhgqd,bhkd->bhgqk", qg, k, preferred_element_type=jnp.float32
-    ) * (1.0 / math.sqrt(hd))
+    ) * (1.0 / math.sqrt(hd) if scale is None else scale)
     if window is not None and not causal:
         raise ValueError("a window is causal")
     if causal:
@@ -833,7 +1074,7 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
         scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v)
-    return out.reshape(B, H, T, hd)
+    return out.reshape(B, H, T, v.shape[-1])
 
 
 def _ffn_hidden(h, lp):
@@ -871,8 +1112,14 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
             cf = float(moe_cfg.n_experts)
         beyond = {}
         if moe_cfg.moe_router != "softmax":
-            beyond.update(router=moe_cfg.moe_router,
-                          route_scale=moe_cfg.moe_route_scale)
+            beyond.update(router=moe_cfg.moe_router)
+        if moe_cfg.moe_route_scale != 1.0:
+            beyond.update(route_scale=moe_cfg.moe_route_scale)
+        if moe_cfg.moe_n_group != 1:
+            beyond.update(n_group=moe_cfg.moe_n_group,
+                          topk_group=moe_cfg.moe_topk_group)
+        if with_aux and any(moe_cfg.moe_balance_weights):
+            beyond.update(balance_groups=moe_cfg.moe_n_group)
         if moe_cfg.moe_router_experts is not None:
             beyond.update(first_expert=moe_cfg.moe_first_expert,
                           held_row_factor=moe_cfg.moe_held_row_factor)
@@ -904,9 +1151,54 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
     return (x + partial_f, None) if with_aux else x + partial_f
 
 
+def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
+                         window, latent):
+    """The latent mixer (``TransformerConfig.latent``) on a full-sequence
+    activation, heads column-parallel: the row-parallel PARTIAL output.
+    The sizes are the tree's (``wq_b`` and ``wkv_b`` hold this chip's
+    heads); ``latent`` carries what the shapes do not say: the softmax
+    ``scale``, the rotary ``inv_freq`` and ``table_scale``, the norms'
+    ``eps``.  Projections, norms and rope run under the device scope
+    ``accl.attn::latent``, the score/softmax/value core under
+    ``accl.attn::mla``; the rope key goes to the core as ONE head."""
+    B, T, _ = h.shape
+    H = n_heads_local
+    rank = lp["wkv_b"].shape[0]
+    dr = lp["wkv_a"].shape[1] - rank
+    dn = lp["wq_b"].shape[1] // H - dr
+    norm = partial(_rmsnorm, eps=latent["eps"])
+    heads = lambda t, n: t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
+    with device_scope("accl.attn::latent"):
+        q = heads(norm(h @ lp["wq_a"], lp["q_a_norm"]) @ lp["wq_b"], H)
+        ckv = h @ lp["wkv_a"]
+        kv = heads(norm(ckv[..., :rank], lp["kv_a_norm"]) @ lp["wkv_b"], H)
+        q_n, q_r = q[..., :dn], q[..., dn:]
+        k_n, v = kv[..., :dn], kv[..., dn:]
+        k_r = heads(ckv[..., rank:], 1)
+        if rope_base is not None:
+            tables = _rope_tables(
+                jnp.arange(T), dr // 2, rope_base, latent["inv_freq"],
+                latent["table_scale"],
+            )
+            q_r = _rope_rotate(q_r, tables)
+            k_r = _rope_rotate(k_r, tables)
+        # inside a shard_map: the one rope key varying over the axes the
+        # heads vary over (tp), so that its cotangent is summed over the
+        # chips' heads by the cast's transpose
+        if missing := tuple(jax.typeof(q_r).vma - jax.typeof(k_r).vma):
+            k_r = jax.lax.pcast(k_r, missing, to="varying")
+    with device_scope("accl.attn::mla"):
+        attn = _attention(
+            q_n, k_n, v, impl=attn_impl, causal=causal, window=window,
+            scale=latent["scale"], q_rope=q_r, k_rope=k_r,
+        )
+    with device_scope("accl.attn::latent"):
+        return attn.transpose(0, 2, 1, 3).reshape(B, T, -1) @ lp["wo"]
+
+
 def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
                   rope_base=None, positions=None, attention_fn=None,
-                  tp_axis=None, window=None, head_norm=False):
+                  tp_axis=None, window=None, head_norm=False, latent=None):
     """Column-parallel attention on a full-sequence activation: returns
     the row-parallel PARTIAL output (pre-reduction) and the (k, v) head
     tensors (B, Hkv_local, T, hd) for KV-cache prefill.  The kv head
@@ -925,8 +1217,14 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     ``q_norm`` / ``k_norm`` scales are one head wide and norm each head
     AFTER the split (:func:`_qk_norm` is the whole projection's);
     a ``wg`` gates the attention output, ``attn * sigmoid(h wg)``, before
-    ``wo``.  ``window`` is the sliding window, run under the device scope
-    ``accl.attn::window`` (full attention stays ``accl.attn::core``)."""
+    ``wo``; a ``wq_a`` is the latent mixer's (:func:`_latent_attn_partial`,
+    which has no cache to return yet).  ``window`` is the sliding window,
+    run under the device scope ``accl.attn::window`` (full attention stays
+    ``accl.attn::core``)."""
+    if "wq_a" in lp:
+        return _latent_attn_partial(
+            h, lp, n_heads_local, attn_impl, causal, rope_base, window, latent
+        ), None
     B, T, _ = h.shape
     q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]  # column-parallel
     if not head_norm:
@@ -966,7 +1264,7 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
            attn_impl="naive", causal=True, rope_base=None,
            ep_axis=None, moe_cfg=None, with_aux=False,
            reduce_fn=None, fanout_fn=None, norm=_layernorm,
-           window=None, head_norm=False):
+           window=None, head_norm=False, latent=None):
     """One transformer block on tp-sharded weights.  ``lp['wqkv']`` etc. are
     the *local shards*; the tp-allreduce after each row-parallel matmul is
     the reference's fused-allreduce hot path in model form.
@@ -991,7 +1289,7 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
         h = fanout_fn(h, tp_axis)
     partial_o, kv = _attn_partial(
         h, lp, n_heads_local, attn_impl, causal, rope_base, tp_axis=tp_axis,
-        window=window, head_norm=head_norm,
+        window=window, head_norm=head_norm, latent=latent,
     )
     if tp_axis is not None:
         partial_o = reduce_fn(partial_o, tp_axis)
@@ -1120,7 +1418,7 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
         cp_kw = dict(
             n_heads=cfg.n_heads, cp_axis=tp_axis,
             rope_base=cfg.rope_base if cfg.uses_rope() else None,
-            attn_impl=cfg.attention, norm=_NORMS[cfg.norm],
+            attn_impl=cfg.attention, norm=_norm_fn(cfg),
         )
         if cfg.n_experts:
             cp_kw["ep_axis"] = cfg.moe_mesh_axis
@@ -1134,7 +1432,12 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
             f"vocab_parallel needs vocab ({cfg.vocab}) divisible by tp "
             f"({tp_size})"
         )
-    if tp_size > 1 and cfg.kv_heads() % tp_size:
+    if cfg.latent is not None and cfg.n_heads % tp_size:
+        raise ValueError(
+            f"n_heads ({cfg.n_heads}) must be divisible by tp ({tp_size}) "
+            "so every chip owns whole heads of the latent mixer"
+        )
+    if tp_size > 1 and cfg.latent is None and cfg.kv_heads() % tp_size:
         raise ValueError(
             f"n_kv_heads ({cfg.kv_heads()}) must be divisible by tp "
             f"({tp_size}) so every chip owns whole kv heads"
@@ -1144,12 +1447,17 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
         n_heads_local=heads_local, tp_axis=tp_axis,
         attn_impl=cfg.attention, causal=causal,
         rope_base=cfg.rope_base if cfg.uses_rope() else None,
-        norm=_NORMS[cfg.norm],
+        norm=_norm_fn(cfg),
     )
     if return_kv:
         kw["return_kv"] = True
     if cfg.qk_norm == "head":
         kw["head_norm"] = True
+    if cfg.latent is not None:
+        kw["latent"] = {
+            "scale": cfg.attn_scale(), "inv_freq": cfg.rope_inv_freq(),
+            "table_scale": cfg.rope_table_scale(), "eps": cfg.norm_eps,
+        }
     if cfg.n_experts:
         # expert parallelism rides cfg.moe_mesh_axis ("dp" welded, or a
         # dedicated "ep"): the sharded makers always run over a mesh
@@ -1196,38 +1504,48 @@ def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):
     ``layout`` flags how ``x`` is sequence-sharded ("" / "sp" / "cp");
     ``aux`` is None for dense FFNs or the MoE router's terms: the health
     terms summed over layers ({"load_balance", "router_z"}, shared by
-    forward() and the fused loss) and its counters an MoE layer
+    forward() and the fused loss; the three ``balance_*`` where the config
+    weighs them) and its counters an MoE layer
     ("expert_tokens" (L, E) over all the router's experts, "dropped"
-    (L,), and where the chip holds a share of them "held_entries" (L,);
+    (L,), where the chip holds a share of them "held_entries" (L,), under
+    group-limited routing "group_tokens" (L, n_group);
     the router probe and the expert bias's rule read them, elsewhere
     they are dead code)."""
     x = _embed_tokens(params, tokens, cfg, tp_axis)
     x, block, sp = _enter_block_layout(x, cfg, tp_axis, tp_size)
     blocks = _layer_blocks(block, cfg)
-    norm = _NORMS[cfg.norm]
+    norm = _norm_fn(cfg)
     if not cfg.n_experts:
         for blk, lp in zip(blocks, params["layers"]):
             x = blk(x, lp)
         return norm(x, params["ln_f"]), sp, None
     lb = jnp.zeros((), jnp.float32)
     rz = jnp.zeros((), jnp.float32)
-    counts, dropped, held = [], [], []
+    balance = {}
+    counts, dropped, held, groups = [], [], [], []
     for blk, lp in zip(blocks, params["layers"]):
         x, aux = blk(x, lp)
         if aux is None:
             continue  # a dense layer of the pattern
         lb = lb + aux["load_balance"]
         rz = rz + aux["router_z"]
+        for name in _BALANCE:
+            if name in aux:
+                balance[name] = balance.get(name, 0.0) + aux[name]
         counts.append(aux["expert_tokens"])
         dropped.append(aux["dropped"])
         if "held_entries" in aux:
             held.append(aux["held_entries"])
+        if "group_tokens" in aux:
+            groups.append(aux["group_tokens"])
     aux = {
-        "load_balance": lb, "router_z": rz,
+        "load_balance": lb, "router_z": rz, **balance,
         "expert_tokens": jnp.stack(counts), "dropped": jnp.stack(dropped),
     }
     if held:
         aux["held_entries"] = jnp.stack(held)
+    if groups:
+        aux["group_tokens"] = jnp.stack(groups)
     return norm(x, params["ln_f"]), sp, aux
 
 
@@ -1300,7 +1618,8 @@ def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1,
             x, _, aux = _final_hidden(params, tokens, cfg, tp_axis, tp_size)
             logits = _lm_logits(x, params, cfg, tp_axis)
             loss = _token_nll(logits, targets).mean()
-            if cfg.moe_aux_weight or cfg.moe_router_z_weight:
+            if (cfg.moe_aux_weight or cfg.moe_router_z_weight
+                    or any(cfg.moe_balance_weights)):
                 loss = loss + _moe_penalty(cfg, aux)
             return (loss, aux) if with_aux else loss
         logits = forward(params, tokens, cfg, tp_axis, tp_size)
@@ -1365,9 +1684,20 @@ def _reject_unservable(cfg) -> None:
             "prefill/generate serve TransformerConfig.plain() only: this "
             "configuration has a layer pattern, a window, a head_dim of "
             "its own, an attention gate, per-head QK-norm, post-norms, a "
-            "scaled embedding, a sigmoid router, a shared expert or a "
-            "held share of the experts, and the decode path has no cache "
+            "scaled embedding, a sigmoid router, a shared expert, a "
+            "held share of the experts, a latent mixer (MLA: its cache is "
+            "the latent and the rope key, not k and v), grouped top-k or "
+            "balance losses, and the decode path has no cache "
             "layout or block for those yet (train and forward do)"
+        )
+
+
+def reject_latent(cfg, where: str) -> None:
+    """The paths beside train and forward refuse a latent mixer by name."""
+    if cfg.latent is not None:
+        raise ValueError(
+            "the latent mixer (MLA, TransformerConfig.latent) is supported "
+            f"on the decoder's train and forward paths only, not {where}"
         )
 
 
@@ -1467,7 +1797,7 @@ def prefill(
         ck = jnp.zeros(shape, x.dtype).at[:, :, :T].set(k)
         cv = jnp.zeros(shape, x.dtype).at[:, :, :T].set(v)
         caches.append((ck, cv))
-    x = _NORMS[cfg.norm](x, params["ln_f"])
+    x = _norm_fn(cfg)(x, params["ln_f"])
     last = x[:, -1]
     if sp:
         # the prompt's final position lives on the LAST sequence shard;
@@ -1563,10 +1893,10 @@ def generate(
                     if (tp_axis and cfg.n_experts) else None
                 ),
                 moe_cfg=cfg if cfg.n_experts else None,
-                norm=_NORMS[cfg.norm],
+                norm=_norm_fn(cfg),
             )
             new_caches.append((ck, cv))
-        x = _NORMS[cfg.norm](x, params["ln_f"])
+        x = _norm_fn(cfg)(x, params["ln_f"])
         logits = _lm_logits(x[:, 0], params, cfg, tp_axis)
         key, sub = jax.random.split(key)
         nxt = _select_token(logits, sub, temperature, top_k).astype(tok.dtype)
@@ -1741,9 +2071,10 @@ def make_sharded_router_probe(cfg: TransformerConfig, mesh: Mesh):
     jitted ``fn(params, tokens) -> {"expert_tokens": (L, E) routing
     entries sent to each of the router's experts in each MoE layer,
     "dropped": (L,) entries past capacity (or past a held share's row
-    buffer), and where the chip holds a share of the experts
-    "held_entries": (L,) entries whose expert is held here}``, summed
-    over the data axes.  A probe beside the step, so that the train step
+    buffer), where the chip holds a share of the experts
+    "held_entries": (L,) entries whose expert is held here, and under
+    group-limited routing "group_tokens": (L, n_group) tokens whose kept
+    groups include each group}``, summed over the data axes.  A probe beside the step, so that the train step
     keeps its ``(params, loss)``."""
     if not cfg.n_experts or cfg.context_parallel:
         raise ValueError("the router probe needs an MoE config without cp")
@@ -1754,8 +2085,9 @@ def make_sharded_router_probe(cfg: TransformerConfig, mesh: Mesh):
     def probe(params, tokens):
         aux = _final_hidden(params, tokens, cfg, "tp", tp)[2]
         out = {
-            k: aux[k] for k in ("expert_tokens", "dropped", "held_entries")
-            if k in aux
+            k: aux[k] for k in (
+                "expert_tokens", "dropped", "held_entries", "group_tokens"
+            ) if k in aux
         }
         for a in axes:
             out = collectives.allreduce(out, a, ReduceFunction.SUM)

@@ -35,8 +35,9 @@ _NEG = -1e30
 
 
 def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
-                   window=None):
-    """One (T, D) head: scan q blocks; fold k blocks with online softmax.
+                   window=None, scale=None):
+    """One head, q and k (T, D) and v (T, Dv): scan q blocks; fold k
+    blocks with online softmax.
 
     ``t_real`` masks padded key positions (T may be padded to block
     multiples by the wrapper); ``window`` keeps a query's last ``window``
@@ -44,7 +45,8 @@ def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
     CPU tier and the fallback past the flash kernels' VMEM gate)."""
     T, D = q.shape
     nq, nk = T // bq, T // bk
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
 
     def per_q_block(iq, qb):
         q_pos = iq * bq + jnp.arange(bq)
@@ -90,7 +92,7 @@ def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
         init = (
             jnp.full_like(qb[:, :1], _NEG, dtype=jnp.float32),
             jnp.zeros_like(qb[:, :1], dtype=jnp.float32),
-            jnp.zeros_like(qb, dtype=jnp.float32),
+            jnp.zeros_like(v[:bq], dtype=jnp.float32),
         )
         (m, l, acc), _ = lax.scan(fold, init, jnp.arange(nk))
         return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
@@ -101,7 +103,7 @@ def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
     out = jax.vmap(per_q_block)(
         jnp.arange(nq), q.reshape(nq, bq, D)
     )
-    return out.reshape(T, D)
+    return out.reshape(T, v.shape[-1])
 
 
 def blockwise_attention(
@@ -112,10 +114,13 @@ def blockwise_attention(
     block_q: int = 256,
     block_k: int = 256,
     window: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Causal (or full) attention over ``(B, H, T, Dh)`` operands without
     materializing the (T, T) score matrix.  Exact (not approximate):
-    matches the naive softmax form to float tolerance.
+    matches the naive softmax form to float tolerance.  v, and so the
+    result, may be another width than q and k; ``scale`` multiplies the
+    scores (``Dh ** -0.5`` where not given).
 
     ``window=W`` (causal only): query ``i`` sees keys ``0 <= i - j < W``,
     as ``ops.pallas.flash_attention`` has it.
@@ -143,8 +148,8 @@ def blockwise_attention(
             k[:, :, None], (B, Hkv, G, T, Dh)
         ).reshape(B, H, T, Dh)
         v = jnp.broadcast_to(
-            v[:, :, None], (B, Hkv, G, T, Dh)
-        ).reshape(B, H, T, Dh)
+            v[:, :, None], (B, Hkv, G, T, v.shape[-1])
+        ).reshape(B, H, T, v.shape[-1])
     bq = min(block_q, T) if T > 0 else block_q
     bk = min(block_k, T) if T > 0 else block_k
     pad = (-T) % max(bq, bk)
@@ -162,7 +167,8 @@ def blockwise_attention(
         k = jnp.pad(k, padding)
         v = jnp.pad(v, padding)
     single = functools.partial(
-        _attend_single, causal=causal, bq=bq, bk=bk, t_real=T, window=window
+        _attend_single, causal=causal, bq=bq, bk=bk, t_real=T, window=window,
+        scale=scale,
     )
     out = jax.vmap(jax.vmap(single))(q, k, v)
     return out[:, :, :T]

@@ -439,8 +439,8 @@ def flash_tile_pairs(T: int, block: int = 512, window=None,
 
 
 def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
-                  window=None):
-    """One grid step computes one (bq, D) output block: fold the visiting
+                  window=None, rope=False):
+    """One grid step computes one (bq, Dv) output block: fold the visiting
     k/v blocks with online softmax.  Outputs are written exactly once per
     grid step (blocked o spec): every grid axis of the FORWARD is
     independent, so none needs an "arbitrary" ordering.  (The backward
@@ -457,11 +457,19 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
 
     ``with_lse`` adds a per-row logsumexp output (the softmax normalizer,
     ``m + log l``) — the residual the backward kernel needs to rebuild
-    the probabilities tile by tile without ever storing them."""
+    the probabilities tile by tile without ever storing them.
+
+    ``rope``: two more operands follow v, a second part of q and of k
+    (``flash_attention``'s ``q_rope`` / ``k_rope``) whose product is
+    added into the same score tile before the scale."""
 
     padded = t_real < nkb * bk
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse):
+    def kernel(q_ref, k_ref, v_ref, *rest):
+        if rope:
+            qr_ref, kr_ref, *rest = rest
+            qr = qr_ref[0]  # (bq, Dr)
+        o_ref, *maybe_lse = rest
         iq = pl.program_id(1)
         # operands stay in the input dtype (bf16 MXU fast path); the
         # scale folds into the f32 scores, the softmax state is f32
@@ -480,7 +488,15 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
                     dimension_numbers=(((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                     precision=_mxu_precision(q.dtype),
-                ) * scale
+                )
+                if rope:
+                    s = s + jax.lax.dot_general(
+                        qr, kr_ref[0, pl.ds(j * bk, bk), :],
+                        dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                        precision=_mxu_precision(qr.dtype),
+                    )
+                s = s * scale
                 mask = _tile_mask(
                     cls, rc, iq - j, bq, causal, window,
                     [k_col < t_real - j * bk] if cls == "padded" else [],
@@ -504,7 +520,7 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
         init = (
             jnp.full((bq, 1), _NEG, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
-            jnp.zeros((bq, q.shape[-1]), jnp.float32),
+            jnp.zeros((bq, v_ref.shape[-1]), jnp.float32),
         )
         # causal early exit: with bq == bk, q block iq only sees k blocks
         # 0..iq; under a window only those that reach into it.  A row whose
@@ -554,28 +570,48 @@ def _flash_kv_map(H: int, Hkv: int, blocked: bool = False):
     return lambda bh, i: (head(bh), 0, 0)
 
 
+def _default_scale(q, q_rope=None) -> float:
+    """``1 / sqrt`` of the width the scores are taken over."""
+    D = q.shape[-1] + (0 if q_rope is None else q_rope.shape[-1])
+    return 1.0 / (D ** 0.5)
+
+
+def _pad_rows_lanes(padT: int, *arrays):
+    """``arrays`` (B, H, T, D) with ``padT`` more rows and each last dim
+    padded up to the lanes: an operand is as wide as what IT holds."""
+    out = []
+    for a in arrays:
+        padD = (-a.shape[-1]) % LANES
+        if padT or padD:
+            a = jnp.pad(a, [(0, 0), (0, 0), (0, padT), (0, padD)])
+        out.append(a)
+    return out
+
+
+def _flat_heads(a):
+    B, H, Tp, Dp = a.shape
+    return a.reshape(B * H, Tp, Dp)
+
+
 def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse,
-                    window=None):
+                    window=None, scale=None, q_rope=None, k_rope=None):
     B, H, T, D = q.shape
-    Hkv = k.shape[1]
-    scale = 1.0 / (D ** 0.5)
+    Hkv, Dv = k.shape[1], v.shape[-1]
+    rope = q_rope is not None
+    if scale is None:
+        scale = _default_scale(q, q_rope)
     b = _flash_block(T, q.dtype, block)
     padT = (-T) % b
-    padD = (-D) % LANES
-    if padT or padD:
-        padding = [(0, 0), (0, 0), (0, padT), (0, padD)]
-        q, k, v = (jnp.pad(a, padding) for a in (q, k, v))
-    Tp, Dp = T + padT, D + padD
+    q, k, v = _pad_rows_lanes(padT, q, k, v)
+    Tp, Dp, Dvp = T + padT, q.shape[-1], v.shape[-1]
     nq = nkb = Tp // b
 
-    qf = q.reshape(B * H, Tp, Dp)
-    kf = k.reshape(B * Hkv, Tp, Dp)
-    vf = v.reshape(B * Hkv, Tp, Dp)
+    qf, kf, vf = _flat_heads(q), _flat_heads(k), _flat_heads(v)
     kv_map = _flash_kv_map(H, Hkv)
 
-    out_shape = [out_struct((B * H, Tp, Dp), q.dtype, q, k, v)]
+    out_shape = [out_struct((B * H, Tp, Dvp), q.dtype, q, k, v)]
     out_specs = [
-        pl.BlockSpec((1, b, Dp), lambda bh, iq: (bh, iq, 0),
+        pl.BlockSpec((1, b, Dvp), lambda bh, iq: (bh, iq, 0),
                      memory_space=pltpu.VMEM),
     ]
     if with_lse:
@@ -589,33 +625,48 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse,
             pl.BlockSpec((1, 1, 1, b), lambda bh, iq: (bh, iq, 0, 0),
                          memory_space=pltpu.VMEM)
         )
+    operands = [qf, kf, vf]
+    in_specs = [
+        pl.BlockSpec((1, b, Dp), lambda bh, iq: (bh, iq, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, Tp, Dp), kv_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, Tp, Dvp), kv_map, memory_space=pltpu.VMEM),
+    ]
+    if rope:
+        # the second part of q a head, and of k as few heads as it has
+        # (one under MLA), each shared through the index map
+        q_rope, k_rope = _pad_rows_lanes(padT, q_rope, k_rope)
+        Drp = q_rope.shape[-1]
+        operands += [_flat_heads(q_rope), _flat_heads(k_rope)]
+        in_specs += [
+            pl.BlockSpec((1, b, Drp), lambda bh, iq: (bh, iq, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, Tp, Drp), _flash_kv_map(H, k_rope.shape[1]),
+                         memory_space=pltpu.VMEM),
+        ]
 
     res = pl.pallas_call(
         _flash_kernel(causal, scale, b, b, nkb, T, with_lse=with_lse,
-                      window=window),
+                      window=window, rope=rope),
         grid=(B * H, nq),
         out_shape=out_shape,
-        in_specs=[
-            pl.BlockSpec((1, b, Dp), lambda bh, iq: (bh, iq, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, Tp, Dp), kv_map, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, Tp, Dp), kv_map, memory_space=pltpu.VMEM),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         interpret=default_interpret(interpret),
         name="flash_fwd",
-    )(qf, kf, vf)
-    out = res[0].reshape(B, H, Tp, Dp)[:, :, :T, :D]
+    )(*operands)
+    out = res[0].reshape(B, H, Tp, Dvp)[:, :, :T, :Dv]
     if not with_lse:
         return out, None
     lse = res[1].reshape(B, H, Tp)[:, :, :T]  # (B*H, nq, 1, b) -> rows
     return out, lse
 
 
-def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None):
+def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None,
+                      rope=False):
     """The whole backward of one (k tile, q tile) pair, once: grid step
-    (bh, jk) owns one (bk, D) dk + dv block pair and folds the q blocks
-    that attended to it (causal: q blocks jk..nq-1, a dynamic lower
+    (bh, jk) owns one (bk, D) dk + (bk, Dv) dv block pair and folds the q
+    blocks that attended to it (causal: q blocks jk..nq-1, a dynamic lower
     bound, the mirror of the forward's early exit; under a ``window`` an
     upper bound too, :func:`_window_q_tiles`).  The scores, the
     probabilities (rebuilt from the saved logsumexp, p = exp(s - lse),
@@ -629,21 +680,34 @@ def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None):
     line and first, the interior loop with ``p = exp(s - lse)`` and no
     mask, the window-edge loop); both ``t_real`` compares are traced only
     when T was padded, into the body of the pairs that hold the last q or
-    k tile."""
+    k tile.
+
+    ``rope``: the scores have a second part (``flash_attention``'s
+    ``q_rope`` / ``k_rope``), so a pair has eight products: one more into
+    the score tile, and ``ds`` into that part's dk block and dq
+    accumulator, which follow the first part's as operands, outputs and
+    scratch."""
 
     padded = t_real < nq * bq
 
-    def kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref,
-               dq_ref, dk_ref, dv_ref, dq_acc):
+    def kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, *rest):
+        if rope:
+            kr_ref, qr_ref, *rest = rest
+            dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref, dq_acc, dqr_acc = rest
+        else:
+            dq_ref, dk_ref, dv_ref, dq_acc = rest
         jk = pl.program_id(1)
 
         @pl.when(jk == 0)
         def _():
             dq_acc[...] = jnp.zeros_like(dq_acc)
+            if rope:
+                dqr_acc[...] = jnp.zeros_like(dqr_acc)
 
         kb = k_ref[0]
         vb = v_ref[0]
-        D = kb.shape[-1]
+        if rope:
+            krb = kr_ref[0]
         # q_pos - k_pos of the pair (i, jk) is (i - jk) * bq + rc
         q_row = lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_col = lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -651,7 +715,7 @@ def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None):
 
         def fold(cls):
             def body(i, carry):
-                dk, dv = carry
+                dk, dv, *dkr = carry
                 rows = pl.ds(i * bq, bq)
                 qb = q_ref[0, rows, :]
                 dob = do_ref[0, rows, :]
@@ -663,7 +727,15 @@ def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None):
                     qb, kb, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                     precision=_mxu_precision(qb.dtype),
-                ) * scale
+                )
+                if rope:
+                    qrb = qr_ref[0, rows, :]
+                    s = s + lax.dot_general(
+                        qrb, krb, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                        precision=_mxu_precision(qrb.dtype),
+                    )
+                s = s * scale
                 p = jnp.exp(s - lse)
                 mask = _tile_mask(
                     cls, rc, i - jk, bq, causal, window,
@@ -695,28 +767,46 @@ def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None):
                     preferred_element_type=jnp.float32,
                     precision=_mxu_precision(kb.dtype),
                 )
-                return dk, dv
+                if not rope:
+                    return dk, dv
+                dkr = dkr[0] + lax.dot_general(
+                    ds, qrb, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=_mxu_precision(qrb.dtype),
+                )
+                dqr_acc[rows, :] += lax.dot_general(
+                    ds, krb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=_mxu_precision(krb.dtype),
+                )
+                return dk, dv, dkr
 
             return body
 
+        init = (jnp.zeros((bk, kb.shape[-1]), jnp.float32),
+                jnp.zeros((bk, vb.shape[-1]), jnp.float32))
+        if rope:
+            init += (jnp.zeros((bk, krb.shape[-1]), jnp.float32),)
         # bq == bk
-        dk, dv = _fold_tiles(
-            fold,
-            (jnp.zeros((bk, D), jnp.float32),
-             jnp.zeros((bk, D), jnp.float32)),
-            jk, nq, bq, causal, window, padded, forward=False,
+        dk, dv, *dkr = _fold_tiles(
+            fold, init, jk, nq, bq, causal, window, padded, forward=False,
         )
         dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
+        if rope:
+            dkr_ref[0] = dkr[0].astype(dkr_ref.dtype)
 
         @pl.when(jk == pl.num_programs(1) - 1)
         def _():
             dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+            if rope:
+                dqr_ref[0] = dqr_acc[...].astype(dqr_ref.dtype)
 
     return kernel
 
 
-def _flash_bwd_vmem_bytes(Tp: int, Dp: int, b: int, itemsize: int) -> int:
+def _flash_bwd_vmem_bytes(Tp: int, Dp: int, b: int, itemsize: int,
+                          Dvp: int | None = None, Drp: int = 0) -> int:
     """What one grid step of :func:`_flash_bwd_kernel` keeps in VMEM, from
     the shapes: q, dO and the dq output block whole (each double-buffered
     by the pipeline), the f32 dq accumulator, the k/v/dk/dv tiles, the two
@@ -726,97 +816,138 @@ def _flash_bwd_vmem_bytes(Tp: int, Dp: int, b: int, itemsize: int) -> int:
     T=8192, D=128, bf16, b=512: 4+4+4 MiB, 4 MiB, 1 MiB, 1 MiB, 6 MiB,
     1 MiB = 25 MiB, past the compiler's 16 MiB scoped default — so the
     call passes this sum (and a quarter of it as room for what Mosaic
-    spills) as its ``vmem_limit_bytes``."""
-    whole = Tp * Dp * itemsize
-    tiles = 4 * b * Dp * itemsize
+    spills) as its ``vmem_limit_bytes``.
+
+    Each operand at its own padded width: q, k and their gradients
+    ``Dp``, v, dO and dv ``Dvp`` (``Dp`` where not given), and with a
+    second score part q_rope, k_rope and their gradients ``Drp`` (T=4096,
+    192 | 128 as 128 + 64 -> 128: 23 MiB)."""
+    Dvp = Dp if Dvp is None else Dvp
+    whole = Tp * (2 * Dp + Dvp + 2 * Drp) * itemsize   # q, dq, dO, q_rope, dq_rope
+    tiles = 2 * b * (Dp + Dvp + Drp) * itemsize        # k, v, k_rope and theirs
     stats = 2 * (Tp // b) * 8 * b * 4
-    return 2 * (3 * whole + tiles + stats) + Tp * Dp * 4 + 7 * b * b * 4
+    return (
+        2 * (whole + tiles + stats) + Tp * (Dp + Drp) * 4 + 7 * b * b * 4
+    )
 
 
 def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
-                    window=None):
+                    window=None, scale=None, q_rope=None, k_rope=None):
     B, H, T, D = q.shape
-    Hkv = k.shape[1]
-    G = H // Hkv
-    scale = 1.0 / (D ** 0.5)
+    Hkv, Dv = k.shape[1], v.shape[-1]
+    rope = q_rope is not None
+    if scale is None:
+        scale = _default_scale(q, q_rope)
     b = _flash_block(T, q.dtype, block)
     padT = (-T) % b
-    padD = (-D) % LANES
     # delta = rowsum(dO * O): the softmax-transpose correction, a cheap
     # fused elementwise+reduce XLA does well — no kernel needed
     delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
-    if padT or padD:
-        padding = [(0, 0), (0, 0), (0, padT), (0, padD)]
-        q, k, v, g = (jnp.pad(a, padding) for a in (q, k, v, g))
+    q, k, v, g = _pad_rows_lanes(padT, q, k, v, g)
     if padT:
         rows = [(0, 0), (0, 0), (0, padT)]
         lse = jnp.pad(lse, rows, constant_values=_NEG)
         delta = jnp.pad(delta, rows)
-    Tp, Dp = T + padT, D + padD
+    Tp, Dp, Dvp = T + padT, q.shape[-1], v.shape[-1]
     nq = nkb = Tp // b
 
-    qf = q.reshape(B * H, Tp, Dp)
-    kf = k.reshape(B * Hkv, Tp, Dp)
-    vf = v.reshape(B * Hkv, Tp, Dp)
-    dof = g.reshape(B * H, Tp, Dp)
+    qf, kf, vf, dof = (_flat_heads(a) for a in (q, k, v, g))
     # row-stat layout (see _flash_fwd_impl): block last-two dims == array
     lsef = lse.reshape(B * H, nq, 1, b)
     dlf = delta.reshape(B * H, nq, 1, b)
-    kv_blk = pl.BlockSpec((1, b, Dp), _flash_kv_map(H, Hkv, blocked=True),
-                          memory_space=pltpu.VMEM)
-    blk = pl.BlockSpec((1, b, Dp), lambda bh, j: (bh, j, 0),
-                       memory_space=pltpu.VMEM)
-    whole = pl.BlockSpec((1, Tp, Dp), lambda bh, j: (bh, 0, 0),
-                         memory_space=pltpu.VMEM)
+    vmem = pltpu.VMEM
+
+    def tile(width, heads=H):
+        """A (b, width) tile of the ``heads`` that the q heads share."""
+        return pl.BlockSpec((1, b, width),
+                            _flash_kv_map(H, heads, blocked=True),
+                            memory_space=vmem)
+
+    def whole(width):
+        return pl.BlockSpec((1, Tp, width), lambda bh, j: (bh, 0, 0),
+                            memory_space=vmem)
+
     rows_whole = pl.BlockSpec((1, nq, 1, b), lambda bh, j: (bh, 0, 0, 0),
-                              memory_space=pltpu.VMEM)
+                              memory_space=vmem)
 
     # dk/dv come out PER Q-HEAD (a kv head's blocks are written once by
     # each q head of its group); the group sum is one cheap XLA reduction
     # after the kernel
-    grad_struct = out_struct((B * H, Tp, Dp), q.dtype, q, k, v, g)
-    resident = _flash_bwd_vmem_bytes(Tp, Dp, b, q.dtype.itemsize)
-    dq, dk, dv = pl.pallas_call(
-        _flash_bwd_kernel(causal, scale, b, b, nq, T, window),
+    def grad_struct(width):
+        return out_struct((B * H, Tp, width), q.dtype, q, k, v, g)
+
+    operands = [kf, vf, qf, dof, lsef, dlf]
+    in_specs = [tile(Dp, Hkv), tile(Dvp, Hkv), whole(Dp), whole(Dvp),
+                rows_whole, rows_whole]
+    out_shape = [grad_struct(Dp), grad_struct(Dp), grad_struct(Dvp)]
+    out_specs = [whole(Dp), tile(Dp), tile(Dvp)]
+    scratch = [pltpu.VMEM((Tp, Dp), jnp.float32)]
+    Drp = 0
+    if rope:
+        Dr, Hr = q_rope.shape[-1], k_rope.shape[1]
+        q_rope, k_rope = _pad_rows_lanes(padT, q_rope, k_rope)
+        Drp = q_rope.shape[-1]
+        operands += [_flat_heads(k_rope), _flat_heads(q_rope)]
+        in_specs += [tile(Drp, Hr), whole(Drp)]
+        out_shape += [grad_struct(Drp), grad_struct(Drp)]
+        out_specs += [whole(Drp), tile(Drp)]
+        scratch.append(pltpu.VMEM((Tp, Drp), jnp.float32))
+    resident = _flash_bwd_vmem_bytes(Tp, Dp, b, q.dtype.itemsize, Dvp, Drp)
+    dq, dk, dv, *rope_grads = pl.pallas_call(
+        _flash_bwd_kernel(causal, scale, b, b, nq, T, window, rope=rope),
         grid=(B * H, nkb),
-        out_shape=[grad_struct] * 3,
-        in_specs=[kv_blk, kv_blk, whole, whole, rows_whole, rows_whole],
-        out_specs=[whole, blk, blk],
-        scratch_shapes=[pltpu.VMEM((Tp, Dp), jnp.float32)],
+        out_shape=out_shape,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=resident + resident // 4,
         ),
         interpret=default_interpret(interpret),
         name="flash_bwd",
-    )(kf, vf, qf, dof, lsef, dlf)
+    )(*operands)
 
-    dq = dq.reshape(B, H, Tp, Dp)[:, :, :T, :D]
-    def group_sum(a):
-        a = a.reshape(B, Hkv, G, Tp, Dp)[:, :, :, :T, :D]
-        if G == 1:
+    def group_sum(a, heads, width):
+        """Per-q-head blocks summed onto the ``heads`` that share them,
+        at their real ``width``."""
+        a = a.reshape(B, heads, H // heads, Tp, a.shape[-1])[:, :, :, :T, :width]
+        if H == heads:
             return a[:, :, 0]
         return a.astype(jnp.float32).sum(2).astype(k.dtype)
-    return dq, group_sum(dk), group_sum(dv)
+
+    grads = (dq.reshape(B, H, Tp, Dp)[:, :, :T, :D],
+             group_sum(dk, Hkv, D), group_sum(dv, Hkv, Dv))
+    if not rope:
+        return grads + (None, None)
+    dqr, dkr = rope_grads
+    return grads + (
+        dqr.reshape(B, H, Tp, Drp)[:, :, :T, :Dr], group_sum(dkr, Hr, Dr),
+    )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_vjp(q, k, v, causal, block, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_vjp(q, k, v, q_rope, k_rope, causal, block, interpret,
+                    window, scale):
+    """``scale`` and the scores' second part may each be ``None``."""
     out, _ = _flash_fwd_impl(q, k, v, causal, block, interpret,
-                             with_lse=False, window=window)
+                             with_lse=False, window=window, scale=scale,
+                             q_rope=q_rope, k_rope=k_rope)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, block, interpret, window):
+def _flash_vjp_fwd(q, k, v, q_rope, k_rope, causal, block, interpret,
+                        window, scale):
     out, lse = _flash_fwd_impl(q, k, v, causal, block, interpret,
-                               with_lse=True, window=window)
-    return out, (q, k, v, out, lse)
+                               with_lse=True, window=window, scale=scale,
+                               q_rope=q_rope, k_rope=k_rope)
+    return out, (q, k, v, q_rope, k_rope, out, lse)
 
 
-def _flash_vjp_bwd(causal, block, interpret, window, res, g):
-    q, k, v, o, lse = res
+def _flash_vjp_bwd(causal, block, interpret, window, scale, res, g):
+    q, k, v, q_rope, k_rope, o, lse = res
     return _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
-                           window)
+                           window, scale, q_rope, k_rope)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -830,6 +961,9 @@ def flash_attention(
     *,
     block: int = 512,
     window: int | None = None,
+    scale: float | None = None,
+    q_rope: jax.Array | None = None,
+    k_rope: jax.Array | None = None,
     interpret: InterpretArg = None,
 ) -> jax.Array:
     """Local (single-chip) fused attention: ``(B, H, T, D) -> same`` with
@@ -869,13 +1003,24 @@ def flash_attention(
     class comes from the tiles' distance, the window and the padding,
     which the kernels see; nothing a caller sets.
 
+    TWO WIDTHS: v ``(B, Hkv, T, Dv)`` and the output ``(B, H, T, Dv)``
+    may be another width than q and k; every operand and gradient is
+    padded to the lanes on its own, so no product runs over columns that
+    are padding because another operand is wider.  ``scale`` multiplies
+    the scores (``(D + Dr) ** -0.5`` where not given).  ``q_rope``
+    ``(B, H, T, Dr)`` and ``k_rope`` ``(B, Hr, T, Dr)`` (``H % Hr == 0``)
+    are a SECOND PART of q and k: ``q_rope . k_rope`` is added into the
+    same f32 score tile (two products into one tile; three products a
+    pair forward, eight backward), and k's part may have fewer heads than
+    k itself, shared through the index map as a kv head is (DeepSeek-V2's
+    latent attention: 128 + 64 columns scored, 128 of values, ONE rope
+    key head for 128 query heads, never expanded in HBM).
+
     ``block=512`` is the measured optimum on v5e at T=4096: vs 256 the
     forward runs 2.1x faster (40.7 vs 19.6 TFLOPs) and the full T=4096
     train step gains 6.9 MFU points (62.1% -> 69.0%, A/B on the bench's
     own step); 1024 regresses (VMEM pressure).  Short sequences clamp
     the block to T via ``_flash_block``."""
-    if k.shape != v.shape:
-        raise ValueError(f"k/v shapes must match, got {k.shape}/{v.shape}")
     B, H, T, D = q.shape
     Bk, Hkv, Tk, Dk = k.shape
     if (Bk, Tk, Dk) != (B, T, D) or Hkv <= 0 or H % Hkv:
@@ -883,11 +1028,31 @@ def flash_attention(
             f"q/k shapes must match outside the head dim and q heads must "
             f"be a multiple of kv heads, got {q.shape}/{k.shape}"
         )
+    if k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"k/v shapes must match outside the width, got "
+            f"{k.shape}/{v.shape}"
+        )
     if k.dtype != q.dtype or v.dtype != q.dtype:
         raise ValueError(
             f"q/k/v dtypes must match (tiles and accumulators are typed "
             f"from q), got {q.dtype}/{k.dtype}/{v.dtype}"
         )
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("q_rope and k_rope come together")
+    if q_rope is not None:
+        Hr = k_rope.shape[1]
+        if (
+            q_rope.shape[:3] != (B, H, T) or Hr <= 0 or H % Hr
+            or k_rope.shape != (B, Hr, T, q_rope.shape[-1])
+            or q_rope.dtype != q.dtype or k_rope.dtype != q.dtype
+        ):
+            raise ValueError(
+                f"q_rope is q's second part (B, H, T, Dr) and k_rope k's, "
+                f"on heads that divide H, in q's dtype; got {q_rope.shape} "
+                f"{q_rope.dtype}/{k_rope.shape} {k_rope.dtype} for q "
+                f"{q.shape} {q.dtype}"
+            )
     require_mosaic_dtypes(default_interpret(interpret), "flash attention",
                           q.dtype)
     if window is not None:
@@ -897,4 +1062,7 @@ def flash_attention(
             )
         if window >= T:
             window = None  # every earlier key is inside it
-    return _flash_vjp(q, k, v, causal, block, interpret, window)
+    return _flash_vjp(
+        q, k, v, q_rope, k_rope, causal, block, interpret, window,
+        None if scale is None else float(scale),
+    )

@@ -50,7 +50,13 @@ from jax._src.config import _check_vma
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import InterpretArg, block_rows, default_interpret, out_struct
+from ._common import (
+    LANES,
+    InterpretArg,
+    block_rows,
+    default_interpret,
+    out_struct,
+)
 
 #: the three kernels, by the scope name each runs under
 FWD, DLHS, DRHS = "gmm_fwd", "gmm_dlhs", "gmm_drhs"
@@ -85,10 +91,24 @@ def tiles(form: str, m: int, k: int, n: int, dtype) -> tuple[int, int, int]:
     itemsize = jnp.dtype(dtype).itemsize
     tm = block_rows(m, ROWS)
     if form == DRHS:
-        return tm, min(k, 1024), min(n, 2048 // itemsize)
+        return tm, _dividing(k, 1024), _dividing(n, 2048 // itemsize)
     contract, cols = (n, k) if form == DLHS else (k, n)
-    tk = min(contract, 2048)
-    return tm, tk, min(cols, max(_WEIGHT_BLOCK_BYTES // itemsize // tk, 128))
+    tk = _dividing(contract, 2048)
+    fits = _WEIGHT_BLOCK_BYTES // itemsize // tk // LANES * LANES
+    return tm, tk, min(cols, max(fits, LANES))
+
+
+def _dividing(size: int, most: int) -> int:
+    """The tile of a dimension of ``size``: all of it up to ``most``, else
+    the largest multiple of the lanes under ``most`` that divides it (5120
+    in tiles of 1280, where 2048 would leave a last tile to mask and 1365
+    columns no tile Mosaic takes), else ``most``."""
+    if size <= most:
+        return size
+    return next(
+        (t for t in range(most // LANES * LANES, 0, -LANES) if size % t == 0),
+        most,
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -72,8 +72,15 @@ drops ``op_name``, so a reader joins the two by instruction name
 ======================== ==================================================
 ``accl.attn::core``      ``models/transformer.py`` ``_attn_partial``: the
                          attention call (flash kernels, or the XLA forms)
+``accl.attn::window``    the same under a ``LayerKind.window``: a sliding
+                         layer's attention call
+``accl.attn::latent``    ``_latent_attn_partial`` (a latent mixer, MLA): the
+                         five projections, the two latent norms, the rope
+``accl.attn::mla``       the same: the score/softmax/value core (the flash
+                         kernels with two widths and ONE rope key head)
 ``accl.moe::route``      ``models/moe.py``, dropless path: router matmul,
-                         float32 softmax, top-k
+                         float32 softmax, (group-limited) top-k, the
+                         balance losses
 ``accl.moe::dispatch``   the same: sort of the routing entries by expert,
                          group sizes, gather of the rows
 ``accl.moe::experts``    the same: the three grouped matmuls and the gate

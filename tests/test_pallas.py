@@ -1545,3 +1545,114 @@ def test_flash_attention_gqa_validates():
             jnp.zeros((1, 4, 16, 8)), jnp.zeros((1, 3, 16, 8)),
             jnp.zeros((1, 3, 16, 8)),
         )
+
+
+# ---------------------------------------------------------------------------
+# heads of two widths, a second score part on shared key heads, a caller's
+# scale (the latent mixer's core)
+# ---------------------------------------------------------------------------
+
+#: name -> (H, Hkv, rope key heads, D, Dr, Dv, T): T = 100 is three tiles of
+#: 32 and four rows, so the padded class is traced too
+_FLASH_WIDE_CASES = {
+    "mla_128_64_v128": (4, 4, 1, 128, 64, 128, 100),
+    "small_32_16_v32": (4, 4, 1, 32, 16, 32, 100),
+    "gqa_two_rope_heads": (4, 2, 2, 32, 16, 24, 100),
+    "v_narrower_no_rope": (4, 2, 0, 48, 0, 32, 100),
+    "v_wider_no_rope": (2, 2, 0, 24, 0, 40, 96),
+}
+_WIDE_SCALE = 0.11472
+
+
+def _wide_naive(q, k, v, q_rope, k_rope, scale, window):
+    H, T = q.shape[1], q.shape[2]
+    rep = lambda a: jnp.repeat(a, H // a.shape[1], axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, rep(k))
+    if q_rope is not None:
+        s = s + jnp.einsum("bhqd,bhkd->bhqk", q_rope, rep(k_rope))
+    dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    mask = dist >= 0
+    if window is not None:
+        mask &= dist < window
+    s = jnp.where(mask, s * scale, -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), rep(v))
+
+
+@pytest.mark.parametrize("window", [None, 40])
+@pytest.mark.parametrize("case", list(_FLASH_WIDE_CASES))
+def test_flash_attention_two_widths_and_a_shared_rope_key(case, window):
+    """q and k scored over D + Dr columns (the Dr part of k on fewer heads,
+    shared through the index map), v and the output Dv wide, the caller's
+    scale, with and without a window: forward, dq, dk, dv and the two rope
+    parts' gradients against the naive form."""
+    H, Hkv, Hr, D, Dr, Dv, T = _FLASH_WIDE_CASES[case]
+    rng = np.random.default_rng(34)
+    n = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    args = [n(1, H, T, D), n(1, Hkv, T, D), n(1, Hkv, T, Dv)]
+    if Dr:
+        args += [n(1, H, T, Dr), n(1, Hr, T, Dr)]
+    w = n(1, H, T, Dv)
+
+    def run(fn):
+        def loss(*a):
+            out = fn(*a, *([None, None] if not Dr else []))
+            return (out * w).sum(), out
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(args))), has_aux=True))(*args)
+
+    (_, got), grads = run(lambda q, k, v, qr, kr: pk.flash_attention(
+        q, k, v, block=32, window=window, scale=_WIDE_SCALE,
+        q_rope=qr, k_rope=kr))
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = run(lambda q, k, v, qr, kr: _wide_naive(
+            q, k, v, qr, kr, _WIDE_SCALE, window))
+    assert got.shape == (1, H, T, Dv)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    names = ("q", "k", "v", "q_rope", "k_rope")
+    for a, b, name in zip(grads, want_grads, names):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=_GRAD_ATOL,
+            err_msg=f"d{name}")
+
+
+def test_flash_attention_default_scale_is_over_both_score_parts():
+    rng = np.random.default_rng(35)
+    n = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    q, k, v = n(1, 2, 64, 32), n(1, 2, 64, 32), n(1, 2, 64, 24)
+    qr, kr = n(1, 2, 64, 16), n(1, 1, 64, 16)
+    got = pk.flash_attention(q, k, v, block=32, q_rope=qr, k_rope=kr)
+    with jax.default_matmul_precision("highest"):
+        want = _wide_naive(q, k, v, qr, kr, 48 ** -0.5, None)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    # and a caller's scale equal to the default is the plain call, bit for bit
+    same = pk.flash_attention(q, k, q, block=32, scale=1.0 / (32 ** 0.5))
+    np.testing.assert_array_equal(
+        np.asarray(same), np.asarray(pk.flash_attention(q, k, q, block=32)))
+
+
+def test_flash_attention_rope_parts_validate_and_other_lowerings_agree():
+    rng = np.random.default_rng(36)
+    n = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    q, k, v = n(1, 4, 64, 32), n(1, 4, 64, 32), n(1, 4, 64, 24)
+    qr, kr = n(1, 4, 64, 16), n(1, 1, 64, 16)
+    with pytest.raises(ValueError, match="come together"):
+        pk.flash_attention(q, k, v, q_rope=qr)
+    with pytest.raises(ValueError, match="second part"):
+        pk.flash_attention(q, k, v, q_rope=qr, k_rope=n(1, 3, 64, 16))
+    with pytest.raises(ValueError, match="second part"):
+        pk.flash_attention(q, k, v, q_rope=qr, k_rope=n(1, 1, 64, 8))
+    with pytest.raises(ValueError, match="outside the width"):
+        pk.flash_attention(q, k, n(1, 2, 64, 24))
+    from accl_tpu.models.transformer import _attention
+
+    with jax.default_matmul_precision("highest"):
+        want = _wide_naive(q, k, v, qr, kr, _WIDE_SCALE, 24)
+        for impl in ("naive", "blockwise", "flash"):
+            got = _attention(q, k, v, impl=impl, window=24, scale=_WIDE_SCALE,
+                             q_rope=qr, k_rope=kr)
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5,
+                err_msg=impl)

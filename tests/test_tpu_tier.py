@@ -335,3 +335,56 @@ def test_flash_attention_window_at_the_kernels_tile_size(window):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert pk.flash_tile_pairs(T, 512, W) == {200: 5, 1024: 6, 700: 6,
                                                4096: 6}[W]
+
+
+# ---------------------------------------------------------------------------
+# the latent mixer's core at the kernels' tile size (Mosaic-compiled here)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("window", [None, 700])
+def test_flash_attention_mla_widths_at_the_kernels_tile_size(window):
+    """``flash_attention`` as DeepSeek-V2's core calls it: q and k heads of
+    128 + 64 columns, the 64 rope columns of k ONE head, v and the output
+    128 wide, the caller's scale, tiles of 512 over a ragged T: forward and
+    all five gradients against the naive form."""
+    import jax.numpy as jnp
+
+    from accl_tpu.compat import has_interpret_params
+    from accl_tpu.ops import pallas as pk
+
+    if jax.default_backend() != "tpu" and not has_interpret_params():
+        pytest.skip("no Pallas TPU interpreter here")
+    B, H, T, D, Dr, Dv, scale = 1, 4, 1100, 128, 64, 128, 0.11472
+    r = np.random.default_rng(43)
+    n = lambda *s: jnp.asarray(r.standard_normal(s), jnp.float32)
+    args = (n(B, H, T, D), n(B, H, T, D), n(B, H, T, Dv), n(B, H, T, Dr),
+            n(B, 1, T, Dr))
+    w = n(B, H, T, Dv)
+
+    def naive(q, k, v, qr, kr):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+        s = s + jnp.einsum("bhqd,bkd->bhqk", qr, kr[:, 0])
+        dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+        mask = dist >= 0 if window is None else (dist >= 0) & (dist < window)
+        s = jnp.where(mask, s * scale, -1e30)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+    def run(fn):
+        def loss(*a):
+            out = fn(*a)
+            return (out * w).sum(), out
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+    (_, got), grads = run(lambda q, k, v, qr, kr: pk.flash_attention(
+        q, k, v, window=window, scale=scale, q_rope=qr, k_rope=kr))
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = run(naive)
+    atol = 5e-4 if jax.default_backend() == "tpu" else 2e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=atol)
+    for a, b in zip(grads, want_grads):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=20 * atol)
