@@ -49,8 +49,13 @@ picked by what the layer's parameters hold or by the caller's word:
   exchange.  The held entries' rows have a static buffer
   (``held_row_factor`` times the balanced share, rounded up to the row
   tile); an entry past it is dropped and counted, as a receive buffer of
-  the exchange would.  The layer counts tokens an expert over ALL the
-  router's experts, the entries held here, and those dropped.
+  the exchange would.  A buffer row's result is placed on its token,
+  weighted, and so is its cotangent on the way back
+  (:func:`_place_rows`): by k gathers a token through the inverse of the
+  sort, as the dropless path combines, or where the shapes make that the
+  dearer (:func:`_gathers_win`) by one scatter-add of the rows.  The
+  layer counts tokens an expert over ALL the router's experts, the
+  entries held here, and those dropped.
 
 * GROUP-LIMITED top-k (``n_group`` > 1, the softmax router; DeepSeek-V2's
   ``group_limited_greedy``): the router's experts are ``n_group`` groups
@@ -180,53 +185,94 @@ def _expert_act(up, params, matmul):
     return jax.nn.gelu(up)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _gather_rows(x, idx, n: int):
-    """``x[idx]`` of an ``(n, D)`` ``x``, rows repeated or left out as
-    ``idx`` says; the cotangent is summed into its ``n`` rows in
-    float32."""
-    return x[idx]
+def _gathers_win(rows: int, entries: int, D: int, itemsize: int) -> bool:
+    """Whether :func:`_place_rows` is cheaper as ``entries`` gathered rows
+    than as one scatter-add of ``rows`` rows of ``D`` columns, by what
+    XLA's two ops were measured to move on a v5e (``PERF.md`` section 5,
+    PR 35): a gather reads its rows at about 300 GB/s while the array it
+    reads from is small enough for the compiler to keep in the chip's
+    near memory (96 MiB was, 128 MiB was not) and at about 100 GB/s from
+    HBM; a scatter-add takes a float32 row at 80 GB/s at best (up to
+    4,096 columns; past them it was 1.4 to 15 times slower).  The choice
+    is the compiler's weakness at a shape, so it reads shapes and nothing
+    else."""
+    gather_gbs = 300 if rows * D * itemsize <= 96 << 20 else 100
+    return entries * D * itemsize / gather_gbs < rows * D * 4 / 80
 
 
-def _gather_rows_fwd(x, idx, n):
-    return x[idx], idx
+def _place_rows(rows, order, slot, p=None):
+    """Each buffer row on its token: ``y[t] = sum_j p[t, j] * rows[slot[t,
+    j]]`` over the ``(N, k)`` slots, a slot past the last row reading
+    zeros and no ``p`` a weight of 1; products and sums in float32, the
+    result in ``rows``' type.  ``order`` is the entries in buffer order,
+    the inverse of ``slot``.  One of two lowerings, by
+    :func:`_gathers_win`: k gathers of a row a token through ``slot``, or
+    one scatter-add of the rows through ``order`` (a row no entry owns
+    must then be zero, as the caller's masks leave it).  They differ in
+    the order of a token's at most k float32 additions."""
+    N, k = slot.shape
+    R, D = rows.shape
+    if _gathers_win(R, N * k, D, rows.dtype.itemsize):
+        acc = 0.0
+        for j in range(k):
+            got = rows.at[slot[:, j]].get(mode="fill", fill_value=0)
+            got = got.astype(jnp.float32)
+            acc = acc + (got if p is None else got * p[:, j, None])
+    else:
+        vals = rows.astype(jnp.float32)
+        if p is not None:
+            vals = vals * p.reshape(-1)[order][:, None]
+        acc = jnp.zeros((N, D), jnp.float32).at[order // k].add(vals)
+    return acc.astype(rows.dtype)
 
 
-def _gather_rows_bwd(n, idx, g):
-    acc = jnp.zeros((n, g.shape[-1]), jnp.float32).at[idx].add(
-        g.astype(jnp.float32)
+@jax.custom_vjp
+def _held_rows(x, order, slot):
+    """``x[order // k]``, the buffer's rows; the cotangent is
+    :func:`_place_rows` of the rows' cotangents."""
+    return x[order // slot.shape[-1]]
+
+
+def _held_rows_fwd(x, order, slot):
+    return _held_rows(x, order, slot), (order, slot)
+
+
+def _held_rows_bwd(res, g):
+    return _place_rows(g, *res), None, None
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_held(out, p, order, slot):
+    """:func:`_place_rows` of the experts' results, weighted by the
+    chosen probabilities ``p`` ``(N, k)``.  The cotangents are gathers
+    of the result's by ``order``, as the rows were gathered: ``out``'s in
+    its type, ``p``'s a buffer row's dot set down at its entry."""
+    return _place_rows(out, order, slot, p)
+
+
+def _combine_held_fwd(out, p, order, slot):
+    return _combine_held(out, p, order, slot), (out, p, order)
+
+
+def _combine_held_bwd(res, g):
+    out, p, order = res
+    g = g[order // p.shape[-1]].astype(jnp.float32)
+    dots = jnp.sum(g * out.astype(jnp.float32), axis=-1)
+    # the rows' scalars scattered, each entry once: N*k scalars gathered
+    # through the slots cost XLA 1.1 ms a layer in the Trinity cell
+    d_p = jnp.zeros((p.size,), dots.dtype).at[order].add(
+        dots, unique_indices=True
     )
-    return acc.astype(g.dtype), None
-
-
-_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine_rows(out, w, idx, n: int):
-    """``y[idx[r]] += w[r] * out[r]`` into ``n`` rows: products and sums
-    in float32, the result in ``out``'s type.  Its cotangents are a
-    gather of the result's, in that type."""
-    acc = jnp.zeros((n, out.shape[-1]), jnp.float32).at[idx].add(
-        out.astype(jnp.float32) * w[:, None]
-    )
-    return acc.astype(out.dtype)
-
-
-def _combine_rows_fwd(out, w, idx, n):
-    return _combine_rows(out, w, idx, n), (out, w, idx)
-
-
-def _combine_rows_bwd(n, res, g):
-    out, w, idx = res
-    g = g[idx].astype(jnp.float32)
     return (
-        (g * w[:, None]).astype(out.dtype),
-        jnp.sum(g * out.astype(jnp.float32), axis=-1), None,
+        (g * p.reshape(-1)[order][:, None]).astype(out.dtype),
+        d_p.reshape(p.shape), None, None,
     )
 
 
-_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
 def _expert_bank(rows, params, sizes):
@@ -256,8 +302,11 @@ def _held_experts(flat, params, topk_e, topk_p, tp_axis, first: int,
     bank is experts ``first .. first + E`` of the router's.  Entries are
     sorted with the held ones in front, by expert; the first ``rows`` of
     the order are gathered and run through the grouped matmuls with the
-    held experts' group sizes (clipped to the buffer), and scattered
-    back weighted.  The kernels visit only the tiles the groups reach,
+    held experts' group sizes (clipped to the buffer), and each row's
+    result is placed on its token, weighted (:func:`_place_rows`).  An
+    entry's place in the order says whether it has a row: it does iff
+    the place is under ``kept``, so ``slot`` is the place or, past the
+    buffer, no row.  The kernels visit only the tiles the groups reach,
     so what lies past them in the buffer is never written: it is masked
     on the way in (for the cotangent) and on the way out.  Returns ``(y,
     counters)``: ``expert_tokens`` over the router's experts,
@@ -271,23 +320,25 @@ def _held_experts(flat, params, topk_e, topk_p, tp_axis, first: int,
         counts = jnp.zeros((n_router,), jnp.int32).at[expert].add(1)
         local = expert - first
         key = jnp.where((local >= 0) & (local < E), local, E)
-        order = jnp.argsort(key, stable=True)[:rows]      # held first
+        every = jnp.argsort(key, stable=True)             # held first
+        back = jnp.argsort(every)                         # entry -> place
+        order = every[:rows]
         ends = jnp.minimum(jnp.cumsum(counts[first:first + E]), rows)
         sizes = jnp.diff(ends, prepend=0)
         held, kept = jnp.sum(counts[first:first + E]), ends[-1]
         valid = jnp.arange(rows) < kept
-        token = order // k
-        x = jnp.where(valid[:, None], _gather_rows(flat, token, N), 0)
+        slot = jnp.where(back < kept, back, rows).reshape(N, k)
+        x = jnp.where(valid[:, None], _held_rows(flat, order, slot), 0)
     out = _expert_bank(x, params, sizes)                  # (rows, D)
     with device_scope("accl.moe::combine"):
-        w = jnp.where(valid, topk_p.reshape(-1)[order], 0.0)
         out = jnp.where(valid[:, None], out, 0)
         # inside a shard_map: the weights varying over the axes the rows
         # vary over (tp-sharded experts), so that their cotangent is
         # summed over those by the cast's transpose
-        if missing := tuple(jax.typeof(out).vma - jax.typeof(w).vma):
-            w = lax.pcast(w, missing, to="varying")
-        y = _combine_rows(out, w, token, N)
+        p = topk_p
+        if missing := tuple(jax.typeof(out).vma - jax.typeof(p).vma):
+            p = lax.pcast(p, missing, to="varying")
+        y = _combine_held(out, p, order, slot)
         if tp_axis is not None:
             y = lax.psum(y, tp_axis)
     return y, {
