@@ -513,12 +513,24 @@ class GangCommandRing:
         disqualifies (the ordinary fused/sequential paths then own the
         batch); True once dispatch begins (request completion is owned
         by the ring's window parks)."""
-        if not self.enabled:
+        if not self.enabled or npos == 0:
             return False
+        with annotate("accl.ring::batch", comm=comm.id, n=npos):
+            if t0 is None:
+                t0 = time.perf_counter_ns()
+            mesh = self.gang.submesh(comm)
+            with annotate("accl.ring::plan"):
+                plans = self._plan_batch(comm, entries, npos, mesh)
+            if not plans:
+                return False
+            self._dispatch_windows(comm, mesh, entries, plans, t0)
+            return True
+
+    def _plan_batch(self, comm, entries, npos: int, mesh):
+        """The breaker and tuning gates, then a plan a position: the
+        planned ``(calls, lead, plan)`` list, or False — a fallback
+        counted, nothing dispatched — when any position disqualifies."""
         gang = self.gang
-        mesh = gang.submesh(comm)
-        if npos == 0:
-            return False
         # ring circuit breaker (membership plane): an OPEN comm rides
         # host dispatch until the cool-down; HALF_OPEN lets one window
         # through, and its success restores the ring
@@ -538,8 +550,6 @@ class GangCommandRing:
                     c.tuning.get(k, "xla") != "xla" for k in keys
                 ):
                     return self._fallback("tuning_override")
-        if t0 is None:
-            t0 = time.perf_counter_ns()
 
         plans = []
         written: set = set()  # result roots of earlier positions
@@ -613,7 +623,10 @@ class GangCommandRing:
             calls, lead, _ = plans[i]
             plans[i] = (calls, lead,
                         self._plan_barrier(comm, mesh, window_npdt))
+        return plans
 
+    def _dispatch_windows(self, comm, mesh, entries, plans, t0) -> None:
+        npos = len(plans)
         # windows of at most `depth` slots — clamped to the comm's QoS
         # slot budget when one is configured (the flooder pays extra
         # doorbells; unbudgeted tenants keep full windows): each window
@@ -642,7 +655,7 @@ class GangCommandRing:
                 import traceback
 
                 traceback.print_exc()
-                brk.record_failure("dispatch_error")
+                self.breaker_for(comm.id).record_failure("dispatch_error")
                 # postmortem plane: a failed window DISPATCH is a ring
                 # failure too (on_error covers in-flight failures)
                 if self.on_failure is not None:
@@ -658,7 +671,6 @@ class GangCommandRing:
                             req.ring_resident = True
                             req.complete(ErrorCode.INVALID_OPERATION, dt)
                 break
-        return True
 
     # -- slot encoding -------------------------------------------------------
     def _encode(self, session: _RingSession, lead, plan) -> np.ndarray:
@@ -810,13 +822,44 @@ class GangCommandRing:
     def _dispatch_window(self, comm, mesh, window, reqs_per_slot,
                          t0) -> None:
         gang = self.gang
-        n = len(window)
-        shape = self._window_shape(comm, window)
         with self._lock:
             session = self._sessions.get(comm.id)
             if session is None:
                 session = self._sessions[comm.id] = _RingSession(self.depth)
-        self._wait_written_dependencies(session, window)
+            # the span's label only: a comm's windows dispatch one at a
+            # time, so the id taken under the lock below is this one
+            window_id = session.next_window
+        with annotate("accl.ring::deps"):
+            self._wait_written_dependencies(session, window)
+        with annotate("accl.ring::encode", window=window_id):
+            shape = self._window_shape(comm, window)
+            park, slots_np = self._encode_window(
+                comm, session, window, reqs_per_slot, t0
+            )
+
+        try:
+            gang.interactions.bump()  # THE refill: one host interaction
+            # for the whole window
+            st = self._launch_window(
+                comm, mesh, shape, park, slots_np, window
+            )
+            with annotate("accl.ring::park", window=park.window_id):
+                self._park_window(comm, session, park, st, t0)
+        except BaseException:
+            # the window never parked: the armed count must not leak
+            # (the parked/no-spin posture is part of the contract)
+            with self._lock:
+                self._inflight_windows = max(0, self._inflight_windows - 1)
+                if park in session.parks:
+                    session.parks.remove(park)
+            raise
+
+    def _encode_window(self, comm, session, window, reqs_per_slot, t0):
+        """Under the lock: the window's slot rows into the session's
+        ring, the counters, its ``_WindowPark`` with the per-slot
+        introspection, the written-root ledger; then the chaos hook.
+        Returns ``(park, slot rows as one array)``."""
+        n = len(window)
         with self._lock:
             start = session.head
             slot_rows = [
@@ -870,23 +913,7 @@ class GangCommandRing:
                             session.written.get(rid, 0) + 1
                         )
             self._inflight_windows += 1
-        slots_np = self._chaos_hook(comm, window, np.stack(slot_rows))
-
-        try:
-            gang.interactions.bump()  # THE refill: one host interaction
-            # for the whole window
-            st = self._launch_window(
-                comm, mesh, shape, park, slots_np, window
-            )
-            self._park_window(comm, session, park, st, t0)
-        except BaseException:
-            # the window never parked: the armed count must not leak
-            # (the parked/no-spin posture is part of the contract)
-            with self._lock:
-                self._inflight_windows = max(0, self._inflight_windows - 1)
-                if park in session.parks:
-                    session.parks.remove(park)
-            raise
+        return park, self._chaos_hook(comm, window, np.stack(slot_rows))
 
     def _settle_window(self, session, park) -> None:
         """Session bookkeeping at window completion, exactly once per
@@ -1035,20 +1062,22 @@ class GangCommandRing:
         from ...ops import cmdring as devring
 
         gang = self.gang
-        globals_ = [
-            self._assemble_ring_global(calls, plan, mesh)
-            for calls, lead, plan in window
-        ]
+        with annotate("accl.ring::assemble"):
+            globals_ = [
+                self._assemble_ring_global(calls, plan, mesh)
+                for calls, lead, plan in window
+            ]
         with annotate(f"accl::cmdring[{len(window)}]"):
             st, results = devring.run_windows(
                 [(slots_np, globals_)], mesh, shape
             )
         with self._lock:
             self.dispatches += 1
-        for k, (calls, lead, plan) in enumerate(window):
-            gang._adopt_out_shards(
-                results[0][k], calls, plan, park.reqs_per_slot[k]
-            )
+        with annotate("accl.ring::adopt"):
+            for k, (calls, lead, plan) in enumerate(window):
+                gang._adopt_out_shards(
+                    results[0][k], calls, plan, park.reqs_per_slot[k]
+                )
         return st
 
     def _zeros_shard(self, w: int, npdt, dev):

@@ -1964,13 +1964,20 @@ class ACCL:
         may legitimately outlive it), use ``Request.wait`` per call.
         Still safe inside a batch — the
         batch stays open for further calls; :meth:`end_batch` closes it."""
-        self._dispatch_pending()
-        # overlap drain point: launched-but-incomplete device calls
-        # finish before flush() returns (no-op on windowless tiers).
-        # A failed (timed-out) drain must SURFACE — callers trust the
-        # documented contract and read result buffers next
-        if not self.engine.drain_inflight():
-            raise self._deadlock_error("flush")
+        # the batch counter is equal on every rank (batches are
+        # collective by contract): the rank threads' spans of one
+        # window share it
+        batch = self._batch_ctr
+        with annotate("accl.batch::flush", comm=self._world.id, batch=batch):
+            self._dispatch_pending()
+            # overlap drain point: launched-but-incomplete device calls
+            # finish before flush() returns (no-op on windowless tiers).
+            # A failed (timed-out) drain must SURFACE — callers trust the
+            # documented contract and read result buffers next
+            with annotate("accl.batch::drain", batch=batch):
+                drained = self.engine.drain_inflight()
+            if not drained:
+                raise self._deadlock_error("flush")
 
     def _dispatch_pending(self) -> None:
         """Dispatch the open batch WITHOUT draining the in-flight
@@ -1988,7 +1995,9 @@ class ACCL:
                 # point
                 for _, req in items:
                     req._pre_wait = None
-                self.engine.start_batch(items)
+                with annotate("accl.batch::submit", batch=self._batch_ctr,
+                              n=len(items)):
+                    self.engine.start_batch(items)
 
     def end_batch(self) -> None:
         """Close the (outermost) batch: flush queued work and return to

@@ -50,7 +50,37 @@ microsecond while no trace is being taken.
 ``accl.gang::park``      ``engine.py``: ``_park_inflight``
 ``accl::batch[n]``       ``engine.py`` ``_dispatch_batch_fused``: a fused
                          batch of n collectives
-``accl::cmdring[n]``     ``backends/xla/cmdring.py``: a ring refill window
+``accl.batch::flush``    ``core.py`` ``flush``: the whole of it, on each
+                         rank's thread (``comm``; ``batch``: the handle's
+                         batch counter, equal on every rank, so the rank
+                         threads' spans of one window share it)
+``accl.batch::submit``   ``core.py`` ``_dispatch_pending``:
+                         ``engine.start_batch``, only where calls were
+                         queued (``batch``, ``n`` calls); holds
+                         ``accl.ring::batch`` on the rank that completes
+                         the gang slot
+``accl.batch::drain``    ``core.py`` ``flush``: ``engine.drain_inflight``
+                         (``batch``); it waits only for what is LAUNCHED,
+                         so on the ranks that flush before the last it
+                         returns at once
+``accl.ring::batch``     ``backends/xla/cmdring.py`` ``run_batch``: a
+                         matched batch tried ring-resident, entry to
+                         return (``comm``, ``n`` positions); ONE span a
+                         stage a window below, never one a slot
+``accl.ring::plan``      ``_plan_batch``: the breaker and tuning gates and
+                         a plan a position
+``accl.ring::deps``      ``_dispatch_window``:
+                         ``_wait_written_dependencies``
+``accl.ring::encode``    ``_dispatch_window``: the window's shape, then
+                         under the ring's lock the slot rows, counters,
+                         ``_WindowPark``, per-slot introspection and the
+                         written-root ledger, then the chaos hook
+                         (``window``: its id)
+``accl.ring::assemble``  ``_launch_window``: the operand globals
+``accl::cmdring[n]``     ``_launch_window``: the window's ONE program call
+``accl.ring::adopt``     ``_launch_window``: ``_adopt_out_shards`` a slot
+``accl.ring::park``      ``_dispatch_window``: ``_park_window``
+                         (``window``)
 ``accl.window::ready``   ``overlap.py`` ``InflightWindow._complete``: the
                          drainer's ``block_until_ready``
 ``accl.window::complete`` ``overlap.py``: requests completed, telemetry
@@ -60,7 +90,11 @@ microsecond while no trace is being taken.
 Only the ``accl::`` names are read by the benchmark's ``engine_span_us``,
 ``facade_self_us`` and ``breakdown``; the stage spans are ``accl.<layer>::``
 so that those keep reading what they read (``perfbench/stage_spans.py``
-reads the stages).
+reads the blocking call's stages, ``perfbench/window_spans.py`` the
+batched window's).  A blocking call outside a batch carries no
+``accl.batch::`` or ``accl.ring::`` span.  The telemetry plane's window
+log (``cmdring.py`` ``_log_window``, basis ``"host"``) is on the
+``perf_counter`` clock and is NOT what the benchmark reads.
 
 Device scopes (:func:`device_scope`), inside the jitted train step and
 forward.  The name lands in every covered instruction's ``op_name``

@@ -3,6 +3,8 @@ mpirun-launched per-rank host processes."""
 
 from __future__ import annotations
 
+import glob
+import os
 import threading
 from typing import Callable, List, Sequence
 
@@ -57,3 +59,39 @@ def launch_with_port_retry(fn, world, attempts=3, retry_if=None, **kwargs):
                 raise
             last = e
     raise last
+
+
+# -- a recorded profiler trace (.xplane.pb), read back ------------------------
+
+
+def trace_spans(logdir):
+    """thread -> [(name, start_ns, end_ns, stats)] by start, of the
+    program's spans in the newest trace under ``logdir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            events = [
+                (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                 {str(k): str(v) for k, v in e.stats})
+                for e in line.events if e.name.startswith("accl")
+            ]
+            if events:
+                out[i] = sorted(events, key=lambda e: e[1])
+    return out
+
+
+def trace_inside(events, outer):
+    return [e for e in events
+            if e is not outer and outer[1] <= e[1] and e[2] <= outer[2]]
+
+
+def trace_in_order(events):
+    """Each span ends before the next starts."""
+    return all(a[2] <= b[1] for a, b in zip(events, events[1:]))

@@ -8,7 +8,6 @@ TraceAnnotations on the CPU backend too; times are the CPU's and are
 not looked at.)
 """
 
-import glob
 import os
 import subprocess
 import sys
@@ -17,7 +16,8 @@ import threading
 import numpy as np
 import pytest
 
-from helpers import run_parallel
+from helpers import (run_parallel, trace_in_order, trace_inside,
+                     trace_spans)
 
 WORLD = 4
 N = 64
@@ -42,39 +42,6 @@ def group():
     yield g
     for a in g:
         a.deinit()
-
-
-def _spans(logdir):
-    """thread -> [(name, start_ns, end_ns, stats)] by start, of the
-    program's spans in the newest trace under ``logdir``."""
-    from jax.profiler import ProfileData
-
-    (path,) = glob.glob(
-        os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb")
-    )
-    out = {}
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for i, line in enumerate(plane.lines):
-            events = [
-                (e.name, e.start_ns, e.start_ns + e.duration_ns,
-                 {str(k): str(v) for k, v in e.stats})
-                for e in line.events if e.name.startswith("accl")
-            ]
-            if events:
-                out[i] = sorted(events, key=lambda e: e[1])
-    return out
-
-
-def _inside(events, outer):
-    return [e for e in events
-            if e is not outer and outer[1] <= e[1] and e[2] <= outer[2]]
-
-
-def _in_order(events):
-    """Each span ends before the next starts."""
-    return all(a[2] <= b[1] for a, b in zip(events, events[1:]))
 
 
 @pytest.mark.parametrize("op", sorted(SHAPES))
@@ -104,33 +71,33 @@ def test_stage_spans_of_a_blocking_gang_call(group, op, tmp_path):
         run_parallel(group, calls)
     assert counter.read() - before == CALLS  # and 1 with it on
 
-    by_thread = _spans(str(tmp_path))
+    by_thread = trace_spans(str(tmp_path))
     rank_threads = {t: ev for t, ev in by_thread.items()
                     if any(e[0] == "accl.facade::call" for e in ev)}
     assert len(rank_threads) == WORLD
     engine_spans = []
     for events in rank_threads.values():
         outer = [e for e in events if e[0] == "accl.facade::call"]
-        assert len(outer) == CALLS and _in_order(outer)
+        assert len(outer) == CALLS and trace_in_order(outer)
         for call in outer:
-            stages = [e for e in _inside(events, call)
+            stages = [e for e in trace_inside(events, call)
                       if e[0].startswith("accl.facade::")]
             assert [e[0] for e in stages] == [
                 "accl.facade::" + s for s in FACADE
             ]
             prepare, plan = stages[:2]  # the plan lookup lies in prepare
             assert prepare[1] <= plan[1] and plan[2] <= prepare[2]
-            assert _in_order(stages[1:]) and prepare[2] <= stages[2][1]
+            assert trace_in_order(stages[1:]) and prepare[2] <= stages[2][1]
             submit = stages[FACADE.index("submit")]
-            ran = [e for e in _inside(events, submit)
+            ran = [e for e in trace_inside(events, submit)
                    if e[0].startswith("accl::")]
             for engine in ran:
                 assert engine[0] == "accl::" + op
-                gang = [e for e in _inside(events, engine)]
+                gang = [e for e in trace_inside(events, engine)]
                 assert [e[0] for e in gang] == [
                     "accl.gang::" + s for s in GANG
                 ]
-                assert _in_order(gang)
+                assert trace_in_order(gang)
                 assert all(e[3] == {"comm": "0"} for e in gang)
             engine_spans += ran
         # nothing of the engine or the window outside a submit
@@ -138,7 +105,7 @@ def test_stage_spans_of_a_blocking_gang_call(group, op, tmp_path):
                    for e in events) == (1 + len(GANG)) * sum(
             e[0].startswith("accl::") for e in events)
     # exactly one thread a gang call ran the program
-    assert len(engine_spans) == CALLS and _in_order(
+    assert len(engine_spans) == CALLS and trace_in_order(
         sorted(engine_spans, key=lambda e: e[1])
     )
     # and a thread that is no rank's completed it: ready, then complete
@@ -146,7 +113,7 @@ def test_stage_spans_of_a_blocking_gang_call(group, op, tmp_path):
     assert [e[0] for e in drainer] == CALLS * [
         "accl.window::ready", "accl.window::complete"
     ]
-    assert _in_order(drainer)
+    assert trace_in_order(drainer)
     for engine, ready in zip(sorted(engine_spans, key=lambda e: e[1]),
                              drainer[::2]):
         assert engine[1] <= ready[1]  # parked from inside the engine's span
