@@ -840,14 +840,92 @@ def _vp_local_ids(ids, vl: int, vocab: int, tp_axis):
     return jnp.clip(local, 0, vl - 1), mine
 
 
+#: Widths at which XLA's scatter-add was measured to walk the TABLE, this
+#: many ns a table row whatever the number of scattered rows, once more
+#: than a tenth as many rows are scattered as the table has (a v5e;
+#: ``PERF.md`` section 5, PR 37's grids: 13 widths from 1,024 to 16,384,
+#: these five the ones with such a walk; why is not known).
+_SCATTER_WALK_NS = {2560: 350, 3584: 150, 4608: 230, 5120: 1600, 7168: 520}
+
+
+def _onehot_wins(V: int, N: int, D: int, itemsize: int) -> bool:
+    """Whether the lookup's cotangent (:func:`_gathered_rows_bwd`) is
+    cheaper as one matmul against the ids' one-hot than as XLA's
+    scatter-add of ``N`` rows of ``D`` columns onto a table of ``V`` rows,
+    by what the two were measured to take on a v5e (``PERF.md`` section 5,
+    PR 37: 51 shapes, each form within a fifth of this model, bar the
+    scatter-add at OLMoE's shape, which it reads at half).  The matmul's
+    ``2 V N D`` FLOPs run at 86-98% of 197 TFLOP/s in bf16 (0.9 here), a
+    float32 cotangent's in three passes.  The scatter-add writes the table
+    once at about 275 GB/s, takes an ELEMENT of a scattered row, whatever
+    its type, in 0.043 ns up to 4,096 columns and 0.085 ns past them, and
+    at some widths walks the table besides (``_SCATTER_WALK_NS``: 20 of
+    DeepSeek-V2's 22.8 ms).  The choice is the compiler's weakness at a
+    shape, so it reads shapes and nothing else."""
+    passes = 1 if itemsize <= 2 else 3
+    matmul_s = passes * 2.0 * V * N * D / (0.9 * 197e12)
+    element_ns = 0.043 if D <= 4096 else 0.085
+    scatter_s = V * D * itemsize / 275e9 + N * D * element_ns * 1e-9
+    if 10 * N > V:
+        scatter_s += V * _SCATTER_WALK_NS.get(D, 0) * 1e-9
+    return matmul_s < scatter_s
+
+
+@jax.custom_vjp
+def _gathered_rows(embed, ids):
+    return embed[ids]
+
+
+def _gathered_rows_fwd(embed, ids):
+    # the gather with JAX's own transpose of it, the scatter-add XLA has
+    # always been handed, kept for the backward to use or to leave
+    rows, scatter_add = jax.vjp(lambda e: e[ids], embed)
+    return rows, (scatter_add, ids)
+
+
+def _gathered_rows_bwd(res, g):
+    """The lookup's cotangent: ``dE[v] = sum of g[n] over ids[n] == v``,
+    ``(V, D)`` in ``g``'s type, by one of two lowerings chosen by
+    :func:`_onehot_wins`: XLA's scatter-add, or ``one_hot(ids, V)^T @ g``
+    on the MXU (the one-hot in ``g``'s type, 0 and 1 exact; float32
+    accumulation).  They differ in the order of a row's float32
+    additions.  Ids are in ``[0, V)``, as ``embed[ids]`` promises."""
+    scatter_add, ids = res
+    V, D = jax.eval_shape(scatter_add, g)[0].shape[0], g.shape[-1]
+    with device_scope("accl.embed::grad"):
+        if not _onehot_wins(V, ids.size, D, g.dtype.itemsize):
+            return scatter_add(g)[0], None
+        hot = jax.nn.one_hot(ids.reshape(-1), V, dtype=g.dtype)
+        placed = jax.lax.dot_general(
+            hot, g.reshape(-1, D), (((0,), (0,)), ((), ())),
+            # a float32 cotangent keeps its mantissa through the MXU
+            precision=None if g.dtype.itemsize <= 2 else "highest",
+            preferred_element_type=jnp.float32,
+        )
+        return placed.astype(g.dtype), None
+
+
+_gathered_rows.defvjp(_gathered_rows_fwd, _gathered_rows_bwd)
+
+
+def _table_rows(embed, ids) -> jax.Array:
+    """``embed[ids]``; the cotangent is :func:`_gathered_rows_bwd`'s."""
+    # inside a shard_map: the table varying over the axes the ids vary
+    # over (dp), so that its cotangent is summed over those by the
+    # cast's transpose, as the plain gather's is
+    if missing := tuple(jax.typeof(ids).vma - jax.typeof(embed).vma):
+        embed = jax.lax.pcast(embed, missing, to="varying")
+    return _gathered_rows(embed, ids)
+
+
 def _embed_rows(embed, ids, cfg, tp_axis) -> jax.Array:
     """Embedding lookup that understands a vocab-row-sharded table: each
     rank looks up the ids it owns (masked) and a tp-allreduce assembles
     the rest — the Megatron vocab-parallel embedding."""
     if not _vp_active(cfg, tp_axis):
-        return embed[ids]
+        return _table_rows(embed, ids)
     local, mine = _vp_local_ids(ids, embed.shape[0], cfg.vocab, tp_axis)
-    out = embed[local] * mine[..., None].astype(embed.dtype)
+    out = _table_rows(embed, local) * mine[..., None].astype(embed.dtype)
     return collectives.allreduce(out, tp_axis, ReduceFunction.SUM)
 
 

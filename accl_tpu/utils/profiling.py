@@ -125,6 +125,12 @@ drops ``op_name``, so a reader joins the two by instruction name
                          ``gmm_drhs`` (the weights')
 ``accl.moe::combine``    the same: unsort, weight by the router
                          probability, sum a token's k results
+``accl.embed::grad``     ``models/transformer.py`` ``_gathered_rows_bwd``,
+                         backward only: the embedding lookup's cotangent
+                         placed on the table, by one matmul against the
+                         ids' one-hot or by XLA's scatter-add
+                         (``_onehot_wins``), with what the compiler fuses
+                         behind it (the table's SGD update)
 ======================== ==================================================
 
 jax is imported LAZILY: the emulator/native tiers (and the telemetry

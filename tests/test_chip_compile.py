@@ -8,16 +8,21 @@ The topology is described inside a fixture, never at import (only one
 process may hold the TPU's library; the fixture skips where it cannot be
 described), and every compile of this tier lives in this one file."""
 
+import dataclasses
+import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -27,7 +32,12 @@ def one_chip():
         )
     except Exception as e:  # no compiler here: nothing to check
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices[0]
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e)
 
 
 def _compile(fn, shapes, sharding):
@@ -105,3 +115,91 @@ def test_grouped_matmuls_compile_at_deepseek_v2_widths(one_chip):
     text = jax.jit(f).lower(*args, sizes).compile().as_text()
     for name in ("gmm_fwd", "gmm_dlhs", "gmm_drhs"):
         assert name in text
+
+
+def _step_text(cell_name, n_layers, device, monkeypatch):
+    """A train cell's step (``make_sharded_train_step`` on a world of the
+    one described chip, the cell's widths, batch and length; its depth cut
+    to ``n_layers``, which the table's gradient does not see), compiled;
+    the Pallas kernels compiled too, as the chip has them."""
+    from accl_tpu.models import init_params, make_sharded_train_step
+    from accl_tpu.models.transformer import normalize_spec, param_specs
+    from perfbench import manifest
+
+    for module in ("attention", "grouped_matmul"):
+        monkeypatch.setattr(
+            importlib.import_module("accl_tpu.ops.pallas." + module),
+            "default_interpret", lambda interpret=None: bool(interpret),
+        )
+    cell = manifest.cell(manifest.load(), cell_name)
+    driver = importlib.import_module(
+        "perfbench.drivers." + cell["traffic"]["driver"]
+    )
+    cfg = driver.program_config(cell["config"])
+    cfg = dataclasses.replace(
+        cfg, attention="flash", n_layers=n_layers,
+        layers=cfg.layers[:n_layers] if cfg.layers else cfg.layers,
+    )
+    mesh = Mesh(np.array([device]).reshape(1, 1), ("dp", "tp"))
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda s, spec: jax.ShapeDtypeStruct(
+            s.shape, s.dtype,
+            sharding=NamedSharding(mesh, normalize_spec(spec)),
+        ),
+        shapes, param_specs(cfg),
+    )
+    tok = jax.ShapeDtypeStruct(
+        (int(cell["traffic"]["batch"]), int(cell["traffic"]["seq"])),
+        jnp.int32, sharding=NamedSharding(mesh, P()),
+    )
+    step, _ = make_sharded_train_step(cfg, mesh, lr=float(cell["traffic"]["lr"]))
+    return step.lower(params, tok, tok).compile().as_text()
+
+
+def _table_ops(text, V, D):
+    """``{instruction: its text}`` of the ENTRY instructions that write a
+    ``bf16[V, D]``."""
+    start = text.find("\nENTRY ")
+    entry = text[start: text.find("\n}", start)]
+    found = re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = bf16\[{V},{D}\]\S* (.*)$", entry, re.M
+    )
+    return dict(found)
+
+
+def test_deepseek_v2_step_places_the_table_gradient_by_a_matmul(
+    v5e, monkeypatch
+):
+    """4,096 cotangent rows of 5,120 columns on 12,800 table rows: no
+    scatter into ``bf16[12800,5120]``, and one op under
+    ``accl.embed::grad`` that holds the matmul (a leading dense layer and
+    one expert layer of the cell's five)."""
+    from perfbench import scope_ops
+
+    text = _step_text("train_dsv2_t4096_b1", 2, v5e, monkeypatch)
+    ops = _table_ops(text, 12800, 5120)
+    assert ops and not any("scatter" in op for op in ops.values())
+    assert not re.search(r"bf16\[12800,5120\]\S* scatter\(", text)
+    under = scope_ops.scopes_of(text).get("accl.embed::grad")
+    assert under and set(under) <= set(ops)
+    # the computation that op calls holds the matmul
+    called = re.search(r"calls=(%\S+?)[,)]", ops[under[0]])[1]
+    body = text[text.find(f"\n{called} ("):]
+    assert re.search(
+        r"f32\[12800,5120\]\S* convolution\(", body[: body.find("\n}")]
+    )
+
+
+def test_starcoder_step_keeps_its_scatter_add(v5e, monkeypatch):
+    """8,192 rows of 4,096 columns on 49,152 table rows: XLA's scatter-add
+    stays, under the same scope."""
+    from perfbench import scope_ops
+
+    text = _step_text("train_t8192_b1", 1, v5e, monkeypatch)
+    ops = _table_ops(text, 49152, 4096)
+    under = scope_ops.scopes_of(text).get("accl.embed::grad")
+    assert under
+    scatters = [n for n in under if n in ops and "scatter-add" in ops[n]]
+    assert len(scatters) == 1
+    assert re.search(r"bf16\[49152,4096\]\S* scatter\(", text)

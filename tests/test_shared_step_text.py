@@ -36,16 +36,19 @@ from perfbench import manifest
 #: text is held (the compiled text numbers the interpreter's host
 #: callbacks by what the process compiled before).  The two ``"flash"``
 #: digests are PR 32's, which changed the kernels' traced bodies by intent
-#: (a body a tile class); the two ``None`` ones are still the parent's of
-#: PR 31.
+#: (a body a tile class); the two ``None`` lowered ones are still the
+#: parent's of PR 31.  The compiled ones are of the same programs, taken
+#: again in PR 37 without the ops' names (``normalised``): PR 37 put the
+#: embedding table's scatter-add under a scope, which renames ops and
+#: changes none.
 PARENT = {
     ("train_t8192_b1", None): (
         "4a3bfd2ae38f5de2aaadbca04d00a094943aeb1d7e2bd1a68ea34553a2bd33cc",
-        "1f781a8eefe8b7bf764fec8d8baacc0da2fb9ff192e2ac2901386406151f08f1",
+        "1e3f204462bc36b939009eea2db25e4729a8cd349d5904638579f4b19212b69d",
     ),
     ("train_olmoe_t4096_b2", None): (
         "5df31246299c5b34126f52f122132a1193099bba9663f409d25a1503efaebed5",
-        "4feeeab0878f609d05df71b6e3c30f1ab47b62b4114df84df3022839412600c1",
+        "95b83023ee09847bbd51d38c81e821891f2ffb350c9c2a7a9489eaa881015622",
     ),
     ("train_t8192_b1", "flash"): (
         "14239fba61a811ec071d82588cdf88eb13010f5c51aa1471b374ecb28992f889",
@@ -58,12 +61,24 @@ PARENT = {
 }
 
 
+@pytest.fixture(autouse=True)
+def scatter_add_as_at_the_timed_sizes(monkeypatch):
+    """A rehearsal's table is small enough for the embedding lookup's
+    cotangent to go by the one-hot matmul (``_onehot_wins``); the cells'
+    timed sizes keep XLA's scatter-add, and that is the program guarded."""
+    from accl_tpu.models import transformer
+
+    monkeypatch.setattr(transformer, "_onehot_wins", lambda *shape: False)
+
+
 def normalised(compiled: str) -> str:
     text = re.sub(
         r'backend_config=\{"outer_dimension_partitions":\[[^\]]*\]\}', "",
         compiled,
     )
     text = re.sub(r" stack_frame_id=\d+", "", text)
+    # an op's name says which scopes and transforms traced it, not what it is
+    text = re.sub(r', metadata=\{op_name="[^"]*"\}', "", text)
     start = text.find("\nFileNames")
     if start >= 0:
         text = text[:start] + text[text.find("\n\n", text.find("\nStackFrames")):]
