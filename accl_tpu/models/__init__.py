@@ -11,10 +11,12 @@ machinery is the substrate for.
 """
 
 from .transformer import (
+    BlockDiffusion,  # noqa: F401
     LatentAttention,  # noqa: F401
     LayerKind,  # noqa: F401
     TransformerConfig,
     YarnScaling,  # noqa: F401
+    diffusion_noise,  # noqa: F401
     generate,
     init_params,
     forward,

@@ -386,6 +386,7 @@ def moe_ffn(
     n_group: int = 1,
     topk_group: int = 1,
     balance_groups: int = 0,
+    switch_balance: bool = False,
 ):
     """Top-k gated MoE FFN (k=1 is Switch routing, k=2 the classic MoE).
 
@@ -412,7 +413,10 @@ def moe_ffn(
     ``n_group`` / ``topk_group`` (group-limited top-k on the softmax
     router; 1, 1 is plain top-k) and ``balance_groups``: where it is not
     0, ``return_aux`` adds :func:`balance_losses` over that many groups,
-    a sequence a row of ``x``, held share or not.
+    a sequence a row of ``x``, held share or not.  ``switch_balance``
+    (the softmax router): ``load_balance`` is the Switch term all the
+    same, over ALL the router's outputs and this chip's rows, whatever
+    share of the experts is held (Qwen3-MoE's auxiliary loss a layer).
 
     Returns (B, T, D): expert outputs weighted by the gate probability;
     over-capacity entries contribute zero (callers add the residual).
@@ -540,8 +544,17 @@ def moe_ffn(
                     topk_group if n_group > 1 else balance_groups,
                 ))
         if beyond:
+            load_balance = jnp.zeros((), jnp.float32)
+            if switch_balance:
+                if router != "softmax":
+                    raise ValueError(
+                        "the Switch load-balance term is the softmax router's"
+                    )
+                with device_scope("accl.moe::route"):
+                    f = counters["expert_tokens"].astype(jnp.float32) / (N * k)
+                    load_balance = n_router * jnp.sum(f * probs.mean(axis=0))
             return y, {
-                "load_balance": jnp.zeros((), jnp.float32),
+                "load_balance": load_balance,
                 "router_z": jnp.zeros((), jnp.float32), **counters,
             }
         f = sizes.astype(jnp.float32) / (N * k)

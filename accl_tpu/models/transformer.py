@@ -82,6 +82,24 @@ class YarnScaling:
     mscale_all_dim: float = 0.0
 
 
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """Block-diffusion training (BD3-LM's objective, as the SDAR family
+    adopts it; ``TransformerConfig.diffusion``).  A sequence of ``L`` ids
+    is ``L / block`` blocks; each block of each sequence draws a level
+    ``t = eps + (1 - eps) u``, ``u ~ U[0, 1)``, and each of its positions
+    becomes ``mask_id`` with probability ``t`` (:func:`diffusion_noise`).
+    The model runs on ``[noisy ; clean]``, ``2 L`` rows at positions
+    ``0..L`` twice, under the block layout of
+    ``ops.attention.block_diffusion_visible``; logits are taken on the
+    noisy half only and predict the id AT the position (no shift):
+    ``loss = sum over masked positions of (1 / t) nll / (sequences * L)``."""
+
+    block: int
+    mask_id: int
+    eps: float = 1e-3
+
+
 def yarn_mscale(factor: float, mscale: float) -> float:
     """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
     if factor <= 1.0 or not mscale:
@@ -195,9 +213,9 @@ class TransformerConfig:
     # train and forward paths honour all of them; prefill/generate, the
     # context- and sequence-parallel blocks, the encoder and the
     # composed pipeline take what :meth:`plain` allows.  ``norm_eps`` is
-    # the epsilon of the block's norms, the final norm and a latent
-    # mixer's (the published ``rms_norm_eps`` / ``layer_norm_epsilon``;
-    # QK-norm keeps 1e-5).
+    # the epsilon of the block's norms, the final norm, QK-norm and a
+    # latent mixer's (the published ``rms_norm_eps`` /
+    # ``layer_norm_epsilon``).
     norm: str = "layernorm"
     norm_eps: float = 1e-5
     ffn: str = "gelu"
@@ -224,6 +242,16 @@ class TransformerConfig:
     # ``pos_embedding="rope"``; ``n_kv_heads``, ``head_dim``, ``qk_norm``
     # and ``attn_gate`` do not apply to it.
     latent: Optional[LatentAttention] = None
+    # the objective where it is not next-token prediction
+    # (:class:`BlockDiffusion`): ``loss_fn`` and the train step noise the
+    # ids from a key (the step's third argument, in ``targets``' place)
+    # and run ``[noisy ; clean]``; ``forward`` and the router probe take
+    # the ``2 L`` ids as they are and return the noisy half's logits.
+    # Needs ``pos_embedding="rope"``; no window, no latent mixer.  REFUSED
+    # by name by prefill/generate (generation denoises a block at a time:
+    # ROADMAP), the context- and sequence-parallel blocks, the encoder and
+    # the pipelines.
+    diffusion: Optional[BlockDiffusion] = None
     # the layer pattern (ROADMAP M1): one :class:`LayerKind` a layer —
     # the mixer's window and whether it rotates, the FFN's kind and
     # width.  ``None`` is the pattern the other fields describe, every
@@ -398,12 +426,13 @@ class TransformerConfig:
         """Every layer alike and nothing of the later kinds (a pattern, a
         head width of its own, the gate, per-head QK-norm, post-norms, a
         scaled embedding, the sigmoid router, a shared expert, a held
-        share, a latent mixer, grouped top-k, the balance losses): what
-        prefill/generate and the context- and sequence-parallel blocks
-        compute."""
+        share, a latent mixer, grouped top-k, the balance losses, an
+        objective of its own): what prefill/generate and the context- and
+        sequence-parallel blocks compute."""
         return (
             self.layers is None and self.head_dim is None
-            and self.latent is None and self.moe_n_group == 1
+            and self.latent is None and self.diffusion is None
+            and self.moe_n_group == 1
             and not any(self.moe_balance_weights)
             and not self.attn_gate and not self.post_norm
             and self.qk_norm in (False, True) and self.embed_scale == 1.0
@@ -430,6 +459,20 @@ class TransformerConfig:
             )
         if self.rope_yarn is not None and self.pos_embedding != "rope":
             raise ValueError("rope_yarn rescales a rotary embedding")
+        if self.diffusion is not None:
+            d = self.diffusion
+            if (
+                self.pos_embedding != "rope" or self.latent is not None
+                or any(k.window is not None for k in self.layers or ())
+                or d.block < 1 or not 0 <= d.mask_id < self.vocab
+                or not 0.0 < d.eps < 1.0
+            ):
+                raise ValueError(
+                    "block diffusion (TransformerConfig.diffusion) rotates "
+                    "(pos_embedding='rope'), has no window and no latent "
+                    "mixer, blocks of at least 1, a mask_id inside the "
+                    f"vocabulary and 0 < eps < 1; got {d}"
+                )
         if self.moe_n_group != 1 or self.moe_topk_group != 1:
             of = self.router_experts()
             if (
@@ -502,7 +545,14 @@ def _check_axis_compat(cfg) -> None:
             "(TransformerConfig.plain): no layer pattern, window, head_dim, "
             "gate, per-head QK-norm, post-norm, scaled embedding, sigmoid "
             "router, shared expert, held share, latent mixer (MLA), grouped "
-            "top-k or balance losses"
+            "top-k, balance losses or block diffusion (its layout is not in "
+            "the ring yet)"
+        )
+    if cfg.diffusion is not None and cfg.vocab_parallel:
+        raise ValueError(
+            "block diffusion (TransformerConfig.diffusion) has no "
+            "vocab_parallel loss: its weighted loss is over full logits of "
+            "the noisy half"
         )
     if cfg.context_parallel and (cfg.seq_parallel or cfg.vocab_parallel):
         raise ValueError(
@@ -798,14 +848,16 @@ def _norm_fn(cfg):
     return fn if cfg.norm_eps == 1e-5 else partial(fn, eps=cfg.norm_eps)
 
 
-def _qk_norm(q, k, lp, tp_axis):
+def _qk_norm(q, k, lp, tp_axis, eps: float = 1e-5):
     """A layer with ``q_norm``/``k_norm`` scales RMS-normalises the whole
     projected q and k (every head at once, over ``tp_axis`` where the
-    heads are sharded) before the split into heads."""
+    heads are sharded) before the split into heads, at the
+    configuration's ``norm_eps``."""
     if "q_norm" not in lp:
         return q, k
     return (
-        _rmsnorm(q, lp["q_norm"], tp_axis), _rmsnorm(k, lp["k_norm"], tp_axis)
+        _rmsnorm(q, lp["q_norm"], tp_axis, eps),
+        _rmsnorm(k, lp["k_norm"], tp_axis, eps),
     )
 
 
@@ -946,6 +998,42 @@ def _embed_tokens(params, tokens, cfg, tp_axis=None) -> jax.Array:
     return x
 
 
+def diffusion_noise(key, tokens, diffusion: BlockDiffusion, shard=None):
+    """The noisy copy of ``tokens`` (S, L) under :class:`BlockDiffusion`,
+    from ``key`` (a typed key or its two uint32 words): a level ``t = eps
+    + (1 - eps) u`` a block a sequence, each position masked with its
+    block's ``t``.  Returns ``(noisy ids, masked (S, L) bool, t (S, L)
+    float32)``; the same key gives the same draw.  ``shard=(index,
+    count)``: ``tokens`` are sequences ``index * S ..`` of a batch of
+    ``count * S`` and get that batch's draw for them, so a batch is
+    noised alike however it is sharded.  Device scope
+    ``accl.diffusion::noise``."""
+    S, L = tokens.shape
+    block = diffusion.block
+    if L % block:
+        raise ValueError(
+            f"block diffusion: {L} ids are no whole blocks of {block}"
+        )
+    index, count = (0, 1) if shard is None else shard
+
+    def mine(draw):
+        return draw if count == 1 else jax.lax.dynamic_slice_in_dim(
+            draw, index * S, S
+        )
+
+    with device_scope("accl.diffusion::noise"):
+        level_key, mask_key = jax.random.split(key)
+        u = mine(jax.random.uniform(
+            level_key, (count * S, L // block), jnp.float32
+        ))
+        t = jnp.repeat(diffusion.eps + (1.0 - diffusion.eps) * u, block, axis=1)
+        masked = mine(jax.random.uniform(
+            mask_key, (count * S, L), jnp.float32
+        )) < t
+        noisy = jnp.where(masked, diffusion.mask_id, tokens)
+    return noisy.astype(tokens.dtype), masked, t
+
+
 _BALANCE = ("balance_expert", "balance_device", "balance_comm")
 
 
@@ -1079,7 +1167,7 @@ def resolve_attention(impl: str, q) -> str:
 
 def _attention(q, k, v, impl: str = "naive", causal: bool = True,
                window: Optional[int] = None, scale: Optional[float] = None,
-               q_rope=None, k_rope=None):
+               q_rope=None, k_rope=None, block_diffusion=None):
     """Attention; q,k,v: (B, H, T, hd); ``causal=False`` is the
     bidirectional (encoder) form; ``window`` (causal only) keeps a
     query's last ``window`` keys, its own among them, in every lowering.
@@ -1089,6 +1177,9 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
     part of q and k whose product is added to the scores: the flash
     kernels take them as they are (the few key heads shared through the
     index map), the XLA forms get them joined onto q and k.
+    ``block_diffusion=(L, B)`` is the block-diffusion layout of ``T = 2 L``
+    rows in place of ``causal``: tile lists in the flash kernels, a dense
+    mask in the XLA forms (``ops.attention.block_diffusion_visible``).
 
     ``impl="auto"`` resolves through :func:`resolve_attention`;
     ``"blockwise"`` runs the fused online-softmax fold (no (T, T) score
@@ -1115,6 +1206,10 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
     windowed = {} if window is None else {"window": window}
     if scale is not None:
         windowed["scale"] = scale
+    if block_diffusion is not None:
+        if window is not None:
+            raise ValueError("block diffusion has no window")
+        windowed["block_diffusion"] = block_diffusion
     if impl == "blockwise":
         from ..ops.attention import blockwise_attention
 
@@ -1145,7 +1240,15 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
     ) * (1.0 / math.sqrt(hd) if scale is None else scale)
     if window is not None and not causal:
         raise ValueError("a window is causal")
-    if causal:
+    if block_diffusion is not None:
+        from ..ops.attention import block_diffusion_visible
+
+        pos = jnp.arange(T)
+        mask = block_diffusion_visible(
+            pos[:, None], pos[None, :], *block_diffusion
+        )
+        scores = jnp.where(mask, scores, -1e30)
+    elif causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
         if window is not None:
             mask &= ~jnp.tril(jnp.ones((T, T), bool), -window)
@@ -1201,6 +1304,10 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
         if moe_cfg.moe_router_experts is not None:
             beyond.update(first_expert=moe_cfg.moe_first_expert,
                           held_row_factor=moe_cfg.moe_held_row_factor)
+            if with_aux and moe_cfg.moe_aux_weight:
+                # the Switch term over all the router's outputs, computed
+                # only where the loss weighs it
+                beyond.update(switch_balance=True)
         out = moe_ffn(
             h, lp["moe"], ep_axis=ep_axis,
             capacity_factor=cf,
@@ -1276,7 +1383,8 @@ def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
 
 def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
                   rope_base=None, positions=None, attention_fn=None,
-                  tp_axis=None, window=None, head_norm=False, latent=None):
+                  tp_axis=None, window=None, head_norm=False, latent=None,
+                  qk_eps=1e-5, block_diffusion=None):
     """Column-parallel attention on a full-sequence activation: returns
     the row-parallel PARTIAL output (pre-reduction) and the (k, v) head
     tensors (B, Hkv_local, T, hd) for KV-cache prefill.  The kv head
@@ -1298,7 +1406,10 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     ``wo``; a ``wq_a`` is the latent mixer's (:func:`_latent_attn_partial`,
     which has no cache to return yet).  ``window`` is the sliding window,
     run under the device scope ``accl.attn::window`` (full attention stays
-    ``accl.attn::core``)."""
+    ``accl.attn::core``).  ``qk_eps`` is QK-norm's epsilon.
+    ``block_diffusion=(L, B)``: ``h`` is ``[noisy ; clean]``, ``2 L`` rows
+    that rotate at positions ``0..L`` twice, and the core runs under that
+    layout in the device scope ``accl.attn::blockdiff``."""
     if "wq_a" in lp:
         return _latent_attn_partial(
             h, lp, n_heads_local, attn_impl, causal, rope_base, window, latent
@@ -1306,7 +1417,7 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     B, T, _ = h.shape
     q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]  # column-parallel
     if not head_norm:
-        q, k = _qk_norm(q, k, lp, tp_axis)
+        q, k = _qk_norm(q, k, lp, tp_axis, qk_eps)
     hd = q.shape[-1] // n_heads_local
     n_kv_local = k.shape[-1] // hd
     heads = lambda t, n: t.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
@@ -1314,13 +1425,22 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
         heads(q, n_heads_local), heads(k, n_kv_local), heads(v, n_kv_local)
     )
     if head_norm and "q_norm" in lp:
-        q, k = _rmsnorm(q, lp["q_norm"]), _rmsnorm(k, lp["k_norm"])
+        q = _rmsnorm(q, lp["q_norm"], eps=qk_eps)
+        k = _rmsnorm(k, lp["k_norm"], eps=qk_eps)
     if rope_base is not None:
-        pos = jnp.arange(T) if positions is None else positions
+        if block_diffusion is not None:
+            pos = jnp.tile(jnp.arange(block_diffusion[0]), 2)
+        else:
+            pos = jnp.arange(T) if positions is None else positions
         tables = _rope_tables(pos, hd // 2, rope_base)
         q = _rope_rotate(q, tables)
         k = _rope_rotate(k, tables)
-    if window is None:
+    if block_diffusion is not None:
+        with device_scope("accl.attn::blockdiff"):
+            attn = _attention(
+                q, k, v, impl=attn_impl, block_diffusion=block_diffusion
+            )
+    elif window is None:
         with device_scope("accl.attn::core"):
             if attention_fn is not None:
                 attn = attention_fn(q, k, v)
@@ -1342,7 +1462,8 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
            attn_impl="naive", causal=True, rope_base=None,
            ep_axis=None, moe_cfg=None, with_aux=False,
            reduce_fn=None, fanout_fn=None, norm=_layernorm,
-           window=None, head_norm=False, latent=None):
+           window=None, head_norm=False, latent=None, qk_eps=1e-5,
+           block_diffusion=None):
     """One transformer block on tp-sharded weights.  ``lp['wqkv']`` etc. are
     the *local shards*; the tp-allreduce after each row-parallel matmul is
     the reference's fused-allreduce hot path in model form.
@@ -1367,7 +1488,8 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
         h = fanout_fn(h, tp_axis)
     partial_o, kv = _attn_partial(
         h, lp, n_heads_local, attn_impl, causal, rope_base, tp_axis=tp_axis,
-        window=window, head_norm=head_norm, latent=latent,
+        window=window, head_norm=head_norm, latent=latent, qk_eps=qk_eps,
+        block_diffusion=block_diffusion,
     )
     if tp_axis is not None:
         partial_o = reduce_fn(partial_o, tp_axis)
@@ -1398,7 +1520,8 @@ def _cp_block_k(t_local: int, attn_impl: str):
 
 
 def _block_cp(x, lp, n_heads, cp_axis, rope_base=None, attn_impl="auto",
-              ep_axis=None, moe_cfg=None, with_aux=False, norm=_layernorm):
+              ep_axis=None, moe_cfg=None, with_aux=False, norm=_layernorm,
+              qk_eps=1e-5):
     """Context-parallel block: ``x`` is (B, T/cp, D), this rank's STRIPED
     sequence shard over ``cp_axis``; weights are full (replicated over
     the axis).  QKV/MLP matmuls are purely local; attention is striped
@@ -1424,7 +1547,7 @@ def _block_cp(x, lp, n_heads, cp_axis, rope_base=None, attn_impl="auto",
     h = norm(x, lp["ln1"])
     o, _ = _attn_partial(
         h, lp, n_heads, rope_base=rope_base,
-        positions=positions, attention_fn=ring,
+        positions=positions, attention_fn=ring, qk_eps=qk_eps,
     )
     x = x + o
     return _mlp(x, lp, None, ep_axis, moe_cfg, with_aux, norm=norm)
@@ -1432,7 +1555,7 @@ def _block_cp(x, lp, n_heads, cp_axis, rope_base=None, attn_impl="auto",
 
 def _block_sp(x_sp, lp, n_heads_local, tp_axis, return_kv=False,
               attn_impl="naive", causal=True, rope_base=None,
-              norm=_layernorm):
+              norm=_layernorm, qk_eps=1e-5):
     """Sequence-parallel block (Megatron-SP): ``x_sp`` is (B, T/tp, D),
     sequence-sharded over ``tp``.  All-gather restores the full sequence
     in front of each column-parallel matmul; the row-parallel reduction
@@ -1448,7 +1571,7 @@ def _block_sp(x_sp, lp, n_heads_local, tp_axis, return_kv=False,
     h_full = collectives.allgather(h, tp_axis, axis=1)
     partial_o, kv = _attn_partial(
         h_full, lp, n_heads_local, attn_impl, causal, rope_base,
-        tp_axis=tp_axis,
+        tp_axis=tp_axis, qk_eps=qk_eps,
     )
     o_sp = collectives.reduce_scatter(
         partial_o, tp_axis, tiled=True, axis=1
@@ -1502,6 +1625,8 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
             cp_kw["ep_axis"] = cfg.moe_mesh_axis
             cp_kw["moe_cfg"] = cfg
             cp_kw["with_aux"] = True
+        if cfg.qk_norm and cfg.norm_eps != 1e-5:
+            cp_kw["qk_eps"] = cfg.norm_eps
         block = partial(_block_cp, **cp_kw)
         return x, block, "cp"
     heads_local = cfg.n_heads // tp_size
@@ -1531,6 +1656,15 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
         kw["return_kv"] = True
     if cfg.qk_norm == "head":
         kw["head_norm"] = True
+    if cfg.qk_norm and cfg.norm_eps != 1e-5:
+        kw["qk_eps"] = cfg.norm_eps
+    if cfg.diffusion is not None:
+        if x.shape[1] % 2 or (x.shape[1] // 2) % cfg.diffusion.block:
+            raise ValueError(
+                f"block diffusion runs [noisy ; clean], 2 L rows of whole "
+                f"blocks of {cfg.diffusion.block}; got {x.shape[1]} rows"
+            )
+        kw["block_diffusion"] = (x.shape[1] // 2, cfg.diffusion.block)
     if cfg.latent is not None:
         kw["latent"] = {
             "scale": cfg.attn_scale(), "inv_freq": cfg.rope_inv_freq(),
@@ -1578,7 +1712,9 @@ def _layer_blocks(block, cfg):
 
 
 def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):
-    """Embed -> blocks -> final layernorm.  Returns ``(x, layout, aux)``:
+    """Embed -> blocks -> final layernorm (under ``cfg.diffusion``
+    ``tokens`` are ``[noisy ; clean]`` and ``x`` the noisy half's rows).
+    Returns ``(x, layout, aux)``:
     ``layout`` flags how ``x`` is sequence-sharded ("" / "sp" / "cp");
     ``aux`` is None for dense FFNs or the MoE router's terms: the health
     terms summed over layers ({"load_balance", "router_z"}, shared by
@@ -1593,10 +1729,16 @@ def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):
     x, block, sp = _enter_block_layout(x, cfg, tp_axis, tp_size)
     blocks = _layer_blocks(block, cfg)
     norm = _norm_fn(cfg)
+    if cfg.diffusion is not None:
+        # [noisy ; clean] went through the layers; the final norm (and
+        # the head after it) see the noisy half only
+        final = lambda x: norm(x[:, : x.shape[1] // 2], params["ln_f"])
+    else:
+        final = lambda x: norm(x, params["ln_f"])
     if not cfg.n_experts:
         for blk, lp in zip(blocks, params["layers"]):
             x = blk(x, lp)
-        return norm(x, params["ln_f"]), sp, None
+        return final(x), sp, None
     lb = jnp.zeros((), jnp.float32)
     rz = jnp.zeros((), jnp.float32)
     balance = {}
@@ -1624,7 +1766,7 @@ def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):
         aux["held_entries"] = jnp.stack(held)
     if groups:
         aux["group_tokens"] = jnp.stack(groups)
-    return norm(x, params["ln_f"]), sp, aux
+    return final(x), sp, aux
 
 
 def forward(params, tokens, cfg: TransformerConfig, tp_axis=None, tp_size=1):
@@ -1632,7 +1774,8 @@ def forward(params, tokens, cfg: TransformerConfig, tp_axis=None, tp_size=1):
     inside shard_map; without, a plain single-device forward.  Always
     returns the FULL-vocab logits (vocab-parallel shards are gathered —
     use :func:`loss_fn` for the fused form that never materializes
-    them).
+    them).  Under ``cfg.diffusion`` ``tokens`` are ``[noisy ; clean]``
+    (B, 2 L) and the logits the noisy half's (B, L, vocab).
 
     Exception: under context parallelism the return value is this
     rank's striped (B, T/cp, vocab) logits shard — the makers'
@@ -1657,6 +1800,37 @@ def forward(params, tokens, cfg: TransformerConfig, tp_axis=None, tp_size=1):
     return logits
 
 
+def _diffusion_loss(params, tokens, key, cfg, tp_axis, tp_size, with_aux,
+                    shard=None):
+    """The block-diffusion objective (:class:`BlockDiffusion`): ``tokens``
+    (S, L) are noised from ``key`` inside the program
+    (:func:`diffusion_noise`), ``[noisy ; clean]`` runs through the layers
+    under the block layout, the final norm, the head and the loss see the
+    noisy half only (the clean half's logits are never made; device scope
+    ``accl.loss::diffusion``), and a masked position's NLL of the id AT
+    the position weighs ``1 / t``: ``sum / (S L)``, plus the router's
+    penalty where weighed.  ``with_aux`` adds the router's counters and
+    ``masked_tokens``; ``shard`` is :func:`diffusion_noise`'s."""
+    noisy, masked, t = diffusion_noise(key, tokens, cfg.diffusion, shard)
+    x, _, aux = _final_hidden(
+        params, jnp.concatenate([noisy, tokens], axis=1), cfg, tp_axis,
+        tp_size,
+    )
+    with device_scope("accl.loss::diffusion"):
+        nll = _token_nll(_lm_logits(x, params, cfg, tp_axis), tokens)
+        loss = jnp.sum(jnp.where(masked, nll / t, 0.0)) / tokens.size
+    if aux is not None and (
+        cfg.moe_aux_weight or cfg.moe_router_z_weight
+        or any(cfg.moe_balance_weights)
+    ):
+        loss = loss + _moe_penalty(cfg, aux)
+    if not with_aux:
+        return loss
+    return loss, {
+        **(aux or {}), "masked_tokens": jnp.sum(masked, dtype=jnp.int32),
+    }
+
+
 def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1,
             with_aux=False):
     """Mean next-token NLL (``with_aux``: and the MoE router's terms and
@@ -1672,8 +1846,15 @@ def loss_fn(params, tokens, targets, cfg, tp_axis=None, tp_size=1,
     rank's STRIPED sequence shards: the cross-entropy stays local
     ((B, T/cp, vocab) logits only) and the ring-mean of the equal-sized
     shard means is the global mean — full-sequence activations never
-    exist on any rank."""
+    exist on any rank.
+
+    Under ``cfg.diffusion`` (:class:`BlockDiffusion`) ``targets`` is the
+    noise KEY: the loss is :func:`_diffusion_loss`."""
     _check_axis_compat(cfg)
+    if cfg.diffusion is not None:
+        return _diffusion_loss(
+            params, tokens, targets, cfg, tp_axis, tp_size, with_aux
+        )
     if _cp_active(cfg, tp_axis):
         x, _, aux = _final_hidden(params, tokens, cfg, tp_axis, tp_size)
         z = _lm_logits(x, params, cfg, tp_axis, gather=False)
@@ -1764,24 +1945,32 @@ def _reject_unservable(cfg) -> None:
             "its own, an attention gate, per-head QK-norm, post-norms, a "
             "scaled embedding, a sigmoid router, a shared expert, a "
             "held share of the experts, a latent mixer (MLA: its cache is "
-            "the latent and the rope key, not k and v), grouped top-k or "
-            "balance losses, and the decode path has no cache "
+            "the latent and the rope key, not k and v), grouped top-k, "
+            "balance losses or block diffusion (generation there denoises "
+            "a block of tokens at a time over several passes), and the "
+            "decode path has no cache "
             "layout or block for those yet (train and forward do)"
         )
 
 
 def reject_latent(cfg, where: str) -> None:
-    """The paths beside train and forward refuse a latent mixer by name."""
+    """The paths beside train and forward refuse a latent mixer, and the
+    block-diffusion objective, by name."""
     if cfg.latent is not None:
         raise ValueError(
             "the latent mixer (MLA, TransformerConfig.latent) is supported "
             f"on the decoder's train and forward paths only, not {where}"
         )
+    if cfg.diffusion is not None:
+        raise ValueError(
+            "block diffusion (TransformerConfig.diffusion) is supported on "
+            f"the decoder's train and forward paths only, not {where}"
+        )
 
 
 def _block_decode(x_t, lp, cache_k, cache_v, pos, n_heads_local, tp_axis,
                   rope_tables=None, ep_axis=None, moe_cfg=None,
-                  norm=_layernorm):
+                  norm=_layernorm, qk_eps=1e-5):
     """One block for a single decode position: write this step's k/v into
     the cache at ``pos`` (dynamic_update_slice keeps shapes static under
     jit/scan), attend over positions <= pos, same tp collectives as the
@@ -1793,7 +1982,7 @@ def _block_decode(x_t, lp, cache_k, cache_v, pos, n_heads_local, tp_axis,
     B, _, D = x_t.shape
     h = norm(x_t, lp["ln1"])
     q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
-    q, k = _qk_norm(q, k, lp, tp_axis)
+    q, k = _qk_norm(q, k, lp, tp_axis, qk_eps)
     hd = q.shape[-1] // n_heads_local
     n_kv_local = k.shape[-1] // hd
     rs = lambda t, n: t.reshape(B, 1, n, hd).transpose(0, 2, 1, 3)
@@ -1971,7 +2160,7 @@ def generate(
                     if (tp_axis and cfg.n_experts) else None
                 ),
                 moe_cfg=cfg if cfg.n_experts else None,
-                norm=_norm_fn(cfg),
+                norm=_norm_fn(cfg), qk_eps=cfg.norm_eps,
             )
             new_caches.append((ck, cv))
         x = _norm_fn(cfg)(x, params["ln_f"])
@@ -2225,7 +2414,15 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh, lr: float = 1e-2
     forward collectives into exactly the right gradient collectives: sharded
     weights keep local shard grads, replicated weights get the cross-shard
     psum — the dp gradient allreduce of classic data parallelism falls out
-    of the same machinery."""
+    of the same machinery.
+
+    Under ``cfg.diffusion`` (:class:`BlockDiffusion`) the step is
+    ``step(params, tokens, key) -> (params, loss, counters)``: the noise
+    key (two uint32 words, or a typed key) stands in ``targets``' place,
+    a data shard takes the whole batch's draw for its sequences, and
+    ``counters`` are the
+    step's own ``masked_tokens`` and, where the chip holds a share of the
+    experts, ``held_entries`` a layer, summed over the data axes."""
     _reject_untrainable_attention(cfg)
     _check_moe_mesh(cfg, mesh)
     specs = param_specs(cfg)
@@ -2237,6 +2434,31 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh, lr: float = 1e-2
     for a in axes:
         denom *= mesh.shape[a]
     bias_rate = cfg.moe_bias_rate if cfg.n_experts else 0.0
+
+    def diffusion_step(params, tokens, key):
+        index = 0   # this shard's place in the batch over the data axes
+        for a in axes:
+            index = index * mesh.shape[a] + jax.lax.axis_index(a)
+
+        def global_loss(p):
+            local, aux = _diffusion_loss(
+                p, tokens, key, cfg, "tp", tp, True, (index, denom)
+            )
+            counters = {
+                k: aux[k] for k in ("masked_tokens", "held_entries")
+                if k in aux
+            }
+            for a in axes:
+                counters = collectives.allreduce(
+                    counters, a, ReduceFunction.SUM
+                )
+            return _mean_over_axes(local, axes, denom), counters
+
+        (loss, counters), grads = jax.value_and_grad(
+            global_loss, has_aux=True
+        )(params)
+        params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
+        return params, loss, counters
 
     def step(params, tokens, targets):
         def global_loss(p):
@@ -2268,12 +2490,25 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh, lr: float = 1e-2
     # transpose machinery exactly like dp's
     batch = _batch_entry(axes)
     seq_spec = P(batch, "tp") if cfg.context_parallel else P(batch, None)
-    smapped = shard_map(
-        step,
-        mesh=mesh,
-        in_specs=(specs, seq_spec, seq_spec),
-        out_specs=(specs, P()),
-    )
+    if cfg.diffusion is not None:
+        if bias_rate:
+            raise ValueError(
+                "block diffusion with an expert bias (moe_bias_rate): the "
+                "step moves no bias under that objective yet"
+            )
+        smapped = shard_map(
+            diffusion_step,
+            mesh=mesh,
+            in_specs=(specs, seq_spec, P()),
+            out_specs=(specs, P(), P()),
+        )
+    else:
+        smapped = shard_map(
+            step,
+            mesh=mesh,
+            in_specs=(specs, seq_spec, seq_spec),
+            out_specs=(specs, P()),
+        )
     if cfg.context_parallel:
         from .ring_attention import stripe_sequence
 

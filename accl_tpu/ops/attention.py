@@ -34,8 +34,25 @@ from .pallas.attention import _mxu_precision
 _NEG = -1e30
 
 
+def block_diffusion_visible(q_pos, k_pos, L: int, block: int):
+    """Whether query position ``q_pos`` sees key position ``k_pos`` under
+    the block-diffusion layout ``(L, block)``, elementwise: the sequence
+    is ``2 L`` rows, a NOISY copy at ``0..L`` and the CLEAN sequence at
+    ``L..2L``, both in blocks of ``block``.  Noisy sees noisy of the same
+    block and clean of the blocks strictly before; clean sees clean of
+    the blocks up to and including its own; nothing sees a noisy key
+    outside its block (BD3-LM's training mask)."""
+    q_noisy, k_noisy = q_pos < L, k_pos < L
+    qb = jnp.where(q_noisy, q_pos, q_pos - L) // block
+    kb = jnp.where(k_noisy, k_pos, k_pos - L) // block
+    return jnp.where(
+        k_noisy, q_noisy & (qb == kb),
+        jnp.where(q_noisy, kb < qb, kb <= qb),
+    )
+
+
 def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
-                   window=None, scale=None):
+                   window=None, scale=None, block_diffusion=None):
     """One head, q and k (T, D) and v (T, Dv): scan q blocks; fold k
     blocks with online softmax.
 
@@ -72,6 +89,10 @@ def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
                 mask &= q_pos[:, None] >= k_pos[None, :]
             if window is not None:
                 mask &= q_pos[:, None] - k_pos[None, :] < window
+            if block_diffusion is not None:
+                mask &= block_diffusion_visible(
+                    q_pos[:, None], k_pos[None, :], *block_diffusion
+                )
             s = jnp.where(mask, s, _NEG)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -115,6 +136,7 @@ def blockwise_attention(
     block_k: int = 256,
     window: int | None = None,
     scale: float | None = None,
+    block_diffusion: tuple | None = None,
 ) -> jax.Array:
     """Causal (or full) attention over ``(B, H, T, Dh)`` operands without
     materializing the (T, T) score matrix.  Exact (not approximate):
@@ -123,7 +145,9 @@ def blockwise_attention(
     scores (``Dh ** -0.5`` where not given).
 
     ``window=W`` (causal only): query ``i`` sees keys ``0 <= i - j < W``,
-    as ``ops.pallas.flash_attention`` has it.
+    as ``ops.pallas.flash_attention`` has it.  ``block_diffusion=(L, B)``
+    is its block-diffusion layout (:func:`block_diffusion_visible`) in
+    place of both, every tile folded under the dense mask.
 
     Block sizes clamp to the (padded) sequence length; T is padded to a
     block multiple internally and the pad keys are masked out.
@@ -138,6 +162,13 @@ def blockwise_attention(
         raise ValueError(
             f"a window ({window}) is causal and at least 1 key wide"
         )
+    if block_diffusion is not None:
+        if window is not None or T != 2 * block_diffusion[0]:
+            raise ValueError(
+                f"block_diffusion=(L, B) lays out T = 2 L rows and has no "
+                f"window, got T={T}, {block_diffusion}, window={window}"
+            )
+        causal = False
     if Hkv != H:
         if Hkv <= 0 or H % Hkv:
             raise ValueError(
@@ -168,7 +199,7 @@ def blockwise_attention(
         v = jnp.pad(v, padding)
     single = functools.partial(
         _attend_single, causal=causal, bq=bq, bk=bk, t_real=T, window=window,
-        scale=scale,
+        scale=scale, block_diffusion=block_diffusion,
     )
     out = jax.vmap(jax.vmap(single))(q, k, v)
     return out[:, :, :T]

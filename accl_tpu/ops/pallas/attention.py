@@ -316,10 +316,84 @@ def _window_q_tiles(jk, b: int, nq: int, window):
 #: what a visited (q tile, k tile) pair has to compare, known from the
 #: tiles' distance ``d = iq - jk`` alone, in the order the counters list them
 TILE_CLASSES = ("interior", "diagonal", "edge", "padded")
+#: classes that are exactly one tile of a grid step where T is not padded
+_ONE_TILE = ("diagonal", "block", "strict", "lower")
+
+
+#: the classes under a block-diffusion layout (:func:`_layout_ranges`)
+LAYOUT_TILE_CLASSES = ("interior", "block", "strict", "lower", "padded")
+
+
+def _layout_ranges(y, n: int, b: int, block: int, noisy: bool, padded: bool,
+                   forward: bool):
+    """:func:`_tile_ranges` under a block-diffusion layout: the sequence
+    is a NOISY half and a CLEAN half of ``n`` tiles each (tiles ``0..n``
+    and ``n..2n``), both at positions ``0..L`` in blocks of ``block``.  A
+    noisy query sees the noisy keys of its own block and the clean keys
+    of the blocks before it; a clean query the clean keys of the blocks up
+    to its own; nothing else is live.  ``y`` is the step's tile WITHIN its
+    half (``noisy`` says which), the ranges are in whole-sequence tiles,
+    ascending, and hold exactly the tiles with a live pair:
+
+    * ``block < b`` (a tile holds whole blocks): the pairs of one tile
+      index are the masked ones, ``block`` (noisy on noisy: same block),
+      ``strict`` (noisy on clean: an earlier block) and ``lower`` (clean
+      on clean: no later block); the clean tiles before are ``interior``.
+      A noisy q tile folds its noisy tile, then the clean tiles up to its
+      own: two ranges that do not touch.
+    * ``block`` a multiple of ``b`` (a block holds ``m`` whole tiles):
+      every live tile is all true, ``interior``.
+
+    ``padded``: each half was padded up to the tiles and its last tile
+    holds that many real rows (0: no padding).  A pair whose q or k tile
+    is a half's last then compares everything, as the causal kernels'
+    padded class does (only ``block < b`` can need padding: the halves
+    are whole blocks); and where those rows are ONE block, the last noisy
+    tile has no earlier block in the clean tile of its index, which is
+    then not visited."""
+    if block < b:
+        if forward and noisy:
+            ranges = [(y, y + 1, "block", False), (n, n + y, "interior", False),
+                      (n + y, n + y + 1, "strict", False)]
+        elif forward:
+            ranges = [(n, n + y, "interior", False),
+                      (n + y, n + y + 1, "lower", False)]
+        elif noisy:
+            ranges = [(y, y + 1, "block", False)]
+        else:
+            ranges = [(y, y + 1, "strict", False), (y + 1, n, "interior", True),
+                      (n + y, n + y + 1, "lower", False),
+                      (n + y + 1, 2 * n, "interior", True)]
+    else:
+        m = block // b
+        g = y // m * m          # the first tile of y's block
+        if forward and noisy:
+            ranges = [(g, g + m, "interior", False),
+                      (n, n + g, "interior", False)]
+        elif forward:
+            ranges = [(n, n + g + m, "interior", False)]
+        elif noisy:
+            ranges = [(g, g + m, "interior", False)]
+        else:
+            ranges = [(g + m, n, "interior", False),
+                      (n + g, 2 * n, "interior", False)]
+    if not padded:
+        return [r[:3] for r in ranges]
+    last = y == n - 1
+    out = []
+    for start, stop, cls, tail in ranges:
+        if cls == "strict" and padded <= block:
+            stop = jnp.where(last, start, stop)
+        # what the step's own last tile pairs with, and (backward) a
+        # range's last q tile where that is a half's last
+        cut = stop - 1 if tail else stop
+        cut = jnp.where(last, start, jnp.clip(cut, start, stop))
+        out += [(start, cut, cls), (cut, stop, "padded")]
+    return out
 
 
 def _tile_ranges(y, n: int, b: int, causal: bool, window, padded: bool,
-                 forward: bool):
+                 forward: bool, layout=None):
     """The tiles a grid step visits (all ``n`` when not causal, else
     :func:`_window_k_tiles` in the forward and :func:`_window_q_tiles` in
     the backward), split into consecutive ``(start, stop, class)`` ranges,
@@ -339,7 +413,12 @@ def _tile_ranges(y, n: int, b: int, causal: bool, window, padded: bool,
     The forward meets them in the order edge, interior, diagonal (``d``
     falls as k tiles ascend), the backward in the order diagonal, interior,
     edge; the padded range closes both.  Traced and plain integers alike
-    (the kernels and :func:`flash_tile_classes` count by the same lines)."""
+    (the kernels and :func:`flash_tile_classes` count by the same lines).
+
+    ``layout=(noisy, block)`` is the block-diffusion geometry, which is
+    no band: :func:`_layout_ranges` (``y`` within its half of ``n``)."""
+    if layout is not None:
+        return _layout_ranges(y, n, b, layout[1], layout[0], padded, forward)
     lo, hi = 0, n
     if causal:
         lo, hi = (_window_k_tiles if forward else _window_q_tiles)(
@@ -391,31 +470,121 @@ def _tile_mask(cls: str, rc, d, b: int, causal: bool, window, pad):
     return functools.reduce(jnp.logical_and, terms) if terms else None
 
 
+def _block_index(pos, block: int):
+    """``pos // block`` of non-negative int32 positions: a shift where
+    ``block`` is a power of two (what Mosaic is sure to lower)."""
+    if block & (block - 1) == 0:
+        return lax.shift_right_logical(pos, block.bit_length() - 1)
+    return lax.div(pos, block)
+
+
+def _layout_mask(cls: str, bd, pad=(), bounds=None):
+    """The ``(b, b)`` mask of one tile pair under a block-diffusion layout
+    with whole blocks a tile, or ``None`` where it is all true.  ``bd`` is
+    ``row // block - col // block`` inside a tile, built once a grid step:
+    the q and k tiles of a masked pair hold the same positions, so the
+    blocks' distance is ``bd`` itself and a class is one compare of it
+    against 0.  A ``padded`` pair is of any kind: it compares ``bd`` with
+    the ``bounds`` (two scalars, from the pair's halves and whether the
+    tiles are the same) and the ``pad`` terms of the real length."""
+    if cls == "interior":
+        return None
+    if cls == "block":
+        return bd == 0
+    if cls == "strict":
+        return bd > 0
+    if cls == "lower":
+        return bd >= 0
+    lo, hi = bounds
+    return functools.reduce(
+        jnp.logical_and, [*pad, bd >= lo, bd <= hi]
+    )
+
+
+_FAR = 1 << 30
+
+
+def _layout_bounds(q_noisy, k_noisy, same):
+    """The ``(lo, hi)`` a padded pair compares ``bd`` with
+    (:func:`_layout_mask`): of the tiles at one index ``[0, 0]`` noisy on
+    noisy, ``[1, far)`` noisy on clean, ``[0, far)`` clean on clean;
+    everything where the k tile lies before.  Scalars, traced or not."""
+    lo = jnp.where(same, jnp.where(k_noisy, 0, jnp.where(q_noisy, 1, 0)),
+                   -_FAR)
+    return lo, jnp.where(k_noisy, 0, _FAR)
+
+
 def _fold_tiles(fold, carry, y, n: int, b: int, causal: bool, window,
-                padded: bool, forward: bool):
+                padded: bool, forward: bool, layout=None):
     """Fold the ranges of :func:`_tile_ranges` in their order, each by the
     body ``fold(class)`` traced for it.  Every range is a loop of a dynamic
     trip count (Mosaic lowers it to a while loop; the causal early exit and
     the window's bound are these trip counts) but the diagonal of an
     unpadded T, which is exactly the tile ``start``: folded in line, where
     its ``d`` is the literal 0 and its mask a constant the compiler folds
-    into the vregs that straddle the diagonal."""
-    ranges = _tile_ranges(y, n, b, causal, window, padded, forward)
+    into the vregs that straddle the diagonal; so are a layout's
+    ``block``, ``strict`` and ``lower`` tiles, one tile each."""
+    ranges = _tile_ranges(y, n, b, causal, window, padded, forward, layout)
     for start, stop, cls in ranges:
-        if cls == "diagonal" and not padded:
+        if cls in _ONE_TILE and not padded:
             carry = fold(cls)(start, carry)
         else:
             carry = lax.fori_loop(start, stop, fold(cls), carry)
     return carry
 
 
+def _layout_tile(L: int, block: int, dtype, tile: int) -> int:
+    """The flash kernels' tile under a block-diffusion layout of halves
+    of ``L`` in blocks of ``block``: :func:`_flash_block` of a HALF (the
+    halves are padded to the tiles apart, so no tile straddles them).
+    Refuses, by name, the layouts the kernels' ranges do not cover."""
+    b = _flash_block(L, dtype, tile)
+    if block < 1 or L % block:
+        raise ValueError(
+            f"block_diffusion: the block length ({block}) must divide the "
+            f"half's length ({L})"
+        )
+    if not (block < b and b % block == 0) and block % b:
+        raise ValueError(
+            f"block_diffusion: the flash kernels take a block length that "
+            f"divides their tile or is a multiple of it, got blocks of "
+            f"{block} under tiles of {b} (the naive and blockwise forms "
+            "take any)"
+        )
+    return b
+
+
 def flash_tile_classes(T: int, block: int = 512, window=None,
-                       dtype=jnp.bfloat16) -> dict:
+                       dtype=jnp.bfloat16, block_diffusion=None,
+                       forward: bool = True) -> dict:
     """How many of the (q tile, k tile) pairs the causal flash kernels
     visit for one head fall into each of :data:`TILE_CLASSES`, from the
     shapes and by the kernels' own ranges (:func:`_tile_ranges`; forward
     and backward agree): at T=8192 in tiles of 512, 120 interior and 16
-    diagonal, under a window of 2048 42, 16 and 12 window-edge ones."""
+    diagonal, under a window of 2048 42, 16 and 12 window-edge ones.
+
+    ``block_diffusion=(L, B)`` (``T = 2 L``): the pairs under that layout
+    by :data:`LAYOUT_TILE_CLASSES`; at L=4096, B=4 in tiles of 512, 56
+    interior, 8 ``block``, 8 ``strict`` and 8 ``lower`` of 80, every one
+    with a live pair and no other tile with one.  ``forward=False`` counts
+    by the backward's lists (k tile major)."""
+    if block_diffusion is not None:
+        L, B = block_diffusion
+        if T != 2 * L or window is not None:
+            raise ValueError(
+                f"block_diffusion=(L, B) lays out T = 2 L rows and has no "
+                f"window, got T={T}, L={L}, window={window}"
+            )
+        b = _layout_tile(L, B, dtype, block)
+        n = -(-L // b)
+        counts = dict.fromkeys(LAYOUT_TILE_CLASSES, 0)
+        for noisy in (True, False):
+            for y in range(n):
+                for start, stop, cls in _tile_ranges(
+                    y, n, b, False, None, L % b, forward, (noisy, B)
+                ):
+                    counts[cls] += int(stop) - int(start)
+        return counts
     b = _flash_block(T, dtype, block)
     n = -(-T // b)
     if window is not None and window >= T:
@@ -423,23 +592,25 @@ def flash_tile_classes(T: int, block: int = 512, window=None,
     counts = dict.fromkeys(TILE_CLASSES, 0)
     for iq in range(n):
         for start, stop, cls in _tile_ranges(
-            iq, n, b, True, window, T % b != 0, forward=True
+            iq, n, b, True, window, T % b != 0, forward=forward
         ):
             counts[cls] += int(stop) - int(start)
     return counts
 
 
 def flash_tile_pairs(T: int, block: int = 512, window=None,
-                     dtype=jnp.bfloat16) -> int:
+                     dtype=jnp.bfloat16, block_diffusion=None) -> int:
     """How many (q tile, k tile) pairs the causal flash kernels visit for
     one head of a ``T``-long sequence, from the shapes and by the kernels'
     own bounds: 136 at T=8192 in tiles of 512, 70 of them under a window
-    of 2048."""
-    return sum(flash_tile_classes(T, block, window, dtype).values())
+    of 2048, 80 under ``block_diffusion=(4096, 4)``."""
+    return sum(
+        flash_tile_classes(T, block, window, dtype, block_diffusion).values()
+    )
 
 
 def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
-                  window=None, rope=False):
+                  window=None, rope=False, layout=None):
     """One grid step computes one (bq, Dv) output block: fold the visiting
     k/v blocks with online softmax.  Outputs are written exactly once per
     grid step (blocked o spec): every grid axis of the FORWARD is
@@ -461,9 +632,19 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
 
     ``rope``: two more operands follow v, a second part of q and of k
     (``flash_attention``'s ``q_rope`` / ``k_rope``) whose product is
-    added into the same score tile before the scale."""
+    added into the same score tile before the scale.
 
-    padded = t_real < nkb * bk
+    ``layout``: the block length of a block-diffusion layout
+    (:func:`_layout_ranges`; ``nkb`` tiles in two halves, ``t_real`` a
+    half's real length).  The two halves' q tiles fold different lists, so
+    the step branches once on its half and each branch is traced with its
+    own ranges and its own in-line masked tiles."""
+
+    halves = 1 if layout is None else 2
+    nh = nkb // halves
+    padded = t_real < nh * bk
+    if layout is not None:
+        padded = t_real % bk   # the real rows of a half's last tile
 
     def kernel(q_ref, k_ref, v_ref, *rest):
         if rope:
@@ -476,9 +657,34 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
         q = q_ref[0]  # (bq, D)
         # q_pos - k_pos of the pair (iq, j) is (iq - j) * bq + rc
         k_col = lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        rc = lax.broadcasted_iota(jnp.int32, (bq, bk), 0) - k_col
+        if layout is None:
+            rc = lax.broadcasted_iota(jnp.int32, (bq, bk), 0) - k_col
+        else:
+            # the blocks' distance of a pair of tiles at one index
+            bd = _block_index(
+                lax.broadcasted_iota(jnp.int32, (bq, bk), 0), layout
+            ) - _block_index(k_col, layout)
 
-        def fold(cls):
+        def causal_mask(cls, j):
+            return _tile_mask(
+                cls, rc, iq - j, bq, causal, window,
+                [k_col < t_real - j * bk] if cls == "padded" else [],
+            )
+
+        def layout_mask(noisy, i):
+            def mask_of(cls, j):
+                if cls != "padded":
+                    return _layout_mask(cls, bd)
+                k_noisy = j < nh
+                jh = jnp.where(k_noisy, j, j - nh)
+                return _layout_mask(
+                    cls, bd, [k_col < t_real - jh * bk],
+                    _layout_bounds(noisy, k_noisy, jh == i),
+                )
+
+            return mask_of
+
+        def fold(cls, mask_of=causal_mask):
             def body(j, carry):
                 m, l, acc = carry
                 kb = k_ref[0, pl.ds(j * bk, bk), :]
@@ -497,10 +703,7 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
                         precision=_mxu_precision(qr.dtype),
                     )
                 s = s * scale
-                mask = _tile_mask(
-                    cls, rc, iq - j, bq, causal, window,
-                    [k_col < t_real - j * bk] if cls == "padded" else [],
-                )
+                mask = mask_of(cls, j)
                 if mask is not None:
                     s = jnp.where(mask, s, _NEG)
                 m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
@@ -527,17 +730,32 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
         # keys in the first visited tile are all outside the window folds
         # that tile at m = _NEG, and the next tile's alpha = exp(_NEG - m)
         # = 0 wipes it exactly; its own diagonal tile always comes.
-        m, l, acc = _fold_tiles(
-            fold, init, iq, nkb, bq, causal, window, padded, forward=True
-        )
-        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        if with_lse:
-            # (bq, 1) sublane vector -> (bq,) lane vector: an explicit
-            # relayout Mosaic supports; rows beyond t_real carry ~-1e30
-            # and are masked out by the backward kernel
-            maybe_lse[0][0, 0, 0] = (
-                m + jnp.log(jnp.maximum(l, 1e-30))
-            ).reshape(bq)
+        def write(m, l, acc):
+            o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            if with_lse:
+                # (bq, 1) sublane vector -> (bq,) lane vector: an explicit
+                # relayout Mosaic supports; rows beyond t_real carry ~-1e30
+                # and are masked out by the backward kernel
+                maybe_lse[0][0, 0, 0] = (
+                    m + jnp.log(jnp.maximum(l, 1e-30))
+                ).reshape(bq)
+
+        if layout is None:
+            write(*_fold_tiles(
+                fold, init, iq, nkb, bq, causal, window, padded, forward=True
+            ))
+            return
+
+        def half(noisy):
+            i = iq if noisy else iq - nh
+            masks = layout_mask(noisy, i)
+            write(*_fold_tiles(
+                lambda cls: fold(cls, masks), init, i, nh, bq, False, None,
+                padded, forward=True, layout=(noisy, layout),
+            ))
+
+        pl.when(iq < nh)(functools.partial(half, True))
+        pl.when(iq >= nh)(functools.partial(half, False))
 
     return kernel
 
@@ -576,16 +794,55 @@ def _default_scale(q, q_rope=None) -> float:
     return 1.0 / (D ** 0.5)
 
 
-def _pad_rows_lanes(padT: int, *arrays):
-    """``arrays`` (B, H, T, D) with ``padT`` more rows and each last dim
-    padded up to the lanes: an operand is as wide as what IT holds."""
+def _pad_rows(a, padT: int, halves: int = 1, value=0):
+    """``a`` (B, H, T, ...) with ``padT`` more rows behind each of its
+    ``halves`` (a block-diffusion layout's two are padded apart)."""
+    if not padT:
+        return a
+    B, H, T = a.shape[:3]
+    a = a.reshape(B, H, halves, T // halves, *a.shape[3:])
+    a = jnp.pad(
+        a, [(0, 0)] * 3 + [(0, padT)] + [(0, 0)] * (a.ndim - 4),
+        constant_values=value,
+    )
+    return a.reshape(B, H, T + halves * padT, *a.shape[4:])
+
+
+def _unpad_rows(a, T: int, halves: int = 1, axis: int = 2):
+    """The first ``T / halves`` of each half of ``a``'s ``axis`` (of
+    ``Tp`` padded rows)."""
+    Tp = a.shape[axis]
+    if Tp == T:
+        return a
+    lead, rest = a.shape[:axis], a.shape[axis + 1:]
+    a = a.reshape(*lead, halves, Tp // halves, *rest)
+    a = lax.slice_in_dim(a, 0, T // halves, axis=axis + 1)
+    return a.reshape(*lead, T, *rest)
+
+
+def _pad_rows_lanes(padT: int, *arrays, halves: int = 1):
+    """``arrays`` (B, H, T, D) with ``padT`` more rows (behind each of
+    ``halves``) and each last dim padded up to the lanes: an operand is as
+    wide as what IT holds."""
     out = []
     for a in arrays:
         padD = (-a.shape[-1]) % LANES
-        if padT or padD:
-            a = jnp.pad(a, [(0, 0), (0, 0), (0, padT), (0, padD)])
-        out.append(a)
+        if padD:
+            a = jnp.pad(a, [(0, 0), (0, 0), (0, 0), (0, padD)])
+        out.append(_pad_rows(a, padT, halves))
     return out
+
+
+def _tiling(T: int, dtype, block: int, layout):
+    """``(tile, rows padded behind each half, halves, a half's length,
+    the kernels' ``layout``)`` for a sequence of ``T``: under a
+    block-diffusion ``layout=(L, B)`` the tile is a half's."""
+    if layout is None:
+        b = _flash_block(T, dtype, block)
+        return b, (-T) % b, 1, T, None
+    L, B = layout
+    b = _layout_tile(L, B, dtype, block)
+    return b, (-L) % b, 2, L, B
 
 
 def _flat_heads(a):
@@ -594,16 +851,16 @@ def _flat_heads(a):
 
 
 def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse,
-                    window=None, scale=None, q_rope=None, k_rope=None):
+                    window=None, scale=None, q_rope=None, k_rope=None,
+                    layout=None):
     B, H, T, D = q.shape
     Hkv, Dv = k.shape[1], v.shape[-1]
     rope = q_rope is not None
     if scale is None:
         scale = _default_scale(q, q_rope)
-    b = _flash_block(T, q.dtype, block)
-    padT = (-T) % b
-    q, k, v = _pad_rows_lanes(padT, q, k, v)
-    Tp, Dp, Dvp = T + padT, q.shape[-1], v.shape[-1]
+    b, padT, halves, t_real, layout = _tiling(T, q.dtype, block, layout)
+    q, k, v = _pad_rows_lanes(padT, q, k, v, halves=halves)
+    Tp, Dp, Dvp = q.shape[2], q.shape[-1], v.shape[-1]
     nq = nkb = Tp // b
 
     qf, kf, vf = _flat_heads(q), _flat_heads(k), _flat_heads(v)
@@ -635,7 +892,7 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse,
     if rope:
         # the second part of q a head, and of k as few heads as it has
         # (one under MLA), each shared through the index map
-        q_rope, k_rope = _pad_rows_lanes(padT, q_rope, k_rope)
+        q_rope, k_rope = _pad_rows_lanes(padT, q_rope, k_rope, halves=halves)
         Drp = q_rope.shape[-1]
         operands += [_flat_heads(q_rope), _flat_heads(k_rope)]
         in_specs += [
@@ -646,8 +903,8 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse,
         ]
 
     res = pl.pallas_call(
-        _flash_kernel(causal, scale, b, b, nkb, T, with_lse=with_lse,
-                      window=window, rope=rope),
+        _flash_kernel(causal, scale, b, b, nkb, t_real, with_lse=with_lse,
+                      window=window, rope=rope, layout=layout),
         grid=(B * H, nq),
         out_shape=out_shape,
         in_specs=in_specs,
@@ -655,15 +912,15 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse,
         interpret=default_interpret(interpret),
         name="flash_fwd",
     )(*operands)
-    out = res[0].reshape(B, H, Tp, Dvp)[:, :, :T, :Dv]
+    out = _unpad_rows(res[0].reshape(B, H, Tp, Dvp), T, halves)[..., :Dv]
     if not with_lse:
         return out, None
-    lse = res[1].reshape(B, H, Tp)[:, :, :T]  # (B*H, nq, 1, b) -> rows
-    return out, lse
+    # (B*H, nq, 1, b) -> rows
+    return out, _unpad_rows(res[1].reshape(B, H, Tp), T, halves)
 
 
 def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None,
-                      rope=False):
+                      rope=False, layout=None):
     """The whole backward of one (k tile, q tile) pair, once: grid step
     (bh, jk) owns one (bk, D) dk + (bk, Dv) dv block pair and folds the q
     blocks that attended to it (causal: q blocks jk..nq-1, a dynamic lower
@@ -686,9 +943,18 @@ def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None,
     ``q_rope`` / ``k_rope``), so a pair has eight products: one more into
     the score tile, and ``ds`` into that part's dk block and dq
     accumulator, which follow the first part's as operands, outputs and
-    scratch."""
+    scratch.
 
-    padded = t_real < nq * bq
+    ``layout``: as in :func:`_flash_kernel`; the step branches on its k
+    tile's half and folds the q tiles of :func:`_layout_ranges`' backward
+    lists (a clean k tile's in two ranges, the noisy and the clean q
+    tiles that see it)."""
+
+    halves = 1 if layout is None else 2
+    nh = nq // halves
+    padded = t_real < nh * bq
+    if layout is not None:
+        padded = t_real % bq   # the real rows of a half's last tile
 
     def kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, *rest):
         if rope:
@@ -711,9 +977,33 @@ def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None,
         # q_pos - k_pos of the pair (i, jk) is (i - jk) * bq + rc
         q_row = lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_col = lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        rc = q_row - k_col
+        if layout is None:
+            rc = q_row - k_col
+        else:
+            bd = _block_index(q_row, layout) - _block_index(k_col, layout)
 
-        def fold(cls):
+        def causal_mask(cls, i):
+            return _tile_mask(
+                cls, rc, i - jk, bq, causal, window,
+                [k_col < t_real - jk * bk, q_row < t_real - i * bq]
+                if cls == "padded" else [],
+            )
+
+        def layout_mask(noisy, j):
+            def mask_of(cls, i):
+                if cls != "padded":
+                    return _layout_mask(cls, bd)
+                q_noisy = i < nh
+                ih = jnp.where(q_noisy, i, i - nh)
+                return _layout_mask(
+                    cls, bd,
+                    [k_col < t_real - j * bk, q_row < t_real - ih * bq],
+                    _layout_bounds(q_noisy, noisy, ih == j),
+                )
+
+            return mask_of
+
+        def fold(cls, mask_of=causal_mask):
             def body(i, carry):
                 dk, dv, *dkr = carry
                 rows = pl.ds(i * bq, bq)
@@ -737,11 +1027,7 @@ def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None,
                     )
                 s = s * scale
                 p = jnp.exp(s - lse)
-                mask = _tile_mask(
-                    cls, rc, i - jk, bq, causal, window,
-                    [k_col < t_real - jk * bk, q_row < t_real - i * bq]
-                    if cls == "padded" else [],
-                )
+                mask = mask_of(cls, i)
                 if mask is not None:
                     # explicit where: padded q rows have lse ~ -1e30, where
                     # a bare exp(s - lse) would resurrect them as p = 1
@@ -787,14 +1073,28 @@ def _flash_bwd_kernel(causal, scale, bq, bk, nq, t_real, window=None,
                 jnp.zeros((bk, vb.shape[-1]), jnp.float32))
         if rope:
             init += (jnp.zeros((bk, krb.shape[-1]), jnp.float32),)
+        def write(dk, dv, *dkr):
+            dk_ref[0] = dk.astype(dk_ref.dtype)
+            dv_ref[0] = dv.astype(dv_ref.dtype)
+            if rope:
+                dkr_ref[0] = dkr[0].astype(dkr_ref.dtype)
+
+        def half(noisy):
+            j = jk if noisy else jk - nh
+            masks = layout_mask(noisy, j)
+            write(*_fold_tiles(
+                lambda cls: fold(cls, masks), init, j, nh, bq, False, None,
+                padded, forward=False, layout=(noisy, layout),
+            ))
+
         # bq == bk
-        dk, dv, *dkr = _fold_tiles(
-            fold, init, jk, nq, bq, causal, window, padded, forward=False,
-        )
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dv.astype(dv_ref.dtype)
-        if rope:
-            dkr_ref[0] = dkr[0].astype(dkr_ref.dtype)
+        if layout is None:
+            write(*_fold_tiles(
+                fold, init, jk, nq, bq, causal, window, padded, forward=False,
+            ))
+        else:
+            pl.when(jk < nh)(functools.partial(half, True))
+            pl.when(jk >= nh)(functools.partial(half, False))
 
         @pl.when(jk == pl.num_programs(1) - 1)
         def _():
@@ -832,23 +1132,21 @@ def _flash_bwd_vmem_bytes(Tp: int, Dp: int, b: int, itemsize: int,
 
 
 def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
-                    window=None, scale=None, q_rope=None, k_rope=None):
+                    window=None, scale=None, q_rope=None, k_rope=None,
+                    layout=None):
     B, H, T, D = q.shape
     Hkv, Dv = k.shape[1], v.shape[-1]
     rope = q_rope is not None
     if scale is None:
         scale = _default_scale(q, q_rope)
-    b = _flash_block(T, q.dtype, block)
-    padT = (-T) % b
+    b, padT, halves, t_real, layout = _tiling(T, q.dtype, block, layout)
     # delta = rowsum(dO * O): the softmax-transpose correction, a cheap
     # fused elementwise+reduce XLA does well — no kernel needed
     delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
-    q, k, v, g = _pad_rows_lanes(padT, q, k, v, g)
-    if padT:
-        rows = [(0, 0), (0, 0), (0, padT)]
-        lse = jnp.pad(lse, rows, constant_values=_NEG)
-        delta = jnp.pad(delta, rows)
-    Tp, Dp, Dvp = T + padT, q.shape[-1], v.shape[-1]
+    q, k, v, g = _pad_rows_lanes(padT, q, k, v, g, halves=halves)
+    lse = _pad_rows(lse, padT, halves, value=_NEG)
+    delta = _pad_rows(delta, padT, halves)
+    Tp, Dp, Dvp = q.shape[2], q.shape[-1], v.shape[-1]
     nq = nkb = Tp // b
 
     qf, kf, vf, dof = (_flat_heads(a) for a in (q, k, v, g))
@@ -885,7 +1183,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
     Drp = 0
     if rope:
         Dr, Hr = q_rope.shape[-1], k_rope.shape[1]
-        q_rope, k_rope = _pad_rows_lanes(padT, q_rope, k_rope)
+        q_rope, k_rope = _pad_rows_lanes(padT, q_rope, k_rope, halves=halves)
         Drp = q_rope.shape[-1]
         operands += [_flat_heads(k_rope), _flat_heads(q_rope)]
         in_specs += [tile(Drp, Hr), whole(Drp)]
@@ -894,7 +1192,8 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
         scratch.append(pltpu.VMEM((Tp, Drp), jnp.float32))
     resident = _flash_bwd_vmem_bytes(Tp, Dp, b, q.dtype.itemsize, Dvp, Drp)
     dq, dk, dv, *rope_grads = pl.pallas_call(
-        _flash_bwd_kernel(causal, scale, b, b, nq, T, window, rope=rope),
+        _flash_bwd_kernel(causal, scale, b, b, nq, t_real, window, rope=rope,
+                          layout=layout),
         grid=(B * H, nkb),
         out_shape=out_shape,
         in_specs=in_specs,
@@ -911,43 +1210,47 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
     def group_sum(a, heads, width):
         """Per-q-head blocks summed onto the ``heads`` that share them,
         at their real ``width``."""
-        a = a.reshape(B, heads, H // heads, Tp, a.shape[-1])[:, :, :, :T, :width]
+        a = a.reshape(B, heads, H // heads, Tp, a.shape[-1])
+        a = _unpad_rows(a, T, halves, axis=3)[..., :width]
         if H == heads:
             return a[:, :, 0]
         return a.astype(jnp.float32).sum(2).astype(k.dtype)
 
-    grads = (dq.reshape(B, H, Tp, Dp)[:, :, :T, :D],
-             group_sum(dk, Hkv, D), group_sum(dv, Hkv, Dv))
+    def rows_of(a, width):
+        return _unpad_rows(a.reshape(B, H, Tp, a.shape[-1]), T, halves)[
+            ..., :width
+        ]
+
+    grads = (rows_of(dq, D), group_sum(dk, Hkv, D), group_sum(dv, Hkv, Dv))
     if not rope:
         return grads + (None, None)
     dqr, dkr = rope_grads
-    return grads + (
-        dqr.reshape(B, H, Tp, Drp)[:, :, :T, :Dr], group_sum(dkr, Hr, Dr),
-    )
+    return grads + (rows_of(dqr, Dr), group_sum(dkr, Hr, Dr))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_vjp(q, k, v, q_rope, k_rope, causal, block, interpret,
-                    window, scale):
-    """``scale`` and the scores' second part may each be ``None``."""
+                    window, scale, layout):
+    """``scale``, the scores' second part and the block-diffusion
+    ``layout`` may each be ``None``."""
     out, _ = _flash_fwd_impl(q, k, v, causal, block, interpret,
                              with_lse=False, window=window, scale=scale,
-                             q_rope=q_rope, k_rope=k_rope)
+                             q_rope=q_rope, k_rope=k_rope, layout=layout)
     return out
 
 
 def _flash_vjp_fwd(q, k, v, q_rope, k_rope, causal, block, interpret,
-                        window, scale):
+                        window, scale, layout):
     out, lse = _flash_fwd_impl(q, k, v, causal, block, interpret,
                                with_lse=True, window=window, scale=scale,
-                               q_rope=q_rope, k_rope=k_rope)
+                               q_rope=q_rope, k_rope=k_rope, layout=layout)
     return out, (q, k, v, q_rope, k_rope, out, lse)
 
 
-def _flash_vjp_bwd(causal, block, interpret, window, scale, res, g):
+def _flash_vjp_bwd(causal, block, interpret, window, scale, layout, res, g):
     q, k, v, q_rope, k_rope, o, lse = res
     return _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
-                           window, scale, q_rope, k_rope)
+                           window, scale, q_rope, k_rope, layout)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -964,6 +1267,7 @@ def flash_attention(
     scale: float | None = None,
     q_rope: jax.Array | None = None,
     k_rope: jax.Array | None = None,
+    block_diffusion: tuple | None = None,
     interpret: InterpretArg = None,
 ) -> jax.Array:
     """Local (single-chip) fused attention: ``(B, H, T, D) -> same`` with
@@ -1016,6 +1320,22 @@ def flash_attention(
     latent attention: 128 + 64 columns scored, 128 of values, ONE rope
     key head for 128 query heads, never expanded in HBM).
 
+    ``block_diffusion=(L, B)`` is the layout of block-diffusion training
+    (BD3-LM's), in place of ``causal`` and a window: the ``T = 2 L`` rows
+    are a NOISY copy of a sequence and then the CLEAN one, both at
+    positions ``0..L`` in blocks of ``B``.  A noisy query sees the noisy
+    keys of its own block (both ways) and the clean keys of the blocks
+    strictly before; a clean query the clean keys of the blocks up to and
+    including its own; no query sees a noisy key outside its block.  The
+    kernels visit exactly the tile pairs that hold a live (q, k) pair
+    (:func:`_layout_ranges`; 80 of 256 at L=4096, B=4 in tiles of 512, 56
+    of them with no mask work), a noisy q tile in two ranges that do not
+    touch, the backward by the transposed lists; the noisy-on-noisy tiles
+    are a class of the kernel.  ``B`` divides the tile or is a multiple of
+    it (anything else is refused by name; the naive and blockwise forms
+    take any), an ``L`` off the tiles pads each half apart, and GQA, the
+    two widths and the second score part work as without it.
+
     ``block=512`` is the measured optimum on v5e at T=4096: vs 256 the
     forward runs 2.1x faster (40.7 vs 19.6 TFLOPs) and the full T=4096
     train step gains 6.9 MFU points (62.1% -> 69.0%, A/B on the bench's
@@ -1055,6 +1375,18 @@ def flash_attention(
             )
     require_mosaic_dtypes(default_interpret(interpret), "flash attention",
                           q.dtype)
+    if block_diffusion is not None:
+        L, B_len = (int(n) for n in block_diffusion)
+        if window is not None or T != 2 * L:
+            raise ValueError(
+                f"block_diffusion=(L, B) lays out T = 2 L rows and has no "
+                f"window, got T={T}, L={L}, window={window}"
+            )
+        _layout_tile(L, B_len, q.dtype, block)   # refuses what it cannot
+        return _flash_vjp(
+            q, k, v, q_rope, k_rope, False, block, interpret, None,
+            None if scale is None else float(scale), (L, B_len),
+        )
     if window is not None:
         if not causal or window < 1:
             raise ValueError(
@@ -1064,5 +1396,5 @@ def flash_attention(
             window = None  # every earlier key is inside it
     return _flash_vjp(
         q, k, v, q_rope, k_rope, causal, block, interpret, window,
-        None if scale is None else float(scale),
+        None if scale is None else float(scale), None,
     )

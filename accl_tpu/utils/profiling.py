@@ -112,9 +112,19 @@ drops ``op_name``, so a reader joins the two by instruction name
                          five projections, the two latent norms, the rope
 ``accl.attn::mla``       the same: the score/softmax/value core (the flash
                          kernels with two widths and ONE rope key head)
+``accl.attn::blockdiff`` ``_attn_partial`` under ``TransformerConfig.
+                         diffusion``: the attention call on ``[noisy ;
+                         clean]`` under the block-diffusion layout (the
+                         flash kernels by its tile lists, or the XLA forms
+                         under the dense mask)
+``accl.diffusion::noise`` ``diffusion_noise``: the ids' noising inside the
+                         step (a level a block, a mask a position)
+``accl.loss::diffusion`` ``_diffusion_loss``: the head and the
+                         ``1 / t``-weighted loss of the noisy half
 ``accl.moe::route``      ``models/moe.py``, dropless path: router matmul,
                          float32 softmax, (group-limited) top-k, the
-                         balance losses
+                         balance losses, the Switch term under held
+                         experts (``switch_balance``)
 ``accl.moe::dispatch``   the same: sort of the routing entries by expert,
                          group sizes, gather of the rows
 ``accl.moe::experts``    the same: the three grouped matmuls and the gate
@@ -132,6 +142,14 @@ drops ``op_name``, so a reader joins the two by instruction name
                          (``_onehot_wins``), with what the compiler fuses
                          behind it (the table's SGD update)
 ======================== ==================================================
+
+Counters of the same paths, from the program and not from a trace: the
+train step under ``TransformerConfig.diffusion`` returns ``masked_tokens``
+(the positions its noise masked) and, under held experts,
+``held_entries`` a layer; ``ops.pallas.attention.flash_tile_classes``
+counts the tile pairs the flash kernels visit by class from the shapes,
+under ``block_diffusion`` too (80 at L = 4096, blocks of 4: 56 interior,
+8 ``block``, 8 ``strict``, 8 ``lower``).
 
 jax is imported LAZILY: the emulator/native tiers (and the telemetry
 plane's exporters) run in jax-free processes, and pulling a device

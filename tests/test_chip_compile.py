@@ -91,6 +91,28 @@ def test_flash_kernels_compile_at_the_other_cells_widths(one_chip, case):
     assert "flash_fwd" in text and "flash_bwd" in text
 
 
+@pytest.mark.parametrize("block", [4, 1024])
+def test_flash_kernels_compile_under_the_block_diffusion_layout(one_chip, block):
+    """``train_sdar_t4096_b2``'s core: 32 query heads of 128 on 4 KV heads,
+    ``[noisy ; clean]`` of L = 4,096 (T = 8,192, the last length the
+    kernels take), blocks of 4 (masked tiles in line, a noisy q tile's two
+    ranges) and of 1,024 (two tiles a block, nothing masked); the
+    backward's VMEM sum is the causal T = 8,192's, 25 MiB."""
+    from accl_tpu.ops.pallas import attention as fa
+
+    L = 4096
+    text = _compile(
+        _loss(lambda q, k, v: fa.flash_attention(
+            q, k, v, block_diffusion=(L, block), interpret=False
+        )),
+        [(1, 32, 2 * L, 128), (1, 4, 2 * L, 128), (1, 4, 2 * L, 128)],
+        one_chip,
+    )
+    assert "flash_fwd" in text and "flash_bwd" in text
+    assert fa._flash_bwd_vmem_bytes(2 * L, 128, 512, 2) == 25 * 2**20
+    assert fa.flash_tile_pairs(2 * L, block_diffusion=(L, block)) == 80
+
+
 def test_grouped_matmuls_compile_at_deepseek_v2_widths(one_chip):
     """20 held experts of 5120 x 1536 over the cell's 6,144 buffer rows:
     tiles that divide 5120 and 1536 (``grouped_matmul.tiles``)."""

@@ -1656,3 +1656,202 @@ def test_flash_attention_rope_parts_validate_and_other_lowerings_agree():
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5,
                 err_msg=impl)
+
+
+# -- the block-diffusion layout (PR 38) ---------------------------------------
+
+
+def _block_diffusion_naive(q, k, v, L, B, scale=None, q_rope=None, k_rope=None):
+    """Materialised scores under the dense mask of
+    ``ops.attention.block_diffusion_visible``, float32."""
+    from accl_tpu.ops.attention import block_diffusion_visible
+
+    Bn, H, T, D = q.shape
+    Hkv = k.shape[1]
+    if q_rope is not None:
+        expand = lambda t: jnp.repeat(t, Hkv // t.shape[1], axis=1)
+        q = jnp.concatenate([q, q_rope], axis=-1)
+        k = jnp.concatenate([k, expand(k_rope)], axis=-1)
+    qg = q.astype(jnp.float32).reshape(Bn, Hkv, H // Hkv, T, q.shape[-1])
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k.astype(jnp.float32)) * (
+        q.shape[-1] ** -0.5 if scale is None else scale
+    )
+    pos = jnp.arange(T)
+    mask = block_diffusion_visible(pos[:, None], pos[None, :], L, B)
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32))
+    return out.reshape(Bn, H, T, v.shape[-1])
+
+
+def _live_tiles(L, B, b):
+    """Brute force: which (q tile, k tile) pairs of the padded halves hold
+    a live (query, key) pair."""
+    from accl_tpu.ops.attention import block_diffusion_visible
+
+    n = -(-L // b)
+    half = n * b
+    pos = np.arange(2 * half)
+    real = (pos % half) < L
+    rows = np.where(pos < half, pos, pos - half + L)   # the unpadded index
+    live = np.asarray(
+        block_diffusion_visible(rows[:, None], rows[None, :], L, B)
+    ) & real[:, None] & real[None, :]
+    return live.reshape(2 * n, b, 2 * n, b).any(axis=(1, 3))
+
+
+#: name -> (L, block length, tile, q heads, kv heads, dtype)
+_BLOCK_DIFFUSION_CASES = {
+    "b4": (64, 4, 16, 2, 2, jnp.float32),
+    "b1": (64, 1, 16, 2, 2, jnp.float32),
+    "b32_two_tiles_a_block": (64, 32, 16, 2, 2, jnp.float32),
+    "b32_half_a_tile": (64, 32, 64, 2, 2, jnp.float32),
+    "one_block": (64, 64, 16, 2, 2, jnp.float32),
+    "padded_L": (40, 4, 16, 2, 2, jnp.float32),
+    "padded_L_one_block_in_the_last_tile": (56, 8, 16, 2, 2, jnp.float32),
+    "gqa_8_to_1": (48, 4, 16, 8, 1, jnp.float32),
+    "bf16_padded": (40, 4, 16, 4, 2, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_BLOCK_DIFFUSION_CASES))
+def test_flash_attention_block_diffusion_fwd_and_grads_match_the_dense_mask(case):
+    """The flash kernels (interpreted) under ``block_diffusion=(L, B)``
+    against the naive dense mask: the output and all three gradients."""
+    L, B, tile, H, Hkv, dtype = _BLOCK_DIFFUSION_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    shape = lambda h: (2, h, 2 * L, 32)
+    q = jax.random.normal(ks[0], shape(H), dtype)
+    k = jax.random.normal(ks[1], shape(Hkv), dtype)
+    v = jax.random.normal(ks[2], shape(Hkv), dtype)
+    w = jax.random.normal(ks[3], shape(H), jnp.float32)
+
+    def flash(q, k, v):
+        return pk.flash_attention(q, k, v, block=tile, block_diffusion=(L, B))
+
+    want = _block_diffusion_naive(q, k, v, L, B)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32), want, atol=tol, rtol=tol
+    )
+    got = jax.grad(
+        lambda *a: (flash(*a).astype(jnp.float32) * w).sum(), (0, 1, 2)
+    )(q, k, v)
+    ref = jax.grad(
+        lambda *a: (_block_diffusion_naive(*a, L, B) * w).sum(), (0, 1, 2)
+    )(q, k, v)
+    for g, r in zip(got, ref):
+        scale = float(jnp.abs(r.astype(jnp.float32)).max())
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(r, np.float32),
+            atol=tol * scale, rtol=tol,
+        )
+
+
+def test_flash_attention_block_diffusion_takes_two_widths_and_a_rope_part():
+    """PR 34's forms under the layout: v of another width, a caller's
+    scale, a second score part on ONE shared key head."""
+    L, B = 32, 4
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    q = jax.random.normal(ks[0], (1, 4, 2 * L, 32))
+    k = jax.random.normal(ks[1], (1, 4, 2 * L, 32))
+    v = jax.random.normal(ks[2], (1, 4, 2 * L, 16))
+    qr = jax.random.normal(ks[3], (1, 4, 2 * L, 8))
+    kr = jax.random.normal(ks[4], (1, 1, 2 * L, 8))
+    w = jax.random.normal(ks[5], (1, 4, 2 * L, 16))
+    flash = lambda *a: pk.flash_attention(
+        *a[:3], block=16, scale=0.2, q_rope=a[3], k_rope=a[4],
+        block_diffusion=(L, B),
+    )
+    naive = lambda *a: _block_diffusion_naive(
+        *a[:3], L, B, scale=0.2, q_rope=a[3], k_rope=a[4]
+    )
+    args = (q, k, v, qr, kr)
+    np.testing.assert_allclose(flash(*args), naive(*args), atol=2e-5)
+    got = jax.grad(lambda *a: (flash(*a) * w).sum(), range(5))(*args)
+    ref = jax.grad(lambda *a: (naive(*a) * w).sum(), range(5))(*args)
+    for g, r in zip(got, ref):
+        np.testing.assert_allclose(g, r, atol=5e-5)
+
+
+def test_flash_attention_block_diffusion_refuses_by_name_and_the_xla_forms_take_any():
+    """A block length that neither divides the tile nor is a multiple of
+    it, a window, a T that is not 2 L: refused by name.  The naive and the
+    blockwise form take any block length."""
+    from accl_tpu.models.transformer import _attention
+    from accl_tpu.ops.attention import blockwise_attention
+
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 96, 32))
+    for kw, msg in (
+        (dict(block_diffusion=(48, 6)), "divides their tile or is a multiple"),
+        (dict(block_diffusion=(48, 5)), "must divide the half"),
+        (dict(block_diffusion=(48, 4), window=8), "has no window"),
+        (dict(block_diffusion=(40, 4)), "T = 2 L"),
+    ):
+        with pytest.raises(ValueError, match="block_diffusion.*" + msg):
+            pk.flash_attention(q, q, q, block=16, **kw)
+    with pytest.raises(ValueError, match="block_diffusion"):
+        pk.flash_tile_pairs(96, 16, dtype=jnp.float32, block_diffusion=(48, 6))
+    want = _block_diffusion_naive(q, q, q, 48, 6)
+    np.testing.assert_allclose(
+        blockwise_attention(q, q, q, block_q=16, block_k=16,
+                            block_diffusion=(48, 6)), want, atol=2e-5)
+    for impl in ("naive", "blockwise"):
+        np.testing.assert_allclose(
+            _attention(q, q, q, impl=impl, block_diffusion=(48, 6)), want,
+            atol=2e-5,
+        )
+    # and the three lowerings agree where the kernels take the layout
+    for impl in ("naive", "blockwise", "flash"):
+        np.testing.assert_allclose(
+            _attention(q, q, q, impl=impl, block_diffusion=(48, 4)),
+            _block_diffusion_naive(q, q, q, 48, 4), atol=2e-5,
+        )
+
+
+@pytest.mark.parametrize("L,B,tile,classes", [
+    # interior, block, strict, lower, padded
+    (4096, 4, 512, (56, 8, 8, 8, 0)),       # the cell: 80 of 256
+    (4096, 1, 512, (56, 8, 8, 8, 0)),
+    (4096, 512, 512, (72, 0, 0, 0, 0)),     # a block a tile: nothing masked
+    (4096, 1024, 512, (80, 0, 0, 0, 0)),    # two tiles a block
+    (4096, 4096, 512, (128, 0, 0, 0, 0)),   # one block: noisy and clean whole
+    (1024, 4, 512, (2, 2, 2, 2, 0)),
+    (4000, 4, 512, (42, 7, 7, 7, 17)),      # padded halves
+    (3600, 16, 512, (42, 7, 7, 7, 16)),     # ONE block in the last tile
+    (64, 4, 16, (12, 4, 4, 4, 0)),
+    (40, 4, 16, (2, 2, 2, 2, 7)),
+])
+def test_flash_tile_classes_under_block_diffusion_are_the_live_tiles(
+        L, B, tile, classes):
+    """``flash_tile_classes`` under the layout against a brute-force count
+    of the tile pairs that hold a live (query, key) pair: every visited
+    pair is live, every live pair is visited once, forward and backward
+    lists alike, by the kernels' own ranges."""
+    from accl_tpu.ops.pallas import attention as fa
+
+    dtype = jnp.float32
+    want = dict(zip(fa.LAYOUT_TILE_CLASSES, classes))
+    for forward in (True, False):
+        assert fa.flash_tile_classes(
+            2 * L, tile, dtype=dtype, block_diffusion=(L, B), forward=forward
+        ) == want, forward
+    assert pk.flash_tile_pairs(
+        2 * L, tile, dtype=dtype, block_diffusion=(L, B)
+    ) == sum(classes)
+    b = fa._layout_tile(L, B, dtype, tile)
+    n = -(-L // b)
+    live = _live_tiles(L, B, b)
+    assert live.sum() == sum(classes)
+    for forward in (True, False):
+        seen = np.zeros_like(live)
+        for noisy in (True, False):
+            for y in range(n):
+                for start, stop, _ in fa._tile_ranges(
+                    y, n, b, False, None, L % b, forward, (noisy, B)
+                ):
+                    for x in range(int(start), int(stop)):
+                        me = y if noisy else y + n
+                        pair = (me, x) if forward else (x, me)
+                        assert not seen[pair], (forward, pair)
+                        seen[pair] = True
+        assert (seen == live).all(), forward
