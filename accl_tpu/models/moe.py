@@ -57,14 +57,17 @@ picked by what the layer's parameters hold or by the caller's word:
   layer counts tokens an expert over ALL the router's experts, the
   entries held here, and those dropped.
 
-* GROUP-LIMITED top-k (``n_group`` > 1, the softmax router; DeepSeek-V2's
-  ``group_limited_greedy``): the router's experts are ``n_group`` groups
-  of consecutive experts (the devices of its expert-parallel group), a
-  group's score is the LARGEST of its experts' probabilities, the
-  ``topk_group`` best groups stay and the top-k is over what stays; the
-  weights are the chosen probabilities, times ``route_scale``.  With a
-  held share of whole groups an entry can be held only where its group
-  was kept.
+* GROUP-LIMITED top-k (``n_group`` > 1): the router's experts are
+  ``n_group`` groups of consecutive experts (the devices of its
+  expert-parallel group), the ``topk_group`` best groups stay and the
+  top-k is over what stays.  Under the softmax router (DeepSeek-V2's
+  ``group_limited_greedy``) a group's score is the LARGEST of its
+  experts' probabilities and the weights are the chosen probabilities,
+  times ``route_scale``; under the sigmoid router (``noaux_tc``) it is the
+  SUM of the group's two largest ``score + bias``, the choice is on
+  ``score + bias`` inside the kept groups and the weights are the sigmoid
+  router's as above.  With a held share of whole groups an entry can be
+  held only where its group was kept.
 * the BALANCE LOSSES (``balance_groups`` > 0; :func:`balance_losses`):
   DeepSeek-V2's expert-, device- and communication-level terms, each a
   sequence's, averaged over the batch; whole on every chip, since the
@@ -414,8 +417,8 @@ def moe_ffn(
     are this dispatch's (module docstring: the sigmoid router, the shared
     expert, held experts); with them ``return_aux`` adds ``held_entries``
     and its ``load_balance`` and ``router_z`` are zero.  So are
-    ``n_group`` / ``topk_group`` (group-limited top-k on the softmax
-    router; 1, 1 is plain top-k) and ``balance_groups``: where it is not
+    ``n_group`` / ``topk_group`` (group-limited top-k, on either router;
+    1, 1 is plain top-k) and ``balance_groups``: where it is not
     0, ``return_aux`` adds :func:`balance_losses` over that many groups,
     a sequence a row of ``x``, held share or not.  ``switch_balance``
     (the softmax router): ``load_balance`` is the Switch term all the
@@ -485,6 +488,20 @@ def moe_ffn(
                 pick = scores
                 if "bias" in params:
                     pick = scores + lax.stop_gradient(params["bias"])
+                if n_group > 1:
+                    # a group's score is the sum of its two best ``score +
+                    # bias``; the choice is inside the topk_group best groups
+                    best = lax.top_k(
+                        pick.reshape(N, n_group, -1), 2
+                    )[0].sum(axis=-1)
+                    _, keep = lax.top_k(best, topk_group)
+                    kept = jax.nn.one_hot(
+                        keep, n_group, dtype=pick.dtype
+                    ).sum(axis=1)                         # (N, n_group) 1/0
+                    pick = jnp.where(
+                        jnp.repeat(kept, n_router // n_group, axis=-1) > 0,
+                        pick, -jnp.inf,
+                    )
                 _, topk_e = lax.top_k(pick, k)
                 topk_p = jnp.take_along_axis(scores, topk_e, axis=-1)
                 if renormalize:

@@ -43,25 +43,33 @@ class LayerKind:
     q and k rotate (a ``pos_embedding="rope"`` model may leave some
     layers without position, NoPE).  ``ffn``: ``"dense"`` or ``"moe"``
     (the expert bank of the config's ``n_experts``); ``d_ff``: the dense
-    FFN's width, or one expert's."""
+    FFN's width, or one expert's.  ``mixer``: ``"attention"`` (wq, wk,
+    wv), ``"latent"`` (``TransformerConfig.latent``'s sizes) or ``"kda"``
+    (``TransformerConfig.kda``'s: a linear-attention layer, which has no
+    position encoding and no window, so ``rope`` and ``window`` say
+    nothing of it); ``None`` is the config's own, the latent mixer where
+    it has one and attention elsewhere (``TransformerConfig.mixer``)."""
 
     window: Optional[int] = None
     rope: bool = True
     ffn: str = "dense"
     d_ff: int = 0
+    mixer: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
 class LatentAttention:
     """The sizes of a latent mixer (multi-head latent attention, MLA;
     ``TransformerConfig.latent``): q comes up from a normed latent of
-    ``q_rank``, k's content part and v from a normed latent of
+    ``q_rank`` (``None``: straight from the hidden state, one matrix
+    ``wq`` and no q norm, a published ``q_lora_rank`` null), k's content
+    part and v from a normed latent of
     ``kv_rank``; a head's q and k are ``nope_dim`` columns without
     position beside ``rope_dim`` that rotate (k's rotating part is ONE
     head, projected straight from the input and shared by every query
     head); v and the output are ``v_dim`` a head."""
 
-    q_rank: int
+    q_rank: Optional[int]
     kv_rank: int
     nope_dim: int
     rope_dim: int
@@ -80,6 +88,24 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaAttention:
+    """The sizes of a KDA mixer (Kimi Delta Attention, arXiv:2510.26692;
+    ``TransformerConfig.kda``; the layers whose ``LayerKind.mixer`` is
+    ``"kda"``): ``n_heads`` heads whose q, k and v are ``head_dim`` wide,
+    each ``silu(conv(h w))`` under a causal depthwise convolution of
+    ``conv`` taps; q and k L2-normalised a head; a log-decay a CHANNEL
+    ``lower_bound * sigmoid(exp(a_log) (h wf + dt_bias))`` and a write
+    strength a head ``sigmoid(h wbeta)`` into the gated delta rule
+    (``ops.kda``); the output RMS-normed a head and gated a channel by
+    ``sigmoid(h wg)`` before ``wo``.  ``lower_bound`` must stay within what
+    ``ops.kda``'s sub-blocks keep inside float32 (``-80 / SUB``)."""
+
+    head_dim: int
+    conv: int = 4
+    lower_bound: float = -5.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,7 +230,9 @@ class TransformerConfig:
     # scale of ``head_dim`` for q, one for k); ``tie_head=False`` gives
     # the LM head its own (d_model, vocab) matrix instead of the
     # embedding's transpose; ``attn_gate`` multiplies the attention
-    # output by ``sigmoid(h wg)`` before ``wo``; ``post_norm`` norms each
+    # output by ``sigmoid(h wg)`` before ``wo`` (``True``: a value a
+    # channel, the attention mixer's; ``"head"``: a value a head, ``wg``
+    # ``(d_model, n_heads)``, the latent mixer's); ``post_norm`` norms each
     # half's output once more before the residual add (``ln1_post``,
     # ``ln2_post``: four norms a layer); ``embed_scale`` multiplies the
     # embedding rows (a muP model's ``sqrt(d_model)``); ``head_dim`` is
@@ -221,7 +249,7 @@ class TransformerConfig:
     ffn: str = "gelu"
     qk_norm: Union[bool, str] = False
     tie_head: bool = True
-    attn_gate: bool = False
+    attn_gate: Union[bool, str] = False
     post_norm: bool = False
     embed_scale: float = 1.0
     head_dim: Optional[int] = None
@@ -239,9 +267,20 @@ class TransformerConfig:
     # ``_a`` matrices and their norms replicated).  REFUSED by name by
     # prefill/generate (no latent cache yet), the context- and
     # sequence-parallel blocks, the encoder and the pipelines.  Needs
-    # ``pos_embedding="rope"``; ``n_kv_heads``, ``head_dim``, ``qk_norm``
-    # and ``attn_gate`` do not apply to it.
+    # ``pos_embedding="rope"``; ``n_kv_heads``, ``head_dim`` and ``qk_norm``
+    # do not apply to it, and of ``attn_gate`` only ``"head"``.  It is the
+    # mixer of every layer whose ``LayerKind.mixer`` does not say otherwise.
     latent: Optional[LatentAttention] = None
+    # the KDA mixer's sizes (:class:`DeltaAttention`), for the layers of
+    # the pattern whose ``LayerKind.mixer`` is ``"kda"``: a chunked gated
+    # delta rule with a decay a channel (``ops.kda``), forward and
+    # backward, on the train and forward paths (tp splits the heads: every
+    # matrix but ``wo`` column-parallel, the convolutions' taps, ``a_log``
+    # and ``dt_bias`` with their channels).  REFUSED by name where the
+    # latent mixer is, and for the same reason: prefill/generate would need
+    # the recurrent state as a cache, the ring would hand a state from rank
+    # to rank.
+    kda: Optional[DeltaAttention] = None
     # the objective where it is not next-token prediction
     # (:class:`BlockDiffusion`): ``loss_fn`` and the train step noise the
     # ids from a key (the step's third argument, in ``targets``' place)
@@ -297,9 +336,11 @@ class TransformerConfig:
     # static buffer, ``moe_held_row_factor`` times the balanced share
     # ``tokens * k * n_experts / moe_router_experts``; an entry past it is
     # dropped and counted.
-    # Group-limited routing (dropless, the softmax router): the router's
+    # Group-limited routing (dropless): the router's
     # experts in ``moe_n_group`` groups of consecutive experts, a group's
-    # score the largest of its experts', the ``moe_topk_group`` best
+    # score the largest of its experts' under the softmax router
+    # (``group_limited_greedy``) and the SUM of its two largest ``score +
+    # bias`` under the sigmoid one (``noaux_tc``), the ``moe_topk_group`` best
     # groups kept and the top-k taken over what stays (1, 1 = no limit).
     # ``moe_route_scale`` multiplies the chosen weights on either router.
     # ``moe_balance_weights``: DeepSeek-V2's expert-, device- and
@@ -422,16 +463,23 @@ class TransformerConfig:
         )
         return (kind,) * self.n_layers
 
+    def mixer(self, kind: LayerKind) -> str:
+        """The mixer of a layer of ``kind``: its own, or the config's."""
+        if kind.mixer is not None:
+            return kind.mixer
+        return "latent" if self.latent is not None else "attention"
+
     def plain(self) -> bool:
         """Every layer alike and nothing of the later kinds (a pattern, a
         head width of its own, the gate, per-head QK-norm, post-norms, a
         scaled embedding, the sigmoid router, a shared expert, a held
-        share, a latent mixer, grouped top-k, the balance losses, an
-        objective of its own): what prefill/generate and the context- and
-        sequence-parallel blocks compute."""
+        share, a latent or a KDA mixer, grouped top-k, the balance losses,
+        an objective of its own): what prefill/generate and the context-
+        and sequence-parallel blocks compute."""
         return (
             self.layers is None and self.head_dim is None
             and self.latent is None and self.diffusion is None
+            and self.kda is None
             and self.moe_n_group == 1
             and not any(self.moe_balance_weights)
             and not self.attn_gate and not self.post_norm
@@ -449,20 +497,45 @@ class TransformerConfig:
             raise ValueError(f"unknown qk_norm {self.qk_norm!r}")
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if self.attn_gate not in (False, True, "head"):
+            raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
         if self.latent is not None and (
             self.pos_embedding != "rope" or self.n_kv_heads is not None
-            or self.head_dim is not None or self.qk_norm or self.attn_gate
+            or self.head_dim is not None or self.qk_norm
+            or self.attn_gate is True
         ):
             raise ValueError(
                 "a latent mixer rotates (pos_embedding='rope') and has no "
-                "n_kv_heads, head_dim, qk_norm or attn_gate of its own"
+                "n_kv_heads, head_dim, qk_norm or attn_gate of its own "
+                "(its gate is a value a head: attn_gate='head')"
             )
+        if self.attn_gate == "head" and self.latent is None:
+            raise ValueError(
+                "attn_gate='head' is the latent mixer's gate; the attention "
+                "mixer's is a value a channel (attn_gate=True)"
+            )
+        kinds = {self.mixer(kind) for kind in self.layers or ()}
+        if self.kda is not None:
+            from ..ops.kda import SUB
+
+            d = self.kda
+            if (
+                "kda" not in kinds or d.head_dim < 1 or d.conv < 1
+                or not -80.0 / SUB <= d.lower_bound < 0.0
+            ):
+                raise ValueError(
+                    "a KDA mixer (TransformerConfig.kda) is some layer's of "
+                    "the pattern (LayerKind.mixer='kda'), with a head_dim "
+                    f"and a convolution of at least 1 and a lower_bound in "
+                    f"[{-80.0 / SUB}, 0); got {d}"
+                )
         if self.rope_yarn is not None and self.pos_embedding != "rope":
             raise ValueError("rope_yarn rescales a rotary embedding")
         if self.diffusion is not None:
             d = self.diffusion
             if (
                 self.pos_embedding != "rope" or self.latent is not None
+                or self.kda is not None
                 or any(k.window is not None for k in self.layers or ())
                 or d.block < 1 or not 0 <= d.mask_id < self.vocab
                 or not 0.0 < d.eps < 1.0
@@ -470,21 +543,24 @@ class TransformerConfig:
                 raise ValueError(
                     "block diffusion (TransformerConfig.diffusion) rotates "
                     "(pos_embedding='rope'), has no window and no latent "
-                    "mixer, blocks of at least 1, a mask_id inside the "
+                    "or KDA mixer, blocks of at least 1, a mask_id inside the "
                     f"vocabulary and 0 < eps < 1; got {d}"
                 )
         if self.moe_n_group != 1 or self.moe_topk_group != 1:
             of = self.router_experts()
             if (
-                self.moe_router != "softmax" or self.moe_n_group < 1
-                or of % self.moe_n_group
+                self.moe_n_group < 1 or of % self.moe_n_group
                 or not 1 <= self.moe_topk_group <= self.moe_n_group
                 or self.moe_topk_group * (of // self.moe_n_group)
                 < self.moe_top_k
+                # the sigmoid router's group score is of two experts
+                or (self.moe_router == "sigmoid"
+                    and of // self.moe_n_group < 2)
             ):
                 raise ValueError(
                     f"grouped top-k: {self.moe_n_group} groups must divide "
-                    f"the softmax router's {of} experts and the "
+                    f"the router's {of} experts (into groups of at least two "
+                    f"under the sigmoid router) and the "
                     f"{self.moe_topk_group} kept must hold {self.moe_top_k}"
                 )
         if self.layers is not None:
@@ -500,6 +576,24 @@ class TransformerConfig:
                     raise ValueError(f"layer {i}: an moe layer needs n_experts")
                 if kind.window is not None and kind.window < 1:
                     raise ValueError(f"layer {i}: window {kind.window}")
+                mixer = self.mixer(kind)
+                if mixer not in ("attention", "latent", "kda"):
+                    raise ValueError(f"layer {i}: unknown mixer {mixer!r}")
+                if mixer == "kda":
+                    if self.kda is None or kind.window is not None:
+                        raise ValueError(
+                            f"layer {i}: a KDA layer needs "
+                            "TransformerConfig.kda and has no window"
+                        )
+                    continue  # no position encoding: ``rope`` says nothing
+                if (mixer == "latent") != (self.latent is not None):
+                    raise ValueError(
+                        f"layer {i}: the {mixer} mixer in a stack whose "
+                        "TransformerConfig.latent is "
+                        f"{'set' if self.latent is not None else 'None'} (a "
+                        "stack holds the latent mixer or attention, beside "
+                        "KDA layers)"
+                    )
                 if kind.rope and self.pos_embedding != "rope":
                     raise ValueError(
                         f"layer {i} rotates but pos_embedding is "
@@ -544,9 +638,10 @@ def _check_axis_compat(cfg) -> None:
             "context_parallel and seq_parallel take the plain block only "
             "(TransformerConfig.plain): no layer pattern, window, head_dim, "
             "gate, per-head QK-norm, post-norm, scaled embedding, sigmoid "
-            "router, shared expert, held share, latent mixer (MLA), grouped "
-            "top-k, balance losses or block diffusion (its layout is not in "
-            "the ring yet)"
+            "router, shared expert, held share, latent mixer (MLA), KDA "
+            "mixer (its recurrent state is not handed round the ring yet), "
+            "grouped top-k, balance losses or block diffusion (its layout is "
+            "not in the ring yet)"
         )
     if cfg.diffusion is not None and cfg.vocab_parallel:
         raise ValueError(
@@ -654,14 +749,29 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
     # every weight is replicated over it (dp still shards the batch)
     col = P(None, None) if cp else P(None, "tp")   # output dim on tp
     row = P(None, None) if cp else P("tp", None)   # input dim on tp
-    if cfg.latent is not None:
+    mixer = cfg.mixer(kind)
+    heads = None if cp else "tp"
+    if mixer == "kda":
+        layer = {
+            # every projection's columns are heads (``wbeta``'s one a
+            # head), and so are the channels of the taps and of ``dt_bias``;
+            # the output norm's scale is one head wide, every head's
+            "wq": col, "wk": col, "wv": col, "wf": col, "wg": col,
+            "wbeta": col, "conv_q": col, "conv_k": col, "conv_v": col,
+            "a_log": P(heads), "dt_bias": P(heads), "o_norm": P(None),
+            "wo": row,
+        }
+    elif mixer == "latent":
         layer = {
             # the down-projections and their norms are every chip's; the
             # up-projections' columns are heads, sharded as wq's are
-            "wq_a": P(None, None), "q_a_norm": P(None), "wq_b": col,
             "wkv_a": P(None, None), "kv_a_norm": P(None), "wkv_b": col,
             "wo": row,  # (heads * v_dim / tp, d_model)
         }
+        if cfg.latent.q_rank is None:
+            layer["wq"] = col
+        else:
+            layer.update(wq_a=P(None, None), q_a_norm=P(None), wq_b=col)
     else:
         layer = {
             "wq": col,  # (d_model, heads * head_size / tp): heads sharded
@@ -671,19 +781,20 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
         }
     layer["ln1"] = P(None)
     layer["ln2"] = P(None)
-    if cfg.attn_gate:
+    if cfg.attn_gate and mixer != "kda":
         layer["wg"] = col  # the gate's columns follow q's heads
     if cfg.post_norm:
         layer["ln1_post"] = P(None)
         layer["ln2_post"] = P(None)
-    if cfg.qk_norm == "head":
+    # a KDA layer's q and k are L2-normalised: no learned QK-norm
+    qk_norm = mixer != "kda" and cfg.qk_norm
+    if qk_norm == "head":
         # one scale of head_size for every head: replicated
         layer["q_norm"] = P(None)
         layer["k_norm"] = P(None)
-    elif cfg.qk_norm:
+    elif qk_norm:
         # scales of the whole projected q and k: sharded like the
         # projections' output columns
-        heads = None if cp else "tp"
         layer["q_norm"] = P(heads)
         layer["k_norm"] = P(heads)
     if kind.ffn == "dense":
@@ -766,19 +877,55 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
     def latent_mixer(key, la):
         ks = jax.random.split(key, 5)
         H = cfg.n_heads
+        d_q = H * (la.nope_dim + la.rope_dim)
+        if la.q_rank is None:
+            q = {"wq": normal(ks[0], (cfg.d_model, d_q))}
+        else:
+            q = {
+                "wq_a": normal(ks[0], (cfg.d_model, la.q_rank)),
+                "q_a_norm": jnp.ones((la.q_rank,), cfg.dtype),
+                "wq_b": normal(ks[1], (la.q_rank, d_q)),
+            }
         return {
-            "wq_a": normal(ks[0], (cfg.d_model, la.q_rank)),
-            "q_a_norm": jnp.ones((la.q_rank,), cfg.dtype),
-            "wq_b": normal(ks[1], (la.q_rank, H * (la.nope_dim + la.rope_dim))),
+            **q,
             "wkv_a": normal(ks[2], (cfg.d_model, la.kv_rank + la.rope_dim)),
             "kv_a_norm": jnp.ones((la.kv_rank,), cfg.dtype),
             "wkv_b": normal(ks[3], (la.kv_rank, H * (la.nope_dim + la.v_dim))),
             "wo": normal(ks[4], (H * la.v_dim, cfg.d_model)),
         }
 
+    def kda_mixer(key, kda):
+        """The matrices as every other (normal, 0.02); the taps normal at
+        ``conv ** -0.5`` (a Conv1d's default range); ``a_log`` the log of
+        a uniform draw from [1, 16) a head (the family's convention);
+        ``dt_bias`` standard normal a channel, so that channels differ in
+        how fast they forget."""
+        ks = jax.random.split(key, 12)
+        wide = cfg.n_heads * kda.head_dim
+        matrix = lambda key: normal(key, (cfg.d_model, wide))
+        taps = lambda key: (
+            jax.random.normal(key, (kda.conv, wide), cfg.dtype)
+            * kda.conv ** -0.5
+        )
+        return {
+            "wq": matrix(ks[0]), "wk": matrix(ks[1]), "wv": matrix(ks[2]),
+            "wf": matrix(ks[3]), "wg": matrix(ks[4]),
+            "wbeta": normal(ks[5], (cfg.d_model, cfg.n_heads)),
+            "conv_q": taps(ks[6]), "conv_k": taps(ks[7]), "conv_v": taps(ks[8]),
+            "a_log": jnp.log(jax.random.uniform(
+                ks[9], (cfg.n_heads,), jnp.float32, 1.0, 16.0
+            )),
+            "dt_bias": jax.random.normal(ks[10], (wide,), jnp.float32),
+            "o_norm": jnp.ones((kda.head_dim,), cfg.dtype),
+            "wo": normal(ks[11], (wide, cfg.d_model)),
+        }
+
     for i, kind in enumerate(cfg.pattern()):
         kk = k[2 + 4 * i : 6 + 4 * i]
-        if cfg.latent is not None:
+        mixer = cfg.mixer(kind)
+        if mixer == "kda":
+            layer = kda_mixer(kk[0], cfg.kda)
+        elif mixer == "latent":
             layer = latent_mixer(kk[0], cfg.latent)
         else:
             layer = {
@@ -789,15 +936,20 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
             }
         layer["ln1"] = jnp.ones((cfg.d_model,), cfg.dtype)
         layer["ln2"] = jnp.ones((cfg.d_model,), cfg.dtype)
-        if cfg.attn_gate:
-            layer["wg"] = normal(jax.random.fold_in(kk[1], 1), (cfg.d_model, d_q))
+        if cfg.attn_gate and mixer != "kda":
+            layer["wg"] = normal(
+                jax.random.fold_in(kk[1], 1),
+                (cfg.d_model, cfg.n_heads if cfg.attn_gate == "head" else d_q),
+            )
         if cfg.post_norm:
             layer["ln1_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
             layer["ln2_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
-        if cfg.qk_norm == "head":
+        # a KDA layer's q and k are L2-normalised: no learned QK-norm
+        qk_norm = mixer != "kda" and cfg.qk_norm
+        if qk_norm == "head":
             layer["q_norm"] = jnp.ones((hd,), cfg.dtype)
             layer["k_norm"] = jnp.ones((hd,), cfg.dtype)
-        elif cfg.qk_norm:
+        elif qk_norm:
             layer["q_norm"] = jnp.ones((d_q,), cfg.dtype)
             layer["k_norm"] = jnp.ones((d_kv,), cfg.dtype)
         if kind.ffn == "moe":
@@ -1184,15 +1336,14 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
     ``impl="auto"`` resolves through :func:`resolve_attention`;
     ``"blockwise"`` runs the fused online-softmax fold (no (T, T) score
     matrix in HBM); ``"naive"`` is the materialized-scores baseline."""
-    if q_rope is None:
-        impl = resolve_attention(impl, q)
-    else:
-        wide = jax.ShapeDtypeStruct(
-            q.shape[:-1] + (q.shape[-1] + q_rope.shape[-1],), q.dtype
-        )
-        impl = resolve_attention(impl, wide)
+    # with a second score part the flash kernels hold, a head, k's first
+    # part and v as they do without one, and beside them the second part
+    # of as few heads as it has (one under MLA): ``auto`` is decided on the
+    # first part's width
+    impl = resolve_attention(impl, q)
+    if q_rope is not None:
         if scale is None:
-            scale = wide.shape[-1] ** -0.5
+            scale = (q.shape[-1] + q_rope.shape[-1]) ** -0.5
         if impl != "flash":
             B, H, T, _ = q.shape
             expand = lambda t: jnp.broadcast_to(
@@ -1345,16 +1496,23 @@ def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
     ``scale``, the rotary ``inv_freq`` and ``table_scale``, the norms'
     ``eps``.  Projections, norms and rope run under the device scope
     ``accl.attn::latent``, the score/softmax/value core under
-    ``accl.attn::mla``; the rope key goes to the core as ONE head."""
+    ``accl.attn::mla``; the rope key goes to the core as ONE head.  What
+    the tree holds picks the rest: a ``wq`` in place of ``wq_a`` is q
+    straight from the hidden state (no q latent, no q norm), and a ``wg``
+    ``(d_model, heads)`` gates each head's output by ``sigmoid(h wg)``
+    before ``wo``."""
     B, T, _ = h.shape
     H = n_heads_local
     rank = lp["wkv_b"].shape[0]
     dr = lp["wkv_a"].shape[1] - rank
-    dn = lp["wq_b"].shape[1] // H - dr
     norm = partial(_rmsnorm, eps=latent["eps"])
     heads = lambda t, n: t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
     with device_scope("accl.attn::latent"):
-        q = heads(norm(h @ lp["wq_a"], lp["q_a_norm"]) @ lp["wq_b"], H)
+        if "wq_a" in lp:
+            q = heads(norm(h @ lp["wq_a"], lp["q_a_norm"]) @ lp["wq_b"], H)
+        else:
+            q = heads(h @ lp["wq"], H)
+        dn = q.shape[-1] - dr
         ckv = h @ lp["wkv_a"]
         kv = heads(norm(ckv[..., :rank], lp["kv_a_norm"]) @ lp["wkv_b"], H)
         q_n, q_r = q[..., :dn], q[..., dn:]
@@ -1378,13 +1536,64 @@ def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
             scale=latent["scale"], q_rope=q_r, k_rope=k_r,
         )
     with device_scope("accl.attn::latent"):
+        if "wg" in lp:
+            gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
+            attn = attn * gate.astype(attn.dtype).transpose(0, 2, 1)[..., None]
         return attn.transpose(0, 2, 1, 3).reshape(B, T, -1) @ lp["wo"]
+
+
+def _kda_partial(h, lp, n_heads_local, kda):
+    """The KDA mixer (:class:`DeltaAttention`) on a full-sequence
+    activation, heads column-parallel: the row-parallel PARTIAL output.
+    The sizes are the tree's; ``kda`` carries what the shapes do not say,
+    the gate's ``lower_bound`` and the output norm's ``eps``.  The matmuls
+    take the activations' type; the convolutions, SiLU, the L2 norms, the
+    gate, beta, the core and the output norm are float32.  Everything but
+    the core runs under the device scope ``accl.attn::kda_proj``, the core
+    (from normalised q, k, v, the log-decay and beta to ``o``:
+    ``ops.kda.kda_chunked``) under ``accl.attn::kda``."""
+    from ..ops.kda import kda_chunked
+
+    B, T, _ = h.shape
+    H = n_heads_local
+    f32 = jnp.float32
+    heads = lambda t: t.reshape(B, T, H, -1).transpose(0, 2, 1, 3)
+
+    def conv_silu(x, taps):
+        """Causal depthwise convolution (zero left padding, the last tap
+        the current token's), then SiLU."""
+        n = taps.shape[0]
+        x = jnp.pad(x.astype(f32), ((0, 0), (n - 1, 0), (0, 0)))
+        return jax.nn.silu(
+            sum(x[:, i:i + T] * taps[i].astype(f32) for i in range(n))
+        )
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    with device_scope("accl.attn::kda_proj"):
+        q = heads(conv_silu(h @ lp["wq"], lp["conv_q"]))
+        k = heads(conv_silu(h @ lp["wk"], lp["conv_k"]))
+        v = heads(conv_silu(h @ lp["wv"], lp["conv_v"]))
+        q = unit(q) * q.shape[-1] ** -0.5
+        k = unit(k)
+        f = heads((h @ lp["wf"]).astype(f32) + lp["dt_bias"].astype(f32))
+        rate = jnp.exp(lp["a_log"].astype(f32))[None, :, None, None]
+        g = kda["lower_bound"] * jax.nn.sigmoid(rate * f)
+        beta = jax.nn.sigmoid((h @ lp["wbeta"]).astype(f32)).transpose(0, 2, 1)
+    with device_scope("accl.attn::kda"):
+        o = kda_chunked(q, k, v, g, beta)                 # (B, H, T, dv) f32
+    with device_scope("accl.attn::kda_proj"):
+        o = _rmsnorm(o, lp["o_norm"].astype(f32), eps=kda["eps"])
+        gate = jax.nn.sigmoid((h @ lp["wg"]).astype(f32))
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, -1) * gate
+        return o.astype(h.dtype) @ lp["wo"]
 
 
 def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
                   rope_base=None, positions=None, attention_fn=None,
                   tp_axis=None, window=None, head_norm=False, latent=None,
-                  qk_eps=1e-5, block_diffusion=None):
+                  qk_eps=1e-5, block_diffusion=None, kda=None):
     """Column-parallel attention on a full-sequence activation: returns
     the row-parallel PARTIAL output (pre-reduction) and the (k, v) head
     tensors (B, Hkv_local, T, hd) for KV-cache prefill.  The kv head
@@ -1403,14 +1612,17 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     ``q_norm`` / ``k_norm`` scales are one head wide and norm each head
     AFTER the split (:func:`_qk_norm` is the whole projection's);
     a ``wg`` gates the attention output, ``attn * sigmoid(h wg)``, before
-    ``wo``; a ``wq_a`` is the latent mixer's (:func:`_latent_attn_partial`,
-    which has no cache to return yet).  ``window`` is the sliding window,
+    ``wo``; a ``wkv_a`` is the latent mixer's (:func:`_latent_attn_partial`,
+    which has no cache to return yet) and an ``a_log`` the KDA mixer's
+    (:func:`_kda_partial`, likewise).  ``window`` is the sliding window,
     run under the device scope ``accl.attn::window`` (full attention stays
     ``accl.attn::core``).  ``qk_eps`` is QK-norm's epsilon.
     ``block_diffusion=(L, B)``: ``h`` is ``[noisy ; clean]``, ``2 L`` rows
     that rotate at positions ``0..L`` twice, and the core runs under that
     layout in the device scope ``accl.attn::blockdiff``."""
-    if "wq_a" in lp:
+    if "a_log" in lp:
+        return _kda_partial(h, lp, n_heads_local, kda), None
+    if "wkv_a" in lp:
         return _latent_attn_partial(
             h, lp, n_heads_local, attn_impl, causal, rope_base, window, latent
         ), None
@@ -1463,7 +1675,7 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
            ep_axis=None, moe_cfg=None, with_aux=False,
            reduce_fn=None, fanout_fn=None, norm=_layernorm,
            window=None, head_norm=False, latent=None, qk_eps=1e-5,
-           block_diffusion=None):
+           block_diffusion=None, kda=None):
     """One transformer block on tp-sharded weights.  ``lp['wqkv']`` etc. are
     the *local shards*; the tp-allreduce after each row-parallel matmul is
     the reference's fused-allreduce hot path in model form.
@@ -1489,7 +1701,7 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
     partial_o, kv = _attn_partial(
         h, lp, n_heads_local, attn_impl, causal, rope_base, tp_axis=tp_axis,
         window=window, head_norm=head_norm, latent=latent, qk_eps=qk_eps,
-        block_diffusion=block_diffusion,
+        block_diffusion=block_diffusion, kda=kda,
     )
     if tp_axis is not None:
         partial_o = reduce_fn(partial_o, tp_axis)
@@ -1669,6 +1881,10 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
         kw["latent"] = {
             "scale": cfg.attn_scale(), "inv_freq": cfg.rope_inv_freq(),
             "table_scale": cfg.rope_table_scale(), "eps": cfg.norm_eps,
+        }
+    if cfg.kda is not None:
+        kw["kda"] = {
+            "lower_bound": cfg.kda.lower_bound, "eps": cfg.norm_eps,
         }
     if cfg.n_experts:
         # expert parallelism rides cfg.moe_mesh_axis ("dp" welded, or a
@@ -1945,7 +2161,9 @@ def _reject_unservable(cfg) -> None:
             "its own, an attention gate, per-head QK-norm, post-norms, a "
             "scaled embedding, a sigmoid router, a shared expert, a "
             "held share of the experts, a latent mixer (MLA: its cache is "
-            "the latent and the rope key, not k and v), grouped top-k, "
+            "the latent and the rope key, not k and v), a KDA mixer (its "
+            "cache is a recurrent state and a convolution's last inputs), "
+            "grouped top-k, "
             "balance losses or block diffusion (generation there denoises "
             "a block of tokens at a time over several passes), and the "
             "decode path has no cache "
@@ -1954,12 +2172,18 @@ def _reject_unservable(cfg) -> None:
 
 
 def reject_latent(cfg, where: str) -> None:
-    """The paths beside train and forward refuse a latent mixer, and the
-    block-diffusion objective, by name."""
+    """The paths beside train and forward refuse a latent mixer, a KDA
+    mixer and the block-diffusion objective, by name."""
     if cfg.latent is not None:
         raise ValueError(
             "the latent mixer (MLA, TransformerConfig.latent) is supported "
             f"on the decoder's train and forward paths only, not {where}"
+        )
+    if cfg.kda is not None:
+        raise ValueError(
+            "the KDA mixer (linear attention, TransformerConfig.kda) is "
+            f"supported on the decoder's train and forward paths only, not "
+            f"{where}"
         )
     if cfg.diffusion is not None:
         raise ValueError(

@@ -109,9 +109,21 @@ drops ``op_name``, so a reader joins the two by instruction name
 ``accl.attn::window``    the same under a ``LayerKind.window``: a sliding
                          layer's attention call
 ``accl.attn::latent``    ``_latent_attn_partial`` (a latent mixer, MLA): the
-                         five projections, the two latent norms, the rope
+                         five projections (four where q has no latent),
+                         the latent norms, the rope, the head-wise gate
 ``accl.attn::mla``       the same: the score/softmax/value core (the flash
                          kernels with two widths and ONE rope key head)
+``accl.attn::kda``       ``_kda_partial`` (a KDA mixer, ``LayerKind.mixer``
+                         ``"kda"``): the core, ``ops/kda.py``
+                         ``kda_chunked`` from normalised q, k, v, the
+                         log-decay and beta to ``o``, forward and backward,
+                         its scan over the chunks with it (a loop of the
+                         compiled step: the body's instructions are device
+                         events of their own)
+``accl.attn::kda_proj``  the same: everything round the core (the seven
+                         projections, the three convolutions and SiLU, the
+                         L2 norms, the gate and beta, the output norm and
+                         gate, ``wo``)
 ``accl.attn::blockdiff`` ``_attn_partial`` under ``TransformerConfig.
                          diffusion``: the attention call on ``[noisy ;
                          clean]`` under the block-diffusion layout (the

@@ -91,6 +91,24 @@ def test_flash_kernels_compile_at_the_other_cells_widths(one_chip, case):
     assert "flash_fwd" in text and "flash_bwd" in text
 
 
+def test_flash_kernels_compile_at_ling3_widths(one_chip):
+    """``train_ling3_t8192_b2``'s one latent layer: 32 heads of 128 + 64
+    columns, ONE rope key head, v heads of 128, two sequences of T = 8192
+    (twice the DeepSeek-V2 cell's length: the backward holds 37.5 MiB)."""
+    from accl_tpu.ops.pallas.attention import flash_attention
+
+    B, H, T = 2, 32, 8192
+    text = _compile(
+        _loss(lambda q, k, v, qr, kr: flash_attention(
+            q, k, v, scale=192 ** -0.5, q_rope=qr, k_rope=kr, interpret=False,
+        )),
+        [(B, H, T, 128), (B, H, T, 128), (B, H, T, 128), (B, H, T, 64),
+         (B, 1, T, 64)],
+        one_chip,
+    )
+    assert "flash_fwd" in text and "flash_bwd" in text
+
+
 @pytest.mark.parametrize("block", [4, 1024])
 def test_flash_kernels_compile_under_the_block_diffusion_layout(one_chip, block):
     """``train_sdar_t4096_b2``'s core: 32 query heads of 128 on 4 KV heads,
@@ -169,11 +187,12 @@ def test_placement_kernel_compiles_at_the_held_cells_shapes(one_chip, case):
     assert "place_rows" in text
 
 
-def _step_text(cell_name, n_layers, device, monkeypatch):
+def _step_text(cell_name, n_layers, device, monkeypatch, layers=None):
     """A train cell's step (``make_sharded_train_step`` on a world of the
     one described chip, the cell's widths, batch and length; its depth cut
-    to ``n_layers``, which the table's gradient does not see), compiled;
-    the Pallas kernels compiled too, as the chip has them."""
+    to its first ``n_layers``, which the table's gradient does not see, or
+    to the ``layers`` of its pattern named by index), compiled; the Pallas
+    kernels compiled too, as the chip has them."""
     from accl_tpu.models import init_params, make_sharded_train_step
     from accl_tpu.models.transformer import normalize_spec, param_specs
     from perfbench import manifest
@@ -188,9 +207,12 @@ def _step_text(cell_name, n_layers, device, monkeypatch):
         "perfbench.drivers." + cell["traffic"]["driver"]
     )
     cfg = driver.program_config(cell["config"])
+    if layers is not None:
+        kept = tuple(cfg.layers[i] for i in layers)
+    else:
+        kept = cfg.layers[:n_layers] if cfg.layers else cfg.layers
     cfg = dataclasses.replace(
-        cfg, attention="flash", n_layers=n_layers,
-        layers=cfg.layers[:n_layers] if cfg.layers else cfg.layers,
+        cfg, attention="flash", n_layers=n_layers, layers=kept
     )
     mesh = Mesh(np.array([device]).reshape(1, 1), ("dp", "tp"))
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
@@ -274,3 +296,34 @@ def test_trinity_step_places_the_held_rows_by_the_kernel(v5e, monkeypatch):
     assert len(placed) == 2
     for scope in ("accl.moe::combine", "accl.moe::dispatch"):
         assert len(set(placed) & set(scopes[scope])) == 1
+
+
+def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
+    v5e, monkeypatch
+):
+    """One KDA expert layer and the latent expert layer of the cell's
+    seven, 2 x 8,192 tokens, under ``remat`` as the cell runs: the KDA
+    core is under ``accl.attn::kda`` with a loop over the chunks (whose
+    body ``scopes_of`` does not walk: the driver's ``scoped_instructions``
+    does), the latent core is the flash kernels, the held rows are placed
+    by the kernel, and no array is a square of the length (memory linear
+    in T)."""
+    from perfbench import scope_ops
+    from perfbench.drivers import train_steps_ling3
+
+    text = _step_text("train_ling3_t8192_b2", 2, v5e, monkeypatch, layers=(5, 6))
+    entry = scope_ops.scopes_of(text)
+    every = train_steps_ling3.scoped_instructions(text)
+    assert any(n.startswith("while") for n in entry["accl.attn::kda"])
+    assert set(entry["accl.attn::kda"]) < set(every["accl.attn::kda"])
+    assert every["accl.attn::kda_proj"] and entry["accl.attn::latent"]
+    assert any("flash_fwd" in n for n in entry["accl.attn::mla"])
+    assert any("flash_bwd" in n for n in entry["accl.attn::mla"])
+    assert re.search(r"%place_rows\S* = bf16\[16384,2560\]", text)
+    # one head's scores over the whole length would be 2^32 elements; the
+    # largest array here is the float32 logits' 16,384 x 19,648 (2^28.3)
+    largest = max(
+        int(np.prod([int(n) for n in dims.split(",")]))
+        for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
+    )
+    assert largest == 16384 * 19648
