@@ -10,10 +10,17 @@ and a write strength ``beta_t`` in (0, 1):
     o_t = S_t^T q_t
 
 :func:`kda_chunked` computes that for whole sequences in chunks of
-``CHUNK`` tokens, memory linear in T, everything in float32, by plain
-``jax.numpy`` that XLA lowers (no kernel yet: ROADMAP M5) and autodiff
-differentiates: the backward is chunked as the forward is, and the chunk
-states are what a step of the scan saves.
+``CHUNK`` tokens, memory linear in T, float32 in and out, by ONE OF TWO
+lowerings picked from the shapes (``ops.pallas.kda.takes``): heads whose
+``dk`` and ``dv`` are whole lanes (multiples of 128) at the default chunk
+and sub-block run the Mosaic kernels ``kda_fwd`` / ``kda_bwd``
+(``ops/pallas/kda.py``: what a chunk makes stays in VMEM, the scan over
+the chunk states is fused, the backward is a kernel of its own behind a
+``custom_vjp`` that saves only the chunk start states); every other shape
+(the tests' tiny widths, a CPU rehearsal's) runs the XLA FORM below, plain
+``jax.numpy`` that XLA lowers and autodiff differentiates, which is also
+the oracle the kernels are tested against.  Both compute the same
+products at the same precision (the kernels' docstring lists them).
 
 THE CHUNKED FORM.  Inside a chunk, with ``G_t`` the sum of ``g`` from the
 chunk's first token to ``t``, ``Gamma_t = exp(G_t)`` and ``S`` the state
@@ -50,9 +57,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-#: tokens a chunk (one step of the scan over the states) and a sub-block
-CHUNK = 64
-SUB = 16
+from .pallas import kda as _kernels
+
+#: tokens a chunk (one step of the scan over the states) and a sub-block:
+#: the kernels' own, which take no other
+CHUNK, SUB = _kernels.CHUNK, _kernels.SUB
 
 
 def _dot(x, y):
@@ -135,11 +144,19 @@ def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB):
     ``-80 / sub`` a token (the gate's lower bound of -5 at ``sub`` 16), or
     a diagonal block's exponents leave float32.  T need be no multiple of
     the chunk: the tail is padded with tokens that leave the state alone
-    (no decay, no write)."""
-    B, H, T, dk = q.shape
-    dv = v.shape[-1]
+    (no decay, no write).  The shapes pick the lowering (module
+    docstring)."""
     if chunk % sub or sub % 2:
         raise ValueError(f"chunk {chunk} is no whole even sub-blocks of {sub}")
+    if _kernels.takes(q.shape, v.shape, chunk, sub):
+        return _kernels.kda(q, k, v, g, beta)
+    return _xla_form(q, k, v, g, beta, chunk, sub)
+
+
+def _xla_form(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB):
+    """:func:`kda_chunked` as plain ``jax.numpy``, at any shape."""
+    B, H, T, dk = q.shape
+    dv = v.shape[-1]
     q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
     if pad := -T % chunk:
         q, k, v, g, beta = (

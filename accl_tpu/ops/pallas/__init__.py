@@ -18,6 +18,9 @@ TPU kernels.
   weighted (the held MoE path's combine and its dispatch gather's
   cotangent): one kernel that reads the buffer's rows once, in the
   contiguous runs a stable sort by expert leaves a token tile.
+* ``kda`` — the KDA core (the chunked gated delta rule of ``ops/kda.py``)
+  as a forward and a backward kernel that keep what a chunk makes in
+  VMEM, the scan over the chunk states fused into them.
 
 On non-TPU backends every kernel runs under the Pallas TPU interpreter so
 the CI tier exercises the identical kernel code (see

@@ -1,0 +1,516 @@
+"""The KDA core (``ops/kda.py``: the chunked gated delta rule with a decay
+a channel) as two Mosaic kernels, ``kda_fwd`` and ``kda_bwd``, that keep
+what a chunk makes in VMEM.
+
+``ops.kda.kda_chunked`` picks them from the shapes (:func:`takes`: heads
+whose ``dk`` and ``dv`` are whole lanes); the XLA form there stays the
+oracle they are tested against and what every other shape runs.
+
+THE GRID.  ``(head-sequences, steps)``, the step axis ``arbitrary``: a
+grid step holds :data:`BLOCKS` BLOCKS of one head-sequence, a block two
+chunks of :data:`CHUNK` tokens (:data:`ROWS` = 128 rows), and walks them
+in a loop with the running state ``S`` (held TRANSPOSED, ``(dv, dk)``, so
+that a channel's decay scales a lane) in a VMEM scratch that lives across
+the steps of a head-sequence.  Everything a chunk makes that does not
+wait for ``S`` is made for the two chunks of a block AT ONCE as
+block-diagonal ``(128, 128)`` matrices (``A``, ``P``, ``(I + A)^-1``) and
+``(128, dk)`` row arrays (``W``, ``U~``, ``K^``, ``Gamma Q``): a product
+then fills the MXU's 128 x 128 where a chunk alone would fill a quarter,
+at the same count of passes.  Only three products a chunk wait for ``S``
+(``U = U~ - W S``, ``S' = diag S + K^^T U``, ``(Gamma Q) S``); the
+backward's chain is two (``dU += K^ dS'``, ``dS = ... - W^T dU``).
+
+WHAT MOVES.  Forward: q, k, v, g, beta in, ``o`` out and, under
+differentiation, the state each chunk STARTS from (``(dv, dk)`` float32 a
+chunk) and each block's inverse (``(128, 128)`` float32: the one thing of
+a block that costs ``highest`` products to rebuild).  Backward
+(hand-derived, a reverse pass over the blocks carrying ``dS``): the same
+inputs, ``do`` and what the forward saved in, the five gradients out;
+every other chunk-local quantity is rebuilt from the inputs (with the
+saved states nothing of the rebuild waits for the chain).
+
+PRECISION, product by product what the XLA form computes: its ``@`` and
+``einsum`` run at the TPU's default precision for float32, ONE bfloat16
+pass with float32 accumulation (operands rounded to bfloat16), so every
+such product here is :func:`_mm` (explicit casts, ``preferred_element_type``
+float32), the backward's too (autodiff's products of the XLA form round
+their cotangents the same way); the inverse's squarings and its
+cotangent's two products are :func:`_mm_hi` (``highest``: full float32);
+the cumulative log-decay is a product with a 0/1 triangle of the three
+bfloat16 parts of ``g`` (exact products, float32 sums: a float32 cumsum
+up to the order of the additions), its cotangent the same with the
+transposed triangle.  Everything elementwise is float32.
+
+EXPONENTS.  As the XLA form: sub-blocks of :data:`SUB` tokens, a ROW
+sub-block's exponents all relative to its own middle token ``m``: a row
+carries ``exp(G_t - m)`` (within ``e^+-40`` at the gate's bound of -5), a
+key column ``exp(m - G_j)`` (at most ``e^40``, and at most 1 for every
+earlier sub-block; what underflows there is below ``e^-47`` beside terms
+of order 1).  The XLA form splits blocks below the diagonal at the row
+block's START instead; both are exact up to rounding.  ``exp(G_t - m)
+exp(m - G_j)`` does not depend on ``m``, so ``m``'s cotangent is zero but
+for the products' rounding; the backward leaves that rounding on the
+middle token as autodiff of the XLA form does, which cancels it out of
+``dg`` for the tokens before (measured: ``dg`` is then as near the
+``highest`` truth as the XLA form's, 1.0% rms, and 2.7% without).
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._common import LANES, InterpretArg, default_interpret, out_struct, vary_together
+from .grouped_matmul import _run, _settled
+
+FWD, BWD = "kda_fwd", "kda_bwd"
+#: tokens a chunk and a sub-block (``ops.kda``'s), rows a block (two
+#: chunks side by side on the MXU), blocks a grid step
+CHUNK, SUB = 64, 16
+ROWS = 2 * CHUNK
+BLOCKS = 4
+
+_f32, _bf16 = jnp.float32, jnp.bfloat16
+#: what a default-precision product rounds its operands to, read at each
+#: call (the tests of the mathematics set float32: a CPU's XLA form
+#: rounds nothing)
+_ONE_PASS = _bf16
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def takes(q_shape, v_shape, chunk: int = CHUNK, sub: int = SUB) -> bool:
+    """The shape rule: the kernels take heads whose ``dk`` and ``dv`` are
+    whole lanes at this module's chunk and sub-block; any other shape is
+    the XLA form's."""
+    return (
+        (chunk, sub) == (CHUNK, SUB)
+        and q_shape[-1] % LANES == 0 and v_shape[-1] % LANES == 0
+    )
+
+
+def _mm(a, b, dims=_NN, *, one_pass):
+    """A product at the XLA form's default precision on the chip: one
+    pass of operands rounded to ``one_pass`` (bfloat16), float32 sums."""
+    return lax.dot_general(
+        a.astype(one_pass), b.astype(one_pass), (dims, ((), ())),
+        preferred_element_type=_f32,
+    )
+
+
+def _parts(x):
+    """``x`` as its three bfloat16 parts, largest first: their sum is the
+    float32 exactly."""
+    out = []
+    for _ in range(3):
+        out.append(x.astype(_bf16))
+        x = x - out[-1].astype(_f32)
+    return out
+
+
+def _mm_hi(a, b):
+    """A product at full float32 (``highest``)."""
+    return lax.dot_general(
+        a, b, (_NN, ((), ())), precision=lax.Precision.HIGHEST,
+        preferred_element_type=_f32,
+    )
+
+
+def _tri_sum(tri, x, dims):
+    """``tri @ x`` (``tri.T @ x`` under ``_TN``) for a 0/1 ``tri`` in
+    bfloat16, exact products: ``x`` goes through as its three parts."""
+    return sum(
+        lax.dot_general(tri, part, (dims, ((), ())), preferred_element_type=_f32)
+        for part in reversed(_parts(x))
+    )
+
+
+def _rows(pieces):
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=0)
+
+
+def _halves(x):
+    return [x[h * CHUNK:(h + 1) * CHUNK] for h in range(ROWS // CHUNK)]
+
+
+def _inverse(a, eye):
+    """``(I + a)^-1`` for the block's two strictly lower triangular chunks
+    on the diagonal of ``a`` (ROWS, ROWS), ``eye`` the diagonal's mask:
+    ``ops.kda._unit_lower_inverse``'s products, a power's square and the
+    inverse's next term in ONE product (they share their right factor)."""
+    power = -a
+    inv = jnp.where(eye, 1.0, 0.0) + power
+    power, reach = _mm_hi(power, power), 2   # inv holds the powers below reach
+    while 2 * reach < CHUNK:
+        both = _mm_hi(jnp.concatenate([inv, power], axis=0), power)
+        inv, power, reach = inv + both[:ROWS], both[ROWS:], 2 * reach
+    return inv + _mm_hi(inv, power)
+
+
+def _local(mm, q, k, v, g, brow, T=None):
+    """What a block makes without the state, from its ``(ROWS, .)`` rows
+    and its ``(1, ROWS)`` beta: a dict of the module docstring's names.
+    ``mm`` is the default-precision product; ``T`` the inverse, where the
+    forward saved it."""
+    dk = q.shape[-1]
+    row = lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 0)
+    col = lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 1)
+    same = (row // CHUNK) == (col // CHUNK)
+    upto, below = same & (col <= row), same & (col < row)
+    eye = row == col
+    tri = jnp.where(upto, 1.0, 0.0).astype(_bf16)
+
+    G = _tri_sum(tri, g, _NN)                        # summed inside a chunk
+    mids = [
+        G[SUB * a + SUB // 2 - 1: SUB * a + SUB // 2] for a in range(ROWS // SUB)
+    ]
+    mid = _rows([jnp.broadcast_to(m, (SUB, dk)) for m in mids])
+    ends = [x[CHUNK - 1:] for x in _halves(G)]       # (1, dk) a chunk
+    end = _rows([jnp.broadcast_to(e, (CHUNK, dk)) for e in ends])
+
+    up = jnp.exp(G - mid)
+    q_up, k_up = q * up, k * up
+    downs, lhs, p0, a0 = [], [], [], []
+    for a, m in enumerate(mids):
+        # the key columns a row sub-block can see: its chunk's, up to its own
+        lo, hi = CHUNK * (SUB * a // CHUNK), SUB * (a + 1)
+        rows = slice(SUB * a, SUB * a + SUB)
+        down = _rows(
+            ([jnp.zeros((lo, dk), _f32)] if lo else [])
+            + [jnp.exp(m - G[lo:hi])]
+            + ([jnp.zeros((ROWS - hi, dk), _f32)] if hi < ROWS else [])
+        )
+        x = jnp.concatenate([q_up[rows], k_up[rows]], axis=0)
+        out = mm(x, k * down, _NT)                  # (2 SUB, ROWS)
+        downs.append(down)
+        lhs.append(x)
+        p0.append(out[:SUB])
+        a0.append(out[SUB:])
+    P0, A0 = _rows(p0), _rows(a0)
+    Pm = jnp.where(upto, P0, 0.0) * brow
+    if T is None:
+        T = _inverse(jnp.where(below, A0, 0.0) * brow, eye)
+
+    bcol = jnp.sum(
+        jnp.where(eye, jnp.broadcast_to(brow, (ROWS, ROWS)), 0.0), axis=1, keepdims=True
+    )
+    eG, eD = jnp.exp(G), jnp.exp(end - G)
+    k_gam = k * eG
+    return dict(
+        upto=upto, below=below, eye=eye, tri=tri, up=up, downs=downs, lhs=lhs,
+        P0=P0, A0=A0, Pm=Pm, T=T, eG=eG, eD=eD, bcol=bcol, k_gam=k_gam,
+        q_gam=q * eG, W=mm(T, k_gam), U0=mm(T, v), k_hat=k * eD * bcol,
+        keeps=[jnp.exp(e) for e in ends],
+    )
+
+
+def _block_fwd(mm, q, k, v, g, brow, state):
+    """One block forward: ``(o, the state after it, the states its two
+    chunks started from, the block's inverse)``; ``state`` is ``S^T`` (dv,
+    dk)."""
+    loc = _local(mm, q, k, v, g, brow)
+    starts, u, read = [], [], []
+    for W, U0, q_gam, k_hat, keep in zip(
+        *(_halves(loc[n]) for n in ("W", "U0", "q_gam", "k_hat")), loc["keeps"]
+    ):
+        starts.append(state)
+        u.append(U0 - mm(W, state, _NT))
+        read.append(mm(q_gam, state, _NT))
+        state = state * keep + mm(u[-1], k_hat, _TN)
+    o = _rows(read) + mm(loc["Pm"], _rows(u))
+    return o, state, starts, loc["T"]
+
+
+def _block_bwd(mm, q, k, v, g, brow, do, starts, T, d_state):
+    """One block backward: the block's rows, ``do``, the states its chunks
+    started from, its inverse and the cotangent ``d_state`` of the state
+    after it (both states transposed); returns ``(dq, dk, dv, dg, dbeta
+    (1, ROWS), the cotangent of the state before it)``."""
+    loc = _local(mm, q, k, v, g, brow, T)
+    Pm, eG, eD, bcol = (loc[n] for n in ("Pm", "eG", "eD", "bcol"))
+    W, U0, q_gam, k_hat, do_h = (
+        _halves(x) for x in (loc["W"], loc["U0"], loc["q_gam"], loc["k_hat"], do)
+    )
+    u = [u0 - mm(w, s, _NT) for w, u0, s in zip(W, U0, starts)]
+    U = _rows(u)
+
+    # the chain: the chunks backwards
+    dU_p = _halves(mm(Pm, do, _TN))
+    dU, d_after = [None] * len(starts), [None] * len(starts)
+    for h in reversed(range(len(starts))):
+        d_after[h] = d_state
+        dU[h] = dU_p[h] + mm(k_hat[h], d_state, _NT)
+        d_state = d_state * loc["keeps"][h] + mm(
+            jnp.concatenate([do_h[h], -dU[h]], axis=0),
+            jnp.concatenate([q_gam[h], W[h]], axis=0), _TN,
+        )
+
+    # what the chain leaves: every cotangent of the block's own quantities
+    dq_gam, dW, dk_hat, d_end = [], [], [], []
+    for h, (s, d_s) in enumerate(zip(starts, d_after)):
+        both = mm(jnp.concatenate([do_h[h], dU[h]], axis=0), s)
+        dq_gam.append(both[:CHUNK])
+        dW.append(-both[CHUNK:])
+        dk_hat.append(mm(u[h], d_s))
+        d_end.append(jnp.sum(s * d_s, axis=0, keepdims=True) * loc["keeps"][h])
+    dU, dq_gam, dW, dk_hat = _rows(dU), _rows(dq_gam), _rows(dW), _rows(dk_hat)
+
+    dPm = mm(do, U, _NT)
+    dT = mm(dW, loc["k_gam"], _NT) + mm(dU, v, _NT)
+    Tt = T.T
+    dk_gam, dv = mm(Tt, dW), mm(Tt, dU)
+    dAm = -_mm_hi(_mm_hi(Tt, dT), Tt)
+    dP0 = jnp.where(loc["upto"], dPm * brow, 0.0)
+    dA0 = jnp.where(loc["below"], dAm * brow, 0.0)
+    dbrow = jnp.sum(
+        jnp.where(loc["below"], loc["A0"] * dAm, 0.0)
+        + jnp.where(loc["upto"], loc["P0"] * dPm, 0.0),
+        axis=0, keepdims=True,
+    )
+
+    dq_up, dk_up, d_mid, back = [], [], [], None
+    middle = lax.broadcasted_iota(jnp.int32, (SUB, q.shape[-1]), 0) == SUB // 2 - 1
+    for a, (down, x) in enumerate(zip(loc["downs"], loc["lhs"])):
+        rows = slice(SUB * a, SUB * a + SUB)
+        d = jnp.concatenate([dP0[rows], dA0[rows]], axis=0)      # (2 SUB, ROWS)
+        got = mm(d, k * down)
+        dq_up.append(got[:SUB])
+        dk_up.append(got[SUB:])
+        term = mm(d, x, _TN) * down                             # (ROWS, dk)
+        back = term if back is None else back + term
+        # the reference m's cotangent: zero but for the products'
+        # rounding, left on the middle token (module docstring)
+        of_m = jnp.sum(k * term, axis=0, keepdims=True) - jnp.sum(
+            x * got, axis=0, keepdims=True
+        )
+        d_mid.append(jnp.where(middle, of_m, 0.0))
+
+    dk_end = dk_hat * eD * bcol                 # through K^ = k exp(G_C - G) beta
+    back = back + dk_end
+    dq = _rows(dq_up) * loc["up"] + dq_gam * eG
+    dk = _rows(dk_up) * loc["up"] + dk_gam * eG + back
+    # every exponent is +-G: a factor's cotangent times the factor
+    dG = q * dq + k * (dk - 2.0 * back) + _rows(d_mid)
+    at = lax.broadcasted_iota(jnp.int32, dG.shape, 0)
+    for h, (x, e) in enumerate(zip(_halves(dk_end * k), d_end)):
+        total = jnp.sum(x, axis=0, keepdims=True) + e            # of G_C
+        dG = dG + jnp.where(at == h * CHUNK + CHUNK - 1, total, 0.0)
+    dg = _tri_sum(loc["tri"], dG, _TN)
+    dbcol = jnp.sum(dk_hat * k * eD, axis=1, keepdims=True)
+    dbrow = dbrow + jnp.sum(
+        jnp.where(loc["eye"], jnp.broadcast_to(dbcol, (ROWS, ROWS)), 0.0),
+        axis=0, keepdims=True,
+    )
+    return dq, dk, dv, dg, dbrow, d_state
+
+
+def _fwd_kernel(blocks, save, mm):
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
+        s_ref, t_ref, state_ref = rest if save else (None, None, *rest)
+
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            state_ref[...] = jnp.zeros_like(state_ref)
+
+        state = state_ref[...]
+        # unrolled: a block's products that do not wait for the state are
+        # the scheduler's to run beside the block before's chain
+        for i in range(blocks):
+            rows = slice(i * ROWS, (i + 1) * ROWS)
+            o, state, starts, T = _block_fwd(
+                mm, q_ref[0, rows], k_ref[0, rows], v_ref[0, rows],
+                g_ref[0, rows], b_ref[0, i], state,
+            )
+            o_ref[0, rows] = o
+            if save:
+                for h, s in enumerate(starts):
+                    s_ref[0, 2 * i + h] = s
+                t_ref[0, i] = T
+        state_ref[...] = state
+
+    return kernel
+
+
+def _bwd_kernel(blocks, mm):
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, t_ref,
+               dq_ref, dk_ref, dv_ref, dg_ref, db_ref, d_state_ref):
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            d_state_ref[...] = jnp.zeros_like(d_state_ref)
+
+        d_state = d_state_ref[...]
+        for i in reversed(range(blocks)):
+            rows = slice(i * ROWS, (i + 1) * ROWS)
+            dq, dk, dv, dg, db, d_state = _block_bwd(
+                mm, q_ref[0, rows], k_ref[0, rows], v_ref[0, rows],
+                g_ref[0, rows], b_ref[0, i], do_ref[0, rows],
+                [s_ref[0, 2 * i + h] for h in range(ROWS // CHUNK)],
+                t_ref[0, i], d_state,
+            )
+            dq_ref[0, rows] = dq
+            dk_ref[0, rows] = dk
+            dv_ref[0, rows] = dv
+            dg_ref[0, rows] = dg
+            db_ref[0, i] = db
+        d_state_ref[...] = d_state
+
+    return kernel
+
+
+def _specs(blocks, dk, dv, steps, backward):
+    """Block specs of the row arrays, beta and what the forward saves (the
+    chunks' start states, the blocks' inverses); the backward visits the
+    steps in reverse."""
+    at = (lambda s: steps - 1 - s) if backward else (lambda s: s)
+    rows = lambda d: pl.BlockSpec((1, blocks * ROWS, d), lambda n, s: (n, at(s), 0))
+    beta = pl.BlockSpec((1, blocks, 1, ROWS), lambda n, s: (n, at(s), 0, 0))
+    states = pl.BlockSpec((1, 2 * blocks, dv, dk), lambda n, s: (n, at(s), 0, 0))
+    inverses = pl.BlockSpec((1, blocks, ROWS, ROWS), lambda n, s: (n, at(s), 0, 0))
+    return rows, beta, (states, inverses)
+
+
+def _vmem_limit(blocks, dk, dv, arrays):
+    """Double-buffered row blocks, states and inverses, the scratch, and
+    room for what a block keeps live between its products."""
+    row = blocks * ROWS * max(dk, dv) * 4
+    saved = blocks * (2 * dk * dv + ROWS * ROWS) * 4
+    return 2 * (arrays * row + saved) + dk * dv * 4 + (24 << 20)
+
+
+# jitted, so that a program's layers share one trace and one lowered
+# function a shape
+@partial(jax.jit, static_argnames=("save", "blocks", "interpret", "one_pass"))
+def _forward(q, k, v, g, beta, *, save, blocks, interpret, one_pass):
+    N, Tp, dk = q.shape
+    dv = v.shape[-1]
+    steps = Tp // (blocks * ROWS)
+    rows, b_spec, s_spec = _specs(blocks, dk, dv, steps, backward=False)
+    operands = (q, k, v, g, beta)
+    out_shape = [out_struct((N, Tp, dv), _f32, *operands)]
+    out_specs = [rows(dv)]
+    if save:
+        out_shape += [
+            out_struct((N, Tp // CHUNK, dv, dk), _f32, *operands),
+            out_struct((N, Tp // ROWS, ROWS, ROWS), _f32, *operands),
+        ]
+        out_specs += s_spec
+    call = pl.pallas_call(
+        _fwd_kernel(blocks, save, partial(_mm, one_pass=one_pass)),
+        grid=(N, steps),
+        out_shape=out_shape,
+        in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), b_spec],
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((dv, dk), _f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(blocks, dk, dv, 5),
+        ),
+        interpret=interpret,
+        name=FWD,
+    )
+    return _run(call, interpret, *operands)
+
+
+@partial(jax.jit, static_argnames=("blocks", "interpret", "one_pass"))
+def _backward(q, k, v, g, beta, do, states, inverses, *, blocks, interpret, one_pass):
+    N, Tp, dk = q.shape
+    dv = v.shape[-1]
+    steps = Tp // (blocks * ROWS)
+    rows, b_spec, s_spec = _specs(blocks, dk, dv, steps, backward=True)
+    operands = (q, k, v, g, beta, do, states, inverses)
+    call = pl.pallas_call(
+        _bwd_kernel(blocks, partial(_mm, one_pass=one_pass)),
+        grid=(N, steps),
+        out_shape=[
+            out_struct(x.shape, _f32, *operands) for x in (q, k, v, g, beta)
+        ],
+        in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), b_spec, rows(dv), *s_spec],
+        out_specs=[rows(dk), rows(dk), rows(dv), rows(dk), b_spec],
+        scratch_shapes=[pltpu.VMEM((dv, dk), _f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(blocks, dk, dv, 10),
+        ),
+        interpret=interpret,
+        name=BWD,
+    )
+    return _run(call, interpret, *operands)
+
+
+def _blocks_for(T):
+    """Blocks a grid step and the padded length: BLOCKS, or all of a
+    shorter sequence's."""
+    n = -(-T // ROWS)
+    blocks = min(BLOCKS, n)
+    return blocks, -(-n // blocks) * blocks * ROWS
+
+
+def _flat(x, Tp):
+    """``(B, H, T, .)`` or ``(B, H, T)`` as float32 ``(B H, Tp, .)`` rows,
+    the tail zeros."""
+    B, H, T = x.shape[:3]
+    x = x.astype(_f32).reshape(B * H, T, -1)
+    return jnp.pad(x, ((0, 0), (0, Tp - T), (0, 0)))
+
+
+def _packed(q, k, v, g, beta):
+    """``(blocks a step, the operands)``: rows padded to whole grid steps
+    with tokens that leave the state alone (no decay, no write), beta a
+    lane row a block."""
+    blocks, Tp = _blocks_for(q.shape[2])
+    q, k, v, g, beta = (_flat(x, Tp) for x in (q, k, v, g, beta))
+    return blocks, (q, k, v, g, beta.reshape(-1, Tp // ROWS, 1, ROWS))
+
+
+def _apply(q, k, v, g, beta, how, save):
+    """``(o, what the forward saves for the backward or ())``; ``how``:
+    ``(interpret, one_pass)``."""
+    B, H, T, _ = q.shape
+    blocks, packed = _packed(q, k, v, g, beta)
+    o, *saved = _forward(
+        *packed, save=save, blocks=blocks, interpret=how[0], one_pass=how[1]
+    )
+    o = _settled(o.reshape(B, H, -1, o.shape[-1])[:, :, :T], how[0])
+    return o, tuple(saved)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda(q, k, v, g, beta, how):
+    return _apply(q, k, v, g, beta, how, save=False)[0]
+
+
+def _kda_fwd(q, k, v, g, beta, how):
+    o, saved = _apply(q, k, v, g, beta, how, save=True)
+    return o, (q, k, v, g, beta, *saved)
+
+
+def _kda_bwd(how, res, do):
+    interpret, one_pass = how
+    *inputs, states, inverses = res
+    B, H, T, _ = inputs[0].shape
+    blocks, packed = _packed(*inputs)
+    do = _flat(do, packed[0].shape[1])
+    *grads, dbeta = _backward(
+        *packed, do, states, inverses,
+        blocks=blocks, interpret=interpret, one_pass=one_pass,
+    )
+    grads = [x[:, :T].reshape(B, H, T, -1) for x in grads]
+    grads.append(dbeta.reshape(B, H, -1)[:, :, :T])
+    return tuple(
+        _settled(x.astype(p.dtype), interpret) for x, p in zip(grads, inputs)
+    )
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
+
+
+def kda(q, k, v, g, beta, *, interpret: InterpretArg = None):
+    """``ops.kda.kda_chunked`` by the kernels: ``q``, ``k``, ``g`` (B, H,
+    T, dk), ``v`` (B, H, T, dv), ``beta`` (B, H, T), :func:`takes` their
+    shapes; ``o`` (B, H, T, dv) float32.  Differentiable by all five."""
+    _, operands = vary_together(q, k, v, g, beta)
+    return _kda(*operands, (default_interpret(interpret), _ONE_PASS))

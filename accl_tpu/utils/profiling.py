@@ -116,10 +116,14 @@ drops ``op_name``, so a reader joins the two by instruction name
 ``accl.attn::kda``       ``_kda_partial`` (a KDA mixer, ``LayerKind.mixer``
                          ``"kda"``): the core, ``ops/kda.py``
                          ``kda_chunked`` from normalised q, k, v, the
-                         log-decay and beta to ``o``, forward and backward,
-                         its scan over the chunks with it (a loop of the
-                         compiled step: the body's instructions are device
-                         events of their own)
+                         log-decay and beta to ``o``, forward and backward:
+                         at whole-lane heads the kernels ``kda_fwd`` (the
+                         forward, and under ``remat`` the replayed one) and
+                         ``kda_bwd`` of ``ops/pallas/kda.py``, the scan over
+                         the chunks fused into them; at any other shape the
+                         XLA form, whose scan is a loop of the compiled
+                         step (the body's instructions are device events
+                         of their own)
 ``accl.attn::kda_proj``  the same: everything round the core (the seven
                          projections, the three convolutions and SiLU, the
                          L2 norms, the gate and beta, the output norm and

@@ -187,7 +187,29 @@ def test_placement_kernel_compiles_at_the_held_cells_shapes(one_chip, case):
     assert "place_rows" in text
 
 
+def test_kda_kernels_compile_at_ling3_shapes(one_chip):
+    """``train_ling3_t8192_b2``'s KDA core alone, forward and gradient: 2 x
+    32 head-sequences of 8,192 tokens, ``dk = dv = 128``, float32: Mosaic
+    takes every tile and the VMEM each kernel asks for."""
+    from accl_tpu.ops.pallas import kda
+
+    B, H, T, d = 2, 32, 8192, 128
+    rows = jax.ShapeDtypeStruct((B, H, T, d), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((B, H, T), jnp.float32, sharding=one_chip)
+    core = lambda *a: kda.kda(*a, interpret=False)
+    text = jax.jit(core).lower(rows, rows, rows, rows, beta).compile().as_text()
+    assert "kda_fwd" in text and "kda_bwd" not in text
+    text = jax.jit(jax.grad(
+        lambda *a: core(*a).sum(), argnums=(0, 1, 2, 3, 4)
+    )).lower(rows, rows, rows, rows, beta).compile().as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+
+
 def _step_text(cell_name, n_layers, device, monkeypatch, layers=None):
+    return _step(cell_name, n_layers, device, monkeypatch, layers).as_text()
+
+
+def _step(cell_name, n_layers, device, monkeypatch, layers=None):
     """A train cell's step (``make_sharded_train_step`` on a world of the
     one described chip, the cell's widths, batch and length; its depth cut
     to its first ``n_layers``, which the table's gradient does not see, or
@@ -197,7 +219,7 @@ def _step_text(cell_name, n_layers, device, monkeypatch, layers=None):
     from accl_tpu.models.transformer import normalize_spec, param_specs
     from perfbench import manifest
 
-    for module in ("attention", "grouped_matmul", "place_rows"):
+    for module in ("attention", "grouped_matmul", "place_rows", "kda"):
         monkeypatch.setattr(
             importlib.import_module("accl_tpu.ops.pallas." + module),
             "default_interpret", lambda interpret=None: bool(interpret),
@@ -228,7 +250,7 @@ def _step_text(cell_name, n_layers, device, monkeypatch, layers=None):
         jnp.int32, sharding=NamedSharding(mesh, P()),
     )
     step, _ = make_sharded_train_step(cfg, mesh, lr=float(cell["traffic"]["lr"]))
-    return step.lower(params, tok, tok).compile().as_text()
+    return step.lower(params, tok, tok).compile()
 
 
 def _table_ops(text, V, D):
@@ -303,19 +325,42 @@ def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
 ):
     """One KDA expert layer and the latent expert layer of the cell's
     seven, 2 x 8,192 tokens, under ``remat`` as the cell runs: the KDA
-    core is under ``accl.attn::kda`` with a loop over the chunks (whose
-    body ``scopes_of`` does not walk: the driver's ``scoped_instructions``
-    does), the latent core is the flash kernels, the held rows are placed
-    by the kernel, and no array is a square of the length (memory linear
-    in T)."""
+    core is the kernels ``kda_fwd`` / ``kda_bwd`` under ``accl.attn::kda``
+    as ``flash_fwd`` / ``flash_bwd`` are under ``accl.attn::mla`` (a KDA
+    layer: the forward, the replayed forward and the backward), the scan
+    over the chunks fused into them (no ``while`` under the scope, nothing
+    of it in a loop's body, which ``scopes_of`` does not walk and the
+    driver's ``scoped_instructions`` does), no float32 array of the
+    inputs' size there but the kernels' operands and results, the step's
+    scratch no more than the parent's, the latent core the flash kernels,
+    the held rows placed by the kernel, and no array a square of the
+    length (memory linear in T)."""
     from perfbench import scope_ops
     from perfbench.drivers import train_steps_ling3
 
-    text = _step_text("train_ling3_t8192_b2", 2, v5e, monkeypatch, layers=(5, 6))
+    compiled = _step("train_ling3_t8192_b2", 2, v5e, monkeypatch, layers=(5, 6))
+    text = compiled.as_text()
     entry = scope_ops.scopes_of(text)
     every = train_steps_ling3.scoped_instructions(text)
-    assert any(n.startswith("while") for n in entry["accl.attn::kda"])
-    assert set(entry["accl.attn::kda"]) < set(every["accl.attn::kda"])
+    core = entry["accl.attn::kda"]
+    kda_layers = 1
+    assert sum(n.startswith("kda_fwd") for n in core) == 2 * kda_layers
+    assert sum(n.startswith("kda_bwd") for n in core) == kda_layers
+    assert not any(n.startswith("while") for n in core)
+    assert set(core) == set(every["accl.attn::kda"])
+    # whatever else under the scope is as large as q is a view of a
+    # kernel's operand or result, no array of its own
+    start = text.find("\nENTRY ")
+    made = dict(re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = (.*)$", text[start: text.find("\n}", start)], re.M
+    ))
+    for name in core:
+        shape, op = re.match(r"(\(.*?\)|\S+) ([\w-]+)\(", made[name]).groups()
+        if re.search(r"f32\[(2,32|64),8192,128\]", shape):
+            assert op in ("custom-call", "bitcast", "get-tuple-element"), name
+    # the parent's XLA form, the same cut: 10,363,852,800 bytes of scratch
+    # (6,177,251,840 when the kernels came)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 10_363_852_800
     assert every["accl.attn::kda_proj"] and entry["accl.attn::latent"]
     assert any("flash_fwd" in n for n in entry["accl.attn::mla"])
     assert any("flash_bwd" in n for n in entry["accl.attn::mla"])
