@@ -1548,46 +1548,32 @@ def _kda_partial(h, lp, n_heads_local, kda):
     The sizes are the tree's; ``kda`` carries what the shapes do not say,
     the gate's ``lower_bound`` and the output norm's ``eps``.  The matmuls
     take the activations' type; the convolutions, SiLU, the L2 norms, the
-    gate, beta, the core and the output norm are float32.  Everything but
-    the core runs under the device scope ``accl.attn::kda_proj``, the core
-    (from normalised q, k, v, the log-decay and beta to ``o``:
-    ``ops.kda.kda_chunked``) under ``accl.attn::kda``."""
-    from ..ops.kda import kda_chunked
+    gate, beta, the core and the output norm are float32, in either
+    lowering of the three chains round the core (``ops.kda``: ``conv_in``
+    from a projection to q, k or v, ``decay_in`` to the log-decay,
+    ``gated_out`` from ``o`` to what ``wo`` takes; at heads of whole lanes
+    each is one Mosaic kernel forward and one backward that keep the
+    chain's float32 values in VMEM and save only the projection, at any
+    other shape XLA's fusions).  Everything but the core runs under the
+    device scope ``accl.attn::kda_proj``, the core (from normalised q, k,
+    v, the log-decay and beta to ``o``: ``ops.kda.kda_chunked``) under
+    ``accl.attn::kda``."""
+    from ..ops.kda import conv_in, decay_in, gated_out, kda_chunked
 
-    B, T, _ = h.shape
     H = n_heads_local
     f32 = jnp.float32
-    heads = lambda t: t.reshape(B, T, H, -1).transpose(0, 2, 1, 3)
-
-    def conv_silu(x, taps):
-        """Causal depthwise convolution (zero left padding, the last tap
-        the current token's), then SiLU."""
-        n = taps.shape[0]
-        x = jnp.pad(x.astype(f32), ((0, 0), (n - 1, 0), (0, 0)))
-        return jax.nn.silu(
-            sum(x[:, i:i + T] * taps[i].astype(f32) for i in range(n))
-        )
-
-    def unit(x):
-        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
     with device_scope("accl.attn::kda_proj"):
-        q = heads(conv_silu(h @ lp["wq"], lp["conv_q"]))
-        k = heads(conv_silu(h @ lp["wk"], lp["conv_k"]))
-        v = heads(conv_silu(h @ lp["wv"], lp["conv_v"]))
-        q = unit(q) * q.shape[-1] ** -0.5
-        k = unit(k)
-        f = heads((h @ lp["wf"]).astype(f32) + lp["dt_bias"].astype(f32))
-        rate = jnp.exp(lp["a_log"].astype(f32))[None, :, None, None]
-        g = kda["lower_bound"] * jax.nn.sigmoid(rate * f)
+        q = conv_in(h @ lp["wq"], lp["conv_q"], H, unit=True,
+                    scale=(lp["wq"].shape[1] // H) ** -0.5)
+        k = conv_in(h @ lp["wk"], lp["conv_k"], H, unit=True)
+        v = conv_in(h @ lp["wv"], lp["conv_v"], H, unit=False)
+        g = decay_in(h @ lp["wf"], lp["dt_bias"], lp["a_log"], kda["lower_bound"])
         beta = jax.nn.sigmoid((h @ lp["wbeta"]).astype(f32)).transpose(0, 2, 1)
     with device_scope("accl.attn::kda"):
         o = kda_chunked(q, k, v, g, beta)                 # (B, H, T, dv) f32
     with device_scope("accl.attn::kda_proj"):
-        o = _rmsnorm(o, lp["o_norm"].astype(f32), eps=kda["eps"])
-        gate = jax.nn.sigmoid((h @ lp["wg"]).astype(f32))
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, -1) * gate
-        return o.astype(h.dtype) @ lp["wo"]
+        o = gated_out(o, h @ lp["wg"], lp["o_norm"], kda["eps"], h.dtype)
+        return o @ lp["wo"]
 
 
 def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,

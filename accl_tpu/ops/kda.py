@@ -49,6 +49,11 @@ diagonal splits ``exp(G_t - G_j)`` at the row block's start (both factors
 at most 1), a diagonal block at its own middle token (factors within
 ``exp(+-SUB / 2 * 5)``, e^40 at the bound).  What underflows is smaller
 than float32 resolves beside the terms it is added to.
+
+THE CHAINS ROUND THE CORE (the end of this module): what the mixer runs in
+float32 between a projection and the core and between the core and ``wo``
+(:func:`conv_in`, :func:`decay_in`, :func:`gated_out`), by the same kind
+of choice (``ops.pallas.kda_mixer.takes``).
 """
 
 from __future__ import annotations
@@ -58,6 +63,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .pallas import kda as _kernels
+from .pallas import kda_mixer as _chains
 
 #: tokens a chunk (one step of the scan over the states) and a sub-block:
 #: the kernels' own, which take no other
@@ -207,3 +213,79 @@ def _xla_form(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB):
         "bhntj,nbhje->bhnte", P, u
     )
     return o.reshape(B, H, N * chunk, dv)[:, :, :T]
+
+
+# -- the mixer's float32 chains round the core ---------------------------------
+#
+# What ``models.transformer._kda_partial`` runs between a projection and the
+# core, and between the core and ``wo``: float32 from the projection (which
+# has the matmuls' type) on, by ONE OF TWO lowerings picked from the shapes
+# (``ops.pallas.kda_mixer.takes``: heads of whole lanes), the Mosaic kernels
+# there (one pass over HBM a chain, forward and backward) or the XLA forms
+# here (plain ``jax.numpy``: every other shape's, and the kernels' oracle).
+
+
+def conv_in(x, taps, heads: int, *, unit: bool, scale: float = 1.0):
+    """q, k or v from its projection ``x`` (B, T, H d): the causal depthwise
+    convolution by ``taps`` (n, H d) (zero left padding, the last tap the
+    current token's), SiLU, under ``unit`` the L2 norm over a head's
+    columns (``+ 1e-6`` inside the root) times ``scale``; float32
+    (B, H, T, d)."""
+    if _chains.takes(x.shape[-1], heads, taps.shape[0]):
+        return _chains.conv_in(x, taps, heads, unit=unit, scale=scale)
+    return _xla_conv_in(x, taps, heads, unit=unit, scale=scale)
+
+
+def _heads(x, heads):
+    B, T, _ = x.shape
+    return x.reshape(B, T, heads, -1).transpose(0, 2, 1, 3)
+
+
+def _xla_conv_in(x, taps, heads, *, unit, scale=1.0):
+    T, n = x.shape[1], taps.shape[0]
+    x = jnp.pad(x.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0)))
+    y = _heads(jax.nn.silu(
+        sum(x[:, i:i + T] * taps[i].astype(jnp.float32) for i in range(n))
+    ), heads)
+    if unit:
+        y = y * lax.rsqrt(
+            jnp.sum(y * y, axis=-1, keepdims=True) + _chains.UNIT_EPS
+        )
+        if scale != 1.0:
+            y = y * scale
+    return y
+
+
+def decay_in(x, dt_bias, a_log, lower_bound: float):
+    """The log-decay a channel from its projection ``x`` (B, T, H d):
+    ``lower_bound * sigmoid(exp(a_log) (x + dt_bias))``, ``dt_bias`` a
+    channel, ``a_log`` (H,) a head; float32 (B, H, T, d)."""
+    if _chains.takes(x.shape[-1], a_log.shape[0]):
+        return _chains.decay_in(x, dt_bias, a_log, lower_bound)
+    return _xla_decay_in(x, dt_bias, a_log, lower_bound)
+
+
+def _xla_decay_in(x, dt_bias, a_log, lower_bound):
+    f = _heads(
+        x.astype(jnp.float32) + dt_bias.astype(jnp.float32), a_log.shape[0]
+    )
+    rate = jnp.exp(a_log.astype(jnp.float32))[None, :, None, None]
+    return lower_bound * jax.nn.sigmoid(rate * f)
+
+
+def gated_out(o, gate, o_norm, eps: float, dtype):
+    """What ``wo`` takes from the core's ``o`` (B, H, T, d): the RMS norm a
+    head with the scale ``o_norm`` (d,), times ``sigmoid`` of the gate's
+    projection ``gate`` (B, T, H d); (B, T, H d) in ``dtype``."""
+    if _chains.takes(gate.shape[-1], o.shape[1]):
+        return _chains.gated_out(o, gate, o_norm, eps, dtype)
+    return _xla_gated_out(o, gate, o_norm, eps, dtype)
+
+
+def _xla_gated_out(o, gate, o_norm, eps, dtype):
+    B, _, T, d = o.shape
+    o = o.astype(jnp.float32)
+    o = o * lax.rsqrt(jnp.sum(o * o, axis=-1, keepdims=True) / d + eps)
+    o = o * o_norm.astype(jnp.float32)
+    o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    return (o * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)

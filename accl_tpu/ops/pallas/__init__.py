@@ -21,6 +21,10 @@ TPU kernels.
 * ``kda`` — the KDA core (the chunked gated delta rule of ``ops/kda.py``)
   as a forward and a backward kernel that keep what a chunk makes in
   VMEM, the scan over the chunk states fused into them.
+* ``kda_mixer`` — the KDA mixer's float32 chains round that core (the
+  convolutions, SiLU and L2 norms of q, k, v; the decay's gate; the output
+  norm and gate) as one pass over HBM each, forward and backward, the move
+  to head-major and back a block's index map.
 
 On non-TPU backends every kernel runs under the Pallas TPU interpreter so
 the CI tier exercises the identical kernel code (see

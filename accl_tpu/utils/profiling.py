@@ -124,10 +124,22 @@ drops ``op_name``, so a reader joins the two by instruction name
                          XLA form, whose scan is a loop of the compiled
                          step (the body's instructions are device events
                          of their own)
-``accl.attn::kda_proj``  the same: everything round the core (the seven
-                         projections, the three convolutions and SiLU, the
-                         L2 norms, the gate and beta, the output norm and
-                         gate, ``wo``)
+``accl.attn::kda_proj``  the same: everything round the core.  The seven
+                         projections and ``wo`` are XLA's matmuls and beta
+                         its fusion; the three float32 chains between them
+                         and the core (``ops/kda.py``) are, at heads of
+                         whole lanes, the kernels of ``ops/pallas/
+                         kda_mixer.py``, one pass over HBM each:
+                         ``kda_in_fwd`` / ``kda_in_bwd`` (q, k, v: the
+                         convolution, SiLU, the L2 norm, to head-major),
+                         ``kda_decay_fwd`` / ``kda_decay_bwd`` (the
+                         log-decay's gate) and ``kda_out_fwd`` /
+                         ``kda_out_bwd`` (the output norm and gate, back
+                         from head-major); under ``remat`` a layer runs
+                         each forward kernel twice.  At any other shape
+                         XLA's fusions.  Float32 in either lowering,
+                         inside the kernels too (only the projections
+                         and their cotangents have the matmuls' type)
 ``accl.attn::blockdiff`` ``_attn_partial`` under ``TransformerConfig.
                          diffusion``: the attention call on ``[noisy ;
                          clean]`` under the block-diffusion layout (the

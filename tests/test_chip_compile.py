@@ -205,6 +205,48 @@ def test_kda_kernels_compile_at_ling3_shapes(one_chip):
     assert "kda_fwd" in text and "kda_bwd" in text
 
 
+#: a chain of ``train_ling3_t8192_b2``'s KDA mixer: (the kernels' names,
+#: the call, its operands' shapes and types)
+def _mixer_chains():
+    from accl_tpu.ops.pallas import kda_mixer as km
+
+    B, H, T, d = 2, 32, 8192, 128
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    flat, taps = ((B, T, H * d), bf16), ((4, H * d), bf16)
+    conv = lambda **how: lambda x, t: km.conv_in(x, t, H, interpret=False, **how)
+    return {
+        "q": ("kda_in", conv(unit=True, scale=d ** -0.5), [flat, taps]),
+        "v": ("kda_in", conv(unit=False), [flat, taps]),
+        "decay": (
+            "kda_decay", lambda *a: km.decay_in(*a, -5.0, interpret=False),
+            [flat, ((H * d,), f32), ((H,), f32)],
+        ),
+        "out": (
+            "kda_out", lambda *a: km.gated_out(*a, 1e-6, bf16, interpret=False),
+            [((B, H, T, d), f32), flat, ((d,), bf16)],
+        ),
+    }
+
+
+@pytest.mark.parametrize("chain", ["q", "v", "decay", "out"])
+def test_kda_mixer_kernels_compile_at_ling3_shapes(one_chip, chain):
+    """``train_ling3_t8192_b2``'s three float32 chains round the KDA core,
+    each alone, forward and gradient: bfloat16 projections of 2 x 8,192
+    rows and 32 heads of 128 columns (q with the norm and the scale, v
+    without, the decay, the output norm and gate): Mosaic takes every
+    tile, the unaligned row windows of the convolution and the VMEM each
+    kernel asks for."""
+    name, call, operands = _mixer_chains()[chain]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in operands]
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert name + "_fwd" in text and name + "_bwd" not in text
+    text = jax.jit(jax.grad(
+        lambda *a: call(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(len(args))),
+    )).lower(*args).compile().as_text()
+    assert name + "_bwd" in text
+
+
 def _step_text(cell_name, n_layers, device, monkeypatch, layers=None):
     return _step(cell_name, n_layers, device, monkeypatch, layers).as_text()
 
@@ -219,7 +261,7 @@ def _step(cell_name, n_layers, device, monkeypatch, layers=None):
     from accl_tpu.models.transformer import normalize_spec, param_specs
     from perfbench import manifest
 
-    for module in ("attention", "grouped_matmul", "place_rows", "kda"):
+    for module in ("attention", "grouped_matmul", "place_rows", "kda", "kda_mixer"):
         monkeypatch.setattr(
             importlib.import_module("accl_tpu.ops.pallas." + module),
             "default_interpret", lambda interpret=None: bool(interpret),
@@ -331,7 +373,12 @@ def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
     over the chunks fused into them (no ``while`` under the scope, nothing
     of it in a loop's body, which ``scopes_of`` does not walk and the
     driver's ``scoped_instructions`` does), no float32 array of the
-    inputs' size there but the kernels' operands and results, the step's
+    inputs' size there but the kernels' operands and results; the mixer's
+    float32 chains round the core are the kernels of ``kda_mixer`` under
+    ``accl.attn::kda_proj`` (q, k and v in, the decay in, out: each
+    forward kernel twice, each backward once), and XLA makes no float32
+    array of a projection's size there, let alone a backward
+    convolution's stack of four (``f32[4,2,8192,4096]``); the step's
     scratch no more than the parent's, the latent core the flash kernels,
     the held rows placed by the kernel, and no array a square of the
     length (memory linear in T)."""
@@ -342,26 +389,35 @@ def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
     text = compiled.as_text()
     entry = scope_ops.scopes_of(text)
     every = train_steps_ling3.scoped_instructions(text)
-    core = entry["accl.attn::kda"]
+    core, chains = entry["accl.attn::kda"], entry["accl.attn::kda_proj"]
     kda_layers = 1
     assert sum(n.startswith("kda_fwd") for n in core) == 2 * kda_layers
     assert sum(n.startswith("kda_bwd") for n in core) == kda_layers
     assert not any(n.startswith("while") for n in core)
     assert set(core) == set(every["accl.attn::kda"])
-    # whatever else under the scope is as large as q is a view of a
+    assert set(chains) == set(every["accl.attn::kda_proj"])
+    kernels = {
+        "kda_in_fwd": 6, "kda_in_bwd": 3, "kda_decay_fwd": 2,
+        "kda_decay_bwd": 1, "kda_out_fwd": 2, "kda_out_bwd": 1,
+    }
+    for kernel, count in kernels.items():
+        assert sum(n.startswith(kernel) for n in chains) == count * kda_layers, kernel
+    # whatever else under either scope is as large as q is a view of a
     # kernel's operand or result, no array of its own
     start = text.find("\nENTRY ")
     made = dict(re.findall(
         r"^\s*(?:ROOT )?%(\S+) = (.*)$", text[start: text.find("\n}", start)], re.M
     ))
-    for name in core:
+    for name in core + chains:
         shape, op = re.match(r"(\(.*?\)|\S+) ([\w-]+)\(", made[name]).groups()
-        if re.search(r"f32\[(2,32|64),8192,128\]", shape):
+        assert "f32[4,2,8192,4096]" not in shape, name
+        if re.search(r"f32\[(2,32|64),8192,128\]|f32\[2,8192,4096\]", shape):
             assert op in ("custom-call", "bitcast", "get-tuple-element"), name
-    # the parent's XLA form, the same cut: 10,363,852,800 bytes of scratch
-    # (6,177,251,840 when the kernels came)
-    assert compiled.memory_analysis().temp_size_in_bytes <= 10_363_852_800
-    assert every["accl.attn::kda_proj"] and entry["accl.attn::latent"]
+    # the parent's kernels for the core alone, the same cut: 6,177,251,840
+    # bytes of scratch (10,363,852,800 under the XLA form of the core;
+    # 4,722,778,112 when the chains' kernels came)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6_177_251_840
+    assert entry["accl.attn::latent"]
     assert any("flash_fwd" in n for n in entry["accl.attn::mla"])
     assert any("flash_bwd" in n for n in entry["accl.attn::mla"])
     assert re.search(r"%place_rows\S* = bf16\[16384,2560\]", text)
