@@ -3,7 +3,7 @@
 Usage::
 
     python -m accl_tpu.analysis            # analyze the package, report
-    python -m accl_tpu.analysis --check    # quiet gate mode (CI / bench)
+    python -m accl_tpu.analysis --check    # quiet gate mode (CI)
     python -m accl_tpu.analysis --json     # machine-readable findings
 
     from accl_tpu.analysis import run_checks

@@ -1,8 +1,7 @@
 """acclint CLI: ``python -m accl_tpu.analysis``.
 
 Exit status: 0 when no unsuppressed findings, 1 otherwise, 2 on usage
-errors — so it slots straight into shell gates (CI; bench.py runs the
-same checks in-process as a capture gate).
+errors — so it slots straight into shell gates (CI).
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ The analyzer encodes the project's concurrency/architecture invariants
 as named, individually-suppressible checks (the list lives in
 ``accl_tpu.analysis.CHECKS``).  Everything here is stdlib-only — the
 analyzer must be runnable from CI shells and jax-free processes, and
-fast enough to gate every bench capture.
+fast enough to gate every CI run.
 
 Suppression syntax (audited-safe sites)::
 

@@ -270,11 +270,10 @@ class Fabric:
     #: emulated wire's bandwidth model.  The in-process transports move
     #: frames at memcpy speed (~10 GB/s), which is no wire at all — a
     #: compression sweep measured there reads codec cost only.  With a
-    #: rate set (``set_wire_rate`` / ACCL_WIRE_GBPS, read by the bench
-    #: harness), every transmit pays payload_bytes/rate of wall clock,
-    #: serialized per sender like a real NIC — deterministic, byte-
-    #: proportional, honest about WHAT is being measured (the artifact
-    #: records the modeled rate).
+    #: rate set (``set_wire_rate``; the autotuner's ``--wire-gbps``),
+    #: every transmit pays payload_bytes/rate of wall clock, serialized
+    #: per sender like a real NIC — deterministic, byte-proportional,
+    #: honest about WHAT is being measured.
     _wire_rate_Bps: Optional[float] = None
 
     def set_wire_rate(self, gbps: Optional[float]) -> None:
@@ -337,7 +336,7 @@ class Fabric:
 
     def wire_class_stats(self) -> dict:
         """Per-link-class byte/message counters + the modeled rates —
-        the telemetry evidence the topology capture gate counter-asserts
+        the telemetry evidence tests/test_topology.py counter-asserts
         (hierarchical must cut DCN bytes by ~the slice factor)."""
         lock = getattr(self, "_class_lock", None)
         if lock is None:

@@ -793,7 +793,7 @@ class XLAGangContext:
         completed with RECEIVE_TIMEOUT (its gang never assembled)."""
         # overlap plane: a FULL drain first — every launched program's
         # requests complete normally before any state is abandoned (the
-        # soft_reset drain-point contract, asserted by chip_soak).
+        # soft_reset drain-point contract).
         # BOUNDED: soft_reset is the recovery path, so a wedged device
         # call must not also wedge recovery — past the bound the reset
         # proceeds and the stragglers complete (or fail) from the
@@ -2729,9 +2729,8 @@ class XLAEngine(StreamPortMixin, BaseEngine):
         the live slot state here is parked gang rendezvous slots,
         unmatched p2p posts, and undrained stream-port chunks.  Lines for
         occupied state carry the ``rxbuf`` token WITHOUT ``IDLE`` so the
-        soak/stress leak filters (benchmarks/chip_soak.py,
-        tests/test_soak.py) read this tier's dump exactly like the
-        emulator pool's — a clean engine emits no ``rxbuf`` line at all."""
+        soak/stress leak filters (tests/test_soak.py) read this tier's
+        dump exactly like the emulator pool's — a clean engine emits no ``rxbuf`` line at all."""
         lines = [
             "XLA gang rx state "
             f"(device={self.device}, "

@@ -534,8 +534,7 @@ class ACCL:
         ``ACCL_VERIFY_INTERVAL``, 8) — the verifier exists to check
         exactly that kind of agreement, so arming it divergently is
         self-defeating.  Facade-local: no engine config write, no
-        device traffic; the per-call cost is one crc32 + a ring append
-        (gated <=5% by ``parse_results.check_verify``)."""
+        device traffic; the per-call cost is one crc32 + a ring append."""
         if not enabled:
             v, self._contract = self._contract, None
             if v is not None:

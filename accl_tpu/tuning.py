@@ -11,9 +11,7 @@ Three pieces:
 
 * the measurement harness (:func:`rank_op` / :func:`run_group_op`) — one
   synchronized collective run across a group of rank handles, returning
-  the max engine-reported duration.  ``benchmarks/sweep.py`` drives its
-  CSV sweeps through these same functions, so the autotuner and the
-  committed sweep artifacts measure identically.
+  the max engine-reported duration.
 * :func:`autotune` — sweeps candidate register sets (algorithm x
   ``RING_SEGMENTS`` x eager threshold, tier-appropriate) per
   (collective, size) and emits a :class:`TuningPlan`: a JSON document
@@ -274,7 +272,7 @@ class TuningPlan:
 
 
 # ---------------------------------------------------------------------------
-# Measurement harness (shared with benchmarks/sweep.py)
+# Measurement harness
 # ---------------------------------------------------------------------------
 
 
@@ -393,8 +391,7 @@ def _candidates(
 ) -> List[Dict[str, object]]:
     """Tier-appropriate register sets to race for one collective.  The
     empty dict (the defaults) is always candidate 0 — a plan can only
-    ever *beat* the defaults, never silently regress them (the >5%
-    not-slower gate in parse_results holds the artifact to that)."""
+    ever *beat* the defaults, never silently regress them."""
     cands: List[Dict[str, object]] = [{}]
     if tier in ("xla", "dist"):
         if op == "allreduce":
@@ -446,7 +443,7 @@ def _candidates(
         # like any register — off is always candidate 0 (the defaults),
         # so a lane only wins where the byte saving beats its cast cost
         # by the hysteresis margin (the wall-clock race; correctness is
-        # gated separately by check_compression's convergence leg)
+        # tests/test_wire.py's subject)
         cands += [
             {"wire_dtype": wire_dtype_value(wd)}
             for wd in wire_dtypes
@@ -529,8 +526,7 @@ def autotune(
     number a cached-plan dispatch path will see.  A non-default
     candidate only wins by beating the defaults by ``margin`` (ties go
     to the defaults): host-timer noise must never bake a fake winner
-    into the plan, which the committed artifacts' <=5% not-slower gate
-    (parse_results.check_tuned_not_slower) would then refuse.
+    into the plan.
     Registers are restored to the defaults before returning (the group
     keeps serving)."""
     world = len(group)
@@ -730,15 +726,15 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--csv-default", default=None,
         help="also write the race's defaults-candidate measurements as "
-             "a sweep CSV (one session with --csv-tuned: the committed "
-             "tuned-vs-default pair parse_results --check-tuned gates)",
+             "a sweep CSV (one session with --csv-tuned: a "
+             "tuned-vs-default pair)",
     )
     ap.add_argument(
         "--csv-tuned", default=None,
         help="also write the race's per-point winner measurements as a "
              "sweep CSV (the winner is the defaults unless a candidate "
-             "beat them by --margin, so the pair passes the not-slower "
-             "gate unless the selection logic itself regresses)",
+             "beat them by --margin, so no row is slower than its "
+             "default unless the selection logic itself regresses)",
     )
     ap.add_argument(
         "--platform", default=None,
@@ -833,9 +829,8 @@ def main(argv=None) -> int:
                             "gbps"],
             )
             w.writeheader()
-            # same writer-side refusal as benchmarks/sweep.py write_row:
             # a sentinel/garbage duration must be an ERROR here, not a
-            # committed artifact
+            # row of the file
             ceiling = float(
                 os.environ.get("ACCL_SWEEP_GBPS_CEILING", "10000")
             )

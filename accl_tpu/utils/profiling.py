@@ -171,6 +171,9 @@ drops ``op_name``, so a reader joins the two by instruction name
                          probability, sum a token's k results; under held
                          experts the weighted ``place_rows`` kernel or the
                          k gathers, by the same rule
+``accl.moe::shared``     the same, where the layer's parameters hold a
+                         ``shared`` expert: the dense gated-SiLU FFN every
+                         token passes through, added to the routed result
 ``accl.embed::grad``     ``models/transformer.py`` ``_gathered_rows_bwd``,
                          backward only: the embedding lookup's cotangent
                          placed on the table, by one matmul against the

@@ -737,8 +737,8 @@ def leg_zoo(n_chips: int) -> dict:
 
 
 def _full_width_configs():
-    """The flagship (bench.py ``_bench_train_mfu``) and decode
-    (``_bench_decode_throughput``) configurations at their full widths."""
+    """The flagship train step's and the decode leg's configurations
+    at their full widths."""
     import jax.numpy as jnp
 
     from accl_tpu.models import TransformerConfig

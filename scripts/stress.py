@@ -8,8 +8,8 @@ verifies payload integrity on every iteration (the reference relies on the
 gtest assertions around its loop).
 
 Usage:
-    python benchmarks/stress.py --backend emulator --world 4 --iters 500
-    python benchmarks/stress.py --backend native --iters 2000
+    python scripts/stress.py --backend emulator --world 4 --iters 500
+    python scripts/stress.py --backend native --iters 2000
 """
 
 from __future__ import annotations

@@ -61,6 +61,52 @@ def launch_with_port_retry(fn, world, attempts=3, retry_if=None, **kwargs):
     raise last
 
 
+def record_rank_traces(outdir, world=4):
+    """One chrome trace a rank of a small gang group, written under
+    ``outdir``: a few collectives (the cross-rank s/t/f flows), a plain
+    send -> recv pair between ranks 0 and 1 (the p2p flow) and two
+    batched windows (ring-resident spans).  What the merge CLI's tests
+    merge; returns the files by rank."""
+    import numpy as np
+
+    from accl_tpu.core import xla_group
+
+    n = 256
+    g = xla_group(world)
+    try:
+        sends = [a.create_buffer_from(np.ones(n, np.float32)) for a in g]
+        outs = [[a.create_buffer(n, np.float32) for a in g] for _ in range(2)]
+
+        def collectives(a, r):
+            for _ in range(3):
+                a.allreduce(sends[r], outs[0][r], n)
+
+        def pair(a, r):
+            if r == 0:
+                a.send(sends[0], n, 1, tag=7)
+            elif r == 1:
+                a.recv(outs[0][1], n, 0, tag=7)
+
+        def window(a, r):
+            with a.batch():
+                reqs = [a.allreduce(sends[r], out[r], n, run_async=True)
+                        for out in outs]
+            for req in reqs:
+                assert req.wait(60)
+                req.check()
+
+        for step in (collectives, pair, window, window):
+            run_parallel(g, step)
+        paths = [os.path.join(str(outdir), f"trace_rank{r}.json")
+                 for r in range(world)]
+        for a, path in zip(g, paths):
+            a.export_chrome_trace(path)
+        return paths
+    finally:
+        for a in g:
+            a.deinit()
+
+
 # -- a recorded profiler trace (.xplane.pb), read back ------------------------
 
 

@@ -303,6 +303,24 @@ def test_inflight_window_per_key_depth():
 # ---------------------------------------------------------------------------
 
 
+def _record_blocked_admissions(group, monkeypatch):
+    """Who ever BLOCKS for a grant: the names of the threads that reach
+    the shared arbiter's condition wait, appended as they do.  An
+    admission granted inline or by its own pump never gets there, so a
+    tenant whose threads are absent was never held behind another's
+    grant, however slowly a shared CPU ran either."""
+    arb = group[0]._arbiter
+    assert all(a._arbiter is arb for a in group)
+    cv_wait, blocked = arb._cv.wait, []
+
+    def counted_wait(timeout=None):
+        blocked.append(threading.current_thread().name)
+        return cv_wait(timeout)
+
+    monkeypatch.setattr(arb._cv, "wait", counted_wait, raising=False)
+    return arb, blocked
+
+
 def _register_all(group, cls, comm=None, name=None, **quota):
     def reg(a, r):
         a.set_tenant_class(cls, comm=comm, name=name)
@@ -565,22 +583,23 @@ def test_gang_two_tenant_ring_shares_match_quotas():
 
 
 @pytest.mark.chaos
-def test_adversarial_flooder_vs_guaranteed_p99(fault_plan):
+def test_adversarial_flooder_vs_guaranteed_p99(fault_plan, monkeypatch):
     """A BEST_EFFORT flooder plus a GUARANTEED small-message tenant on
     the same fabric under a seeded fault plan (every flooder-comm frame
-    wire-delayed): the guaranteed tenant's p99 — read from the live
-    ``/tenants`` route, the histograms the monitor plane serves — stays
-    within its bound while the flooder absorbs the backpressure: its
-    admissions queue at the arbiter, its grant waits dwarf the
-    guaranteed tenant's, and its own tail carries the congestion."""
-    # eager-sized flooder payloads (8 KiB = 2 wire segments): the
-    # seeded per-message delay congests the shared link — the fabric
-    # queues everything behind a delayed frame — without tripping the
-    # rendezvous deadline, so the pressure is pure queueing
+    wire-delayed).  What the arbiter DECIDES is asserted, as counts and
+    an order, read from the live ``/tenants`` route the monitor plane
+    serves: the flooder absorbs the backpressure (its admissions queue
+    at the arbiter, one in flight a rank, and only its threads ever
+    block for a grant), every guaranteed call is granted in its own
+    admission, and both latency histograms hold every call.  How fast a
+    shared CPU ran the fabric is no part of it."""
+    # 64 KiB flooder payloads ride rendezvous: a SERIALIZED delayed
+    # handshake a call, so the first call of a rank is still in flight
+    # when the second asks for admission
     FLOOD_CALLS = 16
-    FLOOD_COUNT = 16384       # 64 KiB: rendezvous, a SERIALIZED delayed
-    SERVE_CALLS = 40          # handshake per call (eager frames would
-    P99_BOUND_US = 16384.0    # amortize their absolute delays in parallel)
+    FLOOD_COUNT = 16384
+    SERVE_CALLS = 40
+    WINDOW_SHARE = 1
 
     g = emulated_group(2)
     try:
@@ -601,9 +620,11 @@ def test_adversarial_flooder_vs_guaranteed_p99(fault_plan):
 
         def reg_bulk(a, r):
             a.set_tenant_class("best_effort", comm=subs[r], name="bulk")
-            a.set_tenant_quota(comm=subs[r], window_share=1)
+            a.set_tenant_quota(comm=subs[r], window_share=WINDOW_SHARE)
 
         run_parallel(g, reg_bulk)
+
+        arb, blocked = _record_blocked_admissions(g, monkeypatch)
 
         fsend = [
             a.create_buffer_from(np.ones(FLOOD_COUNT, np.float32))
@@ -614,6 +635,10 @@ def test_adversarial_flooder_vs_guaranteed_p99(fault_plan):
             a.create_buffer_from(np.ones(64, np.float32)) for a in g
         ]
         grecv = [a.create_buffer(64, np.float32) for a in g]
+        before = {
+            cid: (t["admitted"], t["latency"]["count"])
+            for cid, t in arb.snapshot()["tenants"].items()
+        }
 
         def flood(a, r):
             # offered load deeper than the share: the surplus queues AT
@@ -652,7 +677,7 @@ def test_adversarial_flooder_vs_guaranteed_p99(fault_plan):
         inj = g[0].engine.fabric.fault_injector
         assert inj.stats()["by_action"].get("delay", 0) > 0
 
-        # p99 from the LIVE monitor surface, not local timers
+        # counts from the LIVE monitor surface, not local state
         port = g[0].start_monitor(0)
         doc = json.loads(
             urllib.request.urlopen(
@@ -662,27 +687,30 @@ def test_adversarial_flooder_vs_guaranteed_p99(fault_plan):
         g[0].stop_monitor()
         serve_t = doc["tenants"][str(g[0].comm.id)]
         bulk_t = doc["tenants"][str(subs[0].id)]
-        # the guaranteed tail holds its bound; the flooder carries the
-        # congestion its class signed up for — compared on MEANS, which
-        # log2-bucket quantization cannot tie the way adjacent-bucket
-        # p99s can
-        assert serve_t["latency"]["p99_us"] is not None
-        assert serve_t["latency"]["p99_us"] <= P99_BOUND_US, serve_t
-        assert (
-            bulk_t["latency"]["mean_us"]
-            >= 2 * serve_t["latency"]["mean_us"]
-        ), (serve_t["latency"], bulk_t["latency"])
-        # backpressure absorbed at the arbiter: the flooder queued and
-        # waited; the guaranteed tenant sailed through
+        # every call of both tenants was admitted, completed and timed
+        # into its tenant's histogram, on each of the two ranks
+        for t, calls in ((serve_t, SERVE_CALLS), (bulk_t, FLOOD_CALLS)):
+            admitted0, samples0 = before[str(t["comm"])]
+            assert t["admitted"] - admitted0 == 2 * calls, t
+            assert t["latency"]["count"] - samples0 == 2 * calls, t
+            assert t["admitted"] == t["completed"], t
+            assert t["outstanding"] == 0 and t["queued"] == 0, t
+            assert t["over_admissions"] == 0, t
+            assert t["latency"]["p99_us"] is not None
+        # backpressure absorbed at the arbiter: the flooder queued, held
+        # to its share of one call in flight a rank ...
         assert bulk_t["queued_peak"] >= 1
-        assert bulk_t["grant_wait_ns_total"] > 0
-        g_wait = (
-            serve_t["grant_wait_ns_total"] / max(serve_t["admitted"], 1)
-        )
-        f_wait = (
-            bulk_t["grant_wait_ns_total"] / max(bulk_t["admitted"], 1)
-        )
-        assert g_wait < f_wait, (g_wait, f_wait)
+        assert bulk_t["outstanding_limit"] == WINDOW_SHARE
+        assert bulk_t["outstanding_peak"] <= WINDOW_SHARE * len(g)
+        # ... and only ITS threads ever blocked for a grant: every
+        # guaranteed call was granted in its own admission, never
+        # behind a flooder's
+        assert blocked and set(blocked) <= {
+            "accl-test-flood-0", "accl-test-flood-1",
+        }, set(blocked)
+        assert (
+            bulk_t["grant_wait_ns_total"] > serve_t["grant_wait_ns_total"]
+        ), (serve_t, bulk_t)
         # SPMD uniformity: one latched record per (comm, call index) —
         # both in-process ranks replayed the same decisions
         for (comm_id, seq), dec in g[0]._arbiter._decisions.items():
@@ -692,16 +720,20 @@ def test_adversarial_flooder_vs_guaranteed_p99(fault_plan):
         _deinit(g)
 
 
-def test_gang_flooder_absorbs_backpressure_serve_tail_bounded():
+def test_gang_flooder_absorbs_backpressure_serve_never_blocks(monkeypatch):
     """The fairness mechanism on the device tier, counter-asserted on a
     steady flood: with the flooder held to window_share=1, its
     per-admission grant wait dwarfs the guaranteed tenant's by an order
-    of magnitude (the flooder absorbs the backpressure at the arbiter),
-    while the guaranteed tenant's live p99 holds a generous bound and
-    nothing over-admits.  (The arbitrated-vs-unarbitrated wall-clock
-    contrast is a chip-tier claim — the bench's check_arbiter gate owns
-    it; on the CPU mesh gang calls are host-bound, so only the
-    admission counters separate deterministically.)"""
+    of magnitude (the flooder absorbs the backpressure at the arbiter:
+    it queues, one call in flight a rank, and only its threads ever
+    block for a grant), while every guaranteed call is granted in its
+    own admission, lands in the live histogram, and nothing
+    over-admits.  The guaranteed tenant's tail is held by that ORDER
+    (no guaranteed call ever waits behind a flooder's grant), not by a
+    wall-clock bound: on the CPU mesh gang calls are host-bound, so a
+    p99 there measures the shared CPU.  (The arbitrated-vs-unarbitrated
+    wall-clock contrast is a chip-tier claim no cell of the benchmark
+    measures yet.)"""
     g = xla_group(2)
     try:
         subs = run_parallel(
@@ -730,6 +762,7 @@ def test_gang_flooder_absorbs_backpressure_serve_tail_bounded():
             a.set_tenant_quota(comm=subs[r], window_share=1)
 
         run_parallel(g, reg_bulk)
+        arb, blocked = _record_blocked_admissions(g, monkeypatch)
         stop = threading.Event()
         # symmetric stop via publish-and-reconcile: both ranks converge
         # on the max issued call count, so no gang collective is left
@@ -804,9 +837,22 @@ def test_gang_flooder_absorbs_backpressure_serve_tail_bounded():
         g_wait = serve_t["grant_wait_ns_total"] / serve_t["admitted"]
         f_wait = bulk_t["grant_wait_ns_total"] / bulk_t["admitted"]
         assert f_wait > 10 * g_wait, (g_wait, f_wait)
-        # and the guaranteed tail held its (generous, CPU-mesh) bound
+        # ... held to its share of one call in flight a rank, its
+        # surplus queued at the arbiter ...
+        assert bulk_t["queued_peak"] >= 1
+        assert bulk_t["outstanding_limit"] == 1
+        assert bulk_t["outstanding_peak"] <= 1 * len(g)
+        # ... and only ITS threads ever blocked for a grant: each of
+        # the 80 guaranteed calls was granted in its own admission,
+        # never behind a flooder's.  That order is the guaranteed
+        # tenant's tail guarantee (a wall-clock p99 on the CPU mesh
+        # would measure the shared CPU instead)
+        assert blocked and set(blocked) <= {
+            "accl-test-gflood-0", "accl-test-gflood-1",
+        }, set(blocked)
+        # and every guaranteed call was timed into the live histogram
+        assert serve_t["latency"]["count"] == 80
         assert serve_t["latency"]["p99_us"] is not None
-        assert serve_t["latency"]["p99_us"] <= 65536.0, serve_t
     finally:
         _deinit(g)
 

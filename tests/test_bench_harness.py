@@ -4,18 +4,19 @@ number may hide the device.
 * ``chip_smoke.py``: each leg at a tiny size on the 8-device CPU mesh,
   and ``main`` refusing to run a leg off the TPU or on a ``device_kind``
   the peak table does not know;
-* ``bench.py``: one process, exit code nonzero on a failed leg, a refused
-  capture gate, a non-TPU platform or an unknown ``device_kind``;
+* ``perfbench.run``, the benchmark ``BENCHMARK.json`` declares: every
+  cell's CPU rehearsal (its driver, the program's main path and the
+  check against the plain reference, at tiny sizes), and the command's
+  refusal off the TPU;
 * the compile-cache helper, the peak table, and the refusals that took
   the place of quiet fallbacks (``xla_group`` past the device count,
   ``dryrun_multichip`` short of devices, the dist launcher on a TPU
   host, a failed native build).
 
-Everything here is stubbed or tiny: no chip, no child process except
-``chip_soak``'s own refusal.
+Everything but the rehearsals is stubbed or tiny; a rehearsal is the
+benchmark's own command in a child process, a cell a case.
 """
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -32,21 +33,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, os.path.abspath(ROOT))
 
 import chip_smoke  # noqa: E402
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        f"{name}_under_test", os.path.join(ROOT, f"{name}.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture()
-def bench():
-    """A fresh bench module instance."""
-    return _load("bench")
+from perfbench import manifest  # noqa: E402
 
 
 @pytest.fixture()
@@ -213,196 +200,68 @@ def test_smoke_main_fails_when_any_leg_failed(no_cache, monkeypatch, capsys):
 
 
 # ---------------------------------------------------------------------------
-# bench.py: fail closed
+# perfbench.run: the benchmark's command, rehearsed on the CPU
 # ---------------------------------------------------------------------------
 
 
-def test_headline_prefers_winning_pallas(bench):
-    r = bench._headline({"combine_xla": 700.0, "combine_pallas": 768.0})
-    assert r["value"] == 768.0 and r["impl"] == "pallas"
-    r = bench._headline({"combine_xla": 700.0, "combine_pallas": 600.0})
-    assert r["value"] == 700.0 and "impl" not in r
+#: what a rehearsal may print: counts of the program, never a time, a
+#: rate or a share of the device
+_COUNTS = {"plan_hit_share", "interactions_per_call",
+           "interactions_per_window", "ring_fallbacks", "peak_hbm"}
+_CELLS = [w["name"] for w in manifest.load()["workloads"]]
 
 
-def test_headline_null_when_empty(bench):
-    assert bench._headline({})["value"] is None
-
-
-def test_try_records_a_failure_and_the_run_goes_on(bench):
-    extras, errors = {}, {}
-
-    def boom():
-        raise ValueError("kernel refused")
-
-    assert bench._try(extras, errors, "bad", boom) is None
-    bench._try(extras, errors, "good", lambda: 2.0)
-    bench._try(extras, errors, "many", lambda: {"a": 1, "b": 2})
-    assert errors == {"bad": "ValueError: kernel refused"}
-    assert extras == {"good": 2.0, "a": 1, "b": 2}
-
-
-def test_try_classifies_hbm_oom(bench):
-    """A compile-time HBM overflow must reach the artifact as a stated
-    finding (the T=4096 blockwise train step is a real instance: 17.91G
-    needed vs 15.75G on v5e)."""
-    extras, errors = {}, {}
-
-    def oom():
-        raise RuntimeError(
-            "XLA:TPU compile permanent error. Ran out of memory in"
-            " memory space hbm. Used 17.91G of 15.75G hbm. Exceeded hbm"
-            " capacity by 2.16G."
-        )
-
-    bench._try(extras, errors, "big_train", oom)
-    assert errors["big_train"].startswith("HBM OOM at compile:")
-    assert "Used 17.91G of 15.75G hbm" in errors["big_train"]
-
-
-def test_sanitize_extras_moves_impossible_rates(bench):
-    """Bandwidth extras above the plausibility ceiling move to errors —
-    the artifact-side twin of the sweep writer's gate."""
-    extras = {"combine_xla": 700.0, "cast_pallas": 16_777_216.0}
-    errors = {}
-    bench._sanitize_extras(extras, errors)
-    assert "cast_pallas" not in extras
-    assert "implausible" in errors["cast_pallas"]
-    assert extras["combine_xla"] == 700.0  # plausible numbers untouched
-
-
-def test_bench_is_one_process_with_no_replay(bench):
-    """No child process, no probe, no last-known-good: what the run did
-    not measure, it does not print."""
-    with open(os.path.join(ROOT, "bench.py")) as f:
-        src = f.read()
-    assert "subprocess" not in src and "lkg" not in src.lower()
-    for gone in ("_emit_fallback", "_probe", "_probe_device",
-                 "_run_guarded", "_run_child", "_load_lkg", "_save_lkg"):
-        assert not hasattr(bench, gone), gone
-
-
-def test_bench_device_refuses_the_cpu(bench, monkeypatch):
-    monkeypatch.setattr(bench, "_SMALL", False)
-    with pytest.raises(SystemExit) as exc:
-        bench._device()
-    assert "not a TPU" in str(exc.value.code)
-
-
-def test_bench_device_refuses_an_unknown_device_kind(bench, monkeypatch):
-    monkeypatch.setattr(bench, "_SMALL", False)
-    monkeypatch.setattr(jax, "devices", _fake_devices(kind="TPU v5 turbo"))
-    with pytest.raises(SystemExit) as exc:
-        bench._device()
-    assert "TPU v5 turbo" in str(exc.value.code)
-
-
-def test_bench_device_stamp_on_a_known_chip(bench, monkeypatch):
-    monkeypatch.setattr(bench, "_SMALL", False)
-    monkeypatch.setattr(jax, "devices", _fake_devices(count=4))
-    assert bench._device() == {
-        "platform": "tpu", "kind": "TPU v5 lite", "count": 4,
-    }
-
-
-def test_bench_small_is_the_cpu_harness_by_name(bench, monkeypatch):
-    monkeypatch.setattr(bench, "_SMALL", True)
-    assert bench._device()["platform"] == "cpu"
-    assert bench._peak_flops("cpu") is None  # no peak, so no MFU
-
-
-def test_bench_peak_is_an_exact_match(bench, monkeypatch):
-    """'v5' inside an unknown kind is not a v5p."""
-    monkeypatch.setattr(bench, "_SMALL", False)
-    assert bench._peak_flops("TPU v5 lite") == 197e12
-    with pytest.raises(KeyError, match="TPU v5 turbo"):
-        bench._peak_flops("TPU v5 turbo")
-
-
-def _stub_bench_legs(bench, monkeypatch, **legs):
-    import accl_tpu.analysis
-
-    # (acclint's own verdicts are tests/test_analysis.py's subject)
-    monkeypatch.setattr(accl_tpu.analysis, "run_checks", lambda: [])
-    monkeypatch.setattr(bench, "_SMALL", True)
-    for name in dir(bench):
-        if name.startswith("_bench_"):
-            monkeypatch.setattr(bench, name, lambda *a, **k: {})
-    for name, fn in legs.items():
-        monkeypatch.setattr(bench, name, fn)
-
-
-def test_bench_main_exit_code_zero_when_every_leg_ran(
-    bench, no_cache, monkeypatch, capsys
-):
-    _stub_bench_legs(bench, monkeypatch)
-    assert bench.main() == 0
-    out = _last_json(capsys)
-    assert "errors" not in out
-    assert out["device"] == {
-        "platform": "cpu", "kind": "cpu", "count": len(jax.devices()),
-    }
-
-
-def test_bench_main_exits_nonzero_on_a_failed_leg(
-    bench, no_cache, monkeypatch, capsys
-):
-    def boom(*a, **k):
-        raise RuntimeError("Mosaic refused the kernel")
-
-    _stub_bench_legs(bench, monkeypatch, _bench_attention=boom)
-    assert bench.main() == 1
-    assert "Mosaic refused" in _last_json(capsys)["errors"]["attention"]
-
-
-def test_bench_main_exits_nonzero_on_a_refused_gate(
-    bench, no_cache, monkeypatch, capsys
-):
-    _stub_bench_legs(
-        bench, monkeypatch,
-        _bench_gang_device_time=lambda: {
-            "gang_allreduce_dispatch_floor_us": 400.0
-        },
+def _perfbench(cell, *args, **env):
+    """``python -m perfbench.run --workload <cell> ...`` as the driver
+    runs it: a child process from the checkout.  ``--rehearse`` forces
+    its own four host devices, so this process's ``XLA_FLAGS`` stay
+    here.  The child compiles and runs whole train steps on every core
+    it finds, beside five other xdist workers whose tests wait on
+    threads and sockets: it runs at the lowest priority (``nice``), so
+    that it takes the cores they leave and none they want.  One file is
+    one worker under ``--dist loadfile``, so there is one such child at
+    a time."""
+    base = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    return subprocess.run(
+        ["nice", "-n", "19", sys.executable, "-m", "perfbench.run",
+         "--workload", cell, *args],
+        cwd=manifest.CHECKOUT, env=dict(base, **env),
+        capture_output=True, text=True, timeout=600,
     )
-    assert bench.main() == 1
-    assert "overlap_gate" in _last_json(capsys)["errors"]
 
 
-def test_gang_device_time_invariant(bench, monkeypatch):
-    """The device-time decomposition must satisfy device <= wall and
-    floor = pipelined_wall - device, live against the real facade on
-    the CPU tier."""
-    monkeypatch.setattr(bench, "_SMALL", True)
-    out = bench._bench_gang_device_time()
-    wall = out["gang_allreduce_wall_us"]
-    dev = out["gang_allreduce_device_us"]
-    pipe = out["gang_allreduce_pipelined_wall_us"]
-    floor = out["gang_allreduce_dispatch_floor_us"]
-    pct = out["gang_inflight_overlap_pct"]
-    assert 0 <= dev <= wall
-    assert 0 <= floor <= pipe
-    assert floor == pytest.approx(
-        min(max(pipe - dev, 0.0), pipe), abs=0.2
-    )
-    # the overlap evidence the capture gate requires rides along
-    assert pct >= 0.0
-    assert out["gang_inflight_window_depth"] >= 1
-    assert out["gang_inflight_max_depth_seen"] >= 1
+@pytest.mark.parametrize(
+    "cell,trace",
+    [(cell, 0) for cell in _CELLS]
+    + [("coll_w4_sweep", 1), ("train_t1024_b8", 1)],
+)
+def test_benchmark_rehearsal_is_correct_and_prints_counts_only(cell, trace):
+    proc = _perfbench(cell, "--seed", "3", "--seconds", "2",
+                      "--trace", str(trace), "--rehearse")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0, proc.stdout[-2000:]
+    assert line["attempted"] > 0
+    assert line["device"]["platform"] == "cpu"
+    values = {k: m["value"] for k, m in line["metrics"].items()}
+    assert set(values) <= _COUNTS
+    if not trace:
+        assert values == {}
+    elif cell == "coll_w4_sweep":
+        # the facade's counts by the benchmark's own readers: every warm
+        # call a plan hit and one device interaction, a batched window
+        # one interaction, nothing off the ring
+        assert values == {
+            "plan_hit_share": 100.0, "interactions_per_call": 1.0,
+            "interactions_per_window": 1.0, "ring_fallbacks": 0,
+        }
 
 
-def test_chip_soak_requires_tpu(tmp_path):
-    """benchmarks/chip_soak.py must refuse to fake device evidence: on a
-    non-TPU backend it emits an error JSON and a distinct exit code
-    instead of running the soak against the interpreter."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["ACCL_SOAK_SECONDS"] = "1"  # belt: even a wrong backend is brief
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "chip_soak.py")],
-        capture_output=True, text=True, env=env, timeout=120,
-    )
-    assert proc.returncode == 2, proc.stderr[-300:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert "needs a TPU backend" in out["error"]
+def test_benchmark_refuses_to_run_off_the_tpu():
+    proc = _perfbench("train_t1024_b8", "--seed", "0", "--seconds", "1",
+                      "--trace", "0", JAX_PLATFORMS="cpu")
+    assert proc.returncode != 0 and "no TPU" in proc.stderr
+    assert not proc.stdout.strip()
 
 
 # ---------------------------------------------------------------------------

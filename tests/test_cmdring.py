@@ -417,116 +417,8 @@ def test_disabled_ring_stays_off(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# bench gate units (parse_results.check_cmdring)
+# a mixed-dtype window falls back whole
 # ---------------------------------------------------------------------------
-
-
-def _gate():
-    import importlib.util
-    import os
-
-    path = os.path.join(
-        os.path.dirname(__file__), "..", "benchmarks", "parse_results.py"
-    )
-    spec = importlib.util.spec_from_file_location("parse_results", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _evidence(**over):
-    base = {
-        "gang_cmdring_dispatch_floor_us": 40.0,
-        "gang_cmdring_host_floor_us": 200.0,
-        "gang_cmdring_refills_per_call": 0.125,
-        "gang_cmdring_ring_slots": 96,
-        # persistent-sequencer evidence (the sustained + mixed legs)
-        "gang_cmdring_sustained_floor_us": 35.0,
-        "gang_cmdring_redispatches_per_window": 0.0,
-        "gang_cmdring_op_slots": {
-            "ALLREDUCE": 2, "REDUCE_SCATTER": 1, "ALLGATHER": 1,
-            "ALLTOALL": 1, "BARRIER": 1,
-        },
-        "gang_cmdring_mixed_fallbacks": {
-            "unsupported_op": 0, "compressed": 0,
-        },
-    }
-    base.update(over)
-    return base
-
-
-def test_check_cmdring_passes_good_capture():
-    _gate().check_cmdring(_evidence())
-
-
-def test_check_cmdring_noop_when_bench_never_ran():
-    _gate().check_cmdring({})
-
-
-def test_check_cmdring_refuses_floor_without_evidence():
-    mod = _gate()
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(
-            {"gang_cmdring_dispatch_floor_us": 40.0})
-
-
-def test_check_cmdring_refuses_unamortized_refills():
-    mod = _gate()
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(
-            _evidence(gang_cmdring_refills_per_call=1.0))
-
-
-def test_check_cmdring_refuses_ring_not_engaging():
-    mod = _gate()
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(_evidence(gang_cmdring_ring_slots=0))
-
-
-def test_check_cmdring_requires_ring_below_host_floor():
-    mod = _gate()
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(
-            _evidence(gang_cmdring_dispatch_floor_us=250.0))
-
-
-def test_committed_cpu_capture_passes_gate():
-    import json
-    import os
-
-    mod = _gate()
-    path = os.path.join(
-        os.path.dirname(__file__), "..", "benchmarks", "results",
-        "cmdring_gang_cpu.json",
-    )
-    with open(path) as f:
-        doc = json.load(f)
-    mod.check_cmdring(doc["cmdring"])
-    assert doc["cmdring"]["gang_cmdring_refills_per_call"] < 1.0
-    # the committed capture carries the persistence evidence: the
-    # sustained stream's redispatch amortization and the per-opcode
-    # residency of the mixed warm workload
-    assert doc["cmdring"]["gang_cmdring_redispatches_per_window"] < 1.0
-    for op in mod.CMDRING_EVIDENCE_OPS:
-        assert doc["cmdring"]["gang_cmdring_op_slots"][op] > 0
-    assert not any(
-        doc["cmdring"]["gang_cmdring_mixed_fallbacks"].values()
-    )
-    # ...and the fused-compute-slot evidence (kernel-initiated
-    # collectives): the warm fused train step at exactly its refill
-    # count in host interactions, no faster-unfused inversion, every
-    # fused opcode ring-resident with fused fallbacks at zero
-    cm = doc["cmdring"]
-    assert cm["gang_cmdring_fused_interactions_per_step"] == (
-        cm["gang_cmdring_fused_refills_per_step"]
-    )
-    assert cm["gang_cmdring_fused_interactions_per_step"] <= 1.0
-    assert cm["gang_cmdring_fused_step_us"] <= (
-        cm["gang_cmdring_unfused_step_us"]
-    )
-    for op in mod.CMDRING_FUSED_EVIDENCE_OPS:
-        assert cm["gang_cmdring_fused_op_slots"][op] > 0
-    assert not any(cm["gang_cmdring_fused_fallbacks"].values())
 
 
 def test_mixed_dtype_window_falls_back(g4):
@@ -562,68 +454,6 @@ def test_mixed_dtype_window_falls_back(g4):
         np.testing.assert_allclose(out_f[r].data, 10.0)
         out_i[r].sync_from_device()
         np.testing.assert_array_equal(out_i[r].data, 10)
-
-
-def test_check_cmdring_refuses_partial_evidence_any_side():
-    mod = _gate()
-    ev = _evidence()
-    for missing in (
-        "gang_cmdring_dispatch_floor_us",
-        "gang_cmdring_host_floor_us",
-        "gang_cmdring_refills_per_call",
-    ):
-        partial = {k: v for k, v in ev.items() if k != missing}
-        with pytest.raises(mod.CmdringGateError):
-            mod.check_cmdring(partial)
-
-
-def test_check_cmdring_refuses_unamortized_redispatch():
-    mod = _gate()
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(
-            _evidence(gang_cmdring_redispatches_per_window=1.0))
-
-
-def test_check_cmdring_requires_per_opcode_residency():
-    mod = _gate()
-    ev = _evidence()
-    ev["gang_cmdring_op_slots"] = dict(
-        ev["gang_cmdring_op_slots"], ALLTOALL=0
-    )
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(ev)
-
-
-def test_check_cmdring_fallback_zero_gate():
-    mod = _gate()
-    ev = _evidence()
-    ev["gang_cmdring_mixed_fallbacks"] = {
-        "unsupported_op": 0, "compressed": 2,
-    }
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(ev)
-
-
-def test_check_cmdring_refuses_partial_persistence_evidence():
-    mod = _gate()
-    ev = _evidence()
-    del ev["gang_cmdring_sustained_floor_us"]
-    with pytest.raises(mod.CmdringGateError):
-        mod.check_cmdring(ev)
-
-
-def test_check_cmdring_accepts_pre_persistence_capture():
-    """Captures from before the persistent sequencer (no sustained
-    keys) still gate on the original requirements alone — the TPU r06
-    leg may re-run an older harness."""
-    mod = _gate()
-    ev = {
-        "gang_cmdring_dispatch_floor_us": 40.0,
-        "gang_cmdring_host_floor_us": 200.0,
-        "gang_cmdring_refills_per_call": 0.125,
-        "gang_cmdring_ring_slots": 96,
-    }
-    mod.check_cmdring(ev)
 
 
 # ---------------------------------------------------------------------------
@@ -1655,109 +1485,6 @@ def test_chaos_delay_fused_window_bounded_and_correct(g4):
     for r in range(4):
         out[r].sync_from_device()
         np.testing.assert_allclose(out[r].data, ref[r], rtol=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# the extended capture gate: fused-evidence refusals
-# ---------------------------------------------------------------------------
-
-
-def _fused_evidence(**over):
-    ev = _evidence(
-        gang_cmdring_fused_step_us=9000.0,
-        gang_cmdring_unfused_step_us=18000.0,
-        gang_cmdring_fused_interactions_per_step=1.0,
-        gang_cmdring_fused_refills_per_step=1.0,
-        gang_cmdring_fused_op_slots={
-            "FUSED_MATMUL_RS": 1, "FUSED_APPLY": 1, "FUSED_ATTN_HOP": 1,
-        },
-        gang_cmdring_fused_fallbacks={
-            "unsupported_op": 0, "compressed": 0, "fused_decomposed": 0,
-        },
-    )
-    ev.update(over)
-    return ev
-
-
-def test_check_cmdring_passes_fused_capture():
-    _gate().check_cmdring(_fused_evidence())
-
-
-def test_check_cmdring_refuses_partial_fused_evidence():
-    mod = _gate()
-    for missing in (
-        "gang_cmdring_fused_step_us",
-        "gang_cmdring_unfused_step_us",
-        "gang_cmdring_fused_interactions_per_step",
-        "gang_cmdring_fused_refills_per_step",
-    ):
-        ev = _fused_evidence()
-        del ev[missing]
-        with pytest.raises(mod.CmdringGateError, match="partial fused"):
-            mod.check_cmdring(ev)
-
-
-def test_check_cmdring_refuses_fused_host_reentry():
-    """interactions/step must EQUAL the refill count and never exceed
-    one — a fused step re-entering the host between compute and
-    collective is exactly what the tentpole removes."""
-    mod = _gate()
-    with pytest.raises(mod.CmdringGateError, match="re-entering"):
-        mod.check_cmdring(_fused_evidence(
-            gang_cmdring_fused_interactions_per_step=2.0,
-            gang_cmdring_fused_refills_per_step=2.0,
-        ))
-    with pytest.raises(mod.CmdringGateError, match="re-entering"):
-        mod.check_cmdring(_fused_evidence(
-            gang_cmdring_fused_interactions_per_step=1.0,
-            gang_cmdring_fused_refills_per_step=0.5,
-        ))
-
-
-def test_check_cmdring_requires_fused_opcode_residency():
-    mod = _gate()
-    ev = _fused_evidence()
-    ev["gang_cmdring_fused_op_slots"] = dict(
-        ev["gang_cmdring_fused_op_slots"], FUSED_ATTN_HOP=0
-    )
-    with pytest.raises(mod.CmdringGateError, match="FUSED_ATTN_HOP"):
-        mod.check_cmdring(ev)
-
-
-def test_check_cmdring_fused_fallback_zero_gate():
-    mod = _gate()
-    for bad in (
-        {"unsupported_op": 1, "compressed": 0, "fused_decomposed": 0},
-        {"unsupported_op": 0, "compressed": 0, "fused_decomposed": 2},
-        None,  # fallbacks absent entirely: unverifiable, refused
-    ):
-        ev = _fused_evidence()
-        if bad is None:
-            del ev["gang_cmdring_fused_fallbacks"]
-        else:
-            ev["gang_cmdring_fused_fallbacks"] = bad
-        with pytest.raises(mod.CmdringGateError, match="fallback"):
-            mod.check_cmdring(ev)
-
-
-def test_check_cmdring_refuses_fused_slower_than_unfused():
-    mod = _gate()
-    with pytest.raises(mod.CmdringGateError, match="buy nothing"):
-        mod.check_cmdring(_fused_evidence(
-            gang_cmdring_fused_step_us=20000.0,
-            gang_cmdring_unfused_step_us=18000.0,
-        ))
-
-
-def test_check_cmdring_refuses_unanchored_fused_evidence():
-    """Fused keys WITHOUT the base command-ring evidence are refused —
-    unanchored fused counters would gate nothing."""
-    mod = _gate()
-    with pytest.raises(mod.CmdringGateError, match="unanchored"):
-        mod.check_cmdring({
-            "gang_cmdring_fused_step_us": 9000.0,
-            "gang_cmdring_fused_interactions_per_step": 1.0,
-        })
 
 
 # ---------------------------------------------------------------------------

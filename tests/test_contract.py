@@ -523,41 +523,6 @@ def test_diverge_rules_do_not_touch_wire_traffic():
             a.deinit()
 
 
-# ---------------------------------------------------------------------------
-# bench gate (parse_results.check_verify)
-# ---------------------------------------------------------------------------
-
-
-def test_verify_gate():
-    from benchmarks.parse_results import VerifyGateError, check_verify
-
-    good = {
-        "telemetry": {"snapshot_keys": [], "records": 1},
-        "verify": {
-            "overhead_pct": 1.2, "interval": 8,
-            "calls_verified": 300, "windows_exchanged": 37,
-        },
-    }
-    check_verify(good)
-    # wedged/partial captures (no facade bench at all): nothing to gate
-    check_verify({})
-    # facade bench ran (telemetry evidence present) but no verify block
-    with pytest.raises(VerifyGateError):
-        check_verify({"telemetry": good["telemetry"]})
-    # dead verifier: zero fingerprinted calls
-    bad = {"telemetry": good["telemetry"],
-           "verify": dict(good["verify"], calls_verified=0)}
-    with pytest.raises(VerifyGateError):
-        check_verify(bad)
-    # over-budget
-    bad = {"telemetry": good["telemetry"],
-           "verify": dict(good["verify"], overhead_pct=7.5)}
-    with pytest.raises(VerifyGateError):
-        check_verify(bad)
-    # tolerance override
-    check_verify(bad, tolerance_pct=10.0)
-
-
 def test_corrupt_verify_frame_is_discarded_not_adopted():
     """A corrupt-fault VERIFY frame must be dropped by the checksum
     guard BEFORE the contract hook can consume it as a verdict (review

@@ -319,32 +319,104 @@ def _plan_stats(a) -> dict:
     return pc
 
 
-def test_warm_collective_is_one_interaction_and_plan_hit(g4):
-    """The cached-dispatch contract, counter-asserted both ways: a warm
-    gang collective is EXACTLY 1 device interaction AND >= 1 plan-cache
-    hit (zero misses) — pool-lookup -> dispatch, nothing re-derived."""
+#: the module's group (``g4``)
+W = 4
+
+
+def _rank_data(r, elems):
+    return r * 1000.0 + np.arange(elems, dtype=np.float32)
+
+
+def _all(elems):
+    return [_rank_data(q, elems) for q in range(W)]
+
+
+#: op -> (send and recv elements in units of the call's ``count`` n, the
+#: call, the result on rank r or None where the rank receives nothing);
+#: rank r's send buffer holds ``_rank_data(r, .)``
+_WARM_OPS = {
+    "allreduce": (
+        1, 1, lambda a, s, d, n: a.allreduce(s, d, n),
+        lambda r, n: sum(_all(n)),
+    ),
+    "allgather": (
+        1, W, lambda a, s, d, n: a.allgather(s, d, n),
+        lambda r, n: np.concatenate(_all(n)),
+    ),
+    "reduce_scatter": (
+        W, 1, lambda a, s, d, n: a.reduce_scatter(s, d, n),
+        lambda r, n: sum(_all(n * W))[r * n:(r + 1) * n],
+    ),
+    "alltoall": (
+        W, W, lambda a, s, d, n: a.alltoall(s, d, n),
+        lambda r, n: np.concatenate(
+            [x[r * n:(r + 1) * n] for x in _all(n * W)]
+        ),
+    ),
+    "bcast": (
+        1, 0, lambda a, s, d, n: a.bcast(s, n, root=1),
+        lambda r, n: _rank_data(1, n),
+    ),
+    "scatter": (
+        W, 1, lambda a, s, d, n: a.scatter(s, d, n, root=1),
+        lambda r, n: _rank_data(1, n * W)[r * n:(r + 1) * n],
+    ),
+    "gather": (
+        1, W, lambda a, s, d, n: a.gather(s, d, n, root=1),
+        lambda r, n: np.concatenate(_all(n)) if r == 1 else None,
+    ),
+    "reduce": (
+        1, 1, lambda a, s, d, n: a.reduce(s, d, n, root=1),
+        lambda r, n: sum(_all(n)) if r == 1 else None,
+    ),
+    "barrier": (0, 0, lambda a, s, d, n: a.barrier(), lambda r, n: None),
+}
+
+
+@pytest.mark.parametrize("op", list(_WARM_OPS))
+def test_warm_collective_is_one_interaction_and_plan_hit(g4, op):
+    """The cached-dispatch contract, counter-asserted both ways, for
+    each of the facade's nine collectives: a warm gang collective is
+    EXACTLY 1 device interaction AND >= 1 plan-cache hit (zero misses)
+    — pool-lookup -> dispatch, nothing re-derived.  The barrier is the
+    one whose counts are by design other ones."""
     n = 64
+    send_x, recv_x, call, want = _WARM_OPS[op]
     send = [
-        a.create_buffer_from(np.full(n, float(r + 1), np.float32))
+        a.create_buffer_from(_rank_data(r, n * send_x)) if send_x else None
         for r, a in enumerate(g4)
     ]
-    recv = [a.create_buffer(n, np.float32) for a in g4]
+    recv = [
+        a.create_buffer(n * recv_x, np.float32) if recv_x else None
+        for a in g4
+    ]
 
     def work(a, r):
-        a.allreduce(send[r], recv[r], n)
+        call(a, send[r], recv[r], n)
 
     run_parallel(g4, work)  # cold: builds the plan (miss) + template
     run_parallel(g4, work)  # first hit: prepares the program handle
     ic0 = _interactions(g4[0])
     pc0 = _plan_stats(g4[0])
     run_parallel(g4, work)
-    assert _interactions(g4[0]) - ic0 == 1
     pc1 = _plan_stats(g4[0])
-    assert pc1["hits"] - pc0["hits"] >= 1, "warm call must hit the pool"
+    if op == "barrier":
+        # by design 0 and 0: on this tier the gang's assembly IS the
+        # barrier (engine ``_run_op``), no program is dispatched, and a
+        # call without a payload builds its options itself, so the
+        # pool is never asked
+        assert _interactions(g4[0]) - ic0 == 0
+        assert pc1["hits"] == pc0["hits"]
+    else:
+        assert _interactions(g4[0]) - ic0 == 1
+        assert pc1["hits"] - pc0["hits"] >= 1, "warm call must hit the pool"
     assert pc1["misses"] == pc0["misses"], "warm call must not re-plan"
-    for r in range(4):
-        recv[r].sync_from_device()
-        np.testing.assert_allclose(recv[r].data, 10.0)
+    for r in range(W):
+        out = send[r] if op == "bcast" else recv[r]
+        expect = want(r, n)
+        if expect is not None:
+            out.sync_from_device()
+            np.testing.assert_array_equal(out.data, expect)
 
 
 def test_set_tuning_forces_exactly_one_replan(g4):
@@ -447,31 +519,6 @@ def test_subcomm_epoch_churn_never_reuses_stale_plan(g4):
     assert pc3["misses"] - pc2["misses"] == 1, (
         "a re-created same-id subcomm must never reuse the stale plan"
     )
-
-
-# ---------------------------------------------------------------------------
-# capture-regression gate (benchmarks/parse_results.py / sweep.py)
-# ---------------------------------------------------------------------------
-
-
-def test_overlap_gate():
-    """The overlap plane's capture refusal: a gang dispatch-floor number
-    without its gang_inflight_overlap_pct is refused; captures where the
-    gang benches never ran (neither key) are no-ops."""
-    from benchmarks.parse_results import OverlapGateError, check_overlap
-
-    check_overlap({})  # gang benches never ran
-    with pytest.raises(OverlapGateError):
-        check_overlap({"gang_allreduce_dispatch_floor_us": 400.0})
-    check_overlap({
-        "gang_allreduce_dispatch_floor_us": 540.0,
-        "gang_inflight_overlap_pct": 55.0,
-    })
-    # sweep.py re-exports the same surface (both artifact writers gate)
-    from benchmarks.sweep import check_overlap as via_sweep
-
-    with pytest.raises(OverlapGateError):
-        via_sweep({"gang_allreduce_dispatch_floor_us": 1.0})
 
 
 # ---------------------------------------------------------------------------

@@ -171,10 +171,14 @@ def test_socket_dead_peer_send_times_out_fast():
         b.set_timeout(30.0)  # the FULL deadline we must NOT wait out
         t0 = time.monotonic()
         with pytest.raises(ACCLError) as exc:
-            # one send may land in the OS buffer of the dead connection;
-            # the follow-up hits the reset and must fail fast
-            for i in range(4):
+            # a send may land in the OS buffer of the dead connection
+            # until its reset has come back: that takes TIME on a busy
+            # machine, not sends (four back to back all landed under
+            # six xdist workers), so the sends are spaced, and capped:
+            # a fabric that buffers for ever fails here at 64, early
+            for i in range(64):
                 b.send(sb, 8, dst=0, tag=2 + i)
+                time.sleep(0.01)
         elapsed = time.monotonic() - t0
         assert exc.value.code == ErrorCode.SEND_TIMEOUT
         assert elapsed < 10.0, f"dead-peer send took {elapsed:.1f}s"

@@ -1,6 +1,5 @@
 """Monitor-plane tests: the live scrape service, streaming trace
-export, cross-rank straggler diagnosis, the anomaly watchdog, and the
-bench gate (parse_results.check_monitor).
+export, cross-rank straggler diagnosis and the anomaly watchdog.
 
 The straggler acceptance pair: a seeded one-rank ``delay`` FaultRule on
 the emulator tier must produce a ``slow_rank`` verdict naming that rank
@@ -9,7 +8,6 @@ convicted rank) — while an unfaulted run over the same traffic produces
 ZERO verdicts (the false-positive guard)."""
 
 import json
-import os
 import re
 import threading
 import time
@@ -471,73 +469,6 @@ def test_anomaly_alert_reaches_snapshot_and_prom(monkeypatch):
     finally:
         for a in g:
             a.deinit()
-
-
-# ---------------------------------------------------------------------------
-# bench gate (parse_results.check_monitor)
-# ---------------------------------------------------------------------------
-
-
-def test_check_monitor_gate_units():
-    from benchmarks.parse_results import MonitorGateError, check_monitor
-
-    good = {
-        "telemetry": {"overhead_pct": 0.0},
-        "monitor": {
-            "overhead_pct": 1.2, "scrapes": 12, "scrape_errors": 0,
-            "routes_ok": True,
-        },
-    }
-    check_monitor(good)
-    # schema 4+ captures must also carry ring-span evidence (older
-    # captures pin their capture-time schema and are exempt)
-    with pytest.raises(MonitorGateError):
-        check_monitor({
-            "monitor": dict(
-                good["monitor"], schema_version=4, ring_spans=0
-            ),
-        })
-    check_monitor({
-        "monitor": dict(
-            good["monitor"], schema_version=4, ring_spans=17
-        ),
-    })
-    check_monitor({})  # facade bench never ran: nothing to gate
-    with pytest.raises(MonitorGateError):
-        check_monitor({"telemetry": good["telemetry"]})  # A/B missing
-    bad = {k: dict(v) for k, v in good.items()}
-    bad["monitor"]["scrapes"] = 0
-    with pytest.raises(MonitorGateError):
-        check_monitor(bad)  # never actually polled
-    bad = {k: dict(v) for k, v in good.items()}
-    bad["monitor"]["routes_ok"] = False
-    with pytest.raises(MonitorGateError):
-        check_monitor(bad)
-    bad = {k: dict(v) for k, v in good.items()}
-    bad["monitor"]["overhead_pct"] = 9.7
-    with pytest.raises(MonitorGateError):
-        check_monitor(bad)
-    check_monitor(bad, tolerance_pct=15.0)
-
-
-def test_committed_capture_passes_monitor_gate():
-    """The committed monitor A/B capture carries live-scrape evidence
-    and its measured overhead is within the <=5% budget."""
-    from benchmarks.parse_results import check_monitor
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks", "results", "facade_monitor_cpu.json",
-    )
-    assert os.path.exists(path), f"committed artifact missing: {path}"
-    with open(path) as f:
-        doc = json.load(f)
-    check_monitor(doc)
-    assert doc["monitor"]["scrapes"] >= 1
-    assert doc["monitor"]["routes_ok"] is True
-    # the committed capture predates the membership plane (schema 3):
-    # the artifact gate pins the version it was captured at
-    assert doc["monitor"]["schema_version"] == 2
 
 
 def test_skew_tracker_begin_comm_resolves_early_claims():

@@ -3,15 +3,13 @@ hierarchical): the slice/link-class descriptor, hierarchical collective
 decomposition (bit-identical to flat on every tier), the link-class
 plan-key axis with per-class wire ladders, topology-scoped error
 feedback, the paced two-class fabric model, the autotuner's
-hierarchical-vs-flat race, the TuningPlan topology provenance refusal,
-and the check_topology capture gate."""
+hierarchical-vs-flat race and the TuningPlan topology provenance
+refusal."""
 
 from __future__ import annotations
 
 import json
-import os
 import socket as socketlib
-import sys
 import threading
 import time
 
@@ -32,24 +30,10 @@ from accl_tpu.topology import LinkClass, Topology
 
 from helpers import run_parallel
 
-_BENCHMARKS = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmarks",
-)
-
 
 def _deinit(group):
     for a in group:
         a.deinit()
-
-
-def _parse_results():
-    sys.path.insert(0, _BENCHMARKS)
-    try:
-        import parse_results
-    finally:
-        sys.path.remove(_BENCHMARKS)
-    return parse_results
 
 
 # ---------------------------------------------------------------------------
@@ -719,75 +703,6 @@ def test_tuning_plan_topology_provenance_refusal():
         ) is not None
     finally:
         _deinit(g)
-
-
-# ---------------------------------------------------------------------------
-# the capture gate
-# ---------------------------------------------------------------------------
-
-
-def _good_extras():
-    payload = 1 << 20
-    return {
-        "topology_signature": "2x4",
-        "topology_world": 8,
-        "topology_num_slices": 2,
-        "topology_payload_bytes": payload,
-        "topology_wire_gbps_model": {"ici": 8.0, "dcn": 0.05},
-        "topology_flat": {
-            "wall_us": 312000.0,
-            "dcn_bytes_per_run": 3670016,
-            "ici_bytes_per_run": 0,
-        },
-        "topology_hier": {
-            "wall_us": 82000.0,
-            "dcn_bytes_per_run": 2097152,
-            "ici_bytes_per_run": 9437184,
-        },
-        "topology_speedup": 312000.0 / 82000.0,
-        "topology_dcn_reduction": 3670016 / 2097152,
-        "topology_bit_identical": True,
-    }
-
-
-def test_check_topology_gate_units():
-    pr = _parse_results()
-    pr.check_topology(_good_extras())  # the committed shape passes
-
-    def refused(mutate):
-        doc = {
-            k: (dict(v) if isinstance(v, dict) else v)
-            for k, v in _good_extras().items()
-        }
-        mutate(doc)
-        with pytest.raises(pr.TopologyGateError):
-            pr.check_topology(doc)
-
-    refused(lambda d: d.pop("topology_speedup"))
-    refused(lambda d: d.pop("topology_flat"))
-    refused(lambda d: d.__setitem__("topology_speedup", 1.5))
-    refused(lambda d: d.__setitem__("topology_bit_identical", False))
-    refused(lambda d: d.__setitem__("topology_dcn_reduction", 1.0))
-    refused(lambda d: d.__setitem__("topology_payload_bytes", 4096))
-    refused(lambda d: d.__setitem__("topology_num_slices", 1))
-    refused(lambda d: d["topology_wire_gbps_model"].__setitem__(
-        "dcn", 9.0))  # DCN modeled faster than ICI: no evidence
-    refused(lambda d: d["topology_hier"].__setitem__(
-        "dcn_bytes_per_run", 0))  # counters off: refuse
-    # the slice-factor reduction floor scales with the topology
-    refused(lambda d: d.__setitem__(
-        "topology_dcn_reduction",
-        0.8 * 2 * 7 / 8,  # below 0.9 * L(W-1)/W
-    ))
-
-
-def test_committed_topology_capture_passes_gate():
-    pr = _parse_results()
-    path = os.path.join(_BENCHMARKS, "results", "topology_cpu.json")
-    pr.check_topology_capture(path)  # raises on regression
-    doc = json.load(open(path))
-    speed = doc["topology"]["topology_speedup"]
-    assert speed >= pr.TOPOLOGY_SPEEDUP_FLOOR
 
 
 # ---------------------------------------------------------------------------

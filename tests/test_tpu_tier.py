@@ -11,7 +11,7 @@ conftest in this mode; multi-device kernels self-skip on one chip).
 Everything here also passes on the CPU host platform — handy for
 developing the tier itself — but its purpose is chip execution:
 DeviceBuffer paths, compiled kernels, and the gang backend are otherwise
-only chip-exercised by bench.py.
+only chip-exercised by ``chip_smoke.py`` and ``perfbench``.
 """
 
 import numpy as np

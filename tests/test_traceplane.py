@@ -23,12 +23,7 @@ from accl_tpu.constants import ACCLError, ErrorCode
 from accl_tpu.core import emulated_group, socket_group_member, xla_group
 from accl_tpu.faults import FaultPlan, FaultRule
 from accl_tpu.monitor import BlackBox, load_bundle
-from helpers import run_parallel
-
-RESULTS = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmarks", "results",
-)
+from helpers import record_rank_traces, run_parallel
 
 
 def _deinit(group):
@@ -233,18 +228,17 @@ def test_pipelined_segments_nest_under_aggregate():
 # ---------------------------------------------------------------------------
 
 
-def test_merge_cli_validates_committed_artifact(tmp_path, capsys):
-    """The committed 4-rank sweep traces merge cleanly through the CLI
+@pytest.fixture(scope="module")
+def rank_traces(tmp_path_factory):
+    return record_rank_traces(tmp_path_factory.mktemp("rank_traces"))
+
+
+def test_merge_cli_validates_committed_artifact(rank_traces, tmp_path, capsys):
+    """A 4-rank gang run's traces merge cleanly through the CLI
     (flow validation on), and the merged artifact carries cross-rank
     flow events plus ring-resident spans."""
-    inputs = [
-        os.path.join(RESULTS, f"trace_xla_w4_rank{r}.json")
-        for r in range(4)
-    ]
-    for p in inputs:
-        assert os.path.exists(p), f"committed artifact missing: {p}"
     out = str(tmp_path / "merged.json")
-    assert T.main(["merge", "--out", out] + inputs) == 0
+    assert T.main(["merge", "--out", out] + rank_traces) == 0
     with open(out) as f:
         doc = json.load(f)
     evs = doc["traceEvents"]
@@ -252,7 +246,7 @@ def test_merge_cli_validates_committed_artifact(tmp_path, capsys):
     flows = [e for e in evs if e.get("cat") == "accl.flow"]
     assert {e["ph"] for e in flows} >= {"s", "f"}
     assert any(e.get("cat") == "cmdring" for e in evs), (
-        "no ring-resident spans in the committed merged trace"
+        "no ring-resident spans in the merged trace"
     )
     # p2p flows (send→recv): both ends of at least one pair
     p2p_ids = {
@@ -262,16 +256,12 @@ def test_merge_cli_validates_committed_artifact(tmp_path, capsys):
     assert p2p_ids
 
 
-def test_merge_cli_errors_when_rank_file_missing(tmp_path):
-    """Merging only 3 of the 4 committed rank files drops rank 0's
+def test_merge_cli_errors_when_rank_file_missing(rank_traces, tmp_path):
+    """Merging only 3 of the 4 rank files drops rank 0's
     flow starts: the CLI refuses the merge (the artifact would claim
     cross-rank coverage it doesn't have)."""
-    inputs = [
-        os.path.join(RESULTS, f"trace_xla_w4_rank{r}.json")
-        for r in range(1, 4)
-    ]
     with pytest.raises(SystemExit, match="unmatched flow"):
-        T.main(["merge", "--out", str(tmp_path / "m.json")] + inputs)
+        T.main(["merge", "--out", str(tmp_path / "m.json")] + rank_traces[1:])
 
 
 def test_flow_validation_exempts_ring_truncation():

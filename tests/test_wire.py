@@ -1,6 +1,6 @@
 """Quantized wire protocols: codec bit identity, stochastic-rounding
-determinism, error-feedback accounting, cross-tier agreement, wire
-verdicts, and the check_compression gate.
+determinism, error-feedback accounting, cross-tier agreement and wire
+verdicts.
 
 The load-bearing contracts:
 
@@ -19,7 +19,6 @@ The load-bearing contracts:
 """
 
 import json
-import os
 import threading
 
 import numpy as np
@@ -779,16 +778,6 @@ def test_wire_verdict_skips_unsupported_reduce_function(rng):
             a.deinit()
 
 
-def test_check_compression_better_than_baseline_passes():
-    """One-sided convergence bound: EF converging BETTER than the f32
-    baseline (a large negative delta) must pass (review-caught)."""
-    from benchmarks.parse_results import check_compression
-
-    good = _good_extras()
-    good["compression_convergence"]["delta_pct"] = -45.0
-    check_compression(good)
-
-
 def test_wire_dtype_register_validation():
     from accl_tpu.core import emulated_group
     from accl_tpu.tuning import validate_registers, wire_dtype_value
@@ -934,113 +923,6 @@ def test_compression_telemetry_counters():
     finally:
         for a in g:
             a.deinit()
-
-
-# ---------------------------------------------------------------------------
-# check_compression gate
-# ---------------------------------------------------------------------------
-
-
-def _good_extras():
-    return {
-        "compression_sweep": {
-            "off": {"wall_us": 100e3, "effective_gbps": 0.26,
-                    "wire_bytes_per_contrib": 1 << 22},
-            "float16": {"wall_us": 70e3, "effective_gbps": 0.39,
-                        "wire_bytes_per_contrib": 1 << 21},
-            "float8_e4m3": {"wall_us": 72e3, "effective_gbps": 0.38,
-                            "wire_bytes_per_contrib": 1 << 20},
-            "int8": {"wall_us": 66e3, "effective_gbps": 0.42,
-                     "wire_bytes_per_contrib": (1 << 20) + 16384},
-        },
-        "compression_payload_bytes": 1 << 22,
-        "compression_wire_gbps_model": 0.5,
-        "compression_effective_gain_fp8": 0.46,
-        "compression_effective_gain_int8": 0.61,
-        "compression_convergence": {
-            "wire": "float8_e4m3", "steps": 40, "delta_pct": 0.5,
-        },
-    }
-
-
-def test_check_compression_gate_units():
-    from benchmarks.parse_results import (
-        CompressionGateError,
-        check_compression,
-    )
-
-    check_compression(_good_extras())  # passes
-    check_compression({})  # no-op when the bench never ran
-
-    bad = _good_extras()
-    del bad["compression_convergence"]
-    with pytest.raises(CompressionGateError, match="partial"):
-        check_compression(bad)
-
-    bad = _good_extras()
-    bad["compression_effective_gain_int8"] = -0.1
-    with pytest.raises(CompressionGateError, match="int8.*no effect"
-                       "|no effective-bandwidth gain"):
-        check_compression(bad)
-
-    bad = _good_extras()
-    bad["compression_wire_gbps_model"] = 0
-    with pytest.raises(CompressionGateError, match="link rate"):
-        check_compression(bad)
-
-    bad = _good_extras()
-    bad["compression_convergence"]["delta_pct"] = 25.0
-    with pytest.raises(CompressionGateError, match="convergence"):
-        check_compression(bad)
-
-    bad = _good_extras()
-    del bad["compression_sweep"]["int8"]
-    with pytest.raises(CompressionGateError, match="missing lanes"):
-        check_compression(bad)
-
-    bad = _good_extras()
-    bad["compression_sweep"]["int8"]["wire_bytes_per_contrib"] = (
-        1 << 22
-    )
-    with pytest.raises(CompressionGateError, match="ceiling"):
-        check_compression(bad)
-
-
-def test_check_compression_committed_artifact():
-    """The committed CPU-mesh capture passes its own gate (the CLI
-    path bench/LKG use)."""
-    from benchmarks.parse_results import check_compression_capture
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks", "results", "compression_cpu.json",
-    )
-    check_compression_capture(path)
-    with open(path) as f:
-        doc = json.load(f)
-    comp = doc["compression"]
-    assert comp["compression_effective_gain_fp8"] > 0
-    assert comp["compression_effective_gain_int8"] > 0
-    assert abs(comp["compression_convergence"]["delta_pct"]) <= 10.0
-
-
-def test_committed_wire_tuning_plan_artifact():
-    """The committed wire-axis tuned plan loads, validates, and carries
-    a raced per-bucket wire verdict with the modeled link rate in its
-    provenance."""
-    from accl_tpu.tuning import TuningPlan
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks", "results", "tuning_plan_wire_emu_w4.json",
-    )
-    plan = TuningPlan.load(path)
-    regs = [
-        e.get("registers") or {}
-        for e in plan.entries.get("allreduce", {}).values()
-    ]
-    assert any("wire_dtype" in r for r in regs), regs
-    assert plan.provenance.get("wire_gbps_model")
 
 
 # ---------------------------------------------------------------------------
