@@ -15,10 +15,12 @@ from .transformer import (
     DeltaAttention,  # noqa: F401
     LatentAttention,  # noqa: F401
     LayerKind,  # noqa: F401
+    Mamba2,  # noqa: F401
     TransformerConfig,
     YarnScaling,  # noqa: F401
     diffusion_noise,  # noqa: F401
     generate,
+    hybrid_layers,  # noqa: F401
     init_params,
     forward,
     make_sharded_generate,

@@ -73,8 +73,17 @@ picked by what the layer's parameters hold or by the caller's word:
   sequence's, averaged over the batch; whole on every chip, since the
   router scores all of its experts everywhere.
 
-Experts are two-matrix GELU FFNs (``w1``, ``w2``) or, with a ``w3`` in
-the parameters, gated-SiLU FFNs ``(silu(x w1) * (x w3)) w2``.
+* a LATENT expert bank (``w_down`` and ``w_up`` in the parameters, a
+  LatentMoE): the router and the shared expert read the token's own width,
+  the routed experts another: ``x w_down`` is what is sorted, gathered,
+  multiplied and placed (the held path's row buffer is that much
+  narrower), and the tokens' weighted sums go through ``w_up`` once a
+  token, after the combine; both products under the device scope
+  ``accl.moe::latent``.
+
+Experts are two-matrix FFNs (``w1``, ``w2``), GELU or by the caller's word
+``relu ** 2`` (``relu2``), or, with a ``w3`` in the parameters, gated-SiLU
+FFNs ``(silu(x w1) * (x w3)) w2``; a shared expert likewise.
 """
 
 from __future__ import annotations
@@ -93,35 +102,52 @@ from ..utils.profiling import device_scope
 def init_moe_params(key, d_model: int, d_ff: int, n_experts: int,
                     dtype=jnp.float32, gated: bool = False,
                     router_experts: int | None = None,
-                    shared_d_ff: int = 0, bias: bool = False):
+                    shared_d_ff: int = 0, bias: bool = False,
+                    latent: int = 0):
     """Gate + per-expert FFN weights (unsharded; shard E over 'ep').
     ``gated`` adds ``w3``, the second up-projection of a gated-SiLU
     expert.  ``router_experts``: the gate's width where the bank holds
-    only ``n_experts`` of them; ``shared_d_ff``: a ``shared`` gated-SiLU
-    expert of that width; ``bias``: a float32 selection bias, zero."""
+    only ``n_experts`` of them; ``shared_d_ff``: a ``shared`` expert of
+    that width, ``gated`` as the routed ones are; ``bias``: a float32
+    selection bias, zero; ``latent``: the
+    routed experts' width where it is not ``d_model``, with ``w_down``
+    into it and ``w_up`` out of it."""
     k1, k2, k3 = jax.random.split(key, 3)
     scale = d_model ** -0.5
     n_router = n_experts if router_experts is None else router_experts
+    width = latent or d_model        # what a routed expert reads and writes
     params = {
         "gate": jax.random.normal(k1, (d_model, n_router), dtype) * scale,
-        "w1": jax.random.normal(k2, (n_experts, d_model, d_ff), dtype) * scale,
-        "w2": jax.random.normal(k3, (n_experts, d_ff, d_model), dtype)
+        "w1": jax.random.normal(k2, (n_experts, width, d_ff), dtype)
+        * width ** -0.5,
+        "w2": jax.random.normal(k3, (n_experts, d_ff, width), dtype)
         * (d_ff ** -0.5),
     }
     if gated:
         params["w3"] = jax.random.normal(
-            jax.random.fold_in(k2, 1), (n_experts, d_model, d_ff), dtype
-        ) * scale
+            jax.random.fold_in(k2, 1), (n_experts, width, d_ff), dtype
+        ) * width ** -0.5
     if bias:
         params["bias"] = jnp.zeros((n_router,), jnp.float32)
     if shared_d_ff:
         ks = jax.random.split(jax.random.fold_in(key, 7), 3)
         params["shared"] = {
             "w1": jax.random.normal(ks[0], (d_model, shared_d_ff), dtype) * scale,
-            "w3": jax.random.normal(ks[1], (d_model, shared_d_ff), dtype) * scale,
             "w2": jax.random.normal(ks[2], (shared_d_ff, d_model), dtype)
             * (shared_d_ff ** -0.5),
         }
+        if gated:
+            params["shared"]["w3"] = (
+                jax.random.normal(ks[1], (d_model, shared_d_ff), dtype) * scale
+            )
+    if latent:
+        kd, ku = jax.random.split(jax.random.fold_in(key, 11))
+        params["w_down"] = (
+            jax.random.normal(kd, (d_model, latent), dtype) * scale
+        )
+        params["w_up"] = (
+            jax.random.normal(ku, (latent, d_model), dtype) * latent ** -0.5
+        )
     return params
 
 
@@ -181,11 +207,14 @@ def balance_losses(probs, topk_e, seqs: int, n_group: int, topk_group: int):
     }
 
 
-def _expert_act(up, params, matmul):
+def _expert_act(up, params, matmul, relu2: bool = False):
     """The experts' hidden activation from the first up-projection:
-    gated SiLU where the bank has a ``w3``, GELU otherwise."""
+    gated SiLU where the bank has a ``w3``, else GELU or, by the caller's
+    word, ``relu ** 2``."""
     if "w3" in params:
         return jax.nn.silu(up) * matmul(params["w3"])
+    if relu2:
+        return jnp.square(jax.nn.relu(up))
     return jax.nn.gelu(up)
 
 
@@ -278,13 +307,13 @@ def _combine_held_bwd(res, g):
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
-def _expert_bank(rows, params, sizes):
+def _expert_bank(rows, params, sizes, relu2: bool = False):
     """``rows``, sorted by expert in groups of ``sizes``, through the
     bank's grouped matmuls."""
     with device_scope("accl.moe::experts"):
         grouped = lambda a, w: grouped_matmul(a, w, sizes)
         h = _expert_act(
-            grouped(rows, params["w1"]), params, partial(grouped, rows)
+            grouped(rows, params["w1"]), params, partial(grouped, rows), relu2
         )
         return grouped(h, params["w2"])
 
@@ -300,7 +329,7 @@ def held_rows(entries: int, held: int, of: int, factor: float) -> int:
 
 
 def _held_experts(flat, params, topk_e, topk_p, tp_axis, first: int,
-                  rows: int):
+                  rows: int, relu2: bool = False):
     """The held experts' part of the result (module docstring): the
     bank is experts ``first .. first + E`` of the router's.  Entries are
     sorted with the held ones in front, by expert; the first ``rows`` of
@@ -336,7 +365,7 @@ def _held_experts(flat, params, topk_e, topk_p, tp_axis, first: int,
         if not _gathers_win(rows, N * k, D, flat.dtype.itemsize):
             starts = place_metadata(key, E, k, D, rows)
         x = jnp.where(valid[:, None], _held_rows(flat, order, slot, starts), 0)
-    out = _expert_bank(x, params, sizes)                  # (rows, D)
+    out = _expert_bank(x, params, sizes, relu2)           # (rows, D)
     with device_scope("accl.moe::combine"):
         out = jnp.where(valid[:, None], out, 0)
         # inside a shard_map: the weights varying over the axes the rows
@@ -353,7 +382,8 @@ def _held_experts(flat, params, topk_e, topk_p, tp_axis, first: int,
     }
 
 
-def _dropless_experts(flat, params, topk_e, topk_p, tp_axis):
+def _dropless_experts(flat, params, topk_e, topk_p, tp_axis,
+                      relu2: bool = False):
     """Every routing entry through its expert, none dropped: sort the
     N*k entries by expert, grouped matmuls over the sorted rows, unsort,
     weight and sum a token's k results.  Returns ``(y, tokens an
@@ -367,7 +397,7 @@ def _dropless_experts(flat, params, topk_e, topk_p, tp_axis):
         back = jnp.argsort(order)                         # entry -> sorted
         sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
         rows = _take_rows(flat, order, back, k)           # (N*k, D) by expert
-    out = _expert_bank(rows, params, sizes)               # (N*k, D)
+    out = _expert_bank(rows, params, sizes, relu2)        # (N*k, D)
     with device_scope("accl.moe::combine"):
         got = _take_rows(out, back, order, 1).reshape(N, k, D)
         y = (got.astype(jnp.float32) * topk_p[..., None]).sum(axis=1)
@@ -394,6 +424,7 @@ def moe_ffn(
     topk_group: int = 1,
     balance_groups: int = 0,
     switch_balance: bool = False,
+    relu2: bool = False,
 ):
     """Top-k gated MoE FFN (k=1 is Switch routing, k=2 the classic MoE).
 
@@ -424,6 +455,9 @@ def moe_ffn(
     (the softmax router): ``load_balance`` is the Switch term all the
     same, over ALL the router's outputs and this chip's rows, whatever
     share of the experts is held (Qwen3-MoE's auxiliary loss a layer).
+    ``relu2``: a two-matrix expert's activation is ``relu ** 2`` and not
+    GELU, routed and shared alike, on either dispatch.  A ``w_down`` in
+    the parameters makes the bank a latent one (module docstring).
 
     Returns (B, T, D): expert outputs weighted by the gate probability;
     over-capacity entries contribute zero (callers add the residual).
@@ -463,12 +497,13 @@ def moe_ffn(
     beyond = (
         router != "softmax" or "shared" in params or n_router != E
         or n_group > 1 or route_scale != 1.0 or bool(balance_groups)
+        or "w_down" in params
     )
     if beyond and capacity_factor is not None:
         raise ValueError(
             "the sigmoid router, a shared expert, held experts, grouped "
-            "top-k, a route scale and the balance losses are the "
-            "dropless dispatch's (capacity_factor=None)"
+            "top-k, a route scale, a latent bank and the balance losses are "
+            "the dropless dispatch's (capacity_factor=None)"
         )
     if capacity_factor is None:
         if ep > 1:
@@ -531,21 +566,32 @@ def moe_ffn(
                     topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
                 if route_scale != 1.0:
                     topk_p = topk_p * route_scale
+        # what the routed experts read: the token, or its latent
+        routed = flat
+        if "w_down" in params:
+            with device_scope("accl.moe::latent"):
+                routed = flat @ params["w_down"]
         if n_router != E:
             rows = held_rows(N * k, E, n_router, held_row_factor)
             y, counters = _held_experts(
-                flat, params, topk_e, topk_p, tp_axis, first_expert, rows
+                routed, params, topk_e, topk_p, tp_axis, first_expert, rows,
+                relu2,
             )
         else:
-            y, sizes = _dropless_experts(flat, params, topk_e, topk_p, tp_axis)
+            y, sizes = _dropless_experts(
+                routed, params, topk_e, topk_p, tp_axis, relu2
+            )
             counters = {
                 "expert_tokens": sizes, "dropped": jnp.zeros((), jnp.int32),
             }
+        if "w_up" in params:
+            with device_scope("accl.moe::latent"):
+                y = y @ params["w_up"]
         if "shared" in params:
             with device_scope("accl.moe::shared"):
                 sp = params["shared"]
-                every = (
-                    jax.nn.silu(flat @ sp["w1"]) * (flat @ sp["w3"])
+                every = _expert_act(
+                    flat @ sp["w1"], sp, lambda w: flat @ w, relu2
                 ) @ sp["w2"]
                 if tp_axis is not None:
                     every = lax.psum(every, tp_axis)
@@ -630,7 +676,7 @@ def moe_ffn(
 
     # --- expert FFN on the local experts (batched einsum -> MXU) ---------
     up = lambda w: jnp.einsum("ecd,edf->ecf", work, w)
-    h = _expert_act(up(params["w1"]), params, up)
+    h = _expert_act(up(params["w1"]), params, up, relu2)
     out = jnp.einsum("ecf,efd->ecd", h, params["w2"])
 
     if ep_axis is not None:

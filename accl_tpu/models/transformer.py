@@ -41,14 +41,20 @@ class LayerKind:
     and its FFN.  ``window``: how many keys a query sees, its own among
     them (``None`` = every earlier key).  ``rope``: whether this layer's
     q and k rotate (a ``pos_embedding="rope"`` model may leave some
-    layers without position, NoPE).  ``ffn``: ``"dense"`` or ``"moe"``
-    (the expert bank of the config's ``n_experts``); ``d_ff``: the dense
-    FFN's width, or one expert's.  ``mixer``: ``"attention"`` (wq, wk,
-    wv), ``"latent"`` (``TransformerConfig.latent``'s sizes) or ``"kda"``
-    (``TransformerConfig.kda``'s: a linear-attention layer, which has no
-    position encoding and no window, so ``rope`` and ``window`` say
-    nothing of it); ``None`` is the config's own, the latent mixer where
-    it has one and attention elsewhere (``TransformerConfig.mixer``)."""
+    layers without position, NoPE).  ``ffn``: ``"dense"``, ``"moe"``
+    (the expert bank of the config's ``n_experts``) or ``"none"``;
+    ``d_ff``: the dense FFN's width, or one expert's.  ``mixer``:
+    ``"attention"`` (wq, wk, wv), ``"latent"``
+    (``TransformerConfig.latent``'s sizes), ``"kda"``
+    (``TransformerConfig.kda``'s: a linear-attention layer) or
+    ``"mamba2"`` (``TransformerConfig.mamba``'s: a state-space layer;
+    neither has a position encoding or a window, so ``rope`` and
+    ``window`` say nothing of them), or ``"none"``; ``None`` is the
+    config's own, the latent mixer where it has one and attention
+    elsewhere (``TransformerConfig.mixer``).  A block with ``"none"`` for
+    one of the two has ONE sub-layer, ``h + f(norm h)`` with one norm and
+    one residual (``ln1`` a mixer's, ``ln2`` an FFN's: the other is not
+    in the tree); it cannot have ``"none"`` for both."""
 
     window: Optional[int] = None
     rope: bool = True
@@ -109,6 +115,34 @@ class DeltaAttention:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba2:
+    """The sizes of a Mamba-2 mixer (state-space duality, arXiv:2405.21060;
+    ``TransformerConfig.mamba``; the layers whose ``LayerKind.mixer`` is
+    ``"mamba2"``): ``n_heads`` heads (its own count, not the attention's)
+    of ``head_dim`` columns with a state of ``head_dim x state`` each, in
+    ``groups`` groups of consecutive heads that share the state's input and
+    output directions B and C.  ``[z | x | B | C | dt] = u W_in``; x, B and
+    C through ``silu(conv(.) + bias)``, a causal depthwise convolution of
+    ``conv`` taps; ``dt = softplus(dt + dt_bias)`` and a rate ``A =
+    -exp(a_log)``, scalars a head, into ``S_t = exp(dt_t A) S_{t-1} + dt_t
+    x_t B_t^T``, ``y_t = S_t C_t + D x_t`` (``ops.ssd``, in chunks of
+    ``chunk``); ``y silu(z)`` RMS-normed a group (the gate BEFORE the norm)
+    before ``wo``.  ``dt_min``, ``dt_max`` and ``dt_floor`` are
+    INITIALISATION (``dt_bias`` is the inverse softplus of a log-uniform
+    draw between the first two, floored): the step has no clamp."""
+
+    n_heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv: int = 4
+    chunk: int = 128
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockDiffusion:
     """Block-diffusion training (BD3-LM's objective, as the SDAR family
     adopts it; ``TransformerConfig.diffusion``).  A sequence of ``L`` ids
@@ -124,6 +158,26 @@ class BlockDiffusion:
     block: int
     mask_id: int
     eps: float = 1e-3
+
+
+def hybrid_layers(pattern: str, d_ff: int = 0, moe_d_ff: int = 0):
+    """One :class:`LayerKind` a letter of a published
+    ``hybrid_override_pattern`` (the Nemotron-H family's): every block has
+    ONE sub-layer, ``M`` a Mamba-2 mixer, ``*`` attention without
+    position, ``E`` the expert bank (experts ``moe_d_ff`` wide), ``-`` a
+    dense FFN ``d_ff`` wide."""
+    kinds = {
+        "M": LayerKind(mixer="mamba2", rope=False, ffn="none"),
+        "*": LayerKind(mixer="attention", rope=False, ffn="none"),
+        "E": LayerKind(mixer="none", rope=False, ffn="moe", d_ff=moe_d_ff),
+        "-": LayerKind(mixer="none", rope=False, ffn="dense", d_ff=d_ff),
+    }
+    if unknown := set(pattern) - set(kinds):
+        raise ValueError(
+            f"hybrid pattern {pattern!r}: unknown block {sorted(unknown)}; "
+            f"a block is one of {sorted(kinds)}"
+        )
+    return tuple(kinds[letter] for letter in pattern)
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -222,8 +276,9 @@ class TransformerConfig:
     seq_parallel: bool = False
     # the block's kinds, alike in every layer: ``norm`` is "layernorm"
     # (mean-centred) or "rmsnorm", both a scale and no bias at eps 1e-5;
-    # ``ffn`` is "gelu" (two matrices) or "swiglu" (gated SiLU, three
-    # matrices: ``(silu(x w1) * (x w3)) w2``), the dense FFN and each
+    # ``ffn`` is "gelu" (two matrices), "swiglu" (gated SiLU, three
+    # matrices: ``(silu(x w1) * (x w3)) w2``) or "relu2" (two matrices,
+    # ``relu(x w1) ** 2 w2``), the dense FFN, each expert and a shared
     # expert alike; ``qk_norm`` puts an RMSNorm on the projected q and k:
     # ``True`` over the WHOLE projection before the split into heads,
     # ``"head"`` over each head's ``head_dim`` after it (one learned
@@ -281,6 +336,15 @@ class TransformerConfig:
     # the recurrent state as a cache, the ring would hand a state from rank
     # to rank.
     kda: Optional[DeltaAttention] = None
+    # the Mamba-2 mixer's sizes (:class:`Mamba2`), for the layers of the
+    # pattern whose ``LayerKind.mixer`` is ``"mamba2"``: the chunked
+    # selective state-space recurrence with a scalar decay a head
+    # (``ops.ssd``), forward and backward, on the train and forward paths
+    # (tp splits its heads AND its B/C groups together: every matrix but
+    # ``wo`` column-parallel, the taps, biases and scalars with their
+    # channels and heads).  REFUSED by name where the KDA mixer is, for
+    # the same reasons.
+    mamba: Optional[Mamba2] = None
     # the objective where it is not next-token prediction
     # (:class:`BlockDiffusion`): ``loss_fn`` and the train step noise the
     # ids from a key (the step's third argument, in ``targets``' place)
@@ -326,8 +390,8 @@ class TransformerConfig:
     # renormalised (``moe_norm_topk_prob``) and times ``moe_route_scale``;
     # the bias is state no gradient touches, moved after each train step
     # by ``moe_bias_rate * sign(mean load - load)`` (0 = no bias).
-    # ``moe_shared_d_ff``: one gated-SiLU expert of that width beside the
-    # routed ones, every token through it (0 = none).
+    # ``moe_shared_d_ff``: one expert of that width (``ffn``'s kind, as
+    # the routed ones) beside them, every token through it (0 = none).
     # ``moe_router_experts``: the router's width where this chip HOLDS
     # only ``n_experts`` of them, ``moe_first_expert`` on (one chip's
     # share of an expert-parallel group, without its exchange): routing
@@ -343,6 +407,12 @@ class TransformerConfig:
     # bias`` under the sigmoid one (``noaux_tc``), the ``moe_topk_group`` best
     # groups kept and the top-k taken over what stays (1, 1 = no limit).
     # ``moe_route_scale`` multiplies the chosen weights on either router.
+    # ``moe_latent``: the routed experts work on ANOTHER WIDTH than the
+    # router's (a LatentMoE): the router and a shared expert read the
+    # hidden state, the routed experts ``x w_down`` (``moe_latent`` wide,
+    # and so are the rows that are sorted, gathered, multiplied and placed),
+    # their weighted sum going through ``w_up`` once a token, after the
+    # combine (0 = the token's own width).
     # ``moe_balance_weights``: DeepSeek-V2's expert-, device- and
     # communication-level balance losses (models/moe.py), each a
     # sequence's, averaged over the batch, summed over the expert layers
@@ -357,6 +427,7 @@ class TransformerConfig:
     moe_router_experts: Optional[int] = None
     moe_first_expert: int = 0
     moe_held_row_factor: float = 2.0
+    moe_latent: int = 0
     # which mesh axis the expert bank shards over.  "dp" (default) is the
     # DeepSpeed-MoE welded layout: expert parallelism rides the data
     # axis.  Naming a DEDICATED axis (conventionally "ep", on a
@@ -473,13 +544,15 @@ class TransformerConfig:
         """Every layer alike and nothing of the later kinds (a pattern, a
         head width of its own, the gate, per-head QK-norm, post-norms, a
         scaled embedding, the sigmoid router, a shared expert, a held
-        share, a latent or a KDA mixer, grouped top-k, the balance losses,
-        an objective of its own): what prefill/generate and the context-
-        and sequence-parallel blocks compute."""
+        share, a latent, a KDA or a Mamba-2 mixer, grouped top-k, the
+        balance losses, a latent expert bank, relu2, an objective of its
+        own): what prefill/generate and the context- and sequence-parallel
+        blocks compute."""
         return (
             self.layers is None and self.head_dim is None
             and self.latent is None and self.diffusion is None
-            and self.kda is None
+            and self.kda is None and self.mamba is None
+            and not self.moe_latent and self.ffn != "relu2"
             and self.moe_n_group == 1
             and not any(self.moe_balance_weights)
             and not self.attn_gate and not self.post_norm
@@ -491,7 +564,7 @@ class TransformerConfig:
     def __post_init__(self):
         if self.norm not in _NORMS:
             raise ValueError(f"unknown norm {self.norm!r}")
-        if self.ffn not in ("gelu", "swiglu"):
+        if self.ffn not in ("gelu", "swiglu", "relu2"):
             raise ValueError(f"unknown ffn {self.ffn!r}")
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"unknown qk_norm {self.qk_norm!r}")
@@ -529,21 +602,40 @@ class TransformerConfig:
                     f"and a convolution of at least 1 and a lower_bound in "
                     f"[{-80.0 / SUB}, 0); got {d}"
                 )
+        if self.mamba is not None:
+            m = self.mamba
+            if (
+                "mamba2" not in kinds
+                or min(m.n_heads, m.head_dim, m.state, m.groups, m.conv,
+                       m.chunk) < 1
+                or m.n_heads % m.groups
+                or not 0.0 < m.dt_floor <= m.dt_min <= m.dt_max
+            ):
+                raise ValueError(
+                    "a Mamba-2 mixer (TransformerConfig.mamba) is some "
+                    "layer's of the pattern (LayerKind.mixer='mamba2'), with "
+                    "heads in whole groups, sizes of at least 1 and 0 < "
+                    f"dt_floor <= dt_min <= dt_max; got {m}"
+                )
         if self.rope_yarn is not None and self.pos_embedding != "rope":
             raise ValueError("rope_yarn rescales a rotary embedding")
         if self.diffusion is not None:
             d = self.diffusion
             if (
                 self.pos_embedding != "rope" or self.latent is not None
-                or self.kda is not None
+                or self.kda is not None or self.mamba is not None
                 or any(k.window is not None for k in self.layers or ())
+                or any(
+                    "none" in (k.mixer, k.ffn) for k in self.layers or ()
+                )
                 or d.block < 1 or not 0 <= d.mask_id < self.vocab
                 or not 0.0 < d.eps < 1.0
             ):
                 raise ValueError(
                     "block diffusion (TransformerConfig.diffusion) rotates "
-                    "(pos_embedding='rope'), has no window and no latent "
-                    "or KDA mixer, blocks of at least 1, a mask_id inside the "
+                    "(pos_embedding='rope'), has no window and no latent, "
+                    "KDA mixer, Mamba-2 mixer or block of one sub-layer, "
+                    "blocks of at least 1, a mask_id inside the "
                     f"vocabulary and 0 < eps < 1; got {d}"
                 )
         if self.moe_n_group != 1 or self.moe_topk_group != 1:
@@ -570,15 +662,21 @@ class TransformerConfig:
                     f"{self.n_layers}"
                 )
             for i, kind in enumerate(self.layers):
-                if kind.ffn not in ("dense", "moe"):
+                if kind.ffn not in ("dense", "moe", "none"):
                     raise ValueError(f"layer {i}: unknown ffn {kind.ffn!r}")
                 if kind.ffn == "moe" and not self.n_experts:
                     raise ValueError(f"layer {i}: an moe layer needs n_experts")
                 if kind.window is not None and kind.window < 1:
                     raise ValueError(f"layer {i}: window {kind.window}")
                 mixer = self.mixer(kind)
-                if mixer not in ("attention", "latent", "kda"):
+                if mixer not in ("attention", "latent", "kda", "mamba2", "none"):
                     raise ValueError(f"layer {i}: unknown mixer {mixer!r}")
+                if mixer == "none":
+                    if kind.ffn == "none":
+                        raise ValueError(
+                            f"layer {i}: a block has a mixer, an FFN or both"
+                        )
+                    continue
                 if mixer == "kda":
                     if self.kda is None or kind.window is not None:
                         raise ValueError(
@@ -586,13 +684,20 @@ class TransformerConfig:
                             "TransformerConfig.kda and has no window"
                         )
                     continue  # no position encoding: ``rope`` says nothing
+                if mixer == "mamba2":
+                    if self.mamba is None or kind.window is not None:
+                        raise ValueError(
+                            f"layer {i}: a Mamba-2 layer needs "
+                            "TransformerConfig.mamba and has no window"
+                        )
+                    continue
                 if (mixer == "latent") != (self.latent is not None):
                     raise ValueError(
                         f"layer {i}: the {mixer} mixer in a stack whose "
                         "TransformerConfig.latent is "
                         f"{'set' if self.latent is not None else 'None'} (a "
                         "stack holds the latent mixer or attention, beside "
-                        "KDA layers)"
+                        "KDA and Mamba-2 layers)"
                     )
                 if kind.rope and self.pos_embedding != "rope":
                     raise ValueError(
@@ -602,12 +707,12 @@ class TransformerConfig:
         beyond = (
             self.moe_router != "softmax" or self.moe_shared_d_ff
             or self.moe_router_experts is not None or self.moe_n_group != 1
-            or any(self.moe_balance_weights)
+            or any(self.moe_balance_weights) or self.moe_latent
         )
         if beyond and (not self.n_experts or self.moe_capacity_factor is not None):
             raise ValueError(
                 "the sigmoid router, a shared expert, a held share, grouped "
-                "top-k and the balance losses are "
+                "top-k, a latent expert bank and the balance losses are "
                 "the dropless path's (n_experts > 0, "
                 "moe_capacity_factor=None)"
             )
@@ -639,7 +744,9 @@ def _check_axis_compat(cfg) -> None:
             "(TransformerConfig.plain): no layer pattern, window, head_dim, "
             "gate, per-head QK-norm, post-norm, scaled embedding, sigmoid "
             "router, shared expert, held share, latent mixer (MLA), KDA "
-            "mixer (its recurrent state is not handed round the ring yet), "
+            "mixer or Mamba-2 mixer (a recurrent state is not handed round "
+            "the ring yet), block of one sub-layer, latent expert bank, "
+            "relu2, "
             "grouped top-k, balance losses or block diffusion (its layout is "
             "not in the ring yet)"
         )
@@ -751,7 +858,21 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
     row = P(None, None) if cp else P("tp", None)   # input dim on tp
     mixer = cfg.mixer(kind)
     heads = None if cp else "tp"
-    if mixer == "kda":
+    if mixer == "none":
+        layer = {}
+    elif mixer == "mamba2":
+        layer = {
+            # [z | x | B | C | dt] = u W_in as five matrices, so that tp
+            # splits the heads (z, x, dt) AND the groups (B, C) together:
+            # a chip's heads keep their own groups; the taps, the biases,
+            # the scalars a head and the grouped norm's scale follow
+            "wz": col, "wx": col, "wb": col, "wc": col, "wdt": col,
+            "conv_x": col, "conv_b": col, "conv_c": col,
+            "bias_x": P(heads), "bias_b": P(heads), "bias_c": P(heads),
+            "dt_bias": P(heads), "a_log": P(heads), "d_skip": P(heads),
+            "y_norm": P(heads), "wo": row,
+        }
+    elif mixer == "kda":
         layer = {
             # every projection's columns are heads (``wbeta``'s one a
             # head), and so are the channels of the taps and of ``dt_bias``;
@@ -779,15 +900,22 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
             "wv": col,
             "wo": row,  # (heads * head_size / tp, d_model)
         }
-    layer["ln1"] = P(None)
-    layer["ln2"] = P(None)
-    if cfg.attn_gate and mixer != "kda":
+    # one norm a sub-layer: a block without a mixer has no ``ln1``, one
+    # without an FFN no ``ln2``
+    softmax_mixer = mixer in ("attention", "latent")
+    if mixer != "none":
+        layer["ln1"] = P(None)
+    if kind.ffn != "none":
+        layer["ln2"] = P(None)
+    if cfg.attn_gate and softmax_mixer:
         layer["wg"] = col  # the gate's columns follow q's heads
     if cfg.post_norm:
-        layer["ln1_post"] = P(None)
-        layer["ln2_post"] = P(None)
+        if mixer != "none":
+            layer["ln1_post"] = P(None)
+        if kind.ffn != "none":
+            layer["ln2_post"] = P(None)
     # a KDA layer's q and k are L2-normalised: no learned QK-norm
-    qk_norm = mixer != "kda" and cfg.qk_norm
+    qk_norm = softmax_mixer and cfg.qk_norm
     if qk_norm == "head":
         # one scale of head_size for every head: replicated
         layer["q_norm"] = P(None)
@@ -797,6 +925,8 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
         # projections' output columns
         layer["q_norm"] = P(heads)
         layer["k_norm"] = P(heads)
+    if kind.ffn == "none":
+        return layer
     if kind.ffn == "dense":
         layer["w1"] = col  # (d_model, d_ff/tp)
         layer["w2"] = row  # (d_ff/tp, d_model)
@@ -824,7 +954,13 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
     if cfg.moe_bias_rate:
         moe["bias"] = P(None)
     if cfg.moe_shared_d_ff:
-        moe["shared"] = {"w1": col, "w3": col, "w2": row}
+        moe["shared"] = {"w1": col, "w2": row}
+        if gated:
+            moe["shared"]["w3"] = col
+    if cfg.moe_latent:
+        # into and out of the experts' width: every chip's, whole
+        moe["w_down"] = P(None, None)
+        moe["w_up"] = P(None, None)
     layer["moe"] = moe
     return layer
 
@@ -920,10 +1056,50 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
             "wo": normal(ks[11], (wide, cfg.d_model)),
         }
 
+    def mamba_mixer(key, m):
+        """The matrices as every other (normal, 0.02); taps and the
+        convolution's bias normal at ``conv ** -0.5``; ``a_log`` the log of
+        a uniform draw from [1, 16) a head, ``dt_bias`` the inverse
+        softplus of a log-uniform draw from [dt_min, dt_max) floored at
+        dt_floor, ``d_skip`` 1 (the family's conventions)."""
+        ks = jax.random.split(key, 14)
+        inner, bc = m.n_heads * m.head_dim, m.groups * m.state
+        matrix = lambda key, n: normal(key, (cfg.d_model, n))
+        taps = lambda key, n: (
+            jax.random.normal(key, (m.conv, n), cfg.dtype) * m.conv ** -0.5
+        )
+        bias = lambda key, n: (
+            jax.random.normal(key, (n,), cfg.dtype) * m.conv ** -0.5
+        )
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            ks[12], (m.n_heads,), jnp.float32, math.log(m.dt_min),
+            math.log(m.dt_max),
+        )), m.dt_floor)
+        return {
+            "wz": matrix(ks[0], inner), "wx": matrix(ks[1], inner),
+            "wb": matrix(ks[2], bc), "wc": matrix(ks[3], bc),
+            "wdt": matrix(ks[4], m.n_heads),
+            "conv_x": taps(ks[5], inner), "conv_b": taps(ks[6], bc),
+            "conv_c": taps(ks[7], bc),
+            "bias_x": bias(ks[8], inner), "bias_b": bias(ks[9], bc),
+            "bias_c": bias(ks[10], bc),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "a_log": jnp.log(jax.random.uniform(
+                ks[11], (m.n_heads,), jnp.float32, 1.0, 16.0
+            )),
+            "d_skip": jnp.ones((m.n_heads,), jnp.float32),
+            "y_norm": jnp.ones((inner,), cfg.dtype),
+            "wo": normal(ks[13], (inner, cfg.d_model)),
+        }
+
     for i, kind in enumerate(cfg.pattern()):
         kk = k[2 + 4 * i : 6 + 4 * i]
         mixer = cfg.mixer(kind)
-        if mixer == "kda":
+        if mixer == "none":
+            layer = {}
+        elif mixer == "mamba2":
+            layer = mamba_mixer(kk[0], cfg.mamba)
+        elif mixer == "kda":
             layer = kda_mixer(kk[0], cfg.kda)
         elif mixer == "latent":
             layer = latent_mixer(kk[0], cfg.latent)
@@ -934,18 +1110,23 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
                 "wv": normal(jax.random.fold_in(kk[0], 2), (cfg.d_model, d_kv)),
                 "wo": normal(kk[1], (d_q, cfg.d_model)),
             }
-        layer["ln1"] = jnp.ones((cfg.d_model,), cfg.dtype)
-        layer["ln2"] = jnp.ones((cfg.d_model,), cfg.dtype)
-        if cfg.attn_gate and mixer != "kda":
+        softmax_mixer = mixer in ("attention", "latent")
+        if mixer != "none":
+            layer["ln1"] = jnp.ones((cfg.d_model,), cfg.dtype)
+        if kind.ffn != "none":
+            layer["ln2"] = jnp.ones((cfg.d_model,), cfg.dtype)
+        if cfg.attn_gate and softmax_mixer:
             layer["wg"] = normal(
                 jax.random.fold_in(kk[1], 1),
                 (cfg.d_model, cfg.n_heads if cfg.attn_gate == "head" else d_q),
             )
         if cfg.post_norm:
-            layer["ln1_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
-            layer["ln2_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
+            if mixer != "none":
+                layer["ln1_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
+            if kind.ffn != "none":
+                layer["ln2_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
         # a KDA layer's q and k are L2-normalised: no learned QK-norm
-        qk_norm = mixer != "kda" and cfg.qk_norm
+        qk_norm = softmax_mixer and cfg.qk_norm
         if qk_norm == "head":
             layer["q_norm"] = jnp.ones((hd,), cfg.dtype)
             layer["k_norm"] = jnp.ones((hd,), cfg.dtype)
@@ -959,8 +1140,9 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
                 kk[2], cfg.d_model, kind.d_ff, cfg.n_experts, cfg.dtype,
                 gated=gated, router_experts=cfg.router_experts(),
                 shared_d_ff=cfg.moe_shared_d_ff, bias=bool(cfg.moe_bias_rate),
+                latent=cfg.moe_latent,
             )
-        else:
+        elif kind.ffn == "dense":
             layer["w1"] = normal(kk[2], (cfg.d_model, kind.d_ff))
             layer["w2"] = normal(kk[3], (kind.d_ff, cfg.d_model))
             if gated:
@@ -1409,17 +1591,19 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
     return out.reshape(B, H, T, v.shape[-1])
 
 
-def _ffn_hidden(h, lp):
+def _ffn_hidden(h, lp, relu2: bool = False):
     """The dense FFN's hidden activation: gated SiLU where the layer has
-    a ``w3``, GELU otherwise."""
+    a ``w3``, else GELU or, by the caller's word, ``relu ** 2``."""
     if "w3" in lp:
         return jax.nn.silu(h @ lp["w1"]) * (h @ lp["w3"])
+    if relu2:
+        return jnp.square(jax.nn.relu(h @ lp["w1"]))
     return jax.nn.gelu(h @ lp["w1"])
 
 
 def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
          moe_no_drop=False, reduce_fn=None, fanout_fn=None,
-         norm=_layernorm):
+         norm=_layernorm, relu2=False):
     """The block's MLP half (shared by train and decode paths): ln2 ->
     column-parallel up, row-parallel down -> tp-allreduce, residual.
 
@@ -1428,7 +1612,8 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
     expert's dp rank through the all-to-all over ``ep_axis`` and the
     outputs return the same way (models/moe.py).  ``with_aux=True``
     (training) additionally returns the router health terms; serving
-    paths leave it off."""
+    paths leave it off.  ``relu2``: the two-matrix FFN's activation is
+    ``relu ** 2`` (``TransformerConfig.ffn``), dense, routed and shared."""
     h = norm(x, lp["ln2"])
     if "moe" in lp:
         from .moe import moe_ffn
@@ -1450,6 +1635,8 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
         if moe_cfg.moe_n_group != 1:
             beyond.update(n_group=moe_cfg.moe_n_group,
                           topk_group=moe_cfg.moe_topk_group)
+        if relu2:
+            beyond.update(relu2=True)
         if with_aux and any(moe_cfg.moe_balance_weights):
             beyond.update(balance_groups=moe_cfg.moe_n_group)
         if moe_cfg.moe_router_experts is not None:
@@ -1474,7 +1661,7 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
         return (x + y, aux) if with_aux else x + y
     if fanout_fn is not None and tp_axis is not None:
         h = fanout_fn(h, tp_axis)  # see _block: the w1 fan-out point
-    partial_f = _ffn_hidden(h, lp) @ lp["w2"]
+    partial_f = _ffn_hidden(h, lp, relu2) @ lp["w2"]
     if tp_axis is not None:
         if reduce_fn is None:
             partial_f = collectives.allreduce(
@@ -1576,10 +1763,44 @@ def _kda_partial(h, lp, n_heads_local, kda):
         return o @ lp["wo"]
 
 
+def _mamba2_partial(h, lp, mamba):
+    """The Mamba-2 mixer (:class:`Mamba2`) on a full-sequence activation,
+    heads and groups column-parallel: the row-parallel PARTIAL output.  The
+    sizes are the tree's but what no shape says, which ``mamba`` carries:
+    a head's width, the state's, the chunk and the norm's ``eps``.  The
+    matmuls take the activations' type; the convolution, SiLU, softplus,
+    the core, the gate and the grouped norm are float32.  Everything but
+    the core runs under the device scope ``accl.attn::mamba_proj``, the
+    core (from x, B, C and dt to y: ``ops.ssd.ssd_chunked``) under
+    ``accl.attn::ssd``."""
+    from ..ops.ssd import conv_silu, gated_group_norm, ssd_chunked
+
+    B, T, _ = h.shape
+    P, N = mamba["head_dim"], mamba["state"]
+    H, G = lp["wdt"].shape[1], lp["wb"].shape[1] // N
+    f32 = jnp.float32
+    heads = lambda t, n: t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
+    with device_scope("accl.attn::mamba_proj"):
+        z = h @ lp["wz"]
+        x = heads(conv_silu(h @ lp["wx"], lp["conv_x"], lp["bias_x"]), H)
+        b = heads(conv_silu(h @ lp["wb"], lp["conv_b"], lp["bias_b"]), G)
+        c = heads(conv_silu(h @ lp["wc"], lp["conv_c"], lp["bias_c"]), G)
+        dt = jax.nn.softplus(
+            (h @ lp["wdt"]).astype(f32) + lp["dt_bias"].astype(f32)
+        ).transpose(0, 2, 1)                              # (B, H, T)
+        a = -jnp.exp(lp["a_log"].astype(f32))
+    with device_scope("accl.attn::ssd"):
+        y = ssd_chunked(x, b, c, dt, a, lp["d_skip"], mamba["chunk"])
+    with device_scope("accl.attn::mamba_proj"):
+        y = y.transpose(0, 2, 1, 3).reshape(B, T, H * P)
+        y = gated_group_norm(y, z, lp["y_norm"], G, mamba["eps"], h.dtype)
+        return y @ lp["wo"]
+
+
 def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
                   rope_base=None, positions=None, attention_fn=None,
                   tp_axis=None, window=None, head_norm=False, latent=None,
-                  qk_eps=1e-5, block_diffusion=None, kda=None):
+                  qk_eps=1e-5, block_diffusion=None, kda=None, mamba=None):
     """Column-parallel attention on a full-sequence activation: returns
     the row-parallel PARTIAL output (pre-reduction) and the (k, v) head
     tensors (B, Hkv_local, T, hd) for KV-cache prefill.  The kv head
@@ -1599,13 +1820,16 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     AFTER the split (:func:`_qk_norm` is the whole projection's);
     a ``wg`` gates the attention output, ``attn * sigmoid(h wg)``, before
     ``wo``; a ``wkv_a`` is the latent mixer's (:func:`_latent_attn_partial`,
-    which has no cache to return yet) and an ``a_log`` the KDA mixer's
-    (:func:`_kda_partial`, likewise).  ``window`` is the sliding window,
+    which has no cache to return yet), a ``d_skip`` the Mamba-2 mixer's
+    (:func:`_mamba2_partial`) and an ``a_log`` without one the KDA mixer's
+    (:func:`_kda_partial`; both likewise).  ``window`` is the sliding window,
     run under the device scope ``accl.attn::window`` (full attention stays
     ``accl.attn::core``).  ``qk_eps`` is QK-norm's epsilon.
     ``block_diffusion=(L, B)``: ``h`` is ``[noisy ; clean]``, ``2 L`` rows
     that rotate at positions ``0..L`` twice, and the core runs under that
     layout in the device scope ``accl.attn::blockdiff``."""
+    if "d_skip" in lp:
+        return _mamba2_partial(h, lp, mamba), None
     if "a_log" in lp:
         return _kda_partial(h, lp, n_heads_local, kda), None
     if "wkv_a" in lp:
@@ -1661,10 +1885,14 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
            ep_axis=None, moe_cfg=None, with_aux=False,
            reduce_fn=None, fanout_fn=None, norm=_layernorm,
            window=None, head_norm=False, latent=None, qk_eps=1e-5,
-           block_diffusion=None, kda=None):
+           block_diffusion=None, kda=None, mamba=None, relu2=False):
     """One transformer block on tp-sharded weights.  ``lp['wqkv']`` etc. are
     the *local shards*; the tp-allreduce after each row-parallel matmul is
     the reference's fused-allreduce hot path in model form.
+
+    What the tree holds says which sub-layers the block has: a mixer where
+    it has an ``ln1``, an FFN where it has an ``ln2`` (``LayerKind``: a
+    block of ONE sub-layer is ``x + f(norm x)``, one norm, one residual).
 
     ``return_kv=True`` additionally returns the (k, v) head tensors
     (B, H_local, T, hd) — the prefill path of the KV-cache decode.
@@ -1678,25 +1906,32 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
         reduce_fn = lambda v, ax: collectives.allreduce(
             v, ax, ReduceFunction.SUM
         )
-    h = norm(x, lp["ln1"])
-    if fanout_fn is not None and tp_axis is not None:
-        # replicated h fans out into the tp-sharded q/k/v matmuls: the
-        # manual-backward mode marks the fan-out so its transpose (a tp
-        # psum of the branch cotangents) lands here and nowhere else
-        h = fanout_fn(h, tp_axis)
-    partial_o, kv = _attn_partial(
-        h, lp, n_heads_local, attn_impl, causal, rope_base, tp_axis=tp_axis,
-        window=window, head_norm=head_norm, latent=latent, qk_eps=qk_eps,
-        block_diffusion=block_diffusion, kda=kda,
-    )
-    if tp_axis is not None:
-        partial_o = reduce_fn(partial_o, tp_axis)
-    if "ln1_post" in lp:
-        # the tree's post-norm: the half's output normed once more
-        partial_o = norm(partial_o, lp["ln1_post"])
-    x = x + partial_o
-    out = _mlp(x, lp, tp_axis, ep_axis, moe_cfg, with_aux,
-               reduce_fn=reduce_fn, fanout_fn=fanout_fn, norm=norm)
+    kv = None
+    if "ln1" in lp:
+        h = norm(x, lp["ln1"])
+        if fanout_fn is not None and tp_axis is not None:
+            # replicated h fans out into the tp-sharded q/k/v matmuls: the
+            # manual-backward mode marks the fan-out so its transpose (a tp
+            # psum of the branch cotangents) lands here and nowhere else
+            h = fanout_fn(h, tp_axis)
+        partial_o, kv = _attn_partial(
+            h, lp, n_heads_local, attn_impl, causal, rope_base,
+            tp_axis=tp_axis, window=window, head_norm=head_norm,
+            latent=latent, qk_eps=qk_eps, block_diffusion=block_diffusion,
+            kda=kda, mamba=mamba,
+        )
+        if tp_axis is not None:
+            partial_o = reduce_fn(partial_o, tp_axis)
+        if "ln1_post" in lp:
+            # the tree's post-norm: the half's output normed once more
+            partial_o = norm(partial_o, lp["ln1_post"])
+        x = x + partial_o
+    if "ln2" not in lp:
+        out = (x, None) if with_aux else x    # the mixer alone
+    else:
+        out = _mlp(x, lp, tp_axis, ep_axis, moe_cfg, with_aux,
+                   reduce_fn=reduce_fn, fanout_fn=fanout_fn, norm=norm,
+                   relu2=relu2)
     return (out, kv) if return_kv else out
 
 
@@ -1872,6 +2107,19 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
         kw["kda"] = {
             "lower_bound": cfg.kda.lower_bound, "eps": cfg.norm_eps,
         }
+    if cfg.mamba is not None:
+        m = cfg.mamba
+        if tp_size > 1 and m.groups % tp_size:
+            raise ValueError(
+                f"the Mamba-2 mixer's groups ({m.groups}) must be divisible "
+                f"by tp ({tp_size}) so every chip owns whole groups of heads"
+            )
+        kw["mamba"] = {
+            "head_dim": m.head_dim, "state": m.state, "chunk": m.chunk,
+            "eps": cfg.norm_eps,
+        }
+    if cfg.ffn == "relu2":
+        kw["relu2"] = True
     if cfg.n_experts:
         # expert parallelism rides cfg.moe_mesh_axis ("dp" welded, or a
         # dedicated "ep"): the sharded makers always run over a mesh
@@ -2147,8 +2395,10 @@ def _reject_unservable(cfg) -> None:
             "its own, an attention gate, per-head QK-norm, post-norms, a "
             "scaled embedding, a sigmoid router, a shared expert, a "
             "held share of the experts, a latent mixer (MLA: its cache is "
-            "the latent and the rope key, not k and v), a KDA mixer (its "
-            "cache is a recurrent state and a convolution's last inputs), "
+            "the latent and the rope key, not k and v), a KDA mixer or a "
+            "Mamba-2 mixer (the cache is a recurrent state and a "
+            "convolution's last inputs), a block of one sub-layer, a latent "
+            "expert bank, relu2, "
             "grouped top-k, "
             "balance losses or block diffusion (generation there denoises "
             "a block of tokens at a time over several passes), and the "
@@ -2159,7 +2409,8 @@ def _reject_unservable(cfg) -> None:
 
 def reject_latent(cfg, where: str) -> None:
     """The paths beside train and forward refuse a latent mixer, a KDA
-    mixer and the block-diffusion objective, by name."""
+    mixer, a Mamba-2 mixer, a block of one sub-layer and the
+    block-diffusion objective, by name."""
     if cfg.latent is not None:
         raise ValueError(
             "the latent mixer (MLA, TransformerConfig.latent) is supported "
@@ -2169,6 +2420,18 @@ def reject_latent(cfg, where: str) -> None:
         raise ValueError(
             "the KDA mixer (linear attention, TransformerConfig.kda) is "
             f"supported on the decoder's train and forward paths only, not "
+            f"{where}"
+        )
+    if cfg.mamba is not None:
+        raise ValueError(
+            "the Mamba-2 mixer (a state-space layer, TransformerConfig.mamba) "
+            "is supported on the decoder's train and forward paths only, not "
+            f"{where}"
+        )
+    if any("none" in (k.mixer, k.ffn) for k in cfg.layers or ()):
+        raise ValueError(
+            "a block of one sub-layer (LayerKind.mixer or .ffn 'none') is "
+            "supported on the decoder's train and forward paths only, not "
             f"{where}"
         )
     if cfg.diffusion is not None:

@@ -1,0 +1,132 @@
+"""Mamba-2's core (state-space duality, SSD, arXiv:2405.21060): the selective
+state-space recurrence with a SCALAR decay a head, in its chunked form.
+
+A head keeps a state ``S`` (P x N, ``S_0 = 0``).  Token ``t`` brings an
+input ``x_t`` (P, the head's width), a step ``dt_t > 0`` (the caller's
+softplus) and, shared by the ``H / G`` heads of its GROUP, an input
+direction ``B_t`` and an output direction ``C_t`` (N, the state's width);
+the head has a rate ``A < 0`` and a skip ``D``, both scalars:
+
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T
+    y_t = S_t C_t + D x_t
+
+:func:`ssd_chunked` computes that for whole sequences in chunks of
+``CHUNK`` tokens, memory linear in T, float32 in and out, as plain
+``jax.numpy`` that XLA lowers and autodiff differentiates (ONE lowering:
+nothing is chosen from the shapes; a kernel would start from the device
+scope's time, ROADMAP M5).  It shares ``ops/kda.py``'s shape, chunks and a
+``lax.scan`` over the chunks' states with every exponent kept inside
+float32, and none of its inverse: without a delta rule a token's write does
+not depend on the state, so a chunk is two masked products and no solve.
+
+THE CHUNKED FORM.  Inside a chunk, with ``l_t`` the sum of ``dt A`` from the
+chunk's first token to ``t`` (``<= 0``, falling) and ``S`` the state the
+chunk starts from:
+
+    y_t = sum_{s<=t} (C_t . B_s) exp(l_t - l_s) dt_s x_s      masked (C B^T) x
+        + exp(l_t) S C_t                                      what came before
+        + D x_t
+    S'  = exp(l_C) S + sum_s exp(l_C - l_s) dt_s x_s B_s^T    a scan over chunks
+
+``C B^T`` is a GROUP's (its heads share it); the decay ``exp(l_t - l_s)`` is
+a head's.  EXPONENTS: every one is a difference ``l_t - l_s`` with ``s <=
+t``, at most 0; the other half of the chunk's square is set to ``-inf``
+BEFORE the exponential (``exp(l_t) / exp(l_s)`` would overflow, and a mask
+after the exponential would hand its backward ``0 x inf``).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+#: tokens a chunk (one step of the scan over the states): the published
+#: ``chunk_size``
+CHUNK = 128
+
+
+def ssd_chunked(x, b, c, dt, a, d, chunk: int = CHUNK):
+    """The recurrence of the module docstring over whole sequences: ``x``
+    (B, H, T, P), ``b`` and ``c`` (B, G, T, N) with head ``i`` in group ``i
+    // (H / G)``, ``dt`` (B, H, T) positive, ``a`` (H,) negative, ``d``
+    (H,); returns ``y`` (B, H, T, P) in float32.  T need be no multiple of
+    the chunk: the tail is padded with tokens of ``dt = 0``, which neither
+    decay the state nor write to it."""
+    B, H, T, P = x.shape
+    G, N = b.shape[1], b.shape[-1]
+    if H % G:
+        raise ValueError(f"{H} heads are no whole groups of {G}")
+    f32 = jnp.float32
+    x, b, c, dt, a, d = (v.astype(f32) for v in (x, b, c, dt, a, d))
+    if pad := -T % chunk:
+        x, b, c, dt = (
+            jnp.pad(v, [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 3))
+            for v in (x, b, c, dt)
+        )
+    n, per = (T + pad) // chunk, H // G
+    # (B, G, heads of a group, chunks, chunk, .): a group's B and C meet its
+    # heads by broadcasting, never by a copy a head
+    x = x.reshape(B, G, per, n, chunk, P)
+    dt = dt.reshape(B, G, per, n, chunk)
+    b = b.reshape(B, G, n, chunk, N)
+    c = c.reshape(B, G, n, chunk, N)
+    a = a.reshape(G, per)
+
+    l = jnp.cumsum(dt * a[None, :, :, None, None], axis=-1)   # l_t
+    end = l[..., -1:]                                         # l_C
+    t, s = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    decay = jnp.exp(jnp.where(
+        s <= t, l[..., :, None] - l[..., None, :], -jnp.inf
+    ))                                                 # (B, G, per, n, C, C)
+    cb = jnp.einsum("bgntk,bgnsk->bgnts", c, b)        # a group's C B^T
+    xdt = x * dt[..., None]
+    within = jnp.einsum(
+        "bgpnts,bgpnsd->bgpntd", cb[:, :, None] * decay, xdt
+    )
+    # what a chunk adds to the state, decayed to the chunk's end
+    wrote = jnp.einsum(
+        "bgpnsd,bgnsk->bgpndk", xdt * jnp.exp(end - l)[..., None], b
+    )                                                  # (B, G, per, n, P, N)
+    keep = jnp.exp(end[..., 0])                        # (B, G, per, n)
+
+    def a_chunk(state, xs):
+        wrote, keep = xs
+        return keep[..., None, None] * state + wrote, state
+
+    state = jnp.zeros((B, G, per, P, N), f32)
+    # inside a shard_map: the scan's carry varying over the axes its inputs
+    # vary over, from the first step on
+    if varying := tuple(jax.typeof(wrote).vma):
+        state = lax.pcast(state, varying, to="varying")
+    _, starts = lax.scan(
+        a_chunk, state, (jnp.moveaxis(wrote, 3, 0), jnp.moveaxis(keep, 3, 0))
+    )                                                  # (n, B, G, per, P, N)
+    before = jnp.einsum(
+        "bgntk,nbgpdk->bgpntd", c, starts
+    ) * jnp.exp(l)[..., None]
+    y = within + before + x * d.reshape(G, per)[None, :, :, None, None, None]
+    return y.reshape(B, H, n * chunk, P)[:, :, :T]
+
+
+def conv_silu(x, taps, bias):
+    """``SiLU(conv(x) + bias)``: ``x`` (B, T, C) in the matmuls' type, the
+    causal depthwise convolution by ``taps`` (n, C) (zero left padding, the
+    last tap the current token's), a ``bias`` a channel; float32 (B, T,
+    C)."""
+    T, n = x.shape[1], taps.shape[0]
+    x = jnp.pad(x.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0)))
+    y = sum(x[:, i:i + T] * taps[i].astype(jnp.float32) for i in range(n))
+    return jax.nn.silu(y + bias.astype(jnp.float32))
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float, dtype):
+    """What ``W_out`` takes from the core's ``y`` (B, T, C) and the gate's
+    projection ``z`` (B, T, C): ``y SiLU(z)`` (the gate BEFORE the norm),
+    RMS-normed over each of ``groups`` runs of ``C / groups`` columns,
+    times the learned ``scale`` (C,); float32 inside, ``dtype`` out."""
+    B, T, C = y.shape
+    y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    y = y.reshape(B, T, groups, C // groups)
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    return (y.reshape(B, T, C) * scale.astype(jnp.float32)).astype(dtype)

@@ -140,6 +140,16 @@ drops ``op_name``, so a reader joins the two by instruction name
                          XLA's fusions.  Float32 in either lowering,
                          inside the kernels too (only the projections
                          and their cotangents have the matmuls' type)
+``accl.attn::ssd``       ``_mamba2_partial`` (a Mamba-2 mixer, ``LayerKind.
+                         mixer`` ``"mamba2"``): the core, ``ops/ssd.py``
+                         ``ssd_chunked`` from x, B, C and dt to y, forward
+                         and backward, XLA's lowering: a chunk's masked
+                         ``(C B^T) x`` and a scan over the chunks' states,
+                         which is a loop of the compiled step (the body's
+                         instructions are device events of their own)
+``accl.attn::mamba_proj`` the same: everything round the core, the five
+                         projections, the convolutions with their bias,
+                         SiLU, softplus, the gate, the grouped norm, ``wo``
 ``accl.attn::blockdiff`` ``_attn_partial`` under ``TransformerConfig.
                          diffusion``: the attention call on ``[noisy ;
                          clean]`` under the block-diffusion layout (the
@@ -172,8 +182,13 @@ drops ``op_name``, so a reader joins the two by instruction name
                          experts the weighted ``place_rows`` kernel or the
                          k gathers, by the same rule
 ``accl.moe::shared``     the same, where the layer's parameters hold a
-                         ``shared`` expert: the dense gated-SiLU FFN every
-                         token passes through, added to the routed result
+                         ``shared`` expert: the dense FFN (gated SiLU, or
+                         ``relu ** 2``) every token passes through, added
+                         to the routed result
+``accl.moe::latent``     the same, where they hold ``w_down`` and ``w_up``
+                         (a latent expert bank): the token into the
+                         experts' width before the dispatch, the tokens'
+                         weighted sums out of it after the combine
 ``accl.embed::grad``     ``models/transformer.py`` ``_gathered_rows_bwd``,
                          backward only: the embedding lookup's cotangent
                          placed on the table, by one matmul against the

@@ -257,6 +257,33 @@ def test_benchmark_rehearsal_is_correct_and_prints_counts_only(cell, trace):
         }
 
 
+def test_nemotron3_controls_each_end_not_correct():
+    """``perfbench/controls_nemotron3.py``, rehearsed: the cell's own
+    ``judge`` at its committed limits ends correct on the sound reference
+    and not correct on every planted fault; a state the steps left
+    unchanged is past the update's two limits alone."""
+    from perfbench import controls_nemotron3 as controls
+
+    base = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    proc = subprocess.run(
+        ["nice", "-n", "19", sys.executable, "-m",
+         "perfbench.controls_nemotron3", "--seed", "5", "--rehearse"],
+        cwd=manifest.CHECKOUT, env=base, capture_output=True, text=True,
+        timeout=900,
+    )
+    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-2000:])
+    *lines, last = map(json.loads, proc.stdout.strip().splitlines())
+    assert last == {"controls": "ok", "wrong": []}
+    assert [l["control"] for l in lines] == ["sound", *controls.CONTROLS]
+    by_name = {l["control"]: l for l in lines}
+    assert all(l["correct"] == (name == "sound") for name, l in by_name.items())
+    unchanged = by_name["unchanged_state"]["check"]
+    assert unchanged["update_timed_worst"] == 1.0
+    assert abs(unchanged["update_probe_worst"] - 1.0) < 1e-6
+    assert len(by_name["unchanged_state"]["problems"]) == 1
+    assert "update" in by_name["unchanged_state"]["problems"][0]
+
+
 def test_benchmark_refuses_to_run_off_the_tpu():
     proc = _perfbench("train_t1024_b8", "--seed", "0", "--seconds", "1",
                       "--trace", "0", JAX_PLATFORMS="cpu")

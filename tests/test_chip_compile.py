@@ -251,7 +251,7 @@ def _step_text(cell_name, n_layers, device, monkeypatch, layers=None):
     return _step(cell_name, n_layers, device, monkeypatch, layers).as_text()
 
 
-def _step(cell_name, n_layers, device, monkeypatch, layers=None):
+def _step(cell_name, n_layers, device, monkeypatch, layers=None, lr=None):
     """A train cell's step (``make_sharded_train_step`` on a world of the
     one described chip, the cell's widths, batch and length; its depth cut
     to its first ``n_layers``, which the table's gradient does not see, or
@@ -291,7 +291,9 @@ def _step(cell_name, n_layers, device, monkeypatch, layers=None):
         (int(cell["traffic"]["batch"]), int(cell["traffic"]["seq"])),
         jnp.int32, sharding=NamedSharding(mesh, P()),
     )
-    step, _ = make_sharded_train_step(cfg, mesh, lr=float(cell["traffic"]["lr"]))
+    if lr is None:
+        lr = float(cell["traffic"]["lr"])
+    step, _ = make_sharded_train_step(cfg, mesh, lr=lr)
     return step.lower(params, tok, tok).compile()
 
 
@@ -428,3 +430,78 @@ def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
         for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
     )
     assert largest == 16384 * 19648
+
+
+def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
+    v5e, monkeypatch
+):
+    """One Mamba-2 block, one LatentMoE block and the attention block of the
+    cell's eleven, 1 x 8,192 tokens, under ``remat`` as the cell runs: the
+    SSD core is XLA's under ``accl.attn::ssd`` with its scan over the 64
+    chunks a loop of the step (the body's instructions are found by the
+    driver's ``scoped_instructions``, not by ``scopes_of``), the mixer round
+    it under ``accl.attn::mamba_proj``, ``W_down`` and ``W_up`` under
+    ``accl.moe::latent``; the held rows are 1,024 wide and placed by the
+    kernel (``_gathers_win`` at 45,056 rows, 180,224 entries: the gathers
+    would take 1.2 ms); attention is the flash kernels on 2 KV heads; no
+    array is a square of the length, and the largest are the held experts'
+    float32 weight gradients (64 x 1,024 x 2,688: more than the float32
+    logits' 8,192 x 16,384, which come next)."""
+    from perfbench import scope_ops
+    from perfbench.drivers import train_steps_ling3
+
+    compiled = _step(
+        "train_nemotron3_t8192_b1", 3, v5e, monkeypatch, layers=(0, 1, 9)
+    )
+    text = compiled.as_text()
+    entry = scope_ops.scopes_of(text)
+    every = train_steps_ling3.scoped_instructions(text)
+    for scope in ("accl.attn::ssd", "accl.attn::mamba_proj",
+                  "accl.moe::latent", "accl.moe::route", "accl.moe::dispatch",
+                  "accl.moe::experts", "accl.moe::combine",
+                  "accl.moe::shared", "accl.attn::core"):
+        assert entry.get(scope), scope
+    core = every["accl.attn::ssd"]
+    assert any(n.startswith("while") for n in entry["accl.attn::ssd"])
+    assert len(core) > len(entry["accl.attn::ssd"])     # the loops' bodies
+    assert any("flash_fwd" in n for n in entry["accl.attn::core"])
+    assert any("flash_bwd" in n for n in entry["accl.attn::core"])
+    for kernel in ("gmm_fwd", "gmm_dlhs", "gmm_drhs"):
+        assert any(kernel in n for n in entry["accl.moe::experts"]), kernel
+    # forward, the replayed forward and the dispatch gather's cotangent
+    placed = re.findall(r"%(place_rows\S*) = bf16\[8192,1024\]", text)
+    assert len(placed) == 3
+    assert re.search(r"bf16\[45056,1024\]", text)
+    assert not re.search(r"\[45056,4096\]|\[8192,8192,\d+\]|\[8192,8192\]\S* dot", text)
+    sizes = sorted({
+        int(np.prod([int(n) for n in dims.split(",")]))
+        for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
+    })
+    # one head's scores over the whole length would be 2^26 elements a head
+    # and 2^31 for the 32: nothing here is; the decay squares of a Mamba-2
+    # block are 8 x 16 x 64 x 128 x 128 = 2^27, linear in T
+    assert sizes[-1] == 64 * 1024 * 2688 and sizes[-2] == 8192 * 16384
+    # my compile for the described chip, PR 45: 4,698,582,016 bytes
+    assert compiled.memory_analysis().temp_size_in_bytes <= 5_000_000_000
+
+    # the step whose update the cell's check reads the gradient from (the
+    # driver's UPDATE_PROBE_RATE) is this step with ONE number changed: the
+    # rate, a constant of the leaf's type at each leaf's update
+    from perfbench.drivers import train_steps_nemotron3
+
+    probe = _step(
+        "train_nemotron3_t8192_b1", 3, v5e, monkeypatch, layers=(0, 1, 9),
+        lr=train_steps_nemotron3.UPDATE_PROBE_RATE,
+    ).as_text()
+    rate = re.compile(
+        r"%constant\.\d+|constant\((?:0\.0009995|0\.001|4096)\)|, metadata=\{[^}]*\}"
+        # a kernel's body carries the line numbers of the call that built it
+        r'|"body":"[^"]*"'
+    )
+    instructions = lambda text: [
+        rate.sub("", line) for line in text.splitlines()
+        if re.match(r"\s*(?:ROOT )?%", line)
+    ]
+    assert "constant(0.0009995)" in text and "constant(4096)" in probe
+    assert len(instructions(text)) > 5000
+    assert instructions(text) == instructions(probe)

@@ -41,6 +41,8 @@ WHERE = {
     "accl.attn::mla": "deepseek_v2",
     "accl.attn::kda": "ling3",
     "accl.attn::kda_proj": "ling3",
+    "accl.attn::ssd": "nemotron3",
+    "accl.attn::mamba_proj": "nemotron3",
     "accl.attn::blockdiff": "sdar",
     "accl.diffusion::noise": "sdar",
     "accl.loss::diffusion": "sdar",
@@ -49,6 +51,7 @@ WHERE = {
     "accl.moe::experts": "olmoe",
     "accl.moe::combine": "olmoe",
     "accl.moe::shared": "deepseek_v2",     # its two shared experts
+    "accl.moe::latent": "nemotron3",
     "accl.embed::grad": "olmoe",           # any step: the lookup's backward
 }
 
