@@ -1771,28 +1771,28 @@ def _mamba2_partial(h, lp, mamba):
     matmuls take the activations' type; the convolution, SiLU, softplus,
     the core, the gate and the grouped norm are float32.  Everything but
     the core runs under the device scope ``accl.attn::mamba_proj``, the
-    core (from x, B, C and dt to y: ``ops.ssd.ssd_chunked``) under
-    ``accl.attn::ssd``."""
-    from ..ops.ssd import conv_silu, gated_group_norm, ssd_chunked
+    core (from x, B, C and dt to y, all TOKEN-MAJOR, as the convolutions
+    leave them and the norm takes them: ``ops.ssd.ssd_mixer``, which picks
+    from the shapes the Mosaic kernels ``ssd_fwd`` / ``ssd_bwd`` that keep
+    a chunk's decay squares and the running state in VMEM, or the XLA form
+    round its head-major transposes) under ``accl.attn::ssd``."""
+    from ..ops.ssd import conv_silu, gated_group_norm, ssd_mixer
 
-    B, T, _ = h.shape
-    P, N = mamba["head_dim"], mamba["state"]
-    H, G = lp["wdt"].shape[1], lp["wb"].shape[1] // N
+    N = mamba["state"]
+    G = lp["wb"].shape[1] // N
     f32 = jnp.float32
-    heads = lambda t, n: t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
     with device_scope("accl.attn::mamba_proj"):
         z = h @ lp["wz"]
-        x = heads(conv_silu(h @ lp["wx"], lp["conv_x"], lp["bias_x"]), H)
-        b = heads(conv_silu(h @ lp["wb"], lp["conv_b"], lp["bias_b"]), G)
-        c = heads(conv_silu(h @ lp["wc"], lp["conv_c"], lp["bias_c"]), G)
+        x = conv_silu(h @ lp["wx"], lp["conv_x"], lp["bias_x"])
+        b = conv_silu(h @ lp["wb"], lp["conv_b"], lp["bias_b"])
+        c = conv_silu(h @ lp["wc"], lp["conv_c"], lp["bias_c"])
         dt = jax.nn.softplus(
             (h @ lp["wdt"]).astype(f32) + lp["dt_bias"].astype(f32)
-        ).transpose(0, 2, 1)                              # (B, H, T)
+        )                                                 # (B, T, H)
         a = -jnp.exp(lp["a_log"].astype(f32))
     with device_scope("accl.attn::ssd"):
-        y = ssd_chunked(x, b, c, dt, a, lp["d_skip"], mamba["chunk"])
+        y = ssd_mixer(x, b, c, dt, a, lp["d_skip"], G, mamba["chunk"])
     with device_scope("accl.attn::mamba_proj"):
-        y = y.transpose(0, 2, 1, 3).reshape(B, T, H * P)
         y = gated_group_norm(y, z, lp["y_norm"], G, mamba["eps"], h.dtype)
         return y @ lp["wo"]
 

@@ -10,14 +10,20 @@ the head has a rate ``A < 0`` and a skip ``D``, both scalars:
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T
     y_t = S_t C_t + D x_t
 
-:func:`ssd_chunked` computes that for whole sequences in chunks of
-``CHUNK`` tokens, memory linear in T, float32 in and out, as plain
-``jax.numpy`` that XLA lowers and autodiff differentiates (ONE lowering:
-nothing is chosen from the shapes; a kernel would start from the device
-scope's time, ROADMAP M5).  It shares ``ops/kda.py``'s shape, chunks and a
-``lax.scan`` over the chunks' states with every exponent kept inside
-float32, and none of its inverse: without a delta rule a token's write does
-not depend on the state, so a chunk is two masked products and no solve.
+:func:`ssd_mixer` computes that for whole sequences in chunks of ``CHUNK``
+tokens, memory linear in T, float32 in and out, by ONE OF TWO lowerings
+picked from the shapes (``ops.pallas.ssd.takes``: the published chunk, a
+state of whole lanes, a head that divides a lane row, a group's heads
+filling whole lane rows): the Mosaic kernels ``ssd_fwd`` / ``ssd_bwd``
+(``ops/pallas/ssd.py``: a chunk's decay squares and the running state stay
+in VMEM, the scan over the chunks is the grid, the backward hand-derived;
+ROADMAP S9 (b), M5), or, at any other shape (the tests' tiny widths, the
+benchmark's ``--rehearse``), :func:`ssd_chunked`, plain ``jax.numpy`` that
+XLA lowers and autodiff differentiates, which is also the kernels' oracle.
+The XLA form shares ``ops/kda.py``'s shape, chunks and a ``lax.scan`` over
+the chunks' states with every exponent kept inside float32, and none of its
+inverse: without a delta rule a token's write does not depend on the state,
+so a chunk is two masked products and no solve.
 
 THE CHUNKED FORM.  Inside a chunk, with ``l_t`` the sum of ``dt A`` from the
 chunk's first token to ``t`` (``<= 0``, falling) and ``S`` the state the
@@ -41,13 +47,42 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .pallas import ssd as _kernels
+
 #: tokens a chunk (one step of the scan over the states): the published
 #: ``chunk_size``
 CHUNK = 128
 
 
+def ssd_mixer(x, b, c, dt, a, d, groups: int, chunk: int = CHUNK):
+    """The recurrence of the module docstring over whole sequences, TOKEN-MAJOR
+    as the mixer's chains leave their operands: ``x`` (B, T, H P), ``b`` and
+    ``c`` (B, T, G N) with head ``i`` in group ``i // (H / G)``, ``dt`` (B,
+    T, H) positive, ``a`` (H,) negative, ``d`` (H,); returns ``y`` (B, T, H
+    P) in float32.  The shapes pick the lowering (module docstring): the
+    kernels read and write these rows where they lie, the XLA form takes
+    them head-major and gives them back."""
+    B, T, H = dt.shape
+    f32 = jnp.float32
+    if _kernels.takes(x.shape, b.shape, H, groups, chunk):
+        pad = ((0, 0), (0, -T % chunk), (0, 0))
+        x, b, c, dt = (jnp.pad(v.astype(f32), pad) for v in (x, b, c, dt))
+        dt = dt.transpose(0, 2, 1)                        # (B, H, T + pad)
+        # l_t inside each chunk: an exact float32 sum, as the XLA form's
+        l = jnp.cumsum(
+            (dt * a.astype(f32)[None, :, None]).reshape(B, H, -1, chunk), axis=-1
+        ).reshape(dt.shape)
+        return _kernels.ssd(x, b, c, dt, l, d, groups)[:, :T]
+    heads = lambda t, n: t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
+    y = ssd_chunked(
+        heads(x, H), heads(b, groups), heads(c, groups), dt.transpose(0, 2, 1),
+        a, d, chunk,
+    )
+    return y.transpose(0, 2, 1, 3).reshape(B, T, -1)
+
+
 def ssd_chunked(x, b, c, dt, a, d, chunk: int = CHUNK):
-    """The recurrence of the module docstring over whole sequences: ``x``
+    """The XLA form, at any shape and head-major.  The recurrence of the module docstring over whole sequences: ``x``
     (B, H, T, P), ``b`` and ``c`` (B, G, T, N) with head ``i`` in group ``i
     // (H / G)``, ``dt`` (B, H, T) positive, ``a`` (H,) negative, ``d``
     (H,); returns ``y`` (B, H, T, P) in float32.  T need be no multiple of

@@ -142,11 +142,20 @@ drops ``op_name``, so a reader joins the two by instruction name
                          and their cotangents have the matmuls' type)
 ``accl.attn::ssd``       ``_mamba2_partial`` (a Mamba-2 mixer, ``LayerKind.
                          mixer`` ``"mamba2"``): the core, ``ops/ssd.py``
-                         ``ssd_chunked`` from x, B, C and dt to y, forward
-                         and backward, XLA's lowering: a chunk's masked
-                         ``(C B^T) x`` and a scan over the chunks' states,
-                         which is a loop of the compiled step (the body's
+                         ``ssd_mixer`` from token-major x, B, C and dt to
+                         y, forward and backward: where a group's heads
+                         fill whole lane rows and the state is whole lanes
+                         the kernels ``ssd_fwd`` (the forward, and under
+                         ``remat`` the replayed one) and ``ssd_bwd`` of
+                         ``ops/pallas/ssd.py``, the scan over the chunks
+                         fused into them, beside XLA's ``cumsum`` of the
+                         log-decay inside a chunk and the heads' scalars'
+                         small transposes; at any other shape the XLA
+                         form (``ssd_chunked``: a chunk's masked ``(C B^T)
+                         x`` and a scan over the chunks' states, which is
+                         a loop of the compiled step whose body's
                          instructions are device events of their own)
+                         round its head-major transposes
 ``accl.attn::mamba_proj`` the same: everything round the core, the five
                          projections, the convolutions with their bias,
                          SiLU, softplus, the gate, the grouped norm, ``wo``

@@ -205,6 +205,30 @@ def test_kda_kernels_compile_at_ling3_shapes(one_chip):
     assert "kda_fwd" in text and "kda_bwd" in text
 
 
+def test_ssd_kernels_compile_at_nemotron3_shapes(one_chip):
+    """``train_nemotron3_t8192_b1``'s Mamba-2 core alone, forward and
+    gradient: 8,192 tokens, 128 heads of 64 in 8 groups, state 128,
+    token-major float32 rows: Mosaic takes every tile (a group's 1,024
+    columns a block, the heads' scalars as columns of 16 lanes), the lane
+    broadcasts of a head's column and the VMEM each kernel asks for."""
+    from accl_tpu.ops.pallas import ssd
+
+    B, T, H, width, G, N = 1, 8192, 128, 64, 8, 128
+    struct = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    args = (
+        struct(B, T, H * width), struct(B, T, G * N), struct(B, T, G * N),
+        struct(B, H, T), struct(B, H, T), struct(H),
+    )
+    assert ssd.takes(args[0].shape, args[1].shape, H, G)
+    core = lambda *a: ssd.ssd(*a, G, interpret=False)
+    text = jax.jit(core).lower(*args).compile().as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" not in text
+    text = jax.jit(jax.grad(
+        lambda *a: core(*a).sum(), argnums=tuple(range(6))
+    )).lower(*args).compile().as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+
+
 #: a chain of ``train_ling3_t8192_b2``'s KDA mixer: (the kernels' names,
 #: the call, its operands' shapes and types)
 def _mixer_chains():
@@ -261,7 +285,9 @@ def _step(cell_name, n_layers, device, monkeypatch, layers=None, lr=None):
     from accl_tpu.models.transformer import normalize_spec, param_specs
     from perfbench import manifest
 
-    for module in ("attention", "grouped_matmul", "place_rows", "kda", "kda_mixer"):
+    for module in (
+        "attention", "grouped_matmul", "place_rows", "kda", "kda_mixer", "ssd"
+    ):
         monkeypatch.setattr(
             importlib.import_module("accl_tpu.ops.pallas." + module),
             "default_interpret", lambda interpret=None: bool(interpret),
@@ -437,16 +463,21 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
 ):
     """One Mamba-2 block, one LatentMoE block and the attention block of the
     cell's eleven, 1 x 8,192 tokens, under ``remat`` as the cell runs: the
-    SSD core is XLA's under ``accl.attn::ssd`` with its scan over the 64
-    chunks a loop of the step (the body's instructions are found by the
-    driver's ``scoped_instructions``, not by ``scopes_of``), the mixer round
-    it under ``accl.attn::mamba_proj``, ``W_down`` and ``W_up`` under
+    SSD core is the kernels ``ssd_fwd`` / ``ssd_bwd`` under
+    ``accl.attn::ssd`` (a Mamba-2 block: the forward, the replayed forward
+    and the backward), the scan over the 64 chunks fused into them (no
+    ``while`` under the scope and nothing of it in a loop's body, which
+    ``scopes_of`` does not walk and the driver's ``scoped_instructions``
+    does), no decay square (8 x 16 x 64 x 128 x 128 = 2^27 elements a block
+    under the XLA form) anywhere in the text; the mixer round it under
+    ``accl.attn::mamba_proj``, ``W_down`` and ``W_up`` under
     ``accl.moe::latent``; the held rows are 1,024 wide and placed by the
     kernel (``_gathers_win`` at 45,056 rows, 180,224 entries: the gathers
     would take 1.2 ms); attention is the flash kernels on 2 KV heads; no
     array is a square of the length, and the largest are the held experts'
     float32 weight gradients (64 x 1,024 x 2,688: more than the float32
-    logits' 8,192 x 16,384, which come next)."""
+    logits' 8,192 x 16,384, which come next); the step's scratch no more
+    than the parent's."""
     from perfbench import scope_ops
     from perfbench.drivers import train_steps_ling3
 
@@ -461,9 +492,11 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
                   "accl.moe::experts", "accl.moe::combine",
                   "accl.moe::shared", "accl.attn::core"):
         assert entry.get(scope), scope
-    core = every["accl.attn::ssd"]
-    assert any(n.startswith("while") for n in entry["accl.attn::ssd"])
-    assert len(core) > len(entry["accl.attn::ssd"])     # the loops' bodies
+    core, mamba_blocks = entry["accl.attn::ssd"], 1
+    assert sum(n.startswith("ssd_fwd") for n in core) == 2 * mamba_blocks
+    assert sum(n.startswith("ssd_bwd") for n in core) == mamba_blocks
+    assert not any(n.startswith("while") for n in core)
+    assert set(core) == set(every["accl.attn::ssd"])     # no loop's body
     assert any("flash_fwd" in n for n in entry["accl.attn::core"])
     assert any("flash_bwd" in n for n in entry["accl.attn::core"])
     for kernel in ("gmm_fwd", "gmm_dlhs", "gmm_drhs"):
@@ -478,11 +511,13 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
         for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
     })
     # one head's scores over the whole length would be 2^26 elements a head
-    # and 2^31 for the 32: nothing here is; the decay squares of a Mamba-2
-    # block are 8 x 16 x 64 x 128 x 128 = 2^27, linear in T
+    # and 2^31 for the 32: nothing here is, and nothing is the XLA form's
+    # decay squares (2^27 a block) or chunk products (2^26, five dimensions)
     assert sizes[-1] == 64 * 1024 * 2688 and sizes[-2] == 8192 * 16384
-    # my compile for the described chip, PR 45: 4,698,582,016 bytes
-    assert compiled.memory_analysis().temp_size_in_bytes <= 5_000_000_000
+    assert not re.search(r"f32\[1,8,16,64,128,(?:64|128)\]", text)
+    # the parent's compile for the described chip (the XLA form of the core),
+    # the same cut: 4,698,582,016 bytes
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4_698_582_016
 
     # the step whose update the cell's check reads the gradient from (the
     # driver's UPDATE_PROBE_RATE) is this step with ONE number changed: the
