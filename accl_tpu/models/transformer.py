@@ -1770,12 +1770,18 @@ def _mamba2_partial(h, lp, mamba):
     a head's width, the state's, the chunk and the norm's ``eps``.  The
     matmuls take the activations' type; the convolution, SiLU, softplus,
     the core, the gate and the grouped norm are float32.  Everything but
-    the core runs under the device scope ``accl.attn::mamba_proj``, the
-    core (from x, B, C and dt to y, all TOKEN-MAJOR, as the convolutions
-    leave them and the norm takes them: ``ops.ssd.ssd_mixer``, which picks
-    from the shapes the Mosaic kernels ``ssd_fwd`` / ``ssd_bwd`` that keep
-    a chunk's decay squares and the running state in VMEM, or the XLA form
-    round its head-major transposes) under ``accl.attn::ssd``."""
+    the core runs under the device scope ``accl.attn::mamba_proj``: the
+    matmuls, ``dt``'s softplus and the two float32 chains, ``ops.ssd``'s
+    ``conv_silu`` (x, B and C) and ``gated_group_norm``, each of which
+    picks from the shapes the Mosaic kernels of ``ops/pallas/
+    mamba_mixer.py`` (``mamba_in_fwd`` / ``mamba_in_bwd``, ``mamba_out_fwd``
+    / ``mamba_out_bwd``: one pass over HBM a chain, forward and backward)
+    or XLA's fusions.  The core (from x, B, C and dt to y, all TOKEN-MAJOR,
+    as the convolutions leave them and the norm takes them:
+    ``ops.ssd.ssd_mixer``, which picks from the shapes the Mosaic kernels
+    ``ssd_fwd`` / ``ssd_bwd`` that keep a chunk's decay squares and the
+    running state in VMEM, or the XLA form round its head-major
+    transposes) runs under ``accl.attn::ssd``."""
     from ..ops.ssd import conv_silu, gated_group_norm, ssd_mixer
 
     N = mamba["state"]

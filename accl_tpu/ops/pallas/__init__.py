@@ -25,6 +25,14 @@ TPU kernels.
   convolutions, SiLU and L2 norms of q, k, v; the decay's gate; the output
   norm and gate) as one pass over HBM each, forward and backward, the move
   to head-major and back a block's index map.
+* ``ssd`` — the Mamba-2 core (the chunked selective state-space recurrence
+  of ``ops/ssd.py``) as a forward and a backward kernel that keep a chunk's
+  decay squares and the running state in VMEM, token-major in and out.
+* ``mamba_mixer`` — the Mamba-2 mixer's two float32 chains round that core
+  (the convolution, bias and SiLU of x, B and C: ``mamba_in_fwd`` /
+  ``mamba_in_bwd``; the gate and the grouped norm: ``mamba_out_fwd`` /
+  ``mamba_out_bwd``) as one pass over HBM each, token-major as the core
+  takes and gives its rows.
 
 On non-TPU backends every kernel runs under the Pallas TPU interpreter so
 the CI tier exercises the identical kernel code (see

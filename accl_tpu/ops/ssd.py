@@ -39,6 +39,15 @@ a head's.  EXPONENTS: every one is a difference ``l_t - l_s`` with ``s <=
 t``, at most 0; the other half of the chunk's square is set to ``-inf``
 BEFORE the exponential (``exp(l_t) / exp(l_s)`` would overflow, and a mask
 after the exponential would hand its backward ``0 x inf``).
+
+THE MIXER'S CHAINS round the core live here too: :func:`conv_silu` (x, B and
+C from their projections) and :func:`gated_group_norm` (what ``W_out`` takes
+from ``y`` and the gate), float32 and token-major, each by one of two
+lowerings the shapes pick as well (``ops.pallas.mamba_mixer.takes``: columns
+in whole blocks of 1,024, a group of whole lanes, taps that a halo block
+holds): the Mosaic kernels ``mamba_in_fwd`` / ``mamba_in_bwd`` and ``mamba_out_fwd`` /
+``mamba_out_bwd`` (one pass over HBM a chain, forward and backward), or the
+XLA forms ``_xla_conv_silu`` / ``_xla_gated_group_norm``, their oracle.
 """
 
 from __future__ import annotations
@@ -47,6 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .pallas import mamba_mixer as _chains
 from .pallas import ssd as _kernels
 
 #: tokens a chunk (one step of the scan over the states): the published
@@ -144,11 +154,21 @@ def ssd_chunked(x, b, c, dt, a, d, chunk: int = CHUNK):
     return y.reshape(B, H, n * chunk, P)[:, :, :T]
 
 
+# -- the mixer's float32 chains round the core (module docstring) ---------------
+
+
 def conv_silu(x, taps, bias):
     """``SiLU(conv(x) + bias)``: ``x`` (B, T, C) in the matmuls' type, the
     causal depthwise convolution by ``taps`` (n, C) (zero left padding, the
     last tap the current token's), a ``bias`` a channel; float32 (B, T,
-    C)."""
+    C).  Whole blocks of 1,024 columns run the kernels ``mamba_in_fwd`` /
+    ``mamba_in_bwd``, any other width the XLA form."""
+    if _chains.takes(x.shape[-1], taps=taps.shape[0]):
+        return _chains.conv_silu(x, taps, bias)
+    return _xla_conv_silu(x, taps, bias)
+
+
+def _xla_conv_silu(x, taps, bias):
     T, n = x.shape[1], taps.shape[0]
     x = jnp.pad(x.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0)))
     y = sum(x[:, i:i + T] * taps[i].astype(jnp.float32) for i in range(n))
@@ -159,7 +179,16 @@ def gated_group_norm(y, z, scale, groups: int, eps: float, dtype):
     """What ``W_out`` takes from the core's ``y`` (B, T, C) and the gate's
     projection ``z`` (B, T, C): ``y SiLU(z)`` (the gate BEFORE the norm),
     RMS-normed over each of ``groups`` runs of ``C / groups`` columns,
-    times the learned ``scale`` (C,); float32 inside, ``dtype`` out."""
+    times the learned ``scale`` (C,); float32 inside, ``dtype`` out.
+    Groups of whole lanes in whole blocks of 1,024 columns run the kernels
+    ``mamba_out_fwd`` / ``mamba_out_bwd`` on the rows as they lie, any other
+    width the XLA form over its ``(B, T, groups, C / groups)`` view."""
+    if _chains.takes(y.shape[-1], groups):
+        return _chains.gated_group_norm(y, z, scale, groups, eps, dtype)
+    return _xla_gated_group_norm(y, z, scale, groups, eps, dtype)
+
+
+def _xla_gated_group_norm(y, z, scale, groups, eps, dtype):
     B, T, C = y.shape
     y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     y = y.reshape(B, T, groups, C // groups)

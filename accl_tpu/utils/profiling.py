@@ -159,6 +159,12 @@ drops ``op_name``, so a reader joins the two by instruction name
 ``accl.attn::mamba_proj`` the same: everything round the core, the five
                          projections, the convolutions with their bias,
                          SiLU, softplus, the gate, the grouped norm, ``wo``
+                         (the two float32 chains, ``ops/ssd.py``
+                         ``conv_silu`` and ``gated_group_norm``, are the
+                         kernels ``mamba_in_fwd`` / ``mamba_in_bwd`` and
+                         ``mamba_out_fwd`` / ``mamba_out_bwd`` of
+                         ``ops/pallas/mamba_mixer.py`` at whole blocks of
+                         1,024 columns, XLA's fusions at any other width)
 ``accl.attn::blockdiff`` ``_attn_partial`` under ``TransformerConfig.
                          diffusion``: the attention call on ``[noisy ;
                          clean]`` under the block-diffusion layout (the

@@ -260,7 +260,12 @@ def test_kda_mixer_kernels_compile_at_ling3_shapes(one_chip, chain):
     without, the decay, the output norm and gate): Mosaic takes every
     tile, the unaligned row windows of the convolution and the VMEM each
     kernel asks for."""
-    name, call, operands = _mixer_chains()[chain]
+    _chain_compiles(*_mixer_chains()[chain], one_chip)
+
+
+def _chain_compiles(name, call, operands, one_chip):
+    """A mixer's chain alone: its forward kernel in the forward's text, its
+    backward kernel in the gradient's."""
     args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in operands]
     text = jax.jit(call).lower(*args).compile().as_text()
     assert name + "_fwd" in text and name + "_bwd" not in text
@@ -269,6 +274,41 @@ def test_kda_mixer_kernels_compile_at_ling3_shapes(one_chip, chain):
         argnums=tuple(range(len(args))),
     )).lower(*args).compile().as_text()
     assert name + "_bwd" in text
+
+
+#: a chain of ``train_nemotron3_t8192_b1``'s Mamba-2 mixer: (the kernels'
+#: names, the call, its operands' shapes and types)
+def _mamba_chains():
+    from accl_tpu.ops.pallas import mamba_mixer as mm
+
+    B, T, wide, state, G = 1, 8192, 8192, 1024, 8
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    conv = lambda *a: mm.conv_silu(*a, interpret=False)
+    operands = lambda C: [((B, T, C), bf16), ((4, C), bf16), ((C,), bf16)]
+    return {
+        "x": ("mamba_in", conv, operands(wide)),
+        "b_or_c": ("mamba_in", conv, operands(state)),
+        "out": (
+            "mamba_out",
+            lambda *a: mm.gated_group_norm(*a, G, 1e-5, bf16, interpret=False),
+            [((B, T, wide), f32), ((B, T, wide), bf16), ((wide,), bf16)],
+        ),
+    }
+
+
+@pytest.mark.parametrize("chain", ["x", "b_or_c", "out"])
+def test_mamba_mixer_kernels_compile_at_nemotron3_shapes(one_chip, chain):
+    """``train_nemotron3_t8192_b1``'s two float32 chains round the SSD core,
+    each alone, forward and gradient: the convolution, bias and SiLU of a
+    bfloat16 projection of 8,192 rows at x's 8,192 columns and at B's and
+    C's 1,024, 4 taps; the gate and the norm over 8 groups of 1,024 columns
+    of the core's float32 ``y``: Mosaic takes every tile, the unaligned row
+    windows of the convolution, a group's sweeps over its eight lane tiles
+    and the VMEM each kernel asks for."""
+    from accl_tpu.ops.pallas import mamba_mixer as mm
+
+    assert mm.takes(8192, 8, 4) and mm.takes(1024, taps=4)
+    _chain_compiles(*_mamba_chains()[chain], one_chip)
 
 
 def _step_text(cell_name, n_layers, device, monkeypatch, layers=None):
@@ -286,7 +326,8 @@ def _step(cell_name, n_layers, device, monkeypatch, layers=None, lr=None):
     from perfbench import manifest
 
     for module in (
-        "attention", "grouped_matmul", "place_rows", "kda", "kda_mixer", "ssd"
+        "attention", "grouped_matmul", "place_rows", "kda", "kda_mixer", "ssd",
+        "mamba_mixer",
     ):
         monkeypatch.setattr(
             importlib.import_module("accl_tpu.ops.pallas." + module),
@@ -470,9 +511,13 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
     ``scopes_of`` does not walk and the driver's ``scoped_instructions``
     does), no decay square (8 x 16 x 64 x 128 x 128 = 2^27 elements a block
     under the XLA form) anywhere in the text; the mixer round it under
-    ``accl.attn::mamba_proj``, ``W_down`` and ``W_up`` under
-    ``accl.moe::latent``; the held rows are 1,024 wide and placed by the
-    kernel (``_gathers_win`` at 45,056 rows, 180,224 entries: the gathers
+    ``accl.attn::mamba_proj``, its two float32 chains the kernels of
+    ``mamba_mixer`` there (x, B and C in, the gated norm out: each forward
+    kernel twice, each backward once), XLA making no float32 array of x's
+    size under the scope, no copy of y into the norm's ``(B, T, G, C / G)``
+    view (``f32[1024,8,8,1024]`` by token tile) and no such view at all;
+    ``W_down`` and ``W_up`` under ``accl.moe::latent``; the held rows are
+    1,024 wide and placed by the kernel (``_gathers_win`` at 45,056 rows, 180,224 entries: the gathers
     would take 1.2 ms); attention is the flash kernels on 2 KV heads; no
     array is a square of the length, and the largest are the held experts'
     float32 weight gradients (64 x 1,024 x 2,688: more than the float32
@@ -497,6 +542,24 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
     assert sum(n.startswith("ssd_bwd") for n in core) == mamba_blocks
     assert not any(n.startswith("while") for n in core)
     assert set(core) == set(every["accl.attn::ssd"])     # no loop's body
+    chains = entry["accl.attn::mamba_proj"]
+    assert set(chains) == set(every["accl.attn::mamba_proj"])
+    kernels = {
+        "mamba_in_fwd": 6, "mamba_in_bwd": 3, "mamba_out_fwd": 2, "mamba_out_bwd": 1,
+    }
+    for kernel, count in kernels.items():
+        assert sum(n.startswith(kernel) for n in chains) == count * mamba_blocks, kernel
+    # whatever else under the scope is as large as x in float32 is a view of
+    # a kernel's operand or result, no array of its own
+    start = text.find("\nENTRY ")
+    made = dict(re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = (.*)$", text[start: text.find("\n}", start)], re.M
+    ))
+    for name in chains:
+        shape, op = re.match(r"(\(.*?\)|\S+) ([\w-]+)\(", made[name]).groups()
+        if "f32[1,8192,8192]" in shape:
+            assert op in ("custom-call", "bitcast", "get-tuple-element"), name
+    assert not re.search(r"f32\[1024,8,8,1024\]|f32\[1,8192,8,1024\]", text)
     assert any("flash_fwd" in n for n in entry["accl.attn::core"])
     assert any("flash_bwd" in n for n in entry["accl.attn::core"])
     for kernel in ("gmm_fwd", "gmm_dlhs", "gmm_drhs"):
@@ -515,9 +578,10 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
     # decay squares (2^27 a block) or chunk products (2^26, five dimensions)
     assert sizes[-1] == 64 * 1024 * 2688 and sizes[-2] == 8192 * 16384
     assert not re.search(r"f32\[1,8,16,64,128,(?:64|128)\]", text)
-    # the parent's compile for the described chip (the XLA form of the core),
-    # the same cut: 4,698,582,016 bytes
-    assert compiled.memory_analysis().temp_size_in_bytes <= 4_698_582_016
+    # the parent's compile for the described chip (the chains XLA's fusions),
+    # the same cut: 2,976,605,696 bytes (4,698,582,016 under the XLA form of
+    # the core; 2,068,348,928 when the chains' kernels came)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2_976_605_696
 
     # the step whose update the cell's check reads the gradient from (the
     # driver's UPDATE_PROBE_RATE) is this step with ONE number changed: the
