@@ -19,6 +19,7 @@ the CCLO.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -104,14 +105,29 @@ class DeltaAttention:
     each ``silu(conv(h w))`` under a causal depthwise convolution of
     ``conv`` taps; q and k L2-normalised a head; a log-decay a CHANNEL
     ``lower_bound * sigmoid(exp(a_log) (h wf + dt_bias))`` and a write
-    strength a head ``sigmoid(h wbeta)`` into the gated delta rule
-    (``ops.kda``); the output RMS-normed a head and gated a channel by
-    ``sigmoid(h wg)`` before ``wo``.  ``lower_bound`` must stay within what
-    ``ops.kda``'s sub-blocks keep inside float32 (``-80 / SUB``)."""
+    strength a head ``beta_scale * sigmoid(h wbeta)`` into the gated delta
+    rule (``ops.kda``); the output RMS-normed a head and gated a channel by
+    ``sigmoid(h wg)`` before ``wo``.  A ``lower_bound`` must stay within
+    what ``ops.kda``'s sub-blocks keep inside float32 (``-80 / SUB``);
+    ``None`` is the PUBLISHED gate without a bound, ``-exp(a_log)
+    softplus(h wf + dt_bias)``, any value below 0, under which the core
+    splits its decays by halving (``ops.kda``, ANY ``g <= 0``; chosen here,
+    statically, so a bounded model's program is untouched).  ``beta_scale``
+    2 lets the transition's eigenvalue along k be negative (the family's
+    ``allow_neg_eigval``).  ``gate_rank``: the two gate projections ``wf``
+    and ``wg`` through that rank, ``wf_a wf_b`` and ``wg_a wg_b`` in the
+    tree (``None``: full rank, one matrix each)."""
 
     head_dim: int
     conv: int = 4
-    lower_bound: float = -5.0
+    lower_bound: Optional[float] = -5.0
+    beta_scale: float = 1.0
+    gate_rank: Optional[int] = None
+
+
+#: the softplus values ``init_params`` draws the unbounded KDA gate's
+#: ``dt_bias`` for, log-uniform a channel (its docstring there says why)
+KDA_UNBOUNDED_DT = (1e-3, 16.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -594,13 +610,20 @@ class TransformerConfig:
             d = self.kda
             if (
                 "kda" not in kinds or d.head_dim < 1 or d.conv < 1
-                or not -80.0 / SUB <= d.lower_bound < 0.0
+                or not (
+                    d.lower_bound is None
+                    or -80.0 / SUB <= d.lower_bound < 0.0
+                )
+                or d.beta_scale not in (1.0, 2.0)
+                or (d.gate_rank is not None and d.gate_rank < 1)
             ):
                 raise ValueError(
                     "a KDA mixer (TransformerConfig.kda) is some layer's of "
                     "the pattern (LayerKind.mixer='kda'), with a head_dim "
-                    f"and a convolution of at least 1 and a lower_bound in "
-                    f"[{-80.0 / SUB}, 0); got {d}"
+                    f"and a convolution of at least 1, a lower_bound in "
+                    f"[{-80.0 / SUB}, 0) or None (the gate without a bound), "
+                    "a beta_scale of 1 or 2 and a gate_rank of at least 1 or "
+                    f"None; got {d}"
                 )
         if self.mamba is not None:
             m = self.mamba
@@ -877,11 +900,19 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
             # every projection's columns are heads (``wbeta``'s one a
             # head), and so are the channels of the taps and of ``dt_bias``;
             # the output norm's scale is one head wide, every head's
-            "wq": col, "wk": col, "wv": col, "wf": col, "wg": col,
+            "wq": col, "wk": col, "wv": col,
             "wbeta": col, "conv_q": col, "conv_k": col, "conv_v": col,
             "a_log": P(heads), "dt_bias": P(heads), "o_norm": P(None),
             "wo": row,
         }
+        if cfg.kda.gate_rank is None:
+            layer.update(wf=col, wg=col)
+        else:
+            # the way down to the rank is every chip's, the way up has the
+            # heads' columns
+            layer.update(
+                wf_a=P(None, None), wf_b=col, wg_a=P(None, None), wg_b=col
+            )
     elif mixer == "latent":
         layer = {
             # the down-projections and their norms are every chip's; the
@@ -1035,7 +1066,13 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
         ``conv ** -0.5`` (a Conv1d's default range); ``a_log`` the log of
         a uniform draw from [1, 16) a head (the family's convention);
         ``dt_bias`` standard normal a channel, so that channels differ in
-        how fast they forget."""
+        how fast they forget.  Under the gate without a bound ``dt_bias``
+        is the inverse softplus of a log-uniform draw from
+        :data:`KDA_UNBOUNDED_DT` (the family's own range, [0.001, 0.1],
+        would leave every seeded channel remembering for hundreds of
+        tokens; a trained gate does not): log-decays from -0.001 to under
+        -100 a token, channels on both sides of a bound of -5.  A
+        ``gate_rank`` splits ``wf`` and ``wg`` in two, normal alike."""
         ks = jax.random.split(key, 12)
         wide = cfg.n_heads * kda.head_dim
         matrix = lambda key: normal(key, (cfg.d_model, wide))
@@ -1043,15 +1080,34 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
             jax.random.normal(key, (kda.conv, wide), cfg.dtype)
             * kda.conv ** -0.5
         )
+        if kda.gate_rank is None:
+            gates = {"wf": matrix(ks[3]), "wg": matrix(ks[4])}
+        else:
+            down = lambda key: normal(key, (cfg.d_model, kda.gate_rank))
+            up = lambda key: normal(
+                jax.random.fold_in(key, 1), (kda.gate_rank, wide)
+            )
+            gates = {
+                "wf_a": down(ks[3]), "wf_b": up(ks[3]),
+                "wg_a": down(ks[4]), "wg_b": up(ks[4]),
+            }
+        if kda.lower_bound is None:
+            low, high = KDA_UNBOUNDED_DT
+            dt = jnp.exp(jax.random.uniform(
+                ks[10], (wide,), jnp.float32, math.log(low), math.log(high)
+            ))
+            dt_bias = dt + jnp.log(-jnp.expm1(-dt))
+        else:
+            dt_bias = jax.random.normal(ks[10], (wide,), jnp.float32)
         return {
             "wq": matrix(ks[0]), "wk": matrix(ks[1]), "wv": matrix(ks[2]),
-            "wf": matrix(ks[3]), "wg": matrix(ks[4]),
+            **gates,
             "wbeta": normal(ks[5], (cfg.d_model, cfg.n_heads)),
             "conv_q": taps(ks[6]), "conv_k": taps(ks[7]), "conv_v": taps(ks[8]),
             "a_log": jnp.log(jax.random.uniform(
                 ks[9], (cfg.n_heads,), jnp.float32, 1.0, 16.0
             )),
-            "dt_bias": jax.random.normal(ks[10], (wide,), jnp.float32),
+            "dt_bias": dt_bias,
             "o_norm": jnp.ones((kda.head_dim,), cfg.dtype),
             "wo": normal(ks[11], (wide, cfg.d_model)),
         }
@@ -1732,8 +1788,11 @@ def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
 def _kda_partial(h, lp, n_heads_local, kda):
     """The KDA mixer (:class:`DeltaAttention`) on a full-sequence
     activation, heads column-parallel: the row-parallel PARTIAL output.
-    The sizes are the tree's; ``kda`` carries what the shapes do not say,
-    the gate's ``lower_bound`` and the output norm's ``eps``.  The matmuls
+    The sizes are the tree's (gate projections through a rank where it
+    holds ``wf_a`` / ``wf_b`` and ``wg_a`` / ``wg_b``); ``kda`` carries what
+    the shapes do not say, the gate's ``lower_bound`` (``None``: the gate
+    without a bound, and the core's split by halving), the write strength's
+    ``beta_scale`` and the output norm's ``eps``.  The matmuls
     take the activations' type; the convolutions, SiLU, the L2 norms, the
     gate, beta, the core and the output norm are float32, in either
     lowering of the three chains round the core (``ops.kda``: ``conv_in``
@@ -1754,12 +1813,19 @@ def _kda_partial(h, lp, n_heads_local, kda):
                     scale=(lp["wq"].shape[1] // H) ** -0.5)
         k = conv_in(h @ lp["wk"], lp["conv_k"], H, unit=True)
         v = conv_in(h @ lp["wv"], lp["conv_v"], H, unit=False)
-        g = decay_in(h @ lp["wf"], lp["dt_bias"], lp["a_log"], kda["lower_bound"])
+        through = lambda w: (
+            h @ lp[w] if w in lp else (h @ lp[w + "_a"]) @ lp[w + "_b"]
+        )
+        bound = kda["lower_bound"]
+        g = decay_in(through("wf"), lp["dt_bias"], lp["a_log"], bound)
         beta = jax.nn.sigmoid((h @ lp["wbeta"]).astype(f32)).transpose(0, 2, 1)
+        if kda.get("beta_scale", 1.0) != 1.0:
+            beta = beta * kda["beta_scale"]
     with device_scope("accl.attn::kda"):
-        o = kda_chunked(q, k, v, g, beta)                 # (B, H, T, dv) f32
+        # (B, H, T, dv) f32; without a bound, the split by halving
+        o = kda_chunked(q, k, v, g, beta, safe=bound is None)
     with device_scope("accl.attn::kda_proj"):
-        o = gated_out(o, h @ lp["wg"], lp["o_norm"], kda["eps"], h.dtype)
+        o = gated_out(o, through("wg"), lp["o_norm"], kda["eps"], h.dtype)
         return o @ lp["wo"]
 
 
@@ -1830,7 +1896,8 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     (:func:`_mamba2_partial`) and an ``a_log`` without one the KDA mixer's
     (:func:`_kda_partial`; both likewise).  ``window`` is the sliding window,
     run under the device scope ``accl.attn::window`` (full attention stays
-    ``accl.attn::core``).  ``qk_eps`` is QK-norm's epsilon.
+    ``accl.attn::core``; where the stack has KDA layers, ``kda`` given, what
+    is round the core runs under ``accl.attn::gqa_proj``).  ``qk_eps`` is QK-norm's epsilon.
     ``block_diffusion=(L, B)``: ``h`` is ``[noisy ; clean]``, ``2 L`` rows
     that rotate at positions ``0..L`` twice, and the core runs under that
     layout in the device scope ``accl.attn::blockdiff``."""
@@ -1843,7 +1910,16 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
             h, lp, n_heads_local, attn_impl, causal, rope_base, window, latent
         ), None
     B, T, _ = h.shape
-    q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]  # column-parallel
+    # a softmax layer BESIDE KDA layers (``kda`` is the stack's: a gated
+    # grouped-query layer without position among linear-attention ones)
+    # runs what is round its core under a device scope of its own, as the
+    # KDA layers do; every other stack's program is what it was
+    proj_scope = (
+        device_scope("accl.attn::gqa_proj") if kda is not None
+        else contextlib.nullcontext()
+    )
+    with proj_scope:
+        q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]  # column-parallel
     if not head_norm:
         q, k = _qk_norm(q, k, lp, tp_axis, qk_eps)
     hd = q.shape[-1] // n_heads_local
@@ -1879,11 +1955,12 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
             attn = _attention(
                 q, k, v, impl=attn_impl, causal=causal, window=window
             )
-    attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
-    if "wg" in lp:
-        gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
-        attn = attn * gate.astype(attn.dtype)
-    return attn @ lp["wo"], (k, v)
+    with proj_scope:
+        attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
+        if "wg" in lp:
+            gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
+            attn = attn * gate.astype(attn.dtype)
+        return attn @ lp["wo"], (k, v)
 
 
 def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
@@ -2112,6 +2189,7 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
     if cfg.kda is not None:
         kw["kda"] = {
             "lower_bound": cfg.kda.lower_bound, "eps": cfg.norm_eps,
+            "beta_scale": cfg.kda.beta_scale,
         }
     if cfg.mamba is not None:
         m = cfg.mamba
@@ -2424,7 +2502,9 @@ def reject_latent(cfg, where: str) -> None:
         )
     if cfg.kda is not None:
         raise ValueError(
-            "the KDA mixer (linear attention, TransformerConfig.kda) is "
+            "the KDA mixer (linear attention, TransformerConfig.kda: its "
+            "state is no cache yet, under either gate and at either rank of "
+            "the gate projections) is "
             f"supported on the decoder's train and forward paths only, not "
             f"{where}"
         )

@@ -4,7 +4,8 @@ rule with a decay a CHANNEL, in its chunked form.
 A head keeps a state ``S`` (dk x dv, ``S_0 = 0``).  Token ``t`` brings a
 query ``q_t`` and a key ``k_t`` (dk; the caller has L2-normalised both and
 scaled q), a value ``v_t`` (dv), a log-decay ``g_t <= 0`` a channel of dk
-and a write strength ``beta_t`` in (0, 1):
+and a write strength ``beta_t`` in (0, 1), or in (0, 2) where the model
+allows the transition's eigenvalue along ``k_t`` to be negative:
 
     S_t = (I - beta_t k_t k_t^T) diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
@@ -49,6 +50,30 @@ diagonal splits ``exp(G_t - G_j)`` at the row block's start (both factors
 at most 1), a diagonal block at its own middle token (factors within
 ``exp(+-SUB / 2 * 5)``, e^40 at the bound).  What underflows is smaller
 than float32 resolves beside the terms it is added to.
+
+ANY ``g <= 0`` (``safe=True``: the published, unbounded gate ``-exp(a_log)
+softplus(.)``, where one token can carry -50 and eight of them leave
+float32).  No exponent may then be taken from a point BETWEEN tokens of
+one sub-block, so ``exp(G_t - G_j)`` is split by HALVING instead: a chunk
+is two half chunks and the square below them, ``t`` in the lower half and
+``j`` in the upper, split at the lower half's start, ``exp(G_t - c) exp(c
+- G_j)`` with both factors at most 1; each half again, down to single
+tokens.  A pair ``j < t`` belongs to exactly one LEVEL ``h`` (32, 16, ..,
+1: the highest bit in which ``t`` and ``j`` differ), so ``A`` and ``P``
+are the sum over the levels of one masked product each, rows times
+``exp(D_h)`` (a token's log-decay summed from its ``h``-segment's first
+token to itself) and key columns times ``exp(E_h)`` (summed from the next
+token to its segment's last), plus ``P``'s diagonal.  Both are plain sums
+of ``g``, never a difference of two large ``G``: nothing overflows, what
+underflows bounds its product under ``e^-87``, and a log-decay's
+cotangent has no two large terms that must cancel.  ``Gamma`` and
+``Gamma_C / Gamma`` are ``exp(D_C)`` and ``exp(E_C)`` the same way, and
+``(I + A)^-1`` goes up the same levels (:func:`_unit_lower_inverse_by_
+halving`: the write strengths such a model has, in (0, 2), cost the
+powers of ``A`` float32's last digits).
+:func:`_safe_products` is that form; the bounded gate keeps the sub-block
+form above (chosen statically by the caller: ``models.transformer``
+``DeltaAttention.lower_bound``), so its program is what it was.
 
 THE CHAINS ROUND THE CORE (the end of this module): what the mixer runs in
 float32 between a projection and the core and between the core and ``wo``
@@ -107,6 +132,32 @@ def _unit_lower_inverse_bwd(inv, g):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
+@jax.custom_vjp
+def _unit_lower_inverse_by_halving(a):
+    """:func:`_unit_lower_inverse` without a power of ``a``: the inverse of
+    a block ``[[M1, 0], [C, M2]]`` is ``[[T1, 0], [-T2 C T1, T2]]``, that is
+    ``T - T C T`` for the block-diagonal ``T`` of the two halves' inverses,
+    from single tokens up (n a power of two; two ``highest`` products a
+    level).  Under a write strength in (0, 2) and keys that lean the same way
+    the powers' entries reach the hundreds before they cancel, which costs
+    float32 its last three digits; these factors stay of the inverse's own
+    size.  The same cotangent."""
+    n = a.shape[-1]
+    inv, h = jnp.eye(n, dtype=a.dtype), 1
+    while h < n:
+        inv = inv - _dot(_dot(inv, jnp.where(_level_pairs(n, h), a, 0.0)), inv)
+        h *= 2
+    return inv
+
+
+def _by_halving_fwd(a):
+    inv = _unit_lower_inverse_by_halving(a)
+    return inv, inv
+
+
+_unit_lower_inverse_by_halving.defvjp(_by_halving_fwd, _unit_lower_inverse_bwd)
+
+
 def _decayed_products(q, k, within, start):
     """``[q_t . k_j exp(G_t - G_j), k_t . k_j exp(G_t - G_j)]`` of every
     chunk, (2, ..., C, C), right where ``j <= t`` and finite elsewhere
@@ -143,23 +194,68 @@ def _decayed_products(q, k, within, start):
     return jnp.concatenate(out, axis=-2)
 
 
-def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB):
+def _segment_sums(g, h: int):
+    """``(D_h, E_h)`` of ``g`` (..., C, dk) in segments of ``h`` tokens: a
+    token's ``g`` summed from its segment's first token to itself, and from
+    the NEXT token to its segment's last (0 at the last); plain sums, so
+    neither is above 0 for ``g <= 0``."""
+    C, dk = g.shape[-2:]
+    x = g.reshape(*g.shape[:-2], C // h, h, dk)
+    after = jnp.concatenate([x[..., 1:, :], jnp.zeros_like(x[..., :1, :])], -2)
+    return (
+        jnp.cumsum(x, axis=-2).reshape(g.shape),
+        lax.cumsum(after, axis=after.ndim - 2, reverse=True).reshape(g.shape),
+    )
+
+
+def _level_pairs(n: int, h: int):
+    """The (n, n) mask of level ``h``'s pairs: ``t`` in the lower half and
+    ``j`` in the upper half of one segment of ``2 h`` tokens."""
+    t, j = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    return (t // (2 * h) == j // (2 * h)) & (t // h % 2 == 1) & (j // h % 2 == 0)
+
+
+def _safe_products(q, k, g):
+    """``[q_t . k_j exp(G_t - G_j), k_t . k_j exp(G_t - G_j)]`` of every
+    chunk, (2, ..., C, C), for ANY ``g <= 0`` (module docstring, ANY ``g <=
+    0``): zero where ``j > t`` (the caller masks the second to ``j < t``).  ``q``, ``k``, ``g``: (..., C, dk)."""
+    C = q.shape[-2]
+    rows = jnp.stack([q, k])                             # (2, ..., C, dk)
+    t, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    pairs = lambda x, y: jnp.einsum("r...td,...jd->r...tj", x, y)
+    out = jnp.where(t == j, pairs(rows, k), 0.0)
+    h = C // 2
+    while h:
+        D, E = _segment_sums(g, h)
+        out = out + jnp.where(
+            _level_pairs(C, h), pairs(rows * jnp.exp(D), k * jnp.exp(E)), 0.0
+        )
+        h //= 2
+    return out
+
+
+def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB,
+                safe: bool = False):
     """The gated delta rule of the module docstring over whole sequences:
     ``q``, ``k``, ``g`` (B, H, T, dk), ``v`` (B, H, T, dv), ``beta`` (B, H,
-    T); returns ``o`` (B, H, T, dv) in float32.  ``g`` must not pass
-    ``-80 / sub`` a token (the gate's lower bound of -5 at ``sub`` 16), or
-    a diagonal block's exponents leave float32.  T need be no multiple of
-    the chunk: the tail is padded with tokens that leave the state alone
-    (no decay, no write).  The shapes pick the lowering (module
-    docstring)."""
+    T); returns ``o`` (B, H, T, dv) in float32.  Without ``safe``, ``g``
+    must not pass ``-80 / sub`` a token (the gate's lower bound of -5 at
+    ``sub`` 16), or a diagonal block's exponents leave float32; with it
+    any ``g <= 0`` is right (the split by halving; ``chunk`` a power of
+    two).  T need be no multiple of the chunk: the tail is padded with
+    tokens that leave the state alone (no decay, no write).  The shapes
+    pick the lowering (module docstring)."""
     if chunk % sub or sub % 2:
         raise ValueError(f"chunk {chunk} is no whole even sub-blocks of {sub}")
+    if safe and chunk & (chunk - 1):
+        raise ValueError(f"the split by halving needs a chunk of 2^n, not {chunk}")
     if _kernels.takes(q.shape, v.shape, chunk, sub):
-        return _kernels.kda(q, k, v, g, beta)
-    return _xla_form(q, k, v, g, beta, chunk, sub)
+        return _kernels.kda(q, k, v, g, beta, safe=safe)
+    return _xla_form(q, k, v, g, beta, chunk, sub, safe)
 
 
-def _xla_form(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB):
+def _xla_form(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB,
+              safe: bool = False):
     """:func:`kda_chunked` as plain ``jax.numpy``, at any shape."""
     B, H, T, dk = q.shape
     dv = v.shape[-1]
@@ -174,23 +270,29 @@ def _xla_form(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB):
     flat = lambda x: x.reshape(B, H, N, chunk, x.shape[-1])
     beta = beta.reshape(B, H, N, chunk)
 
-    # log-decay summed inside a sub-block, and before it inside the chunk
-    within = jnp.cumsum(blocks(g), axis=4)
-    total = within[..., -1:, :]
-    start = jnp.cumsum(total, axis=3) - total
-    G = flat(start + within)                             # G_t
-    end = G[..., -1:, :]                                 # G_C
-
-    P, A = _decayed_products(blocks(q), blocks(k), within, start)
+    if safe:
+        # G_t and G_C - G_t as plain sums of g over the chunk
+        G, to_end = _segment_sums(flat(g), chunk)
+        end = G[..., -1:, :]                             # G_C
+        P, A = _safe_products(flat(q), flat(k), flat(g))
+    else:
+        # log-decay summed inside a sub-block, and before it inside the chunk
+        within = jnp.cumsum(blocks(g), axis=4)
+        total = within[..., -1:, :]
+        start = jnp.cumsum(total, axis=3) - total
+        G = flat(start + within)                         # G_t
+        end = G[..., -1:, :]                             # G_C
+        P, A = _decayed_products(blocks(q), blocks(k), within, start)
     t, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
     by_column = beta[..., None, :]
     P = jnp.where(j <= t, P, 0.0) * by_column
-    inv = _unit_lower_inverse(jnp.where(j < t, A, 0.0) * by_column)
+    inverse = _unit_lower_inverse_by_halving if safe else _unit_lower_inverse
+    inv = inverse(jnp.where(j < t, A, 0.0) * by_column)
 
     q, k, v = flat(q), flat(k), flat(v)
     w = inv @ (k * jnp.exp(G))                           # W
     u0 = inv @ v                                         # U~
-    write = k * jnp.exp(end - G) * beta[..., None]       # K^
+    write = k * jnp.exp(to_end if safe else end - G) * beta[..., None]  # K^
     read = q * jnp.exp(G)
     keep = jnp.exp(end[..., 0, :])                       # (B, H, N, dk)
 
@@ -256,10 +358,12 @@ def _xla_conv_in(x, taps, heads, *, unit, scale=1.0):
     return y
 
 
-def decay_in(x, dt_bias, a_log, lower_bound: float):
+def decay_in(x, dt_bias, a_log, lower_bound):
     """The log-decay a channel from its projection ``x`` (B, T, H d):
     ``lower_bound * sigmoid(exp(a_log) (x + dt_bias))``, ``dt_bias`` a
-    channel, ``a_log`` (H,) a head; float32 (B, H, T, d)."""
+    channel, ``a_log`` (H,) a head; under ``lower_bound`` None the
+    published gate without a bound, ``-exp(a_log) softplus(x + dt_bias)``
+    (the core then runs ``safe``); float32 (B, H, T, d)."""
     if _chains.takes(x.shape[-1], a_log.shape[0]):
         return _chains.decay_in(x, dt_bias, a_log, lower_bound)
     return _xla_decay_in(x, dt_bias, a_log, lower_bound)
@@ -270,6 +374,8 @@ def _xla_decay_in(x, dt_bias, a_log, lower_bound):
         x.astype(jnp.float32) + dt_bias.astype(jnp.float32), a_log.shape[0]
     )
     rate = jnp.exp(a_log.astype(jnp.float32))[None, :, None, None]
+    if lower_bound is None:
+        return -rate * jax.nn.softplus(f)
     return lower_bound * jax.nn.sigmoid(rate * f)
 
 

@@ -53,6 +53,22 @@ for the products' rounding; the backward leaves that rounding on the
 middle token as autodiff of the XLA form does, which cancels it out of
 ``dg`` for the tokens before (measured: ``dg`` is then as near the
 ``highest`` truth as the XLA form's, 1.0% rms, and 2.7% without).
+
+ANY ``g <= 0`` (``safe=True``, the unbounded gate; ``ops/kda.py``, ANY ``g
+<= 0``).  The split at a middle token overflows, so ``A`` and ``P`` are
+the XLA form's split by HALVING, for the block's two chunks at once: the
+segment sums ``D_h`` and ``E_h`` of every level from ``g`` by a doubling
+recursion of sublane rolls (:func:`_segments`; plain sums of ``g``, its
+transpose the cotangent's, :func:`_segments_bwd`), six masked one-pass
+products of ``(2 ROWS, dk) x (dk, ROWS)`` and the diagonal's
+(:func:`_local_safe`), every factor at most 1; ``Gamma`` and ``Gamma_C /
+Gamma`` are the top level's; the inverse goes up the same levels, twelve
+``highest`` products and no power of ``A`` (:func:`_inverse_by_halving`).
+The backward (:func:`_block_bwd_safe`) shares everything that waits for
+the state with the bounded one (:func:`_state_bwd`) and takes each level's
+two cotangent products; no exponent is a difference, so no cotangent of
+``g`` is two large terms that cancel.  ``safe`` is static: the bounded gate's kernels are the bodies
+they were.
 """
 
 from __future__ import annotations
@@ -151,6 +167,16 @@ def _inverse(a, eye):
     return inv + _mm_hi(inv, power)
 
 
+def _inverse_by_halving(a, masks, eye):
+    """``ops.kda._unit_lower_inverse_by_halving`` for the block's two
+    chunks: ``masks`` the levels' pair masks, lowest first; two ``highest``
+    products a level and no power of ``a``."""
+    inv = jnp.where(eye, 1.0, 0.0)
+    for mask in masks:
+        inv = inv - _mm_hi(_mm_hi(inv, jnp.where(mask, a, 0.0)), inv)
+    return inv
+
+
 def _local(mm, q, k, v, g, brow, T=None):
     """What a block makes without the state, from its ``(ROWS, .)`` rows
     and its ``(1, ROWS)`` beta: a dict of the module docstring's names.
@@ -208,11 +234,87 @@ def _local(mm, q, k, v, g, brow, T=None):
     )
 
 
-def _block_fwd(mm, q, k, v, g, brow, state):
+def _segments(g):
+    """``[(D_h, E_h) for h in 1, 2, .., CHUNK]`` of a block's ``g`` (ROWS,
+    dk): a token's ``g`` summed from its ``h``-segment's first token to
+    itself, and from the next token to its segment's last.  Doubling: a
+    ``2h``-segment's lower half adds the upper half's total, which stands
+    ``h`` rows up (any row's ``D_h + E_h`` is its segment's total); the
+    upper half adds the lower's, ``h`` rows down."""
+    at = lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    D, E = g, jnp.zeros_like(g)
+    out, h = [(D, E)], 1
+    while h < CHUNK:
+        total, lower = D + E, (at & h) != 0
+        D = D + jnp.where(lower, pltpu.roll(total, h, 0), 0.0)
+        E = E + jnp.where(lower, 0.0, pltpu.roll(total, ROWS - h, 0))
+        out.append((D, E))
+        h *= 2
+    return out
+
+
+def _segments_bwd(dD, dE):
+    """The cotangent of ``g`` from those of :func:`_segments`' results
+    (two lists, a level each): the recursion transposed, the top level
+    first."""
+    at = lax.broadcasted_iota(jnp.int32, dD[0].shape, 0)
+    cD, cE, h = dD[-1], dE[-1], CHUNK // 2
+    for i in reversed(range(len(dD) - 1)):
+        lower = (at & h) != 0
+        d_total = pltpu.roll(jnp.where(lower, cD, 0.0), ROWS - h, 0) + pltpu.roll(
+            jnp.where(lower, 0.0, cE), h, 0
+        )
+        cD, cE, h = cD + d_total + dD[i], cE + d_total + dE[i], h // 2
+    return cD
+
+
+def _local_safe(mm, q, k, v, g, brow, T=None):
+    """:func:`_local` for any ``g <= 0`` (module docstring, ANY ``g <=
+    0``): the same names but the middle token's (``up``, ``downs``,
+    ``lhs``), and ``levels``: a level's ``(mask, rows, key columns,
+    exp(D_h), exp(E_h))``."""
+    row = lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 0)
+    col = lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 1)
+    same = (row // CHUNK) == (col // CHUNK)
+    upto, below = same & (col <= row), same & (col < row)
+    eye = row == col
+    differ = row ^ col           # its highest bit is the pair's level
+
+    *halvings, (G, to_end) = _segments(g)
+    P0 = jnp.where(eye, mm(q, k, _NT), 0.0)
+    A0 = jnp.zeros_like(P0)
+    levels = []
+    for i, (D, E) in enumerate(halvings):
+        h = 1 << i
+        mask = (differ >= h) & (differ < 2 * h) & (col < row)
+        eDh, eEh = jnp.exp(D), jnp.exp(E)
+        x, y = jnp.concatenate([q * eDh, k * eDh], axis=0), k * eEh
+        out = mm(x, y, _NT)                          # (2 ROWS, ROWS)
+        P0 = P0 + jnp.where(mask, out[:ROWS], 0.0)
+        A0 = A0 + jnp.where(mask, out[ROWS:], 0.0)
+        levels.append((mask, x, y, eDh, eEh))
+    Pm = P0 * brow
+    if T is None:
+        T = _inverse_by_halving(A0 * brow, [level[0] for level in levels], eye)
+
+    bcol = jnp.sum(
+        jnp.where(eye, jnp.broadcast_to(brow, (ROWS, ROWS)), 0.0), axis=1, keepdims=True
+    )
+    eG, eD = jnp.exp(G), jnp.exp(to_end)
+    k_gam = k * eG
+    return dict(
+        upto=upto, below=below, eye=eye, levels=levels,
+        P0=P0, A0=A0, Pm=Pm, T=T, eG=eG, eD=eD, bcol=bcol, k_gam=k_gam,
+        q_gam=q * eG, W=mm(T, k_gam), U0=mm(T, v), k_hat=k * eD * bcol,
+        keeps=[x[CHUNK - 1:] for x in _halves(eG)],
+    )
+
+
+def _block_fwd(mm, q, k, v, g, brow, state, local=_local):
     """One block forward: ``(o, the state after it, the states its two
     chunks started from, the block's inverse)``; ``state`` is ``S^T`` (dv,
     dk)."""
-    loc = _local(mm, q, k, v, g, brow)
+    loc = local(mm, q, k, v, g, brow)
     starts, u, read = [], [], []
     for W, U0, q_gam, k_hat, keep in zip(
         *(_halves(loc[n]) for n in ("W", "U0", "q_gam", "k_hat")), loc["keeps"]
@@ -225,13 +327,12 @@ def _block_fwd(mm, q, k, v, g, brow, state):
     return o, state, starts, loc["T"]
 
 
-def _block_bwd(mm, q, k, v, g, brow, do, starts, T, d_state):
-    """One block backward: the block's rows, ``do``, the states its chunks
-    started from, its inverse and the cotangent ``d_state`` of the state
-    after it (both states transposed); returns ``(dq, dk, dv, dg, dbeta
-    (1, ROWS), the cotangent of the state before it)``."""
-    loc = _local(mm, q, k, v, g, brow, T)
-    Pm, eG, eD, bcol = (loc[n] for n in ("Pm", "eG", "eD", "bcol"))
+def _state_bwd(mm, loc, v, brow, do, starts, d_state):
+    """What both backward passes share: the chain through the chunks'
+    states backwards, and every cotangent it leaves of the block's own
+    quantities, as a dict (``d_state``: of the state before the block)."""
+    T = loc["T"]
+    Pm = loc["Pm"]
     W, U0, q_gam, k_hat, do_h = (
         _halves(x) for x in (loc["W"], loc["U0"], loc["q_gam"], loc["k_hat"], do)
     )
@@ -271,6 +372,24 @@ def _block_bwd(mm, q, k, v, g, brow, do, starts, T, d_state):
         + jnp.where(loc["upto"], loc["P0"] * dPm, 0.0),
         axis=0, keepdims=True,
     )
+    return dict(
+        d_state=d_state, dq_gam=dq_gam, dk_gam=dk_gam, dk_hat=dk_hat,
+        d_end=d_end, dv=dv, dP0=dP0, dA0=dA0, dbrow=dbrow,
+    )
+
+
+def _block_bwd(mm, q, k, v, g, brow, do, starts, T, d_state):
+    """One block backward: the block's rows, ``do``, the states its chunks
+    started from, its inverse and the cotangent ``d_state`` of the state
+    after it (both states transposed); returns ``(dq, dk, dv, dg, dbeta
+    (1, ROWS), the cotangent of the state before it)``."""
+    loc = _local(mm, q, k, v, g, brow, T)
+    eG, eD, bcol = (loc[n] for n in ("eG", "eD", "bcol"))
+    c = _state_bwd(mm, loc, v, brow, do, starts, d_state)
+    d_state, dq_gam, dk_gam, dk_hat, d_end, dv, dP0, dA0, dbrow = (
+        c[n] for n in ("d_state", "dq_gam", "dk_gam", "dk_hat", "d_end",
+                       "dv", "dP0", "dA0", "dbrow")
+    )
 
     dq_up, dk_up, d_mid, back = [], [], [], None
     middle = lax.broadcasted_iota(jnp.int32, (SUB, q.shape[-1]), 0) == SUB // 2 - 1
@@ -308,7 +427,52 @@ def _block_bwd(mm, q, k, v, g, brow, do, starts, T, d_state):
     return dq, dk, dv, dg, dbrow, d_state
 
 
-def _fwd_kernel(blocks, save, mm):
+def _block_bwd_safe(mm, q, k, v, g, brow, do, starts, T, d_state):
+    """:func:`_block_bwd` for any ``g <= 0``: a level's two cotangent
+    products where the bounded one has a row sub-block's, and every
+    exponent's cotangent its factor's times the factor (the exponents are
+    sums of ``g``: :func:`_segments_bwd` takes them back to it)."""
+    loc = _local_safe(mm, q, k, v, g, brow, T)
+    eG, eD, bcol = (loc[n] for n in ("eG", "eD", "bcol"))
+    c = _state_bwd(mm, loc, v, brow, do, starts, d_state)
+    dq_gam, dk_gam, dk_hat, dP0, dA0 = (
+        c[n] for n in ("dq_gam", "dk_gam", "dk_hat", "dP0", "dA0")
+    )
+
+    d = jnp.where(loc["eye"], dP0, 0.0)
+    dq, dk = mm(d, k), mm(d, q, _TN)
+    dD, dE = [], []
+    for mask, x, y, eDh, eEh in loc["levels"]:
+        d = jnp.concatenate(
+            [jnp.where(mask, dP0, 0.0), jnp.where(mask, dA0, 0.0)], axis=0
+        )                                                        # (2 ROWS, ROWS)
+        got, back = mm(d, y), mm(d, x, _TN)
+        dq = dq + got[:ROWS] * eDh
+        dk = dk + got[ROWS:] * eDh + back * eEh
+        dD.append(x[:ROWS] * got[:ROWS] + x[ROWS:] * got[ROWS:])
+        dE.append(y * back)
+
+    dk_end = dk_hat * eD * bcol                 # through K^ = k exp(G_C - G) beta
+    dq = dq + dq_gam * eG
+    dk = dk + dk_gam * eG + dk_end
+    # the top level: Gamma = exp(D_C) on q and k, exp(E_C) in K^, and a
+    # chunk's exp(G_C) (its last token's D_C) on the state it starts from
+    dG = loc["q_gam"] * dq_gam + loc["k_gam"] * dk_gam
+    at = lax.broadcasted_iota(jnp.int32, dG.shape, 0)
+    for h, e in enumerate(c["d_end"]):
+        dG = dG + jnp.where(at == h * CHUNK + CHUNK - 1, e, 0.0)
+    dg = _segments_bwd(dD + [dG], dE + [dk_end * k])
+    dbcol = jnp.sum(dk_hat * k * eD, axis=1, keepdims=True)
+    dbrow = c["dbrow"] + jnp.sum(
+        jnp.where(loc["eye"], jnp.broadcast_to(dbcol, (ROWS, ROWS)), 0.0),
+        axis=0, keepdims=True,
+    )
+    return dq, dk, c["dv"], dg, dbrow, c["d_state"]
+
+
+def _fwd_kernel(blocks, save, mm, safe=False):
+    local = _local_safe if safe else _local
+
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
         s_ref, t_ref, state_ref = rest if save else (None, None, *rest)
 
@@ -323,7 +487,7 @@ def _fwd_kernel(blocks, save, mm):
             rows = slice(i * ROWS, (i + 1) * ROWS)
             o, state, starts, T = _block_fwd(
                 mm, q_ref[0, rows], k_ref[0, rows], v_ref[0, rows],
-                g_ref[0, rows], b_ref[0, i], state,
+                g_ref[0, rows], b_ref[0, i], state, local,
             )
             o_ref[0, rows] = o
             if save:
@@ -335,7 +499,9 @@ def _fwd_kernel(blocks, save, mm):
     return kernel
 
 
-def _bwd_kernel(blocks, mm):
+def _bwd_kernel(blocks, mm, safe=False):
+    block_bwd = _block_bwd_safe if safe else _block_bwd
+
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, t_ref,
                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, d_state_ref):
         @pl.when(pl.program_id(1) == 0)
@@ -345,7 +511,7 @@ def _bwd_kernel(blocks, mm):
         d_state = d_state_ref[...]
         for i in reversed(range(blocks)):
             rows = slice(i * ROWS, (i + 1) * ROWS)
-            dq, dk, dv, dg, db, d_state = _block_bwd(
+            dq, dk, dv, dg, db, d_state = block_bwd(
                 mm, q_ref[0, rows], k_ref[0, rows], v_ref[0, rows],
                 g_ref[0, rows], b_ref[0, i], do_ref[0, rows],
                 [s_ref[0, 2 * i + h] for h in range(ROWS // CHUNK)],
@@ -383,8 +549,8 @@ def _vmem_limit(blocks, dk, dv, arrays):
 
 # jitted, so that a program's layers share one trace and one lowered
 # function a shape
-@partial(jax.jit, static_argnames=("save", "blocks", "interpret", "one_pass"))
-def _forward(q, k, v, g, beta, *, save, blocks, interpret, one_pass):
+@partial(jax.jit, static_argnames=("save", "blocks", "interpret", "one_pass", "safe"))
+def _forward(q, k, v, g, beta, *, save, blocks, interpret, one_pass, safe=False):
     N, Tp, dk = q.shape
     dv = v.shape[-1]
     steps = Tp // (blocks * ROWS)
@@ -399,7 +565,7 @@ def _forward(q, k, v, g, beta, *, save, blocks, interpret, one_pass):
         ]
         out_specs += s_spec
     call = pl.pallas_call(
-        _fwd_kernel(blocks, save, partial(_mm, one_pass=one_pass)),
+        _fwd_kernel(blocks, save, partial(_mm, one_pass=one_pass), safe),
         grid=(N, steps),
         out_shape=out_shape,
         in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), b_spec],
@@ -415,15 +581,16 @@ def _forward(q, k, v, g, beta, *, save, blocks, interpret, one_pass):
     return _run(call, interpret, *operands)
 
 
-@partial(jax.jit, static_argnames=("blocks", "interpret", "one_pass"))
-def _backward(q, k, v, g, beta, do, states, inverses, *, blocks, interpret, one_pass):
+@partial(jax.jit, static_argnames=("blocks", "interpret", "one_pass", "safe"))
+def _backward(q, k, v, g, beta, do, states, inverses, *, blocks, interpret,
+              one_pass, safe=False):
     N, Tp, dk = q.shape
     dv = v.shape[-1]
     steps = Tp // (blocks * ROWS)
     rows, b_spec, s_spec = _specs(blocks, dk, dv, steps, backward=True)
     operands = (q, k, v, g, beta, do, states, inverses)
     call = pl.pallas_call(
-        _bwd_kernel(blocks, partial(_mm, one_pass=one_pass)),
+        _bwd_kernel(blocks, partial(_mm, one_pass=one_pass), safe),
         grid=(N, steps),
         out_shape=[
             out_struct(x.shape, _f32, *operands) for x in (q, k, v, g, beta)
@@ -468,11 +635,12 @@ def _packed(q, k, v, g, beta):
 
 def _apply(q, k, v, g, beta, how, save):
     """``(o, what the forward saves for the backward or ())``; ``how``:
-    ``(interpret, one_pass)``."""
+    ``(interpret, one_pass, safe)``."""
     B, H, T, _ = q.shape
     blocks, packed = _packed(q, k, v, g, beta)
     o, *saved = _forward(
-        *packed, save=save, blocks=blocks, interpret=how[0], one_pass=how[1]
+        *packed, save=save, blocks=blocks, interpret=how[0], one_pass=how[1],
+        safe=how[2],
     )
     o = _settled(o.reshape(B, H, -1, o.shape[-1])[:, :, :T], how[0])
     return o, tuple(saved)
@@ -489,14 +657,14 @@ def _kda_fwd(q, k, v, g, beta, how):
 
 
 def _kda_bwd(how, res, do):
-    interpret, one_pass = how
+    interpret, one_pass, safe = how
     *inputs, states, inverses = res
     B, H, T, _ = inputs[0].shape
     blocks, packed = _packed(*inputs)
     do = _flat(do, packed[0].shape[1])
     *grads, dbeta = _backward(
         *packed, do, states, inverses,
-        blocks=blocks, interpret=interpret, one_pass=one_pass,
+        blocks=blocks, interpret=interpret, one_pass=one_pass, safe=safe,
     )
     grads = [x[:, :T].reshape(B, H, T, -1) for x in grads]
     grads.append(dbeta.reshape(B, H, -1)[:, :, :T])
@@ -508,9 +676,11 @@ def _kda_bwd(how, res, do):
 _kda.defvjp(_kda_fwd, _kda_bwd)
 
 
-def kda(q, k, v, g, beta, *, interpret: InterpretArg = None):
+def kda(q, k, v, g, beta, *, safe: bool = False,
+        interpret: InterpretArg = None):
     """``ops.kda.kda_chunked`` by the kernels: ``q``, ``k``, ``g`` (B, H,
     T, dk), ``v`` (B, H, T, dv), ``beta`` (B, H, T), :func:`takes` their
-    shapes; ``o`` (B, H, T, dv) float32.  Differentiable by all five."""
+    shapes; ``o`` (B, H, T, dv) float32.  Differentiable by all five.
+    ``safe``: any ``g <= 0`` (the split by halving)."""
     _, operands = vary_together(q, k, v, g, beta)
-    return _kda(*operands, (default_interpret(interpret), _ONE_PASS))
+    return _kda(*operands, (default_interpret(interpret), _ONE_PASS, bool(safe)))

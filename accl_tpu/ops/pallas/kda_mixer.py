@@ -19,8 +19,10 @@ and their cotangents, which keep the matmuls' type):
   pre-SiLU values rebuilt from the projection.
 * ``kda_decay_fwd`` | ``kda_decay_bwd``: projection + a bias a channel ->
   ``lower_bound * sigmoid(rate * .)`` (a rate a head, handed over a
-  channel) -> head-major | the projection's cotangent, the bias's and
-  the rate's gradients.
+  channel), or under ``lower_bound`` None the published unbounded gate
+  ``-rate * softplus(.)`` (static: a bound's kernels are the bodies they
+  were) -> head-major | the projection's cotangent, the bias's and the
+  rate's gradients.
 * ``kda_out_fwd`` | ``kda_out_bwd``: the core's ``o`` -> RMS norm a head
   with a scale -> ``* sigmoid(gate projection)`` -> ``(B, T, H d)`` in the
   matmuls' type | ``o``'s and the gate projection's cotangents, the
@@ -48,6 +50,7 @@ a head, the scale's heads) is summed outside from ``H d`` numbers.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -347,6 +350,11 @@ def conv_in(x, taps, heads: int, *, unit: bool, scale: float = 1.0,
 # -- the decay in ------------------------------------------------------------------
 
 
+def _softplus(f):
+    """``log(1 + e^f)`` without overflow."""
+    return jnp.maximum(f, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(f)))
+
+
 def _decay_fwd_kernel(sp, lower_bound):
     def kernel(x_ref, bias_ref, rate_ref, g_ref):
         for j, cols in _head_cols(sp):
@@ -354,6 +362,9 @@ def _decay_fwd_kernel(sp, lower_bound):
 
             def chunk(r0, _):
                 f = x_ref[0, pl.ds(r0, sp.rows), cols].astype(_f32) + bias
+                if lower_bound is None:
+                    g_ref[0, j, pl.ds(r0, sp.rows), :] = -rate * _softplus(f)
+                    return
                 g_ref[0, j, pl.ds(r0, sp.rows), :] = lower_bound * jax.nn.sigmoid(rate * f)
 
             _chunks(sp, chunk)
@@ -369,6 +380,11 @@ def _decay_bwd_kernel(sp, lower_bound):
 
             def chunk(r0, acc):
                 f = x_ref[0, pl.ds(r0, sp.rows), cols].astype(_f32) + bias
+                if lower_bound is None:
+                    dg = dg_ref[0, j, pl.ds(r0, sp.rows), :]
+                    df = -dg * rate * jax.nn.sigmoid(f)
+                    dx_ref[0, pl.ds(r0, sp.rows), cols] = df.astype(dx_ref.dtype)
+                    return acc[0] + _fold(df), acc[1] - _fold(dg * _softplus(f))
                 s = jax.nn.sigmoid(rate * f)
                 ds = dg_ref[0, j, pl.ds(r0, sp.rows), :] * lower_bound * (s * (1.0 - s))
                 df = ds * rate
@@ -448,7 +464,7 @@ def _decay_bwd(how, res, dg):
 _decay.defvjp(_decay_fwd, _decay_bwd)
 
 
-def decay_in(x, dt_bias, a_log, lower_bound: float, *,
+def decay_in(x, dt_bias, a_log, lower_bound: Optional[float], *,
              interpret: InterpretArg = None):
     """``ops.kda.decay_in`` by the kernels: ``x`` (B, T, H d) a projection,
     ``dt_bias`` (H d,), ``a_log`` (H,); float32 (B, H, T, d).
@@ -459,7 +475,10 @@ def decay_in(x, dt_bias, a_log, lower_bound: float, *,
     rate = jnp.repeat(jnp.exp(a_log.astype(_f32)), x.shape[-1] // heads)[None]
     _, (x, bias, rate) = vary_together(x, bias, rate)
     return _decay(
-        x, bias, rate, (heads, float(lower_bound), default_interpret(interpret))
+        x, bias, rate, (
+            heads, None if lower_bound is None else float(lower_bound),
+            default_interpret(interpret),
+        ),
     )
 
 

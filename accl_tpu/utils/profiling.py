@@ -108,6 +108,11 @@ drops ``op_name``, so a reader joins the two by instruction name
                          attention call (flash kernels, or the XLA forms)
 ``accl.attn::window``    the same under a ``LayerKind.window``: a sliding
                          layer's attention call
+``accl.attn::gqa_proj``  the same where the stack has KDA layers beside it
+                         (a gated grouped-query layer without position
+                         among linear-attention ones): q, k and v's
+                         projections, the gate a channel and ``wo``, what
+                         is round ``accl.attn::core`` there
 ``accl.attn::latent``    ``_latent_attn_partial`` (a latent mixer, MLA): the
                          five projections (four where q has no latent),
                          the latent norms, the rope, the head-wise gate

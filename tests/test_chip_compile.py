@@ -604,3 +604,114 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
     assert "constant(0.0009995)" in text and "constant(4096)" in probe
     assert len(instructions(text)) > 5000
     assert instructions(text) == instructions(probe)
+
+
+#: sha256 of the instructions of ``train_ling3_t8192_b2``'s KDA expert layer
+#: (its pattern's layer 5 alone, 2 x 8,192 tokens, under ``remat``) compiled
+#: for the described chip ON THE PARENT of PR 48 (42dead0), without what is
+#: the file's and not the program's (an op's metadata; a kernel's body, which
+#: carries the line numbers of the file that built it: ``tests/
+#: test_solar2.py`` holds the kernels' own jaxprs to the parent's).  PR 48
+#: edits the four files this layer runs (``ops/kda.py``, ``ops/pallas/
+#: kda.py``, ``ops/pallas/kda_mixer.py``, ``_kda_partial``) for a gate
+#: without a bound; the bounded gate is chosen statically, so this program
+#: is the parent's.
+LING3_KDA_LAYER = "54269a1afb4bb13f0f0d146ca4d9744ecfbe6975b8825b560511a55f21f76e27"
+
+
+def test_ling3_kda_layer_is_the_parents_program(v5e, monkeypatch):
+    import hashlib
+
+    text = _step_text("train_ling3_t8192_b2", 1, v5e, monkeypatch, layers=(5,))
+    assert "kda_fwd" in text and "kda_bwd" in text and "kda_decay_fwd" in text
+    strip = re.compile(r', metadata=\{[^}]*\}|"body":"[^"]*"')
+    lines = [
+        strip.sub("", line) for line in text.splitlines()
+        if re.match(r"\s*(?:ROOT )?%", line)
+    ]
+    assert len(lines) > 4000
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == LING3_KDA_LAYER
+
+
+def test_kda_kernels_compile_under_the_unbounded_gate(one_chip):
+    """``train_solar2_t8192_b1``'s KDA core alone, forward and gradient, for
+    any ``g <= 0`` (the split by halving: sublane rolls, seven masked
+    products, the inverse by halving): 64 head-sequences of 8,192 tokens,
+    ``dk = dv = 128``; and its softplus decay chain, a bf16 projection of
+    8,192 x 8,192."""
+    from accl_tpu.ops.pallas import kda, kda_mixer
+
+    B, H, T, d = 1, 64, 8192, 128
+    rows = jax.ShapeDtypeStruct((B, H, T, d), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((B, H, T), jnp.float32, sharding=one_chip)
+    core = lambda *a: kda.kda(*a, safe=True, interpret=False)
+    text = jax.jit(jax.grad(
+        lambda *a: core(*a).sum(), argnums=(0, 1, 2, 3, 4)
+    )).lower(rows, rows, rows, rows, beta).compile().as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+    _chain_compiles(
+        "kda_decay", lambda *a: kda_mixer.decay_in(*a, None, interpret=False),
+        [((B, T, H * d), jnp.bfloat16), ((H * d,), jnp.float32),
+         ((H,), jnp.float32)],
+        one_chip,
+    )
+
+
+def test_solar2_step_takes_the_kernels_under_the_unbounded_gate(
+    v5e, monkeypatch
+):
+    """The GQA layer and one KDA layer of the cell's four, 1 x 8,192 tokens,
+    under ``remat`` as the cell runs: the KDA core is ``kda_fwd`` (twice:
+    the forward and its replay) and ``kda_bwd`` under ``accl.attn::kda``,
+    nothing of it in a loop's body; the chains round it the kernels of
+    ``kda_mixer`` under ``accl.attn::kda_proj``, the decay's in its
+    softplus form; the softmax layer's core the flash kernels under
+    ``accl.attn::core``, its projections, gate and ``wo`` under
+    ``accl.attn::gqa_proj``; the held rows, 4,096 wide in a buffer of
+    16,384 (134 MB: past what ``_gathers_win`` calls near), placed by the
+    ``place_rows`` kernel; the grouped matmuls the three kernels; and no
+    array a square of the length (memory linear in T): the largest are a
+    held bank's matrix and the float32 logits."""
+    from accl_tpu.models import moe
+    from perfbench import scope_ops
+    from perfbench.drivers import train_steps_ling3
+
+    assert not moe._gathers_win(16384, 8192 * 8, 4096, 2)
+    compiled = _step("train_solar2_t8192_b1", 2, v5e, monkeypatch, layers=(0, 1))
+    text = compiled.as_text()
+    entry = scope_ops.scopes_of(text)
+    every = train_steps_ling3.scoped_instructions(text)
+    for scope in ("accl.attn::kda", "accl.attn::kda_proj", "accl.attn::core",
+                  "accl.attn::gqa_proj", "accl.moe::route", "accl.moe::dispatch",
+                  "accl.moe::experts", "accl.moe::combine", "accl.moe::shared"):
+        assert entry.get(scope), scope
+    core, chains = entry["accl.attn::kda"], entry["accl.attn::kda_proj"]
+    assert sum(n.startswith("kda_fwd") for n in core) == 2
+    assert sum(n.startswith("kda_bwd") for n in core) == 1
+    assert not any(n.startswith("while") for n in core)
+    assert set(core) == set(every["accl.attn::kda"])
+    kernels = {
+        "kda_in_fwd": 6, "kda_in_bwd": 3, "kda_decay_fwd": 2,
+        "kda_decay_bwd": 1, "kda_out_fwd": 2, "kda_out_bwd": 1,
+    }
+    for kernel, count in kernels.items():
+        assert sum(n.startswith(kernel) for n in chains) == count, kernel
+    assert any("flash_fwd" in n for n in entry["accl.attn::core"])
+    assert any("flash_bwd" in n for n in entry["accl.attn::core"])
+    for kernel in ("gmm_fwd", "gmm_dlhs", "gmm_drhs"):
+        assert any(kernel in n for n in entry["accl.moe::experts"]), kernel
+    assert re.search(r"%place_rows\S* = bf16\[8192,4096\]", text)
+    assert re.search(r"bf16\[16384,4096\]", text)
+    sizes = sorted({
+        int(np.prod([int(n) for n in dims.split(",")]))
+        for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
+    })
+    # one head's scores over the whole length would be 2^26 elements a head
+    # and 2^32 for the 64: nothing here is; the largest arrays are a held
+    # bank's matrix of 40 x 4,096 x 1,280 (ISSUE 48 expected the float32
+    # logits' 8,192 x 24,576, which come next, 4% smaller)
+    assert sizes[-1] == 40 * 4096 * 1280 and sizes[-2] == 8192 * 24576
+    assert not re.search(r"\[8192,8192,\d+\]|\[64,8192,8192\]", text)
+    # the whole cell, four layers: 4,686,238,208 bytes of scratch (my compile
+    # for the described chip, PR 48); this cut has half the layers
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4_686_238_208

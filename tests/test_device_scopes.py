@@ -43,6 +43,7 @@ WHERE = {
     "accl.attn::kda_proj": "ling3",
     "accl.attn::ssd": "nemotron3",
     "accl.attn::mamba_proj": "nemotron3",
+    "accl.attn::gqa_proj": "solar2",       # a softmax layer beside KDA ones
     "accl.attn::blockdiff": "sdar",
     "accl.diffusion::noise": "sdar",
     "accl.loss::diffusion": "sdar",

@@ -58,6 +58,18 @@ PARENT = {
         "de019a6df551f727da152016aa0ab751b90dc1cac47e9b49924762845ca3e741",
         None,
     ),
+    # PR 48 edits the four files the Ling-3.0 step runs (``ops/kda.py``,
+    # ``ops/pallas/kda.py``, ``ops/pallas/kda_mixer.py`` and
+    # ``_kda_partial``) for a decay gate without a bound, chosen statically:
+    # the bounded gate's step is the one it was at PR 48's parent (42dead0),
+    # where both digests were taken (a rehearsal's head width runs
+    # ``_kda_partial`` and the XLA form of the core and of the chains;
+    # ``tests/test_chip_compile.py`` holds the layer at the cell's widths,
+    # where the kernels run)
+    ("train_ling3_t8192_b2", None): (
+        "34eccf5413322d7fe4d5ae29c09e679a20eed91bb8706fe5b16020fa635bb3ad",
+        "18c31e0451d195443276ec318dff12bfeb585a88a498401214d222d8ecfa2095",
+    ),
 }
 
 
