@@ -27,6 +27,7 @@ from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
@@ -128,6 +129,13 @@ class DeltaAttention:
 #: the softplus values ``init_params`` draws the unbounded KDA gate's
 #: ``dt_bias`` for, log-uniform a channel (its docstring there says why)
 KDA_UNBOUNDED_DT = (1e-3, 16.0)
+
+#: the name (``jax.ad_checkpoint.checkpoint_name``) of what a mixer keeps
+#: of a block's forward when the block is rematerialised (``cfg.remat``):
+#: ``_layer_blocks`` saves the values under this name and replays the rest.
+#: The two recurrent mixers name their bf16 input projections; without
+#: ``remat`` the name is the identity.
+KEPT_UNDER_REMAT = "accl.remat::mixer_proj"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -281,7 +289,17 @@ class TransformerConfig:
     # rematerialize each block on the backward pass (jax.checkpoint):
     # trades ~30% more FLOPs in exchange for activation memory that no
     # longer scales with n_layers — the standard TPU recipe for fitting
-    # larger models/batches (HBM is the bottleneck, MXU has headroom)
+    # larger models/batches (HBM is the bottleneck, MXU has headroom).
+    # A block's forward is replayed but for the values its mixer NAMES
+    # (``KEPT_UNDER_REMAT``, a static property of the mixer's code, no
+    # option): the KDA mixer's five bf16 input projections (q, k, v, decay
+    # gate, output gate: 5 x B T d_inner x 2 bytes a layer, 671 MB at 8,192
+    # x 8,192) and the Mamba-2 mixer's five (z, x, B, C, dt: 304 MB a block
+    # at 8,192 x 18,560), which their chains' kernels save as their only
+    # residual anyway and whose replay is a matmul at the MXU's peak.
+    # Attention and latent mixers, ``wo``'s product, the cores' saved sets
+    # and the FFNs name nothing: no one static rule fits them into every
+    # cell's memory (``_layer_blocks``)
     remat: bool = False
     # Megatron-style sequence parallelism: between blocks, activations
     # live SEQUENCE-sharded over tp (T/tp per chip), the row-parallel
@@ -1785,6 +1803,13 @@ def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
         return attn.transpose(0, 2, 1, 3).reshape(B, T, -1) @ lp["wo"]
 
 
+def _kept_under_remat(projection):
+    """``projection`` under the name ``_layer_blocks``' checkpoint policy
+    saves: the identity but inside a rematerialised block, whose backward
+    then reads this array where it would have multiplied it out again."""
+    return checkpoint_name(projection, KEPT_UNDER_REMAT)
+
+
 def _kda_partial(h, lp, n_heads_local, kda):
     """The KDA mixer (:class:`DeltaAttention`) on a full-sequence
     activation, heads column-parallel: the row-parallel PARTIAL output.
@@ -1803,17 +1828,28 @@ def _kda_partial(h, lp, n_heads_local, kda):
     other shape XLA's fusions).  Everything but the core runs under the
     device scope ``accl.attn::kda_proj``, the core (from normalised q, k,
     v, the log-decay and beta to ``o``: ``ops.kda.kda_chunked``) under
-    ``accl.attn::kda``."""
+    ``accl.attn::kda``.
+
+    Under ``cfg.remat`` the block keeps the five bf16 projections the
+    chains read (``h wq``, ``h wk``, ``h wv``, the decay gate's and the
+    output gate's, the FINAL product where a gate goes through a rank:
+    ``KEPT_UNDER_REMAT``; 5 x B T d_inner x 2 bytes, 671,088,640 a layer at
+    2 x 8,192 x 4,096 or 1 x 8,192 x 8,192): the backward replays the
+    chains and the core from them and multiplies none of them out twice.
+    Nothing else is named: ``o`` and the core's saved set (1.07 GB a layer)
+    do not fit six layers, ``wo``'s product and beta's are replayed."""
     from ..ops.kda import conv_in, decay_in, gated_out, kda_chunked
 
     H = n_heads_local
     f32 = jnp.float32
     with device_scope("accl.attn::kda_proj"):
-        q = conv_in(h @ lp["wq"], lp["conv_q"], H, unit=True,
+        proj = lambda w: _kept_under_remat(h @ lp[w])
+        q = conv_in(proj("wq"), lp["conv_q"], H, unit=True,
                     scale=(lp["wq"].shape[1] // H) ** -0.5)
-        k = conv_in(h @ lp["wk"], lp["conv_k"], H, unit=True)
-        v = conv_in(h @ lp["wv"], lp["conv_v"], H, unit=False)
-        through = lambda w: (
+        k = conv_in(proj("wk"), lp["conv_k"], H, unit=True)
+        v = conv_in(proj("wv"), lp["conv_v"], H, unit=False)
+        # a gate through a rank keeps its FINAL product
+        through = lambda w: _kept_under_remat(
             h @ lp[w] if w in lp else (h @ lp[w + "_a"]) @ lp[w + "_b"]
         )
         bound = kda["lower_bound"]
@@ -1847,19 +1883,26 @@ def _mamba2_partial(h, lp, mamba):
     ``ops.ssd.ssd_mixer``, which picks from the shapes the Mosaic kernels
     ``ssd_fwd`` / ``ssd_bwd`` that keep a chunk's decay squares and the
     running state in VMEM, or the XLA form round its head-major
-    transposes) runs under ``accl.attn::ssd``."""
+    transposes) runs under ``accl.attn::ssd``.
+
+    Under ``cfg.remat`` the block keeps the five bf16 projections (``h
+    wz``, ``h wx``, ``h wb``, ``h wc``, ``h wdt``: ``KEPT_UNDER_REMAT``;
+    B T (2 d_inner + 2 G N + H) x 2 bytes, 304,087,040 a block at 8,192 x
+    18,560): the backward replays the chains and the core from them and
+    multiplies none of them out twice; ``wo``'s product is replayed."""
     from ..ops.ssd import conv_silu, gated_group_norm, ssd_mixer
 
     N = mamba["state"]
     G = lp["wb"].shape[1] // N
     f32 = jnp.float32
     with device_scope("accl.attn::mamba_proj"):
-        z = h @ lp["wz"]
-        x = conv_silu(h @ lp["wx"], lp["conv_x"], lp["bias_x"])
-        b = conv_silu(h @ lp["wb"], lp["conv_b"], lp["bias_b"])
-        c = conv_silu(h @ lp["wc"], lp["conv_c"], lp["bias_c"])
+        proj = lambda w: _kept_under_remat(h @ lp[w])
+        z = proj("wz")
+        x = conv_silu(proj("wx"), lp["conv_x"], lp["bias_x"])
+        b = conv_silu(proj("wb"), lp["conv_b"], lp["bias_b"])
+        c = conv_silu(proj("wc"), lp["conv_c"], lp["bias_c"])
         dt = jax.nn.softplus(
-            (h @ lp["wdt"]).astype(f32) + lp["dt_bias"].astype(f32)
+            proj("wdt").astype(f32) + lp["dt_bias"].astype(f32)
         )                                                 # (B, T, H)
         a = -jnp.exp(lp["a_log"].astype(f32))
     with device_scope("accl.attn::ssd"):
@@ -2230,10 +2273,21 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
 def _layer_blocks(block, cfg):
     """``block`` for each layer of the pattern: the layout's block as it
     is where every layer is alike, and with the layer's own window and
-    rotation where ``cfg.layers`` gives them."""
+    rotation where ``cfg.layers`` gives them.  Under ``cfg.remat`` each is
+    rematerialised on the backward pass but for what its mixer names
+    ``KEPT_UNDER_REMAT`` (the KDA and Mamba-2 mixers' bf16 input
+    projections, 671 and 304 MB a layer at the train cells' widths: what
+    their chains save as their residual anyway, so that no block multiplies
+    them out twice); a block whose mixer names nothing is replayed whole.
+    One static rule, no budget: those projections fit every layer of every
+    cell, which nothing larger (a core's saved set) does."""
+    remat = partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(KEPT_UNDER_REMAT),
+    )
     if cfg.layers is None:
         if cfg.remat:
-            block = jax.checkpoint(block)
+            block = remat(block)
         return [block] * cfg.n_layers
     blocks = [
         partial(
@@ -2242,7 +2296,7 @@ def _layer_blocks(block, cfg):
         )
         for kind in cfg.layers
     ]
-    return [jax.checkpoint(b) for b in blocks] if cfg.remat else blocks
+    return [remat(b) for b in blocks] if cfg.remat else blocks
 
 
 def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):

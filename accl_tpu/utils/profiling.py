@@ -141,7 +141,10 @@ drops ``op_name``, so a reader joins the two by instruction name
                          log-decay's gate) and ``kda_out_fwd`` /
                          ``kda_out_bwd`` (the output norm and gate, back
                          from head-major); under ``remat`` a layer runs
-                         each forward kernel twice.  At any other shape
+                         each forward kernel twice, but the five matmuls
+                         whose bf16 products they read once: the block
+                         keeps those by name (``transformer.
+                         KEPT_UNDER_REMAT``).  At any other shape
                          XLA's fusions.  Float32 in either lowering,
                          inside the kernels too (only the projections
                          and their cotangents have the matmuls' type)
@@ -169,7 +172,9 @@ drops ``op_name``, so a reader joins the two by instruction name
                          kernels ``mamba_in_fwd`` / ``mamba_in_bwd`` and
                          ``mamba_out_fwd`` / ``mamba_out_bwd`` of
                          ``ops/pallas/mamba_mixer.py`` at whole blocks of
-                         1,024 columns, XLA's fusions at any other width)
+                         1,024 columns, XLA's fusions at any other width;
+                         under ``remat`` the five projections are kept by
+                         name and multiplied out once, the chains replay)
 ``accl.attn::blockdiff`` ``_attn_partial`` under ``TransformerConfig.
                          diffusion``: the attention call on ``[noisy ;
                          clean]`` under the block-diffusion layout (the

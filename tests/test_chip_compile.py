@@ -364,6 +364,32 @@ def _step(cell_name, n_layers, device, monkeypatch, layers=None, lr=None):
     return step.lower(params, tok, tok).compile()
 
 
+def _entry_instructions(text):
+    """``{name: the rest of its line}`` of the ENTRY computation's
+    instructions."""
+    start = text.find("\nENTRY ")
+    return dict(re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = (.*)$", text[start: text.find("\n}", start)], re.M
+    ))
+
+
+def _products(text, names, shape):
+    """Those of the ENTRY instructions ``names`` that are a fusion holding a
+    matmul (a ``convolution``, as the chip has it) and writing a ``shape``:
+    under a mixer's scope and at a projection's shape, the projection's
+    forward products (and whatever cotangent has that shape)."""
+    made = _entry_instructions(text)
+    found = []
+    for name in names:
+        fusion = re.match(r"(\(.*?\)|\S+) fusion\(.*?calls=(%[\w.\-]+)", made[name])
+        if fusion is None or shape not in fusion[1]:
+            continue
+        body = text[text.find(f"\n{fusion[2]} ("):]
+        if " convolution(" in body[: body.find("\n}")]:
+            found.append(name)
+    return found
+
+
 def _table_ops(text, V, D):
     """``{instruction: its text}`` of the ENTRY instructions that write a
     ``bf16[V, D]``."""
@@ -445,10 +471,14 @@ def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
     inputs' size there but the kernels' operands and results; the mixer's
     float32 chains round the core are the kernels of ``kda_mixer`` under
     ``accl.attn::kda_proj`` (q, k and v in, the decay in, out: each
-    forward kernel twice, each backward once), and XLA makes no float32
+    forward kernel twice, each backward once: the chains still replay),
+    but the five bf16 projections they read are multiplied out ONCE, kept
+    under ``remat`` by name (PR 49; the parent's text has each twice), and
+    XLA makes no float32
     array of a projection's size there, let alone a backward
     convolution's stack of four (``f32[4,2,8192,4096]``); the step's
-    scratch no more than the parent's, the latent core the flash kernels,
+    scratch no more than the parent's and the kept projections, the latent
+    core the flash kernels,
     the held rows placed by the kernel, and no array a square of the
     length (memory linear in T)."""
     from perfbench import scope_ops
@@ -471,12 +501,12 @@ def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
     }
     for kernel, count in kernels.items():
         assert sum(n.startswith(kernel) for n in chains) == count * kda_layers, kernel
+    # q, k, v and the two gates from the hidden state once each (the parent
+    # of PR 49 replayed them: 11), and ``wo``'s cotangent, the same shape
+    assert len(_products(text, chains, "bf16[2,8192,4096]")) == (5 + 1) * kda_layers
     # whatever else under either scope is as large as q is a view of a
     # kernel's operand or result, no array of its own
-    start = text.find("\nENTRY ")
-    made = dict(re.findall(
-        r"^\s*(?:ROOT )?%(\S+) = (.*)$", text[start: text.find("\n}", start)], re.M
-    ))
+    made = _entry_instructions(text)
     for name in core + chains:
         shape, op = re.match(r"(\(.*?\)|\S+) ([\w-]+)\(", made[name]).groups()
         assert "f32[4,2,8192,4096]" not in shape, name
@@ -486,6 +516,13 @@ def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
     # bytes of scratch (10,363,852,800 under the XLA form of the core;
     # 4,722,778,112 when the chains' kernels came)
     assert compiled.memory_analysis().temp_size_in_bytes <= 6_177_251_840
+    # and no more than PR 49's parent (4,722,778,112) with the five kept
+    # projections of the one KDA layer, 5 x 2 x 8,192 x 4,096 x 2 bytes (this
+    # cut reads 4,722,423,296: its peak is elsewhere; the whole cell's six
+    # layers add 3.35 GB to 5.37)
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        4_722_778_112 + 671_088_640 * kda_layers
+    )
     assert entry["accl.attn::latent"]
     assert any("flash_fwd" in n for n in entry["accl.attn::mla"])
     assert any("flash_bwd" in n for n in entry["accl.attn::mla"])
@@ -513,7 +550,9 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
     under the XLA form) anywhere in the text; the mixer round it under
     ``accl.attn::mamba_proj``, its two float32 chains the kernels of
     ``mamba_mixer`` there (x, B and C in, the gated norm out: each forward
-    kernel twice, each backward once), XLA making no float32 array of x's
+    kernel twice, each backward once: the chains still replay), the five bf16
+    projections they and ``dt`` read multiplied out ONCE, kept under ``remat``
+    by name (PR 49; the parent's text has each twice), XLA making no float32 array of x's
     size under the scope, no copy of y into the norm's ``(B, T, G, C / G)``
     view (``f32[1024,8,8,1024]`` by token tile) and no such view at all;
     ``W_down`` and ``W_up`` under ``accl.moe::latent``; the held rows are
@@ -549,12 +588,15 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
     }
     for kernel, count in kernels.items():
         assert sum(n.startswith(kernel) for n in chains) == count * mamba_blocks, kernel
+    # z and x from the hidden state once each and ``wo``'s cotangent, the
+    # same shape (the parent of PR 49: 5); B and C once each (4); ``dt``'s
+    # product, fused with its softplus into the heads' rows, once (2)
+    assert len(_products(text, chains, "bf16[1,8192,8192]")) == (2 + 1) * mamba_blocks
+    assert len(_products(text, chains, "bf16[1,8192,1024]")) == 2 * mamba_blocks
+    assert len(_products(text, chains, "f32[1,128,8192]")) == mamba_blocks
     # whatever else under the scope is as large as x in float32 is a view of
     # a kernel's operand or result, no array of its own
-    start = text.find("\nENTRY ")
-    made = dict(re.findall(
-        r"^\s*(?:ROOT )?%(\S+) = (.*)$", text[start: text.find("\n}", start)], re.M
-    ))
+    made = _entry_instructions(text)
     for name in chains:
         shape, op = re.match(r"(\(.*?\)|\S+) ([\w-]+)\(", made[name]).groups()
         if "f32[1,8192,8192]" in shape:
@@ -582,6 +624,12 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
     # the same cut: 2,976,605,696 bytes (4,698,582,016 under the XLA form of
     # the core; 2,068,348,928 when the chains' kernels came)
     assert compiled.memory_analysis().temp_size_in_bytes <= 2_976_605_696
+    # and no more than PR 49's parent (2,068,348,928) with the five kept
+    # projections of the one Mamba-2 block, 8,192 x 18,560 x 2 bytes (this
+    # cut reads 2,071,933,952; the whole cell's five blocks add 1.43 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        2_068_348_928 + 304_087_040 * mamba_blocks
+    )
 
     # the step whose update the cell's check reads the gradient from (the
     # driver's UPDATE_PROBE_RATE) is this step with ONE number changed: the
@@ -602,7 +650,9 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
         if re.match(r"\s*(?:ROOT )?%", line)
     ]
     assert "constant(0.0009995)" in text and "constant(4096)" in probe
-    assert len(instructions(text)) > 5000
+    # (4,898 instructions since PR 49 took the projections' replay out of
+    # the program; over 5,000 before)
+    assert len(instructions(text)) > 4500
     assert instructions(text) == instructions(probe)
 
 
@@ -615,8 +665,13 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
 #: edits the four files this layer runs (``ops/kda.py``, ``ops/pallas/
 #: kda.py``, ``ops/pallas/kda_mixer.py``, ``_kda_partial``) for a gate
 #: without a bound; the bounded gate is chosen statically, so this program
-#: is the parent's.
-LING3_KDA_LAYER = "54269a1afb4bb13f0f0d146ca4d9744ecfbe6975b8825b560511a55f21f76e27"
+#: is the parent's.  Re-recorded in PR 49 ON ITS OWN TREE, by intent: under
+#: ``remat`` the layer keeps the mixer's five bf16 projections by name, so the
+#: backward's replay of their matmuls is gone from the program (4,354
+#: instructions -> 4,212; PR 48's parent read 54269a1a...f76e27, and PR 49's
+#: parent, a202f4f, still did).  The rehearsal's digests of ``tests/
+#: test_shared_step_text.py`` (``remat`` off) did not move.
+LING3_KDA_LAYER = "648af705e99cbed3e600f24a3049f199103b2b8c37d71e2eeebc47a5554a2dc1"
 
 
 def test_ling3_kda_layer_is_the_parents_program(v5e, monkeypatch):
@@ -665,7 +720,9 @@ def test_solar2_step_takes_the_kernels_under_the_unbounded_gate(
     the forward and its replay) and ``kda_bwd`` under ``accl.attn::kda``,
     nothing of it in a loop's body; the chains round it the kernels of
     ``kda_mixer`` under ``accl.attn::kda_proj``, the decay's in its
-    softplus form; the softmax layer's core the flash kernels under
+    softplus form, and the five bf16 projections they read (q, k, v and the
+    two gates' FINAL products out of rank 128) multiplied out ONCE, kept
+    under ``remat`` by name (PR 49); the softmax layer's core the flash kernels under
     ``accl.attn::core``, its projections, gate and ``wo`` under
     ``accl.attn::gqa_proj``; the held rows, 4,096 wide in a buffer of
     16,384 (134 MB: past what ``_gathers_win`` calls near), placed by the
@@ -696,6 +753,9 @@ def test_solar2_step_takes_the_kernels_under_the_unbounded_gate(
     }
     for kernel, count in kernels.items():
         assert sum(n.startswith(kernel) for n in chains) == count, kernel
+    # the five named projections once each (the parent of PR 49: twice) and
+    # ``wo``'s cotangent, the same shape
+    assert len(_products(text, chains, "bf16[1,8192,8192]")) == 5 + 1
     assert any("flash_fwd" in n for n in entry["accl.attn::core"])
     assert any("flash_bwd" in n for n in entry["accl.attn::core"])
     for kernel in ("gmm_fwd", "gmm_dlhs", "gmm_drhs"):
@@ -715,3 +775,9 @@ def test_solar2_step_takes_the_kernels_under_the_unbounded_gate(
     # the whole cell, four layers: 4,686,238,208 bytes of scratch (my compile
     # for the described chip, PR 48); this cut has half the layers
     assert compiled.memory_analysis().temp_size_in_bytes <= 4_686_238_208
+    # and no more than PR 49's parent at this cut (4,527,492,608) with the five
+    # kept projections of the one KDA layer, 5 x 8,192 x 8,192 x 2 bytes (this
+    # cut reads 4,527,686,144; the whole cell's three layers add 1.34 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        4_527_492_608 + 671_088_640
+    )
