@@ -1132,6 +1132,10 @@ class GangCommandRing:
         refill window): the drainer blocks on the status global — THE
         device status words — then completes every slot's requests
         with its per-slot retcode."""
+        import jax
+
+        from ...ops.cmdring import status_view
+
         gang = self.gang
 
         def window_done():
@@ -1141,14 +1145,13 @@ class GangCommandRing:
                     session.parks.remove(park)
 
         def waiter(park=park, st=st):
-            import jax
-
-            from ...ops.cmdring import status_view
-
-            jax.block_until_ready(st)
-            park.status = status_view(st)[: len(park.plans)]
-            self._settle_window(session, park)
-            park.event.set()
+            with annotate("accl.ring::wait", window=park.window_id):
+                jax.block_until_ready(st)
+            with annotate("accl.ring::status", window=park.window_id):
+                park.status = status_view(st)[: len(park.plans)]
+            with annotate("accl.ring::settle", window=park.window_id):
+                self._settle_window(session, park)
+                park.event.set()
 
         def on_ready(overlap_ns, depth, ready_ns, park=park, t0=t0):
             sv = park.status

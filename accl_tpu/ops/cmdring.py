@@ -63,6 +63,7 @@ from ..constants import (
     CmdOpcode,
 )
 from .. import wire as wirecodec
+from ..utils.profiling import annotate
 from . import wire as devwire
 from .pallas.attention import attn_hop_partial
 from .pallas.ring import hop_source
@@ -413,16 +414,18 @@ def run_windows(windows, mesh, shape: WindowShape):
 
     nwin = len(windows)
     size = mesh.devices.size
-    prog = _windows_program(_mesh_key(mesh), shape.key(), nwin)
-    tiled = np.concatenate(
-        [np.asarray(w[0], np.int32) for w in windows], axis=0
-    )
-    slots_dev = jax.device_put(
-        np.tile(tiled, (size, 1)),
-        NamedSharding(mesh, PartitionSpec(AXIS)),
-    )
-    flat = [g for _, gs in windows for g in gs]
-    out = prog(slots_dev, *flat)
+    with annotate("accl.ring::slots"):
+        tiled = np.concatenate(
+            [np.asarray(w[0], np.int32) for w in windows], axis=0
+        )
+        slots_dev = jax.device_put(
+            np.tile(tiled, (size, 1)),
+            NamedSharding(mesh, PartitionSpec(AXIS)),
+        )
+    with annotate("accl.ring::program"):
+        prog = _windows_program(_mesh_key(mesh), shape.key(), nwin)
+        flat = [g for _, gs in windows for g in gs]
+        out = prog(slots_dev, *flat)
     status, results = out[0], list(out[1:])
     depth = shape.depth
     return status, [

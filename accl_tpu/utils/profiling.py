@@ -77,12 +77,30 @@ microsecond while no trace is being taken.
                          written-root ledger, then the chaos hook
                          (``window``: its id)
 ``accl.ring::assemble``  ``_launch_window``: the operand globals
-``accl::cmdring[n]``     ``_launch_window``: the window's ONE program call
+``accl::cmdring[n]``     ``_launch_window``: ``ops/cmdring.py``
+                         ``run_windows``, the window's ONE program call
+                         with what it needs first; holds the next two
+``accl.ring::slots``     ``run_windows``: the slot words' ``np.concatenate``
+                         / ``np.tile`` and their ``jax.device_put`` onto
+                         the mesh
+``accl.ring::program``   ``run_windows``: the window program's ``lru_cache``
+                         lookup and its one call
 ``accl.ring::adopt``     ``_launch_window``: ``_adopt_out_shards`` a slot
 ``accl.ring::park``      ``_dispatch_window``: ``_park_window``
                          (``window``)
 ``accl.window::ready``   ``overlap.py`` ``InflightWindow._complete``: the
-                         drainer's ``block_until_ready``
+                         drainer in the parked entry's waiter.  A blocking
+                         gang call's is ``block_until_ready`` alone; a
+                         window's holds the next three
+``accl.ring::wait``      ``_park_window``'s waiter, on the drainer:
+                         ``jax.block_until_ready`` on the status global
+                         alone (``window``, as ``::encode`` and ``::park``
+                         carry it: the drainer's spans of a window join
+                         the launching thread's by id)
+``accl.ring::status``    the same: ``status_view``, the device-to-host
+                         read of one shard of the status words (``window``)
+``accl.ring::settle``    the same: ``_settle_window`` under the ring's
+                         lock, and the park's event (``window``)
 ``accl.window::complete`` ``overlap.py``: requests completed, telemetry
                          record, done callbacks
 ======================== ==================================================
@@ -91,10 +109,12 @@ Only the ``accl::`` names are read by the benchmark's ``engine_span_us``,
 ``facade_self_us`` and ``breakdown``; the stage spans are ``accl.<layer>::``
 so that those keep reading what they read (``perfbench/stage_spans.py``
 reads the blocking call's stages, ``perfbench/window_spans.py`` the
-batched window's).  A blocking call outside a batch carries no
-``accl.batch::`` or ``accl.ring::`` span.  The telemetry plane's window
-log (``cmdring.py`` ``_log_window``, basis ``"host"``) is on the
-``perf_counter`` clock and is NOT what the benchmark reads.
+batched window's, ``perfbench/runtime_spans.py`` what the two opened
+spans hold and the runtime's own events inside the dispatches).  A
+blocking call outside a batch carries no ``accl.batch::`` or
+``accl.ring::`` span.  The telemetry plane's window log (``cmdring.py``
+``_log_window``, basis ``"host"``) is on the ``perf_counter`` clock and
+is NOT what the benchmark reads.
 
 Device scopes (:func:`device_scope`), inside the jitted train step and
 forward.  The name lands in every covered instruction's ``op_name``

@@ -29,6 +29,11 @@ OPS = ["allreduce", "allreduce", "reduce_scatter", "allgather",
 SHAPES = {"allreduce": (1, 1), "allgather": (1, WORLD),
           "reduce_scatter": (WORLD, 1)}
 RING = ["plan", "deps", "encode", "assemble", "cmdring", "adopt", "park"]
+#: what ``accl::cmdring[n]`` and the window's ``accl.window::ready`` hold
+OPENED = {"cmdring": ["accl.ring::slots", "accl.ring::program"],
+          "ready": ["accl.ring::wait", "accl.ring::status",
+                    "accl.ring::settle"]}
+FIVE = OPENED["cmdring"] + OPENED["ready"]
 
 
 def _short(name):
@@ -68,7 +73,9 @@ def recorded(tmp_path_factory):
                     q.check()
 
     def blocking(a, r):
-        a.allreduce(bufs[r][0][0], bufs[r][0][1], N)
+        gate.wait()
+        with jax.profiler.TraceAnnotation("bench::small::allreduce"):
+            a.allreduce(bufs[r][0][0], bufs[r][0][1], N)
 
     counter = group[0].engine.gang.interactions
     try:
@@ -94,6 +101,7 @@ def recorded(tmp_path_factory):
             a.deinit()
     return {"batched": trace_spans(str(batched)),
             "blocking": trace_spans(str(alone)), "dir": str(batched),
+            "blocking_dir": str(alone),
             "interactions": {"off": off, "on": on}}
 
 
@@ -142,7 +150,8 @@ def test_ring_stages_lie_in_one_ring_batch_on_one_thread_in_order(recorded):
     for t, ring in rings:
         events = rank_threads[t]
         assert ring[3] == {"comm": "0", "n": str(len(OPS))}
-        inside = trace_inside(events, ring)
+        inside = [e for e in trace_inside(events, ring)
+                  if e[0] not in OPENED["cmdring"]]
         assert [_short(e[0]) for e in inside] == RING
         assert inside[RING.index("cmdring")][0] == f"accl::cmdring[{len(OPS)}]"
         assert trace_in_order(inside)
@@ -155,6 +164,7 @@ def test_ring_stages_lie_in_one_ring_batch_on_one_thread_in_order(recorded):
     # every ring stage of the trace lies in one of those spans
     everything = [e for ev in recorded["batched"].values() for e in ev]
     assert sum(e[0].startswith(("accl.ring::", "accl::cmdring"))
+               and e[0] not in FIVE
                for e in everything) == WINDOWS + staged
     windows = sorted(int(e[3]["window"]) for e in everything
                      if e[0] == "accl.ring::park")
@@ -165,6 +175,7 @@ def test_the_drainer_learns_and_completes_each_window(recorded):
     by_thread = recorded["batched"]
     rank_threads = _rank_threads(by_thread)
     (drainer,) = [ev for t, ev in by_thread.items() if t not in rank_threads]
+    drainer = [e for e in drainer if e[0] not in OPENED["ready"]]
     assert [e[0] for e in drainer] == WINDOWS * [
         "accl.window::ready", "accl.window::complete"
     ]
@@ -179,6 +190,56 @@ def test_the_drainer_learns_and_completes_each_window(recorded):
         # the next window's flushes start after this one is completed
         if k + 1 < WINDOWS:
             assert all(f[k + 1][1] >= done[2] for f in flushes)
+
+
+def test_the_program_call_opens_into_slots_then_program(recorded):
+    """Inside the ONE ``accl::cmdring[n]`` a window, on its thread: the
+    slot words' put, then the program's lookup and call, and nothing
+    else of the program's."""
+    rank_threads = _rank_threads(recorded["batched"])
+    found = 0
+    for events in rank_threads.values():
+        for cmdring in events:
+            if not cmdring[0].startswith("accl::cmdring["):
+                continue
+            inside = trace_inside(events, cmdring)
+            assert [e[0] for e in inside] == OPENED["cmdring"]
+            assert trace_in_order(inside)
+            assert all(e[3] == {} for e in inside)
+            found += 1
+    assert found == WINDOWS
+    everything = [e for ev in recorded["batched"].values() for e in ev]
+    for name in OPENED["cmdring"]:  # one a window, none elsewhere
+        assert len(_of(everything, name)) == WINDOWS
+
+
+def test_the_windows_ready_opens_into_wait_status_settle_by_id(recorded):
+    """Inside the ONE ``accl.window::ready`` a window, on the drainer:
+    the wait, the status words' read, the settle, in order, each with
+    the ``window`` stat of that window's ``accl.ring::encode``."""
+    by_thread = recorded["batched"]
+    rank_threads = _rank_threads(by_thread)
+    (drainer,) = [ev for t, ev in by_thread.items() if t not in rank_threads]
+    encodes = sorted((e for ev in rank_threads.values()
+                      for e in _of(ev, "accl.ring::encode")),
+                     key=lambda e: e[1])
+    readies = _of(drainer, "accl.window::ready")
+    assert len(readies) == len(encodes) == WINDOWS
+    for encode, ready in zip(encodes, readies):
+        inside = trace_inside(drainer, ready)
+        assert [e[0] for e in inside] == OPENED["ready"]
+        assert trace_in_order(inside)
+        assert all(e[3] == encode[3] for e in inside)
+    everything = [e for ev in by_thread.values() for e in ev]
+    for name in OPENED["ready"]:  # one a window, on the drainer alone
+        assert len(_of(everything, name)) == len(_of(drainer, name)) == WINDOWS
+
+
+@pytest.mark.parametrize("name", FIVE)
+def test_a_blocking_collective_emits_none_of_the_five(recorded, name):
+    everything = [e for ev in recorded["blocking"].values() for e in ev]
+    assert len(_of(everything, "accl.window::ready")) == 1
+    assert not _of(everything, name)
 
 
 def test_the_flushes_of_a_window_share_a_batch_stat(recorded):
@@ -238,6 +299,57 @@ def test_the_benchmarks_reader_understands_the_recorded_windows(recorded):
     assert all(table[name] is not None for name in (ws.RING, *ws.PARTS))
 
 
+def test_the_runtime_reader_understands_the_recorded_windows(recorded):
+    """``perfbench/runtime_spans.py`` on the trace recorded here: no
+    window ``window_spans.group`` kept is lost to the runtime's events,
+    the two opened spans are found with their parts inside them, the
+    drainer's three join the launching thread's by id, and the CPU
+    client's execute event lies inside the program call (no device
+    plane on the CPU: the two lags have nothing to read)."""
+    from perfbench import (runtime_spans as rs, stage_spans, trace_reduce,
+                           window_spans as ws)
+
+    path = trace_reduce.find_xplane(recorded["dir"])
+    windows = ws.group(rs.load(path))
+    assert len(windows) == len(ws.group(stage_spans.load(path))) == WINDOWS
+    for w in windows:
+        cmdring, ready = ws.one(w, ws.CMDRING), ws.one(w, ws.READY)
+        assert 0 < rs.slots_put(w) + rs.program_call(w) <= cmdring[2]
+        assert 0 < rs.window_execute(w) <= rs.program_call(w)
+        assert rs.inside(w, ws.one(w, rs.PROGRAM), ("PjitFunction(",))
+        assert rs.inside(w, ws.one(w, rs.SLOTS), ("DevicePutWithSharding",))
+        wait = rs.duration(w, rs.WAIT)
+        assert 0 < wait + rs.status_read(w) <= ready[2]
+        assert rs.joined_by_id(w) is True
+        assert rs.window_pickup(w) is not None
+        assert rs.gate_spread(w) >= 0
+        assert rs.launch_lag(w) is None and rs.wait_lag(w) is None
+    table = rs.report_windows(windows)
+    assert table["windows"] == WINDOWS
+    assert table["joined_by_id_share"] == 1.0
+    assert all(table[k] is not None for k in (
+        rs.SLOTS, rs.PROGRAM, rs.WAIT, rs.STATUS, rs.SETTLE, "execute",
+        "pickup", "gate_spread", "arrival_spread"))
+
+
+def test_the_runtime_reader_understands_the_recorded_blocking_call(recorded):
+    """The blocking call has no new span: its three readers read what
+    was there (the dispatch with the client's execute event inside it,
+    the hand-over to the drainer, the ``bench::`` starts)."""
+    from perfbench import runtime_spans as rs, stage_spans, trace_reduce
+
+    (call,) = stage_spans.group(rs.load(
+        trace_reduce.find_xplane(recorded["blocking_dir"])
+    ))
+    assert len(call["bench"]) == WORLD
+    dispatch = stage_spans.one(call, rs.DISPATCH)
+    assert 0 < rs.call_execute(call) <= dispatch[2]
+    assert rs.completion_pickup(call) is not None
+    assert rs.gate_spread(call) >= 0
+    table = rs.report_calls([call])
+    assert table["calls"] == 1 and table["execute"] is not None
+
+
 def test_every_recorded_span_has_its_row_in_the_span_table(recorded):
     """``utils/profiling.py``'s docstring lists every host span: each
     name the two traces hold is there (``accl::<op>`` and
@@ -250,7 +362,7 @@ def test_every_recorded_span_has_its_row_in_the_span_table(recorded):
             } == {"accl.batch::" + s for s in ("flush", "submit", "drain")
                   } | {"accl.ring::" + s for s in
                        ("batch", "plan", "deps", "encode", "assemble",
-                        "adopt", "park")}
+                        "adopt", "park")} | set(FIVE)
     table = profiling.__doc__
     for name in names:
         if name.startswith("accl::cmdring["):
