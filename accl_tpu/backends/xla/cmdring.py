@@ -8,7 +8,10 @@ of the per-communicator ring and dispatched as ONE program — the window
 program decodes the slot words on the device, executes every slot and
 returns the per-slot status words.  A refill is a doorbell is a program
 launch: ``refills == doorbells == dispatches``, one host interaction a
-window, on every platform.
+window, on every platform.  A window's control words make no trip of
+their own: slot words the chips already hold are not put again (the
+ring's ``KeptSlots``; ``stats()`` counts ``slot_hits`` / ``slot_puts``),
+and the status words' copy to the host is asked for at launch.
 
 The opcode space is the FULL warm set (``constants.CMDRING_OPCODES``):
 allreduce, bcast, reduce-scatter, allgather, alltoall, barrier, and
@@ -37,6 +40,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from ...cmdring import (
@@ -64,6 +68,7 @@ from ...constants import (
     dtype_to_numpy,
 )
 from ...membership import CircuitBreaker
+from ...ops import cmdring as devring
 from ...overlap import drain_deadline_s
 from ...utils.profiling import annotate
 
@@ -202,6 +207,9 @@ class GangCommandRing:
         # the p2p pair's non-source ranks): first use dispatches the
         # zeros program (counted), warm windows reuse with no dispatch
         self._zeros: Dict[tuple, object] = {}
+        # the slot words the chips already hold, a window's by its
+        # content: a warm window puts nothing (ops/cmdring.py)
+        self._kept_slots = devring.KeptSlots()
         # lifetime counters (telemetry_report()["cmdring"]).  One
         # counter backs both the refill and doorbell stats keys: every
         # refill rings the doorbell exactly once, as a program dispatch.
@@ -308,6 +316,10 @@ class GangCommandRing:
                 "refills": self.refills,
                 "doorbells": self.refills,  # every refill rings once
                 "dispatches": self.dispatches,
+                # windows whose slot words were already on the chips /
+                # were put: hits + puts == dispatches
+                "slot_hits": self._kept_slots.hits,
+                "slot_puts": self._kept_slots.puts,
                 "slots": self.slots_enqueued,
                 "wraps": self.wraps,
                 "resets": self.resets,
@@ -395,7 +407,9 @@ class GangCommandRing:
     def reset(self) -> None:
         """soft_reset: realign every session's seqn/head at 0 (the gang
         has already drained the in-flight window — the full-flush
-        contract)."""
+        contract) and forget the slot words kept on the chips with
+        them."""
+        self._kept_slots.clear()
         with self._lock:
             self._sessions.clear()
             self._inflight_windows = 0
@@ -840,11 +854,11 @@ class GangCommandRing:
         try:
             gang.interactions.bump()  # THE refill: one host interaction
             # for the whole window
-            st = self._launch_window(
+            st, st_shard = self._launch_window(
                 comm, mesh, shape, park, slots_np, window
             )
             with annotate("accl.ring::park", window=park.window_id):
-                self._park_window(comm, session, park, st, t0)
+                self._park_window(comm, session, park, st, st_shard, t0)
         except BaseException:
             # the window never parked: the armed count must not leak
             # (the parked/no-spin posture is part of the contract)
@@ -1058,9 +1072,9 @@ class GangCommandRing:
         Results are adopted at launch, in issue order (a pointer swap,
         or a deferred store layered on the buffer), so successive
         windows writing one buffer land newest-last.
-        Returns the status global the park's waiter blocks on."""
-        from ...ops import cmdring as devring
-
+        Returns the status global the park's waiter blocks on and the
+        one shard of it the waiter then reads (its copy to the host
+        already asked for)."""
         gang = self.gang
         with annotate("accl.ring::assemble"):
             globals_ = [
@@ -1068,8 +1082,8 @@ class GangCommandRing:
                 for calls, lead, plan in window
             ]
         with annotate(f"accl::cmdring[{len(window)}]"):
-            st, results = devring.run_windows(
-                [(slots_np, globals_)], mesh, shape
+            st, st_shard, results = devring.run_windows(
+                [(slots_np, globals_)], mesh, shape, self._kept_slots
             )
         with self._lock:
             self.dispatches += 1
@@ -1078,7 +1092,7 @@ class GangCommandRing:
                 gang._adopt_out_shards(
                     results[0][k], calls, plan, park.reqs_per_slot[k]
                 )
-        return st
+        return st, st_shard
 
     def _zeros_shard(self, w: int, npdt, dev):
         key = (int(w), np.dtype(npdt).str, dev)
@@ -1100,7 +1114,6 @@ class GangCommandRing:
         if op != Operation.BARRIER and "p2p" not in plan:
             g, _prep, _raw = self.gang._assemble_flat(calls, plan, mesh)
             return g
-        import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ...ops import driver as opdriver
@@ -1127,15 +1140,24 @@ class GangCommandRing:
         )
 
     # -- completion ----------------------------------------------------------
-    def _park_window(self, comm, session, park, st, t0) -> None:
+    @staticmethod
+    def _window_status(park, words) -> np.ndarray:
+        """``park.status`` from the device's status words of that
+        window: the retcodes as read; the ``seqn`` the device echoes is
+        window-relative (the kept slot words carry 0 … n-1), so the
+        window's base — the first ``seqn`` the host encoded — goes back
+        on, wrapped as ``_encode`` wraps it."""
+        status = np.array(words[: len(park.plans)], np.int64)
+        status[:, 0] = (
+            park.slots_info[0]["seqn"] + status[:, 0]
+        ) & 0x7FFFFFFF
+        return status.astype(np.int32)
+
+    def _park_window(self, comm, session, park, st, st_shard, t0) -> None:
         """Hand the window's completion to the in-flight window (the
         refill window): the drainer blocks on the status global — THE
-        device status words — then completes every slot's requests
-        with its per-slot retcode."""
-        import jax
-
-        from ...ops.cmdring import status_view
-
+        device status words — then reads them from ``st_shard`` and
+        completes every slot's requests with its per-slot retcode."""
         gang = self.gang
 
         def window_done():
@@ -1144,11 +1166,13 @@ class GangCommandRing:
                 if park in session.parks:
                     session.parks.remove(park)
 
-        def waiter(park=park, st=st):
+        def waiter(park=park, st=st, st_shard=st_shard):
             with annotate("accl.ring::wait", window=park.window_id):
                 jax.block_until_ready(st)
             with annotate("accl.ring::status", window=park.window_id):
-                park.status = status_view(st)[: len(park.plans)]
+                park.status = self._window_status(
+                    park, devring.status_view(st_shard)
+                )
             with annotate("accl.ring::settle", window=park.window_id):
                 self._settle_window(session, park)
                 park.event.set()

@@ -10,6 +10,17 @@ slot (``lax.all_gather`` wire move + :func:`slot_epilogue`) and returns
 the per-slot ``(seqn, retcode)`` status words next to the results.  One
 window, one program launch, on every platform.
 
+A window's control words cost no round trip of their own
+(:func:`run_windows`).  Down: slot words the chips already hold are not
+put again — :class:`KeptSlots` keeps the device array of a window's
+words by the mesh and the words' CONTENT, every field but ``seqn``,
+which rides window-relative (0 … n-1; the device only echoes it into
+the status, the host adds the window's base back).  Back: the copy to
+the host of the ONE status shard the drainer reads is asked for as the
+program's results arrive, so :func:`status_view` finds the literal
+there or on its way.  The program is the same either way, and so are the
+words it runs on.
+
 Split of responsibilities:
 
 * host half (slot codec + window shape): ``accl_tpu/cmdring.py``
@@ -35,6 +46,8 @@ dispatch above ``CMDRING_MAX_PAYLOAD_BYTES``.
 
 from __future__ import annotations
 
+import threading
+from collections import OrderedDict
 from functools import lru_cache
 from typing import Optional
 
@@ -75,6 +88,7 @@ __all__ = [
     "encode_slot",
     "encode_window",
     "fused_slot_eligible",
+    "KeptSlots",
     "run_windows",
     "slot_epilogue",
     "status_view",
@@ -400,42 +414,105 @@ def _windows_program(mesh_id: int, shape_key: tuple, nwin: int):
     return _smap(mesh, body, spec_in, spec_out)
 
 
-def run_windows(windows, mesh, shape: WindowShape):
+#: windows of slot words a ring keeps on its chips (least recently sent
+#: goes first; a window of eight is 352 bytes a chip)
+KEPT_WINDOWS = 32
+
+
+class KeptSlots:
+    """The slot words a ring's chips already hold: one device array a
+    window, by the mesh and the words' content — every field but
+    ``seqn``, which the kept words carry window-relative (0 … n-1).
+    A job's window is the same slots every step, so a warm window sends
+    nothing down; a window whose words differ in ANY other field (the
+    count, the function, a root, a peer, an fparam, a compressed lane's
+    seed, the chaos plane's poisoned opcode) is another key and is put.
+    Owned by the engine's ring, which empties it where it drops its
+    sessions; ``hits`` and ``puts`` count the windows served each way."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._kept: "OrderedDict[tuple, jax.Array]" = OrderedDict()
+        self.hits = 0
+        self.puts = 0
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._kept)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._kept.clear()
+
+    def on_device(self, rows: np.ndarray, mesh):
+        """The ``(size * n, words)`` slot global of ``rows`` (``(n,
+        words)`` int32, ``seqn`` as the host encoded it) on ``mesh``,
+        with ``seqn`` window-relative: kept if these words were sent
+        before, else put now and kept."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from .driver import AXIS, _mesh_key
+
+        words = np.array(rows, np.int32)
+        words[:, _F["seqn"]] = np.arange(len(words), dtype=np.int32)
+        key = (_mesh_key(mesh), words.tobytes())
+        with self._lock:
+            dev = self._kept.get(key)
+            if dev is not None:
+                self._kept.move_to_end(key)
+                self.hits += 1
+                return dev
+        dev = jax.device_put(
+            np.tile(words, (mesh.devices.size, 1)),
+            NamedSharding(mesh, PartitionSpec(AXIS)),
+        )
+        with self._lock:
+            self._kept[key] = dev
+            while len(self._kept) > KEPT_WINDOWS:
+                self._kept.popitem(last=False)
+            self.puts += 1
+        return dev
+
+
+def run_windows(windows, mesh, shape: WindowShape, kept: KeptSlots):
     """Dispatch a backlog of refill windows as ONE program (the engine
     sends one window a call).  ``windows`` is a list of
     ``(slots_np, slot_globals)`` where ``slot_globals`` are assembled
-    flat per-slot globals (the zero-copy assembly of the gang engine).
-    Returns ``(status_global, results)`` with ``results[w][i]`` the
-    slot's result global; the caller blocks on the status global — THE
-    device status words — at its drain points."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    from .driver import AXIS, _mesh_key
+    flat per-slot globals (the zero-copy assembly of the gang engine);
+    ``kept`` is the ring's :class:`KeptSlots`.
+    Returns ``(status_global, status_shard, results)`` with
+    ``results[w][i]`` the slot's result global.  The caller blocks on
+    the status global — THE device status words — at its drain points
+    and then reads ``status_shard`` through :func:`status_view`: the one
+    addressable shard whose copy to the host was asked for here, as the
+    results arrived.  Its ``seqn`` column is relative to the call's
+    first slot (what the kept words carry)."""
+    from .driver import _mesh_key
 
     nwin = len(windows)
-    size = mesh.devices.size
     with annotate("accl.ring::slots"):
-        tiled = np.concatenate(
-            [np.asarray(w[0], np.int32) for w in windows], axis=0
-        )
-        slots_dev = jax.device_put(
-            np.tile(tiled, (size, 1)),
-            NamedSharding(mesh, PartitionSpec(AXIS)),
+        slots_dev = kept.on_device(
+            np.concatenate([w[0] for w in windows], axis=0), mesh
         )
     with annotate("accl.ring::program"):
         prog = _windows_program(_mesh_key(mesh), shape.key(), nwin)
         flat = [g for _, gs in windows for g in gs]
         out = prog(slots_dev, *flat)
     status, results = out[0], list(out[1:])
+    # every rank's copy is identical by construction: the drainer reads
+    # one.  `.data` makes a new array each time and a new array has no
+    # copy under way, so THIS object is the one handed on.
+    status_shard = status.addressable_shards[0].data
+    status_shard.copy_to_host_async()
     depth = shape.depth
-    return status, [
+    return status, status_shard, [
         results[w * depth:(w + 1) * depth] for w in range(nwin)
     ]
 
 
-def status_view(status_global) -> np.ndarray:
-    """The drainer's read of the device status words: one addressable
-    shard (every rank's copy is identical by construction) as a host
-    ``(nwin * depth, 2)`` int32 array of ``(seqn, retcode)``."""
-    shard = status_global.addressable_shards[0].data
-    return np.asarray(shard).reshape(-1, 2)
+def status_view(status_shard) -> np.ndarray:
+    """The drainer's read of the device status words: the shard
+    :func:`run_windows` returned, whose copy to the host is under way or
+    done, as a host ``(nwin * depth, 2)`` int32 array of
+    ``(seqn, retcode)``."""
+    return np.asarray(status_shard).reshape(-1, 2)

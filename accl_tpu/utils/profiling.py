@@ -80,9 +80,12 @@ microsecond while no trace is being taken.
 ``accl::cmdring[n]``     ``_launch_window``: ``ops/cmdring.py``
                          ``run_windows``, the window's ONE program call
                          with what it needs first; holds the next two
-``accl.ring::slots``     ``run_windows``: the slot words' ``np.concatenate``
-                         / ``np.tile`` and their ``jax.device_put`` onto
-                         the mesh
+``accl.ring::slots``     ``run_windows``: the slot words' device array
+                         from the ring's ``KeptSlots`` (PR 51): the words
+                         made window-relative and looked up by mesh and
+                         content; a warm window ends there (a hit), only
+                         words never sent before are ``np.tile``d and
+                         ``jax.device_put`` onto the mesh (a put)
 ``accl.ring::program``   ``run_windows``: the window program's ``lru_cache``
                          lookup and its one call
 ``accl.ring::adopt``     ``_launch_window``: ``_adopt_out_shards`` a slot
@@ -97,8 +100,12 @@ microsecond while no trace is being taken.
                          alone (``window``, as ``::encode`` and ``::park``
                          carry it: the drainer's spans of a window join
                          the launching thread's by id)
-``accl.ring::status``    the same: ``status_view``, the device-to-host
-                         read of one shard of the status words (``window``)
+``accl.ring::status``    the same: ``status_view`` of the ONE status shard
+                         whose copy to the host ``run_windows`` asked for
+                         at launch (PR 51: ``np.asarray`` of a literal
+                         that is there or on its way, no second round
+                         trip), then the window's base put back on the
+                         window-relative ``seqn`` column (``window``)
 ``accl.ring::settle``    the same: ``_settle_window`` under the ring's
                          lock, and the park's event (``window``)
 ``accl.window::complete`` ``overlap.py``: requests completed, telemetry

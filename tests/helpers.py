@@ -4,7 +4,10 @@ mpirun-launched per-rank host processes."""
 from __future__ import annotations
 
 import glob
+import json
 import os
+import subprocess
+import sys
 import threading
 from typing import Callable, List, Sequence
 
@@ -141,3 +144,91 @@ def trace_inside(events, outer):
 def trace_in_order(events):
     """Each span ends before the next starts."""
     return all(a[2] <= b[1] for a, b in zip(events, events[1:]))
+
+
+# ---------------------------------------------------------------------------
+# perfbench.run: the benchmark's command, rehearsed on the CPU
+# ---------------------------------------------------------------------------
+
+CHECKOUT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+
+#: what a rehearsal may print: counts of the program, never a time, a
+#: rate or a share of the device
+REHEARSAL_COUNTS = {"plan_hit_share", "interactions_per_call",
+                    "interactions_per_window", "ring_fallbacks", "peak_hbm"}
+
+#: ``(cell, trace)`` rehearsals by the file that runs them
+#: (``tests/test_bench_rehearsal_<group>.py``; the Nemotron-3 controls
+#: are a file of their own): groups of about equal cost, because one
+#: file is one worker under ``--dist loadfile`` and the rehearsals in
+#: ONE file were most of tier-1's wall (ROADMAP D14).  Seconds beside a
+#: case are the driver's, on six workers, at PR 50.
+#: ``tests/test_bench_harness.py`` holds this to the manifest: a new
+#: cell joins the lightest group.
+REHEARSALS = {
+    "solar2": [
+        ("train_solar2_t8192_b1", 0),      # 237
+        ("train_t8192_b1", 0),             # 18
+    ],
+    "recurrent": [
+        ("train_ling3_t8192_b2", 0),       # 92
+        ("train_sdar_t4096_b2", 0),        # 77
+        ("train_nemotron3_t8192_b1", 0),   # 73
+        ("coll_w4_sweep", 0),              # 19
+    ],
+    "rest": [
+        ("train_dsv2_t4096_b1", 0),        # 61
+        ("train_trinity_t8192_b2", 0),     # 56
+        ("train_olmoe_t4096_b2", 0),       # 48
+        ("train_t1024_b8", 1),             # 44
+        ("coll_w4_sweep", 1),              # 41
+        ("train_t1024_b8", 0),             # 23
+    ],
+}
+
+
+def nice_child(module: str, *args, timeout: float = 600, **env):
+    """``python -m <module> ...`` as the driver runs the benchmark: a
+    child process from the checkout.  ``--rehearse`` forces its own four
+    host devices, so this process's ``XLA_FLAGS`` stay here.  The child
+    compiles and runs whole train steps on every core it finds, beside
+    the other xdist workers whose tests wait on threads and sockets: it
+    runs at the lowest priority (``nice``), so that it takes the cores
+    they leave and none they want.  One file is one worker under
+    ``--dist loadfile``, so there is one such child a rehearsal file at
+    a time."""
+    base = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    return subprocess.run(
+        ["nice", "-n", "19", sys.executable, "-m", module, *args],
+        cwd=CHECKOUT, env=dict(base, **env),
+        capture_output=True, text=True, timeout=timeout,
+    )
+
+
+def perfbench(cell, *args, **env):
+    """``python -m perfbench.run --workload <cell> ...`` in a
+    :func:`nice_child`."""
+    return nice_child("perfbench.run", "--workload", cell, *args, **env)
+
+
+def check_rehearsal(cell: str, trace: int) -> None:
+    """One cell's CPU rehearsal ends correct and prints counts only."""
+    proc = perfbench(cell, "--seed", "3", "--seconds", "2",
+                     "--trace", str(trace), "--rehearse")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0, proc.stdout[-2000:]
+    assert line["attempted"] > 0
+    assert line["device"]["platform"] == "cpu"
+    values = {k: m["value"] for k, m in line["metrics"].items()}
+    assert set(values) <= REHEARSAL_COUNTS
+    if not trace:
+        assert values == {}
+    elif cell == "coll_w4_sweep":
+        # the facade's counts by the benchmark's own readers: every warm
+        # call a plan hit and one device interaction, a batched window
+        # one interaction, nothing off the ring
+        assert values == {
+            "plan_hit_share": 100.0, "interactions_per_call": 1.0,
+            "interactions_per_window": 1.0, "ring_fallbacks": 0,
+        }

@@ -4,17 +4,18 @@ number may hide the device.
 * ``chip_smoke.py``: each leg at a tiny size on the 8-device CPU mesh,
   and ``main`` refusing to run a leg off the TPU or on a ``device_kind``
   the peak table does not know;
-* ``perfbench.run``, the benchmark ``BENCHMARK.json`` declares: every
-  cell's CPU rehearsal (its driver, the program's main path and the
-  check against the plain reference, at tiny sizes), and the command's
-  refusal off the TPU;
+* ``perfbench.run``, the benchmark ``BENCHMARK.json`` declares: the
+  command's refusal off the TPU, and that every cell's CPU rehearsal
+  (its driver, the program's main path and the check against the plain
+  reference, at tiny sizes) is a case of some
+  ``tests/test_bench_rehearsal_*.py``;
 * the compile-cache helper, the peak table, and the refusals that took
   the place of quiet fallbacks (``xla_group`` past the device count,
   ``dryrun_multichip`` short of devices, the dist launcher on a TPU
   host, a failed native build).
 
-Everything but the rehearsals is stubbed or tiny; a rehearsal is the
-benchmark's own command in a child process, a cell a case.
+Everything here is stubbed or tiny; a rehearsal is the benchmark's own
+command in a child process, a cell a case, in those files.
 """
 
 import json
@@ -33,6 +34,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, os.path.abspath(ROOT))
 
 import chip_smoke  # noqa: E402
+from helpers import REHEARSALS, perfbench  # noqa: E402
 from perfbench import manifest  # noqa: E402
 
 
@@ -204,89 +206,25 @@ def test_smoke_main_fails_when_any_leg_failed(no_cache, monkeypatch, capsys):
 # ---------------------------------------------------------------------------
 
 
-#: what a rehearsal may print: counts of the program, never a time, a
-#: rate or a share of the device
-_COUNTS = {"plan_hit_share", "interactions_per_call",
-           "interactions_per_window", "ring_fallbacks", "peak_hbm"}
-_CELLS = [w["name"] for w in manifest.load()["workloads"]]
-
-
-def _perfbench(cell, *args, **env):
-    """``python -m perfbench.run --workload <cell> ...`` as the driver
-    runs it: a child process from the checkout.  ``--rehearse`` forces
-    its own four host devices, so this process's ``XLA_FLAGS`` stay
-    here.  The child compiles and runs whole train steps on every core
-    it finds, beside five other xdist workers whose tests wait on
-    threads and sockets: it runs at the lowest priority (``nice``), so
-    that it takes the cores they leave and none they want.  One file is
-    one worker under ``--dist loadfile``, so there is one such child at
-    a time."""
-    base = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    return subprocess.run(
-        ["nice", "-n", "19", sys.executable, "-m", "perfbench.run",
-         "--workload", cell, *args],
-        cwd=manifest.CHECKOUT, env=dict(base, **env),
-        capture_output=True, text=True, timeout=600,
+def test_every_cell_is_rehearsed_once_in_some_file():
+    """The rehearsals are ``tests/test_bench_rehearsal_*.py``, a group a
+    file (``helpers.REHEARSALS``): every cell of the manifest at
+    ``--trace 0``, the sweep and ``train_t1024_b8`` at ``--trace 1`` too,
+    none twice."""
+    cases = [c for group in REHEARSALS.values() for c in group]
+    assert sorted(cases) == sorted(
+        [(w["name"], 0) for w in manifest.load()["workloads"]]
+        + [("coll_w4_sweep", 1), ("train_t1024_b8", 1)]
     )
-
-
-@pytest.mark.parametrize(
-    "cell,trace",
-    [(cell, 0) for cell in _CELLS]
-    + [("coll_w4_sweep", 1), ("train_t1024_b8", 1)],
-)
-def test_benchmark_rehearsal_is_correct_and_prints_counts_only(cell, trace):
-    proc = _perfbench(cell, "--seed", "3", "--seconds", "2",
-                      "--trace", str(trace), "--rehearse")
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["failed"] == 0, proc.stdout[-2000:]
-    assert line["attempted"] > 0
-    assert line["device"]["platform"] == "cpu"
-    values = {k: m["value"] for k, m in line["metrics"].items()}
-    assert set(values) <= _COUNTS
-    if not trace:
-        assert values == {}
-    elif cell == "coll_w4_sweep":
-        # the facade's counts by the benchmark's own readers: every warm
-        # call a plan hit and one device interaction, a batched window
-        # one interaction, nothing off the ring
-        assert values == {
-            "plan_hit_share": 100.0, "interactions_per_call": 1.0,
-            "interactions_per_window": 1.0, "ring_fallbacks": 0,
-        }
-
-
-def test_nemotron3_controls_each_end_not_correct():
-    """``perfbench/controls_nemotron3.py``, rehearsed: the cell's own
-    ``judge`` at its committed limits ends correct on the sound reference
-    and not correct on every planted fault; a state the steps left
-    unchanged is past the update's two limits alone."""
-    from perfbench import controls_nemotron3 as controls
-
-    base = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    proc = subprocess.run(
-        ["nice", "-n", "19", sys.executable, "-m",
-         "perfbench.controls_nemotron3", "--seed", "5", "--rehearse"],
-        cwd=manifest.CHECKOUT, env=base, capture_output=True, text=True,
-        timeout=900,
-    )
-    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-2000:])
-    *lines, last = map(json.loads, proc.stdout.strip().splitlines())
-    assert last == {"controls": "ok", "wrong": []}
-    assert [l["control"] for l in lines] == ["sound", *controls.CONTROLS]
-    by_name = {l["control"]: l for l in lines}
-    assert all(l["correct"] == (name == "sound") for name, l in by_name.items())
-    unchanged = by_name["unchanged_state"]["check"]
-    assert unchanged["update_timed_worst"] == 1.0
-    assert abs(unchanged["update_probe_worst"] - 1.0) < 1e-6
-    assert len(by_name["unchanged_state"]["problems"]) == 1
-    assert "update" in by_name["unchanged_state"]["problems"][0]
+    for group in REHEARSALS:
+        assert os.path.exists(os.path.join(
+            os.path.dirname(__file__), f"test_bench_rehearsal_{group}.py"
+        ))
 
 
 def test_benchmark_refuses_to_run_off_the_tpu():
-    proc = _perfbench("train_t1024_b8", "--seed", "0", "--seconds", "1",
-                      "--trace", "0", JAX_PLATFORMS="cpu")
+    proc = perfbench("train_t1024_b8", "--seed", "0", "--seconds", "1",
+                     "--trace", "0", JAX_PLATFORMS="cpu")
     assert proc.returncode != 0 and "no TPU" in proc.stderr
     assert not proc.stdout.strip()
 

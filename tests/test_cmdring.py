@@ -20,6 +20,8 @@ from helpers import run_parallel
 from accl_tpu.constants import (
     CMDRING_FIELDS,
     CMDRING_SLOT_WORDS,
+    CMDRING_ST_BAD_OP,
+    CMDRING_ST_OK,
     CmdOpcode,
     FusedCompute,
     Operation,
@@ -1553,3 +1555,455 @@ def test_model_zoo_fused_helpers_ride_ring(g4):
         np.testing.assert_allclose(
             outs[r], 4.0 * q[r] * kv[(r - 1) % world], rtol=1e-6
         )
+
+
+# ---------------------------------------------------------------------------
+# a warm window's control words make no trip of their own: slot words the
+# chips already hold are not put again, the status copy is asked for at
+# launch.  Bytes and results, never a time.
+# ---------------------------------------------------------------------------
+
+
+def _slot_counts(ring):
+    st = ring.stats()
+    return st["slot_hits"], st["slot_puts"]
+
+
+def _ar_window(g, send, out, n, function=ReduceFunction.SUM, slots=3):
+    """One window of ``slots`` allreduces a rank; waits and checks."""
+    def work(a, r):
+        with a.batch():
+            reqs = [
+                a.allreduce(send[r], out[k][r], n, function=function,
+                            run_async=True)
+                for k in range(slots)
+            ]
+        for q in reqs:
+            assert q.wait(60)
+            q.check()
+
+    run_parallel(g, work)
+
+
+def _ar_buffers(g, n, slots=3):
+    vals = [np.arange(n, dtype=np.float32) + 7.0 * r for r in range(len(g))]
+    # a copy: the buffer wraps the array it is made from
+    send = [a.create_buffer_from(vals[r].copy()) for r, a in enumerate(g)]
+    out = [[a.create_buffer(n, np.float32) for a in g] for _ in range(slots)]
+    return vals, send, out
+
+
+def test_identical_warm_windows_put_their_slot_words_once(g4):
+    """K windows of the same slots: one put, K - 1 hits; every result is
+    numpy's on THAT window's operands; the status words carry the seqns
+    the host encoded (the device echoes them window-relative, the host
+    puts the window's base back) and advance by the window's length."""
+    ring = _ring(g4[0])
+    comm_id = g4[0]._world.id
+    n, slots, K = 24, 3, 5
+    vals, send, out = _ar_buffers(g4, n, slots)
+    ring._kept_slots.clear()
+    hits0, puts0 = _slot_counts(ring)
+    disp0 = ring.stats()["dispatches"]
+    prev = None
+    for w in range(K):
+        for r in range(4):  # new operand BYTES, the same slot words
+            send[r].data[:] = vals[r] + w
+            send[r].sync_to_device()
+        _ar_window(g4, send, out, n, slots=slots)
+        ref = np.sum([v + w for v in vals], axis=0)
+        for k in range(slots):
+            for r in range(4):
+                out[k][r].sync_from_device()
+                np.testing.assert_array_equal(out[k][r].data, ref)
+        status = ring.last_status(comm_id)
+        logged = ring.window_log(1)[0]["slots"]
+        assert [s["seqn"] for s in logged] == list(status[:, 0])
+        assert [s["retcode"] for s in logged] == [CMDRING_ST_OK] * slots
+        np.testing.assert_array_equal(np.diff(status[:, 0]), 1)
+        if prev is not None:
+            np.testing.assert_array_equal(status[:, 0] - prev[:, 0], slots)
+        prev = status
+    hits1, puts1 = _slot_counts(ring)
+    assert (puts1 - puts0, hits1 - hits0) == (1, K - 1)
+    assert ring.stats()["dispatches"] - disp0 == K
+    # what the chips hold is window-relative: 0 .. n-1, whatever the base
+    (kept,) = ring._kept_slots._kept.values()
+    words = np.asarray(kept.addressable_shards[0].data)
+    assert list(words[:, CMDRING_FIELDS["seqn"]]) == list(range(slots))
+
+
+def _differs_in_function(g):
+    n = 16
+    vals, send, out = _ar_buffers(g, n)
+
+    def check():
+        for o in out[0]:
+            o.sync_from_device()
+            np.testing.assert_array_equal(o.data, np.max(vals, axis=0))
+
+    return (
+        lambda: _ar_window(g, send, out, n, ReduceFunction.SUM),
+        lambda: _ar_window(g, send, out, n, ReduceFunction.MAX),
+        check,
+    )
+
+
+def _differs_in_count(g):
+    wide, n = 16, 8
+    vals, send, out = _ar_buffers(g, wide, slots=1)
+
+    def check():
+        for o in out[0]:
+            o.sync_from_device()
+            np.testing.assert_array_equal(
+                o.data[:n], np.sum(vals, axis=0)[:n]
+            )
+
+    return (
+        lambda: _ar_window(g, send, out, wide, slots=1),
+        lambda: _ar_window(g, send, out, n, slots=1),
+        check,
+    )
+
+
+def _differs_in_root(g):
+    n = 16
+    vals = [np.full(n, 30.0 + r, np.float32) for r in range(len(g))]
+    bufs = [a.create_buffer_from(vals[r]) for r, a in enumerate(g)]
+
+    def window(root):
+        def work(a, r):
+            bufs[r].data[:] = vals[r]
+            bufs[r].sync_to_device()
+            with a.batch():
+                q = a.bcast(bufs[r], n, root=root, run_async=True)
+            assert q.wait(60)
+            q.check()
+
+        return lambda: run_parallel(g, work)
+
+    def check():
+        for b in bufs:
+            b.sync_from_device()
+            np.testing.assert_array_equal(b.data, vals[3])
+
+    return window(1), window(3), check
+
+
+def _differs_in_peer(g):
+    """A pair slot's ``root`` (src) and ``peer`` (dst) swap."""
+    n = 16
+    vals = [np.arange(n, dtype=np.float32) + 100.0 * (r + 1)
+            for r in range(2)]
+    send = [a.create_buffer_from(vals[r]) for r, a in enumerate(g)]
+    got = [a.create_buffer(n, np.float32) for a in g]
+
+    def window(src):
+        def work(a, r):
+            with a.batch():
+                if r == src:
+                    q = a.send(send[r], n, dst=1 - r, tag=5, run_async=True)
+                else:
+                    q = a.recv(got[r], n, src=1 - r, tag=5, run_async=True)
+            assert q.wait(60)
+            q.check()
+
+        return lambda: run_parallel(g, work)
+
+    def check():
+        got[0].sync_from_device()
+        np.testing.assert_array_equal(got[0].data, vals[1])
+
+    return window(0), window(1), check
+
+
+def _differs_in_fparam(g):
+    n = 8
+    send, out, _ = _fused_apply_buffers(g, n=n)
+    grads = np.sum(
+        [np.arange(4 * n, dtype=np.float32) + r for r in range(4)], axis=0
+    ).reshape(4, n)
+
+    def window(lr):
+        def work(a, r):
+            with a.batch():
+                q = a.fused_apply(send[r], out[r], n, lr=lr, run_async=True)
+            assert q.wait(60)
+            q.check()
+
+        return lambda: run_parallel(g, work)
+
+    def check():
+        for r in range(4):
+            out[r].sync_from_device()
+            np.testing.assert_allclose(
+                out[r].data, (50.0 + r) - 0.25 * grads[r], rtol=1e-6
+            )
+
+    return window(0.5), window(0.25), check
+
+
+def _differs_in_flags(g):
+    """A compressed lane's stochastic-rounding seed rides ``flags`` and
+    advances a call: the same operands, another seed, other roundings."""
+    n = 512
+    rng = np.random.default_rng(5)
+    vals = [rng.standard_normal(n).astype(np.float32) for _ in g]
+    send = [a.create_buffer_from(vals[r]) for r, a in enumerate(g)]
+    out = [a.create_buffer(n, np.float32) for a in g]
+    seen = []
+
+    def window():
+        def work(a, r):
+            with a.batch():
+                q = a.allreduce(send[r], out[r], n, compress_dtype="int8",
+                                run_async=True)
+            assert q.wait(60)
+            q.check()
+
+        run_parallel(g, work)
+        out[0].sync_from_device()
+        seen.append(out[0].data.copy())
+
+    def check():
+        ref = np.sum(vals, axis=0)
+        assert np.max(np.abs(seen[-1] - ref)) < 0.25  # the int8 lane's
+        # a stale hit would round by the OLD seed: bit for bit the old sum
+        assert not np.array_equal(seen[-1], seen[-2])
+
+    return window, window, check
+
+
+@pytest.mark.parametrize("field,world,differ", [
+    ("function", 4, _differs_in_function),
+    ("count", 4, _differs_in_count),
+    ("root", 4, _differs_in_root),
+    ("peer", 2, _differs_in_peer),
+    ("fparam", 4, _differs_in_fparam),
+    ("flags", 4, _differs_in_flags),
+])
+def test_no_false_hit_on_a_window_that_differs_in_one_field(
+    field, world, differ
+):
+    """A warm window, then one whose slot words differ in ``field``
+    alone: it is a put, and its result is the NEW window's.  (A count
+    is also a width, so through the facade it changes the window's
+    shape too; ``test_kept_slots_key_is_every_word_but_seqn`` holds the
+    words alone.)"""
+    g = xla_group(world)
+    try:
+        ring = _ring(g[0])
+        first, second, check = differ(g)
+        first()
+        if field != "flags":  # a churning seed misses every window
+            hits0, _ = _slot_counts(ring)
+            first()
+            assert _slot_counts(ring)[0] - hits0 == 1
+        hits0, puts0 = _slot_counts(ring)
+        second()
+        hits1, puts1 = _slot_counts(ring)
+        assert (puts1 - puts0, hits1 - hits0) == (1, 0)
+        assert ring.stats()["fallbacks"] == {}
+        check()
+    finally:
+        for a in g:
+            a.deinit()
+
+
+def _mesh4():
+    from accl_tpu.ops.driver import make_mesh
+
+    return make_mesh(4)
+
+
+def _rows(base=0, **fields):
+    rows = np.stack([
+        encode_slot(base + k, CmdOpcode.ALLREDUCE, 64,
+                    function=ReduceFunction.SUM)
+        for k in range(3)
+    ])
+    for name, value in fields.items():
+        rows[1, CMDRING_FIELDS[name]] = value
+    return rows
+
+
+@pytest.mark.parametrize(
+    "field", [f for f in CMDRING_FIELDS if f != "seqn"]
+)
+def test_kept_slots_key_is_every_word_but_seqn(field):
+    """``seqn`` alone may differ between a window and the kept words
+    that serve it; any other word makes another key, and what is put is
+    the NEW words."""
+    from accl_tpu.ops.cmdring import KeptSlots
+
+    mesh, kept = _mesh4(), KeptSlots()
+    first = kept.on_device(_rows(), mesh)
+    assert kept.on_device(_rows(base=40), mesh) is first  # seqn: a hit
+    assert (kept.hits, kept.puts) == (1, 1)
+    other = _rows(base=43, **{field: 5})
+    dev = kept.on_device(other, mesh)
+    assert dev is not first and (kept.hits, kept.puts) == (1, 2)
+    want = other.copy()
+    want[:, CMDRING_FIELDS["seqn"]] = np.arange(3)
+    for shard in dev.addressable_shards:  # every chip holds the words
+        np.testing.assert_array_equal(np.asarray(shard.data), want)
+    assert kept.on_device(_rows(base=7), mesh) is first  # still kept
+
+
+def test_kept_slots_are_bounded_and_drop_the_least_recently_sent():
+    from accl_tpu.ops import cmdring as devring
+
+    mesh, kept = _mesh4(), devring.KeptSlots()
+    extra = 5
+    for i in range(devring.KEPT_WINDOWS + extra):
+        kept.on_device(_rows(count=100 + i), mesh)
+        kept.on_device(_rows(count=100), mesh)  # window 0 stays in use
+    assert len(kept) == devring.KEPT_WINDOWS
+    puts = kept.puts
+    kept.on_device(_rows(count=100), mesh)          # kept: used all along
+    kept.on_device(_rows(count=100 + devring.KEPT_WINDOWS + extra - 1), mesh)
+    assert kept.puts == puts
+    kept.on_device(_rows(count=101), mesh)          # the oldest: gone
+    assert kept.puts == puts + 1 and len(kept) == devring.KEPT_WINDOWS
+
+
+@pytest.mark.parametrize("how", ["reset", "soft_reset"])
+def test_reset_forgets_the_kept_slot_words(g4, how):
+    ring = _ring(g4[0])
+    n = 16
+    vals, send, out = _ar_buffers(g4, n)
+    _ar_window(g4, send, out, n)
+    hits0, _ = _slot_counts(ring)
+    _ar_window(g4, send, out, n)
+    assert _slot_counts(ring)[0] - hits0 == 1 and len(ring._kept_slots) > 0
+    if how == "reset":
+        ring.reset()
+    else:
+        run_parallel(g4, lambda a, r: a.soft_reset())
+    assert len(ring._kept_slots) == 0
+    hits0, puts0 = _slot_counts(ring)
+    _ar_window(g4, send, out, n)
+    assert _slot_counts(ring) == (hits0, puts0 + 1)
+    for o in out[0]:
+        o.sync_from_device()
+        np.testing.assert_array_equal(o.data, np.sum(vals, axis=0))
+
+
+@pytest.mark.chaos
+def test_chaos_corrupt_after_warm_up_is_a_put_then_a_hit(g4):
+    """The poisoned opcode is another content: that window is put and
+    judged BAD_OP on the device (its first slot INVALID_OPERATION, fast);
+    the next clean window finds its own words still kept, and is OK."""
+    from accl_tpu import ACCLError, ErrorCode, FaultPlan, FaultRule
+    from accl_tpu import contract as contract_mod
+
+    ring = _ring(g4[0])
+    comm_id = g4[0]._world.id
+    n = 16
+    vals, send, out = _ar_buffers(g4, n, slots=2)
+    _ar_window(g4, send, out, n, slots=2)
+    _ar_window(g4, send, out, n, slots=2)
+    hits0, puts0 = _slot_counts(ring)
+    contract_mod.install_fault_plan(FaultPlan(
+        rules=[FaultRule(action="corrupt", msg_type="RING", nth=1, count=1)],
+        seed=11,
+    ))
+    try:
+        with pytest.raises(ACCLError) as err:
+            _ar_window(g4, send, out, n, slots=2)
+        assert err.value.code == ErrorCode.INVALID_OPERATION
+    finally:
+        contract_mod.install_fault_plan(None)
+    assert _slot_counts(ring) == (hits0, puts0 + 1)
+    status = ring.last_status(comm_id)
+    # the device's own words: BAD_OP on the poisoned slot alone
+    assert list(status[:, 1]) == [CMDRING_ST_BAD_OP, CMDRING_ST_OK]
+    _ar_window(g4, send, out, n, slots=2)
+    assert _slot_counts(ring) == (hits0 + 1, puts0 + 1)
+    assert list(ring.last_status(comm_id)[:, 1]) == [CMDRING_ST_OK] * 2
+    for k in range(2):
+        for o in out[k]:
+            o.sync_from_device()
+            np.testing.assert_array_equal(o.data, np.sum(vals, axis=0))
+
+
+def test_the_waiter_reads_the_shard_whose_copy_was_asked_for(
+    g4, monkeypatch
+):
+    """``run_windows`` asks for ONE status shard's copy to the host and
+    hands on THAT array; the drainer reads that object, once a window
+    (``shard.data`` makes a new array each time, and a new array has no
+    copy under way)."""
+    from jax._src import array as jarray
+
+    from accl_tpu.ops import cmdring as devring
+
+    asked, read = [], []
+    ask = jarray.ArrayImpl.copy_to_host_async
+    view = devring.status_view
+
+    def recording_ask(self):
+        asked.append(self)
+        return ask(self)
+
+    def recording_view(shard):
+        read.append(shard)
+        assert shard.shape == (3, 2) and len(shard.sharding.device_set) == 1
+        return view(shard)
+
+    n = 16
+    vals, send, out = _ar_buffers(g4, n)
+    _ar_window(g4, send, out, n)  # warm: nothing else moves to the host
+    monkeypatch.setattr(jarray.ArrayImpl, "copy_to_host_async", recording_ask)
+    monkeypatch.setattr(devring, "status_view", recording_view)
+    for _ in range(2):
+        _ar_window(g4, send, out, n)
+    assert len(read) == 2 == len(asked)
+    assert all(r is a for r, a in zip(read, asked))
+
+
+#: sha256 of the lowered text (543 lines) of the window the sweep's
+#: ``--rehearse`` issues (eight slots of 2,048 bytes, float32, the
+#: four-device CPU mesh), as PR 29 read it and as the parent of PR 51
+#: (fd6a099) lowers it: the host stopped sending words the chips hold
+#: and asks for the status sooner; the program is the one it was
+REHEARSAL_WINDOW_TEXT = (
+    "2dd482c5a4747056e6c28a4cd896a9f1a9a1a6d32824a89f7c3da7f8d58ba5e1"
+)
+
+
+def test_window_program_text_is_the_parents():
+    import hashlib
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from accl_tpu.cmdring import WindowShape
+    from accl_tpu.ops import cmdring as devring
+    from accl_tpu.ops.driver import AXIS, _mesh_key
+
+    world, n = 4, 2048 // 4
+    ops = [Operation.ALLREDUCE, Operation.ALLREDUCE,
+           Operation.REDUCE_SCATTER, Operation.ALLGATHER] * 2
+    # the sweep's counts: a whole buffer reduced, a rank's share
+    # scattered or gathered
+    widths = [ring_widths(op, n if op == Operation.ALLREDUCE
+                          else n // world, world) for op in ops]
+    shape = WindowShape(
+        len(ops), [w[0] for w in widths], [w[1] for w in widths],
+        [None] * len(ops), np.float32,
+    )
+    mesh = _mesh4()
+    sh = NamedSharding(mesh, PartitionSpec(AXIS))
+    args = [jax.ShapeDtypeStruct(
+        (world * len(ops), CMDRING_SLOT_WORDS), np.int32, sharding=sh
+    )] + [
+        # raw committed shards: every send buffer is a whole slot wide,
+        # the allgather's too (the program slices it)
+        jax.ShapeDtypeStruct((world * n,), np.float32, sharding=sh)
+    ] * len(ops)
+    text = devring._windows_program(
+        _mesh_key(mesh), shape.key(), 1
+    ).lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == REHEARSAL_WINDOW_TEXT

@@ -426,7 +426,8 @@ def test_ring_stats_schema(ring4):
     ring = g[0].engine.telemetry_report()["cmdring"]
     kept = {
         "enabled", "mode", "lowering", "depth", "state", "refills",
-        "doorbells", "dispatches", "slots", "wraps", "resets",
+        "doorbells", "dispatches", "slot_hits", "slot_puts", "slots",
+        "wraps", "resets",
         "max_window", "occupancy", "ops", "fallbacks", "chaos_faults",
         "breakers", "slot_budgets", "comm_slots", "budgeted_windows",
         "windows_logged", "window_latency_sum_us",
@@ -435,6 +436,8 @@ def test_ring_stats_schema(ring4):
     assert set(ring) == kept
     assert ring["state"] in ("parked", "armed")
     assert ring["refills"] == ring["doorbells"] == ring["dispatches"] >= 1
+    # a window's slot words were on the chips already, or were put
+    assert ring["slot_hits"] + ring["slot_puts"] == ring["dispatches"]
     assert ring["lowering"] == "xla"
     win = ring["windows"][-1]
     assert set(win) == {
