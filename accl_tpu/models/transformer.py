@@ -117,14 +117,36 @@ class DeltaAttention:
     2 lets the transition's eigenvalue along k be negative (the family's
     ``allow_neg_eigval``).  ``gate_rank``: the two gate projections ``wf``
     and ``wg`` through that rank, ``wf_a wf_b`` and ``wg_a wg_b`` in the
-    tree (``None``: full rank, one matrix each)."""
+    tree (``None``: full rank, one matrix each).
+
+    GATED DELTANET (arXiv:2412.06464) is the same rule with a decay a
+    HEAD, three more properties of this description: ``v_dim`` is the width
+    of a head's v, output gate and ``wo`` rows where it is not ``head_dim``
+    (the state is ``head_dim x v_dim``; q and k stay ``head_dim``);
+    ``head_decay`` makes the log-decay ONE value a head a token, ``-exp(a_log)
+    softplus(h wa + dt_bias)`` with ``wa`` ``(d_model, n_heads)`` and
+    ``dt_bias`` a head in ``wf``'s place (no bound to set: ``lower_bound``
+    None, ``gate_rank`` None; ``ops.kda`` runs a gate of one column through
+    the same core, its heads padded to whole lanes where that costs at most
+    half again); ``out_gate`` ``"silu"`` gates the normed output by ``SiLU(h
+    wg)`` in the sigmoid's place."""
 
     head_dim: int
     conv: int = 4
     lower_bound: Optional[float] = -5.0
     beta_scale: float = 1.0
     gate_rank: Optional[int] = None
+    v_dim: Optional[int] = None
+    head_decay: bool = False
+    out_gate: str = "sigmoid"
 
+    def value_dim(self) -> int:
+        return self.head_dim if self.v_dim is None else self.v_dim
+
+
+#: the softplus values ``init_params`` draws a head's ``dt_bias`` for under
+#: ``DeltaAttention.head_decay``, log-uniform (the family's initial range)
+KDA_HEAD_DECAY_DT = (1e-3, 0.1)
 
 #: the softplus values ``init_params`` draws the unbounded KDA gate's
 #: ``dt_bias`` for, log-uniform a channel (its docstring there says why)
@@ -323,8 +345,12 @@ class TransformerConfig:
     # channel, the attention mixer's; ``"head"``: a value a head, ``wg``
     # ``(d_model, n_heads)``, the latent mixer's); ``post_norm`` norms each
     # half's output once more before the residual add (``ln1_post``,
-    # ``ln2_post``: four norms a layer); ``embed_scale`` multiplies the
-    # embedding rows (a muP model's ``sqrt(d_model)``); ``head_dim`` is
+    # ``ln2_post``: four norms a layer; ``"only"``: that norm ALONE, none
+    # before the sub-layer, ``x + norm(f(x))`` with ``f`` reading the
+    # residual stream itself, Olmo 2's reordered block: the tree then holds
+    # ``ln1_post`` / ``ln2_post`` and no ``ln1`` / ``ln2``); ``embed_scale``
+    # multiplies the embedding rows (a muP model's ``sqrt(d_model)``);
+    # ``head_dim`` is
     # the heads' width where it is not ``d_model // n_heads`` (q and o
     # are then ``n_heads * head_dim`` wide, :meth:`head_size`).  The
     # train and forward paths honour all of them; prefill/generate, the
@@ -339,7 +365,7 @@ class TransformerConfig:
     qk_norm: Union[bool, str] = False
     tie_head: bool = True
     attn_gate: Union[bool, str] = False
-    post_norm: bool = False
+    post_norm: Union[bool, str] = False
     embed_scale: float = 1.0
     head_dim: Optional[int] = None
     # the latent mixer (:class:`LatentAttention`, DeepSeek's MLA) in place
@@ -362,13 +388,14 @@ class TransformerConfig:
     latent: Optional[LatentAttention] = None
     # the KDA mixer's sizes (:class:`DeltaAttention`), for the layers of
     # the pattern whose ``LayerKind.mixer`` is ``"kda"``: a chunked gated
-    # delta rule with a decay a channel (``ops.kda``), forward and
-    # backward, on the train and forward paths (tp splits the heads: every
-    # matrix but ``wo`` column-parallel, the convolutions' taps, ``a_log``
-    # and ``dt_bias`` with their channels).  REFUSED by name where the
-    # latent mixer is, and for the same reason: prefill/generate would need
-    # the recurrent state as a cache, the ring would hand a state from rank
-    # to rank.
+    # delta rule with a decay a channel, or a head under ``head_decay``
+    # (Gated DeltaNet; ``ops.kda``), forward and backward, on the train and
+    # forward paths (tp splits the heads: every matrix but ``wo``
+    # column-parallel, ``wa`` and ``wbeta`` with the heads themselves, the
+    # convolutions' taps, ``a_log`` and ``dt_bias`` with their channels).
+    # REFUSED by name where the latent mixer is, and for the same reason:
+    # prefill/generate would need the recurrent state as a cache, the ring
+    # would hand a state from rank to rank.
     kda: Optional[DeltaAttention] = None
     # the Mamba-2 mixer's sizes (:class:`Mamba2`), for the layers of the
     # pattern whose ``LayerKind.mixer`` is ``"mamba2"``: the chunked
@@ -606,6 +633,8 @@ class TransformerConfig:
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         if self.attn_gate not in (False, True, "head"):
             raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
+        if self.post_norm not in (False, True, "only"):
+            raise ValueError(f"unknown post_norm {self.post_norm!r}")
         if self.latent is not None and (
             self.pos_embedding != "rope" or self.n_kv_heads is not None
             or self.head_dim is not None or self.qk_norm
@@ -634,14 +663,21 @@ class TransformerConfig:
                 )
                 or d.beta_scale not in (1.0, 2.0)
                 or (d.gate_rank is not None and d.gate_rank < 1)
+                or d.value_dim() < 1
+                or d.out_gate not in ("sigmoid", "silu")
+                or (d.head_decay and (
+                    d.lower_bound is not None or d.gate_rank is not None
+                ))
             ):
                 raise ValueError(
                     "a KDA mixer (TransformerConfig.kda) is some layer's of "
                     "the pattern (LayerKind.mixer='kda'), with a head_dim "
                     f"and a convolution of at least 1, a lower_bound in "
                     f"[{-80.0 / SUB}, 0) or None (the gate without a bound), "
-                    "a beta_scale of 1 or 2 and a gate_rank of at least 1 or "
-                    f"None; got {d}"
+                    "a beta_scale of 1 or 2, a gate_rank of at least 1 or "
+                    "None, a v_dim of at least 1 or None, an out_gate "
+                    "'sigmoid' or 'silu' and, under head_decay, neither a "
+                    f"lower_bound nor a gate_rank; got {d}"
                 )
         if self.mamba is not None:
             m = self.mamba
@@ -923,7 +959,10 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
             "a_log": P(heads), "dt_bias": P(heads), "o_norm": P(None),
             "wo": row,
         }
-        if cfg.kda.gate_rank is None:
+        if cfg.kda.head_decay:
+            # a decay a head: ``wa``'s columns are the heads themselves
+            layer.update(wa=col, wg=col)
+        elif cfg.kda.gate_rank is None:
             layer.update(wf=col, wg=col)
         else:
             # the way down to the rank is every chip's, the way up has the
@@ -952,10 +991,11 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
     # one norm a sub-layer: a block without a mixer has no ``ln1``, one
     # without an FFN no ``ln2``
     softmax_mixer = mixer in ("attention", "latent")
-    if mixer != "none":
-        layer["ln1"] = P(None)
-    if kind.ffn != "none":
-        layer["ln2"] = P(None)
+    if cfg.post_norm != "only":
+        if mixer != "none":
+            layer["ln1"] = P(None)
+        if kind.ffn != "none":
+            layer["ln2"] = P(None)
     if cfg.attn_gate and softmax_mixer:
         layer["wg"] = col  # the gate's columns follow q's heads
     if cfg.post_norm:
@@ -1093,12 +1133,17 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
         ``gate_rank`` splits ``wf`` and ``wg`` in two, normal alike."""
         ks = jax.random.split(key, 12)
         wide = cfg.n_heads * kda.head_dim
-        matrix = lambda key: normal(key, (cfg.d_model, wide))
-        taps = lambda key: (
-            jax.random.normal(key, (kda.conv, wide), cfg.dtype)
+        wide_v = cfg.n_heads * kda.value_dim()
+        matrix = lambda key, n=wide: normal(key, (cfg.d_model, n))
+        taps = lambda key, n=wide: (
+            jax.random.normal(key, (kda.conv, n), cfg.dtype)
             * kda.conv ** -0.5
         )
-        if kda.gate_rank is None:
+        if kda.head_decay:
+            gates = {
+                "wa": matrix(ks[3], cfg.n_heads), "wg": matrix(ks[4], wide_v),
+            }
+        elif kda.gate_rank is None:
             gates = {"wf": matrix(ks[3]), "wg": matrix(ks[4])}
         else:
             down = lambda key: normal(key, (cfg.d_model, kda.gate_rank))
@@ -1110,24 +1155,27 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
                 "wg_a": down(ks[4]), "wg_b": up(ks[4]),
             }
         if kda.lower_bound is None:
-            low, high = KDA_UNBOUNDED_DT
+            low, high = KDA_HEAD_DECAY_DT if kda.head_decay else KDA_UNBOUNDED_DT
             dt = jnp.exp(jax.random.uniform(
-                ks[10], (wide,), jnp.float32, math.log(low), math.log(high)
+                ks[10], (cfg.n_heads if kda.head_decay else wide,),
+                jnp.float32, math.log(low), math.log(high),
             ))
             dt_bias = dt + jnp.log(-jnp.expm1(-dt))
         else:
             dt_bias = jax.random.normal(ks[10], (wide,), jnp.float32)
         return {
-            "wq": matrix(ks[0]), "wk": matrix(ks[1]), "wv": matrix(ks[2]),
+            "wq": matrix(ks[0]), "wk": matrix(ks[1]),
+            "wv": matrix(ks[2], wide_v),
             **gates,
             "wbeta": normal(ks[5], (cfg.d_model, cfg.n_heads)),
-            "conv_q": taps(ks[6]), "conv_k": taps(ks[7]), "conv_v": taps(ks[8]),
+            "conv_q": taps(ks[6]), "conv_k": taps(ks[7]),
+            "conv_v": taps(ks[8], wide_v),
             "a_log": jnp.log(jax.random.uniform(
                 ks[9], (cfg.n_heads,), jnp.float32, 1.0, 16.0
             )),
             "dt_bias": dt_bias,
-            "o_norm": jnp.ones((kda.head_dim,), cfg.dtype),
-            "wo": normal(ks[11], (wide, cfg.d_model)),
+            "o_norm": jnp.ones((kda.value_dim(),), cfg.dtype),
+            "wo": normal(ks[11], (wide_v, cfg.d_model)),
         }
 
     def mamba_mixer(key, m):
@@ -1185,10 +1233,11 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
                 "wo": normal(kk[1], (d_q, cfg.d_model)),
             }
         softmax_mixer = mixer in ("attention", "latent")
-        if mixer != "none":
-            layer["ln1"] = jnp.ones((cfg.d_model,), cfg.dtype)
-        if kind.ffn != "none":
-            layer["ln2"] = jnp.ones((cfg.d_model,), cfg.dtype)
+        if cfg.post_norm != "only":
+            if mixer != "none":
+                layer["ln1"] = jnp.ones((cfg.d_model,), cfg.dtype)
+            if kind.ffn != "none":
+                layer["ln2"] = jnp.ones((cfg.d_model,), cfg.dtype)
         if cfg.attn_gate and softmax_mixer:
             layer["wg"] = normal(
                 jax.random.fold_in(kk[1], 1),
@@ -1687,8 +1736,9 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
     outputs return the same way (models/moe.py).  ``with_aux=True``
     (training) additionally returns the router health terms; serving
     paths leave it off.  ``relu2``: the two-matrix FFN's activation is
-    ``relu ** 2`` (``TransformerConfig.ffn``), dense, routed and shared."""
-    h = norm(x, lp["ln2"])
+    ``relu ** 2`` (``TransformerConfig.ffn``), dense, routed and shared.
+    A tree without ``ln2`` (``post_norm="only"``) reads the stream itself."""
+    h = norm(x, lp["ln2"]) if "ln2" in lp else x
     if "moe" in lp:
         from .moe import moe_ffn
 
@@ -1837,7 +1887,16 @@ def _kda_partial(h, lp, n_heads_local, kda):
     2 x 8,192 x 4,096 or 1 x 8,192 x 8,192): the backward replays the
     chains and the core from them and multiplies none of them out twice.
     Nothing else is named: ``o`` and the core's saved set (1.07 GB a layer)
-    do not fit six layers, ``wo``'s product and beta's are replayed."""
+    do not fit six layers, ``wo``'s product and beta's are replayed.
+
+    A tree with ``wa`` (``DeltaAttention.head_decay``: Gated DeltaNet) has
+    ONE log-decay a head a token, ``g`` (B, H, T, 1), from a product of H
+    columns that is replayed like beta's (four projections are kept: 8,192 x
+    17,280 x 2 bytes at heads of 96 / 192); v, the output gate and ``wo``'s
+    rows are as wide as ``wv`` says, and ``kda["out_gate"]`` ``"silu"`` gates
+    the normed output by a SiLU.  The core then runs ``ops.kda``'s padded
+    path and the chains their XLA forms (heads of 96 and 192 are no whole
+    lanes)."""
     from ..ops.kda import conv_in, decay_in, gated_out, kda_chunked
 
     H = n_heads_local
@@ -1853,7 +1912,10 @@ def _kda_partial(h, lp, n_heads_local, kda):
             h @ lp[w] if w in lp else (h @ lp[w + "_a"]) @ lp[w + "_b"]
         )
         bound = kda["lower_bound"]
-        g = decay_in(through("wf"), lp["dt_bias"], lp["a_log"], bound)
+        # a decay a head (``wa``, H columns): one log-decay a head a token,
+        # (B, H, T, 1), a product too small to keep
+        gate = h @ lp["wa"] if "wa" in lp else through("wf")
+        g = decay_in(gate, lp["dt_bias"], lp["a_log"], bound)
         beta = jax.nn.sigmoid((h @ lp["wbeta"]).astype(f32)).transpose(0, 2, 1)
         if kda.get("beta_scale", 1.0) != 1.0:
             beta = beta * kda["beta_scale"]
@@ -1861,7 +1923,8 @@ def _kda_partial(h, lp, n_heads_local, kda):
         # (B, H, T, dv) f32; without a bound, the split by halving
         o = kda_chunked(q, k, v, g, beta, safe=bound is None)
     with device_scope("accl.attn::kda_proj"):
-        o = gated_out(o, through("wg"), lp["o_norm"], kda["eps"], h.dtype)
+        o = gated_out(o, through("wg"), lp["o_norm"], kda["eps"], h.dtype,
+                      silu=kda.get("out_gate") == "silu")
         return o @ lp["wo"]
 
 
@@ -2018,7 +2081,8 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
 
     What the tree holds says which sub-layers the block has: a mixer where
     it has an ``ln1``, an FFN where it has an ``ln2`` (``LayerKind``: a
-    block of ONE sub-layer is ``x + f(norm x)``, one norm, one residual).
+    block of ONE sub-layer is ``x + f(norm x)``, one norm, one residual),
+    or their ``_post`` twins alone (``post_norm="only"``: ``x + norm(f(x))``).
 
     ``return_kv=True`` additionally returns the (k, v) head tensors
     (B, H_local, T, hd) — the prefill path of the KV-cache decode.
@@ -2033,8 +2097,9 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
             v, ax, ReduceFunction.SUM
         )
     kv = None
-    if "ln1" in lp:
-        h = norm(x, lp["ln1"])
+    if "ln1" in lp or "ln1_post" in lp:
+        # ``post_norm="only"``: no norm before the mixer
+        h = norm(x, lp["ln1"]) if "ln1" in lp else x
         if fanout_fn is not None and tp_axis is not None:
             # replicated h fans out into the tp-sharded q/k/v matmuls: the
             # manual-backward mode marks the fan-out so its transpose (a tp
@@ -2052,7 +2117,7 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
             # the tree's post-norm: the half's output normed once more
             partial_o = norm(partial_o, lp["ln1_post"])
         x = x + partial_o
-    if "ln2" not in lp:
+    if "ln2" not in lp and "ln2_post" not in lp:
         out = (x, None) if with_aux else x    # the mixer alone
     else:
         out = _mlp(x, lp, tp_axis, ep_axis, moe_cfg, with_aux,
@@ -2232,7 +2297,7 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
     if cfg.kda is not None:
         kw["kda"] = {
             "lower_bound": cfg.kda.lower_bound, "eps": cfg.norm_eps,
-            "beta_scale": cfg.kda.beta_scale,
+            "beta_scale": cfg.kda.beta_scale, "out_gate": cfg.kda.out_gate,
         }
     if cfg.mamba is not None:
         m = cfg.mamba
@@ -2557,8 +2622,8 @@ def reject_latent(cfg, where: str) -> None:
     if cfg.kda is not None:
         raise ValueError(
             "the KDA mixer (linear attention, TransformerConfig.kda: its "
-            "state is no cache yet, under either gate and at either rank of "
-            "the gate projections) is "
+            "state is no cache yet, under either gate, at either rank of "
+            "the gate projections, with a decay a channel or a head) is "
             f"supported on the decoder's train and forward paths only, not "
             f"{where}"
         )

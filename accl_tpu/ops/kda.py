@@ -75,6 +75,17 @@ powers of ``A`` float32's last digits).
 form above (chosen statically by the caller: ``models.transformer``
 ``DeltaAttention.lower_bound``), so its program is what it was.
 
+A DECAY A HEAD (Gated DeltaNet, arXiv:2412.06464: ``g`` of ONE column,
+``(B, H, T, 1)``) is the same rule with every channel of a head decaying
+alike, and runs the same core for any ``g <= 0`` (:func:`_decay_a_head`):
+``g`` goes to every channel of the head, and heads that are not whole lanes
+but within :data:`ops.pallas.kda.PAD` of them (keys of 96, values of 192)
+are padded with zero columns up to whole lanes for the kernels, exactly (a
+zero key column writes nothing and answers nothing; ``o``'s columns are cut
+back).  The scalar gate's own chunked form, whose ``exp(G_t - G_j)``
+multiplies ``K K^T`` and ``Q K^T`` AFTER the products with no split at all,
+is not built.
+
 THE CHAINS ROUND THE CORE (the end of this module): what the mixer runs in
 float32 between a projection and the core and between the core and ``wo``
 (:func:`conv_in`, :func:`decay_in`, :func:`gated_out`), by the same kind
@@ -244,14 +255,37 @@ def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB,
     any ``g <= 0`` is right (the split by halving; ``chunk`` a power of
     two).  T need be no multiple of the chunk: the tail is padded with
     tokens that leave the state alone (no decay, no write).  The shapes
-    pick the lowering (module docstring)."""
+    pick the lowering (module docstring).  A ``g`` of ONE column, (B, H, T,
+    1), is a decay a head (module docstring, A DECAY A HEAD): it has no
+    bound, so it runs as under ``safe`` whatever ``safe`` says."""
     if chunk % sub or sub % 2:
         raise ValueError(f"chunk {chunk} is no whole even sub-blocks of {sub}")
-    if safe and chunk & (chunk - 1):
+    head_decay = g.shape[-1] == 1 and q.shape[-1] != 1
+    if (safe or head_decay) and chunk & (chunk - 1):
         raise ValueError(f"the split by halving needs a chunk of 2^n, not {chunk}")
+    if head_decay:
+        return _decay_a_head(q, k, v, g, beta, chunk, sub)
     if _kernels.takes(q.shape, v.shape, chunk, sub):
         return _kernels.kda(q, k, v, g, beta, safe=safe)
     return _xla_form(q, k, v, g, beta, chunk, sub, safe)
+
+
+def _decay_a_head(q, k, v, g, beta, chunk, sub):
+    """:func:`kda_chunked` for a decay a head, ``g`` (B, H, T, 1) (module
+    docstring, A DECAY A HEAD): the split by halving with ``g`` on every
+    channel, by the kernels where the heads are whole lanes or within
+    ``ops.pallas.kda.PAD`` of them (zero columns up to whole lanes, ``o``
+    cut back), else by the XLA form."""
+    dv = v.shape[-1]
+    if not _kernels.takes_padded(q.shape[-1], dv, chunk, sub):
+        g = jnp.broadcast_to(g, q.shape)
+        return _xla_form(q, k, v, g, beta, chunk, sub, safe=True)
+    wide = lambda x: jnp.pad(
+        x, [(0, 0)] * 3 + [(0, -x.shape[-1] % _kernels.LANES)]
+    )
+    q, k, v = wide(q), wide(k), wide(v)
+    g = jnp.broadcast_to(g, q.shape)
+    return _kernels.kda(q, k, v, g, beta, safe=True)[..., :dv]
 
 
 def _xla_form(q, k, v, g, beta, chunk: int = CHUNK, sub: int = SUB,
@@ -363,7 +397,8 @@ def decay_in(x, dt_bias, a_log, lower_bound):
     ``lower_bound * sigmoid(exp(a_log) (x + dt_bias))``, ``dt_bias`` a
     channel, ``a_log`` (H,) a head; under ``lower_bound`` None the
     published gate without a bound, ``-exp(a_log) softplus(x + dt_bias)``
-    (the core then runs ``safe``); float32 (B, H, T, d)."""
+    (the core then runs ``safe``); float32 (B, H, T, d).  ``d`` 1 is a
+    decay a head (``x`` (B, T, H), ``dt_bias`` a head): the XLA form's."""
     if _chains.takes(x.shape[-1], a_log.shape[0]):
         return _chains.decay_in(x, dt_bias, a_log, lower_bound)
     return _xla_decay_in(x, dt_bias, a_log, lower_bound)
@@ -379,19 +414,21 @@ def _xla_decay_in(x, dt_bias, a_log, lower_bound):
     return lower_bound * jax.nn.sigmoid(rate * f)
 
 
-def gated_out(o, gate, o_norm, eps: float, dtype):
+def gated_out(o, gate, o_norm, eps: float, dtype, silu: bool = False):
     """What ``wo`` takes from the core's ``o`` (B, H, T, d): the RMS norm a
     head with the scale ``o_norm`` (d,), times ``sigmoid`` of the gate's
-    projection ``gate`` (B, T, H d); (B, T, H d) in ``dtype``."""
-    if _chains.takes(gate.shape[-1], o.shape[1]):
+    projection ``gate`` (B, T, H d), or under ``silu`` times its SiLU (the
+    XLA form alone); (B, T, H d) in ``dtype``."""
+    if not silu and _chains.takes(gate.shape[-1], o.shape[1]):
         return _chains.gated_out(o, gate, o_norm, eps, dtype)
-    return _xla_gated_out(o, gate, o_norm, eps, dtype)
+    return _xla_gated_out(o, gate, o_norm, eps, dtype, silu)
 
 
-def _xla_gated_out(o, gate, o_norm, eps, dtype):
+def _xla_gated_out(o, gate, o_norm, eps, dtype, silu=False):
     B, _, T, d = o.shape
     o = o.astype(jnp.float32)
     o = o * lax.rsqrt(jnp.sum(o * o, axis=-1, keepdims=True) / d + eps)
     o = o * o_norm.astype(jnp.float32)
     o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
-    return (o * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
+    act = jax.nn.silu if silu else jax.nn.sigmoid
+    return (o * act(gate.astype(jnp.float32))).astype(dtype)

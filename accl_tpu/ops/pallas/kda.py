@@ -91,6 +91,11 @@ CHUNK, SUB = 64, 16
 ROWS = 2 * CHUNK
 BLOCKS = 4
 
+#: how much wider a head may get when ``ops.kda`` pads it with zero columns
+#: up to whole lanes for these kernels (a decay a head: keys of 96 to 128,
+#: values of 192 to 256, a third more); anything narrower is the XLA form's
+PAD = 1.5
+
 _f32, _bf16 = jnp.float32, jnp.bfloat16
 #: what a default-precision product rounds its operands to, read at each
 #: call (the tests of the mathematics set float32: a CPU's XLA form
@@ -106,6 +111,15 @@ def takes(q_shape, v_shape, chunk: int = CHUNK, sub: int = SUB) -> bool:
     return (
         (chunk, sub) == (CHUNK, SUB)
         and q_shape[-1] % LANES == 0 and v_shape[-1] % LANES == 0
+    )
+
+
+def takes_padded(dk: int, dv: int, chunk: int = CHUNK, sub: int = SUB) -> bool:
+    """:func:`takes` for heads that zero columns bring up to whole lanes:
+    each width at most :data:`PAD` times wider for it."""
+    up = lambda d: -(-d // LANES) * LANES
+    return (chunk, sub) == (CHUNK, SUB) and all(
+        up(d) <= PAD * d for d in (dk, dv)
     )
 
 

@@ -155,7 +155,12 @@ drops ``op_name``, so a reader joins the two by instruction name
                          the chunks fused into them; at any other shape the
                          XLA form, whose scan is a loop of the compiled
                          step (the body's instructions are device events
-                         of their own)
+                         of their own).  At a decay a head (Gated
+                         DeltaNet: a log-decay of ONE column) the same
+                         kernels on heads padded to whole lanes (keys of
+                         96 to 128, values of 192 to 256) with the head's
+                         decay on every channel; the pads, the broadcast
+                         and the cut of ``o`` are XLA's, under this scope
 ``accl.attn::kda_proj``  the same: everything round the core.  The seven
                          projections and ``wo`` are XLA's matmuls and beta
                          its fusion; the three float32 chains between them
@@ -172,7 +177,11 @@ drops ``op_name``, so a reader joins the two by instruction name
                          whose bf16 products they read once: the block
                          keeps those by name (``transformer.
                          KEPT_UNDER_REMAT``).  At any other shape
-                         XLA's fusions.  Float32 in either lowering,
+                         XLA's fusions: at a decay a head all three
+                         chains (heads of 96 and 192 are no whole lanes;
+                         the gate's projection is ``wa``, 30 columns, and
+                         the output gate a SiLU).  Float32 in either
+                         lowering,
                          inside the kernels too (only the projections
                          and their cotangents have the matmuls' type)
 ``accl.attn::ssd``       ``_mamba2_partial`` (a Mamba-2 mixer, ``LayerKind.

@@ -169,6 +169,7 @@ REHEARSALS = {
     "solar2": [
         ("train_solar2_t8192_b1", 0),      # 237
         ("train_t8192_b1", 0),             # 18
+        ("train_olmoh_t8192_b1", 0),       # PR 52: the lightest group's
     ],
     "recurrent": [
         ("train_ling3_t8192_b2", 0),       # 92

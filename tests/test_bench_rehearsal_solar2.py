@@ -1,5 +1,5 @@
 """``perfbench.run``'s CPU rehearsals, one file a group of about equal
-cost (``helpers.REHEARSALS``, ROADMAP D14): the Solar Open 2 cell's (the dearest) and a StarCoder cell's."""
+cost (``helpers.REHEARSALS``, ROADMAP D14): the Solar Open 2 cell's (the dearest), a StarCoder cell's and the Olmo Hybrid cell's."""
 
 import pytest
 

@@ -712,6 +712,59 @@ def test_kda_kernels_compile_under_the_unbounded_gate(one_chip):
     )
 
 
+def test_olmoh_step_takes_the_kernels_at_padded_heads(v5e, monkeypatch):
+    """One Gated DeltaNet layer and the full-attention layer of the cell's
+    four, 1 x 8,192 tokens of the whole 100,352 ids, under ``remat`` as the
+    cell runs: the delta core at a decay a head and heads of 96 / 192 is
+    ``kda_fwd`` (twice: the forward and its replay) and ``kda_bwd`` under
+    ``accl.attn::kda`` (0 would mean that the shape rule fell through to the
+    XLA form, whose scan is a loop: nothing of the scope is in one), on
+    operands padded to 128 / 256; the chains round it are XLA's under
+    ``accl.attn::kda_proj`` (no chain kernel takes such heads), and the four
+    wide bf16 projections they read (q, k, v, the output gate) are
+    multiplied out ONCE, kept under ``remat`` by name; the full layer's core
+    the flash kernels at 30 heads under ``accl.attn::core``, its projections
+    under ``accl.attn::gqa_proj``; and no array larger than the float32
+    logits (8,192 x 100,352: memory linear in T)."""
+    from perfbench import scope_ops
+    from perfbench.drivers import train_steps_ling3
+
+    compiled = _step("train_olmoh_t8192_b1", 2, v5e, monkeypatch, layers=(2, 3))
+    text = compiled.as_text()
+    entry = scope_ops.scopes_of(text)
+    every = train_steps_ling3.scoped_instructions(text)
+    for scope in ("accl.attn::kda", "accl.attn::kda_proj", "accl.attn::core",
+                  "accl.attn::gqa_proj", "accl.embed::grad"):
+        assert entry.get(scope), scope
+    core, chains = entry["accl.attn::kda"], entry["accl.attn::kda_proj"]
+    assert sum(n.startswith("kda_fwd") for n in core) == 2
+    assert sum(n.startswith("kda_bwd") for n in core) == 1
+    assert not any(n.startswith("while") for n in core)
+    assert set(core) == set(every["accl.attn::kda"])
+    assert re.search(r"f32\[30,8192,256\]", text)       # v and o, padded
+    assert not any(n.startswith("kda_in_") or n.startswith("kda_out_")
+                   for n in chains)
+    # q and k once each, v once, the gate once (the compiler writes it
+    # without the batch's 1) beside ``wo``'s cotangent, the same shape: a
+    # replay would make each forward product twice
+    assert len(_products(text, chains, "bf16[1,8192,2880]")) == 2
+    assert len(_products(text, chains, "bf16[1,8192,5760]")) == 1
+    assert len(_products(text, chains, "bf16[8192,5760]")) == 1 + 1
+    flash = entry["accl.attn::core"]
+    assert sum(n.startswith("flash_fwd") for n in flash) == 2
+    assert sum(n.startswith("flash_bwd") for n in flash) == 1
+    assert re.search(r"bf16\[1,30,8192,128\]", text)
+    sizes = sorted({
+        int(np.prod([int(n) for n in dims.split(",")]))
+        for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
+    })
+    assert sizes[-1] == 8192 * 100352
+    assert not re.search(r"\[8192,8192,\d+\]|\[30,8192,8192\]", text)
+    # the whole cell, four layers: 11,371,662,336 bytes of scratch (my
+    # compile for the described chip, PR 52); this cut has half the layers
+    assert compiled.memory_analysis().temp_size_in_bytes <= 11_371_662_336
+
+
 def test_solar2_step_takes_the_kernels_under_the_unbounded_gate(
     v5e, monkeypatch
 ):

@@ -7,6 +7,7 @@ with seeded weights.  Float32 against float32 is held to 1e-4 of the
 largest value."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -148,11 +149,37 @@ def test_auto_resolves_to_flash_at_the_cell_shapes():
 # -- the whole model ------------------------------------------------------------
 
 
+@functools.cache
+def _seeded_reference_logits():
+    """The reference's logits of ``_params()`` on ``_batch()``, ONE compiled
+    function run once for the three lowerings' cases (an eager walk compiles
+    every operation by itself: ROADMAP D14)."""
+
+    @jax.jit
+    def logits(weights, tok):
+        h, _, _ = reference.hidden(weights, tok, **REF)
+        return reference.head(weights, h)
+
+    with jax.default_matmul_precision("highest"):
+        params, (tok, _) = _params(), _batch()
+        return logits(driver.reference_weights(params), tok)
+
+
+@functools.cache
+def _seeded_reference_grads():
+    """The reference's loss and gradients of ``_params()`` on ``_batch()``,
+    ONE compiled function run once for the cases that compare with it (an
+    eager walk compiles every operation by itself: ROADMAP D14)."""
+    with jax.default_matmul_precision("highest"):
+        params, (tok, tgt) = _params(), _batch()
+        return _reference_grads(params, tok, tgt)
+
+
 def _reference_grads(params, tok, tgt):
     weights = driver.reference_weights(params)
-    return jax.value_and_grad(
+    return jax.jit(jax.value_and_grad(
         lambda w: reference.loss(w, tok, tgt, alphas=ALPHAS, **REF)
-    )(weights)
+    ))(weights)
 
 
 @pytest.mark.parametrize("attention", ["naive", "blockwise", "flash"])
@@ -161,9 +188,7 @@ def test_logits_against_the_reference(attention):
     params, (tok, _) = _params(), _batch()
     fwd, shard = make_sharded_forward(cfg, _mesh(1))
     got = fwd(shard(params), tok)
-    weights = driver.reference_weights(params)
-    h, _, _ = reference.hidden(weights, tok, **REF)
-    _close(got, reference.head(weights, h))
+    _close(got, _seeded_reference_logits())
 
 
 @pytest.mark.parametrize("tp", [1, 2])
@@ -174,7 +199,7 @@ def test_loss_and_gradients_against_the_reference(tp):
     params, (tok, tgt) = _params(), _batch()
     step, shard = make_sharded_train_step(CFG, _mesh(tp), lr=1.0)
     new, loss = step(shard(params), tok, tgt)
-    want_loss, want = _reference_grads(params, tok, tgt)
+    want_loss, want = _seeded_reference_grads()
     _close(loss, want_loss, 1e-5)
     got = driver.reference_weights(
         jax.tree.map(lambda p, n: p - n, params, jax.device_get(new))

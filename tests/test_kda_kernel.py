@@ -61,7 +61,12 @@ def _close(got, want, tol):
 
 
 def _with_grads(fn, x, co):
-    return fn(*x), jax.grad(lambda *a: jnp.sum(fn(*a) * co), argnums=ARGS)(*x)
+    """``fn``'s output and its gradient by every input, as ONE compiled
+    function (an eager walk compiles each of the XLA form's hundreds of
+    operations by itself: most of what these cases cost, ROADMAP D14)."""
+    return jax.jit(lambda x, co: (
+        fn(*x), jax.grad(lambda *a: jnp.sum(fn(*a) * co), argnums=ARGS)(*x)
+    ))(x, co)
 
 
 def _cotangent(x):

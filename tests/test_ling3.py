@@ -8,6 +8,7 @@ seeded weights.  Float32 against float32 is held to 1e-4 of the largest
 value."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -167,24 +168,26 @@ def test_chunked_core_against_the_recurrence(length, at_bound):
     are not whole chunks; with every gate at the lower bound (a chunk's
     decay sums to -320: ``exp`` of it is 0 in float32) nothing overflows."""
     x = _core_inputs(length, at_bound=at_bound)
-    got, want = kda.kda_chunked(*x), _recurrence(*x)
+    co = jax.random.normal(jax.random.PRNGKey(9), x[2].shape)
+    # each side ONE compiled function (an eager walk compiles every
+    # operation by itself: ROADMAP D14)
+    both = lambda f: jax.jit(lambda *a: (f(*a), jax.grad(
+        lambda *b: jnp.sum(f(*b) * co), argnums=(0, 1, 2, 3, 4)
+    )(*a)))(*x)
+    (got, got_grads), (want, want_grads) = both(kda.kda_chunked), both(_recurrence)
     assert got.dtype == jnp.float32 and got.shape == want.shape
     _close(got, want, 2e-5)
-    co = jax.random.normal(jax.random.PRNGKey(9), got.shape)
-    grads = lambda f: jax.grad(
-        lambda *a: jnp.sum(f(*a) * co), argnums=(0, 1, 2, 3, 4)
-    )(*x)
-    for name, a, b in zip("q k v g beta".split(), grads(kda.kda_chunked),
-                          grads(_recurrence)):
+    for name, a, b in zip("q k v g beta".split(), got_grads, want_grads):
         # at the bound a gate's gradient is a difference of terms of e^-5
         _close(a, b, 2e-4 if at_bound and name == "g" else 5e-5), name
 
 
 def test_chunks_and_sub_blocks_are_the_callers_to_choose():
     x = _core_inputs(96)
-    want = _recurrence(*x)
+    want = jax.jit(_recurrence)(*x)
     for chunk, sub in ((32, 16), (64, 32), (16, 2)):
-        _close(kda.kda_chunked(*x, chunk=chunk, sub=sub), want, 2e-5)
+        core = jax.jit(lambda *a: kda.kda_chunked(*a, chunk=chunk, sub=sub))
+        _close(core(*x), want, 2e-5)
     with pytest.raises(ValueError, match="sub-blocks"):
         kda.kda_chunked(*x, chunk=64, sub=24)
     assert (kda.CHUNK, kda.SUB) == (64, 16)
@@ -207,11 +210,37 @@ def test_unit_lower_inverse_and_its_cotangent():
 # -- the whole model ------------------------------------------------------------
 
 
+@functools.cache
+def _seeded_reference_logits():
+    """The reference's logits of ``_params()`` on ``_batch()``, ONE compiled
+    function run once for the three lowerings' cases (an eager walk compiles
+    every operation by itself: ROADMAP D14)."""
+
+    @jax.jit
+    def logits(weights, tok):
+        h, _ = reference.hidden(weights, tok, **REF)
+        return reference.head(weights, h)
+
+    with jax.default_matmul_precision("highest"):
+        params, (tok, _) = _params(), _batch()
+        return logits(driver.reference_weights(params), tok)
+
+
+@functools.cache
+def _seeded_reference_grads():
+    """The reference's loss and gradients of ``_params()`` on ``_batch()``,
+    ONE compiled function run once for the cases that compare with it (an
+    eager walk compiles every operation by itself: ROADMAP D14)."""
+    with jax.default_matmul_precision("highest"):
+        params, (tok, tgt) = _params(), _batch()
+        return _reference_grads(params, tok, tgt)
+
+
 def _reference_grads(params, tok, tgt):
     weights = driver.reference_weights(params)
-    return jax.value_and_grad(
+    return jax.jit(jax.value_and_grad(
         lambda w: reference.loss(w, tok, tgt, **REF)
-    )(weights)
+    ))(weights)
 
 
 @pytest.mark.parametrize("attention", ["naive", "blockwise", "flash"])
@@ -220,9 +249,7 @@ def test_logits_against_the_reference(attention):
     params, (tok, _) = _params(), _batch()
     fwd, shard = make_sharded_forward(cfg, _mesh(1))
     got = fwd(shard(params), tok)
-    weights = driver.reference_weights(params)
-    h, _ = reference.hidden(weights, tok, **REF)
-    _close(got, reference.head(weights, h))
+    _close(got, _seeded_reference_logits())
 
 
 @pytest.mark.parametrize("tp", [1, 2])
@@ -234,7 +261,7 @@ def test_loss_and_gradients_against_the_reference(tp):
     params, (tok, tgt) = _params(), _batch()
     step, shard = make_sharded_train_step(CFG, _mesh(tp), lr=1.0)
     new, loss = step(shard(params), tok, tgt)
-    want_loss, want = _reference_grads(params, tok, tgt)
+    want_loss, want = _seeded_reference_grads()
     _close(loss, want_loss, 1e-5)
     got = driver.reference_weights(
         jax.tree.map(lambda p, n: p - n, params, jax.device_get(new))

@@ -8,6 +8,7 @@ recurrence), at small sizes on the CPU mesh with seeded weights.  Float32
 against float32 is held to 1e-4 of the largest value."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -153,23 +154,25 @@ def test_chunked_core_against_the_recurrence(length, groups):
     """Forward and the gradient by every input, at lengths that are and are
     not whole chunks, with four, two and one head a group."""
     v = _core_inputs(length, G=groups)
-    got, want = ssd.ssd_chunked(*v), _recurrence(*v)
+    co = jax.random.normal(jax.random.PRNGKey(9), v[0].shape)
+    # each side ONE compiled function (an eager walk compiles every
+    # operation by itself: ROADMAP D14)
+    both = lambda f: jax.jit(lambda *a: (f(*a), jax.grad(
+        lambda *b: jnp.sum(f(*b) * co), argnums=tuple(range(6))
+    )(*a)))(*v)
+    (got, got_grads), (want, want_grads) = both(ssd.ssd_chunked), both(_recurrence)
     assert got.dtype == jnp.float32 and got.shape == want.shape
     _close(got, want, 2e-5)
-    co = jax.random.normal(jax.random.PRNGKey(9), got.shape)
-    grads = lambda f: jax.grad(
-        lambda *a: jnp.sum(f(*a) * co), argnums=tuple(range(6))
-    )(*v)
-    for name, a, b in zip("x B C dt A D".split(), grads(ssd.ssd_chunked),
-                          grads(_recurrence)):
+    for name, a, b in zip("x B C dt A D".split(), got_grads, want_grads):
         _close(a, b, 1e-4), name
 
 
 def test_the_chunk_is_the_callers_and_a_long_decay_stays_finite():
     v = _core_inputs(96)
-    want = _recurrence(*v)
+    want = jax.jit(_recurrence)(*v)
     for chunk in (16, 32, 96, 128):
-        _close(ssd.ssd_chunked(*v, chunk=chunk), want, 2e-5)
+        core = jax.jit(lambda *a: ssd.ssd_chunked(*a, chunk=chunk))
+        _close(core(*v), want, 2e-5)
     assert ssd.CHUNK == 128
     # steps of 40 at a rate of -16: a chunk's decay sums to -81,920, whose
     # exponential is 0 in float32, forward and backward
@@ -188,11 +191,37 @@ def test_the_chunk_is_the_callers_and_a_long_decay_stays_finite():
 # -- the whole model ------------------------------------------------------------
 
 
+@functools.cache
+def _seeded_reference_logits():
+    """The reference's logits of ``_params()`` on ``_batch()``, ONE compiled
+    function run once for the three lowerings' cases (an eager walk compiles
+    every operation by itself: ROADMAP D14)."""
+
+    @jax.jit
+    def logits(weights, tok):
+        h, _ = reference.hidden(weights, tok, **REF)
+        return reference.head(weights, h)
+
+    with jax.default_matmul_precision("highest"):
+        params, (tok, _) = _params(), _batch()
+        return logits(driver.reference_weights(params), tok)
+
+
+@functools.cache
+def _seeded_reference_grads():
+    """The reference's loss and gradients of ``_params()`` on ``_batch()``,
+    ONE compiled function run once for the cases that compare with it (an
+    eager walk compiles every operation by itself: ROADMAP D14)."""
+    with jax.default_matmul_precision("highest"):
+        params, (tok, tgt) = _params(), _batch()
+        return _reference_grads(params, tok, tgt)
+
+
 def _reference_grads(params, tok, tgt):
     weights = driver.reference_weights(params)
-    return jax.value_and_grad(
+    return jax.jit(jax.value_and_grad(
         lambda w: reference.loss(w, tok, tgt, **REF)
-    )(weights)
+    ))(weights)
 
 
 @pytest.mark.parametrize("attention", ["naive", "blockwise", "flash"])
@@ -201,9 +230,7 @@ def test_logits_against_the_reference(attention):
     params, (tok, _) = _params(), _batch()
     fwd, shard = make_sharded_forward(cfg, _mesh(1))
     got = fwd(shard(params), tok)
-    weights = driver.reference_weights(params)
-    h, _ = reference.hidden(weights, tok, **REF)
-    _close(got, reference.head(weights, h))
+    _close(got, _seeded_reference_logits())
 
 
 @pytest.mark.parametrize("tp", [1, 2])
@@ -215,7 +242,7 @@ def test_loss_and_gradients_against_the_reference(tp):
     params, (tok, tgt) = _params(), _batch()
     step, shard = make_sharded_train_step(CFG, _mesh(tp), lr=1.0)
     new, loss = step(shard(params), tok, tgt)
-    want_loss, want = _reference_grads(params, tok, tgt)
+    want_loss, want = _seeded_reference_grads()
     _close(loss, want_loss, 1e-5)
     got = driver.reference_weights(
         jax.tree.map(lambda p, n: p - n, params, jax.device_get(new))

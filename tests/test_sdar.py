@@ -96,13 +96,15 @@ def f32():
     with jax.default_matmul_precision("highest"):
         params, tok = _params(), _tokens()
         noisy, masked, t = diffusion_noise(KEY, tok, CFG.diffusion)
-        loss, grads = jax.value_and_grad(
+        # each side ONE compiled function: an eager walk compiles every
+        # operation by itself (ROADMAP D14)
+        loss, grads = jax.jit(jax.value_and_grad(
             lambda p: loss_fn(p, tok, KEY, CFG)
-        )(params)
+        ))(params)
         weights = driver.reference_weights(params)
-        want_loss, want_grads = jax.value_and_grad(
+        want_loss, want_grads = jax.jit(jax.value_and_grad(
             lambda w: sdar_moe.loss(w, noisy, tok, masked, t, **REF)
-        )(weights)
+        ))(weights)
         return dict(
             params=params, tok=tok, noisy=noisy, masked=masked, t=t,
             both=jnp.concatenate([noisy, tok], axis=1), weights=weights,
@@ -229,14 +231,17 @@ def test_a_shard_takes_the_whole_batchs_draw_for_its_sequences():
 @pytest.mark.parametrize("attention", ["naive", "blockwise", "flash"])
 def test_f32_logits_of_the_noisy_half_match_reference(f32, attention):
     cfg = dataclasses.replace(CFG, attention=attention)
-    got = forward(f32["params"], f32["both"], cfg)
+    got = jax.jit(lambda p, both: forward(p, both, cfg))(
+        f32["params"], f32["both"]
+    )
     assert got.shape == (2, L, CFG.vocab)
-    want = sdar_moe.logits(f32["weights"], f32["noisy"], f32["tok"], **REF)
+    reference = lambda **how: jax.jit(lambda w, noisy, tok: sdar_moe.logits(
+        w, noisy, tok, **{**REF, **how}
+    ))(f32["weights"], f32["noisy"], f32["tok"])
+    want = reference()
     _close(got, want)
     # the query block changes no value
-    _close(sdar_moe.logits(
-        f32["weights"], f32["noisy"], f32["tok"], **{**REF, "q_block": 64}
-    ), want, 1e-6)
+    _close(reference(q_block=64), want, 1e-6)
 
 
 def test_f32_loss_matches_reference(f32):

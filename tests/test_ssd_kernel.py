@@ -73,7 +73,12 @@ def _close(got, want, tol):
 
 
 def _with_grads(fn, v, co):
-    return fn(*v), jax.grad(lambda *a: jnp.sum(fn(*a) * co), argnums=ARGS)(*v)
+    """``fn``'s output and its gradient by every input, as ONE compiled
+    function (an eager walk compiles each of the XLA form's hundreds of
+    operations by itself: most of what these cases cost, ROADMAP D14)."""
+    return jax.jit(lambda v, co: (
+        fn(*v), jax.grad(lambda *a: jnp.sum(fn(*a) * co), argnums=ARGS)(*v)
+    ))(v, co)
 
 
 def _cotangent(v):

@@ -103,12 +103,14 @@ def f32():
     with jax.default_matmul_precision("highest"):
         params = _params()
         tok, tgt = _batch()
-        loss, grads = jax.value_and_grad(
+        # each side ONE compiled function: an eager walk compiles every
+        # operation by itself (ROADMAP D14)
+        loss, grads = jax.jit(jax.value_and_grad(
             lambda p: loss_fn(p, tok, tgt, CFG)
-        )(params)
-        want_loss, want_grads = jax.value_and_grad(
+        ))(params)
+        want_loss, want_grads = jax.jit(jax.value_and_grad(
             lambda w: afmoe.loss(w, tok, tgt, **REF)
-        )(driver.reference_weights(params))
+        ))(driver.reference_weights(params))
         return dict(
             params=params, tok=tok, tgt=tgt, loss=loss, want_loss=want_loss,
             grads=grads, want_grads=want_grads,
@@ -174,13 +176,16 @@ def test_unknown_kinds_fail_in_post_init(bad):
 
 
 def test_f32_logits_match_reference(f32):
-    got = forward(f32["params"], f32["tok"], CFG)
+    got = jax.jit(lambda p, tok: forward(p, tok, CFG))(f32["params"], f32["tok"])
     weights = driver.reference_weights(f32["params"])
+    whole = jax.jit(lambda w, tok: afmoe.logits(w, tok, last=T, **REF))
     for b in range(2):
-        _close(got[b], afmoe.logits(weights, f32["tok"][b], last=T, **REF))
+        _close(got[b], whole(weights, f32["tok"][b]))
     # ``last`` and the query block change no value
     _close(
-        afmoe.logits(weights, f32["tok"][0], last=5, **{**REF, "q_block": 48}),
+        jax.jit(lambda w, tok: afmoe.logits(
+            w, tok, last=5, **{**REF, "q_block": 48}
+        ))(weights, f32["tok"][0]),
         got[0, -5:],
     )
 
