@@ -1158,7 +1158,10 @@ class MonitorServer:
                     name="accl-monitor-req", daemon=True,
                 )
                 t.start()
+                # kept for stop() (this runs on the serve thread alone)
+                requests[:] = [r for r in requests if r.is_alive()] + [t]
 
+        requests = self._requests = []
         self._server = _Server((host, int(port)), _Handler)
         self.host = host
         self.port = int(self._server.server_address[1])
@@ -1174,10 +1177,14 @@ class MonitorServer:
     def stop(self, timeout: float = 5.0) -> bool:
         """Shut the service down; True when the serve thread joined
         within ``timeout`` (bounded — a wedged handler must not wedge
-        deinit)."""
+        deinit).  A request thread that has sent its reply and not yet
+        returned is waited for too, inside the same bound."""
+        end = time.monotonic() + timeout
         self._server.shutdown()
         self._server.server_close()
         self._thread.join(timeout=timeout)
+        for t in self._requests:
+            t.join(timeout=max(0.0, end - time.monotonic()))
         return not self._thread.is_alive()
 
     @property

@@ -30,15 +30,15 @@ every other chunk-local quantity is rebuilt from the inputs (with the
 saved states nothing of the rebuild waits for the chain).
 
 PRECISION, product by product what the XLA form computes: its ``@`` and
-``einsum`` run at the TPU's default precision for float32, ONE bfloat16
-pass with float32 accumulation (operands rounded to bfloat16), so every
-such product here is :func:`_mm` (explicit casts, ``preferred_element_type``
-float32), the backward's too (autodiff's products of the XLA form round
-their cotangents the same way); the inverse's squarings and its
-cotangent's two products are :func:`_mm_hi` (``highest``: full float32);
-the cumulative log-decay is a product with a 0/1 triangle of the three
-bfloat16 parts of ``g`` (exact products, float32 sums: a float32 cumsum
-up to the order of the additions), its cotangent the same with the
+``einsum`` run at the TPU's default precision for float32, ONE bfloat16 pass
+with float32 accumulation (operands rounded to bfloat16), so every such
+product here is :func:`_mm` (explicit casts, ``preferred_element_type``
+float32), the backward's too (autodiff's products of the XLA form round their
+cotangents the same way); the inverse's products and its cotangent's two are
+:func:`_mm_hi` (``highest``: full float32; the inverse's levels 2 and 4
+float32 on the VPU); the cumulative log-decay is a product with a 0/1 triangle
+of the three bfloat16 parts of ``g`` (exact products, float32 sums: a float32
+cumsum up to the order of the additions), its cotangent the same with the
 transposed triangle.  Everything elementwise is float32.
 
 EXPONENTS.  As the XLA form: sub-blocks of :data:`SUB` tokens, a ROW
@@ -54,21 +54,20 @@ middle token as autodiff of the XLA form does, which cancels it out of
 ``dg`` for the tokens before (measured: ``dg`` is then as near the
 ``highest`` truth as the XLA form's, 1.0% rms, and 2.7% without).
 
-ANY ``g <= 0`` (``safe=True``, the unbounded gate; ``ops/kda.py``, ANY ``g
-<= 0``).  The split at a middle token overflows, so ``A`` and ``P`` are
-the XLA form's split by HALVING, for the block's two chunks at once: the
-segment sums ``D_h`` and ``E_h`` of every level from ``g`` by a doubling
-recursion of sublane rolls (:func:`_segments`; plain sums of ``g``, its
-transpose the cotangent's, :func:`_segments_bwd`), six masked one-pass
-products of ``(2 ROWS, dk) x (dk, ROWS)`` and the diagonal's
-(:func:`_local_safe`), every factor at most 1; ``Gamma`` and ``Gamma_C /
-Gamma`` are the top level's; the inverse goes up the same levels, twelve
-``highest`` products and no power of ``A`` (:func:`_inverse_by_halving`).
-The backward (:func:`_block_bwd_safe`) shares everything that waits for
-the state with the bounded one (:func:`_state_bwd`) and takes each level's
-two cotangent products; no exponent is a difference, so no cotangent of
-``g`` is two large terms that cancel.  ``safe`` is static: the bounded gate's kernels are the bodies
-they were.
+ANY ``g <= 0`` (``safe=True``, the unbounded gate; ``ops/kda.py``, ANY ``g <=
+0``).  The split at a middle token overflows, so ``A`` and ``P`` are the XLA
+form's split by HALVING, for the block's two chunks at once: the segment sums
+``D_h`` and ``E_h`` of every level from ``g`` by a doubling recursion of
+sublane rolls (:func:`_segments`; plain sums of ``g``, its transpose the
+cotangent's, :func:`_segments_bwd`), six masked one-pass products of ``(2
+ROWS, dk) x (dk, ROWS)`` and the diagonal's (:func:`_local_safe`), every
+factor at most 1; ``Gamma`` and ``Gamma_C / Gamma`` are the top level's; the
+inverse goes up the same levels with no power of ``A``
+(:func:`_inverse_by_halving`, BOTH gates').  The backward
+(:func:`_block_bwd_safe`) shares everything that waits for the state with the
+bounded one (:func:`_state_bwd`) and takes each level's two cotangent
+products; no exponent is a difference, so no cotangent of ``g`` is two large
+terms that cancel.  ``safe`` is static.
 """
 
 from __future__ import annotations
@@ -167,27 +166,37 @@ def _halves(x):
     return [x[h * CHUNK:(h + 1) * CHUNK] for h in range(ROWS // CHUNK)]
 
 
-def _inverse(a, eye):
-    """``(I + a)^-1`` for the block's two strictly lower triangular chunks
-    on the diagonal of ``a`` (ROWS, ROWS), ``eye`` the diagonal's mask:
-    ``ops.kda._unit_lower_inverse``'s products, a power's square and the
-    inverse's next term in ONE product (they share their right factor)."""
-    power = -a
-    inv = jnp.where(eye, 1.0, 0.0) + power
-    power, reach = _mm_hi(power, power), 2   # inv holds the powers below reach
-    while 2 * reach < CHUNK:
-        both = _mm_hi(jnp.concatenate([inv, power], axis=0), power)
-        inv, power, reach = inv + both[:ROWS], both[ROWS:], 2 * reach
-    return inv + _mm_hi(inv, power)
+def _pair_masks(row, col):
+    """The levels' pair masks, lowest first: ``t xor j``'s highest bit is a pair's level."""
+    differ = row ^ col
+    return [(differ >> i == 1) & (col < row) for i in range(CHUNK.bit_length() - 1)]
 
 
-def _inverse_by_halving(a, masks, eye):
+def _inverse_by_halving(a, masks, row, col):
     """``ops.kda._unit_lower_inverse_by_halving`` for the block's two
-    chunks: ``masks`` the levels' pair masks, lowest first; two ``highest``
-    products a level and no power of ``a``."""
-    inv = jnp.where(eye, 1.0, 0.0)
-    for mask in masks:
-        inv = inv - _mm_hi(_mm_hi(inv, jnp.where(mask, a, 0.0)), inv)
+    chunks, ``inv <- inv - inv a_h inv`` up the levels of ``masks``, nothing
+    multiplied that is zero: ``a_h`` (level ``h``'s pairs of ``a``) and the
+    correction live in the rows whose bit ``h`` is set, ``inv`` in diagonal
+    blocks of ``h``.  Under 8 (rows inside a sublane tile; level 1 comes to
+    ``I - a_1``) in float32 on the VPU: ``a_h``'s rows rolled down times
+    ``inv``'s subdiagonals as columns, then lanes rolled left times them as
+    rows.  From 8 up those rows, whole tiles, go ALONE through two ``highest``
+    products: 384 rows a block where whole levels were 1,536."""
+    inv = jnp.where(row == col, 1.0, 0.0)
+    for i, mask in enumerate(masks):
+        h, a_h = 1 << i, jnp.where(mask, a, 0.0)
+        if h < 8:
+            diags = [jnp.where(col == row - s, inv, 0.0) for s in range(1, h)]
+            left = sum((jnp.sum(d, axis=1, keepdims=True) * pltpu.roll(a_h, s, 0)
+                        for s, d in enumerate(diags, 1)), a_h)
+            inv = inv - sum((pltpu.roll(left, ROWS - s, 1) * jnp.sum(d, axis=0, keepdims=True)
+                             for s, d in enumerate(diags, 1)), left)
+            continue
+        pieces = [inv[s:s + h] for s in range(0, ROWS, h)]   # odd ones: bit h set
+        lower = _rows(pieces[1::2])
+        lower = lower - _mm_hi(_mm_hi(lower, a_h), inv)
+        pieces[1::2] = [lower[s:s + h] for s in range(0, ROWS // 2, h)]
+        inv = _rows(pieces)
     return inv
 
 
@@ -233,7 +242,7 @@ def _local(mm, q, k, v, g, brow, T=None):
     P0, A0 = _rows(p0), _rows(a0)
     Pm = jnp.where(upto, P0, 0.0) * brow
     if T is None:
-        T = _inverse(jnp.where(below, A0, 0.0) * brow, eye)
+        T = _inverse_by_halving(A0 * brow, _pair_masks(row, col), row, col)
 
     bcol = jnp.sum(
         jnp.where(eye, jnp.broadcast_to(brow, (ROWS, ROWS)), 0.0), axis=1, keepdims=True
@@ -292,15 +301,13 @@ def _local_safe(mm, q, k, v, g, brow, T=None):
     same = (row // CHUNK) == (col // CHUNK)
     upto, below = same & (col <= row), same & (col < row)
     eye = row == col
-    differ = row ^ col           # its highest bit is the pair's level
+    masks = _pair_masks(row, col)
 
     *halvings, (G, to_end) = _segments(g)
     P0 = jnp.where(eye, mm(q, k, _NT), 0.0)
     A0 = jnp.zeros_like(P0)
     levels = []
-    for i, (D, E) in enumerate(halvings):
-        h = 1 << i
-        mask = (differ >= h) & (differ < 2 * h) & (col < row)
+    for mask, (D, E) in zip(masks, halvings):
         eDh, eEh = jnp.exp(D), jnp.exp(E)
         x, y = jnp.concatenate([q * eDh, k * eDh], axis=0), k * eEh
         out = mm(x, y, _NT)                          # (2 ROWS, ROWS)
@@ -309,7 +316,7 @@ def _local_safe(mm, q, k, v, g, brow, T=None):
         levels.append((mask, x, y, eDh, eEh))
     Pm = P0 * brow
     if T is None:
-        T = _inverse_by_halving(A0 * brow, [level[0] for level in levels], eye)
+        T = _inverse_by_halving(A0 * brow, masks, row, col)
 
     bcol = jnp.sum(
         jnp.where(eye, jnp.broadcast_to(brow, (ROWS, ROWS)), 0.0), axis=1, keepdims=True
@@ -495,8 +502,8 @@ def _fwd_kernel(blocks, save, mm, safe=False):
             state_ref[...] = jnp.zeros_like(state_ref)
 
         state = state_ref[...]
-        # unrolled: a block's products that do not wait for the state are
-        # the scheduler's to run beside the block before's chain
+        # unrolled, yet the final bundles run the blocks one after another:
+        # a block's inverse is a chain of products the MXUs wait through
         for i in range(blocks):
             rows = slice(i * ROWS, (i + 1) * ROWS)
             o, state, starts, T = _block_fwd(

@@ -670,8 +670,13 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
 #: backward's replay of their matmuls is gone from the program (4,354
 #: instructions -> 4,212; PR 48's parent read 54269a1a...f76e27, and PR 49's
 #: parent, a202f4f, still did).  The rehearsal's digests of ``tests/
-#: test_shared_step_text.py`` (``remat`` off) did not move.
-LING3_KDA_LAYER = "648af705e99cbed3e600f24a3049f199103b2b8c37d71e2eeebc47a5554a2dc1"
+#: test_shared_step_text.py`` (``remat`` off) did not move.  Since PR 53 the
+#: VMEM a kernel's body USED (``used_scoped_memory_configs``) is stripped with
+#: the body it belongs to: ``kda_fwd``'s went 5,369,856 -> 5,677,056 bytes
+#: with its inverse and nothing else of the layer moved: PR 53's parent
+#: (700e969, which read 648af705...554a2dc1 with that field in) and its own
+#: tree both read the digest below.
+LING3_KDA_LAYER = "91dd79c3460ec6b4f3fabfed40a01d757676585ddb5caabd04eee2a4e1c433c4"
 
 
 def test_ling3_kda_layer_is_the_parents_program(v5e, monkeypatch):
@@ -679,7 +684,9 @@ def test_ling3_kda_layer_is_the_parents_program(v5e, monkeypatch):
 
     text = _step_text("train_ling3_t8192_b2", 1, v5e, monkeypatch, layers=(5,))
     assert "kda_fwd" in text and "kda_bwd" in text and "kda_decay_fwd" in text
-    strip = re.compile(r', metadata=\{[^}]*\}|"body":"[^"]*"')
+    strip = re.compile(
+        r', metadata=\{[^}]*\}|"body":"[^"]*"|"used_scoped_memory_configs":\[[^\]]*\]'
+    )
     lines = [
         strip.sub("", line) for line in text.splitlines()
         if re.match(r"\s*(?:ROOT )?%", line)
@@ -710,6 +717,23 @@ def test_kda_kernels_compile_under_the_unbounded_gate(one_chip):
          ((H,), jnp.float32)],
         one_chip,
     )
+
+
+def test_kda_kernels_compile_at_olmoh_padded_shapes(one_chip):
+    """``train_olmoh_t8192_b1``'s delta core alone as ``_decay_a_head`` hands
+    it over, forward and gradient, for any ``g <= 0``: 30 head-sequences of
+    8,192 tokens, keys padded to 128 columns beside values padded to 256."""
+    from accl_tpu.ops.pallas import kda
+
+    B, H, T, dk, dv = 1, 30, 8192, 128, 256
+    struct = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    args = [struct(B, H, T, dk)] * 2 + [struct(B, H, T, dv), struct(B, H, T, dk), struct(B, H, T)]
+    assert kda.takes(args[0].shape, args[2].shape) and kda.takes_padded(96, 192)
+    core = lambda *a: kda.kda(*a, safe=True, interpret=False)
+    text = jax.jit(jax.grad(
+        lambda *a: core(*a).sum(), argnums=(0, 1, 2, 3, 4)
+    )).lower(*args).compile().as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
 
 
 def test_olmoh_step_takes_the_kernels_at_padded_heads(v5e, monkeypatch):

@@ -10,11 +10,13 @@ the tests of the MATHEMATICS run the kernels with float32 products
 (``exact``) and hold them to float32's noise; the shipped rounding is
 held to bfloat16's."""
 
+from functools import partial, reduce
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from accl_tpu.ops import kda
@@ -162,6 +164,96 @@ def test_the_replayed_forward_gives_the_same_gradients():
     got = _with_grads(jax.checkpoint(fn), x, co)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(a, b)
+
+
+# -- the inverse ---------------------------------------------------------------------
+
+
+def _highest_rows(jaxpr):
+    """The rows of the left factors of a traced program's ``highest``
+    products, summed (a ``pallas_call``'s body and every other inner
+    program included)."""
+    rows = 0
+    for eqn in jaxpr.eqns:
+        precision = eqn.params.get("precision") if eqn.primitive.name == "dot_general" else None
+        if precision is not None and set(np.ravel(precision)) == {lax.Precision.HIGHEST}:
+            rows += eqn.invars[0].aval.shape[0]
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(v, "jaxpr", v)              # a closed program's
+                if hasattr(inner, "eqns"):
+                    rows += _highest_rows(inner)
+    return rows
+
+
+@pytest.mark.parametrize("safe", [False, True])
+def test_the_forwards_highest_products_stream_384_rows_a_block(safe):
+    """The engagement: level 1 a subtraction, levels 2 and 4 float32 on the
+    VPU, levels 8, 16 and 32 their lower rows alone through two products
+    (3 x 2 x 64), under both gates (six whole levels were 1,536 rows, the
+    bounded gate's squaring 1,280)."""
+    f32 = jnp.float32
+    rows, beta = jax.ShapeDtypeStruct((1, 256, 128), f32), jax.ShapeDtypeStruct((1, 2, 1, 128), f32)
+    traced = jax.make_jaxpr(partial(
+        kernels._forward, save=True, blocks=2, interpret=False, one_pass=jnp.bfloat16, safe=safe,
+    ))(rows, rows, rows, rows, beta)
+    assert _highest_rows(traced.jaxpr) == 2 * 384
+
+
+def _whole_levels(a, masks, eye, hi=partial(jnp.matmul, precision="highest")):
+    """The inverse by halving as it was: every level two whole products."""
+    inv = jnp.where(eye, 1.0, 0.0)
+    for mask in masks:
+        inv = inv - hi(hi(inv, jnp.where(mask, a, 0.0)), inv)
+    return inv
+
+
+def _saved_inverses(x, safe, monkeypatch, inverse=None):
+    """What the forward saves as the blocks' inverses, (blocks, ROWS, ROWS),
+    float32 products; ``inverse`` in the kernel's place where given."""
+    if inverse is not None:
+        monkeypatch.setattr(kernels, "_inverse_by_halving", inverse)
+    # not the jitted forward, which would answer from its cache
+    monkeypatch.setattr(
+        kernels, "_forward", getattr(kernels._forward, "__wrapped__", kernels._forward)
+    )
+    how = (kernels.default_interpret(None), jnp.float32, safe)
+    return kernels._apply(*x, how, save=True)[1][1][0]
+
+
+@pytest.mark.parametrize("case", ["128x1", "200x2", "520x4"])
+@pytest.mark.parametrize("safe", [False, True])
+def test_the_saved_inverse_is_the_whole_levels_and_the_xla_forms(case, safe, monkeypatch):
+    """The blocks' ``A`` as the kernel built them (saved in the inverse's
+    place), then: the saved inverse against all six levels WHOLE (what
+    multiplying the zero rows too gave) and, under a bound, against the
+    XLA form's inverse by squaring, chunk by chunk."""
+    length, blocks = LENGTHS[case]
+    monkeypatch.setattr(kernels, "BLOCKS", blocks)
+    shipped = kernels._inverse_by_halving
+    q, k, v, g, beta = _inputs(length, H=1)
+    if safe:                     # gates down to -200, a write strength in (0, 2)
+        g, beta = 40.0 * g, 2.0 * beta
+    x = (q, k, v, g, beta)
+    got = _saved_inverses(x, safe, monkeypatch)
+    a = _saved_inverses(
+        x, safe, monkeypatch,
+        lambda a, masks, row, col: jnp.where(reduce(jnp.logical_or, masks), a, 0.0),
+    )
+    assert got.shape == a.shape == (-(-length // (blocks * 128)) * blocks, 128, 128)
+    row, col = (lax.broadcasted_iota(jnp.int32, (128, 128), i) for i in (0, 1))
+    masks, eye = kernels._pair_masks(row, col), row == col
+    assert float(jnp.abs(a).max()) > 0.01 and not bool(jnp.any(jnp.triu(a)))
+    _close(got, jax.vmap(lambda a: _whole_levels(a, masks, eye))(a), 1e-6)
+    # level 1 has no product: the whole level to the bit
+    first = jax.vmap(lambda a: shipped(a, masks[:1], row, col))(a)
+    np.testing.assert_array_equal(
+        first, jax.vmap(lambda a: _whole_levels(a, masks[:1], eye))(a)
+    )
+    if not safe:
+        chunks = lambda m: jnp.stack([m[:, :64, :64], m[:, 64:, 64:]])
+        with jax.default_matmul_precision("highest"):
+            _close(chunks(got), kda._unit_lower_inverse(chunks(a)), 1e-6)
 
 
 # -- the shape rule ----------------------------------------------------------------

@@ -74,11 +74,16 @@ REF = dict(n_head=4, n_kv_head=2, top_k=3, routed_scaling_factor=1.0,
 #: results, to the bit) are what they were.  ``xla_*``: the XLA form of the
 #: core and of the decay chain at Ling-3.0's rehearsal head width; the rest:
 #: the kernels at the Ling-3.0 cell's shapes (a kernel's jaxpr, without the
-#: file's line numbers).
+#: file's line numbers).  ``core`` was re-recorded IN PLACE in PR 53, by
+#: intent, on its own tree: the bounded gate's forward takes the inverse by
+#: halving (level 1 a subtraction, levels 2 and 4 on the VPU, the upper
+#: levels' lower rows alone through ``highest`` products) where it squared
+#: powers of ``A`` (ten products), so its kernel's body changed at the same
+#: precision (PR 47's read e7f55e04...d7b0d9dc); the other six did not move.
 PARENT_PROGRAMS = {
     "xla_core": "483dc4107869af5d8cd11b16005aea6c5187d1bc99abf3f26738d920737b3627",
     "xla_decay": "1d1c4b298cf92921b2acf709f7ac303c088cfb6f9ad8c5148868dc7eda68e33d",
-    "core": "e7f55e04be2f41ad661116f225fcaad6c677791e36f20b208076e171d7b0d9dc",
+    "core": "a4f385f0b93a5b9ccbfd3687d035865dc4694b6955afa7faa5e61d0c4294bdd3",
     "q": "de344cee8ecae1f41dee1c2c47e0eac28e7fef07cd2375ddfaebca1ab2f7385e",
     "v": "3fe8b1457422fac9d3192cd1b7c43bc591d6aac144e3ae4762e4bbfd02e7b631",
     "decay": "fad34474a5d3b69fdfd5f5da9bc5525ecd52e08a9c1250067a85bff487699a5a",
