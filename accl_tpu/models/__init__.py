@@ -13,6 +13,7 @@ machinery is the substrate for.
 from .transformer import (
     BlockDiffusion,  # noqa: F401
     DeltaAttention,  # noqa: F401
+    HeadGeometry,  # noqa: F401
     LatentAttention,  # noqa: F401
     LayerKind,  # noqa: F401
     Mamba2,  # noqa: F401

@@ -38,6 +38,24 @@ from ..utils.profiling import device_scope
 
 
 @dataclasses.dataclass(frozen=True)
+class HeadGeometry:
+    """The geometry of an ``"attention"`` mixer's heads where it is not the
+    plain one (``LayerKind.heads``): ``rope_dim``, the FIRST columns of a q
+    or k head that rotate, the rest carrying no position (a published
+    ``partial_rotary_factor``; ``None``: every column; the two parts go to
+    the attention lowerings apart, the rotating one as the scores' second
+    part, so a head of 128 + 64 is two operands of whole lanes to the flash
+    kernels and not one of 192 padded to 256); ``v_dim``, the width of a v
+    head, of the output a head and of ``wo``'s rows a head where it is not
+    q's and k's (``None``: theirs); ``v_scale`` multiplies v (a published
+    ``attention_value_scale``)."""
+
+    rope_dim: Optional[int] = None
+    v_dim: Optional[int] = None
+    v_scale: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerKind:
     """One layer of a pattern (``TransformerConfig.layers``): its mixer
     and its FFN.  ``window``: how many keys a query sees, its own among
@@ -56,13 +74,27 @@ class LayerKind:
     elsewhere (``TransformerConfig.mixer``).  A block with ``"none"`` for
     one of the two has ONE sub-layer, ``h + f(norm h)`` with one norm and
     one residual (``ln1`` a mixer's, ``ln2`` an FFN's: the other is not
-    in the tree); it cannot have ``"none"`` for both."""
+    in the tree); it cannot have ``"none"`` for both.
+
+    What differs by kind among ``"attention"`` mixers of one stack:
+    ``kv_heads``, this kind's K/V heads (``None``: the config's
+    ``n_kv_heads``; the tree's ``wk`` / ``wv`` are that wide and the mixer
+    reads the count off them); ``rope_base``, this kind's rotary base
+    (``None``: the config's); ``sink``, one learned float32 scalar a QUERY
+    head (the tree's ``sink``, ``(n_heads,)``) that stands in every row's
+    softmax as a key without a value, after the scale; ``heads``, the
+    heads' geometry (:class:`HeadGeometry`; ``None``: every column rotates,
+    v as wide as q and k, no value scale)."""
 
     window: Optional[int] = None
     rope: bool = True
     ffn: str = "dense"
     d_ff: int = 0
     mixer: Optional[str] = None
+    kv_heads: Optional[int] = None
+    rope_base: Optional[float] = None
+    sink: bool = False
+    heads: Optional[HeadGeometry] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -522,8 +554,12 @@ class TransformerConfig:
     # through jax.nn.softmax.
     attention: str = "auto"
 
-    def kv_heads(self) -> int:
+    def kv_heads(self, kind: Optional[LayerKind] = None) -> int:
+        """The K/V heads of an attention layer of ``kind`` (its own count
+        where it has one), or the config's."""
         n_kv = self.n_heads if self.n_kv_heads is None else self.n_kv_heads
+        if kind is not None and kind.kv_heads is not None:
+            n_kv = kind.kv_heads
         if n_kv <= 0 or self.n_heads % n_kv:
             raise ValueError(
                 f"n_kv_heads ({n_kv}) must divide n_heads ({self.n_heads})"
@@ -781,6 +817,7 @@ class TransformerConfig:
                         f"layer {i} rotates but pos_embedding is "
                         f"{self.pos_embedding!r}"
                     )
+                self._check_attention_kind(i, kind, mixer)
         beyond = (
             self.moe_router != "softmax" or self.moe_shared_d_ff
             or self.moe_router_experts is not None or self.moe_n_group != 1
@@ -803,6 +840,36 @@ class TransformerConfig:
                 f"the router's {self.moe_router_experts}"
             )
 
+    def _check_attention_kind(self, i: int, kind: LayerKind, mixer: str):
+        """What a kind says of its OWN attention heads (``kv_heads``,
+        ``rope_base``, ``sink``, ``heads``) is the ``"attention"`` mixer's."""
+        g = kind.heads
+        own = (kind.kv_heads, kind.rope_base, g)
+        if not kind.sink and all(x is None for x in own):
+            return
+        if mixer != "attention" or self.diffusion is not None:
+            raise ValueError(
+                f"layer {i}: kv_heads, rope_base, sink and heads are the "
+                f"attention mixer's under a causal mask, not the {mixer} "
+                "mixer's or block diffusion's"
+            )
+        self.kv_heads(kind)
+        if g is None:
+            return
+        hd = self.head_size()
+        if (
+            self.qk_norm or self.attn_gate or g.v_scale <= 0.0
+            or (g.v_dim is not None and g.v_dim < 1)
+            or (g.rope_dim is not None
+                and not (0 < g.rope_dim <= hd and g.rope_dim % 2 == 0))
+        ):
+            raise ValueError(
+                f"layer {i}: a head geometry (LayerKind.heads) rotates an "
+                f"even number of a head's {hd} columns, has a v_dim of at "
+                "least 1 and a v_scale above 0, and is not built beside "
+                f"QK-norm or the attention gate; got {g}"
+            )
+
     def default_block(self) -> bool:
         """The block the encoder and the composed pipeline compute."""
         return (
@@ -820,7 +887,8 @@ def _check_axis_compat(cfg) -> None:
             "context_parallel and seq_parallel take the plain block only "
             "(TransformerConfig.plain): no layer pattern, window, head_dim, "
             "gate, per-head QK-norm, post-norm, scaled embedding, sigmoid "
-            "router, shared expert, held share, latent mixer (MLA), KDA "
+            "router, shared expert, held share, K/V heads, rope base, sink or "
+            "head geometry of a layer kind's own, latent mixer (MLA), KDA "
             "mixer or Mamba-2 mixer (a recurrent state is not handed round "
             "the ring yet), block of one sub-layer, latent expert bank, "
             "relu2, "
@@ -988,6 +1056,8 @@ def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
             "wv": col,
             "wo": row,  # (heads * head_size / tp, d_model)
         }
+        if kind.sink:
+            layer["sink"] = P(heads)  # a scalar a query head
     # one norm a sub-layer: a block without a mixer has no ``ln1``, one
     # without an FFN no ``ln2``
     softmax_mixer = mixer in ("attention", "latent")
@@ -1094,7 +1164,7 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
         )
     gated = cfg.ffn == "swiglu"
     hd = cfg.head_size()
-    d_q, d_kv = cfg.n_heads * hd, cfg.kv_heads() * hd
+    d_q = cfg.n_heads * hd
 
     def normal(key, shape):
         return jax.random.normal(key, shape, cfg.dtype) * scale
@@ -1226,12 +1296,21 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
         elif mixer == "latent":
             layer = latent_mixer(kk[0], cfg.latent)
         else:
+            # the kind's own K/V heads and v width where it has them
+            n_kv = cfg.kv_heads(kind)
+            dv = (kind.heads and kind.heads.v_dim) or hd
             layer = {
                 "wq": normal(kk[0], (cfg.d_model, d_q)),
-                "wk": normal(jax.random.fold_in(kk[0], 1), (cfg.d_model, d_kv)),
-                "wv": normal(jax.random.fold_in(kk[0], 2), (cfg.d_model, d_kv)),
-                "wo": normal(kk[1], (d_q, cfg.d_model)),
+                "wk": normal(
+                    jax.random.fold_in(kk[0], 1), (cfg.d_model, n_kv * hd)
+                ),
+                "wv": normal(
+                    jax.random.fold_in(kk[0], 2), (cfg.d_model, n_kv * dv)
+                ),
+                "wo": normal(kk[1], (cfg.n_heads * dv, cfg.d_model)),
             }
+            if kind.sink:
+                layer["sink"] = jnp.zeros((cfg.n_heads,), jnp.float32)
         softmax_mixer = mixer in ("attention", "latent")
         if cfg.post_norm != "only":
             if mixer != "none":
@@ -1255,7 +1334,7 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
             layer["k_norm"] = jnp.ones((hd,), cfg.dtype)
         elif qk_norm:
             layer["q_norm"] = jnp.ones((d_q,), cfg.dtype)
-            layer["k_norm"] = jnp.ones((d_kv,), cfg.dtype)
+            layer["k_norm"] = jnp.ones((layer["wk"].shape[1],), cfg.dtype)
         if kind.ffn == "moe":
             from .moe import init_moe_params
 
@@ -1587,7 +1666,14 @@ _AUTO_FUSED_MIN_T = 1024
 # uses it only while K+V fit this budget (4 MiB = T 8192 at hd<=128
 # bf16; the gate scales with the PADDED head dim and dtype width, so
 # wide-head or f32 configs fall back to the streaming XLA fold instead
-# of failing Mosaic's VMEM allocation)
+# of failing Mosaic's VMEM allocation).  A head whose rotating part is
+# passed APART (``q_rope`` / ``k_rope``: the latent mixer's, and a
+# ``HeadGeometry.rope_dim``'s) is gated by each part's own padded width:
+# the gate sees the first part alone (``_attention``), the second rides
+# beside it on its own lanes.  The MiMo-V2.5 cell is the case: a head of
+# 192 as ONE operand pads to 256 lanes and stops ``auto`` at T = 4,096;
+# as 128 columns without position beside a rotating 64 (padded to 128)
+# it runs the kernels at 8,192
 _AUTO_FLASH_KV_BYTES = 4 * 2**20
 
 
@@ -1624,7 +1710,7 @@ def resolve_attention(impl: str, q) -> str:
 
 def _attention(q, k, v, impl: str = "naive", causal: bool = True,
                window: Optional[int] = None, scale: Optional[float] = None,
-               q_rope=None, k_rope=None, block_diffusion=None):
+               q_rope=None, k_rope=None, block_diffusion=None, sink=None):
     """Attention; q,k,v: (B, H, T, hd); ``causal=False`` is the
     bidirectional (encoder) form; ``window`` (causal only) keeps a
     query's last ``window`` keys, its own among them, in every lowering.
@@ -1637,6 +1723,9 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
     ``block_diffusion=(L, B)`` is the block-diffusion layout of ``T = 2 L``
     rows in place of ``causal``: tile lists in the flash kernels, a dense
     mask in the XLA forms (``ops.attention.block_diffusion_visible``).
+    ``sink`` (H,): one learned scalar a query head that stands in every
+    row's softmax as a key without a value, in every lowering (the carry's
+    first term in the two folds, one more column here).
 
     ``impl="auto"`` resolves through :func:`resolve_attention`;
     ``"blockwise"`` runs the fused online-softmax fold (no (T, T) score
@@ -1650,7 +1739,9 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
         if scale is None:
             scale = (q.shape[-1] + q_rope.shape[-1]) ** -0.5
         if impl != "flash":
-            B, H, T, _ = q.shape
+            # k's second part on as many heads as k has (all of q's under
+            # the latent mixer, whose ``expand(k)`` is then the identity)
+            B, H, T = q.shape[0], k.shape[1], q.shape[2]
             expand = lambda t: jnp.broadcast_to(
                 t[:, :, None], (B, t.shape[1], H // t.shape[1], T, t.shape[-1])
             ).reshape(B, H, T, t.shape[-1])
@@ -1666,6 +1757,8 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
         if window is not None:
             raise ValueError("block diffusion has no window")
         windowed["block_diffusion"] = block_diffusion
+    if sink is not None and impl != "naive":
+        windowed["sink"] = sink
     if impl == "blockwise":
         from ..ops.attention import blockwise_attention
 
@@ -1709,7 +1802,17 @@ def _attention(q, k, v, impl: str = "naive", causal: bool = True,
         if window is not None:
             mask &= ~jnp.tril(jnp.ones((T, T), bool), -window)
         scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    if sink is not None:
+        # one more column a row, the head's scalar; it takes its share of
+        # the row's probability and has no value
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, Hkv, H // Hkv, 1, 1),
+            (*scores.shape[:-1], 1),
+        )
+        scores = jnp.concatenate([scores, column], axis=-1)
+        probs = jax.nn.softmax(scores, axis=-1)[..., :-1].astype(v.dtype)
+    else:
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v)
     return out.reshape(B, H, T, v.shape[-1])
 
@@ -1978,7 +2081,8 @@ def _mamba2_partial(h, lp, mamba):
 def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
                   rope_base=None, positions=None, attention_fn=None,
                   tp_axis=None, window=None, head_norm=False, latent=None,
-                  qk_eps=1e-5, block_diffusion=None, kda=None, mamba=None):
+                  qk_eps=1e-5, block_diffusion=None, kda=None, mamba=None,
+                  geometry=None):
     """Column-parallel attention on a full-sequence activation: returns
     the row-parallel PARTIAL output (pre-reduction) and the (k, v) head
     tensors (B, Hkv_local, T, hd) for KV-cache prefill.  The kv head
@@ -2002,8 +2106,15 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     (:func:`_mamba2_partial`) and an ``a_log`` without one the KDA mixer's
     (:func:`_kda_partial`; both likewise).  ``window`` is the sliding window,
     run under the device scope ``accl.attn::window`` (full attention stays
-    ``accl.attn::core``; where the stack has KDA layers, ``kda`` given, what
-    is round the core runs under ``accl.attn::gqa_proj``).  ``qk_eps`` is QK-norm's epsilon.
+    ``accl.attn::core``; where the stack has KDA layers, ``kda`` given, or
+    the layer a head geometry, what is round the core runs under
+    ``accl.attn::gqa_proj``).  A ``sink`` leaf (a float32 scalar a query
+    head) stands in every row's softmax as a key without a value; v's width
+    is ``wv``'s over the K/V heads that ``wk``'s gives.  ``geometry``
+    (:class:`HeadGeometry`) is what the shapes cannot say: of a head's
+    columns the FIRST ``rope_dim`` rotate and go to the core as the scores'
+    second part (``q_rope`` / ``k_rope`` on the K/V heads), the others carry
+    no position; v is times ``v_scale``.  ``qk_eps`` is QK-norm's epsilon.
     ``block_diffusion=(L, B)``: ``h`` is ``[noisy ; clean]``, ``2 L`` rows
     that rotate at positions ``0..L`` twice, and the core runs under that
     layout in the device scope ``accl.attn::blockdiff``."""
@@ -2021,30 +2132,57 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     # runs what is round its core under a device scope of its own, as the
     # KDA layers do; every other stack's program is what it was
     proj_scope = (
-        device_scope("accl.attn::gqa_proj") if kda is not None
+        device_scope("accl.attn::gqa_proj")
+        if kda is not None or geometry is not None
         else contextlib.nullcontext()
     )
     with proj_scope:
         q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]  # column-parallel
-    if not head_norm:
-        q, k = _qk_norm(q, k, lp, tp_axis, qk_eps)
-    hd = q.shape[-1] // n_heads_local
-    n_kv_local = k.shape[-1] // hd
-    heads = lambda t, n: t.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
-    q, k, v = (
-        heads(q, n_heads_local), heads(k, n_kv_local), heads(v, n_kv_local)
-    )
-    if head_norm and "q_norm" in lp:
-        q = _rmsnorm(q, lp["q_norm"], eps=qk_eps)
-        k = _rmsnorm(k, lp["k_norm"], eps=qk_eps)
-    if rope_base is not None:
-        if block_diffusion is not None:
-            pos = jnp.tile(jnp.arange(block_diffusion[0]), 2)
-        else:
-            pos = jnp.arange(T) if positions is None else positions
-        tables = _rope_tables(pos, hd // 2, rope_base)
-        q = _rope_rotate(q, tables)
-        k = _rope_rotate(k, tables)
+    # a head geometry's own work between the projections and the core (the
+    # value scale, the split into the part that rotates and the part without
+    # position, the sink's cast) goes under the same scope; without one the
+    # lines below trace outside any scope, as they always did
+    with proj_scope if geometry is not None else contextlib.nullcontext():
+        if not head_norm:
+            q, k = _qk_norm(q, k, lp, tp_axis, qk_eps)
+        hd = q.shape[-1] // n_heads_local
+        n_kv_local = k.shape[-1] // hd
+        # v's heads are as wide as ``wv`` makes them
+        heads = lambda t, n: t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
+        q, k, v = (
+            heads(q, n_heads_local), heads(k, n_kv_local), heads(v, n_kv_local)
+        )
+        if geometry is not None and geometry.v_scale != 1.0:
+            v = v * geometry.v_scale
+        # the scores' second part and the sink, where the layer has them
+        more = {}
+        if "sink" in lp:
+            # inside a shard_map: the sink varying over every axis q varies
+            # over (the batch's too), so that its cotangent is summed over
+            # them by the cast's transpose, as the latent mixer's one rope
+            # key is
+            more["sink"] = sink = lp["sink"]
+            if missing := tuple(jax.typeof(q).vma - jax.typeof(sink).vma):
+                more["sink"] = jax.lax.pcast(sink, missing, to="varying")
+        if head_norm and "q_norm" in lp:
+            q = _rmsnorm(q, lp["q_norm"], eps=qk_eps)
+            k = _rmsnorm(k, lp["k_norm"], eps=qk_eps)
+        if rope_base is not None:
+            if block_diffusion is not None:
+                pos = jnp.tile(jnp.arange(block_diffusion[0]), 2)
+            else:
+                pos = jnp.arange(T) if positions is None else positions
+            dr = hd if geometry is None else geometry.rope_dim or hd
+            tables = _rope_tables(pos, dr // 2, rope_base)
+            if dr == hd:
+                q = _rope_rotate(q, tables)
+                k = _rope_rotate(k, tables)
+            else:
+                more.update(
+                    q_rope=_rope_rotate(q[..., :dr], tables),
+                    k_rope=_rope_rotate(k[..., :dr], tables),
+                )
+                q, k = q[..., dr:], k[..., dr:]
     if block_diffusion is not None:
         with device_scope("accl.attn::blockdiff"):
             attn = _attention(
@@ -2055,11 +2193,13 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
             if attention_fn is not None:
                 attn = attention_fn(q, k, v)
             else:
-                attn = _attention(q, k, v, impl=attn_impl, causal=causal)
+                attn = _attention(
+                    q, k, v, impl=attn_impl, causal=causal, **more
+                )
     else:
         with device_scope("accl.attn::window"):
             attn = _attention(
-                q, k, v, impl=attn_impl, causal=causal, window=window
+                q, k, v, impl=attn_impl, causal=causal, window=window, **more
             )
     with proj_scope:
         attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
@@ -2074,7 +2214,8 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
            ep_axis=None, moe_cfg=None, with_aux=False,
            reduce_fn=None, fanout_fn=None, norm=_layernorm,
            window=None, head_norm=False, latent=None, qk_eps=1e-5,
-           block_diffusion=None, kda=None, mamba=None, relu2=False):
+           block_diffusion=None, kda=None, mamba=None, relu2=False,
+           geometry=None):
     """One transformer block on tp-sharded weights.  ``lp['wqkv']`` etc. are
     the *local shards*; the tp-allreduce after each row-parallel matmul is
     the reference's fused-allreduce hot path in model form.
@@ -2109,7 +2250,7 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
             h, lp, n_heads_local, attn_impl, causal, rope_base,
             tp_axis=tp_axis, window=window, head_norm=head_norm,
             latent=latent, qk_eps=qk_eps, block_diffusion=block_diffusion,
-            kda=kda, mamba=mamba,
+            kda=kda, mamba=mamba, geometry=geometry,
         )
         if tp_axis is not None:
             partial_o = reduce_fn(partial_o, tp_axis)
@@ -2264,10 +2405,13 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
             f"n_heads ({cfg.n_heads}) must be divisible by tp ({tp_size}) "
             "so every chip owns whole heads of the latent mixer"
         )
-    if tp_size > 1 and cfg.latent is None and cfg.kv_heads() % tp_size:
+    kv_counts = sorted({cfg.kv_heads(kind) for kind in cfg.pattern()})
+    if tp_size > 1 and cfg.latent is None and any(
+        n % tp_size for n in kv_counts
+    ):
         raise ValueError(
-            f"n_kv_heads ({cfg.kv_heads()}) must be divisible by tp "
-            f"({tp_size}) so every chip owns whole kv heads"
+            f"n_kv_heads ({', '.join(map(str, kv_counts))}) must be divisible "
+            f"by tp ({tp_size}) so every chip owns whole kv heads"
         )
     sp = cfg.seq_parallel and tp_axis is not None and tp_size > 1
     kw = dict(
@@ -2357,7 +2501,8 @@ def _layer_blocks(block, cfg):
     blocks = [
         partial(
             block, window=kind.window,
-            rope_base=cfg.rope_base if kind.rope else None,
+            rope_base=(kind.rope_base or cfg.rope_base) if kind.rope else None,
+            geometry=kind.heads,
         )
         for kind in cfg.layers
     ]
@@ -2597,7 +2742,11 @@ def _reject_unservable(cfg) -> None:
             "configuration has a layer pattern, a window, a head_dim of "
             "its own, an attention gate, per-head QK-norm, post-norms, a "
             "scaled embedding, a sigmoid router, a shared expert, a "
-            "held share of the experts, a latent mixer (MLA: its cache is "
+            "held share of the experts, K/V heads, a rope base, a sink or a "
+            "head geometry of a layer kind's own (LayerKind.kv_heads, "
+            ".rope_base, .sink, .heads: the cache would be a window's keys "
+            "beside every key, on two head counts), a latent mixer (MLA: its "
+            "cache is "
             "the latent and the rope key, not k and v), a KDA mixer or a "
             "Mamba-2 mixer (the cache is a recurrent state and a "
             "convolution's last inputs), a block of one sub-layer, a latent "
@@ -2643,6 +2792,17 @@ def reject_latent(cfg, where: str) -> None:
         raise ValueError(
             "block diffusion (TransformerConfig.diffusion) is supported on "
             f"the decoder's train and forward paths only, not {where}"
+        )
+    if any(
+        k.sink or (k.kv_heads, k.rope_base, k.heads) != (None,) * 3
+        for k in cfg.layers or ()
+    ):
+        raise ValueError(
+            "attention heads of a layer kind's own (LayerKind.kv_heads, "
+            ".rope_base), a sink in the softmax (LayerKind.sink) and a head "
+            "geometry (LayerKind.heads: partial rotary, a v width of its "
+            "own, a value scale) are supported on the decoder's train and "
+            f"forward paths only, not {where}"
         )
 
 

@@ -52,14 +52,16 @@ def block_diffusion_visible(q_pos, k_pos, L: int, block: int):
 
 
 def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
-                   window=None, scale=None, block_diffusion=None):
+                   window=None, scale=None, block_diffusion=None, sink=None):
     """One head, q and k (T, D) and v (T, Dv): scan q blocks; fold k
     blocks with online softmax.
 
     ``t_real`` masks padded key positions (T may be padded to block
     multiples by the wrapper); ``window`` keeps a query's last ``window``
     keys, its own among them (every tile is still folded: this is the
-    CPU tier and the fallback past the flash kernels' VMEM gate)."""
+    CPU tier and the fallback past the flash kernels' VMEM gate);
+    ``sink``: this head's scalar, the fold's first term (``m = sink, l =
+    1``: a key without a value in every row's softmax)."""
     T, D = q.shape
     nq, nk = T // bq, T // bk
     if scale is None:
@@ -115,6 +117,10 @@ def _attend_single(q, k, v, causal: bool, bq: int, bk: int, t_real: int,
             jnp.zeros_like(qb[:, :1], dtype=jnp.float32),
             jnp.zeros_like(v[:bq], dtype=jnp.float32),
         )
+        if sink is not None:
+            init = (
+                init[1] + sink.astype(jnp.float32), init[1] + 1.0, init[2]
+            )
         (m, l, acc), _ = lax.scan(fold, init, jnp.arange(nk))
         return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
@@ -137,6 +143,7 @@ def blockwise_attention(
     window: int | None = None,
     scale: float | None = None,
     block_diffusion: tuple | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Causal (or full) attention over ``(B, H, T, Dh)`` operands without
     materializing the (T, T) score matrix.  Exact (not approximate):
@@ -147,7 +154,9 @@ def blockwise_attention(
     ``window=W`` (causal only): query ``i`` sees keys ``0 <= i - j < W``,
     as ``ops.pallas.flash_attention`` has it.  ``block_diffusion=(L, B)``
     is its block-diffusion layout (:func:`block_diffusion_visible`) in
-    place of both, every tile folded under the dense mask.
+    place of both, every tile folded under the dense mask.  ``sink``
+    ``(H,)`` is its scalar a query head in each row's softmax, a key
+    without a value (causal only).
 
     Block sizes clamp to the (padded) sequence length; T is padded to a
     block multiple internally and the pad keys are masked out.
@@ -169,6 +178,11 @@ def blockwise_attention(
                 f"window, got T={T}, {block_diffusion}, window={window}"
             )
         causal = False
+    if sink is not None and (sink.shape != (H,) or not causal):
+        raise ValueError(
+            f"sink is one scalar a query head, ({H},), of a causal softmax; "
+            f"got {sink.shape}, causal={causal}"
+        )
     if Hkv != H:
         if Hkv <= 0 or H % Hkv:
             raise ValueError(
@@ -201,5 +215,9 @@ def blockwise_attention(
         _attend_single, causal=causal, bq=bq, bk=bk, t_real=T, window=window,
         scale=scale, block_diffusion=block_diffusion,
     )
-    out = jax.vmap(jax.vmap(single))(q, k, v)
+    if sink is None:
+        out = jax.vmap(jax.vmap(single))(q, k, v)
+    else:
+        a_head = lambda q, k, v, s: single(q, k, v, sink=s)
+        out = jax.vmap(jax.vmap(a_head), in_axes=(0, 0, 0, None))(q, k, v, sink)
     return out[:, :, :T]

@@ -610,7 +610,7 @@ def flash_tile_pairs(T: int, block: int = 512, window=None,
 
 
 def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
-                  window=None, rope=False, layout=None):
+                  window=None, rope=False, layout=None, sink=None):
     """One grid step computes one (bq, Dv) output block: fold the visiting
     k/v blocks with online softmax.  Outputs are written exactly once per
     grid step (blocked o spec): every grid axis of the FORWARD is
@@ -638,7 +638,14 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
     (:func:`_layout_ranges`; ``nkb`` tiles in two halves, ``t_real`` a
     half's real length).  The two halves' q tiles fold different lists, so
     the step branches once on its half and each branch is traced with its
-    own ranges and its own in-line masked tiles."""
+    own ranges and its own in-line masked tiles.
+
+    ``sink``: the number of heads ``H`` of one more operand, a learned
+    scalar a head ``(H,)`` in SMEM that stands in every row's softmax as a
+    key without a value (``flash_attention``'s ``sink``).  It is the fold's
+    FIRST term: the carry starts at ``m = sink, l = 1, acc = 0`` in place of
+    ``(-inf, 0, 0)`` and every body is what it was; the saved logsumexp then
+    holds the sink, which is all the backward kernel needs of it."""
 
     halves = 1 if layout is None else 2
     nh = nkb // halves
@@ -650,6 +657,8 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
         if rope:
             qr_ref, kr_ref, *rest = rest
             qr = qr_ref[0]  # (bq, Dr)
+        if sink:
+            sink_ref, *rest = rest
         o_ref, *maybe_lse = rest
         iq = pl.program_id(1)
         # operands stay in the input dtype (bf16 MXU fast path); the
@@ -725,6 +734,11 @@ def _flash_kernel(causal, scale, bq, bk, nkb, t_real, with_lse=False,
             jnp.zeros((bq, 1), jnp.float32),
             jnp.zeros((bq, v_ref.shape[-1]), jnp.float32),
         )
+        if sink:
+            init = (
+                jnp.full((bq, 1), sink_ref[pl.program_id(0) % sink]),
+                jnp.ones((bq, 1), jnp.float32), init[2],
+            )
         # causal early exit: with bq == bk, q block iq only sees k blocks
         # 0..iq; under a window only those that reach into it.  A row whose
         # keys in the first visited tile are all outside the window folds
@@ -852,7 +866,7 @@ def _flat_heads(a):
 
 def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse,
                     window=None, scale=None, q_rope=None, k_rope=None,
-                    layout=None):
+                    layout=None, sink=None):
     B, H, T, D = q.shape
     Hkv, Dv = k.shape[1], v.shape[-1]
     rope = q_rope is not None
@@ -901,10 +915,15 @@ def _flash_fwd_impl(q, k, v, causal, block, interpret, with_lse,
             pl.BlockSpec((1, Tp, Drp), _flash_kv_map(H, k_rope.shape[1]),
                          memory_space=pltpu.VMEM),
         ]
+    if sink is not None:
+        # a scalar a head, whole in SMEM: the step reads its head's
+        operands.append(sink.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
     res = pl.pallas_call(
         _flash_kernel(causal, scale, b, b, nkb, t_real, with_lse=with_lse,
-                      window=window, rope=rope, layout=layout),
+                      window=window, rope=rope, layout=layout,
+                      sink=None if sink is None else H),
         grid=(B * H, nq),
         out_shape=out_shape,
         in_specs=in_specs,
@@ -1228,29 +1247,43 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
     return grads + (rows_of(dqr, Dr), group_sum(dkr, Hr, Dr))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_vjp(q, k, v, q_rope, k_rope, causal, block, interpret,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _flash_vjp(q, k, v, q_rope, k_rope, sink, causal, block, interpret,
                     window, scale, layout):
-    """``scale``, the scores' second part and the block-diffusion
-    ``layout`` may each be ``None``."""
+    """``scale``, the scores' second part, the ``sink`` and the
+    block-diffusion ``layout`` may each be ``None`` (a ``None`` among the
+    differentiable arguments adds no leaf: a call without it traces what it
+    always did)."""
     out, _ = _flash_fwd_impl(q, k, v, causal, block, interpret,
                              with_lse=False, window=window, scale=scale,
-                             q_rope=q_rope, k_rope=k_rope, layout=layout)
+                             q_rope=q_rope, k_rope=k_rope, layout=layout,
+                             sink=sink)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, q_rope, k_rope, causal, block, interpret,
+def _flash_vjp_fwd(q, k, v, q_rope, k_rope, sink, causal, block, interpret,
                         window, scale, layout):
     out, lse = _flash_fwd_impl(q, k, v, causal, block, interpret,
                                with_lse=True, window=window, scale=scale,
-                               q_rope=q_rope, k_rope=k_rope, layout=layout)
-    return out, (q, k, v, q_rope, k_rope, out, lse)
+                               q_rope=q_rope, k_rope=k_rope, layout=layout,
+                               sink=sink)
+    return out, (q, k, v, q_rope, k_rope, sink, out, lse)
 
 
 def _flash_vjp_bwd(causal, block, interpret, window, scale, layout, res, g):
-    q, k, v, q_rope, k_rope, o, lse = res
-    return _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
-                           window, scale, q_rope, k_rope, layout)
+    """With a ``sink`` the backward kernel is as it is: ``exp(s - lse)``
+    with the sink inside ``lse`` IS the sink's softmax, and ``delta =
+    rowsum(dO * O)`` needs no term of the sink, whose value is zero.  The
+    sink's own gradient is ``-sum_rows p_sink * delta``, ``p_sink =
+    exp(sink - lse)``: a reduce of two row statistics, XLA's."""
+    q, k, v, q_rope, k_rope, sink, o, lse = res
+    grads = _flash_bwd_impl(q, k, v, o, lse, g, causal, block, interpret,
+                            window, scale, q_rope, k_rope, layout)
+    if sink is None:
+        return grads + (None,)
+    delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    p_sink = jnp.exp(sink.astype(jnp.float32)[None, :, None] - lse)
+    return grads + ((-(p_sink * delta).sum((0, 2))).astype(sink.dtype),)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -1268,6 +1301,7 @@ def flash_attention(
     q_rope: jax.Array | None = None,
     k_rope: jax.Array | None = None,
     block_diffusion: tuple | None = None,
+    sink: jax.Array | None = None,
     interpret: InterpretArg = None,
 ) -> jax.Array:
     """Local (single-chip) fused attention: ``(B, H, T, D) -> same`` with
@@ -1289,7 +1323,13 @@ def flash_attention(
     (batch*head) grid step — sized for serving/training sequence lengths
     (T <= ~8K at 128 lanes; the backward passes the sum of its residents
     as its VMEM limit, :func:`_flash_bwd_vmem_bytes`); the ring kernel
-    covers longer sequences across chips.
+    covers longer sequences across chips.  The model's ``auto`` gates on
+    the PADDED width of ``q`` (``models.transformer._auto_flash_fits``): a
+    head of 192 as one operand pads to 256 lanes and falls back to
+    ``blockwise`` past T = 4,096, while the same head with its rotating
+    part passed apart (``q_rope`` / ``k_rope``: 128 + 64, the MiMo-V2.5
+    cell's) is gated by each part's own padded width and stays here at
+    8,192.
 
     ``window=W`` (causal only) is sliding-window attention: query ``i``
     sees keys ``j`` with ``0 <= i - j < W``, its own among them.  Forward
@@ -1336,6 +1376,16 @@ def flash_attention(
     take any), an ``L`` off the tiles pads each half apart, and GQA, the
     two widths and the second score part work as without it.
 
+    ``sink`` ``(H,)``: a learned scalar a QUERY head that stands in every
+    row's softmax as one more key WITHOUT a value, after the scale: ``p_ij =
+    exp(s_ij - m_i) / (sum_j' exp(s_ij' - m_i) + exp(sink_h - m_i))`` with
+    ``m_i`` the larger of the row's largest score and the sink; it takes
+    probability and adds nothing (an attention sink; causal, with or without
+    a window, beside GQA, the two widths and the second score part; not under
+    a block-diffusion layout).  The forward kernel only starts its fold from
+    another carry and the backward kernel is untouched (``_flash_vjp_bwd``);
+    ``d sink`` is a reduce of the saved row statistics outside it.
+
     ``block=512`` is the measured optimum on v5e at T=4096: vs 256 the
     forward runs 2.1x faster (40.7 vs 19.6 TFLOPs) and the full T=4096
     train step gains 6.9 MFU points (62.1% -> 69.0%, A/B on the bench's
@@ -1375,6 +1425,14 @@ def flash_attention(
             )
     require_mosaic_dtypes(default_interpret(interpret), "flash attention",
                           q.dtype)
+    if sink is not None and (
+        sink.shape != (H,) or not causal or block_diffusion is not None
+    ):
+        raise ValueError(
+            f"sink is one scalar a query head, ({H},), of a causal softmax "
+            f"(no block-diffusion layout); got {sink.shape}, causal={causal}, "
+            f"block_diffusion={block_diffusion}"
+        )
     if block_diffusion is not None:
         L, B_len = (int(n) for n in block_diffusion)
         if window is not None or T != 2 * L:
@@ -1384,7 +1442,7 @@ def flash_attention(
             )
         _layout_tile(L, B_len, q.dtype, block)   # refuses what it cannot
         return _flash_vjp(
-            q, k, v, q_rope, k_rope, False, block, interpret, None,
+            q, k, v, q_rope, k_rope, None, False, block, interpret, None,
             None if scale is None else float(scale), (L, B_len),
         )
     if window is not None:
@@ -1395,6 +1453,6 @@ def flash_attention(
         if window >= T:
             window = None  # every earlier key is inside it
     return _flash_vjp(
-        q, k, v, q_rope, k_rope, causal, block, interpret, window,
+        q, k, v, q_rope, k_rope, sink, causal, block, interpret, window,
         None if scale is None else float(scale), None,
     )

@@ -132,14 +132,30 @@ drops ``op_name``, so a reader joins the two by instruction name
 
 ======================== ==================================================
 ``accl.attn::core``      ``models/transformer.py`` ``_attn_partial``: the
-                         attention call (flash kernels, or the XLA forms)
+                         attention call (flash kernels, or the XLA forms),
+                         on the K/V heads the layer's kind has
+                         (``LayerKind.kv_heads``); a head in two parts
+                         where ``LayerKind.heads`` rotates only its first
+                         columns (128 without position + a rotating 64 as
+                         the scores' second part, v of its own width)
 ``accl.attn::window``    the same under a ``LayerKind.window``: a sliding
-                         layer's attention call
+                         layer's attention call; with the sink a query
+                         head where the kind has one (``LayerKind.sink``:
+                         the fold's first term, ``d sink`` a reduce of the
+                         saved row statistics beside the backward kernel,
+                         under this scope too)
 ``accl.attn::gqa_proj``  the same where the stack has KDA layers beside it
                          (a gated grouped-query layer without position
-                         among linear-attention ones): q, k and v's
-                         projections, the gate a channel and ``wo``, what
-                         is round ``accl.attn::core`` there
+                         among linear-attention ones) or the layer a head
+                         geometry (``LayerKind.heads``): q, k and v's
+                         projections, the gate a channel, the heads'
+                         transpose back and ``wo``; under a head geometry
+                         ALSO what lies between the projections and the
+                         core (the heads' reshape, the value scale, the
+                         split into the two parts, their rope, the sink's
+                         cast); beside KDA layers that stretch stays
+                         outside any scope, as Solar's and Olmo's pinned
+                         programs have it
 ``accl.attn::latent``    ``_latent_attn_partial`` (a latent mixer, MLA): the
                          five projections (four where q has no latent),
                          the latent norms, the rope, the head-wise gate

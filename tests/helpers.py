@@ -176,6 +176,7 @@ REHEARSALS = {
         ("train_sdar_t4096_b2", 0),        # 77
         ("train_nemotron3_t8192_b1", 0),   # 73
         ("coll_w4_sweep", 0),              # 19
+        ("train_mimo_t8192_b1", 0),        # PR 54: the lightest group's
     ],
     "rest": [
         ("train_dsv2_t4096_b1", 0),        # 61

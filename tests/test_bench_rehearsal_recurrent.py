@@ -1,5 +1,5 @@
 """``perfbench.run``'s CPU rehearsals, one file a group of about equal
-cost (``helpers.REHEARSALS``, ROADMAP D14): the Ling-3.0, SDAR and Nemotron-3 cells' and the sweep's."""
+cost (``helpers.REHEARSALS``, ROADMAP D14): the Ling-3.0, SDAR, Nemotron-3 and MiMo-V2.5 cells' and the sweep's."""
 
 import pytest
 

@@ -789,6 +789,42 @@ def test_olmoh_step_takes_the_kernels_at_padded_heads(v5e, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes <= 11_371_662_336
 
 
+def test_mimo_step_takes_the_flash_kernels_in_both_kinds(v5e, monkeypatch):
+    """One sliding and one full layer of the MiMo-V2.5 cell's seven, 1 x
+    8,192 tokens, under ``remat`` as the cell runs (the rule fixed in ISSUE
+    54): the cores are ``flash_fwd`` (twice: the forward and its replay) and
+    ``flash_bwd`` under ``accl.attn::window`` (a window of 128 keys in tiles
+    of 512, the sink a head an operand in SMEM) and under ``accl.attn::core``,
+    at 64 heads x 8,192 x (128 + a rotating 64 | 128) on 8 and 4 KV heads, the
+    rotating key part on the KV heads and never expanded; the projections
+    under ``accl.attn::gqa_proj``; and no array larger than the float32
+    logits (8,192 x 19,072: nothing square in the length, no head of 192
+    padded to 256)."""
+    from perfbench import scope_ops
+
+    compiled = _step("train_mimo_t8192_b1", 2, v5e, monkeypatch, layers=(1, 6))
+    text = compiled.as_text()
+    entry = scope_ops.scopes_of(text)
+    for scope in ("accl.attn::window", "accl.attn::core",
+                  "accl.attn::gqa_proj", "accl.moe::experts"):
+        assert entry.get(scope), scope
+    for scope in ("accl.attn::window", "accl.attn::core"):
+        assert sum("flash_fwd" in n for n in entry[scope]) == 2, scope
+        assert sum("flash_bwd" in n for n in entry[scope]) == 1, scope
+    assert re.search(r"bf16\[1,64,8192,128\]", text)      # q without position
+    assert re.search(r"bf16\[1,8,8192,64\]", text)        # the rotating key part
+    assert re.search(r"bf16\[1,4,8192,64\]", text)
+    assert not re.search(r"bf16\[1,64,8192,256\]|\[64,8192,8192\]", text)
+    sizes = sorted({
+        int(np.prod([int(n) for n in dims.split(",")]))
+        for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
+    })
+    assert sizes[-1] <= 8192 * 19072
+    # the whole cell, seven layers: 3,315,502,080 bytes of scratch (my
+    # compile for the described chip, PR 54); this cut has two of them
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3_315_502_080
+
+
 def test_solar2_step_takes_the_kernels_under_the_unbounded_gate(
     v5e, monkeypatch
 ):
