@@ -27,7 +27,6 @@ from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
@@ -35,6 +34,7 @@ from jax import shard_map
 from ..constants import ReduceFunction
 from ..ops import collectives
 from ..utils.profiling import device_scope
+from ..utils.remat import KEPT_UNDER_REMAT, kept_under_remat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,14 +183,6 @@ KDA_HEAD_DECAY_DT = (1e-3, 0.1)
 #: the softplus values ``init_params`` draws the unbounded KDA gate's
 #: ``dt_bias`` for, log-uniform a channel (its docstring there says why)
 KDA_UNBOUNDED_DT = (1e-3, 16.0)
-
-#: the name (``jax.ad_checkpoint.checkpoint_name``) of what a mixer keeps
-#: of a block's forward when the block is rematerialised (``cfg.remat``):
-#: ``_layer_blocks`` saves the values under this name and replays the rest.
-#: The two recurrent mixers name their bf16 input projections; without
-#: ``remat`` the name is the identity.
-KEPT_UNDER_REMAT = "accl.remat::mixer_proj"
-
 
 @dataclasses.dataclass(frozen=True)
 class Mamba2:
@@ -345,15 +337,21 @@ class TransformerConfig:
     # longer scales with n_layers — the standard TPU recipe for fitting
     # larger models/batches (HBM is the bottleneck, MXU has headroom).
     # A block's forward is replayed but for the values its mixer NAMES
-    # (``KEPT_UNDER_REMAT``, a static property of the mixer's code, no
-    # option): the KDA mixer's five bf16 input projections (q, k, v, decay
-    # gate, output gate: 5 x B T d_inner x 2 bytes a layer, 671 MB at 8,192
-    # x 8,192) and the Mamba-2 mixer's five (z, x, B, C, dt: 304 MB a block
-    # at 8,192 x 18,560), which their chains' kernels save as their only
-    # residual anyway and whose replay is a matmul at the MXU's peak.
-    # Attention and latent mixers, ``wo``'s product, the cores' saved sets
-    # and the FFNs name nothing: no one static rule fits them into every
-    # cell's memory (``_layer_blocks``)
+    # (``utils.remat.KEPT_UNDER_REMAT``, a static property of the mixer's
+    # and the core's code, no option): the KDA mixer's five bf16 input
+    # projections (q, k, v, decay gate, output gate: 5 x B T d_inner x 2
+    # bytes a layer, 671 MB at 8,192 x 8,192) and the Mamba-2 mixer's five
+    # (z, x, B, C, dt: 304 MB a block at 8,192 x 18,560), which their
+    # chains' kernels save as their only residual anyway and whose replay
+    # is a matmul at the MXU's peak; a softmax mixer's q, k and v as its
+    # core takes them (head-major, after the norms, the rope and a head's
+    # split, ``q_rope`` / ``k_rope`` with them), and the flash core's ``o``
+    # and ``lse`` (the forward rule of ``ops.pallas.attention`` names them,
+    # for the latent mixer too): what ``flash_bwd`` reads, 0.38 GB a layer
+    # at 64 heads of 192 x 8,192 rows, so that the backward runs neither the
+    # products, the relayouts nor ``flash_fwd`` a second time.  ``wo``'s
+    # product, a gate's, the latent mixer's expanded q, k, v, the KDA and
+    # SSD cores' saved sets and the FFNs name nothing (``_layer_blocks``)
     remat: bool = False
     # Megatron-style sequence parallelism: between blocks, activations
     # live SEQUENCE-sharded over tp (T/tp per chip), the row-parallel
@@ -1914,7 +1912,10 @@ def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
     the tree holds picks the rest: a ``wq`` in place of ``wq_a`` is q
     straight from the hidden state (no q latent, no q norm), and a ``wg``
     ``(d_model, heads)`` gates each head's output by ``sigmoid(h wg)``
-    before ``wo``."""
+    before ``wo``.  Under ``cfg.remat`` the block keeps the flash core's
+    ``o`` and ``lse`` (its forward rule names them) and nothing of this
+    mixer's own: q, k and v are expanded from the latents on every head
+    (0.4 GB more a layer at Ling-3.0's widths) and are replayed."""
     B, T, _ = h.shape
     H = n_heads_local
     rank = lp["wkv_b"].shape[0]
@@ -1954,13 +1955,6 @@ def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
             gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
             attn = attn * gate.astype(attn.dtype).transpose(0, 2, 1)[..., None]
         return attn.transpose(0, 2, 1, 3).reshape(B, T, -1) @ lp["wo"]
-
-
-def _kept_under_remat(projection):
-    """``projection`` under the name ``_layer_blocks``' checkpoint policy
-    saves: the identity but inside a rematerialised block, whose backward
-    then reads this array where it would have multiplied it out again."""
-    return checkpoint_name(projection, KEPT_UNDER_REMAT)
 
 
 def _kda_partial(h, lp, n_heads_local, kda):
@@ -2005,13 +1999,13 @@ def _kda_partial(h, lp, n_heads_local, kda):
     H = n_heads_local
     f32 = jnp.float32
     with device_scope("accl.attn::kda_proj"):
-        proj = lambda w: _kept_under_remat(h @ lp[w])
+        proj = lambda w: kept_under_remat(h @ lp[w])
         q = conv_in(proj("wq"), lp["conv_q"], H, unit=True,
                     scale=(lp["wq"].shape[1] // H) ** -0.5)
         k = conv_in(proj("wk"), lp["conv_k"], H, unit=True)
         v = conv_in(proj("wv"), lp["conv_v"], H, unit=False)
         # a gate through a rank keeps its FINAL product
-        through = lambda w: _kept_under_remat(
+        through = lambda w: kept_under_remat(
             h @ lp[w] if w in lp else (h @ lp[w + "_a"]) @ lp[w + "_b"]
         )
         bound = kda["lower_bound"]
@@ -2062,7 +2056,7 @@ def _mamba2_partial(h, lp, mamba):
     G = lp["wb"].shape[1] // N
     f32 = jnp.float32
     with device_scope("accl.attn::mamba_proj"):
-        proj = lambda w: _kept_under_remat(h @ lp[w])
+        proj = lambda w: kept_under_remat(h @ lp[w])
         z = proj("wz")
         x = conv_silu(proj("wx"), lp["conv_x"], lp["bias_x"])
         b = conv_silu(proj("wb"), lp["conv_b"], lp["bias_b"])
@@ -2117,7 +2111,15 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
     no position; v is times ``v_scale``.  ``qk_eps`` is QK-norm's epsilon.
     ``block_diffusion=(L, B)``: ``h`` is ``[noisy ; clean]``, ``2 L`` rows
     that rotate at positions ``0..L`` twice, and the core runs under that
-    layout in the device scope ``accl.attn::blockdiff``."""
+    layout in the device scope ``accl.attn::blockdiff``.
+
+    Under ``cfg.remat`` the block keeps q, k and v AS THE CORE TAKES THEM
+    (``KEPT_UNDER_REMAT``: head-major, after the norms, the value scale, the
+    rope and the split, ``q_rope`` / ``k_rope`` beside them where a head
+    splits) and, where the core is the flash kernels, the core's ``o`` and
+    ``lse`` (named in its forward rule): all that ``flash_bwd`` reads, so
+    the backward's replay runs none of the three products, no transpose, no
+    rope and no ``flash_fwd``; ``wo``'s product and a gate's are replayed."""
     if "d_skip" in lp:
         return _mamba2_partial(h, lp, mamba), None
     if "a_log" in lp:
@@ -2178,11 +2180,16 @@ def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
                 q = _rope_rotate(q, tables)
                 k = _rope_rotate(k, tables)
             else:
-                more.update(
-                    q_rope=_rope_rotate(q[..., :dr], tables),
-                    k_rope=_rope_rotate(k[..., :dr], tables),
+                rotated = lambda t: kept_under_remat(
+                    _rope_rotate(t[..., :dr], tables)
                 )
+                more.update(q_rope=rotated(q), k_rope=rotated(k))
                 q, k = q[..., dr:], k[..., dr:]
+        # what the core's backward reads of the mixer's work, AS THE CORE
+        # TAKES IT (the two rotated parts above with it): a rematerialised
+        # block keeps these, so that its backward runs neither the products
+        # nor the relayouts above a second time
+        q, k, v = (kept_under_remat(t) for t in (q, k, v))
     if block_diffusion is not None:
         with device_scope("accl.attn::blockdiff"):
             attn = _attention(
@@ -2483,13 +2490,17 @@ def _layer_blocks(block, cfg):
     """``block`` for each layer of the pattern: the layout's block as it
     is where every layer is alike, and with the layer's own window and
     rotation where ``cfg.layers`` gives them.  Under ``cfg.remat`` each is
-    rematerialised on the backward pass but for what its mixer names
-    ``KEPT_UNDER_REMAT`` (the KDA and Mamba-2 mixers' bf16 input
-    projections, 671 and 304 MB a layer at the train cells' widths: what
-    their chains save as their residual anyway, so that no block multiplies
-    them out twice); a block whose mixer names nothing is replayed whole.
-    One static rule, no budget: those projections fit every layer of every
-    cell, which nothing larger (a core's saved set) does."""
+    rematerialised on the backward pass but for what its mixer, or its
+    attention core's forward rule, names ``KEPT_UNDER_REMAT``: the KDA and
+    Mamba-2 mixers' bf16 input projections (671 and 304 MB a layer at the
+    train cells' widths: what their chains save as their residual anyway),
+    a softmax mixer's q, k, v as its core takes them and the flash core's
+    ``o`` and ``lse`` (0.38 GB a layer at MiMo-V2.5's widths: all that
+    ``flash_bwd`` reads), so that no block multiplies those out or runs
+    ``flash_fwd`` twice; a block that names nothing is replayed whole.
+    One static rule, no budget: these fit every layer of every cell
+    (``memory_analysis`` of the five ``remat`` cells' full steps, PERF.md
+    section 5), which nothing larger (the KDA core's saved set) does."""
     remat = partial(
         jax.checkpoint,
         policy=jax.checkpoint_policies.save_only_these_names(KEPT_UNDER_REMAT),

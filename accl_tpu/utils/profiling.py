@@ -137,7 +137,11 @@ drops ``op_name``, so a reader joins the two by instruction name
                          (``LayerKind.kv_heads``); a head in two parts
                          where ``LayerKind.heads`` rotates only its first
                          columns (128 without position + a rotating 64 as
-                         the scores' second part, v of its own width)
+                         the scores' second part, v of its own width);
+                         under ``remat`` ``flash_fwd`` runs ONCE a layer
+                         (the block keeps its ``o`` and ``lse`` by name,
+                         ``utils.remat.KEPT_UNDER_REMAT``: the same under
+                         ``::window`` and ``::mla``)
 ``accl.attn::window``    the same under a ``LayerKind.window``: a sliding
                          layer's attention call; with the sink a query
                          head where the kind has one (``LayerKind.sink``:
@@ -155,7 +159,10 @@ drops ``op_name``, so a reader joins the two by instruction name
                          split into the two parts, their rope, the sink's
                          cast); beside KDA layers that stretch stays
                          outside any scope, as Solar's and Olmo's pinned
-                         programs have it
+                         programs have it; under ``remat`` q, k and v's
+                         products and that stretch run ONCE (the block
+                         keeps q, k, v and the two rotated parts as the
+                         core takes them), ``wo``'s and the gate's twice
 ``accl.attn::latent``    ``_latent_attn_partial`` (a latent mixer, MLA): the
                          five projections (four where q has no latent),
                          the latent norms, the rope, the head-wise gate
@@ -191,7 +198,7 @@ drops ``op_name``, so a reader joins the two by instruction name
                          from head-major); under ``remat`` a layer runs
                          each forward kernel twice, but the five matmuls
                          whose bf16 products they read once: the block
-                         keeps those by name (``transformer.
+                         keeps those by name (``utils.remat.
                          KEPT_UNDER_REMAT``).  At any other shape
                          XLA's fusions: at a decay a head all three
                          chains (heads of 96 and 192 are no whole lanes;

@@ -390,6 +390,17 @@ def _products(text, names, shape):
     return found
 
 
+def _flash_once(scope):
+    """A rematerialised block's attention core, the ENTRY instructions
+    ``scope`` of one layer's: ``flash_fwd`` ONCE beside its ``flash_bwd``.
+    Since PR 55 the block keeps the kernel's ``o`` and ``lse`` by name
+    (``_flash_vjp_fwd``; a softmax mixer also q, k, v as the core takes
+    them), so the backward's replay of the block does not call the forward
+    kernel again; the parent's text has it twice a layer."""
+    for kernel in ("flash_fwd", "flash_bwd"):
+        assert sum(n.startswith(kernel) for n in scope) == 1, (kernel, scope)
+
+
 def _table_ops(text, V, D):
     """``{instruction: its text}`` of the ENTRY instructions that write a
     ``bf16[V, D]``."""
@@ -524,8 +535,7 @@ def test_ling3_step_scans_the_chunks_and_keeps_no_square_of_the_length(
         4_722_778_112 + 671_088_640 * kda_layers
     )
     assert entry["accl.attn::latent"]
-    assert any("flash_fwd" in n for n in entry["accl.attn::mla"])
-    assert any("flash_bwd" in n for n in entry["accl.attn::mla"])
+    _flash_once(entry["accl.attn::mla"])
     assert re.search(r"%place_rows\S* = bf16\[16384,2560\]", text)
     # one head's scores over the whole length would be 2^32 elements; the
     # largest array here is the float32 logits' 16,384 x 19,648 (2^28.3)
@@ -602,8 +612,7 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
         if "f32[1,8192,8192]" in shape:
             assert op in ("custom-call", "bitcast", "get-tuple-element"), name
     assert not re.search(r"f32\[1024,8,8,1024\]|f32\[1,8192,8,1024\]", text)
-    assert any("flash_fwd" in n for n in entry["accl.attn::core"])
-    assert any("flash_bwd" in n for n in entry["accl.attn::core"])
+    _flash_once(entry["accl.attn::core"])
     for kernel in ("gmm_fwd", "gmm_dlhs", "gmm_drhs"):
         assert any(kernel in n for n in entry["accl.moe::experts"]), kernel
     # forward, the replayed forward and the dispatch gather's cotangent
@@ -747,9 +756,10 @@ def test_olmoh_step_takes_the_kernels_at_padded_heads(v5e, monkeypatch):
     ``accl.attn::kda_proj`` (no chain kernel takes such heads), and the four
     wide bf16 projections they read (q, k, v, the output gate) are
     multiplied out ONCE, kept under ``remat`` by name; the full layer's core
-    the flash kernels at 30 heads under ``accl.attn::core``, its projections
-    under ``accl.attn::gqa_proj``; and no array larger than the float32
-    logits (8,192 x 100,352: memory linear in T)."""
+    the flash kernels at 30 heads under ``accl.attn::core``, each ONCE
+    (``_flash_once``), its projections under ``accl.attn::gqa_proj``; and no
+    array larger than the float32 logits (8,192 x 100,352: memory linear in
+    T)."""
     from perfbench import scope_ops
     from perfbench.drivers import train_steps_ling3
 
@@ -775,8 +785,7 @@ def test_olmoh_step_takes_the_kernels_at_padded_heads(v5e, monkeypatch):
     assert len(_products(text, chains, "bf16[1,8192,5760]")) == 1
     assert len(_products(text, chains, "bf16[8192,5760]")) == 1 + 1
     flash = entry["accl.attn::core"]
-    assert sum(n.startswith("flash_fwd") for n in flash) == 2
-    assert sum(n.startswith("flash_bwd") for n in flash) == 1
+    _flash_once(flash)
     assert re.search(r"bf16\[1,30,8192,128\]", text)
     sizes = sorted({
         int(np.prod([int(n) for n in dims.split(",")]))
@@ -792,7 +801,7 @@ def test_olmoh_step_takes_the_kernels_at_padded_heads(v5e, monkeypatch):
 def test_mimo_step_takes_the_flash_kernels_in_both_kinds(v5e, monkeypatch):
     """One sliding and one full layer of the MiMo-V2.5 cell's seven, 1 x
     8,192 tokens, under ``remat`` as the cell runs (the rule fixed in ISSUE
-    54): the cores are ``flash_fwd`` (twice: the forward and its replay) and
+    54): the cores are ``flash_fwd`` (ONCE a layer: ``_flash_once``) and
     ``flash_bwd`` under ``accl.attn::window`` (a window of 128 keys in tiles
     of 512, the sink a head an operand in SMEM) and under ``accl.attn::core``,
     at 64 heads x 8,192 x (128 + a rotating 64 | 128) on 8 and 4 KV heads, the
@@ -809,8 +818,15 @@ def test_mimo_step_takes_the_flash_kernels_in_both_kinds(v5e, monkeypatch):
                   "accl.attn::gqa_proj", "accl.moe::experts"):
         assert entry.get(scope), scope
     for scope in ("accl.attn::window", "accl.attn::core"):
-        assert sum("flash_fwd" in n for n in entry[scope]) == 2, scope
-        assert sum("flash_bwd" in n for n in entry[scope]) == 1, scope
+        _flash_once(entry[scope])
+    # q's and k's products ONCE a layer, written head-major as the core takes
+    # them (kept under ``remat`` by name since PR 55; the parent's text has
+    # each twice): q of both layers, k on the sliding layer's 8 K/V heads and
+    # on the full layer's 4
+    proj = entry["accl.attn::gqa_proj"]
+    assert len(_products(text, proj, "bf16[1,64,8192,192]")) == 2
+    assert len(_products(text, proj, "bf16[1,8,8192,192]")) == 1
+    assert len(_products(text, proj, "bf16[1,4,8192,192]")) == 1
     assert re.search(r"bf16\[1,64,8192,128\]", text)      # q without position
     assert re.search(r"bf16\[1,8,8192,64\]", text)        # the rotating key part
     assert re.search(r"bf16\[1,4,8192,64\]", text)
@@ -869,8 +885,7 @@ def test_solar2_step_takes_the_kernels_under_the_unbounded_gate(
     # the five named projections once each (the parent of PR 49: twice) and
     # ``wo``'s cotangent, the same shape
     assert len(_products(text, chains, "bf16[1,8192,8192]")) == 5 + 1
-    assert any("flash_fwd" in n for n in entry["accl.attn::core"])
-    assert any("flash_bwd" in n for n in entry["accl.attn::core"])
+    _flash_once(entry["accl.attn::core"])
     for kernel in ("gmm_fwd", "gmm_dlhs", "gmm_drhs"):
         assert any(kernel in n for n in entry["accl.moe::experts"]), kernel
     assert re.search(r"%place_rows\S* = bf16\[8192,4096\]", text)
@@ -885,12 +900,17 @@ def test_solar2_step_takes_the_kernels_under_the_unbounded_gate(
     # logits' 8,192 x 24,576, which come next, 4% smaller)
     assert sizes[-1] == 40 * 4096 * 1280 and sizes[-2] == 8192 * 24576
     assert not re.search(r"\[8192,8192,\d+\]|\[64,8192,8192\]", text)
-    # the whole cell, four layers: 4,686,238,208 bytes of scratch (my compile
-    # for the described chip, PR 48); this cut has half the layers
-    assert compiled.memory_analysis().temp_size_in_bytes <= 4_686_238_208
+    # the whole cell, four layers: 6,332,889,600 bytes of scratch (my compile
+    # for the described chip, PR 55; 4,686,238,208 at PR 48, 6,029,447,680
+    # since PR 49 kept the three KDA layers' projections); this cut has half
+    # the layers
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6_332_889_600
     # and no more than PR 49's parent at this cut (4,527,492,608) with the five
-    # kept projections of the one KDA layer, 5 x 8,192 x 8,192 x 2 bytes (this
-    # cut reads 4,527,686,144; the whole cell's three layers add 1.34 GB)
+    # kept projections of the one KDA layer, 5 x 8,192 x 8,192 x 2 bytes, and
+    # what the GQA layer keeps since PR 55: q and o, 8,192 x 8,192 x 2 bytes
+    # each, k and v on 8 of the 64 heads, one float32 logsumexp a row a head
+    # (this cut read 4,527,686,144 at PR 49 and reads 4,831,128,064)
     assert compiled.memory_analysis().temp_size_in_bytes <= (
         4_527_492_608 + 671_088_640
+        + 2 * 134_217_728 + 2 * 16_777_216 + 2_097_152
     )

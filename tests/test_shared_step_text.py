@@ -12,6 +12,19 @@ partitions, stack frame ids and the table of file names and lines).  A
 later PR that means to change these steps records its own digests here
 and says so in CHANGES.md; one that does not mean to has found what it
 broke.
+
+Since PR 55 a softmax mixer and the flash core's forward rule pass what a
+rematerialised block keeps through ``jax.ad_checkpoint.checkpoint_name``
+(``accl_tpu/utils/remat.py``), in every step: without ``remat`` the name
+lowers to NO operation, but MLIR's symbol table counts it.  jax lowers each
+distinct equation as a private function that it inlines and drops, named
+after the primitive (``name``), and the table numbers a clashing symbol from
+ONE counter: a second ``name`` at another shape (k beside q; ``lse`` beside
+``o``) moves the suffix of every numbered symbol after it
+(``@take_along_axis_97`` becomes ``_98``) and nothing else.  So the digests
+below are held on the step as it lowers with the name taken away (the
+parent's text byte for byte: none is re-recorded), and the step as the
+program makes it is held to that text but for those suffixes.
 """
 
 import dataclasses
@@ -97,7 +110,7 @@ def normalised(compiled: str) -> str:
     return text
 
 
-def step_texts(cell_name: str, attention=None):
+def step_texts(cell_name: str, attention=None, compiled=True):
     cell = manifest.cell(manifest.load(), cell_name, rehearse=True)
     driver = importlib.import_module(
         "perfbench.drivers." + cell["traffic"]["driver"]
@@ -116,7 +129,15 @@ def step_texts(cell_name: str, attention=None):
         (int(cell["traffic"]["batch"]), int(cell["traffic"]["seq"])), jnp.int32
     )
     lowered = step.lower(params, tok, tok)
+    if not compiled:
+        return lowered.as_text(), None
     return lowered.as_text(), normalised(lowered.compile().as_text())
+
+
+def _without_symbol_counters(lowered: str) -> str:
+    """The lowered text without the number MLIR's symbol table appends to a
+    private symbol whose name was taken."""
+    return re.sub(r"@(\w+?)_\d+\b", r"@\1", lowered)
 
 
 def _sha(text: str) -> str:
@@ -124,8 +145,19 @@ def _sha(text: str) -> str:
 
 
 @pytest.mark.parametrize("cell,attention", list(PARENT))
-def test_rehearsal_size_step_is_the_program_it_was_at_the_parent(cell, attention):
+def test_rehearsal_size_step_is_the_program_it_was_at_the_parent(
+    cell, attention, monkeypatch
+):
+    from accl_tpu.utils import remat
+
+    as_the_program_makes_it, _ = step_texts(cell, attention, compiled=False)
+    monkeypatch.setattr(remat, "checkpoint_name", lambda value, name: value)
     lowered, compiled = step_texts(cell, attention)
     want_lowered, want_compiled = PARENT[cell, attention]
     assert _sha(lowered) == want_lowered
     assert want_compiled in (None, _sha(compiled))
+    # the names a block would keep under ``remat`` add no operation, operand
+    # or function to a step without it
+    assert _without_symbol_counters(as_the_program_makes_it) == (
+        _without_symbol_counters(lowered)
+    )
