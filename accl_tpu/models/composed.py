@@ -42,6 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 
+from .mixers import MIXERS
 from .pipeline import pipeline_apply, pipeline_apply_interleaved
 from .transformer import (
     TransformerConfig,
@@ -281,8 +282,10 @@ def make_pp_train_step(
             "(forward/loss_fn/generate), not the composed pipeline"
         )
     M = num_microbatches
-    heads_local = cfg.n_heads // tp
     specs = stacked_param_specs(cfg)
+    # the default block: every layer alike, its mixer the configuration's
+    kind = cfg.pattern()[0]
+    mixer = MIXERS[cfg.mixer(kind)].bind(cfg, kind, "tp", tp)
 
     def stage_fn(stage_layers, x):
         """This rank's layer span, walked with one scan; each block is
@@ -293,9 +296,7 @@ def make_pp_train_step(
         :data:`_psum_identity_bwd`)."""
         def body(h, lp):
             blk = partial(
-                _block, n_heads_local=heads_local, tp_axis="tp",
-                attn_impl=cfg.attention,
-                rope_base=cfg.rope_base if cfg.uses_rope() else None,
+                _block, mixer=mixer, tp_axis="tp",
                 reduce_fn=(
                     _psum_identity_bwd if schedule == "1f1b" else None
                 ),

@@ -76,12 +76,12 @@ def encoder_forward(
         )
     B, T = tokens.shape
     x = _embed_tokens(params, tokens, cfg)
-    x, block, sp = _enter_block_layout(
+    x, blocks, sp = _enter_block_layout(
         x, cfg, tp_axis, tp_size, causal=False
     )
     if cfg.remat:
-        block = jax.checkpoint(block)
-    for lp in params["layers"]:
+        blocks = [jax.checkpoint(block) for block in blocks]
+    for block, lp in zip(blocks, params["layers"]):
         x = block(x, lp)
     x = _layernorm(x, params["ln_f"])
     if sp:

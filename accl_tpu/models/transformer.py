@@ -19,7 +19,6 @@ the CCLO.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -34,25 +33,31 @@ from jax import shard_map
 from ..constants import ReduceFunction
 from ..ops import collectives
 from ..utils.profiling import device_scope
-from ..utils.remat import KEPT_UNDER_REMAT, kept_under_remat
-
-
-@dataclasses.dataclass(frozen=True)
-class HeadGeometry:
-    """The geometry of an ``"attention"`` mixer's heads where it is not the
-    plain one (``LayerKind.heads``): ``rope_dim``, the FIRST columns of a q
-    or k head that rotate, the rest carrying no position (a published
-    ``partial_rotary_factor``; ``None``: every column; the two parts go to
-    the attention lowerings apart, the rotating one as the scores' second
-    part, so a head of 128 + 64 is two operands of whole lanes to the flash
-    kernels and not one of 192 padded to 256); ``v_dim``, the width of a v
-    head, of the output a head and of ``wo``'s rows a head where it is not
-    q's and k's (``None``: theirs); ``v_scale`` multiplies v (a published
-    ``attention_value_scale``)."""
-
-    rope_dim: Optional[int] = None
-    v_dim: Optional[int] = None
-    v_scale: float = 1.0
+from ..utils.remat import KEPT_UNDER_REMAT
+from .layers import (  # noqa: F401 (the shared pieces, importable from here)
+    _AUTO_FUSED_MIN_T,
+    _NORMS,
+    _attention,
+    _auto_flash_fits,
+    _layernorm,
+    _norm_fn,
+    _qk_norm,
+    _rmsnorm,
+    _rope_rotate,
+    _rope_tables,
+    _tp_specs,
+    resolve_attention,
+)
+from .mixers import MIXERS
+from .mixers.attention import HeadGeometry
+from .mixers.kda import DeltaAttention
+from .mixers.latent import (
+    LatentAttention,
+    YarnScaling,
+    yarn_inv_freq,
+    yarn_mscale,
+)
+from .mixers.mamba2 import Mamba2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,121 +103,6 @@ class LayerKind:
 
 
 @dataclasses.dataclass(frozen=True)
-class LatentAttention:
-    """The sizes of a latent mixer (multi-head latent attention, MLA;
-    ``TransformerConfig.latent``): q comes up from a normed latent of
-    ``q_rank`` (``None``: straight from the hidden state, one matrix
-    ``wq`` and no q norm, a published ``q_lora_rank`` null), k's content
-    part and v from a normed latent of
-    ``kv_rank``; a head's q and k are ``nope_dim`` columns without
-    position beside ``rope_dim`` that rotate (k's rotating part is ONE
-    head, projected straight from the input and shared by every query
-    head); v and the output are ``v_dim`` a head."""
-
-    q_rank: Optional[int]
-    kv_rank: int
-    nope_dim: int
-    rope_dim: int
-    v_dim: int
-
-
-@dataclasses.dataclass(frozen=True)
-class YarnScaling:
-    """YaRN's rescaling of the rotary frequencies (the published
-    ``rope_scaling`` of type ``yarn``; ``TransformerConfig.rope_yarn``):
-    :func:`yarn_inv_freq` has the formula."""
-
-    factor: float
-    original_max_seq: int
-    beta_fast: float = 32.0
-    beta_slow: float = 1.0
-    mscale: float = 1.0
-    mscale_all_dim: float = 0.0
-
-
-@dataclasses.dataclass(frozen=True)
-class DeltaAttention:
-    """The sizes of a KDA mixer (Kimi Delta Attention, arXiv:2510.26692;
-    ``TransformerConfig.kda``; the layers whose ``LayerKind.mixer`` is
-    ``"kda"``): ``n_heads`` heads whose q, k and v are ``head_dim`` wide,
-    each ``silu(conv(h w))`` under a causal depthwise convolution of
-    ``conv`` taps; q and k L2-normalised a head; a log-decay a CHANNEL
-    ``lower_bound * sigmoid(exp(a_log) (h wf + dt_bias))`` and a write
-    strength a head ``beta_scale * sigmoid(h wbeta)`` into the gated delta
-    rule (``ops.kda``); the output RMS-normed a head and gated a channel by
-    ``sigmoid(h wg)`` before ``wo``.  A ``lower_bound`` must stay within
-    what ``ops.kda``'s sub-blocks keep inside float32 (``-80 / SUB``);
-    ``None`` is the PUBLISHED gate without a bound, ``-exp(a_log)
-    softplus(h wf + dt_bias)``, any value below 0, under which the core
-    splits its decays by halving (``ops.kda``, ANY ``g <= 0``; chosen here,
-    statically, so a bounded model's program is untouched).  ``beta_scale``
-    2 lets the transition's eigenvalue along k be negative (the family's
-    ``allow_neg_eigval``).  ``gate_rank``: the two gate projections ``wf``
-    and ``wg`` through that rank, ``wf_a wf_b`` and ``wg_a wg_b`` in the
-    tree (``None``: full rank, one matrix each).
-
-    GATED DELTANET (arXiv:2412.06464) is the same rule with a decay a
-    HEAD, three more properties of this description: ``v_dim`` is the width
-    of a head's v, output gate and ``wo`` rows where it is not ``head_dim``
-    (the state is ``head_dim x v_dim``; q and k stay ``head_dim``);
-    ``head_decay`` makes the log-decay ONE value a head a token, ``-exp(a_log)
-    softplus(h wa + dt_bias)`` with ``wa`` ``(d_model, n_heads)`` and
-    ``dt_bias`` a head in ``wf``'s place (no bound to set: ``lower_bound``
-    None, ``gate_rank`` None; ``ops.kda`` runs a gate of one column through
-    the same core, its heads padded to whole lanes where that costs at most
-    half again); ``out_gate`` ``"silu"`` gates the normed output by ``SiLU(h
-    wg)`` in the sigmoid's place."""
-
-    head_dim: int
-    conv: int = 4
-    lower_bound: Optional[float] = -5.0
-    beta_scale: float = 1.0
-    gate_rank: Optional[int] = None
-    v_dim: Optional[int] = None
-    head_decay: bool = False
-    out_gate: str = "sigmoid"
-
-    def value_dim(self) -> int:
-        return self.head_dim if self.v_dim is None else self.v_dim
-
-
-#: the softplus values ``init_params`` draws a head's ``dt_bias`` for under
-#: ``DeltaAttention.head_decay``, log-uniform (the family's initial range)
-KDA_HEAD_DECAY_DT = (1e-3, 0.1)
-
-#: the softplus values ``init_params`` draws the unbounded KDA gate's
-#: ``dt_bias`` for, log-uniform a channel (its docstring there says why)
-KDA_UNBOUNDED_DT = (1e-3, 16.0)
-
-@dataclasses.dataclass(frozen=True)
-class Mamba2:
-    """The sizes of a Mamba-2 mixer (state-space duality, arXiv:2405.21060;
-    ``TransformerConfig.mamba``; the layers whose ``LayerKind.mixer`` is
-    ``"mamba2"``): ``n_heads`` heads (its own count, not the attention's)
-    of ``head_dim`` columns with a state of ``head_dim x state`` each, in
-    ``groups`` groups of consecutive heads that share the state's input and
-    output directions B and C.  ``[z | x | B | C | dt] = u W_in``; x, B and
-    C through ``silu(conv(.) + bias)``, a causal depthwise convolution of
-    ``conv`` taps; ``dt = softplus(dt + dt_bias)`` and a rate ``A =
-    -exp(a_log)``, scalars a head, into ``S_t = exp(dt_t A) S_{t-1} + dt_t
-    x_t B_t^T``, ``y_t = S_t C_t + D x_t`` (``ops.ssd``, in chunks of
-    ``chunk``); ``y silu(z)`` RMS-normed a group (the gate BEFORE the norm)
-    before ``wo``.  ``dt_min``, ``dt_max`` and ``dt_floor`` are
-    INITIALISATION (``dt_bias`` is the inverse softplus of a log-uniform
-    draw between the first two, floored): the step has no clamp."""
-
-    n_heads: int
-    head_dim: int
-    state: int
-    groups: int
-    conv: int = 4
-    chunk: int = 128
-    dt_min: float = 0.001
-    dt_max: float = 0.1
-    dt_floor: float = 1e-4
-
-
-@dataclasses.dataclass(frozen=True)
 class BlockDiffusion:
     """Block-diffusion training (BD3-LM's objective, as the SDAR family
     adopts it; ``TransformerConfig.diffusion``).  A sequence of ``L`` ids
@@ -248,39 +138,6 @@ def hybrid_layers(pattern: str, d_ff: int = 0, moe_d_ff: int = 0):
             f"a block is one of {sorted(kinds)}"
         )
     return tuple(kinds[letter] for letter in pattern)
-
-
-def yarn_mscale(factor: float, mscale: float) -> float:
-    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
-    if factor <= 1.0 or not mscale:
-        return 1.0
-    return 0.1 * mscale * math.log(factor) + 1.0
-
-
-def yarn_inv_freq(dim: int, base: float, yarn: YarnScaling):
-    """The ``dim // 2`` inverse frequencies of a rotary embedding over
-    ``dim`` columns under YaRN, float32 (numpy): pair ``i`` keeps
-    ``base ** (-2 i / dim)`` where it turns more than ``beta_fast`` times
-    in the original context, is divided by ``factor`` where fewer than
-    ``beta_slow``, and is blended linearly between the two corrections'
-    pair indices ``low`` and ``high``."""
-    import numpy as np
-
-    half = dim // 2
-    extra = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    inter = extra / yarn.factor
-
-    def corr(turns):
-        return (
-            dim * math.log(yarn.original_max_seq / (turns * 2 * math.pi))
-            / (2 * math.log(base))
-        )
-
-    low = max(math.floor(corr(yarn.beta_fast)), 0)
-    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
-    span = (high - low) or 0.001
-    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / span, 0, 1)
-    return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -636,17 +493,16 @@ class TransformerConfig:
         return "latent" if self.latent is not None else "attention"
 
     def plain(self) -> bool:
-        """Every layer alike and nothing of the later kinds (a pattern, a
-        head width of its own, the gate, per-head QK-norm, post-norms, a
-        scaled embedding, the sigmoid router, a shared expert, a held
-        share, a latent, a KDA or a Mamba-2 mixer, grouped top-k, the
-        balance losses, a latent expert bank, relu2, an objective of its
-        own): what prefill/generate and the context- and sequence-parallel
-        blocks compute."""
+        """Every layer alike, its mixer one the plain blocks have a form for
+        (``MIXERS``: each mixer's ``plain``) and nothing of the later kinds
+        (a pattern, a head width of its own, the gate, per-head QK-norm,
+        post-norms, a scaled embedding, the sigmoid router, a shared expert,
+        a held share, grouped top-k, the balance losses, a latent expert
+        bank, relu2): what prefill/generate and the context- and
+        sequence-parallel blocks compute."""
         return (
-            self.layers is None and self.head_dim is None
-            and self.latent is None and self.diffusion is None
-            and self.kda is None and self.mamba is None
+            self.layers is None and not _beyond_plain_mixers(self)
+            and self.head_dim is None
             and not self.moe_latent and self.ffn != "relu2"
             and self.moe_n_group == 1
             and not any(self.moe_balance_weights)
@@ -669,75 +525,19 @@ class TransformerConfig:
             raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
         if self.post_norm not in (False, True, "only"):
             raise ValueError(f"unknown post_norm {self.post_norm!r}")
-        if self.latent is not None and (
-            self.pos_embedding != "rope" or self.n_kv_heads is not None
-            or self.head_dim is not None or self.qk_norm
-            or self.attn_gate is True
-        ):
-            raise ValueError(
-                "a latent mixer rotates (pos_embedding='rope') and has no "
-                "n_kv_heads, head_dim, qk_norm or attn_gate of its own "
-                "(its gate is a value a head: attn_gate='head')"
-            )
-        if self.attn_gate == "head" and self.latent is None:
-            raise ValueError(
-                "attn_gate='head' is the latent mixer's gate; the attention "
-                "mixer's is a value a channel (attn_gate=True)"
-            )
-        kinds = {self.mixer(kind) for kind in self.layers or ()}
-        if self.kda is not None:
-            from ..ops.kda import SUB
-
-            d = self.kda
-            if (
-                "kda" not in kinds or d.head_dim < 1 or d.conv < 1
-                or not (
-                    d.lower_bound is None
-                    or -80.0 / SUB <= d.lower_bound < 0.0
-                )
-                or d.beta_scale not in (1.0, 2.0)
-                or (d.gate_rank is not None and d.gate_rank < 1)
-                or d.value_dim() < 1
-                or d.out_gate not in ("sigmoid", "silu")
-                or (d.head_decay and (
-                    d.lower_bound is not None or d.gate_rank is not None
-                ))
-            ):
-                raise ValueError(
-                    "a KDA mixer (TransformerConfig.kda) is some layer's of "
-                    "the pattern (LayerKind.mixer='kda'), with a head_dim "
-                    f"and a convolution of at least 1, a lower_bound in "
-                    f"[{-80.0 / SUB}, 0) or None (the gate without a bound), "
-                    "a beta_scale of 1 or 2, a gate_rank of at least 1 or "
-                    "None, a v_dim of at least 1 or None, an out_gate "
-                    "'sigmoid' or 'silu' and, under head_decay, neither a "
-                    f"lower_bound nor a gate_rank; got {d}"
-                )
-        if self.mamba is not None:
-            m = self.mamba
-            if (
-                "mamba2" not in kinds
-                or min(m.n_heads, m.head_dim, m.state, m.groups, m.conv,
-                       m.chunk) < 1
-                or m.n_heads % m.groups
-                or not 0.0 < m.dt_floor <= m.dt_min <= m.dt_max
-            ):
-                raise ValueError(
-                    "a Mamba-2 mixer (TransformerConfig.mamba) is some "
-                    "layer's of the pattern (LayerKind.mixer='mamba2'), with "
-                    "heads in whole groups, sizes of at least 1 and 0 < "
-                    f"dt_floor <= dt_min <= dt_max; got {m}"
-                )
+        # each mixer's description alone, whichever layers have it
+        for mixer in MIXERS.values():
+            mixer.check(self, None, None)
         if self.rope_yarn is not None and self.pos_embedding != "rope":
             raise ValueError("rope_yarn rescales a rotary embedding")
         if self.diffusion is not None:
             d = self.diffusion
             if (
-                self.pos_embedding != "rope" or self.latent is not None
-                or self.kda is not None or self.mamba is not None
-                or any(k.window is not None for k in self.layers or ())
+                self.pos_embedding != "rope"
+                # the layout is the attention mixer's, of every key
                 or any(
-                    "none" in (k.mixer, k.ffn) for k in self.layers or ()
+                    self.mixer(k) != "attention" or k.window is not None
+                    or k.ffn == "none" for k in self.pattern()
                 )
                 or d.block < 1 or not 0 <= d.mask_id < self.vocab
                 or not 0.0 < d.eps < 1.0
@@ -780,42 +580,15 @@ class TransformerConfig:
                 if kind.window is not None and kind.window < 1:
                     raise ValueError(f"layer {i}: window {kind.window}")
                 mixer = self.mixer(kind)
-                if mixer not in ("attention", "latent", "kda", "mamba2", "none"):
-                    raise ValueError(f"layer {i}: unknown mixer {mixer!r}")
                 if mixer == "none":
                     if kind.ffn == "none":
                         raise ValueError(
                             f"layer {i}: a block has a mixer, an FFN or both"
                         )
-                    continue
-                if mixer == "kda":
-                    if self.kda is None or kind.window is not None:
-                        raise ValueError(
-                            f"layer {i}: a KDA layer needs "
-                            "TransformerConfig.kda and has no window"
-                        )
-                    continue  # no position encoding: ``rope`` says nothing
-                if mixer == "mamba2":
-                    if self.mamba is None or kind.window is not None:
-                        raise ValueError(
-                            f"layer {i}: a Mamba-2 layer needs "
-                            "TransformerConfig.mamba and has no window"
-                        )
-                    continue
-                if (mixer == "latent") != (self.latent is not None):
-                    raise ValueError(
-                        f"layer {i}: the {mixer} mixer in a stack whose "
-                        "TransformerConfig.latent is "
-                        f"{'set' if self.latent is not None else 'None'} (a "
-                        "stack holds the latent mixer or attention, beside "
-                        "KDA and Mamba-2 layers)"
-                    )
-                if kind.rope and self.pos_embedding != "rope":
-                    raise ValueError(
-                        f"layer {i} rotates but pos_embedding is "
-                        f"{self.pos_embedding!r}"
-                    )
-                self._check_attention_kind(i, kind, mixer)
+                elif mixer not in MIXERS:
+                    raise ValueError(f"layer {i}: unknown mixer {mixer!r}")
+                else:
+                    MIXERS[mixer].check(self, kind, i)
         beyond = (
             self.moe_router != "softmax" or self.moe_shared_d_ff
             or self.moe_router_experts is not None or self.moe_n_group != 1
@@ -838,42 +611,50 @@ class TransformerConfig:
                 f"the router's {self.moe_router_experts}"
             )
 
-    def _check_attention_kind(self, i: int, kind: LayerKind, mixer: str):
-        """What a kind says of its OWN attention heads (``kv_heads``,
-        ``rope_base``, ``sink``, ``heads``) is the ``"attention"`` mixer's."""
-        g = kind.heads
-        own = (kind.kv_heads, kind.rope_base, g)
-        if not kind.sink and all(x is None for x in own):
-            return
-        if mixer != "attention" or self.diffusion is not None:
-            raise ValueError(
-                f"layer {i}: kv_heads, rope_base, sink and heads are the "
-                f"attention mixer's under a causal mask, not the {mixer} "
-                "mixer's or block diffusion's"
-            )
-        self.kv_heads(kind)
-        if g is None:
-            return
-        hd = self.head_size()
-        if (
-            self.qk_norm or self.attn_gate or g.v_scale <= 0.0
-            or (g.v_dim is not None and g.v_dim < 1)
-            or (g.rope_dim is not None
-                and not (0 < g.rope_dim <= hd and g.rope_dim % 2 == 0))
-        ):
-            raise ValueError(
-                f"layer {i}: a head geometry (LayerKind.heads) rotates an "
-                f"even number of a head's {hd} columns, has a v_dim of at "
-                "least 1 and a v_scale above 0, and is not built beside "
-                f"QK-norm or the attention gate; got {g}"
-            )
-
     def default_block(self) -> bool:
         """The block the encoder and the composed pipeline compute."""
         return (
             self.norm == "layernorm" and self.ffn == "gelu"
             and not self.qk_norm and self.tie_head and self.plain()
         )
+
+
+#: what :meth:`TransformerConfig.plain` refuses beside a mixer, for the
+#: refusals' messages
+_BEYOND_PLAIN_KINDS = (
+    "a layer pattern, a head_dim of its own, an attention gate, per-head "
+    "QK-norm, post-norms, a scaled embedding, a sigmoid router, a shared "
+    "expert, a held share of the experts, a latent expert bank, relu2, "
+    "grouped top-k or balance losses"
+)
+
+
+def _beyond_plain_mixers(cfg) -> list:
+    """What the plain blocks (prefill/generate, the context- and
+    sequence-parallel blocks, the encoder, the pipelines) have no form for
+    among ``cfg``'s layers, in the layers' order: each mixer's by the name
+    its module gives (``MIXERS``: ``plain``), a block of one sub-layer by
+    its own."""
+    named = []
+    for kind in dict.fromkeys(cfg.pattern()):
+        name = cfg.mixer(kind)
+        whats = [] if name == "none" else [MIXERS[name].plain(cfg, kind)]
+        if "none" in (name, kind.ffn):
+            whats.append(
+                "a block of one sub-layer (LayerKind.mixer or .ffn 'none')"
+            )
+        named += [w for w in whats if w is not None and w not in named]
+    return named
+
+
+def _beyond_plain(cfg) -> str:
+    """:func:`_beyond_plain_mixers` and the kinds beside them, as a
+    refusal's message lists them."""
+    return "; ".join(
+        _beyond_plain_mixers(cfg)
+        + ["or, of the pattern's, the block's and the router's kinds, "
+           + _BEYOND_PLAIN_KINDS]
+    )
 
 
 def _check_axis_compat(cfg) -> None:
@@ -883,15 +664,9 @@ def _check_axis_compat(cfg) -> None:
     if (cfg.context_parallel or cfg.seq_parallel) and not cfg.plain():
         raise ValueError(
             "context_parallel and seq_parallel take the plain block only "
-            "(TransformerConfig.plain): no layer pattern, window, head_dim, "
-            "gate, per-head QK-norm, post-norm, scaled embedding, sigmoid "
-            "router, shared expert, held share, K/V heads, rope base, sink or "
-            "head geometry of a layer kind's own, latent mixer (MLA), KDA "
-            "mixer or Mamba-2 mixer (a recurrent state is not handed round "
-            "the ring yet), block of one sub-layer, latent expert bank, "
-            "relu2, "
-            "grouped top-k, balance losses or block diffusion (its layout is "
-            "not in the ring yet)"
+            "(TransformerConfig.plain; a recurrent state is not handed "
+            "round the ring yet, nor is block diffusion's layout in it): "
+            "this configuration has " + _beyond_plain(cfg)
         )
     if cfg.diffusion is not None and cfg.vocab_parallel:
         raise ValueError(
@@ -992,96 +767,25 @@ def _mean_over_axes(local, axes: tuple, denom: int):
 # parameter partition specs over ('dp', 'tp'): column-parallel weights shard
 # their output dim on tp, row-parallel weights their input dim.
 def _layer_specs(cfg: TransformerConfig, kind: LayerKind) -> Dict:
-    """The specs of one layer of ``kind``."""
+    """The specs of one layer of ``kind``: its mixer's leaves (``MIXERS``),
+    the block's norms, its FFN's."""
     gated = cfg.ffn == "swiglu"
     cp = cfg.context_parallel
-    # context parallelism: the tp axis carries the SEQUENCE ring, so
-    # every weight is replicated over it (dp still shards the batch)
-    col = P(None, None) if cp else P(None, "tp")   # output dim on tp
-    row = P(None, None) if cp else P("tp", None)   # input dim on tp
+    col, row, _ = _tp_specs(cfg)
     mixer = cfg.mixer(kind)
-    heads = None if cp else "tp"
-    if mixer == "none":
-        layer = {}
-    elif mixer == "mamba2":
-        layer = {
-            # [z | x | B | C | dt] = u W_in as five matrices, so that tp
-            # splits the heads (z, x, dt) AND the groups (B, C) together:
-            # a chip's heads keep their own groups; the taps, the biases,
-            # the scalars a head and the grouped norm's scale follow
-            "wz": col, "wx": col, "wb": col, "wc": col, "wdt": col,
-            "conv_x": col, "conv_b": col, "conv_c": col,
-            "bias_x": P(heads), "bias_b": P(heads), "bias_c": P(heads),
-            "dt_bias": P(heads), "a_log": P(heads), "d_skip": P(heads),
-            "y_norm": P(heads), "wo": row,
-        }
-    elif mixer == "kda":
-        layer = {
-            # every projection's columns are heads (``wbeta``'s one a
-            # head), and so are the channels of the taps and of ``dt_bias``;
-            # the output norm's scale is one head wide, every head's
-            "wq": col, "wk": col, "wv": col,
-            "wbeta": col, "conv_q": col, "conv_k": col, "conv_v": col,
-            "a_log": P(heads), "dt_bias": P(heads), "o_norm": P(None),
-            "wo": row,
-        }
-        if cfg.kda.head_decay:
-            # a decay a head: ``wa``'s columns are the heads themselves
-            layer.update(wa=col, wg=col)
-        elif cfg.kda.gate_rank is None:
-            layer.update(wf=col, wg=col)
-        else:
-            # the way down to the rank is every chip's, the way up has the
-            # heads' columns
-            layer.update(
-                wf_a=P(None, None), wf_b=col, wg_a=P(None, None), wg_b=col
-            )
-    elif mixer == "latent":
-        layer = {
-            # the down-projections and their norms are every chip's; the
-            # up-projections' columns are heads, sharded as wq's are
-            "wkv_a": P(None, None), "kv_a_norm": P(None), "wkv_b": col,
-            "wo": row,  # (heads * v_dim / tp, d_model)
-        }
-        if cfg.latent.q_rank is None:
-            layer["wq"] = col
-        else:
-            layer.update(wq_a=P(None, None), q_a_norm=P(None), wq_b=col)
-    else:
-        layer = {
-            "wq": col,  # (d_model, heads * head_size / tp): heads sharded
-            "wk": col,
-            "wv": col,
-            "wo": row,  # (heads * head_size / tp, d_model)
-        }
-        if kind.sink:
-            layer["sink"] = P(heads)  # a scalar a query head
+    layer = {} if mixer == "none" else MIXERS[mixer].specs(cfg, kind)
     # one norm a sub-layer: a block without a mixer has no ``ln1``, one
     # without an FFN no ``ln2``
-    softmax_mixer = mixer in ("attention", "latent")
     if cfg.post_norm != "only":
         if mixer != "none":
             layer["ln1"] = P(None)
         if kind.ffn != "none":
             layer["ln2"] = P(None)
-    if cfg.attn_gate and softmax_mixer:
-        layer["wg"] = col  # the gate's columns follow q's heads
     if cfg.post_norm:
         if mixer != "none":
             layer["ln1_post"] = P(None)
         if kind.ffn != "none":
             layer["ln2_post"] = P(None)
-    # a KDA layer's q and k are L2-normalised: no learned QK-norm
-    qk_norm = softmax_mixer and cfg.qk_norm
-    if qk_norm == "head":
-        # one scale of head_size for every head: replicated
-        layer["q_norm"] = P(None)
-        layer["k_norm"] = P(None)
-    elif qk_norm:
-        # scales of the whole projected q and k: sharded like the
-        # projections' output columns
-        layer["q_norm"] = P(heads)
-        layer["k_norm"] = P(heads)
     if kind.ffn == "none":
         return layer
     if kind.ffn == "dense":
@@ -1161,178 +865,25 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
             ) * scale
         )
     gated = cfg.ffn == "swiglu"
-    hd = cfg.head_size()
-    d_q = cfg.n_heads * hd
 
     def normal(key, shape):
         return jax.random.normal(key, shape, cfg.dtype) * scale
 
-    def latent_mixer(key, la):
-        ks = jax.random.split(key, 5)
-        H = cfg.n_heads
-        d_q = H * (la.nope_dim + la.rope_dim)
-        if la.q_rank is None:
-            q = {"wq": normal(ks[0], (cfg.d_model, d_q))}
-        else:
-            q = {
-                "wq_a": normal(ks[0], (cfg.d_model, la.q_rank)),
-                "q_a_norm": jnp.ones((la.q_rank,), cfg.dtype),
-                "wq_b": normal(ks[1], (la.q_rank, d_q)),
-            }
-        return {
-            **q,
-            "wkv_a": normal(ks[2], (cfg.d_model, la.kv_rank + la.rope_dim)),
-            "kv_a_norm": jnp.ones((la.kv_rank,), cfg.dtype),
-            "wkv_b": normal(ks[3], (la.kv_rank, H * (la.nope_dim + la.v_dim))),
-            "wo": normal(ks[4], (H * la.v_dim, cfg.d_model)),
-        }
-
-    def kda_mixer(key, kda):
-        """The matrices as every other (normal, 0.02); the taps normal at
-        ``conv ** -0.5`` (a Conv1d's default range); ``a_log`` the log of
-        a uniform draw from [1, 16) a head (the family's convention);
-        ``dt_bias`` standard normal a channel, so that channels differ in
-        how fast they forget.  Under the gate without a bound ``dt_bias``
-        is the inverse softplus of a log-uniform draw from
-        :data:`KDA_UNBOUNDED_DT` (the family's own range, [0.001, 0.1],
-        would leave every seeded channel remembering for hundreds of
-        tokens; a trained gate does not): log-decays from -0.001 to under
-        -100 a token, channels on both sides of a bound of -5.  A
-        ``gate_rank`` splits ``wf`` and ``wg`` in two, normal alike."""
-        ks = jax.random.split(key, 12)
-        wide = cfg.n_heads * kda.head_dim
-        wide_v = cfg.n_heads * kda.value_dim()
-        matrix = lambda key, n=wide: normal(key, (cfg.d_model, n))
-        taps = lambda key, n=wide: (
-            jax.random.normal(key, (kda.conv, n), cfg.dtype)
-            * kda.conv ** -0.5
-        )
-        if kda.head_decay:
-            gates = {
-                "wa": matrix(ks[3], cfg.n_heads), "wg": matrix(ks[4], wide_v),
-            }
-        elif kda.gate_rank is None:
-            gates = {"wf": matrix(ks[3]), "wg": matrix(ks[4])}
-        else:
-            down = lambda key: normal(key, (cfg.d_model, kda.gate_rank))
-            up = lambda key: normal(
-                jax.random.fold_in(key, 1), (kda.gate_rank, wide)
-            )
-            gates = {
-                "wf_a": down(ks[3]), "wf_b": up(ks[3]),
-                "wg_a": down(ks[4]), "wg_b": up(ks[4]),
-            }
-        if kda.lower_bound is None:
-            low, high = KDA_HEAD_DECAY_DT if kda.head_decay else KDA_UNBOUNDED_DT
-            dt = jnp.exp(jax.random.uniform(
-                ks[10], (cfg.n_heads if kda.head_decay else wide,),
-                jnp.float32, math.log(low), math.log(high),
-            ))
-            dt_bias = dt + jnp.log(-jnp.expm1(-dt))
-        else:
-            dt_bias = jax.random.normal(ks[10], (wide,), jnp.float32)
-        return {
-            "wq": matrix(ks[0]), "wk": matrix(ks[1]),
-            "wv": matrix(ks[2], wide_v),
-            **gates,
-            "wbeta": normal(ks[5], (cfg.d_model, cfg.n_heads)),
-            "conv_q": taps(ks[6]), "conv_k": taps(ks[7]),
-            "conv_v": taps(ks[8], wide_v),
-            "a_log": jnp.log(jax.random.uniform(
-                ks[9], (cfg.n_heads,), jnp.float32, 1.0, 16.0
-            )),
-            "dt_bias": dt_bias,
-            "o_norm": jnp.ones((kda.value_dim(),), cfg.dtype),
-            "wo": normal(ks[11], (wide_v, cfg.d_model)),
-        }
-
-    def mamba_mixer(key, m):
-        """The matrices as every other (normal, 0.02); taps and the
-        convolution's bias normal at ``conv ** -0.5``; ``a_log`` the log of
-        a uniform draw from [1, 16) a head, ``dt_bias`` the inverse
-        softplus of a log-uniform draw from [dt_min, dt_max) floored at
-        dt_floor, ``d_skip`` 1 (the family's conventions)."""
-        ks = jax.random.split(key, 14)
-        inner, bc = m.n_heads * m.head_dim, m.groups * m.state
-        matrix = lambda key, n: normal(key, (cfg.d_model, n))
-        taps = lambda key, n: (
-            jax.random.normal(key, (m.conv, n), cfg.dtype) * m.conv ** -0.5
-        )
-        bias = lambda key, n: (
-            jax.random.normal(key, (n,), cfg.dtype) * m.conv ** -0.5
-        )
-        dt = jnp.maximum(jnp.exp(jax.random.uniform(
-            ks[12], (m.n_heads,), jnp.float32, math.log(m.dt_min),
-            math.log(m.dt_max),
-        )), m.dt_floor)
-        return {
-            "wz": matrix(ks[0], inner), "wx": matrix(ks[1], inner),
-            "wb": matrix(ks[2], bc), "wc": matrix(ks[3], bc),
-            "wdt": matrix(ks[4], m.n_heads),
-            "conv_x": taps(ks[5], inner), "conv_b": taps(ks[6], bc),
-            "conv_c": taps(ks[7], bc),
-            "bias_x": bias(ks[8], inner), "bias_b": bias(ks[9], bc),
-            "bias_c": bias(ks[10], bc),
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-            "a_log": jnp.log(jax.random.uniform(
-                ks[11], (m.n_heads,), jnp.float32, 1.0, 16.0
-            )),
-            "d_skip": jnp.ones((m.n_heads,), jnp.float32),
-            "y_norm": jnp.ones((inner,), cfg.dtype),
-            "wo": normal(ks[13], (inner, cfg.d_model)),
-        }
-
     for i, kind in enumerate(cfg.pattern()):
+        # a layer's four keys: two its mixer's, two its FFN's
         kk = k[2 + 4 * i : 6 + 4 * i]
         mixer = cfg.mixer(kind)
-        if mixer == "none":
-            layer = {}
-        elif mixer == "mamba2":
-            layer = mamba_mixer(kk[0], cfg.mamba)
-        elif mixer == "kda":
-            layer = kda_mixer(kk[0], cfg.kda)
-        elif mixer == "latent":
-            layer = latent_mixer(kk[0], cfg.latent)
-        else:
-            # the kind's own K/V heads and v width where it has them
-            n_kv = cfg.kv_heads(kind)
-            dv = (kind.heads and kind.heads.v_dim) or hd
-            layer = {
-                "wq": normal(kk[0], (cfg.d_model, d_q)),
-                "wk": normal(
-                    jax.random.fold_in(kk[0], 1), (cfg.d_model, n_kv * hd)
-                ),
-                "wv": normal(
-                    jax.random.fold_in(kk[0], 2), (cfg.d_model, n_kv * dv)
-                ),
-                "wo": normal(kk[1], (cfg.n_heads * dv, cfg.d_model)),
-            }
-            if kind.sink:
-                layer["sink"] = jnp.zeros((cfg.n_heads,), jnp.float32)
-        softmax_mixer = mixer in ("attention", "latent")
+        layer = {} if mixer == "none" else MIXERS[mixer].init(kk[:2], cfg, kind)
         if cfg.post_norm != "only":
             if mixer != "none":
                 layer["ln1"] = jnp.ones((cfg.d_model,), cfg.dtype)
             if kind.ffn != "none":
                 layer["ln2"] = jnp.ones((cfg.d_model,), cfg.dtype)
-        if cfg.attn_gate and softmax_mixer:
-            layer["wg"] = normal(
-                jax.random.fold_in(kk[1], 1),
-                (cfg.d_model, cfg.n_heads if cfg.attn_gate == "head" else d_q),
-            )
         if cfg.post_norm:
             if mixer != "none":
                 layer["ln1_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
             if kind.ffn != "none":
                 layer["ln2_post"] = jnp.ones((cfg.d_model,), cfg.dtype)
-        # a KDA layer's q and k are L2-normalised: no learned QK-norm
-        qk_norm = softmax_mixer and cfg.qk_norm
-        if qk_norm == "head":
-            layer["q_norm"] = jnp.ones((hd,), cfg.dtype)
-            layer["k_norm"] = jnp.ones((hd,), cfg.dtype)
-        elif qk_norm:
-            layer["q_norm"] = jnp.ones((d_q,), cfg.dtype)
-            layer["k_norm"] = jnp.ones((layer["wk"].shape[1],), cfg.dtype)
         if kind.ffn == "moe":
             from .moe import init_moe_params
 
@@ -1351,48 +902,6 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
                 )
         params["layers"].append(layer)
     return params
-
-
-def _layernorm(x, scale, eps: float = 1e-5):
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * scale
-
-
-def _rmsnorm(x, scale, tp_axis=None, eps: float = 1e-5):
-    """RMSNorm, statistics in f32 and the scale applied in the input's
-    type.  ``tp_axis``: the last dim is a tp shard of the normed width
-    (QK-norm over head-sharded projections), so the mean square is taken
-    over the whole width with one allreduce of a scalar a row."""
-    x32 = x.astype(jnp.float32)
-    ss = jnp.sum(x32 * x32, axis=-1, keepdims=True)
-    width = x.shape[-1]
-    if tp_axis is not None:
-        ss = collectives.allreduce(ss, tp_axis, ReduceFunction.SUM)
-        width = width * jax.lax.axis_size(tp_axis)
-    return (x32 * jax.lax.rsqrt(ss / width + eps)).astype(x.dtype) * scale
-
-
-_NORMS = {"layernorm": _layernorm, "rmsnorm": _rmsnorm}
-
-
-def _norm_fn(cfg):
-    """The config's norm at its ``norm_eps``."""
-    fn = _NORMS[cfg.norm]
-    return fn if cfg.norm_eps == 1e-5 else partial(fn, eps=cfg.norm_eps)
-
-
-def _qk_norm(q, k, lp, tp_axis, eps: float = 1e-5):
-    """A layer with ``q_norm``/``k_norm`` scales RMS-normalises the whole
-    projected q and k (every head at once, over ``tp_axis`` where the
-    heads are sharded) before the split into heads, at the
-    configuration's ``norm_eps``."""
-    if "q_norm" not in lp:
-        return q, k
-    return (
-        _rmsnorm(q, lp["q_norm"], tp_axis, eps),
-        _rmsnorm(k, lp["k_norm"], tp_axis, eps),
-    )
 
 
 def _vp_active(cfg, tp_axis) -> bool:
@@ -1613,208 +1122,6 @@ def _lm_logits(x, params, cfg, tp_axis, gather: bool = True) -> jax.Array:
     return z
 
 
-def _rope_tables(positions, half: int, base: float, inv_freq=None,
-                 table_scale: float = 1.0):
-    """cos/sin tables for rotary embedding at the given absolute
-    ``positions`` (shape (T,); traced values fine — decode passes its
-    dynamic cursor).  Computed once per attention site and shared by
-    the q and k rotations (and across layers on the decode path), so
-    scanned/rematerialized blocks don't rebuild the pow/cos/sin chain
-    per layer.  ``inv_freq`` (``half`` of them) are the configuration's
-    own inverse frequencies (YaRN, ``TransformerConfig.rope_inv_freq``)
-    in place of ``base``'s; ``table_scale`` multiplies both tables."""
-    if inv_freq is None:
-        freqs = jnp.asarray(base, jnp.float32) ** (
-            -jnp.arange(0, half, dtype=jnp.float32) / half
-        )
-    else:
-        freqs = jnp.asarray(inv_freq, jnp.float32)
-    ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # (T, half)
-    if table_scale != 1.0:
-        return jnp.cos(ang) * table_scale, jnp.sin(ang) * table_scale
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def _rope_rotate(x, tables):
-    """Rotary position embedding [RoFormer]: rotate each (i, i+half)
-    feature pair of every head by position*freq_i.  ``x`` is
-    (B, H, T, hd) with hd even; ``tables`` from :func:`_rope_tables`.
-    Rotation runs in f32, the result is cast back so bf16 activations
-    stay bf16 (the dtype-discipline rule everywhere in this file)."""
-    cos, sin = tables
-    half = x.shape[-1] // 2
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    )
-    return out.astype(x.dtype)
-
-
-# measured crossover on v5e (see TransformerConfig.attention): with the
-# block=512 flash kernel the fused form wins the full train step from
-# T=1024 up (75.4% vs 69.5% MFU at T=1024; at T=4096 it is the only
-# form that fits HBM), so auto resolves to a fused form at/above this
-# and to naive only below it (tiny-T padding-overhead regime)
-_AUTO_FUSED_MIN_T = 1024
-# flash holds whole K/V in VMEM per batch-head (and its one backward
-# kernel whole Q, dO, the dq block and an f32 dq accumulator: six times
-# K's bytes plus T x hd x 4, which that call computes from the shapes
-# and passes as its own VMEM limit — 24 MiB at this gate's edge): auto
-# uses it only while K+V fit this budget (4 MiB = T 8192 at hd<=128
-# bf16; the gate scales with the PADDED head dim and dtype width, so
-# wide-head or f32 configs fall back to the streaming XLA fold instead
-# of failing Mosaic's VMEM allocation).  A head whose rotating part is
-# passed APART (``q_rope`` / ``k_rope``: the latent mixer's, and a
-# ``HeadGeometry.rope_dim``'s) is gated by each part's own padded width:
-# the gate sees the first part alone (``_attention``), the second rides
-# beside it on its own lanes.  The MiMo-V2.5 cell is the case: a head of
-# 192 as ONE operand pads to 256 lanes and stops ``auto`` at T = 4,096;
-# as 128 columns without position beside a rotating 64 (padded to 128)
-# it runs the kernels at 8,192
-_AUTO_FLASH_KV_BYTES = 4 * 2**20
-
-
-def _auto_flash_fits(q) -> bool:
-    import jax.numpy as jnp
-
-    if q.dtype == jnp.float16:
-        # Mosaic's TPU lowering rejects f16 matmul operands (ValueError
-        # at compile), so auto must never route f16 into the flash
-        # kernel — it falls through to the XLA blockwise fold instead.
-        # Explicit
-        # attention="flash" still surfaces the kernel's own f16 error.
-        return False
-    Dp = -(-q.shape[-1] // 128) * 128  # lane-padded head dim
-    return 2 * q.shape[2] * Dp * q.dtype.itemsize <= _AUTO_FLASH_KV_BYTES
-
-
-def resolve_attention(impl: str, q) -> str:
-    """The lowering ``impl`` names for a per-device ``(B, H, T, hd)``
-    query (anything with ``shape`` and ``dtype``).  ``"auto"`` resolves
-    by sequence length and backend: naive under ``_AUTO_FUSED_MIN_T``;
-    at/above it the Pallas flash kernel on TPU while its K/V tiles fit
-    VMEM (:func:`_auto_flash_fits`), and the XLA blockwise fold off TPU
-    or past that gate.  Callers that must know which one ran (the chip
-    smoke) ask here instead of guessing."""
-    if impl != "auto":
-        return impl
-    if q.shape[2] < _AUTO_FUSED_MIN_T:
-        return "naive"
-    if jax.default_backend() == "tpu" and _auto_flash_fits(q):
-        return "flash"  # Mosaic-compiled; trainable via custom_vjp
-    return "blockwise"
-
-
-def _attention(q, k, v, impl: str = "naive", causal: bool = True,
-               window: Optional[int] = None, scale: Optional[float] = None,
-               q_rope=None, k_rope=None, block_diffusion=None, sink=None):
-    """Attention; q,k,v: (B, H, T, hd); ``causal=False`` is the
-    bidirectional (encoder) form; ``window`` (causal only) keeps a
-    query's last ``window`` keys, its own among them, in every lowering.
-    v (and the result) may be another width than q and k; ``scale``
-    multiplies the scores (``hd ** -0.5`` where not given); ``q_rope``
-    (B, H, T, dr) and ``k_rope`` (B, fewer heads, T, dr) are a second
-    part of q and k whose product is added to the scores: the flash
-    kernels take them as they are (the few key heads shared through the
-    index map), the XLA forms get them joined onto q and k.
-    ``block_diffusion=(L, B)`` is the block-diffusion layout of ``T = 2 L``
-    rows in place of ``causal``: tile lists in the flash kernels, a dense
-    mask in the XLA forms (``ops.attention.block_diffusion_visible``).
-    ``sink`` (H,): one learned scalar a query head that stands in every
-    row's softmax as a key without a value, in every lowering (the carry's
-    first term in the two folds, one more column here).
-
-    ``impl="auto"`` resolves through :func:`resolve_attention`;
-    ``"blockwise"`` runs the fused online-softmax fold (no (T, T) score
-    matrix in HBM); ``"naive"`` is the materialized-scores baseline."""
-    # with a second score part the flash kernels hold, a head, k's first
-    # part and v as they do without one, and beside them the second part
-    # of as few heads as it has (one under MLA): ``auto`` is decided on the
-    # first part's width
-    impl = resolve_attention(impl, q)
-    if q_rope is not None:
-        if scale is None:
-            scale = (q.shape[-1] + q_rope.shape[-1]) ** -0.5
-        if impl != "flash":
-            # k's second part on as many heads as k has (all of q's under
-            # the latent mixer, whose ``expand(k)`` is then the identity)
-            B, H, T = q.shape[0], k.shape[1], q.shape[2]
-            expand = lambda t: jnp.broadcast_to(
-                t[:, :, None], (B, t.shape[1], H // t.shape[1], T, t.shape[-1])
-            ).reshape(B, H, T, t.shape[-1])
-            q = jnp.concatenate([q, q_rope], axis=-1)
-            k = jnp.concatenate([expand(k), expand(k_rope)], axis=-1)
-            q_rope = k_rope = None
-    # no window, no scale: each lowering is called as it was before there
-    # was one (tests put a spy of the old signature in a lowering's place)
-    windowed = {} if window is None else {"window": window}
-    if scale is not None:
-        windowed["scale"] = scale
-    if block_diffusion is not None:
-        if window is not None:
-            raise ValueError("block diffusion has no window")
-        windowed["block_diffusion"] = block_diffusion
-    if sink is not None and impl != "naive":
-        windowed["sink"] = sink
-    if impl == "blockwise":
-        from ..ops.attention import blockwise_attention
-
-        return blockwise_attention(q, k, v, causal=causal, **windowed)
-    if impl == "flash":
-        # the Pallas kernel owns the fold schedule; its custom_vjp
-        # backward kernel makes it trainable (rebuilds probability tiles
-        # from the saved logsumexp — no (T, T) residual)
-        from ..ops.pallas.attention import flash_attention
-
-        if q_rope is not None:
-            windowed.update(q_rope=q_rope, k_rope=k_rope)
-        return flash_attention(q, k, v, causal=causal, **windowed)
-    if impl != "naive":
-        raise ValueError(f"unknown attention impl {impl!r}")
-    B, H, T, hd = q.shape
-    Hkv = k.shape[1]
-    # grouped-query attention folds the group into the einsum (each kv
-    # head broadcasts across its G query heads; k/v are never expanded)
-    qg = q.reshape(B, Hkv, H // Hkv, T, hd)
-    # matmuls stay in the input dtype (bf16 on the MXU's fast path) with
-    # f32 accumulation; softmax statistics run in f32 and the probs cast
-    # back down for the second matmul.  The scale is a PYTHON float — a
-    # NumPy scalar (np.sqrt) is strongly typed and would silently promote
-    # bf16 activations to f32 through the rest of the block.
-    scores = jnp.einsum(
-        "bhgqd,bhkd->bhgqk", qg, k, preferred_element_type=jnp.float32
-    ) * (1.0 / math.sqrt(hd) if scale is None else scale)
-    if window is not None and not causal:
-        raise ValueError("a window is causal")
-    if block_diffusion is not None:
-        from ..ops.attention import block_diffusion_visible
-
-        pos = jnp.arange(T)
-        mask = block_diffusion_visible(
-            pos[:, None], pos[None, :], *block_diffusion
-        )
-        scores = jnp.where(mask, scores, -1e30)
-    elif causal:
-        mask = jnp.tril(jnp.ones((T, T), bool))
-        if window is not None:
-            mask &= ~jnp.tril(jnp.ones((T, T), bool), -window)
-        scores = jnp.where(mask, scores, -1e30)
-    if sink is not None:
-        # one more column a row, the head's scalar; it takes its share of
-        # the row's probability and has no value
-        column = jnp.broadcast_to(
-            sink.astype(jnp.float32).reshape(1, Hkv, H // Hkv, 1, 1),
-            (*scores.shape[:-1], 1),
-        )
-        scores = jnp.concatenate([scores, column], axis=-1)
-        probs = jax.nn.softmax(scores, axis=-1)[..., :-1].astype(v.dtype)
-    else:
-        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v)
-    return out.reshape(B, H, T, v.shape[-1])
-
-
 def _ffn_hidden(h, lp, relu2: bool = False):
     """The dense FFN's hidden activation: gated SiLU where the layer has
     a ``w3``, else GELU or, by the caller's word, ``relu ** 2``."""
@@ -1899,333 +1206,14 @@ def _mlp(x, lp, tp_axis, ep_axis=None, moe_cfg=None, with_aux=False,
     return (x + partial_f, None) if with_aux else x + partial_f
 
 
-def _latent_attn_partial(h, lp, n_heads_local, attn_impl, causal, rope_base,
-                         window, latent):
-    """The latent mixer (``TransformerConfig.latent``) on a full-sequence
-    activation, heads column-parallel: the row-parallel PARTIAL output.
-    The sizes are the tree's (``wq_b`` and ``wkv_b`` hold this chip's
-    heads); ``latent`` carries what the shapes do not say: the softmax
-    ``scale``, the rotary ``inv_freq`` and ``table_scale``, the norms'
-    ``eps``.  Projections, norms and rope run under the device scope
-    ``accl.attn::latent``, the score/softmax/value core under
-    ``accl.attn::mla``; the rope key goes to the core as ONE head.  What
-    the tree holds picks the rest: a ``wq`` in place of ``wq_a`` is q
-    straight from the hidden state (no q latent, no q norm), and a ``wg``
-    ``(d_model, heads)`` gates each head's output by ``sigmoid(h wg)``
-    before ``wo``.  Under ``cfg.remat`` the block keeps the flash core's
-    ``o`` and ``lse`` (its forward rule names them) and nothing of this
-    mixer's own: q, k and v are expanded from the latents on every head
-    (0.4 GB more a layer at Ling-3.0's widths) and are replayed."""
-    B, T, _ = h.shape
-    H = n_heads_local
-    rank = lp["wkv_b"].shape[0]
-    dr = lp["wkv_a"].shape[1] - rank
-    norm = partial(_rmsnorm, eps=latent["eps"])
-    heads = lambda t, n: t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
-    with device_scope("accl.attn::latent"):
-        if "wq_a" in lp:
-            q = heads(norm(h @ lp["wq_a"], lp["q_a_norm"]) @ lp["wq_b"], H)
-        else:
-            q = heads(h @ lp["wq"], H)
-        dn = q.shape[-1] - dr
-        ckv = h @ lp["wkv_a"]
-        kv = heads(norm(ckv[..., :rank], lp["kv_a_norm"]) @ lp["wkv_b"], H)
-        q_n, q_r = q[..., :dn], q[..., dn:]
-        k_n, v = kv[..., :dn], kv[..., dn:]
-        k_r = heads(ckv[..., rank:], 1)
-        if rope_base is not None:
-            tables = _rope_tables(
-                jnp.arange(T), dr // 2, rope_base, latent["inv_freq"],
-                latent["table_scale"],
-            )
-            q_r = _rope_rotate(q_r, tables)
-            k_r = _rope_rotate(k_r, tables)
-        # inside a shard_map: the one rope key varying over the axes the
-        # heads vary over (tp), so that its cotangent is summed over the
-        # chips' heads by the cast's transpose
-        if missing := tuple(jax.typeof(q_r).vma - jax.typeof(k_r).vma):
-            k_r = jax.lax.pcast(k_r, missing, to="varying")
-    with device_scope("accl.attn::mla"):
-        attn = _attention(
-            q_n, k_n, v, impl=attn_impl, causal=causal, window=window,
-            scale=latent["scale"], q_rope=q_r, k_rope=k_r,
-        )
-    with device_scope("accl.attn::latent"):
-        if "wg" in lp:
-            gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
-            attn = attn * gate.astype(attn.dtype).transpose(0, 2, 1)[..., None]
-        return attn.transpose(0, 2, 1, 3).reshape(B, T, -1) @ lp["wo"]
-
-
-def _kda_partial(h, lp, n_heads_local, kda):
-    """The KDA mixer (:class:`DeltaAttention`) on a full-sequence
-    activation, heads column-parallel: the row-parallel PARTIAL output.
-    The sizes are the tree's (gate projections through a rank where it
-    holds ``wf_a`` / ``wf_b`` and ``wg_a`` / ``wg_b``); ``kda`` carries what
-    the shapes do not say, the gate's ``lower_bound`` (``None``: the gate
-    without a bound, and the core's split by halving), the write strength's
-    ``beta_scale`` and the output norm's ``eps``.  The matmuls
-    take the activations' type; the convolutions, SiLU, the L2 norms, the
-    gate, beta, the core and the output norm are float32, in either
-    lowering of the three chains round the core (``ops.kda``: ``conv_in``
-    from a projection to q, k or v, ``decay_in`` to the log-decay,
-    ``gated_out`` from ``o`` to what ``wo`` takes; at heads of whole lanes
-    each is one Mosaic kernel forward and one backward that keep the
-    chain's float32 values in VMEM and save only the projection, at any
-    other shape XLA's fusions).  Everything but the core runs under the
-    device scope ``accl.attn::kda_proj``, the core (from normalised q, k,
-    v, the log-decay and beta to ``o``: ``ops.kda.kda_chunked``) under
-    ``accl.attn::kda``.
-
-    Under ``cfg.remat`` the block keeps the five bf16 projections the
-    chains read (``h wq``, ``h wk``, ``h wv``, the decay gate's and the
-    output gate's, the FINAL product where a gate goes through a rank:
-    ``KEPT_UNDER_REMAT``; 5 x B T d_inner x 2 bytes, 671,088,640 a layer at
-    2 x 8,192 x 4,096 or 1 x 8,192 x 8,192): the backward replays the
-    chains and the core from them and multiplies none of them out twice.
-    Nothing else is named: ``o`` and the core's saved set (1.07 GB a layer)
-    do not fit six layers, ``wo``'s product and beta's are replayed.
-
-    A tree with ``wa`` (``DeltaAttention.head_decay``: Gated DeltaNet) has
-    ONE log-decay a head a token, ``g`` (B, H, T, 1), from a product of H
-    columns that is replayed like beta's (four projections are kept: 8,192 x
-    17,280 x 2 bytes at heads of 96 / 192); v, the output gate and ``wo``'s
-    rows are as wide as ``wv`` says, and ``kda["out_gate"]`` ``"silu"`` gates
-    the normed output by a SiLU.  The core then runs ``ops.kda``'s padded
-    path and the chains their XLA forms (heads of 96 and 192 are no whole
-    lanes)."""
-    from ..ops.kda import conv_in, decay_in, gated_out, kda_chunked
-
-    H = n_heads_local
-    f32 = jnp.float32
-    with device_scope("accl.attn::kda_proj"):
-        proj = lambda w: kept_under_remat(h @ lp[w])
-        q = conv_in(proj("wq"), lp["conv_q"], H, unit=True,
-                    scale=(lp["wq"].shape[1] // H) ** -0.5)
-        k = conv_in(proj("wk"), lp["conv_k"], H, unit=True)
-        v = conv_in(proj("wv"), lp["conv_v"], H, unit=False)
-        # a gate through a rank keeps its FINAL product
-        through = lambda w: kept_under_remat(
-            h @ lp[w] if w in lp else (h @ lp[w + "_a"]) @ lp[w + "_b"]
-        )
-        bound = kda["lower_bound"]
-        # a decay a head (``wa``, H columns): one log-decay a head a token,
-        # (B, H, T, 1), a product too small to keep
-        gate = h @ lp["wa"] if "wa" in lp else through("wf")
-        g = decay_in(gate, lp["dt_bias"], lp["a_log"], bound)
-        beta = jax.nn.sigmoid((h @ lp["wbeta"]).astype(f32)).transpose(0, 2, 1)
-        if kda.get("beta_scale", 1.0) != 1.0:
-            beta = beta * kda["beta_scale"]
-    with device_scope("accl.attn::kda"):
-        # (B, H, T, dv) f32; without a bound, the split by halving
-        o = kda_chunked(q, k, v, g, beta, safe=bound is None)
-    with device_scope("accl.attn::kda_proj"):
-        o = gated_out(o, through("wg"), lp["o_norm"], kda["eps"], h.dtype,
-                      silu=kda.get("out_gate") == "silu")
-        return o @ lp["wo"]
-
-
-def _mamba2_partial(h, lp, mamba):
-    """The Mamba-2 mixer (:class:`Mamba2`) on a full-sequence activation,
-    heads and groups column-parallel: the row-parallel PARTIAL output.  The
-    sizes are the tree's but what no shape says, which ``mamba`` carries:
-    a head's width, the state's, the chunk and the norm's ``eps``.  The
-    matmuls take the activations' type; the convolution, SiLU, softplus,
-    the core, the gate and the grouped norm are float32.  Everything but
-    the core runs under the device scope ``accl.attn::mamba_proj``: the
-    matmuls, ``dt``'s softplus and the two float32 chains, ``ops.ssd``'s
-    ``conv_silu`` (x, B and C) and ``gated_group_norm``, each of which
-    picks from the shapes the Mosaic kernels of ``ops/pallas/
-    mamba_mixer.py`` (``mamba_in_fwd`` / ``mamba_in_bwd``, ``mamba_out_fwd``
-    / ``mamba_out_bwd``: one pass over HBM a chain, forward and backward)
-    or XLA's fusions.  The core (from x, B, C and dt to y, all TOKEN-MAJOR,
-    as the convolutions leave them and the norm takes them:
-    ``ops.ssd.ssd_mixer``, which picks from the shapes the Mosaic kernels
-    ``ssd_fwd`` / ``ssd_bwd`` that keep a chunk's decay squares and the
-    running state in VMEM, or the XLA form round its head-major
-    transposes) runs under ``accl.attn::ssd``.
-
-    Under ``cfg.remat`` the block keeps the five bf16 projections (``h
-    wz``, ``h wx``, ``h wb``, ``h wc``, ``h wdt``: ``KEPT_UNDER_REMAT``;
-    B T (2 d_inner + 2 G N + H) x 2 bytes, 304,087,040 a block at 8,192 x
-    18,560): the backward replays the chains and the core from them and
-    multiplies none of them out twice; ``wo``'s product is replayed."""
-    from ..ops.ssd import conv_silu, gated_group_norm, ssd_mixer
-
-    N = mamba["state"]
-    G = lp["wb"].shape[1] // N
-    f32 = jnp.float32
-    with device_scope("accl.attn::mamba_proj"):
-        proj = lambda w: kept_under_remat(h @ lp[w])
-        z = proj("wz")
-        x = conv_silu(proj("wx"), lp["conv_x"], lp["bias_x"])
-        b = conv_silu(proj("wb"), lp["conv_b"], lp["bias_b"])
-        c = conv_silu(proj("wc"), lp["conv_c"], lp["bias_c"])
-        dt = jax.nn.softplus(
-            proj("wdt").astype(f32) + lp["dt_bias"].astype(f32)
-        )                                                 # (B, T, H)
-        a = -jnp.exp(lp["a_log"].astype(f32))
-    with device_scope("accl.attn::ssd"):
-        y = ssd_mixer(x, b, c, dt, a, lp["d_skip"], G, mamba["chunk"])
-    with device_scope("accl.attn::mamba_proj"):
-        y = gated_group_norm(y, z, lp["y_norm"], G, mamba["eps"], h.dtype)
-        return y @ lp["wo"]
-
-
-def _attn_partial(h, lp, n_heads_local, attn_impl="naive", causal=True,
-                  rope_base=None, positions=None, attention_fn=None,
-                  tp_axis=None, window=None, head_norm=False, latent=None,
-                  qk_eps=1e-5, block_diffusion=None, kda=None, mamba=None,
-                  geometry=None):
-    """Column-parallel attention on a full-sequence activation: returns
-    the row-parallel PARTIAL output (pre-reduction) and the (k, v) head
-    tensors (B, Hkv_local, T, hd) for KV-cache prefill.  The kv head
-    count comes from the wk shard's width (GQA: fewer kv heads than q
-    heads; every attention lowering groups q heads onto kv head h//G).
-    With ``rope_base`` set, q/k rotate by absolute position BEFORE
-    attention (and before the kv tensors are returned, so the prefill
-    cache stores rotated keys — decode appends consistently).
-
-    ``positions`` overrides the rope positions (context parallelism
-    passes its shard's global token positions); ``attention_fn``
-    replaces the dense :func:`_attention` lowering (context parallelism
-    passes the striped ring).  ``tp_axis`` is for :func:`_qk_norm`.
-
-    What the layer's tree holds picks the rest: under ``head_norm`` the
-    ``q_norm`` / ``k_norm`` scales are one head wide and norm each head
-    AFTER the split (:func:`_qk_norm` is the whole projection's);
-    a ``wg`` gates the attention output, ``attn * sigmoid(h wg)``, before
-    ``wo``; a ``wkv_a`` is the latent mixer's (:func:`_latent_attn_partial`,
-    which has no cache to return yet), a ``d_skip`` the Mamba-2 mixer's
-    (:func:`_mamba2_partial`) and an ``a_log`` without one the KDA mixer's
-    (:func:`_kda_partial`; both likewise).  ``window`` is the sliding window,
-    run under the device scope ``accl.attn::window`` (full attention stays
-    ``accl.attn::core``; where the stack has KDA layers, ``kda`` given, or
-    the layer a head geometry, what is round the core runs under
-    ``accl.attn::gqa_proj``).  A ``sink`` leaf (a float32 scalar a query
-    head) stands in every row's softmax as a key without a value; v's width
-    is ``wv``'s over the K/V heads that ``wk``'s gives.  ``geometry``
-    (:class:`HeadGeometry`) is what the shapes cannot say: of a head's
-    columns the FIRST ``rope_dim`` rotate and go to the core as the scores'
-    second part (``q_rope`` / ``k_rope`` on the K/V heads), the others carry
-    no position; v is times ``v_scale``.  ``qk_eps`` is QK-norm's epsilon.
-    ``block_diffusion=(L, B)``: ``h`` is ``[noisy ; clean]``, ``2 L`` rows
-    that rotate at positions ``0..L`` twice, and the core runs under that
-    layout in the device scope ``accl.attn::blockdiff``.
-
-    Under ``cfg.remat`` the block keeps q, k and v AS THE CORE TAKES THEM
-    (``KEPT_UNDER_REMAT``: head-major, after the norms, the value scale, the
-    rope and the split, ``q_rope`` / ``k_rope`` beside them where a head
-    splits) and, where the core is the flash kernels, the core's ``o`` and
-    ``lse`` (named in its forward rule): all that ``flash_bwd`` reads, so
-    the backward's replay runs none of the three products, no transpose, no
-    rope and no ``flash_fwd``; ``wo``'s product and a gate's are replayed."""
-    if "d_skip" in lp:
-        return _mamba2_partial(h, lp, mamba), None
-    if "a_log" in lp:
-        return _kda_partial(h, lp, n_heads_local, kda), None
-    if "wkv_a" in lp:
-        return _latent_attn_partial(
-            h, lp, n_heads_local, attn_impl, causal, rope_base, window, latent
-        ), None
-    B, T, _ = h.shape
-    # a softmax layer BESIDE KDA layers (``kda`` is the stack's: a gated
-    # grouped-query layer without position among linear-attention ones)
-    # runs what is round its core under a device scope of its own, as the
-    # KDA layers do; every other stack's program is what it was
-    proj_scope = (
-        device_scope("accl.attn::gqa_proj")
-        if kda is not None or geometry is not None
-        else contextlib.nullcontext()
-    )
-    with proj_scope:
-        q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]  # column-parallel
-    # a head geometry's own work between the projections and the core (the
-    # value scale, the split into the part that rotates and the part without
-    # position, the sink's cast) goes under the same scope; without one the
-    # lines below trace outside any scope, as they always did
-    with proj_scope if geometry is not None else contextlib.nullcontext():
-        if not head_norm:
-            q, k = _qk_norm(q, k, lp, tp_axis, qk_eps)
-        hd = q.shape[-1] // n_heads_local
-        n_kv_local = k.shape[-1] // hd
-        # v's heads are as wide as ``wv`` makes them
-        heads = lambda t, n: t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
-        q, k, v = (
-            heads(q, n_heads_local), heads(k, n_kv_local), heads(v, n_kv_local)
-        )
-        if geometry is not None and geometry.v_scale != 1.0:
-            v = v * geometry.v_scale
-        # the scores' second part and the sink, where the layer has them
-        more = {}
-        if "sink" in lp:
-            # inside a shard_map: the sink varying over every axis q varies
-            # over (the batch's too), so that its cotangent is summed over
-            # them by the cast's transpose, as the latent mixer's one rope
-            # key is
-            more["sink"] = sink = lp["sink"]
-            if missing := tuple(jax.typeof(q).vma - jax.typeof(sink).vma):
-                more["sink"] = jax.lax.pcast(sink, missing, to="varying")
-        if head_norm and "q_norm" in lp:
-            q = _rmsnorm(q, lp["q_norm"], eps=qk_eps)
-            k = _rmsnorm(k, lp["k_norm"], eps=qk_eps)
-        if rope_base is not None:
-            if block_diffusion is not None:
-                pos = jnp.tile(jnp.arange(block_diffusion[0]), 2)
-            else:
-                pos = jnp.arange(T) if positions is None else positions
-            dr = hd if geometry is None else geometry.rope_dim or hd
-            tables = _rope_tables(pos, dr // 2, rope_base)
-            if dr == hd:
-                q = _rope_rotate(q, tables)
-                k = _rope_rotate(k, tables)
-            else:
-                rotated = lambda t: kept_under_remat(
-                    _rope_rotate(t[..., :dr], tables)
-                )
-                more.update(q_rope=rotated(q), k_rope=rotated(k))
-                q, k = q[..., dr:], k[..., dr:]
-        # what the core's backward reads of the mixer's work, AS THE CORE
-        # TAKES IT (the two rotated parts above with it): a rematerialised
-        # block keeps these, so that its backward runs neither the products
-        # nor the relayouts above a second time
-        q, k, v = (kept_under_remat(t) for t in (q, k, v))
-    if block_diffusion is not None:
-        with device_scope("accl.attn::blockdiff"):
-            attn = _attention(
-                q, k, v, impl=attn_impl, block_diffusion=block_diffusion
-            )
-    elif window is None:
-        with device_scope("accl.attn::core"):
-            if attention_fn is not None:
-                attn = attention_fn(q, k, v)
-            else:
-                attn = _attention(
-                    q, k, v, impl=attn_impl, causal=causal, **more
-                )
-    else:
-        with device_scope("accl.attn::window"):
-            attn = _attention(
-                q, k, v, impl=attn_impl, causal=causal, window=window, **more
-            )
-    with proj_scope:
-        attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
-        if "wg" in lp:
-            gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
-            attn = attn * gate.astype(attn.dtype)
-        return attn @ lp["wo"], (k, v)
-
-
-def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
-           attn_impl="naive", causal=True, rope_base=None,
+def _block(x, lp, mixer, tp_axis, return_kv=False,
            ep_axis=None, moe_cfg=None, with_aux=False,
-           reduce_fn=None, fanout_fn=None, norm=_layernorm,
-           window=None, head_norm=False, latent=None, qk_eps=1e-5,
-           block_diffusion=None, kda=None, mamba=None, relu2=False,
-           geometry=None):
+           reduce_fn=None, fanout_fn=None, norm=_layernorm, relu2=False):
     """One transformer block on tp-sharded weights.  ``lp['wqkv']`` etc. are
     the *local shards*; the tp-allreduce after each row-parallel matmul is
-    the reference's fused-allreduce hot path in model form.
+    the reference's fused-allreduce hot path in model form.  ``mixer`` is
+    the layer's bound mixer (``MIXERS[name].bind``): ``(h, lp) ->
+    (partial_o, kv)``, the row-parallel PARTIAL output.
 
     What the tree holds says which sub-layers the block has: a mixer where
     it has an ``ln1``, an FFN where it has an ``ln2`` (``LayerKind``: a
@@ -2253,12 +1241,7 @@ def _block(x, lp, n_heads_local, tp_axis, return_kv=False,
             # manual-backward mode marks the fan-out so its transpose (a tp
             # psum of the branch cotangents) lands here and nowhere else
             h = fanout_fn(h, tp_axis)
-        partial_o, kv = _attn_partial(
-            h, lp, n_heads_local, attn_impl, causal, rope_base,
-            tp_axis=tp_axis, window=window, head_norm=head_norm,
-            latent=latent, qk_eps=qk_eps, block_diffusion=block_diffusion,
-            kda=kda, mamba=mamba, geometry=geometry,
-        )
+        partial_o, kv = mixer(h, lp)
         if tp_axis is not None:
             partial_o = reduce_fn(partial_o, tp_axis)
         if "ln1_post" in lp:
@@ -2291,17 +1274,17 @@ def _cp_block_k(t_local: int, attn_impl: str):
     return None  # tiny/ragged shard: whole-hop fold is already small
 
 
-def _block_cp(x, lp, n_heads, cp_axis, rope_base=None, attn_impl="auto",
-              ep_axis=None, moe_cfg=None, with_aux=False, norm=_layernorm,
-              qk_eps=1e-5):
+def _block_cp(x, lp, mixer, ep_axis=None, moe_cfg=None, with_aux=False,
+              norm=_layernorm):
     """Context-parallel block: ``x`` is (B, T/cp, D), this rank's STRIPED
-    sequence shard over ``cp_axis``; weights are full (replicated over
-    the axis).  QKV/MLP matmuls are purely local; attention is striped
-    causal ring attention — K/V blocks (unexpanded kv heads under GQA)
-    rotate around the ring folding into the local online-softmax state —
-    so nothing in the block ever materializes the full sequence.  Rope
-    rotates by the shard's GLOBAL token positions; ``attn_impl`` maps to
-    the fold's within-hop sub-tiling (:func:`_cp_block_k`).
+    sequence shard over the ring's axis; weights are full (replicated over
+    the axis).  QKV/MLP matmuls are purely local; ``mixer`` is the
+    attention mixer bound to the ring (:func:`_enter_block_layout`):
+    striped causal ring attention — K/V blocks (unexpanded kv heads under
+    GQA) rotate around the ring folding into the local online-softmax
+    state — so nothing in the block ever materializes the full sequence.
+    Rope rotates by the shard's GLOBAL token positions; ``cfg.attention``
+    maps to the fold's within-hop sub-tiling (:func:`_cp_block_k`).
 
     With an expert bank on the layer (MoE x cp — long-context MoE), the
     MLP half routes this rank's sequence shard through the expert
@@ -2309,25 +1292,13 @@ def _block_cp(x, lp, n_heads, cp_axis, rope_base=None, attn_impl="auto",
     tp: the two communication patterns ride DIFFERENT mesh axes, which
     is exactly why the composition is legal (tp_axis stays None — under
     cp the experts, like every weight, are replicated over the ring)."""
-    from .ring_attention import striped_attention
-
-    positions = _cp_positions(x.shape[1], cp_axis)
-    block_k = _cp_block_k(x.shape[1], attn_impl)
-    ring = lambda q, k, v: striped_attention(
-        q, k, v, cp_axis, causal=True, block_k=block_k
-    )
     h = norm(x, lp["ln1"])
-    o, _ = _attn_partial(
-        h, lp, n_heads, rope_base=rope_base,
-        positions=positions, attention_fn=ring, qk_eps=qk_eps,
-    )
+    o, _ = mixer(h, lp)
     x = x + o
     return _mlp(x, lp, None, ep_axis, moe_cfg, with_aux, norm=norm)
 
 
-def _block_sp(x_sp, lp, n_heads_local, tp_axis, return_kv=False,
-              attn_impl="naive", causal=True, rope_base=None,
-              norm=_layernorm, qk_eps=1e-5):
+def _block_sp(x_sp, lp, mixer, tp_axis, return_kv=False, norm=_layernorm):
     """Sequence-parallel block (Megatron-SP): ``x_sp`` is (B, T/tp, D),
     sequence-sharded over ``tp``.  All-gather restores the full sequence
     in front of each column-parallel matmul; the row-parallel reduction
@@ -2341,10 +1312,7 @@ def _block_sp(x_sp, lp, n_heads_local, tp_axis, return_kv=False,
     sequence-parallel prefill path of the KV-cache decode."""
     h = norm(x_sp, lp["ln1"])
     h_full = collectives.allgather(h, tp_axis, axis=1)
-    partial_o, kv = _attn_partial(
-        h_full, lp, n_heads_local, attn_impl, causal, rope_base,
-        tp_axis=tp_axis, qk_eps=qk_eps,
-    )
+    partial_o, kv = mixer(h_full, lp)
     o_sp = collectives.reduce_scatter(
         partial_o, tp_axis, tiled=True, axis=1
     )
@@ -2359,6 +1327,28 @@ def _block_sp(x_sp, lp, n_heads_local, tp_axis, return_kv=False,
     return (out, kv) if return_kv else out
 
 
+def _bound_blocks(block, cfg, tp_axis, tp_size, **softmax):
+    """``block`` for each layer of the pattern, the layer's mixer bound by
+    name (``MIXERS``; a block without a mixer binds none): ONE function
+    where every layer is alike, one a layer where ``cfg.layers`` gives
+    them.  ``softmax``: what the bidirectional and the context-parallel
+    paths, which take the plain block alone, bind the attention mixer
+    with."""
+
+    def bound(kind):
+        name = cfg.mixer(kind)
+        if name == "none":
+            return partial(block, mixer=None)
+        bind = MIXERS[name].bind
+        return partial(
+            block, mixer=bind(cfg, kind, tp_axis, tp_size, **softmax)
+        )
+
+    if cfg.layers is None:
+        return [bound(kind) for kind in cfg.pattern()[:1]] * cfg.n_layers
+    return [bound(kind) for kind in cfg.layers]
+
+
 def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
                         causal=True):
     """Enter the block stack's activation layout and pick the block fn.
@@ -2370,7 +1360,9 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
     blocks run :func:`_block_cp`; otherwise activations stay replicated
     and blocks run :func:`_block`.  Shared by the training forward and
     the serving prefill so the two paths cannot diverge on the entry
-    invariant.  Returns ``(x, block_fn, layout)`` with layout one of
+    invariant.  Returns ``(x, blocks, layout)``: the layout's block for
+    each layer of the pattern with the layer's mixer bound
+    (:func:`_bound_blocks`), and layout one of
     ``""`` (replicated), ``"sp"``, ``"cp"`` — truthy means x is
     sequence-sharded."""
     from jax import lax
@@ -2388,79 +1380,50 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
                 "context_parallel is causal/decoder-only (the striped "
                 "ring's load balance argument is the causal mask)"
             )
-        cp_kw = dict(
-            n_heads=cfg.n_heads, cp_axis=tp_axis,
-            rope_base=cfg.rope_base if cfg.uses_rope() else None,
-            attn_impl=cfg.attention, norm=_norm_fn(cfg),
-        )
+        from .ring_attention import striped_attention
+
+        cp_kw = dict(norm=_norm_fn(cfg))
         if cfg.n_experts:
             cp_kw["ep_axis"] = cfg.moe_mesh_axis
             cp_kw["moe_cfg"] = cfg
             cp_kw["with_aux"] = True
-        if cfg.qk_norm and cfg.norm_eps != 1e-5:
-            cp_kw["qk_eps"] = cfg.norm_eps
-        block = partial(_block_cp, **cp_kw)
-        return x, block, "cp"
-    heads_local = cfg.n_heads // tp_size
+        block_k = _cp_block_k(x.shape[1], cfg.attention)
+        # the ring's axis carries no weight shard: the mixer's weights are
+        # whole (no tp axis, a tp of 1)
+        blocks = _bound_blocks(
+            partial(_block_cp, **cp_kw), cfg, None, 1,
+            positions=lambda t_local: _cp_positions(t_local, tp_axis),
+            attention_fn=lambda q, k, v: striped_attention(
+                q, k, v, tp_axis, causal=True, block_k=block_k
+            ),
+        )
+        return x, blocks, "cp"
     if cfg.vocab_parallel and tp_size > 1 and cfg.vocab % tp_size:
         raise ValueError(
             f"vocab_parallel needs vocab ({cfg.vocab}) divisible by tp "
             f"({tp_size})"
         )
-    if cfg.latent is not None and cfg.n_heads % tp_size:
-        raise ValueError(
-            f"n_heads ({cfg.n_heads}) must be divisible by tp ({tp_size}) "
-            "so every chip owns whole heads of the latent mixer"
-        )
-    kv_counts = sorted({cfg.kv_heads(kind) for kind in cfg.pattern()})
-    if tp_size > 1 and cfg.latent is None and any(
-        n % tp_size for n in kv_counts
-    ):
-        raise ValueError(
-            f"n_kv_heads ({', '.join(map(str, kv_counts))}) must be divisible "
-            f"by tp ({tp_size}) so every chip owns whole kv heads"
-        )
     sp = cfg.seq_parallel and tp_axis is not None and tp_size > 1
-    kw = dict(
-        n_heads_local=heads_local, tp_axis=tp_axis,
-        attn_impl=cfg.attention, causal=causal,
-        rope_base=cfg.rope_base if cfg.uses_rope() else None,
-        norm=_norm_fn(cfg),
-    )
+    kw = dict(tp_axis=tp_axis, norm=_norm_fn(cfg))
     if return_kv:
         kw["return_kv"] = True
-    if cfg.qk_norm == "head":
-        kw["head_norm"] = True
-    if cfg.qk_norm and cfg.norm_eps != 1e-5:
-        kw["qk_eps"] = cfg.norm_eps
-    if cfg.diffusion is not None:
-        if x.shape[1] % 2 or (x.shape[1] // 2) % cfg.diffusion.block:
+    # the bidirectional (encoder) form is the attention mixer's
+    softmax = {} if causal else {"causal": False}
+    if sp:
+        T = x.shape[1]
+        if T % tp_size:
             raise ValueError(
-                f"block diffusion runs [noisy ; clean], 2 L rows of whole "
-                f"blocks of {cfg.diffusion.block}; got {x.shape[1]} rows"
+                f"seq_parallel needs sequence length ({T}) divisible by "
+                f"tp ({tp_size})"
             )
-        kw["block_diffusion"] = (x.shape[1] // 2, cfg.diffusion.block)
-    if cfg.latent is not None:
-        kw["latent"] = {
-            "scale": cfg.attn_scale(), "inv_freq": cfg.rope_inv_freq(),
-            "table_scale": cfg.rope_table_scale(), "eps": cfg.norm_eps,
-        }
-    if cfg.kda is not None:
-        kw["kda"] = {
-            "lower_bound": cfg.kda.lower_bound, "eps": cfg.norm_eps,
-            "beta_scale": cfg.kda.beta_scale, "out_gate": cfg.kda.out_gate,
-        }
-    if cfg.mamba is not None:
-        m = cfg.mamba
-        if tp_size > 1 and m.groups % tp_size:
-            raise ValueError(
-                f"the Mamba-2 mixer's groups ({m.groups}) must be divisible "
-                f"by tp ({tp_size}) so every chip owns whole groups of heads"
-            )
-        kw["mamba"] = {
-            "head_dim": m.head_dim, "state": m.state, "chunk": m.chunk,
-            "eps": cfg.norm_eps,
-        }
+        # enter the sequence-sharded regime: this rank keeps its T/tp slice
+        Tl = T // tp_size
+        idx = lax.axis_index(tp_axis)
+        x = lax.dynamic_slice_in_dim(x, idx * Tl, Tl, axis=1)
+        blocks = _bound_blocks(
+            partial(_block_sp, **kw), cfg, tp_axis, tp_size, **softmax
+        )
+        return x, blocks, "sp"
     if cfg.ffn == "relu2":
         kw["relu2"] = True
     if cfg.n_experts:
@@ -2471,25 +1434,15 @@ def _enter_block_layout(x, cfg, tp_axis, tp_size, return_kv=False,
         kw["ep_axis"] = cfg.moe_mesh_axis if tp_axis is not None else None
         kw["moe_cfg"] = cfg
         kw["with_aux"] = not return_kv  # serving paths skip router aux
-    if not sp:
-        return x, partial(_block, **kw), ""
-    T = x.shape[1]
-    if T % tp_size:
-        raise ValueError(
-            f"seq_parallel needs sequence length ({T}) divisible by "
-            f"tp ({tp_size})"
-        )
-    # enter the sequence-sharded regime: this rank keeps its T/tp slice
-    Tl = T // tp_size
-    idx = lax.axis_index(tp_axis)
-    x = lax.dynamic_slice_in_dim(x, idx * Tl, Tl, axis=1)
-    return x, partial(_block_sp, **kw), "sp"
+    blocks = _bound_blocks(
+        partial(_block, **kw), cfg, tp_axis, tp_size, **softmax
+    )
+    return x, blocks, ""
 
 
-def _layer_blocks(block, cfg):
-    """``block`` for each layer of the pattern: the layout's block as it
-    is where every layer is alike, and with the layer's own window and
-    rotation where ``cfg.layers`` gives them.  Under ``cfg.remat`` each is
+def _layer_blocks(blocks, cfg):
+    """The pattern's blocks (:func:`_enter_block_layout`'s) as the train
+    and forward paths run them: under ``cfg.remat`` each is
     rematerialised on the backward pass but for what its mixer, or its
     attention core's forward rule, names ``KEPT_UNDER_REMAT``: the KDA and
     Mamba-2 mixers' bf16 input projections (671 and 304 MB a layer at the
@@ -2501,23 +1454,12 @@ def _layer_blocks(block, cfg):
     One static rule, no budget: these fit every layer of every cell
     (``memory_analysis`` of the five ``remat`` cells' full steps, PERF.md
     section 5), which nothing larger (the KDA core's saved set) does."""
-    remat = partial(
-        jax.checkpoint,
-        policy=jax.checkpoint_policies.save_only_these_names(KEPT_UNDER_REMAT),
-    )
-    if cfg.layers is None:
-        if cfg.remat:
-            block = remat(block)
-        return [block] * cfg.n_layers
-    blocks = [
-        partial(
-            block, window=kind.window,
-            rope_base=(kind.rope_base or cfg.rope_base) if kind.rope else None,
-            geometry=kind.heads,
-        )
-        for kind in cfg.layers
-    ]
-    return [remat(b) for b in blocks] if cfg.remat else blocks
+    if not cfg.remat:
+        return blocks
+    policy = jax.checkpoint_policies.save_only_these_names(KEPT_UNDER_REMAT)
+    # layers that share a block share its rematerialised form
+    remat = {id(b): jax.checkpoint(b, policy=policy) for b in blocks}
+    return [remat[id(b)] for b in blocks]
 
 
 def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):
@@ -2535,8 +1477,8 @@ def _final_hidden(params, tokens, cfg, tp_axis=None, tp_size=1):
     the router probe and the expert bias's rule read them, elsewhere
     they are dead code)."""
     x = _embed_tokens(params, tokens, cfg, tp_axis)
-    x, block, sp = _enter_block_layout(x, cfg, tp_axis, tp_size)
-    blocks = _layer_blocks(block, cfg)
+    x, blocks, sp = _enter_block_layout(x, cfg, tp_axis, tp_size)
+    blocks = _layer_blocks(blocks, cfg)
     norm = _norm_fn(cfg)
     if cfg.diffusion is not None:
         # [noisy ; clean] went through the layers; the final norm (and
@@ -2749,71 +1691,23 @@ def _reject_unservable(cfg) -> None:
     here, by name, and not served wrongly."""
     if not cfg.plain():
         raise ValueError(
-            "prefill/generate serve TransformerConfig.plain() only: this "
-            "configuration has a layer pattern, a window, a head_dim of "
-            "its own, an attention gate, per-head QK-norm, post-norms, a "
-            "scaled embedding, a sigmoid router, a shared expert, a "
-            "held share of the experts, K/V heads, a rope base, a sink or a "
-            "head geometry of a layer kind's own (LayerKind.kv_heads, "
-            ".rope_base, .sink, .heads: the cache would be a window's keys "
-            "beside every key, on two head counts), a latent mixer (MLA: its "
-            "cache is "
-            "the latent and the rope key, not k and v), a KDA mixer or a "
-            "Mamba-2 mixer (the cache is a recurrent state and a "
-            "convolution's last inputs), a block of one sub-layer, a latent "
-            "expert bank, relu2, "
-            "grouped top-k, "
-            "balance losses or block diffusion (generation there denoises "
-            "a block of tokens at a time over several passes), and the "
-            "decode path has no cache "
-            "layout or block for those yet (train and forward do)"
+            "prefill/generate serve TransformerConfig.plain() only: the "
+            "decode path has no cache layout or block yet (train and "
+            "forward do) for what this configuration has, "
+            + _beyond_plain(cfg)
         )
 
 
 def reject_latent(cfg, where: str) -> None:
-    """The paths beside train and forward refuse a latent mixer, a KDA
-    mixer, a Mamba-2 mixer, a block of one sub-layer and the
-    block-diffusion objective, by name."""
-    if cfg.latent is not None:
+    """The paths beside train and forward refuse, by name, a layer whose
+    mixer they have no form for (``MIXERS``: a latent, a KDA or a Mamba-2
+    mixer, an attention mixer under block diffusion or with heads of a
+    layer kind's own) and a block of one sub-layer."""
+    named = _beyond_plain_mixers(cfg)
+    if named:
         raise ValueError(
-            "the latent mixer (MLA, TransformerConfig.latent) is supported "
-            f"on the decoder's train and forward paths only, not {where}"
-        )
-    if cfg.kda is not None:
-        raise ValueError(
-            "the KDA mixer (linear attention, TransformerConfig.kda: its "
-            "state is no cache yet, under either gate, at either rank of "
-            "the gate projections, with a decay a channel or a head) is "
-            f"supported on the decoder's train and forward paths only, not "
-            f"{where}"
-        )
-    if cfg.mamba is not None:
-        raise ValueError(
-            "the Mamba-2 mixer (a state-space layer, TransformerConfig.mamba) "
-            "is supported on the decoder's train and forward paths only, not "
-            f"{where}"
-        )
-    if any("none" in (k.mixer, k.ffn) for k in cfg.layers or ()):
-        raise ValueError(
-            "a block of one sub-layer (LayerKind.mixer or .ffn 'none') is "
-            "supported on the decoder's train and forward paths only, not "
-            f"{where}"
-        )
-    if cfg.diffusion is not None:
-        raise ValueError(
-            "block diffusion (TransformerConfig.diffusion) is supported on "
-            f"the decoder's train and forward paths only, not {where}"
-        )
-    if any(
-        k.sink or (k.kv_heads, k.rope_base, k.heads) != (None,) * 3
-        for k in cfg.layers or ()
-    ):
-        raise ValueError(
-            "attention heads of a layer kind's own (LayerKind.kv_heads, "
-            ".rope_base), a sink in the softmax (LayerKind.sink) and a head "
-            "geometry (LayerKind.heads: partial rotary, a v width of its "
-            "own, a value scale) are supported on the decoder's train and "
-            f"forward paths only, not {where}"
+            f"{named[0]}: supported on the decoder's train and forward "
+            f"paths only, not {where}"
         )
 
 
@@ -2903,11 +1797,11 @@ def prefill(
     x = _embed_tokens(params, tokens, cfg, tp_axis)
     kv_local = cfg.kv_heads() // tp_size  # GQA: cache holds kv heads only
     hd = cfg.head_size()
-    x, block_kv, sp = _enter_block_layout(
+    x, blocks_kv, sp = _enter_block_layout(
         x, cfg, tp_axis, tp_size, return_kv=True
     )
     caches = []
-    for lp in params["layers"]:
+    for block_kv, lp in zip(blocks_kv, params["layers"]):
         x, (k, v) = block_kv(x, lp)
         shape = (B, kv_local, S, hd)
         ck = jnp.zeros(shape, x.dtype).at[:, :, :T].set(k)

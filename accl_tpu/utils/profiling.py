@@ -131,7 +131,8 @@ drops ``op_name``, so a reader joins the two by instruction name
 (``perfbench/scope_ops.py``).
 
 ======================== ==================================================
-``accl.attn::core``      ``models/transformer.py`` ``_attn_partial``: the
+``accl.attn::core``      ``models/mixers/attention.py`` ``_attn_partial``:
+                         the
                          attention call (flash kernels, or the XLA forms),
                          on the K/V heads the layer's kind has
                          (``LayerKind.kv_heads``); a head in two parts
@@ -163,12 +164,14 @@ drops ``op_name``, so a reader joins the two by instruction name
                          products and that stretch run ONCE (the block
                          keeps q, k, v and the two rotated parts as the
                          core takes them), ``wo``'s and the gate's twice
-``accl.attn::latent``    ``_latent_attn_partial`` (a latent mixer, MLA): the
+``accl.attn::latent``    ``models/mixers/latent.py``
+                         ``_latent_attn_partial`` (a latent mixer, MLA): the
                          five projections (four where q has no latent),
                          the latent norms, the rope, the head-wise gate
 ``accl.attn::mla``       the same: the score/softmax/value core (the flash
                          kernels with two widths and ONE rope key head)
-``accl.attn::kda``       ``_kda_partial`` (a KDA mixer, ``LayerKind.mixer``
+``accl.attn::kda``       ``models/mixers/kda.py`` ``_kda_partial`` (a KDA
+                         mixer, ``LayerKind.mixer``
                          ``"kda"``): the core, ``ops/kda.py``
                          ``kda_chunked`` from normalised q, k, v, the
                          log-decay and beta to ``o``, forward and backward:
@@ -207,7 +210,8 @@ drops ``op_name``, so a reader joins the two by instruction name
                          lowering,
                          inside the kernels too (only the projections
                          and their cotangents have the matmuls' type)
-``accl.attn::ssd``       ``_mamba2_partial`` (a Mamba-2 mixer, ``LayerKind.
+``accl.attn::ssd``       ``models/mixers/mamba2.py`` ``_mamba2_partial`` (a
+                         Mamba-2 mixer, ``LayerKind.
                          mixer`` ``"mamba2"``): the core, ``ops/ssd.py``
                          ``ssd_mixer`` from token-major x, B, C and dt to
                          y, forward and backward: where a group's heads
@@ -234,7 +238,8 @@ drops ``op_name``, so a reader joins the two by instruction name
                          1,024 columns, XLA's fusions at any other width;
                          under ``remat`` the five projections are kept by
                          name and multiplied out once, the chains replay)
-``accl.attn::blockdiff`` ``_attn_partial`` under ``TransformerConfig.
+``accl.attn::blockdiff`` ``models/mixers/attention.py`` ``_attn_partial``
+                         under ``TransformerConfig.
                          diffusion``: the attention call on ``[noisy ;
                          clean]`` under the block-diffusion layout (the
                          flash kernels by its tile lists, or the XLA forms

@@ -672,7 +672,8 @@ def test_nemotron3_step_scans_the_chunks_and_places_latent_rows(
 #: carries the line numbers of the file that built it: ``tests/
 #: test_solar2.py`` holds the kernels' own jaxprs to the parent's).  PR 48
 #: edits the four files this layer runs (``ops/kda.py``, ``ops/pallas/
-#: kda.py``, ``ops/pallas/kda_mixer.py``, ``_kda_partial``) for a gate
+#: kda.py``, ``ops/pallas/kda_mixer.py``, ``_kda_partial``, which PR 56 moved
+#: to ``models/mixers/kda.py`` with this digest untouched) for a gate
 #: without a bound; the bounded gate is chosen statically, so this program
 #: is the parent's.  Re-recorded in PR 49 ON ITS OWN TREE, by intent: under
 #: ``remat`` the layer keeps the mixer's five bf16 projections by name, so the

@@ -16,7 +16,7 @@ import pytest
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from accl_tpu.models import transformer
+from accl_tpu.models.mixers import kda as mixer
 from accl_tpu.ops import kda
 from accl_tpu.ops.pallas import kda as core
 from accl_tpu.ops.pallas import kda_mixer as kernels
@@ -258,7 +258,7 @@ def test_the_mixer_end_to_end_on_both_lowerings(monkeypatch):
     monkeypatch.setattr(core, "_ONE_PASS", jnp.float32)
     h, lp = _mixer(200)
     co = jax.random.normal(jax.random.PRNGKey(7), h.shape)
-    run = lambda h, lp: transformer._kda_partial(
+    run = lambda h, lp: mixer._kda_partial(
         h, lp, H, {"lower_bound": LOWER, "eps": EPS}
     )
     both = lambda: (run(h, lp), jax.grad(

@@ -34,8 +34,8 @@ from accl_tpu.models import (
     make_sharded_train_step,
     moe_ffn,
 )
+from accl_tpu.models.mixers.latent import _latent_attn_partial
 from accl_tpu.models.transformer import (
-    _attn_partial,
     _auto_flash_fits,
     param_specs,
     resolve_attention,
@@ -362,7 +362,9 @@ def test_q_without_a_latent_against_the_references_direct_q(gated):
     h = jax.random.normal(jax.random.PRNGKey(3), (2, T, 64))
     latent = {"scale": cfg.attn_scale(), "inv_freq": cfg.rope_inv_freq(),
               "table_scale": cfg.rope_table_scale(), "eps": cfg.norm_eps}
-    got, _ = _attn_partial(h, lp, 4, rope_base=cfg.rope_base, latent=latent)
+    got = _latent_attn_partial(
+        h, lp, 4, "naive", True, cfg.rope_base, None, latent
+    )
     names = {"q_proj": "wq", "kv_a_proj_with_mqa": "wkv_a", "o_proj": "wo",
              "kv_a_layernorm": "kv_a_norm", "kv_b_proj": "wkv_b"}
     ref_lp = {k: lp[v] for k, v in names.items()}

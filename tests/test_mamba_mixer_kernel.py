@@ -16,7 +16,7 @@ import pytest
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from accl_tpu.models import transformer
+from accl_tpu.models.mixers import mamba2 as mixer
 from accl_tpu.ops import ssd
 from accl_tpu.ops.pallas import kda_mixer as geometry
 from accl_tpu.ops.pallas import mamba_mixer as kernels
@@ -247,7 +247,7 @@ def test_the_mixer_end_to_end_on_both_lowerings(monkeypatch):
     (a chunk of 32 is not the kernels')."""
     h, lp = _mixer(72)
     co = jax.random.normal(jax.random.PRNGKey(7), h.shape)
-    run = lambda h, lp: transformer._mamba2_partial(
+    run = lambda h, lp: mixer._mamba2_partial(
         h, lp, {"state": 512, "chunk": 32, "eps": EPS}
     )
     took = []

@@ -137,8 +137,8 @@ def _blocks(cfg, x, how):
     """The stack's blocks: as the program makes them (``"policy"``: ``remat``
     on; ``"off"``), or each rematerialised WHOLE, as before this policy."""
     on = dataclasses.replace(cfg, remat=how == "policy")
-    _, block, _ = _enter_block_layout(x, on, None, 1)
-    blocks = _layer_blocks(block, on)
+    _, blocks, _ = _enter_block_layout(x, on, None, 1)
+    blocks = _layer_blocks(blocks, on)
     return [jax.checkpoint(b) for b in blocks] if how == "whole" else blocks
 
 

@@ -335,8 +335,8 @@ def test_the_clean_half_at_blocks_of_one_is_the_plain_causal_forward(f32):
 
     def layers(tokens, cfg):
         x = _embed_tokens(params, tokens, cfg)
-        x, block, _ = _enter_block_layout(x, cfg, None, 1)
-        for lp in params["layers"]:
+        x, blocks, _ = _enter_block_layout(x, cfg, None, 1)
+        for block, lp in zip(blocks, params["layers"]):
             x, _ = block(x, lp)
         return x
 

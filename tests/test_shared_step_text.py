@@ -1,6 +1,7 @@
 """Guard for the code the benchmark's train cells share
-(``make_sharded_train_step``, ``loss_fn``, ``_block``, ``_attn_partial``,
-``_mlp``, ``_dropless_experts``, the attention lowerings): the StarCoder
+(``make_sharded_train_step``, ``loss_fn``, ``_block``, the mixers of
+``accl_tpu/models/mixers/`` (``_attn_partial``, ``_kda_partial``), ``_mlp``,
+``_dropless_experts``, the attention lowerings): the StarCoder
 and the OLMoE train step at their rehearsal sizes are the programs they
 were before PR 31's layer pattern, held experts and window went in.
 
@@ -73,7 +74,7 @@ PARENT = {
     ),
     # PR 48 edits the four files the Ling-3.0 step runs (``ops/kda.py``,
     # ``ops/pallas/kda.py``, ``ops/pallas/kda_mixer.py`` and
-    # ``_kda_partial``) for a decay gate without a bound, chosen statically:
+    # ``_kda_partial``, since PR 56 in ``models/mixers/kda.py``) for a decay gate without a bound, chosen statically:
     # the bounded gate's step is the one it was at PR 48's parent (42dead0),
     # where both digests were taken (a rehearsal's head width runs
     # ``_kda_partial`` and the XLA form of the core and of the chains;
